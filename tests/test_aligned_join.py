@@ -127,7 +127,7 @@ def test_aligned_dml_invalidation(s):
 def test_blocked_expand_beyond_out_cap():
     """A many-to-many join whose fan-out exceeds the device out-cap runs
     as K row-range passes with host-merged agg states — device=True, no
-    CPU fallback (VERDICT r4 weak #3 / next #2)."""
+    CPU fallback."""
     eng = Engine()
     s = eng.new_session()
     s.execute("CREATE TABLE big (k BIGINT, v BIGINT)")

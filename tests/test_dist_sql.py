@@ -177,7 +177,7 @@ def test_skewed_exchange_retries_exactly_once(session):
     # 3 distinct group keys hash onto ≤3 of 8 shards: the re-key exchange
     # overflows a deliberately tiny initial bucket cap; the exchange
     # reports its exact need, so recovery is ONE recompile (per-exchange
-    # needs, VERDICT r2 weak #7). This pins the MONOLITHIC oracle path —
+    # needs). This pins the MONOLITHIC oracle path —
     # the staged exchange's per-rank equivalent (one skewed rank = one
     # recompile) is pinned in tests/test_staged_exchange.py
     from tidb_tpu.executor import dist_fragment as DF

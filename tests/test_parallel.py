@@ -36,7 +36,7 @@ def test_exchange_round_trip(mesh):
 
     fn = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("shard"),) * 2,
                            out_specs=(P("shard"), P("shard"), P()),
-                           check_rep=False))
+                           check_vma=False))
     sv, sl = shard_rows(mesh, [vals, live])
     rv, rl, need = fn(sv, sl)
     assert int(need) <= N           # capacity sufficed: nothing dropped
@@ -66,7 +66,7 @@ def test_exchange_overflow_detected(mesh):
         return need
 
     fn = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("shard"),) * 2,
-                           out_specs=P(), check_rep=False))
+                           out_specs=P(), check_vma=False))
     # all 256 rows hash to one destination: the reported need is exact,
     # so the caller can size the retry in ONE recompile
     assert int(fn(*shard_rows(mesh, [vals, live]))) == 32  # 256/8 per shard
@@ -115,7 +115,7 @@ def test_broadcast_build(mesh):
         return g.sum(), gl.sum()
 
     fn = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("shard"),) * 2,
-                           out_specs=(P(), P()), check_rep=False))
+                           out_specs=(P(), P()), check_vma=False))
     s, c = fn(*shard_rows(mesh, [vals, live]))
     assert int(s) == vals.sum() and int(c) == N
 

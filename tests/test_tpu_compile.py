@@ -1,0 +1,354 @@
+"""The main path's device programs compile for a TPU v5e — asked of the TPU
+compiler that is installed here, with no chip attached — and `chip_smoke.py`
+keeps its contract on the CPU.
+
+How the programs get here. The engine builds its device programs from a
+plan and a slab geometry (`_FragmentProgram`, `TreeProgram`,
+`_FusedFinalizeProgram`, `_AggMergeProgram`), and running a statement at a
+real slab (8M rows) on the CPU backend costs most of a minute. So the
+statements of `chip_smoke.py` run ONCE at a toy size under a recording
+`jax.jit`, which keeps, for every program launched, the program object and
+the shapes it was called with. Each is then rebuilt through its own
+constructor at the real geometry — one full lineitem slab of
+`DEFAULT_MAX_SLAB_ROWS` rows with its build sides in proportion (orders a
+quarter, customer a fortieth: the deployment at SF≈1.4, one probe slab), and
+the stack of SF=10's 8 slab partials for the merge/finalize — and compiled
+for a described `v5e:2x2` device. The toy is large enough (256K rows) that
+the compressed layouts come out as at SF=10 (pack / dict / delta per column),
+except that SF=10's wider join keys pack wider; the aligned-join build
+closures (`device_cache._lut/_probe/_gather`) are not rebuilt. The smoke
+itself runs those on the chip.
+
+`on_tpu()` sees the CPU here, so it is steered to True IN THE TEST — the
+programs compiled are the chip's variants (donated merge inputs, f32 for
+DOUBLE) — never through an option of the program.
+
+One file on purpose: only one process may hold the TPU compiler's library,
+and the worker that gets this file is the one that loads it. The topology is
+described inside a module-scoped fixture (never at import, never autouse),
+and nothing here starts a child process.
+"""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+TOY_ROWS = 1 << 18          # lineitem rows of the recording run (one toy slab)
+HBM_BYTES = 16 * 10 ** 9    # one v5e chip
+SF10_SLABS = 8              # ceil(60,012,150 / DEFAULT_MAX_SLAB_ROWS)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+class _Recorder:
+    """Stands in for `jax.jit` during the toy run: jits as usual, and
+    keeps (owner object, method name, argument shapes) of every distinct
+    call signature of a bound-method program."""
+
+    def __init__(self, jax):
+        self.jax = jax
+        self.real_jit = jax.jit
+        self.calls = []     # (owner, method name, args as ShapeDtypeStructs)
+
+    def __call__(self, fn, **kw):
+        jitted = self.real_jit(fn, **kw)
+        owner = getattr(fn, "__self__", None)
+        if owner is None:
+            return jitted
+        rec, jax, seen = self, self.jax, set()
+
+        def call(*args):
+            shapes = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(np.shape(x),
+                                               jax.numpy.result_type(x))
+                if hasattr(x, "dtype") else x, args)
+            if str(shapes) not in seen:
+                seen.add(str(shapes))
+                rec.calls.append((owner, fn.__name__, shapes))
+            return jitted(*args)
+        call.lower = jitted.lower
+        return call
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """Run the smoke's statements once at toy size with the chip's program
+    variants, recording every program launch. → list of calls."""
+    from tidb_tpu.executor import device_cache as dc
+    from tidb_tpu.executor import fragment
+    from tidb_tpu.ops import jax_env
+    from tidb_tpu.session import Engine
+    from tidb_tpu.tools import tpch_shaped as T
+    import chip_smoke
+
+    jax = jax_env.jax
+    rec = _Recorder(jax)
+    mp = pytest.MonkeyPatch()
+    fragment._COMPILE_CACHE.clear()
+    fragment._SPEC_CACHE.clear()
+    try:
+        mp.setattr(jax_env, "on_tpu", lambda: True)
+        mp.setattr(jax, "jit", rec)
+        eng = Engine()
+        eng.global_vars["tidb_enable_auto_analyze"] = False
+        T.load(eng, T.generate(TOY_ROWS, seed=42))
+        s = eng.new_session()
+        s.vars.update(tidb_tpu_row_threshold=1, tidb_tpu_strict="on")
+        for sql in (T.Q1, T.Q3, T.Q6):
+            assert s.query(sql).rows and s.last_engine == "tpu"
+        w = chip_smoke.WRITE
+        s.execute("INSERT INTO lineitem VALUES (10.00, 12345.67, 0.06, "
+                  f"0.02, 'N', 'O', '{w['ship']}', 0)")
+        assert s.query(T.Q6).rows and s.last_engine == "tpu"   # delta slab
+        eng.close()
+    finally:
+        mp.undo()
+        # programs built under the steering must not serve later tests
+        fragment._COMPILE_CACHE.clear()
+        fragment._SPEC_CACHE.clear()
+        dc.clear()
+    return rec.calls
+
+
+def _scaled(jax, tree, factor, sharding, axis=-1):
+    """Every array leaf of `tree`, its `axis` grown by `factor`, placed on
+    the described chip."""
+    def grow(x):
+        if not isinstance(x, jax.ShapeDtypeStruct):
+            return x
+        shape = list(x.shape)
+        if shape:
+            shape[axis] *= factor
+        return jax.ShapeDtypeStruct(tuple(shape), x.dtype, sharding=sharding)
+    return jax.tree.map(grow, tree)
+
+
+def _scaled_cols(jax, cols, factor, sharding):
+    """A scan's column dict: each column is (values, validity) or a packed
+    (words, mask_words[, dictvals | delta base]) — the first two grow with
+    the slab, a dictionary or a base does not."""
+    return {i: tuple(_scaled(jax, leaf, factor if k < 2 else 1, sharding)
+                     for k, leaf in enumerate(col))
+            for i, col in cols.items()}
+
+
+def _rebuild(owner, factor):
+    """The same program through its own constructor at the real slab
+    geometry → (program, jitted entry point by method name)."""
+    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor.tree_fragment import TreeProgram, _walk_joins
+    if isinstance(owner, fragment._FragmentProgram):
+        p = fragment._FragmentProgram(
+            owner.chain, owner.used_cols, owner.in_types,
+            owner.slab_cap * factor, owner.group_cap, owner.key_bounds,
+            owner.has_distinct, owner.layouts, owner.pair_cap)
+        return {"_partial": p.partial, "_merge": p.merge}
+    if isinstance(owner, TreeProgram):
+        p = TreeProgram(
+            owner.plan,
+            {k: (cap * factor, n) for k, (cap, n) in owner.caps.items()},
+            owner.group_cap,
+            [owner.join_cfgs[id(n)] for n in _walk_joins(owner.plan)],
+            owner.agg_key_bounds, owner.scan_layouts, owner.pairs_out,
+            owner.pair_cap)
+        return {"_run": p.run}
+    if isinstance(owner, fragment._FusedFinalizeProgram):
+        p = fragment._FusedFinalizeProgram(owner.agg_root, owner.order_root,
+                                           owner.group_cap)
+        return {"_run": p.run}
+    if isinstance(owner, fragment._AggMergeProgram):
+        p = fragment._AggMergeProgram(owner.root, owner.group_cap)
+        return {"_merge": p.merge}
+    return None
+
+
+def _compile_all(calls, kinds, one_chip, monkeypatch):
+    """Rebuild + compile every recorded call of the given owner kinds.
+    → [(label, CompiledMemoryStats)]"""
+    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor.tree_fragment import TreeProgram
+    from tidb_tpu.ops import jax_env
+    jax = jax_env.jax
+    monkeypatch.setattr(jax_env, "on_tpu", lambda: True)
+    factor = fragment.DEFAULT_MAX_SLAB_ROWS // TOY_ROWS
+    out = []
+    for owner, method, shapes in calls:
+        if not isinstance(owner, kinds):
+            continue
+        entry = _rebuild(owner, factor)[method]
+        if isinstance(owner, fragment._FragmentProgram) \
+                and method == "_partial":
+            cols, n_rows, preps = shapes
+            args = (_scaled_cols(jax, cols, factor, one_chip),
+                    _scaled(jax, n_rows, 1, one_chip),
+                    _scaled(jax, preps, 1, one_chip))
+        elif isinstance(owner, TreeProgram):
+            scans, rows, preps, *rest = shapes
+            args = (tuple(_scaled_cols(jax, c, factor, one_chip)
+                          for c in scans),
+                    _scaled(jax, rows, 1, one_chip),
+                    _scaled(jax, preps, 1, one_chip),
+                    *(_scaled(jax, r, factor, one_chip) for r in rest))
+        else:
+            # merge / finalize: SF=10's 8 slab partials stacked on axis 0
+            args = _scaled(jax, shapes, SF10_SLABS, one_chip, axis=0)
+        compiled = entry.lower(*args).compile()
+        label = f"{type(owner).__name__}.{method}"
+        out.append((label, compiled.memory_analysis()))
+    return out
+
+
+def _fits(stats):
+    for label, m in stats:
+        need = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes + m.generated_code_size_in_bytes)
+        assert need < HBM_BYTES, f"{label} needs {need} bytes of HBM"
+
+
+def test_chain_partials_compile_at_a_full_slab(recorded, one_chip,
+                                               monkeypatch):
+    """Q1 and Q6: scan → in-trace compressed decode → filter → partial
+    aggregate over one 8M-row slab."""
+    from tidb_tpu.executor import fragment
+    calls = [c for c in recorded if c[1] == "_partial"]
+    assert all(c[0].layouts for c in calls), \
+        "a chain ran over raw slabs: decode is not in the trace"
+    stats = _compile_all(calls, fragment._FragmentProgram, one_chip,
+                         monkeypatch)
+    assert len(stats) >= 2          # Q1, Q6 (its delta slab reuses Q6's)
+    _fits(stats)
+
+
+def test_fused_pipeline_compiles_at_a_full_probe_slab(recorded, one_chip,
+                                                      monkeypatch):
+    """Q3: scan → filter → FK-aligned join probe → partial aggregate over
+    one 8M-row lineitem slab with its orders build side."""
+    from tidb_tpu.executor.tree_fragment import TreeProgram
+    stats = _compile_all(recorded, TreeProgram, one_chip, monkeypatch)
+    assert stats, "Q3 launched no fused pipeline program"
+    _fits(stats)
+
+
+def test_finalize_and_merge_compile_over_sf10_partials(recorded, one_chip,
+                                                       monkeypatch):
+    """The whole-query tails: fused finalize (merge → finalize → ORDER BY)
+    and the delta merge, over 8 stacked slab partials, inputs donated."""
+    from tidb_tpu.executor import fragment
+    calls = [c for c in recorded if c[1] != "_partial"]
+    stats = _compile_all(
+        calls, (fragment._FusedFinalizeProgram, fragment._AggMergeProgram,
+                fragment._FragmentProgram), one_chip, monkeypatch)
+    labels = {label for label, _ in stats}
+    assert "_FusedFinalizeProgram._run" in labels, labels
+    assert labels & {"_FragmentProgram._merge", "_AggMergeProgram._merge"}, \
+        f"no merge program was launched by the delta path: {labels}"
+    _fits(stats)
+
+
+def test_shard_map_aggregate_step_compiles_for_four_chips(topo, monkeypatch):
+    """The distributed Q3-shaped step (filter → all_to_all exchange of
+    both sides → per-shard sort-probe join → two-phase aggregate) on a
+    mesh of the described chips. At 4096 probe rows, not a real size: this
+    program's TPU compile grows with its row count (12 s here, 170 s at
+    64K rows, 206 s at 256K — PERF.md, open questions), so tier-1 only
+    asks that the step partitions and lowers for four chips at all."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from tidb_tpu.ops import jax_env
+    from tidb_tpu.parallel.dist_query import AXIS, build_agg_join_step
+    jax, jnp = jax_env.jax, jax_env.jnp
+    monkeypatch.setattr(jax_env, "on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+    assert mesh.devices.size == 4
+    n, b = 1 << 12, 1 << 10
+    step = build_agg_join_step(mesh, bucket_cap=n // 4, group_cap=32,
+                               filter_limit=0.7)
+    f = jax_env.device_float_dtype()
+    assert f == jnp.float32
+    row = NamedSharding(mesh, P(AXIS))
+
+    def arr(rows, dtype):
+        return jax.ShapeDtypeStruct((rows,), dtype, sharding=row)
+
+    compiled = step.lower(
+        arr(n, jnp.int64), arr(n, f), arr(n, f), arr(n, jnp.bool_),
+        arr(b, jnp.int64), arr(b, jnp.int64), arr(b, f),
+        arr(b, jnp.bool_)).compile()
+    assert "all-to-all" in compiled.as_text()
+    _fits([("agg_join_step (per device)", compiled.memory_analysis())])
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py's control flow, guarded at no chip time
+# ---------------------------------------------------------------------------
+
+def test_chip_smoke_refuses_the_cpu_backend(capsys):
+    """On the CPU backend the smoke stops at the device phase, before any
+    data is made: it raises (a non-zero exit from `__main__`) and never
+    prints a result line."""
+    import chip_smoke
+    with pytest.raises(chip_smoke.SmokeFailed, match="needs a TPU"):
+        chip_smoke.main(["--sf", "0.01"])
+    out = capsys.readouterr().out
+    phases = [json.loads(line)["phase"] for line in out.splitlines()]
+    assert phases == ["device"]
+    assert '"ok"' not in out
+
+
+def test_chip_smoke_phases_pass_on_cpu_with_device_phase_bypassed(
+        capsys, monkeypatch):
+    """Load → serve → check → memory through the wire server against the
+    numpy reference, with the device phase replaced BY THIS TEST and
+    `on_tpu()` steered so `tidb_tpu_engine=auto` takes the device path
+    (on the CPU backend it would — rightly — fail every device check)."""
+    import chip_smoke
+    from tidb_tpu.ops import jax_env
+    device = {"platform": "cpu", "kind": "cpu", "count": 1}
+    monkeypatch.setattr(chip_smoke, "phase_device", lambda chips: device)
+    monkeypatch.setattr(jax_env, "on_tpu", lambda: True)
+    assert chip_smoke.main(["--sf", "0.01", "--seed", "7"]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    recs = [json.loads(line) for line in lines[:-1]]
+    served = [r for r in recs if r["phase"] == "serve"]
+    assert [r["statement"] for r in served] == ["Q1", "Q3", "Q5", "Q6"]
+    for r in served:
+        assert "device:yes" in r["explain_analyze"] and r["fallbacks"] == 0
+        assert r["cold"]["launches"] > 0 and r["warm"]["compiles"] == 0
+        assert r["warm"]["h2d_bytes"] == 0
+    writes = [r for r in recs if r["phase"] == "write"]
+    assert [r["count"] for r in writes] == ["60013", "60012"]
+    assert not [r for r in recs if r["phase"] == "check_failed"]
+
+
+def test_chip_smoke_fails_when_a_statement_stays_off_the_device(
+        capsys, monkeypatch):
+    """The device path cannot hide: with the device phase bypassed but
+    `on_tpu()` telling the truth, `auto` serves from the CPU engine,
+    every answer is still right — and the smoke fails."""
+    import chip_smoke
+    monkeypatch.setattr(chip_smoke, "phase_device", lambda chips: {
+        "platform": "cpu", "kind": "cpu", "count": 1})
+    assert chip_smoke.main(["--sf", "0.01"]) == 1
+    out = capsys.readouterr().out
+    assert '"ok"' not in out
+    failed = [json.loads(line)["what"] for line in out.splitlines()
+              if json.loads(line)["phase"] == "check_failed"]
+    assert any("no device program launched" in w for w in failed)
+    assert not any("differ from the numpy reference" in w for w in failed)

@@ -17,6 +17,10 @@ def _explain(s, sql):
 @pytest.fixture()
 def s():
     eng = Engine()
+    # stats_version is part of the plan-cache key: a background analyze
+    # pass landing between two identical queries (it does, on a loaded
+    # host) re-plans the second one and breaks the cache-hit assertions
+    eng.global_vars["tidb_enable_auto_analyze"] = False
     s = eng.new_session()
     s.execute("CREATE TABLE t (a BIGINT, b BIGINT, g VARCHAR(4))")
     s.execute("INSERT INTO t VALUES " + ",".join(

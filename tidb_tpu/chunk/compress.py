@@ -167,8 +167,18 @@ def choose_layout(vals: np.ndarray, valid: np.ndarray,
 
 
 def _pack_codes(codes: np.ndarray, width: int) -> np.ndarray:
-    """Non-negative uint64 codes (< 2^width) → uint32 words, element j
-    of word w at bits [j*width, (j+1)*width)."""
+    """Non-negative uint64 codes (< 2^width) → uint32 words in PLANAR
+    order: with `per` codes to a word and n_words words, code k sits in
+    word k % n_words at bits [j*width, (j+1)*width), j = k // n_words —
+    plane j is the contiguous run of codes [j*n_words, (j+1)*n_words).
+
+    Planar, not interleaved (code k in word k // per), for the decode on
+    the chip: unpacking a plane is one shift/mask over the whole word
+    array, and the planes concatenate with the row axis kept minor-most.
+    The interleaved order needs a (n_words, per) → (n_words*per,)
+    reshape whose minor dimension is 2..32 wide; the TPU compiler
+    relayouts that tile by tile and took a minute per column at 256K
+    rows (longer with size) before any program ran."""
     per = WORD_BITS // width
     n = codes.shape[0]
     n_words = -(-n // per)
@@ -176,9 +186,9 @@ def _pack_codes(codes: np.ndarray, width: int) -> np.ndarray:
         pad = np.zeros(n_words * per, dtype=np.uint64)
         pad[:n] = codes
         codes = pad
-    codes = codes.reshape(n_words, per)
+    codes = codes.reshape(per, n_words)
     shifts = np.arange(per, dtype=np.uint64) * np.uint64(width)
-    words = np.bitwise_or.reduce(codes << shifts[None, :], axis=1)
+    words = np.bitwise_or.reduce(codes << shifts[:, None], axis=0)
     return words.astype(np.uint32)
 
 
@@ -220,7 +230,8 @@ def _unpack_codes(words, width: int, cap: int, xp):
     shifts = (xp.arange(per) * width).astype(np.uint32)
     m = np.uint32(0xFFFFFFFF) if width == WORD_BITS \
         else np.uint32((1 << width) - 1)
-    codes = (w[:, None] >> shifts[None, :]) & m
+    # (per, n_words) planes, row axis minor-most — see _pack_codes
+    codes = (w[None, :] >> shifts[:, None]) & m
     return codes.reshape(-1)[:cap]
 
 

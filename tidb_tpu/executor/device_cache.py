@@ -37,7 +37,7 @@ elsewhere (locate_tables is its oracle).
 from __future__ import annotations
 
 import threading
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
@@ -318,6 +318,26 @@ def invalidate(table_id: int):
 
 
 _STORE_FINALIZERS: Dict[int, object] = {}
+# ids of collected stores, queued by their weakref finalizers. The
+# collector runs a finalizer on whatever thread allocates next — possibly
+# inside a `with _LOCK` block that is iterating _CACHE, or on a thread
+# holding some other lock while a sibling holds _LOCK — so the finalizer
+# itself only appends here (atomic, lock-free) and the eviction happens
+# at the next cache call that cares (_reap_dead_stores).
+_DEAD_STORES: deque = deque()
+
+
+def _reap_dead_stores() -> None:
+    """Evict the entries of every store collected since the last call —
+    run by the cache's entry points before they read _CACHE, so a dead
+    engine's tables are never looked up, counted against the budget, or
+    left holding HBM past the next statement."""
+    while _DEAD_STORES:
+        try:
+            store_id = _DEAD_STORES.popleft()
+        except IndexError:              # a sibling thread drained it
+            return
+        _evict_store(store_id)
 
 
 def _evict_store(store_id: int):
@@ -385,18 +405,23 @@ def _pod_partition(ctx, td) -> bool:
     return _approx_rows(td) >= max(min_rows, 1)
 
 
-def locate_tables(table_ids) -> Dict[int, set]:
+def locate_tables(table_ids, store_id: Optional[int] = None) \
+        -> Dict[int, set]:
     """table_id → set of pool device indices currently holding a cached
     entry for it (-1 marks a pod-partitioned entry whose slab ranges
     span owner devices). The scheduler's locality oracle — a snapshot,
     advisory only: routing to a device that just evicted is a perf
-    miss, never a correctness problem."""
+    miss, never a correctness problem. `store_id` scopes the answer to
+    one store (None = every store): table ids restart per engine, so an
+    unscoped lookup lets engine A's resident table steer — or, when it
+    is pod-partitioned, un-steal — engine B's statements."""
     want = set(table_ids)
     out: Dict[int, set] = {}
     with _LOCK:
-        for k in _CACHE:
-            if k[2] in want:
-                out.setdefault(k[2], set()).add(k[0])
+        keys = list(_CACHE)
+    for k in keys:
+        if k[2] in want and (store_id is None or k[1] == store_id):
+            out.setdefault(k[2], set()).add(k[0])
     return out
 
 
@@ -404,6 +429,7 @@ def replica_overhead_bytes() -> int:
     """HBM bytes spent on replica copies beyond the largest resident
     copy of each (store, table, parts) — the bench's replication-cost
     meter. Pod-partitioned entries hold one copy by construction."""
+    _reap_dead_stores()
     with _LOCK:
         entries = list(_CACHE.items())
     groups: Dict[tuple, List[int]] = {}
@@ -1055,9 +1081,10 @@ def storage_stats(store_id: Optional[int] = None) -> List[dict]:
     entry — the information_schema.table_storage source. Snapshot under
     the lock; byte math (which touches device array metadata only)
     happens outside it. `store_id` scopes the report to one store: a
-    dead engine's entries linger until its store finalizer runs, and
+    dead engine's entries linger until its store is collected, and
     table ids restart per engine, so an unscoped dump can attribute a
     stale entry to an unrelated live table."""
+    _reap_dead_stores()
     with _LOCK:
         entries = [(k, e) for k, e in _CACHE.items()
                    if store_id is None or k[1] == store_id]
@@ -1160,11 +1187,12 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         dev = -1
     key = (dev, id(store), table_id,
            None if parts is None else tuple(parts)) if cacheable else None
+    _reap_dead_stores()
     with _LOCK:
         if store is not None and id(store) not in _STORE_FINALIZERS:
             import weakref
             _STORE_FINALIZERS[id(store)] = weakref.finalize(
-                store, _evict_store, id(store))
+                store, _DEAD_STORES.append, id(store))
 
     def _usable(e):
         # td identity = data freshness; n_cols = DDL (ADD/DROP COLUMN)
@@ -1428,6 +1456,7 @@ def _evict_to_budget(budget: int, keep, keep_aligned=frozenset(),
     partitioned entries charge each owner device only the slabs it
     actually holds."""
     dead_c, dead_a = [], []
+    _reap_dead_stores()
     with _LOCK:
         keep_tables = frozenset(keep_tables) | _all_protected()
         usage: Dict[int, int] = {}

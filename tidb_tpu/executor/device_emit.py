@@ -25,12 +25,19 @@ def emit_decode(layout, slab, cap: int):
     """Traced decode of one compressed column slab INSIDE the fragment:
     (words, mask_words[, dictvals]) → (vals, valid) in the logical
     dtype. A gather-free broadcast shift/mask (plus one take for dict
-    layouts) fused by XLA into the consuming scan→filter→…→agg program,
-    so decode adds zero extra launches and raw bytes never exist on the
-    device either — only in registers mid-program."""
+    layouts) traced into the consuming scan→filter→…→agg program, so
+    decode adds zero extra launches and raw bytes never cross PCIe.
+
+    The decoded column sits behind an optimization barrier: it is
+    computed once per program and read by every consumer. Without it XLA
+    clones the unpack into each consuming fusion — one per aggregate —
+    and the TPU compiler then took six minutes over Q1's partial at an
+    8M-row slab (13 s with the barrier), for the price of one HBM round
+    trip of the decoded column per slab."""
     from tidb_tpu.chunk import compress
-    from tidb_tpu.ops.jax_env import jnp
-    return compress.decode_slab(layout, slab, cap, jnp)
+    from tidb_tpu.ops.jax_env import jnp, lax
+    return lax.optimization_barrier(
+        compress.decode_slab(layout, slab, cap, jnp))
 
 
 def emit_sort(keys, descs, live):
@@ -589,9 +596,11 @@ def _emit_pack_codes(codes, width: int, cap: int):
     from tidb_tpu.ops.jax_env import jnp
     per = 32 // width
     n_words = -(-cap // per)
-    c = codes.astype(jnp.uint32).reshape(n_words, per)
+    c = jnp.pad(codes.astype(jnp.uint32), (0, n_words * per - cap))
     shifts = (jnp.arange(per) * width).astype(jnp.uint32)
-    return jnp.sum(c << shifts[None, :], axis=1, dtype=jnp.uint32)
+    # planar order (plane j = codes [j*n_words, (j+1)*n_words))
+    return jnp.sum(c.reshape(per, n_words) << shifts[:, None], axis=0,
+                   dtype=jnp.uint32)
 
 
 def emit_delta_merge(layout, slab, keep, n_new: int, cap: int):

@@ -108,7 +108,7 @@ class DistTreeProgram(TreeProgram):
             self._run, mesh=mesh,
             in_specs=(P(AXIS), P(AXIS), P()),
             out_specs=out_specs,
-            check_rep=False))
+            check_vma=False))
 
     def __call__(self, scan_inputs, scan_rows, prep_vals,
                  aligned_inputs=()):
@@ -155,7 +155,7 @@ class DistTreeProgram(TreeProgram):
         # per-exchange NEEDED capacities (already pmax'd by exchange()):
         # the executor resizes ONLY the overflowed exchange's buckets to
         # the exact reported need — one skewed exchange costs one
-        # recompile and touches nothing else (VERDICT r2 weak #7)
+        # recompile and touches nothing else
         out["exchange_need"] = (jnp.stack(self._overflow_flags)
                                 if self._overflow_flags
                                 else jnp.zeros(0, dtype=jnp.int32))

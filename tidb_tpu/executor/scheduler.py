@@ -140,8 +140,13 @@ CLASSES = ("interactive", "batch")
 # _STEAL: this waiter may be migrated to an idle sibling (batch-class
 # admission acquires only). _MOVED: set by the stealer (under the
 # victim's _cv) to the target device index — the waiter observes it in
-# its poll loop and raises _Migrated to re-acquire over there.
-_TICKET, _CONN, _TID, _CLASS, _ENQ_T, _COST, _STEAL, _MOVED = range(8)
+# its poll loop and raises _Migrated to re-acquire over there. _DRAINED:
+# set with _MOVED when the mover was a quarantine drain, not a steal —
+# stamped by the mover, because by the time the waiter wakes the device
+# may already be readmitted (the first flap-guard step is 25 ms) and
+# re-reading its health would book the migration as a steal.
+_TICKET, _CONN, _TID, _CLASS, _ENQ_T, _COST, _STEAL, _MOVED, _DRAINED = \
+    range(9)
 
 
 class _Migrated(BaseException):
@@ -149,10 +154,11 @@ class _Migrated(BaseException):
     BaseException so no generic `except Exception` on the wait path can
     swallow the handoff."""
 
-    def __init__(self, target: int, waited: float):
+    def __init__(self, target: int, waited: float, drained: bool = False):
         super().__init__(f"migrated to device {target}")
         self.target = target
         self.waited = waited
+        self.drained = drained      # moved by a quarantine drain
 
 
 class DeviceScheduler:
@@ -241,7 +247,7 @@ class DeviceScheduler:
                 self._depth += 1
                 return 0.0
             ent = [self._next_ticket, conn_id, tid, cls,
-                   time.monotonic(), cost, bool(steal_ok), None]
+                   time.monotonic(), cost, bool(steal_ok), None, False]
             self._next_ticket += 1
             self._queue.append(ent)
             if ent[_STEAL]:
@@ -254,7 +260,8 @@ class DeviceScheduler:
                         # a stealer dequeued us (and decremented
                         # _stealable) under this lock — hand off
                         raise _Migrated(ent[_MOVED],
-                                        time.monotonic() - t0)
+                                        time.monotonic() - t0,
+                                        ent[_DRAINED])
                     if self._holder is None and self._grantee() is ent:
                         break
                     if ent[_STEAL] and self._pool is not None and \
@@ -593,7 +600,8 @@ class SchedulerPool:
         with self._lock:
             return self.schedulers[conn_id % len(self.schedulers)]
 
-    def place_statement(self, guard, conn_id: int = 0) -> int:
+    def place_statement(self, guard, conn_id: int = 0,
+                        store_id: Optional[int] = None) -> int:
         """→ device index for this statement, stamped once on the guard.
 
         Priority: (1) the guard's existing pin (placement is decided
@@ -601,7 +609,11 @@ class SchedulerPool:
         same queue); (2) the device already holding the tables the
         statement's digest touches (guard.sched_tables, stamped by the
         session's admission classifier from the digest profile, located
-        against the per-device HBM cache); (3) least queue depth, ties
+        against the per-device HBM cache OF THE STATEMENT'S OWN STORE
+        (`store_id` — table ids restart per engine, so another engine's
+        resident table with the same id must neither attract this
+        statement nor, when it is pod-partitioned, pin it against
+        stealing); (3) least queue depth, ties
         to the LOWEST index — cold serial workloads deterministically
         stay on device 0, preserving the PR 5/15 shapes. A digest whose
         working set is pod-PARTITIONED (spans every device) pins
@@ -632,7 +644,7 @@ class SchedulerPool:
             if tables:
                 try:
                     from tidb_tpu.executor import device_cache
-                    located = device_cache.locate_tables(tables)
+                    located = device_cache.locate_tables(tables, store_id)
                 except Exception:  # noqa: BLE001 — placement is advisory
                     located = {}
                 votes: Dict[int, int] = {}
@@ -673,7 +685,8 @@ class SchedulerPool:
                 and self.health.healthy(s.device_index)]
 
     @staticmethod
-    def _claim_waiter(sib: DeviceScheduler, e, target_idx: int) -> bool:
+    def _claim_waiter(sib: DeviceScheduler, e, target_idx: int,
+                      drained: bool = False) -> bool:
         """Claim ONE queued waiter for migration — caller holds sib._cv.
         Re-verifies the entry is still queued and unclaimed before
         stamping _MOVED: the exactly-once guard when a release-into-empty
@@ -684,6 +697,7 @@ class SchedulerPool:
         if e[_MOVED] is not None or e not in sib._queue:
             return False
         e[_MOVED] = int(target_idx)
+        e[_DRAINED] = drained
         sib._queue.remove(e)
         sib._stealable -= 1
         return True
@@ -743,7 +757,8 @@ class SchedulerPool:
             for e in [e for e in sched._queue
                       if e[_STEAL] and e[_MOVED] is None]:
                 if self._claim_waiter(sched, e,
-                                      targets[moved % len(targets)]):
+                                      targets[moved % len(targets)],
+                                      drained=True):
                     moved += 1
             if moved:
                 sched._cv.notify_all()
@@ -844,12 +859,19 @@ def device_slot(ctx):
     conn_id = getattr(guard, "conn_id", 0) if guard is not None else 0
     if _queues_on(ctx):
         POOL.ensure(_visible_devices())
-        idx = POOL.place_statement(guard, conn_id)
+        idx = POOL.place_statement(guard, conn_id, _ctx_store_id(ctx))
         with POOL._lock:
             sched = POOL.schedulers[idx]
     else:
         sched = SCHEDULER
     return sched.slot(guard=guard, conn_id=conn_id)
+
+
+def _ctx_store_id(ctx) -> Optional[int]:
+    """The device cache's key for the store this statement reads (None
+    when it has no snapshot: placement then sees every store)."""
+    store = getattr(getattr(ctx, "snapshot", None), "store", None)
+    return None if store is None else id(store)
 
 
 def admit_statement(ctx) -> None:
@@ -870,7 +892,7 @@ def admit_statement(ctx) -> None:
         return
     POOL.ensure(_visible_devices())
     conn_id = getattr(guard, "conn_id", 0)
-    home = POOL.place_statement(guard, conn_id)
+    home = POOL.place_statement(guard, conn_id, _ctx_store_id(ctx))
     if getattr(guard, "sched_class", None) != "batch" \
             or getattr(guard, "sched_admitted", False):
         return
@@ -904,7 +926,7 @@ def admit_statement(ctx) -> None:
                 continue
             idx, steal_ok = int(m.target), False
             from tidb_tpu.util.observability import REGISTRY
-            if not POOL.health.healthy(home):
+            if m.drained:
                 # quarantine drain, not a steal: the waiter left a
                 # quarantined home queue for a healthy survivor
                 guard.sched_migrated = \

@@ -3,13 +3,15 @@
 The reference keeps result delivery native-fast (server/util.go
 dumpTextRow is pure Go on the hot path); our analog compiles
 rowcodec.cpp once per checkout with the baked-in g++ and falls back to
-the pure-Python encoder when no toolchain is available. No pybind11 in
-the image, so the ABI is a C struct array + raw numpy pointers.
+the pure-Python encoder when that fails — saying so once, with the reason,
+in the log; `encoder()` names the one in use. No pybind11 in the image, so
+the ABI is a C struct array + raw numpy pointers.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -37,19 +39,20 @@ class _Col(ctypes.Structure):
     ]
 
 
-def _build() -> Optional[str]:
+def _build() -> str:
+    if os.path.exists(_LIB) and \
+            os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
+        return _LIB
     try:
-        if os.path.exists(_LIB) and \
-                os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-            return _LIB
         subprocess.run(
             ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC,
              "-o", _LIB + ".tmp"],
             check=True, capture_output=True, timeout=120)
-        os.replace(_LIB + ".tmp", _LIB)
-        return _LIB
-    except Exception:  # noqa: BLE001 — no toolchain → python fallback
-        return None
+    except subprocess.CalledProcessError as e:
+        raise OSError(f"g++ exited {e.returncode}: "
+                      f"{e.stderr.decode(errors='replace')[-400:]}") from e
+    os.replace(_LIB + ".tmp", _LIB)
+    return _LIB
 
 
 def get_lib():
@@ -59,20 +62,27 @@ def get_lib():
         if _tried:
             return _lib
         _tried = True
-        path = _build()
-        if path is None:
-            return None
         try:
-            lib = ctypes.CDLL(path)
+            lib = ctypes.CDLL(_build())
             lib.encode_text_rows.restype = ctypes.c_longlong
             lib.encode_text_rows.argtypes = [
                 ctypes.POINTER(_Col), ctypes.c_int32, ctypes.c_int64,
                 ctypes.POINTER(ctypes.c_uint8),
                 ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64]
             _lib = lib
-        except OSError:
+        except (OSError, subprocess.SubprocessError) as e:
+            # no toolchain / unloadable .so → python encoder; said once
+            # (_tried), never silently
+            logging.getLogger("tidb_tpu.native").warning(
+                "native row codec unavailable, using the Python encoder: "
+                "%s: %s", type(e).__name__, e)
             _lib = None
         return _lib
+
+
+def encoder() -> str:
+    """Which text-row encoder this process serves with."""
+    return "native" if get_lib() is not None else "python"
 
 
 # column kind tags (must match rowcodec.cpp)

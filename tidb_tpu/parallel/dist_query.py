@@ -140,7 +140,7 @@ def build_agg_join_step(mesh, bucket_cap: int, group_cap: int,
         step, mesh=mesh,
         in_specs=(P(AXIS),) * 8,
         out_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(), P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 

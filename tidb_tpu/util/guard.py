@@ -33,6 +33,8 @@ from __future__ import annotations
 import threading
 import time
 import weakref
+from collections import deque
+from contextlib import contextmanager
 from typing import Dict, Optional
 
 from tidb_tpu.errors import QueryInterrupted, QueryTimeout
@@ -128,26 +130,44 @@ class ExecutionGuard:
 
 class ProcessRegistry:
     """conn_id → {session weakref, active guard, conn_killed} — the
-    process-info table KILL and SHOW PROCESSLIST resolve against."""
+    process-info table KILL and SHOW PROCESSLIST resolve against.
+
+    A collected Session leaves the table in two steps. Its weakref
+    finalizer only queues the conn id: the collector runs finalizers on
+    whatever thread happens to allocate, which may already be inside one
+    of the locked blocks below, so a finalizer that took `_lock` would
+    wait on its own thread for ever (and wedge every connection of the
+    process behind it). The queue is drained by the next locked call,
+    before it reads the table — a dead connection is never visible to
+    info/kill/snapshot, only its dict slot lingers until then."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._conns: Dict[int, dict] = {}
+        self._dead: deque = deque()     # conn ids queued by finalizers
+
+    @contextmanager
+    def _table(self):
+        """The table under the lock, collected sessions reaped first."""
+        with self._lock:
+            while self._dead:            # only lock holders pop
+                cid = self._dead.popleft()
+                ent = self._conns.get(cid)
+                if ent is not None and ent["session"]() is None:
+                    del self._conns[cid]
+            yield self._conns
 
     def register(self, session) -> None:
         cid = session.conn_id
-        with self._lock:
-            self._conns[cid] = {"session": weakref.ref(session),
-                                "guard": None, "conn_killed": False}
-        weakref.finalize(session, self._drop, cid)
-
-    def _drop(self, cid: int) -> None:
-        with self._lock:
-            self._conns.pop(cid, None)
+        with self._table() as conns:
+            conns[cid] = {"session": weakref.ref(session),
+                          "guard": None, "conn_killed": False}
+        # deque.append is atomic and takes no lock: safe from inside GC
+        weakref.finalize(session, self._dead.append, cid)
 
     def stmt_begin(self, cid: int, guard: ExecutionGuard) -> None:
-        with self._lock:
-            ent = self._conns.get(cid)
+        with self._table() as conns:
+            ent = conns.get(cid)
             if ent is None:
                 return
             if ent["conn_killed"]:
@@ -155,17 +175,17 @@ class ProcessRegistry:
             ent["guard"] = guard
 
     def stmt_end(self, cid: int) -> None:
-        with self._lock:
-            ent = self._conns.get(cid)
+        with self._table() as conns:
+            ent = conns.get(cid)
             if ent is not None:
                 ent["guard"] = None
 
     def info(self, cid: int) -> Optional[dict]:
-        with self._lock:
-            ent = self._conns.get(cid)
-            if ent is None:
+        with self._table() as conns:
+            ent = conns.get(cid)
+            sess = ent["session"]() if ent is not None else None
+            if sess is None:
                 return None
-            sess = ent["session"]()
             return {"session": sess,
                     "user": getattr(sess, "user", None),
                     "guard": ent["guard"],
@@ -175,9 +195,9 @@ class ProcessRegistry:
         """KILL [QUERY] <cid>: flip the active guard's flag (if a
         statement is running) and, for a connection kill, poison the
         entry so future statements refuse to start. → found?"""
-        with self._lock:
-            ent = self._conns.get(cid)
-            if ent is None:
+        with self._table() as conns:
+            ent = conns.get(cid)
+            if ent is None or ent["session"]() is None:
                 return False
             if not query_only:
                 ent["conn_killed"] = True
@@ -189,8 +209,8 @@ class ProcessRegistry:
     def snapshot(self) -> list:
         """Every live connection, running or idle, for SHOW PROCESSLIST:
         (conn_id, user, guard|None, conn_killed)."""
-        with self._lock:
-            items = list(self._conns.items())
+        with self._table() as conns:
+            items = list(conns.items())
         out = []
         for cid, ent in items:
             sess = ent["session"]()
@@ -201,8 +221,8 @@ class ProcessRegistry:
         return out
 
     def conn_killed(self, cid: int) -> bool:
-        with self._lock:
-            ent = self._conns.get(cid)
+        with self._table() as conns:
+            ent = conns.get(cid)
             return bool(ent and ent["conn_killed"])
 
 
