@@ -187,14 +187,30 @@ def test_registry_processlist_delegates_to_session_registry():
 
 # ---- timeline -------------------------------------------------------------
 
-def test_timeline_off_by_default_and_zero_events(dev_session):
+def test_timeline_off_by_default_and_zero_events(dev_session, monkeypatch):
     assert timeline.ENABLED is False
     s = dev_session
+    made = []
+
+    class CountingAnnotation:
+        def __init__(self, *a, **kw):
+            made.append(a)
+
+    # a statement with the timeline off makes no event, no span object and
+    # no TraceAnnotation: span() hands out one shared no-op
+    monkeypatch.setattr(timeline, "_ANNOTATION", CountingAnnotation)
+    before = len(timeline.last_events())
     s.query(AGG)
     assert timeline.ENABLED is False
     assert timeline.global_path() is None
-    # record() is a no-op without a collector attached
+    assert made == [] and len(timeline.last_events()) == before
+    assert timeline.span("a", "stmt") is timeline.span("b", "wire", k=1)
+    with timeline.span("a", "stmt") as sp:
+        assert sp is timeline.span("c", "plan")
+    # record() / tag() are no-ops without a collector attached
     timeline.record("x", "sched", dur_us=5.0, pid=1)
+    timeline.tag(cache="hit")
+    assert len(timeline.last_events()) == before
 
 
 def test_trace_format_chrome_single_statement(dev_session):
@@ -205,7 +221,7 @@ def test_trace_format_chrome_single_statement(dev_session):
     evs = [e for e in doc["traceEvents"] if e["ph"] != "M"]
     assert evs, "no events captured"
     cats = {e["cat"] for e in evs}
-    assert "compute" in cats and "fetch" in cats
+    assert {"launch", "drain", "fetch"} <= cats
     assert {e["pid"] for e in evs} == {s.conn_id}
     # scoped capture must detach afterwards
     assert timeline.ENABLED is False

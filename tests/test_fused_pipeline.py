@@ -15,7 +15,7 @@ Pinned invariants:
   resumed result matches a Python oracle;
 * warm repeats retrace nothing (PROGRAM_TRACES frozen) and launch at
   most 2 device programs per slab (slab partial + amortized merge);
-* fused compute spans land in the Chrome timeline one-per-slab, labeled
+* fused launch spans land in the Chrome timeline one-per-slab, labeled
   with the pipeline signature digest, and cold builds charge the
   `compile:fused` lane.
 """
@@ -256,10 +256,10 @@ def test_timeline_fused_spans_and_compile_lane():
         s.query(sql)
     ph = s.last_guard.phases
     fused_spans = [e for e in col.events
-                   if e["name"] == "compute"
+                   if e["cat"] == "launch"
                    and str(e.get("args", {}).get("sig", ""))
                    .startswith("fused:")]
-    # exactly one labeled compute span per fused slab launch
+    # exactly one labeled launch span per fused slab launch
     assert ph.fused_pipelines >= 2, ph.summary()
     assert len(fused_spans) == ph.fused_pipelines, \
         [e.get("args") for e in col.events]

@@ -178,16 +178,16 @@ def test_warm_selective_scan_launches_only_surviving_slabs():
         assert now == ids, \
             f"column {i} re-uploaded on a pruned warm repeat"
 
-    # Chrome trace: skipping removes exactly the pruned slabs' compute
+    # Chrome trace: skipping removes exactly the pruned slabs' launch
     # spans (the unfiltered warm run is the 3-slab baseline)
     s.query(full)                              # warm the unfiltered shape
 
-    def compute_spans(sql):
+    def launch_spans(sql):
         doc = json.loads(s.query("TRACE FORMAT='chrome' " + sql).rows[0][0])
         return len([e for e in doc["traceEvents"]
-                    if e.get("ph") != "M" and e["cat"] == "compute"])
+                    if e.get("ph") != "M" and e["cat"] == "launch"])
 
-    assert compute_spans(full) - compute_spans(sel) == ph.slabs_skipped
+    assert launch_spans(full) - launch_spans(sel) == ph.slabs_skipped
 
 
 def test_warm_read_after_appends_no_base_reupload_one_extra_launch(session):

@@ -154,11 +154,11 @@ def test_eight_point_reads_one_launch_byte_exact(tier, tmp_path):
             == batches0 + 1
         assert _counter("tidb_tpu_microbatch_members_total") \
             == members0 + N_MEMBERS
-        # exactly one batched compute span in the cross-session trace
+        # exactly one batched launch span in the cross-session trace
         path = timeline.flush()
         doc = json.loads(open(path).read())
         spans = [e for e in doc["traceEvents"]
-                 if e.get("ph") != "M" and e.get("cat") == "compute"
+                 if e.get("ph") != "M" and e.get("cat") == "launch"
                  and str((e.get("args") or {}).get("sig", ""))
                  .startswith("batched:")]
         assert len(spans) == 1, f"batched spans: {len(spans)}"

@@ -69,6 +69,9 @@ class _Recorder:
 
     def __call__(self, fn, **kw):
         jitted = self.real_jit(fn, **kw)
+        # the program gives jit a thin wrapper that carries its name
+        # (jax_env.named_jit): the bound method is behind it
+        fn = getattr(fn, "__wrapped__", fn)
         owner = getattr(fn, "__self__", None)
         if owner is None:
             return jitted

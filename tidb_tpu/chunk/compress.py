@@ -125,34 +125,38 @@ def choose_layout(vals: np.ndarray, valid: np.ndarray,
     cardinality cap 4× and lets dictionary win width ties — dict codes
     double as pre-factorized group ids, so the wider cap pays for
     itself on the agg side even when pack would be byte-equal."""
+    from tidb_tpu.util.observability import first_touch
     hints = hints or {}
     dt = vals.dtype
     if dt.kind not in "iu" or dt.itemsize > 8:
         return None, None
     max_width = dt.itemsize * 8 // 2
     name = dt.name
-    all_valid = bool(valid.all())
-    vv = vals if all_valid else vals[valid]
-    if vv.size == 0:
-        # all-NULL column: width 0, nothing stored but the packed mask
-        return ColLayout("pack", 0, 0, name), None
-    lo, hi = int(vv.min()), int(vv.max())
-    pw = _round_width((hi - lo).bit_length())
-    pack = ColLayout("pack", pw, lo, name) \
-        if pw is not None and pw <= max_width else None
-    # sorted fully-valid columns (PKs, timestamps): successive diffs
-    # need max-gap bits, not range bits — a dense sorted PK packs at
-    # width 1-2 regardless of its absolute range
-    if all_valid and vv.size >= 2:
-        v64 = vv.astype(np.int64)
-        diffs = np.diff(v64)
-        if diffs.size and int(diffs.min()) >= 0 and int(diffs.max()) > 0:
-            xw = _round_width(int(diffs.max()).bit_length())
-            if xw is not None and 0 < xw <= max_width and \
-                    (pack is None or xw < pack.width):
-                pack = ColLayout("delta", xw, 0, name)
+    with first_touch("layout"):
+        all_valid = bool(valid.all())
+        vv = vals if all_valid else vals[valid]
+        if vv.size == 0:
+            # all-NULL column: width 0, nothing stored but the packed mask
+            return ColLayout("pack", 0, 0, name), None
+        lo, hi = int(vv.min()), int(vv.max())
+        pw = _round_width((hi - lo).bit_length())
+        pack = ColLayout("pack", pw, lo, name) \
+            if pw is not None and pw <= max_width else None
+        # sorted fully-valid columns (PKs, timestamps): successive diffs
+        # need max-gap bits, not range bits — a dense sorted PK packs at
+        # width 1-2 regardless of its absolute range
+        if all_valid and vv.size >= 2:
+            v64 = vv.astype(np.int64)
+            diffs = np.diff(v64)
+            if diffs.size and int(diffs.min()) >= 0 \
+                    and int(diffs.max()) > 0:
+                xw = _round_width(int(diffs.max()).bit_length())
+                if xw is not None and 0 < xw <= max_width and \
+                        (pack is None or xw < pack.width):
+                    pack = ColLayout("delta", xw, 0, name)
     if allow_dict and (pack is None or pack.width > 1):
-        uniq = np.unique(vv)
+        with first_touch("dict"):
+            uniq = np.unique(vv)
         card = int(uniq.size)
         dict_cap = DICT_CARD_CAP * (4 if hints.get("group_heavy") else 1)
         if card <= dict_cap:

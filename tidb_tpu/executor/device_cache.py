@@ -684,24 +684,27 @@ def _col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
     dictionary is global and searchsorted on the sorted unique keys IS
     np.unique's return_inverse."""
     from tidb_tpu.chunk import compress
-    vals, valid = _materialize_col(ent, col_idx)
+    from tidb_tpu.util.observability import first_touch
+    with first_touch("materialize", col=col_idx):
+        vals, valid = _materialize_col(ent, col_idx)
     if ftype.is_wide_decimal:
         return {"kind": "wide", "vals": vals, "valid": valid,
                 "n_limbs": ftype.wide_limb_count,
                 "dict": None, "bounds": None, "layout": None}
     if ftype.is_varlen:
-        str_vals = np.array([str(v) for v in vals], dtype=object)
-        if ftype.is_ci:
-            from tidb_tpu.types import fold_ci_array
-            folded = fold_ci_array(str_vals)
-            keys, first = np.unique(folded, return_index=True)
-            dictionary = str_vals[first]    # representative per fold class
-            prep = {"kind": "str", "vals": folded, "valid": valid,
-                    "keys": keys}
-        else:
-            dictionary = np.unique(str_vals)
-            prep = {"kind": "str", "vals": str_vals, "valid": valid,
-                    "keys": dictionary}
+        with first_touch("dict", col=col_idx):
+            str_vals = np.array([str(v) for v in vals], dtype=object)
+            if ftype.is_ci:
+                from tidb_tpu.types import fold_ci_array
+                folded = fold_ci_array(str_vals)
+                keys, first = np.unique(folded, return_index=True)
+                dictionary = str_vals[first]    # representative per class
+                prep = {"kind": "str", "vals": folded, "valid": valid,
+                        "keys": keys}
+            else:
+                dictionary = np.unique(str_vals)
+                prep = {"kind": "str", "vals": str_vals, "valid": valid,
+                        "keys": dictionary}
         prep["dict"] = dictionary
         prep["bounds"] = (0, len(dictionary) - 1) if len(dictionary) else None
         prep["layout"] = None
@@ -719,9 +722,10 @@ def _col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
         return {"kind": "float", "vals": vals, "valid": valid,
                 "dtype": np.dtype(device_float_dtype()),
                 "dict": None, "bounds": None, "layout": None}
+    with first_touch("layout", col=col_idx):
+        bounds = _col_bounds(vals, valid, None)
     prep = {"kind": "num", "vals": vals, "valid": valid,
-            "dict": None, "bounds": _col_bounds(vals, valid, None),
-            "layout": None}
+            "dict": None, "bounds": bounds, "layout": None}
     if ent.compressed:
         layout, dictvals = compress.choose_layout(vals, valid,
                                                   hints=workload_hints())
@@ -891,6 +895,7 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
     from tidb_tpu.executor import zonemap
     from tidb_tpu.ops.jax_env import jax, jnp
     from tidb_tpu.util import failpoint
+    from tidb_tpu.util.observability import first_touch
     new_slabs = {i: [] for i in preps}
     dev_idx = getattr(ent, "device", 0)
     owners = getattr(ent, "owners", None)
@@ -969,10 +974,11 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
             for i, prep in preps.items():
                 if fill is not None and i in fill and s not in fill[i]:
                     continue    # warm slab of a partially-lost column
-                host[i] = _slab_host(prep, start, stop, ent.slab_cap)
+                with first_touch("pack", col=i, slab=s):
+                    host[i] = _slab_host(prep, start, stop, ent.slab_cap)
         slab_dev = owners[s] if owners is not None and s < len(owners) \
             else dev_idx
-        with phases.phase("upload"):
+        with phases.phase("upload"), first_touch("upload"):
             for i, ht in host.items():
                 dev_t = tuple(_put(a, slab_dev) for a in ht)
                 if i in dict_cols:
@@ -1392,6 +1398,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         ph.add_scan(phys, logical=logi)
         return ent, None
     failpoint.inject("device-transfer")
+    from tidb_tpu.util.observability import first_touch
     ftypes = scan.schema.field_types
     preps = {}
     with ph.phase("encode"):
@@ -1421,7 +1428,8 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
             # decision below already sees the new columns' statistics
             ent.layouts[i] = preps[i]["layout"]
             if ent.compressed:
-                zm = _col_zone_stats(ent, preps[i])
+                with first_touch("layout", col=i):
+                    zm = _col_zone_stats(ent, preps[i])
                 if zm is not None:
                     ent.zmaps[i] = zm
     _validate_layouts(ent, used_cols)
@@ -1615,7 +1623,8 @@ def get_aligned(ctx, key, tds: Dict[int, object],
     fact_codes_slabs/fact_valid_slabs: per-fact-slab device arrays of the
     probe key (raw ints or dictionary codes already in the build's code
     space). bounds: the build key column's (lo, hi) value domain."""
-    from tidb_tpu.ops.jax_env import jax, jnp
+    from tidb_tpu.ops.jax_env import (jax, jnp, named_jit,
+                                      program_name)
     if getattr(build_ent, "is_delta", False):
         # delta generations break the LUT's prefix-liveness assumption
         # (iota < total): tombstone-compacted slabs and the appended
@@ -1643,8 +1652,11 @@ def get_aligned(ctx, key, tds: Dict[int, object],
     nb = int(bk_v.shape[0])
     n_live = build_ent.total
     ent = AlignedJoin(key, tds, slab_cap, n_slabs, nb)
+    # named after what the trace bakes in, and nothing of this process
+    # (`key` holds object ids): the name is part of the persistent cache's
+    # key, and a restarted server must find these programs again
+    sig = repr((lo, hi, nb, n_live, slab_cap))
 
-    @jax.jit
     def _lut(bv, bm):
         iota = jnp.arange(nb, dtype=jnp.int32)
         alive = jnp.asarray(bm) & (iota < n_live)
@@ -1656,7 +1668,8 @@ def get_aligned(ctx, key, tds: Dict[int, object],
         lut = jnp.full(domain + 1, -1, jnp.int32).at[code].set(iota)
         return cnt[:domain].max() if domain else jnp.int32(0), lut
 
-    maxcnt, lut = _lut(bk_v, bk_m)
+    maxcnt, lut = named_jit(_lut, program_name("gather_lut", sig))(
+        bk_v, bk_m)
     if int(jax.device_get(maxcnt)) > 1:
         ent.unique = False          # negative result cached
         with _LOCK:
@@ -1664,7 +1677,6 @@ def get_aligned(ctx, key, tds: Dict[int, object],
                 _ALIGNED[key] = ent
         return None
 
-    @jax.jit
     def _probe(lut_, pv, pm):
         c = jnp.asarray(pv).astype(jnp.int64) - lo
         in_dom = (c >= 0) & (c <= (hi - lo))
@@ -1673,6 +1685,7 @@ def get_aligned(ctx, key, tds: Dict[int, object],
         matched = jnp.asarray(pm) & in_dom & (midx >= 0)
         return jnp.clip(midx, 0, nb - 1), matched
 
+    _probe = named_jit(_probe, program_name("gather_probe", sig))
     for pv, pm in zip(fact_codes_slabs, fact_valid_slabs):
         midx, matched = _probe(lut, pv, pm)
         ent.midx.append(midx)
@@ -1691,18 +1704,19 @@ def get_aligned(ctx, key, tds: Dict[int, object],
 def aligned_col(ent: AlignedJoin, build_ent: CachedTable, col: int):
     """Ensure build column `col` is materialized in the fact row space;
     → per-fact-slab [(v, m)] (wide decimals keep their limb-plane axis)."""
-    from tidb_tpu.ops.jax_env import jax, jnp
+    from tidb_tpu.ops.jax_env import jnp, named_jit, program_name
     cached = ent.cols.get(col)
     if cached is not None:
         return cached
     bv, bm = _build_cat(build_ent, col)
 
-    @jax.jit
     def _gather(midx, matched):
         v = jnp.take(jnp.asarray(bv), midx, axis=-1)
         m = jnp.take(jnp.asarray(bm), midx) & matched
         return v, m
 
+    _gather = named_jit(_gather, program_name(
+        "gather", repr((col, bv.shape, str(bv.dtype), ent.slab_cap))))
     slabs = [_gather(midx, matched)
              for midx, matched in zip(ent.midx, ent.matched)]
     with _LOCK:

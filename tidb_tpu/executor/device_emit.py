@@ -11,16 +11,47 @@ literally one function.
 All helpers are pure traced functions of (ctx, live, plan node): `ctx` is
 an expression EvalContext over device arrays, `live` the row-liveness mask
 (the sel vector analog).
+
+Every stage traces under a `jax.named_scope` of its name (`STAGES`), so each
+device operation's metadata (`op_name`: `jit(<program>)/.../<stage>/<op>`)
+says which stage emitted it, and a profile can be read by stage
+(benchmarks/device_scopes.py). The programs' own filter, projection and
+join-probe code (fragment.py, tree_fragment.py) enters `stage()` too. Where
+stages nest (finalize holds merge and sort) the innermost names the
+operation. Trace-time only: nothing runs per call.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 from tidb_tpu.expression import EvalContext
 from tidb_tpu.expression.aggfuncs import AggFunc
 
 
+STAGES = ("decode", "filter", "project", "join_probe", "agg", "merge",
+          "finalize", "sort", "window", "partition")
+
+
+def stage(name: str):
+    """`jax.named_scope(name)` for one of `STAGES`."""
+    from tidb_tpu.ops.jax_env import jax
+    return jax.named_scope(name)
+
+
+def _staged(name: str):
+    """Trace the decorated emit function under `stage(name)`."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def emit(*args, **kwargs):
+            with stage(name):
+                return fn(*args, **kwargs)
+        return emit
+    return deco
+
+
+@_staged("decode")
 def emit_decode(layout, slab, cap: int):
     """Traced decode of one compressed column slab INSIDE the fragment:
     (words, mask_words[, dictvals]) → (vals, valid) in the logical
@@ -40,6 +71,7 @@ def emit_decode(layout, slab, cap: int):
         compress.decode_slab(layout, slab, cap, jnp))
 
 
+@_staged("sort")
 def emit_sort(keys, descs, live):
     """Traced full-sort permutation under ORDER BY semantics → (perm,
     n_live). Thin named wrapper over ops/factorize.sort_perm: keys are
@@ -50,6 +82,7 @@ def emit_sort(keys, descs, live):
     return F.sort_perm(keys, descs, live)
 
 
+@_staged("sort")
 def emit_topk(keys, descs, live, k: int):
     """Traced top-k row selection → (idx (k,), n_out). Same rank
     encoding as emit_sort; k is static (min(count+offset, cap))."""
@@ -57,6 +90,7 @@ def emit_topk(keys, descs, live, k: int):
     return F.topn(keys, descs, live, k)
 
 
+@_staged("agg")
 def emit_distinct(gids, v, m, live, n: int, keys, pairs_out: bool,
                   pair_cap: int = 0, vcols=None):
     """Traced per-batch DISTINCT dedup for one aggregate argument tuple →
@@ -109,24 +143,27 @@ def emit_root(ctx: EvalContext, live, root, aggs=None, group_cap: int = 0,
         # LIMIT pushdown (no ORDER BY): the first offset+count live rows
         # in row order — a stable partition of the live mask, the
         # degenerate keyless emit_topk
-        n = live.shape[0]
-        k = min(root.count + root.offset, slab_cap or n)
-        idx = jnp.argsort(jnp.logical_not(live), stable=True)[:k]
-        n_out = jnp.minimum(live.sum().astype(jnp.int32), jnp.int32(k))
-        out_cols = [ctx.column(i) for i in range(len(root.schema))]
-        gathered = [(jnp.asarray(v)[idx], jnp.asarray(m)[idx])
-                    for v, m in out_cols]
+        with stage("sort"):
+            n = live.shape[0]
+            k = min(root.count + root.offset, slab_cap or n)
+            idx = jnp.argsort(jnp.logical_not(live), stable=True)[:k]
+            n_out = jnp.minimum(live.sum().astype(jnp.int32), jnp.int32(k))
+            out_cols = [ctx.column(i) for i in range(len(root.schema))]
+            gathered = [(jnp.asarray(v)[idx], jnp.asarray(m)[idx])
+                        for v, m in out_cols]
         return {"cols": gathered, "n_out": n_out}
     if isinstance(root, (PhysTopN, PhysSort)):
-        keys = [e.eval(ctx) for e in root.by]
-        out_cols = [ctx.column(i) for i in range(len(root.schema))]
-        if isinstance(root, PhysTopN):
-            k = min(root.count + root.offset, slab_cap or live.shape[0])
-            idx, n_out = emit_topk(keys, root.descs, live, k)
-        else:
-            idx, n_out = emit_sort(keys, root.descs, live)
-        gathered = [(jnp.asarray(v)[idx], jnp.asarray(m)[idx])
-                    for v, m in out_cols]
+        with stage("sort"):
+            keys = [e.eval(ctx) for e in root.by]
+            out_cols = [ctx.column(i) for i in range(len(root.schema))]
+            if isinstance(root, PhysTopN):
+                k = min(root.count + root.offset,
+                        slab_cap or live.shape[0])
+                idx, n_out = emit_topk(keys, root.descs, live, k)
+            else:
+                idx, n_out = emit_sort(keys, root.descs, live)
+            gathered = [(jnp.asarray(v)[idx], jnp.asarray(m)[idx])
+                        for v, m in out_cols]
         return {"cols": gathered, "n_out": n_out}
     if isinstance(root, PhysWindow):
         return emit_window(ctx, live, root)
@@ -135,6 +172,7 @@ def emit_root(ctx: EvalContext, live, root, aggs=None, group_cap: int = 0,
                      for v, m in out_cols], "live": live}
 
 
+@_staged("merge")
 def emit_merge(root, aggs: List[AggFunc], group_cap: int, key_cols,
                states, slot_live):
     """Root merge of stacked per-slab agg partials: re-factorize the
@@ -167,6 +205,7 @@ def emit_merge(root, aggs: List[AggFunc], group_cap: int, key_cols,
     return {"keys": key_out, "states": out_states, "n_groups": n_final}
 
 
+@_staged("finalize")
 def emit_finalize(root, order_root, aggs: List[AggFunc], group_cap: int,
                   key_cols, states, slot_live):
     """Fused finalize: agg merge → finalize expressions → root ORDER BY /
@@ -208,6 +247,7 @@ def emit_finalize(root, order_root, aggs: List[AggFunc], group_cap: int,
             "n_groups": merged["n_groups"], "n_out": n_out}
 
 
+@_staged("agg")
 def emit_agg(ctx: EvalContext, live, root, aggs: List[AggFunc],
              group_cap: int, key_bounds=None, pairs_out: bool = False,
              pair_cap: int = 0):
@@ -379,6 +419,7 @@ def _distinct_arg(ctx: EvalContext, live, desc):
     return F.dense_codes(vcols, live), m, vcols
 
 
+@_staged("agg")
 def agg_states(ctx, live, root, aggs, gids, cap: int, n: int):
     """Per-aggregate partial states over one batch (DISTINCT args dedup
     via factorize.distinct_mask) — shared by single-device and per-shard
@@ -420,6 +461,7 @@ def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
 # ---------------------------------------------------------------------------
 
 
+@_staged("window")
 def emit_window(ctx: EvalContext, live, root):
     """Window root on device: one lax.sort per distinct (partition, order)
     spec, then the cumulative/segment primitives of ops/window.py traced
@@ -433,6 +475,7 @@ def emit_window(ctx: EvalContext, live, root):
                      for v, m in out_cols], "live": live}
 
 
+@_staged("window")
 def emit_window_cols(ctx: EvalContext, live, root, in_cols):
     """The traced window computation proper → the child's column list
     (None placeholders preserved) with one appended (value, mask) column
@@ -520,6 +563,7 @@ def _window_value(ctx, live, d, n, perm, pstart, peerstart):
                      range_key=range_key)
 
 
+@_staged("partition")
 def emit_partition(arrays: Sequence, dest, live, n_shards: int,
                    bucket_cap: int):
     """Traced per-rank bucket scatter — stage 1 of the staged exchange.
@@ -562,7 +606,7 @@ def emit_partition(arrays: Sequence, dest, live, n_shards: int,
     return bufs, sent_live, counts, counts.max()
 
 
-def emit_batched(partial_fn):
+def emit_batched(partial_fn, name: str):
     """Same-plan micro-batching entry: vmap one fragment's traced
     per-slab partial over a LEADING MEMBER AXIS of the prepared inputs
     (each member = one queued statement's stacked parameters), with the
@@ -570,15 +614,15 @@ def emit_batched(partial_fn):
     program whose every output leaf grows a leading member axis; the
     micro-batcher (executor/microbatch.py) slices that axis back out,
     one lane per waiting session. → the jitted batched callable
-    `(cols, n_rows, stacked_preps) -> outputs`."""
-    from tidb_tpu.ops.jax_env import jax
+    `(cols, n_rows, stacked_preps) -> outputs`, compiled under `name`."""
+    from tidb_tpu.ops.jax_env import jax, named_jit
 
     def batched(cols, n_rows, stacked_preps):
         return jax.vmap(partial_fn,
                         in_axes=(None, None, 0))(cols, n_rows,
                                                  stacked_preps)
 
-    return jax.jit(batched)
+    return named_jit(batched, name)
 
 
 # ---------------------------------------------------------------------------
@@ -622,7 +666,7 @@ def emit_delta_merge(layout, slab, keep, n_new: int, cap: int):
     caller's responsibility to reject: their codes are successive
     diffs, which a permutation invalidates."""
     from tidb_tpu.chunk import compress
-    from tidb_tpu.ops.jax_env import jax, jnp
+    from tidb_tpu.ops.jax_env import jnp, named_jit, program_name
     kind = "raw" if layout is None else layout.kind
     width = 0 if layout is None else layout.width
     wide = layout is None and getattr(slab[0], "ndim", 1) == 2
@@ -649,7 +693,8 @@ def emit_delta_merge(layout, slab, keep, n_new: int, cap: int):
                               jnp.uint32(0))
             return _emit_pack_codes(codes, width, cap), mwords
 
-        fn = _DELTA_MERGE_CACHE[ckey] = jax.jit(_rewrite)
+        fn = _DELTA_MERGE_CACHE[ckey] = named_jit(
+            _rewrite, program_name("delta_merge", repr(ckey)))
 
     out_v, out_m = fn(slab[0], slab[1], jnp.asarray(keep),
                       jnp.int32(n_new))

@@ -84,15 +84,15 @@ class DistTreeProgram(TreeProgram):
     def __init__(self, plan: PhysicalPlan, caps: Dict[int, int],
                  group_cap: int, mesh, bucket_caps: Dict[int, int],
                  join_cfgs: Optional[Sequence[JoinCfg]] = None,
-                 scan_layouts=None):
-        from tidb_tpu.ops.jax_env import jax, shard_map
+                 scan_layouts=None, kind: str = "dist", sig: str = ""):
+        from tidb_tpu.ops.jax_env import jax, named_jit, shard_map
         self.mesh = mesh
         self.n_shards = mesh.devices.size
         self.bucket_caps = bucket_caps    # id(exchange-node) → bucket cap
         # TreeProgram.__init__ builds prep_nodes and jits self._run; we
         # re-wrap with shard_map afterwards.
         super().__init__(plan, caps, group_cap, join_cfgs,
-                         scan_layouts=scan_layouts)
+                         scan_layouts=scan_layouts, kind=kind, sig=sig)
         P = jax.sharding.PartitionSpec
         root = plan
         flags = {"join_unique": P(), "join_need": P(),
@@ -104,11 +104,11 @@ class DistTreeProgram(TreeProgram):
             out_specs = {"cols": P(AXIS), "n_out": P(AXIS), **flags}
         else:   # window / selection / projection / join row root
             out_specs = {"cols": P(AXIS), "live": P(AXIS), **flags}
-        self.run = jax.jit(shard_map(
+        self.run = named_jit(shard_map(
             self._run, mesh=mesh,
             in_specs=(P(AXIS), P(AXIS), P()),
             out_specs=out_specs,
-            check_vma=False))
+            check_vma=False), self.name)
 
     def __call__(self, scan_inputs, scan_rows, prep_vals,
                  aligned_inputs=()):
@@ -473,13 +473,13 @@ class StagedDistAgg:
             # the rank's partial streams these slabs
             ph.add_scan(_rank_b, logical=_rank_lb)
             with self.ctx.device_slot():
-                with ph.phase("compute"):
+                with ph.launch(prog.partial_name, slab=r):
                     out = prog.partial(dcols,
                                        jnp.int32(int(self.rank_rows[r])),
                                        prep_vals)
             ph.note_launch()
             ph.note_fused()   # per-rank chain partial = fused local stage
-            with ph.phase("compute"):
+            with ph.drain():
                 # drain outside the scheduler slot (GIL-released wait):
                 # sibling statements dispatch while this rank executes
                 jax.block_until_ready(out)
@@ -931,61 +931,57 @@ class StagedDistExchange:
         ph = self.ctx.phases
         dcols = None
         out = None
-        t0 = timeline.now_us() if timeline.ENABLED else 0.0
         try:
-            failpoint.inject(site)
-            with ph.phase("upload"):
-                dcols = {i: tuple(jax.device_put(a, dev) for a in t)
-                         for i, t in info["rank_cols"][r].items()}
-            phys_b = logi_b = 0
-            for i, t in info["rank_cols"][r].items():
-                b = sum(a.nbytes for a in t)
-                phys_b += b
-                lay = info["layouts"].get(i)
-                logi_b += _compress.raw_slab_bytes(lay, info["cap"]) \
-                    if lay is not None else b
-            ph.add_h2d(phys_b, logical=logi_b)
-            ph.add_scan(phys_b, logical=logi_b)
-            with self.ctx.device_slot():
-                with ph.phase("compute"):
-                    out = prog((dcols,),
-                               (jnp.int32(int(info["rank_rows"][r])),),
-                               prep_vals)
-            ph.note_launch()
-            ph.note_fused()
-            with ph.phase("compute"):
-                jax.block_until_ready(out)
-            # commit point of the rank's partition output: a fault here
-            # loses ONLY this rank's buckets — the retry re-runs stage 1
-            # for this rank alone
-            failpoint.inject("exchange-checkpoint-write")
-            with ph.phase("fetch"):
-                if info["exch"].kind == "hash":
-                    need = int(np.asarray(jax.device_get(out["need"])))
-                    if need > bcap:
-                        # rows past the cap were dropped in the scatter —
-                        # don't checkpoint; report exact need instead
-                        return {"overflow": need}
-                    got = jax.device_get({"bufs": out["bufs"],
-                                          "counts": out["counts"]})
-                    ck = {"bufs": got["bufs"],
-                          "counts": np.asarray(got["counts"]),
-                          "cap": bcap}
-                else:
-                    got = jax.device_get({"bufs": out["bufs"],
-                                          "live": out["live"]})
-                    idx = np.nonzero(np.asarray(got["live"]))[0]
-                    ck = {"rows": {i: (np.asarray(v)[idx],
-                                       np.asarray(m)[idx])
-                                   for i, (v, m) in got["bufs"].items()}}
-            ph.add_d2h(tree_nbytes(got) + 4)
-            if timeline.ENABLED:
-                timeline.record("partition", "partition",
-                                dur_us=timeline.now_us() - t0,
-                                pid=getattr(ph, "conn_id", 0),
-                                args={"rank": r,
-                                      "exchange": info["tag"]})
-            return ck
+            with timeline.span("partition", "partition", pid=ph.conn_id,
+                               req=ph.req, rank=r,
+                               exchange=info["tag"]):
+                failpoint.inject(site)
+                with ph.phase("upload"):
+                    dcols = {i: tuple(jax.device_put(a, dev) for a in t)
+                             for i, t in info["rank_cols"][r].items()}
+                phys_b = logi_b = 0
+                for i, t in info["rank_cols"][r].items():
+                    b = sum(a.nbytes for a in t)
+                    phys_b += b
+                    lay = info["layouts"].get(i)
+                    logi_b += _compress.raw_slab_bytes(lay, info["cap"]) \
+                        if lay is not None else b
+                ph.add_h2d(phys_b, logical=logi_b)
+                ph.add_scan(phys_b, logical=logi_b)
+                with self.ctx.device_slot():
+                    with ph.launch(prog.name, slab=r):
+                        out = prog((dcols,),
+                                   (jnp.int32(int(info["rank_rows"][r])),),
+                                   prep_vals)
+                ph.note_launch()
+                ph.note_fused()
+                with ph.drain():
+                    jax.block_until_ready(out)
+                # commit point of the rank's partition output: a fault here
+                # loses ONLY this rank's buckets — the retry re-runs stage 1
+                # for this rank alone
+                failpoint.inject("exchange-checkpoint-write")
+                with ph.phase("fetch"):
+                    if info["exch"].kind == "hash":
+                        need = int(np.asarray(jax.device_get(out["need"])))
+                        if need > bcap:
+                            # rows past the cap were dropped in the scatter —
+                            # don't checkpoint; report exact need instead
+                            return {"overflow": need}
+                        got = jax.device_get({"bufs": out["bufs"],
+                                              "counts": out["counts"]})
+                        ck = {"bufs": got["bufs"],
+                              "counts": np.asarray(got["counts"]),
+                              "cap": bcap}
+                    else:
+                        got = jax.device_get({"bufs": out["bufs"],
+                                              "live": out["live"]})
+                        idx = np.nonzero(np.asarray(got["live"]))[0]
+                        ck = {"rows": {i: (np.asarray(v)[idx],
+                                           np.asarray(m)[idx])
+                                       for i, (v, m) in got["bufs"].items()}}
+                ph.add_d2h(tree_nbytes(got) + 4)
+                return ck
         finally:
             # eager-delete discipline (StagedDistAgg._attempt): abandoned
             # buffers must be gone BEFORE a retry / re-dispatch uploads
@@ -1063,41 +1059,37 @@ class StagedDistExchange:
         from tidb_tpu.parallel import collective as C
         from tidb_tpu.util import timeline
         nd = self.nd
-        t0 = timeline.now_us() if timeline.ENABLED else 0.0
-        if info["exch"].kind == "hash":
-            routed, recv_rows = C.route_buckets(ckpts, nd)
-        else:
-            cols = list(ckpts[0]["rows"].keys())
-            full = {i: (np.concatenate([ck["rows"][i][0] for ck in ckpts]),
-                        np.concatenate([ck["rows"][i][1] for ck in ckpts]))
-                    for i in cols}
-            n = full[cols[0]][0].shape[0] if cols else 0
-            routed = [full] * nd
-            recv_rows = [n] * nd
-        recv_cap = _pow2(max(max(recv_rows), 1), lo=64)
+        ph = self.ctx.phases
+        with timeline.span("checkpoint", "checkpoint", pid=ph.conn_id,
+                           req=ph.req, exchange=info["tag"]):
+            if info["exch"].kind == "hash":
+                routed, recv_rows = C.route_buckets(ckpts, nd)
+            else:
+                cols = list(ckpts[0]["rows"].keys())
+                full = {i: (np.concatenate([ck["rows"][i][0] for ck in ckpts]),
+                            np.concatenate([ck["rows"][i][1] for ck in ckpts]))
+                        for i in cols}
+                n = full[cols[0]][0].shape[0] if cols else 0
+                routed = [full] * nd
+                recv_rows = [n] * nd
+            recv_cap = _pow2(max(max(recv_rows), 1), lo=64)
 
-        def pad(bufs):
-            cols = {}
-            for i, (v, m) in bufs.items():
-                pv = np.zeros(recv_cap, dtype=v.dtype)
-                pm = np.zeros(recv_cap, dtype=bool)
-                pv[:v.shape[0]] = v
-                pm[:m.shape[0]] = m
-                cols[i] = (pv, pm)
-            return cols
+            def pad(bufs):
+                cols = {}
+                for i, (v, m) in bufs.items():
+                    pv = np.zeros(recv_cap, dtype=v.dtype)
+                    pm = np.zeros(recv_cap, dtype=bool)
+                    pv[:v.shape[0]] = v
+                    pm[:m.shape[0]] = m
+                    cols[i] = (pv, pm)
+                return cols
 
-        if info["exch"].kind == "hash":
-            rank_cols = [pad(routed[r]) for r in range(nd)]
-        else:
-            shared = pad(routed[0])      # replicated build: pad once
-            rank_cols = [shared] * nd
-        if timeline.ENABLED:
-            timeline.record("checkpoint", "checkpoint",
-                            dur_us=timeline.now_us() - t0,
-                            pid=getattr(self.ctx.phases, "conn_id", 0),
-                            args={"exchange": info["tag"],
-                                  "recv_rows": [int(x)
-                                                for x in recv_rows]})
+            if info["exch"].kind == "hash":
+                rank_cols = [pad(routed[r]) for r in range(nd)]
+            else:
+                shared = pad(routed[0])      # replicated build: pad once
+                rank_cols = [shared] * nd
+        timeline.tag(recv_rows=[int(x) for x in recv_rows])
         return {"rank_cols": rank_cols,
                 "rank_rows": np.asarray(recv_rows, dtype=np.int32),
                 "cap": recv_cap, "layouts": {}, "lay_pairs": ()}
@@ -1114,60 +1106,56 @@ class StagedDistExchange:
         root = self.new_root
         dcols = None
         out = None
-        t0 = timeline.now_us() if timeline.ENABLED else 0.0
         try:
-            failpoint.inject(site)
-            with ph.phase("upload"):
-                dcols = tuple(
-                    {i: tuple(jax.device_put(a, dev) for a in t)
-                     for i, t in src["rank_cols"][r].items()}
-                    for src in self.stage3_order)
-            phys_b = logi_b = 0
-            for src in self.stage3_order:
-                for i, t in src["rank_cols"][r].items():
-                    b = sum(a.nbytes for a in t)
-                    phys_b += b
-                    lay = src["layouts"].get(i)
-                    logi_b += _compress.raw_slab_bytes(lay, src["cap"]) \
-                        if lay is not None else b
-            ph.add_h2d(phys_b, logical=logi_b)
-            ph.add_scan(phys_b, logical=logi_b)
-            rows = tuple(jnp.int32(int(src["rank_rows"][r]))
-                         for src in self.stage3_order)
-            with self.ctx.device_slot():
-                with ph.phase("compute"):
-                    out = prog(dcols, rows, prep_vals)
-            ph.note_launch()
-            ph.note_fused()
-            with ph.phase("compute"):
-                jax.block_until_ready(out)
-            failpoint.inject("shard-checkpoint-write")
-            with ph.phase("fetch"):
-                ju = np.asarray(jax.device_get(out["join_unique"]),
-                                dtype=bool)
-                jt = np.asarray(jax.device_get(out["join_totals"]))
-                if isinstance(root, PhysHashAgg):
-                    ngt = int(np.asarray(jax.device_get(out["n_groups"])))
-                    live_n = ngt if root.group_exprs else 1
-                    k = min(live_n, prog.group_cap)
-                    got = jax.device_get(
-                        {"keys": [(v[:k], m[:k]) for v, m in out["keys"]],
-                         "states": [tuple(a[:k] for a in st)
-                                    for st in out["states"]]})
-                    ck = {"ng": k, "keys": got["keys"],
-                          "states": got["states"]}
-                else:
-                    got = jax.device_get({"cols": out["cols"],
-                                          "live": out["live"]})
-                    ck = got
-                    ngt = 0
-            ph.add_d2h(tree_nbytes(got) + 4)
-            if timeline.ENABLED:
-                timeline.record("probe", "probe",
-                                dur_us=timeline.now_us() - t0,
-                                pid=getattr(ph, "conn_id", 0),
-                                args={"rank": r})
-            return ck, ngt, ju, jt
+            with timeline.span("probe", "probe", pid=ph.conn_id,
+                               req=ph.req, rank=r):
+                failpoint.inject(site)
+                with ph.phase("upload"):
+                    dcols = tuple(
+                        {i: tuple(jax.device_put(a, dev) for a in t)
+                         for i, t in src["rank_cols"][r].items()}
+                        for src in self.stage3_order)
+                phys_b = logi_b = 0
+                for src in self.stage3_order:
+                    for i, t in src["rank_cols"][r].items():
+                        b = sum(a.nbytes for a in t)
+                        phys_b += b
+                        lay = src["layouts"].get(i)
+                        logi_b += _compress.raw_slab_bytes(lay, src["cap"]) \
+                            if lay is not None else b
+                ph.add_h2d(phys_b, logical=logi_b)
+                ph.add_scan(phys_b, logical=logi_b)
+                rows = tuple(jnp.int32(int(src["rank_rows"][r]))
+                             for src in self.stage3_order)
+                with self.ctx.device_slot():
+                    with ph.launch(prog.name, slab=r):
+                        out = prog(dcols, rows, prep_vals)
+                ph.note_launch()
+                ph.note_fused()
+                with ph.drain():
+                    jax.block_until_ready(out)
+                failpoint.inject("shard-checkpoint-write")
+                with ph.phase("fetch"):
+                    ju = np.asarray(jax.device_get(out["join_unique"]),
+                                    dtype=bool)
+                    jt = np.asarray(jax.device_get(out["join_totals"]))
+                    if isinstance(root, PhysHashAgg):
+                        ngt = int(np.asarray(jax.device_get(out["n_groups"])))
+                        live_n = ngt if root.group_exprs else 1
+                        k = min(live_n, prog.group_cap)
+                        got = jax.device_get(
+                            {"keys": [(v[:k], m[:k]) for v, m in out["keys"]],
+                             "states": [tuple(a[:k] for a in st)
+                                        for st in out["states"]]})
+                        ck = {"ng": k, "keys": got["keys"],
+                              "states": got["states"]}
+                    else:
+                        got = jax.device_get({"cols": out["cols"],
+                                              "live": out["live"]})
+                        ck = got
+                        ngt = 0
+                ph.add_d2h(tree_nbytes(got) + 4)
+                return ck, ngt, ju, jt
         finally:
             _tree_delete(dcols)
             _tree_delete(out)

@@ -55,6 +55,7 @@ from tidb_tpu.planner.physical import (PhysHashAgg, PhysHashJoin,
                                        PhysTpuFragment, PhysWindow,
                                        PhysicalPlan)
 from tidb_tpu.types import FieldType
+from tidb_tpu.util import timeline
 from tidb_tpu.util.phases import tree_nbytes
 
 DEFAULT_MAX_SLAB_ROWS = 1 << 23   # 8M rows per device slab
@@ -401,6 +402,42 @@ def _build_lock(sig: str) -> threading.Lock:
         return lk
 
 
+# signature → the request (timeline `req`) building that program right now:
+# a request that waits for the build records it as the wait's `cause`
+_BUILDING: Dict[str, int] = {}
+
+
+def _get_or_build(sig: str, kind: str, build):
+    """The single-flight compile cache: the cached program of `sig`, or
+    `build()`'s, built once however many statements ask at once (one trace
+    per signature; the losers wait and adopt it). A cold build is charged
+    to the running statement and to the `compile:<kind>` timeline lane."""
+    prog = _cache_get(sig)
+    if prog is not None:
+        return prog
+    lock = _build_lock(sig)
+    if not lock.acquire(blocking=False):
+        with timeline.span("compile.wait", "compile",
+                           cause=_BUILDING.get(sig, 0)):
+            lock.acquire()
+    try:
+        prog = _cache_get(sig)      # double-checked: one trace per sig
+        if prog is None:
+            from tidb_tpu.util import phases as _phases
+            cur = _phases.current()
+            _BUILDING[sig] = cur.req if cur is not None else 0
+            t0 = time.perf_counter()
+            try:
+                prog = build()
+                _cache_put(sig, prog)
+            finally:
+                _BUILDING.pop(sig, None)
+            _charge_compile(kind, t0)
+    finally:
+        lock.release()
+    return prog
+
+
 def _tree_delete(tree) -> None:
     """Explicitly free every device array in a pytree of stale outputs
     (superseded slab partials / merge results on a ladder retry): without
@@ -551,7 +588,7 @@ class _FragmentProgram:
     def __init__(self, chain: List[PhysicalPlan], used_cols: List[int],
                  in_types: List[FieldType], slab_cap: int, group_cap: int,
                  key_bounds=None, want_pairs: bool = False, layouts=None,
-                 pair_cap: int = 0):
+                 pair_cap: int = 0, sig: str = ""):
         from tidb_tpu.ops.jax_env import jax
         self.chain = chain
         self.used_cols = used_cols
@@ -572,17 +609,22 @@ class _FragmentProgram:
                 for sub in e.walk():
                     if type(sub).prepare is not Expression.prepare:
                         self.prep_nodes.append(sub)
-        from tidb_tpu.ops.jax_env import on_tpu
-        self.partial = jax.jit(self._partial)
+        from tidb_tpu.ops.jax_env import named_jit, on_tpu, program_name
+        # `sig` is the compile-cache signature: its digest names the
+        # programs in the profile and in `launch` spans
+        self.partial_name = program_name("partial_chain", sig)
+        self.merge_name = program_name("merge", sig)
+        self.partial = named_jit(self._partial, self.partial_name)
         # donate the concatenated partial buffers into the merge: they are
         # consumed exactly once, and donation lets XLA alias them as the
         # merge's workspace — a ladder recompile right after a merge never
         # holds both generations of group state in HBM. CPU backends don't
         # support donation (it would warn per call), so gate on TPU.
         if on_tpu():
-            self.merge = jax.jit(self._merge, donate_argnums=(0, 1, 2))
+            self.merge = named_jit(self._merge, self.merge_name,
+                                   donate_argnums=(0, 1, 2))
         else:
-            self.merge = jax.jit(self._merge)
+            self.merge = named_jit(self._merge, self.merge_name)
         # emit distinct (group, value) pair sets only when a multi-slab
         # execution will merge them — single-slab dedup is already exact
         self.has_distinct = want_pairs and \
@@ -617,17 +659,21 @@ class _FragmentProgram:
         col_list: List = [cols.get(i) for i in range(max_idx + 1)]
         ctx = EvalContext(jnp, col_list, prepared=prepared, on_device=True,
                           n_rows=self.slab_cap)
+        from tidb_tpu.executor.device_emit import stage
         for node in reversed(self.chain):
             if isinstance(node, PhysTableScan):
-                for f in node.filters:
-                    v, m = f.eval(ctx)
-                    live = live & (v != 0) & m
+                with stage("filter"):
+                    for f in node.filters:
+                        v, m = f.eval(ctx)
+                        live = live & (v != 0) & m
             elif isinstance(node, PhysSelection):
-                for c in node.conditions:
-                    v, m = c.eval(ctx)
-                    live = live & (v != 0) & m
+                with stage("filter"):
+                    for c in node.conditions:
+                        v, m = c.eval(ctx)
+                        live = live & (v != 0) & m
             elif isinstance(node, PhysProjection):
-                new_cols = [e.eval(ctx) for e in node.exprs]
+                with stage("project"):
+                    new_cols = [e.eval(ctx) for e in node.exprs]
                 ctx = EvalContext(jnp, new_cols, prepared=prepared,
                                   on_device=True, n_rows=self.slab_cap)
         return ctx, live
@@ -765,16 +811,14 @@ def _charge_compile(kind: str, t0: float) -> None:
     PhaseTimer compile counter (thread-local — the single-flight builders
     have no ExecContext in reach) and emit a timeline compile event."""
     from tidb_tpu.util import phases as _phases
-    from tidb_tpu.util import timeline
     with _CC_LOCK:
         COMPILE_COUNTS[kind] = COMPILE_COUNTS.get(kind, 0) + 1
     cur = _phases.current()
     if cur is not None:
         cur.note_compile()
-    if timeline.ENABLED:
-        timeline.record(f"compile:{kind}", "compile",
-                        dur_us=(time.perf_counter() - t0) * 1e6,
-                        pid=cur.conn_id if cur is not None else 0)
+    timeline.record(f"compile:{kind}", "compile",
+                    dur_us=(time.perf_counter() - t0) * 1e6,
+                    pid=cur.conn_id if cur is not None else 0)
 
 
 def get_program(chain, used_cols, in_types, slab_cap, group_cap,
@@ -787,18 +831,9 @@ def get_program(chain, used_cols, in_types, slab_cap, group_cap,
         sig = _chain_signature(chain, used_cols, in_types, slab_cap,
                                group_cap, key_bounds, layouts) + \
             f"|pairs={want_pairs},{pair_cap}"
-    prog = _cache_get(sig)
-    if prog is None:
-        with _build_lock(sig):
-            prog = _cache_get(sig)      # double-checked: one trace per sig
-            if prog is None:
-                t0 = time.perf_counter()
-                prog = _FragmentProgram(chain, used_cols, in_types,
-                                        slab_cap, group_cap, key_bounds,
-                                        want_pairs, layouts, pair_cap)
-                _cache_put(sig, prog)
-                _charge_compile("chain", t0)
-    return prog
+    return _get_or_build(sig, "chain", lambda: _FragmentProgram(
+        chain, used_cols, in_types, slab_cap, group_cap, key_bounds,
+        want_pairs, layouts, pair_cap, sig=sig))
 
 
 class _BatchedProgram:
@@ -807,28 +842,23 @@ class _BatchedProgram:
     stacked along axis 0 (executor/microbatch.py). Shares the compile
     cache/LRU with scalar programs under sig `batched[B]|<base sig>`."""
 
-    __slots__ = ("base", "b_pad", "partial")
+    __slots__ = ("base", "b_pad", "partial", "partial_name")
 
-    def __init__(self, base: _FragmentProgram, b_pad: int):
+    def __init__(self, base: _FragmentProgram, b_pad: int, sig: str = ""):
         from tidb_tpu.executor import device_emit
+        from tidb_tpu.ops.jax_env import program_name
         self.base = base
         self.b_pad = b_pad
-        self.partial = device_emit.emit_batched(base._partial)
+        self.partial_name = program_name("batched", sig)
+        self.partial = device_emit.emit_batched(base._partial,
+                                                self.partial_name)
 
 
 def get_batched_program(base: _FragmentProgram, b_pad: int,
                         base_sig: str) -> _BatchedProgram:
     sig = f"batched[{b_pad}]|{base_sig}"
-    prog = _cache_get(sig)
-    if prog is None:
-        with _build_lock(sig):
-            prog = _cache_get(sig)      # double-checked: one trace per sig
-            if prog is None:
-                t0 = time.perf_counter()
-                prog = _BatchedProgram(base, b_pad)
-                _cache_put(sig, prog)
-                _charge_compile("batched", t0)
-    return prog
+    return _get_or_build(sig, "batched",
+                         lambda: _BatchedProgram(base, b_pad, sig))
 
 
 def _get_dist_program(root, caps, group_cap, mesh, bucket_caps,
@@ -842,18 +872,9 @@ def _get_dist_program(root, caps, group_cap, mesh, bucket_caps,
     sig = (f"dist={mesh.devices.size}|bux={bux}|" +
            tree_signature(root, caps, group_cap, join_cfgs,
                           scan_layouts=scan_layouts))
-    prog = _cache_get(sig)
-    if prog is None:
-        with _build_lock(sig):
-            prog = _cache_get(sig)      # double-checked: one trace per sig
-            if prog is None:
-                t0 = time.perf_counter()
-                prog = DistTreeProgram(root, caps, group_cap, mesh,
-                                       dict(bucket_caps), join_cfgs,
-                                       scan_layouts)
-                _cache_put(sig, prog)
-                _charge_compile("dist", t0)
-    return prog
+    return _get_or_build(sig, "dist", lambda: DistTreeProgram(
+        root, caps, group_cap, mesh, dict(bucket_caps), join_cfgs,
+        scan_layouts, kind="dist", sig=sig))
 
 
 def get_tree_program(root, caps, group_cap, join_cfgs=None,
@@ -861,17 +882,9 @@ def get_tree_program(root, caps, group_cap, join_cfgs=None,
     from tidb_tpu.executor.tree_fragment import TreeProgram, tree_signature
     sig = tree_signature(root, caps, group_cap, join_cfgs, agg_key_bounds,
                          scan_layouts)
-    prog = _cache_get(sig)
-    if prog is None:
-        with _build_lock(sig):
-            prog = _cache_get(sig)      # double-checked: one trace per sig
-            if prog is None:
-                t0 = time.perf_counter()
-                prog = TreeProgram(root, caps, group_cap, join_cfgs,
-                                   agg_key_bounds, scan_layouts)
-                _cache_put(sig, prog)
-                _charge_compile("tree", t0)
-    return prog
+    return _get_or_build(sig, "tree", lambda: TreeProgram(
+        root, caps, group_cap, join_cfgs, agg_key_bounds, scan_layouts,
+        sig=sig))
 
 
 def get_pipeline_program(root, caps, group_cap, join_cfgs=None,
@@ -889,17 +902,9 @@ def get_pipeline_program(root, caps, group_cap, join_cfgs=None,
         sig = (f"fused|pairs={pairs_out},{pair_cap}|" +
                tree_signature(root, caps, group_cap, join_cfgs,
                               agg_key_bounds, scan_layouts))
-    prog = _cache_get(sig)
-    if prog is None:
-        with _build_lock(sig):
-            prog = _cache_get(sig)      # double-checked: one trace per sig
-            if prog is None:
-                t0 = time.perf_counter()
-                prog = TreeProgram(root, caps, group_cap, join_cfgs,
-                                   agg_key_bounds, scan_layouts,
-                                   pairs_out, pair_cap)
-                _cache_put(sig, prog)
-                _charge_compile("fused", t0)
+    prog = _get_or_build(sig, "fused", lambda: TreeProgram(
+        root, caps, group_cap, join_cfgs, agg_key_bounds, scan_layouts,
+        pairs_out, pair_cap, kind="partial_fused", sig=sig))
     return prog, sig
 
 
@@ -909,16 +914,18 @@ class _AggMergeProgram:
     program re-factorizes the stacked keys and scatter-merges the states —
     the second and last device launch of a warm fused execution."""
 
-    def __init__(self, root, group_cap: int):
-        from tidb_tpu.ops.jax_env import jax, on_tpu
+    def __init__(self, root, group_cap: int, sig: str = ""):
+        from tidb_tpu.ops.jax_env import named_jit, on_tpu, program_name
         self.root = root
         self.group_cap = group_cap
         self.aggs = [build_agg(d) for d in root.aggs]
+        self.merge_name = program_name("merge", sig)
         if on_tpu():
             # stacked partials are dead after the merge — donate them
-            self.merge = jax.jit(self._merge, donate_argnums=(0, 1, 2))
+            self.merge = named_jit(self._merge, self.merge_name,
+                                   donate_argnums=(0, 1, 2))
         else:
-            self.merge = jax.jit(self._merge)
+            self.merge = named_jit(self._merge, self.merge_name)
 
     def _merge(self, key_cols, states, slot_live):
         from tidb_tpu.executor import device_emit
@@ -930,16 +937,8 @@ class _AggMergeProgram:
 def get_merge_program(root, group_cap: int,
                       pipeline_sig: str) -> _AggMergeProgram:
     sig = "fusedmerge|" + pipeline_sig
-    prog = _cache_get(sig)
-    if prog is None:
-        with _build_lock(sig):
-            prog = _cache_get(sig)      # double-checked: one trace per sig
-            if prog is None:
-                t0 = time.perf_counter()
-                prog = _AggMergeProgram(root, group_cap)
-                _cache_put(sig, prog)
-                _charge_compile("fused", t0)
-    return prog
+    return _get_or_build(sig, "fused",
+                         lambda: _AggMergeProgram(root, group_cap, sig))
 
 
 def _order_sig(order_root) -> str:
@@ -955,17 +954,20 @@ class _FusedFinalizeProgram:
     merge launch when the statement root is an eligible Sort/TopN over the
     agg, keeping a warm analytic query at `slabs + 1` programs total."""
 
-    def __init__(self, agg_root, order_root, group_cap: int):
-        from tidb_tpu.ops.jax_env import jax, on_tpu
+    def __init__(self, agg_root, order_root, group_cap: int,
+                 sig: str = ""):
+        from tidb_tpu.ops.jax_env import named_jit, on_tpu, program_name
         self.agg_root = agg_root
         self.order_root = order_root
         self.group_cap = group_cap
         self.aggs = [build_agg(d) for d in agg_root.aggs]
+        self.name = program_name("finalize", sig)
         if on_tpu():
             # stacked partials are dead after the finalize — donate them
-            self.run = jax.jit(self._run, donate_argnums=(0, 1, 2))
+            self.run = named_jit(self._run, self.name,
+                                 donate_argnums=(0, 1, 2))
         else:
-            self.run = jax.jit(self._run)
+            self.run = named_jit(self._run, self.name)
 
     def _run(self, key_cols, states, slot_live):
         from tidb_tpu.executor import device_emit
@@ -981,16 +983,8 @@ def get_finalize_program(agg_root, order_root, group_cap: int,
     timeline lane; `base_sig` is the partial/pipeline signature so the
     finalize specializes per upstream shape."""
     sig = "fusedfinal|" + _order_sig(order_root) + "|" + base_sig
-    prog = _cache_get(sig)
-    if prog is None:
-        with _build_lock(sig):
-            prog = _cache_get(sig)      # double-checked: one trace per sig
-            if prog is None:
-                t0 = time.perf_counter()
-                prog = _FusedFinalizeProgram(agg_root, order_root,
-                                             group_cap)
-                _cache_put(sig, prog)
-                _charge_compile("finalize", t0)
+    prog = _get_or_build(sig, "finalize", lambda: _FusedFinalizeProgram(
+        agg_root, order_root, group_cap, sig))
     return prog, sig
 
 
@@ -1071,6 +1065,7 @@ def _spec_store(key, ent: dict) -> None:
 
 def _spec_note(ph, hit: bool) -> None:
     from tidb_tpu.util.observability import REGISTRY
+    timeline.tag(spec="hit" if hit else "miss")
     if hit:
         if ph is not None:
             ph.note_spec_hit()
@@ -1636,10 +1631,11 @@ class TpuFragmentExec:
         # version, reused across queries. First touch STREAMS: open_table
         # returns a per-slab generator the executors drive, so encode of
         # slab k+1 pipelines behind the (async) upload/compute of slab k.
-        ent, stream = device_cache.open_table(self.ctx, scan, used,
-                                              max_slab,
-                                              phases=self.ctx.phases,
-                                              prune=True)
+        with timeline.span("frag.open", "frag"):
+            ent, stream = device_cache.open_table(self.ctx, scan, used,
+                                                  max_slab,
+                                                  phases=self.ctx.phases,
+                                                  prune=True)
         if ent.total == 0:
             raise FragmentFallback("empty input", reason="empty-input")
         dicts = {i: ent.dicts.get(i) for i in used}
@@ -1781,9 +1777,10 @@ class TpuFragmentExec:
         for scan in scans:
             used = scan.used_columns if scan.used_columns else \
                 list(range(len(scan.schema)))
-            ent = device_cache.get_table(self.ctx, scan, used,
-                                         max_slab,
-                                         phases=self.ctx.phases)
+            with timeline.span("frag.open", "frag"):
+                ent = device_cache.get_table(self.ctx, scan, used,
+                                             max_slab,
+                                             phases=self.ctx.phases)
             if ent.total == 0:
                 raise FragmentFallback("empty input", reason="empty-input")
             ents.append((ent, used))
@@ -1895,7 +1892,7 @@ class TpuFragmentExec:
             # so a sibling statement's encode/dispatch overlaps this
             # one's device execution
             with self.ctx.device_slot():
-                with ph.phase("compute"):
+                with ph.launch(prog.name):
                     out = prog(scan_inputs, scan_rows, prep_vals,
                                aligned_inputs)
             ph.note_launch()
@@ -2142,13 +2139,12 @@ class TpuFragmentExec:
         to_run: Optional[List[int]] = None     # None = cold first pass
         n_joins = len(walk_joins)
         while True:
-            prog, pipe_sig = get_pipeline_program(root, pipe_caps, gcap,
-                                                  join_cfgs, akb,
-                                                  scan_layouts,
-                                                  want_pairs, pair_cap,
-                                                  sig=spec_sig)
+            with timeline.span("frag.program", "frag"):
+                prog, pipe_sig = get_pipeline_program(
+                    root, pipe_caps, gcap, join_cfgs, akb, scan_layouts,
+                    want_pairs, pair_cap, sig=spec_sig)
+                prep_vals = prog.collect_preps(flow_list)
             spec_sig = None
-            prep_vals = prog.collect_preps(flow_list)
             sig12 = hashlib.sha1(pipe_sig.encode()).hexdigest()[:12]
             for s in (range(n_run) if to_run is None else to_run):
                 stale = partials[s]
@@ -2156,7 +2152,8 @@ class TpuFragmentExec:
                 # slot per slab DISPATCH (async queue) — one labeled
                 # compute span per fused slab program in the trace
                 with self.ctx.device_slot():
-                    with ph.phase("compute", sig=f"fused:{sig12}"):
+                    with ph.launch(prog.name, slab=run_ids[s],
+                                   sig=f"fused:{sig12}"):
                         partials[s] = prog(si, sr, prep_vals, ai)
                 ph.note_launch()
                 ph.note_fused()
@@ -2215,8 +2212,8 @@ class TpuFragmentExec:
             # device graph first; every control value returns in ONE
             # batched fetch
             with self.ctx.device_slot():
-                with ph.phase("compute"):
-                    if use_fin or n_run > 1:
+                if use_fin or n_run > 1:
+                    with ph.glue():
                         # concatenate even for one slab: the finalize
                         # donates its inputs, and fresh buffers keep the
                         # checkpointed partials alive for resumable
@@ -2238,14 +2235,15 @@ class TpuFragmentExec:
                                     len(partials[0]["states"][ai_]))))
                         slot_live = jnp.concatenate([p["slot_live"]
                                                      for p in partials])
-                    if use_fin:
-                        pass          # launched below, in its own span
-                    elif n_run == 1:
-                        out = partials[0]
-                    else:
-                        mp = get_merge_program(root, gcap, pipe_sig)
+                if use_fin:
+                    pass          # launched below, in its own span
+                elif n_run == 1:
+                    out = partials[0]
+                else:
+                    mp = get_merge_program(root, gcap, pipe_sig)
+                    with ph.launch(mp.merge_name):
                         out = mp.merge(key_cols, states, slot_live)
-                        ph.note_launch()
+                    ph.note_launch()
             if use_fin:
                 # ONE launch for the whole query tail: agg merge →
                 # finalize expressions → root ORDER BY / TopN
@@ -2253,11 +2251,12 @@ class TpuFragmentExec:
                                                    gcap, pipe_sig)
                 fsig12 = hashlib.sha1(fsig.encode()).hexdigest()[:12]
                 with self.ctx.device_slot():
-                    with ph.phase("compute", sig=f"fused-final:{fsig12}"):
+                    with ph.launch(fprog.name,
+                                   sig=f"fused-final:{fsig12}"):
                         out = fprog.run(key_cols, states, slot_live)
                 ph.note_launch()
             with self.ctx.device_slot():
-                with ph.phase("compute"):
+                with ph.glue():
                     fetch = {"ngs": [p["n_groups"] for p in partials],
                              "ng": out["n_groups"],
                              "jus": [p["join_unique"] for p in partials],
@@ -2265,7 +2264,7 @@ class TpuFragmentExec:
                     if use_fin:
                         fetch["no"] = out["n_out"]
                     small = _piggyback_agg(fetch, out, gcap)
-            with ph.phase("compute"):
+            with ph.drain():
                 jax.block_until_ready(fetch)
             with ph.phase("fetch"):
                 got = jax.device_get(fetch)
@@ -2455,8 +2454,9 @@ class TpuFragmentExec:
                 rng = (np.int32(k * step),
                        np.int32(min((k + 1) * step, total_cap)))
                 with self.ctx.device_slot():
-                    out = prog(scan_inputs, scan_rows, prep_vals,
-                               aligned_inputs, rng)
+                    with self.ctx.phases.launch(prog.name, slab=k):
+                        out = prog(scan_inputs, scan_rows, prep_vals,
+                                   aligned_inputs, rng)
                 self.ctx.phases.note_launch()
                 # flags first: a restart/overflow pass never transfers its
                 # (discarded) group arrays, and good passes transfer only
@@ -2958,10 +2958,10 @@ class TpuFragmentExec:
                 # the GIL-releasing drain runs outside it so sibling
                 # statements' host phases overlap the mesh execution.
                 with self.ctx.device_slot():
-                    with ph.phase("compute"):
+                    with ph.launch(prog.name):
                         raw = prog(scan_inputs, scan_rows, prep_vals)
                 ph.note_launch()
-                with ph.phase("compute"):
+                with ph.drain():
                     jax.block_until_ready(raw)
                 with ph.phase("fetch"):
                     out = jax.device_get(raw)
@@ -3195,10 +3195,11 @@ class TpuFragmentExec:
                 psig = _chain_signature(chain, used, in_types, slab_cap,
                                         group_cap, key_bounds, layouts) + \
                     f"|pairs={want_pairs},{pair_cap}"
-            prog = get_program(chain, used, in_types, slab_cap, group_cap,
-                               key_bounds, want_pairs, layouts, pair_cap,
-                               sig=psig)
-            prep_vals = prog.collect_preps(dicts)
+            with timeline.span("frag.program", "frag"):
+                prog = get_program(chain, used, in_types, slab_cap,
+                                   group_cap, key_bounds, want_pairs,
+                                   layouts, pair_cap, sig=psig)
+                prep_vals = prog.collect_preps(dicts)
             if to_run is None:
                 for s, (cols, n) in enumerate(
                         self._slab_iter(ent, stream, prog.used_cols,
@@ -3207,7 +3208,7 @@ class TpuFragmentExec:
                     # next slab (inside _slab_iter) runs slot-free, so a
                     # sibling's dispatch interleaves with our host work
                     with self.ctx.device_slot():
-                        with ph.phase("compute"):
+                        with ph.launch(prog.partial_name, slab=s):
                             partials[s] = _pin(prog.partial(
                                 cols, jnp.int32(n), prep_vals))
                     ph.note_launch()
@@ -3220,7 +3221,7 @@ class TpuFragmentExec:
                     cols, n = self._slab(ent, slab_ids[s],
                                          prog.used_cols)
                     with self.ctx.device_slot():
-                        with ph.phase("compute"):
+                        with ph.launch(prog.partial_name, slab=s):
                             partials[s] = _pin(prog.partial(
                                 cols, jnp.int32(n), prep_vals))
                     ph.note_launch()
@@ -3287,8 +3288,8 @@ class TpuFragmentExec:
             # cap-1), silently conflating groups, while the merged
             # n_groups alone can look fine.
             with self.ctx.device_slot():
-                with ph.phase("compute"):
-                    if use_fin or n_run > 1:
+                if use_fin or n_run > 1:
+                    with ph.glue():
                         # concatenate even for one slab: the finalize
                         # donates its inputs, and fresh buffers keep the
                         # checkpointed partials alive for resumable
@@ -3311,13 +3312,14 @@ class TpuFragmentExec:
                                     len(partials[0]["states"][ai]))))
                         slot_live = jnp.concatenate([p["slot_live"]
                                                      for p in partials])
-                    if use_fin:
-                        pass          # launched below, in its own span
-                    elif n_run == 1:
-                        out = partials[0]
-                    else:
+                if use_fin:
+                    pass          # launched below, in its own span
+                elif n_run == 1:
+                    out = partials[0]
+                else:
+                    with ph.launch(prog.merge_name):
                         out = prog.merge(key_cols, states, slot_live)
-                        ph.note_launch()
+                    ph.note_launch()
             if use_fin:
                 # ONE launch for the whole query tail: agg merge →
                 # finalize expressions → root ORDER BY / TopN
@@ -3325,17 +3327,18 @@ class TpuFragmentExec:
                                                    group_cap, psig)
                 fsig12 = hashlib.sha1(fsig.encode()).hexdigest()[:12]
                 with self.ctx.device_slot():
-                    with ph.phase("compute", sig=f"fused-final:{fsig12}"):
+                    with ph.launch(fprog.name,
+                                   sig=f"fused-final:{fsig12}"):
                         out = fprog.run(key_cols, states, slot_live)
                 ph.note_launch()
             with self.ctx.device_slot():
-                with ph.phase("compute"):
+                with ph.glue():
                     fetch = {"ngs": [p["n_groups"] for p in partials],
                              "ng": out["n_groups"]}
                     if use_fin:
                         fetch["no"] = out["n_out"]
                     small = _piggyback_agg(fetch, out, prog.group_cap)
-            with ph.phase("compute"):
+            with ph.drain():
                 # drain inside "compute" so the flag fetch below measures
                 # pure transfer, not the device finishing its work — but
                 # OUTSIDE the scheduler slot: the wait releases the GIL,
@@ -3469,12 +3472,12 @@ class TpuFragmentExec:
         for cols, n in self._slab_iter(ent, stream, prog.used_cols,
                                        slab_ids):
             with self.ctx.device_slot():
-                with ph.phase("compute"):
+                with ph.launch(prog.partial_name, slab=len(outs)):
                     outs.append(prog.partial(cols, jnp.int32(n),
                                              prep_vals))
             ph.note_launch()
             ph.note_fused()
-        with ph.phase("compute"):
+        with ph.drain():
             jax.block_until_ready([o["n_out"] for o in outs])
         with ph.phase("fetch"):
             n_outs = [int(n) for n in
@@ -3512,12 +3515,12 @@ class TpuFragmentExec:
         for cols, n in self._slab_iter(ent, stream, prog.used_cols,
                                        slab_ids):
             with self.ctx.device_slot():
-                with ph.phase("compute"):
+                with ph.launch(prog.partial_name, slab=len(outs)):
                     outs.append(prog.partial(cols, jnp.int32(n),
                                              prep_vals))
             ph.note_launch()
             ph.note_fused()
-        with ph.phase("compute"):
+        with ph.drain():
             jax.block_until_ready(outs)
         with ph.phase("fetch"):
             host_outs = jax.device_get(outs)   # one batched round trip

@@ -48,7 +48,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from tidb_tpu.util import failpoint
+from tidb_tpu.util import failpoint, timeline
 from tidb_tpu.util.observability import REGISTRY, normalize_sql
 
 # follower guard-poll cadence while parked on the batch event
@@ -73,12 +73,15 @@ class _Member:
 
 
 class _Batch:
-    __slots__ = ("key", "members", "closed")
+    __slots__ = ("key", "members", "closed", "leader_req")
 
-    def __init__(self, key):
+    def __init__(self, key, leader_req: int = 0):
         self.key = key
         self.members: List[_Member] = []
         self.closed = False
+        # the leader's request id: what a follower's wait names as its
+        # `cause` on the timeline
+        self.leader_req = leader_req
 
 
 def queued_members() -> int:
@@ -122,7 +125,7 @@ def execute(exec_, prog, root, ent, dicts, prep_vals, slab_ids, sig,
             joined = b
         else:
             joined = None
-            mine = _Batch(key)
+            mine = _Batch(key, ctx.phases.req)
             _BATCHES[key] = mine     # replaces a closed/full batch
 
     if joined is not None:
@@ -144,20 +147,22 @@ def _follow(batch: _Batch, m: _Member, guard) -> Optional[object]:
     """Park on the member event; KILL/deadline isolation via guard
     polling. → the demuxed Chunk, or None for individual fallback."""
     t0 = time.monotonic()
-    while not m.event.wait(POLL_S):
-        if guard is None:
-            continue
-        try:
-            guard.check("microbatch-wait")
-        except BaseException:
-            with _LOCK:
-                if not m.claimed and m in batch.members:
-                    # still WAITING: leave the batch; only THIS member
-                    # surfaces the typed error
-                    batch.members.remove(m)
-            # claimed members raise too — the leader's lane for them
-            # computes rows nobody reads; isolation is the point
-            raise
+    with timeline.span("microbatch.wait", "sched", pid=m.conn_id,
+                       cause=batch.leader_req):
+        while not m.event.wait(POLL_S):
+            if guard is None:
+                continue
+            try:
+                guard.check("microbatch-wait")
+            except BaseException:
+                with _LOCK:
+                    if not m.claimed and m in batch.members:
+                        # still WAITING: leave the batch; only THIS
+                        # member surfaces the typed error
+                        batch.members.remove(m)
+                # claimed members raise too — the leader's lane for them
+                # computes rows nobody reads; isolation is the point
+                raise
     waited = time.monotonic() - t0
     if guard is not None and waited > 0.0:
         # parked time is queue time: same ledger the scheduler charges
@@ -239,7 +244,7 @@ def _lead(exec_, batch: _Batch, prog, root, ent, dicts, prep_vals,
             outs = []
             for cols, n in exec_._slab_iter(ent, None, prog.used_cols,
                                             slab_ids):
-                with ph.phase("compute", sig=f"batched:{sig}"):
+                with ph.launch(bprog.partial_name, sig=f"batched:{sig}"):
                     outs.append(bprog.partial(cols, jnp.int32(n),
                                               stacked))
                 ph.note_launch()
@@ -254,7 +259,7 @@ def _lead(exec_, batch: _Batch, prog, root, ent, dicts, prep_vals,
 
     # fetch + demux OUTSIDE the slot (matching _execute_filter's shape)
     try:
-        with ph.phase("compute"):
+        with ph.drain():
             jax.block_until_ready(outs)
         with ph.phase("fetch"):
             host_outs = jax.device_get(outs)

@@ -357,22 +357,20 @@ class DeviceScheduler:
         # the PR 5 lane names, siblings suffix @devN so the Chrome trace
         # shows each chip's queue and occupancy separately
         dev_sfx = f"@dev{self.device_index}" if self.device_index else ""
-        if timeline.ENABLED and waited > 0.0:
+        if waited > 0.0:
+            # the wait was timed inside acquire(): a duration measured
+            # elsewhere, recorded as ending now
             lane = "sched-queue" if cls is None else f"sched-queue:{cls}"
             timeline.record(lane + dev_sfx, "sched", dur_us=waited * 1e6,
                             pid=conn_id)
-        hold_t0 = timeline.now_us() if timeline.ENABLED else 0.0
-        try:
-            if waited and guard is not None:
-                guard.queue_wait_s += waited
-                guard.queue_waits += 1
-            yield waited
-        finally:
-            self.release()
-            if timeline.ENABLED:
-                timeline.record("sched-slot" + dev_sfx, "sched",
-                                dur_us=timeline.now_us() - hold_t0,
-                                pid=conn_id, ts_us=hold_t0)
+        with timeline.span("sched-slot" + dev_sfx, "sched", pid=conn_id):
+            try:
+                if waited and guard is not None:
+                    guard.queue_wait_s += waited
+                    guard.queue_waits += 1
+                yield waited
+            finally:
+                self.release()
 
     def queue_depth(self) -> int:
         with self._cv:
@@ -953,10 +951,9 @@ def admit_statement(ctx) -> None:
     if waited_total > 0.0:
         guard.queue_wait_s += waited_total
         guard.queue_waits += 1
-        if timeline.ENABLED:
-            timeline.record(f"sched-queue:batch"
-                            + (f"@dev{idx}" if idx else ""), "sched",
-                            dur_us=waited_total * 1e6, pid=conn_id)
+        timeline.record("sched-queue:batch"
+                        + (f"@dev{idx}" if idx else ""), "sched",
+                        dur_us=waited_total * 1e6, pid=conn_id)
 
 
 def device_fault(ctx, err) -> Optional[int]:

@@ -680,9 +680,13 @@ class TreeProgram:
                  group_cap: int,
                  join_cfgs: Optional[Sequence[JoinCfg]] = None,
                  agg_key_bounds=None, scan_layouts=None,
-                 pairs_out: bool = False, pair_cap: int = 0):
-        from tidb_tpu.ops.jax_env import jax
+                 pairs_out: bool = False, pair_cap: int = 0,
+                 kind: str = "tree", sig: str = ""):
+        from tidb_tpu.ops.jax_env import named_jit, program_name
         self.plan = plan
+        # what the profile and the `launch` spans call this program: its
+        # kind (tree | partial_fused | dist) and its signature's digest
+        self.name = program_name(kind, sig)
         # DISTINCT aggs under a multi-slab driver: the partial also emits
         # per-slab (group, value) pair sets (capped at pair_cap) so the
         # host can merge exact cross-slab distinct states
@@ -721,7 +725,7 @@ class TreeProgram:
                 for sub in e.walk():
                     if type(sub).prepare is not Expression.prepare:
                         self.prep_nodes.append(sub)
-        self.run = jax.jit(self._run)
+        self.run = named_jit(self._run, self.name)
 
     def collect_preps(self, flow_list: List[List]) -> List:
         """Prepared values in structural order.
@@ -764,6 +768,7 @@ class TreeProgram:
         nodes; root reductions are handled in _finish. The column list is
         ALWAYS schema-length so join concatenation stays positionally
         aligned (unused columns ride as None)."""
+        from tidb_tpu.executor.device_emit import stage
         from tidb_tpu.ops.jax_env import jnp
         if isinstance(node, PhysTableScan):
             sub = self._scan_sub.get(id(node))
@@ -772,9 +777,10 @@ class TreeProgram:
                 # row space; live starts from the match mask
                 col_list, live = sub
                 ctx = self._ctx(col_list)
-                for f in node.filters:
-                    v, m = f.eval(ctx)
-                    live = live & (v != 0) & m
+                with stage("filter"):
+                    for f in node.filters:
+                        v, m = f.eval(ctx)
+                        live = live & (v != 0) & m
                 return list(col_list), live
             slot = next(i for i, s in enumerate(self.scan_order)
                         if s is node)
@@ -822,21 +828,24 @@ class TreeProgram:
                 start, stop = self._ranges
                 live = live & (iota >= start) & (iota < stop)
             ctx = self._ctx(col_list)
-            for f in node.filters:
-                v, m = f.eval(ctx)
-                live = live & (v != 0) & m
+            with stage("filter"):
+                for f in node.filters:
+                    v, m = f.eval(ctx)
+                    live = live & (v != 0) & m
             return col_list, live
         if isinstance(node, PhysSelection):
             cols, live = self._emit(node.children[0], scan_inputs, scan_rows)
             ctx = self._ctx(cols)
-            for c in node.conditions:
-                v, m = c.eval(ctx)
-                live = live & (v != 0) & m
+            with stage("filter"):
+                for c in node.conditions:
+                    v, m = c.eval(ctx)
+                    live = live & (v != 0) & m
             return cols, live
         if isinstance(node, PhysProjection):
             cols, live = self._emit(node.children[0], scan_inputs, scan_rows)
             ctx = self._ctx(cols)
-            return [e.eval(ctx) for e in node.exprs], live
+            with stage("project"):
+                return [e.eval(ctx) for e in node.exprs], live
         if isinstance(node, PhysHashJoin):
             return self._emit_join(node, scan_inputs, scan_rows)
         if isinstance(node, PhysWindow) and node is not self.plan:
@@ -856,6 +865,7 @@ class TreeProgram:
 
     # -- join ---------------------------------------------------------------
     def _emit_join(self, node: PhysHashJoin, scan_inputs, scan_rows):
+        from tidb_tpu.executor.device_emit import stage
         from tidb_tpu.ops.jax_env import jnp
         from tidb_tpu.ops import join as J
         cfg = self.join_cfgs[id(node)]
@@ -868,50 +878,51 @@ class TreeProgram:
             bcols, blive, pcols, plive = rcols, rlive, lcols, llive
         else:
             bcols, blive, pcols, plive = lcols, llive, rcols, rlive
-        bkeys, pkeys = join_key_exprs(node)
-        bctx = self._ctx(bcols)
-        # the probe ctx must see the JOIN flow for KeyRemap preps, but
-        # KeyRemap evals its child against probe-side columns
-        pctx = self._ctx(pcols)
-        bk = [e.eval(bctx) for e in bkeys]
-        pk = [e.eval(pctx) for e in pkeys]
-        nb = blive.shape[0]
+        with stage("join_probe"):
+            bkeys, pkeys = join_key_exprs(node)
+            bctx = self._ctx(bcols)
+            # the probe ctx must see the JOIN flow for KeyRemap preps, but
+            # KeyRemap evals its child against probe-side columns
+            pctx = self._ctx(pcols)
+            bk = [e.eval(bctx) for e in bkeys]
+            pk = [e.eval(pctx) for e in pkeys]
+            nb = blive.shape[0]
 
-        if cfg.bounds is not None:
-            bcode, bok = J.pack_bounded_codes(bk, cfg.bounds)
-            pcode, pok = J.pack_bounded_codes(pk, cfg.bounds)
-            bok = bok & blive
-            pok = pok & plive
-            if cfg.mode == "unique":
-                match_idx, matched, unique = J.lut_probe_unique(
-                    bcode, bok, cfg.domain, pcode, pok)
+            if cfg.bounds is not None:
+                bcode, bok = J.pack_bounded_codes(bk, cfg.bounds)
+                pcode, pok = J.pack_bounded_codes(pk, cfg.bounds)
+                bok = bok & blive
+                pok = pok & plive
+                if cfg.mode == "unique":
+                    match_idx, matched, unique = J.lut_probe_unique(
+                        bcode, bok, cfg.domain, pcode, pok)
+                else:
+                    start, count, order = J.lut_probe_multi(
+                        bcode, bok, cfg.domain, pcode, pok)
             else:
-                start, count, order = J.lut_probe_multi(
-                    bcode, bok, cfg.domain, pcode, pok)
-        else:
-            # shared exact code space: factorize over build++probe concat
-            both = [(jnp.concatenate([jnp.asarray(bv), jnp.asarray(pv)]),
-                     jnp.concatenate([jnp.asarray(bm), jnp.asarray(pm)]))
-                    for (bv, bm), (pv, pm) in zip(bk, pk)]
-            both_live = jnp.concatenate([blive, plive])
-            codes, cvalid = J.combine_keys(both, both_live)
-            if cfg.mode == "unique":
-                match_idx, matched, unique = J.sorted_probe_unique(
-                    codes[:nb], cvalid[:nb], blive,
-                    codes[nb:], cvalid[nb:], plive)
-            else:
-                start, count, order = J.sorted_probe_multi(
-                    codes[:nb], cvalid[:nb] & blive,
-                    codes[nb:], cvalid[nb:] & plive)
+                # shared exact code space: factorize over build++probe concat
+                both = [(jnp.concatenate([jnp.asarray(bv), jnp.asarray(pv)]),
+                         jnp.concatenate([jnp.asarray(bm), jnp.asarray(pm)]))
+                        for (bv, bm), (pv, pm) in zip(bk, pk)]
+                both_live = jnp.concatenate([blive, plive])
+                codes, cvalid = J.combine_keys(both, both_live)
+                if cfg.mode == "unique":
+                    match_idx, matched, unique = J.sorted_probe_unique(
+                        codes[:nb], cvalid[:nb], blive,
+                        codes[nb:], cvalid[nb:], plive)
+                else:
+                    start, count, order = J.sorted_probe_multi(
+                        codes[:nb], cvalid[:nb] & blive,
+                        codes[nb:], cvalid[nb:] & plive)
 
-        if cfg.mode == "unique":
-            self._join_unique_flags.append(unique)
-            self._join_totals.append(jnp.int64(0))
-            return self._finish_join_unique(node, bcols, pcols, plive,
-                                            match_idx, matched)
-        self._join_unique_flags.append(jnp.bool_(True))
-        return self._finish_join_expand(node, cfg, bcols, pcols, plive,
-                                        start, count, order)
+            if cfg.mode == "unique":
+                self._join_unique_flags.append(unique)
+                self._join_totals.append(jnp.int64(0))
+                return self._finish_join_unique(node, bcols, pcols, plive,
+                                                match_idx, matched)
+            self._join_unique_flags.append(jnp.bool_(True))
+            return self._finish_join_expand(node, cfg, bcols, pcols, plive,
+                                            start, count, order)
 
     def _emit_join_aligned(self, node: PhysHashJoin, cfg: JoinCfg,
                            scan_inputs, scan_rows):
@@ -951,10 +962,12 @@ class TreeProgram:
         joined = (list(pcols) + list(bcols) if node.build_right
                   else list(bcols) + list(pcols))
         if node.other_conditions:
+            from tidb_tpu.executor.device_emit import stage
             jctx = self._ctx(joined)
-            for cond in node.other_conditions:
-                v, m = cond.eval(jctx)
-                bmatched = bmatched & (v != 0) & m
+            with stage("join_probe"):
+                for cond in node.other_conditions:
+                    v, m = cond.eval(jctx)
+                    bmatched = bmatched & (v != 0) & m
         if node.kind == "semi":
             return list(pcols), plive & bmatched
         if node.kind == "anti":

@@ -31,6 +31,8 @@ configuration is applied before the first trace:
 
 from __future__ import annotations
 
+import functools
+import hashlib
 import os
 from pathlib import Path
 
@@ -58,6 +60,26 @@ def compile_cache_dir():
     return jax.config.jax_compilation_cache_dir
 
 
+def program_name(kind: str, sig: str) -> str:
+    """`<kind>_<sig8>`: what a jitted program is called in the profile's
+    "XLA Modules" line, in HLO `op_name`s and in `launch` spans — its kind
+    (partial_fused, merge, finalize, ...) and eight hex digits of its
+    compile-cache signature."""
+    return f"{kind}_{hashlib.sha1(sig.encode()).hexdigest()[:8]}"
+
+
+def named_jit(fn, name: str, **jit_kwargs):
+    """`jax.jit(fn)` under `name` instead of `fn.__name__`: JAX names the
+    XLA module after the function it is given (`jit__partial` for every
+    fragment program alike), so the function it is given is a thin wrapper
+    that carries the program's own name. Trace-time only."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kwargs)
+
+
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=check_vma)
@@ -80,4 +102,4 @@ def device_float_dtype():
 
 
 __all__ = ["jax", "jnp", "lax", "backend", "on_tpu", "device_float_dtype",
-           "shard_map", "compile_cache_dir"]
+           "shard_map", "compile_cache_dir", "named_jit", "program_name"]

@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Tuple
 
 from tidb_tpu.errors import TiDBTPUError
 from tidb_tpu.types import FieldType, TypeKind
+from tidb_tpu.util import timeline
 
 PROTOCOL_VERSION = 10
 SERVER_VERSION = b"8.0.11-tidb-tpu"
@@ -525,7 +526,11 @@ class _Conn:
         while True:
             self.seq = 0
             try:
-                pkt = self.read_packet()
+                # the server waits here for the client: device time idle
+                # under this span is the client's, not the server's
+                with timeline.span("client.wait", "client",
+                                   pid=self.conn_id):
+                    pkt = self.read_packet()
             except ConnectionError:
                 return
             if not pkt:
@@ -547,11 +552,11 @@ class _Conn:
                 elif cmd == COM_FIELD_LIST:
                     self.write_eof()
                 elif cmd == COM_QUERY:
-                    self._query(data.decode("utf-8", "replace"))
+                    self._request(self._query, data)
                 elif cmd == COM_STMT_PREPARE:
                     self._stmt_prepare(data.decode("utf-8", "replace"))
                 elif cmd == COM_STMT_EXECUTE:
-                    self._stmt_execute(data)
+                    self._request(self._stmt_execute, data)
                 elif cmd == COM_STMT_CLOSE:
                     self.stmts.pop(struct.unpack("<I", data[:4])[0], None)
                     # COM_STMT_CLOSE sends no response (protocol)
@@ -581,6 +586,17 @@ class _Conn:
             from tidb_tpu.util.guard import PROCESS_REGISTRY
             if PROCESS_REGISTRY.conn_killed(self.session.conn_id):
                 return
+
+    def _request(self, handler, data: bytes) -> None:
+        """One request, command received → last result byte written: mint
+        its id, keep it on the session while the request runs, and hold
+        the timeline's `stmt` root span around the handler."""
+        self.session._request_id = rid = timeline.new_request_id()
+        try:
+            with timeline.span("stmt", "stmt", pid=self.conn_id, req=rid):
+                handler(data)
+        finally:
+            self.session._request_id = 0
 
     # -- prepared statements (ref: server/conn_stmt.go) ----------------------
     def _stmt_prepare(self, sql: str) -> None:
@@ -633,23 +649,25 @@ class _Conn:
             self.write_err(1243, f"Unknown prepared statement handler "
                                  f"({sid}) given to EXECUTE", b"HY000")
             return
-        # flags (1) + iteration count (4)
-        i = 9
-        params: List[object] = []
-        if st.n_params:
-            params = decode_binary_params(data, i, st)
-        sql = substitute_placeholders(st.sql, params)
+        with timeline.span("wire.read", "wire"):
+            # flags (1) + iteration count (4)
+            i = 9
+            params: List[object] = []
+            if st.n_params:
+                params = decode_binary_params(data, i, st)
+            sql = substitute_placeholders(st.sql, params)
         # COM_STMT_EXECUTE admissions classify as interactive in the
         # priority scheduler regardless of statement shape
         results = self.session.execute(sql, from_prepared=True)
-        for k, rs in enumerate(results):
-            status = 0x0002 | (SERVER_MORE_RESULTS_EXISTS
-                               if k + 1 < len(results) else 0)
-            if rs.is_query:
-                self._write_binary_resultset(rs.names, rs.ftypes, rs.rows,
-                                             status)
-            else:
-                self.write_ok(affected=rs.affected_rows, status=status)
+        with timeline.span("wire.write", "wire"):
+            for k, rs in enumerate(results):
+                status = 0x0002 | (SERVER_MORE_RESULTS_EXISTS
+                                   if k + 1 < len(results) else 0)
+                if rs.is_query:
+                    self._write_binary_resultset(rs.names, rs.ftypes,
+                                                 rs.rows, status)
+                else:
+                    self.write_ok(affected=rs.affected_rows, status=status)
 
     def _write_binary_resultset(self, names: List[str],
                                 ftypes: List[FieldType],
@@ -674,22 +692,26 @@ class _Conn:
             self.write_packet(b"\x00" + bytes(bitmap) + body)
         self.write_eof(status)
 
-    def _query(self, sql: str) -> None:
+    def _query(self, data: bytes) -> None:
+        with timeline.span("wire.read", "wire"):
+            sql = data.decode("utf-8", "replace")
         results = self.session.execute(sql)
-        for i, rs in enumerate(results):
-            # non-final resultsets carry SERVER_MORE_RESULTS_EXISTS so
-            # drivers keep reading (multi-statement COM_QUERY)
-            status = 0x0002 | (SERVER_MORE_RESULTS_EXISTS
-                               if i + 1 < len(results) else 0)
-            if rs.is_query:
-                # pass rows=None when chunks exist: ResultSet.rows is a
-                # LAZY property and touching it would decode every row
-                self.write_resultset(
-                    rs.names, rs.ftypes,
-                    None if rs.chunks is not None else rs.rows,
-                    status, chunks=rs.chunks)
-            else:
-                self.write_ok(affected=rs.affected_rows, status=status)
+        with timeline.span("wire.write", "wire"):
+            for i, rs in enumerate(results):
+                # non-final resultsets carry SERVER_MORE_RESULTS_EXISTS so
+                # drivers keep reading (multi-statement COM_QUERY)
+                status = 0x0002 | (SERVER_MORE_RESULTS_EXISTS
+                                   if i + 1 < len(results) else 0)
+                if rs.is_query:
+                    # pass rows=None when chunks exist: ResultSet.rows is
+                    # a LAZY property and touching it would decode every
+                    # row
+                    self.write_resultset(
+                        rs.names, rs.ftypes,
+                        None if rs.chunks is not None else rs.rows,
+                        status, chunks=rs.chunks)
+                else:
+                    self.write_ok(affected=rs.affected_rows, status=status)
 
 
 def _text_value(v) -> bytes:

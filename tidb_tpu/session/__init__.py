@@ -35,6 +35,7 @@ from tidb_tpu.planner.builder import ExpressionRewriter, SubqueryEvaluator
 from tidb_tpu.planner.logical import Schema
 from tidb_tpu.storage import Store, Transaction
 from tidb_tpu.types import FieldType
+from tidb_tpu.util import timeline
 
 DEFAULT_VARS: Dict[str, object] = {
     # ref: sessionctx/variable/tidb_vars.go — the knobs our engine honors
@@ -59,9 +60,10 @@ DEFAULT_VARS: Dict[str, object] = {
     # scopes it to read-only SELECT): applies to EVERY statement — the
     # never-hang guarantee matters more here than MySQL fidelity
     "max_execution_time": 0,
-    # when non-empty, every session appends scheduler/compile/stream/
-    # eviction events into ONE Chrome-trace JSON under this directory
-    # (util/timeline.py) — load it in chrome://tracing or Perfetto
+    # when non-empty, every session records its spans, packet-in to last
+    # byte out, into ONE Chrome-trace JSON under this directory
+    # (util/timeline.py; written every 5 s and on stop) — load it in
+    # chrome://tracing or Perfetto
     "tidb_tpu_trace_dir": "",
     # priority-aware serving tier (executor/scheduler.py): classify each
     # admission as interactive/batch and grant the device slot by class;
@@ -577,6 +579,10 @@ class Session:
         # so KILL from any other session can find it
         self._guard = None
         self.last_guard = None     # kept after the stmt for introspection
+        # the request being served (util/timeline.py `req`): minted by the
+        # wire server's command loop, or by execute() when nothing above
+        # it did; 0 between requests
+        self._request_id = 0
         # (Level, Code, Message) rows of the last completed statement —
         # SHOW WARNINGS reads these; e.g. a degraded-mesh completion
         self.warnings: List[tuple] = []
@@ -590,19 +596,37 @@ class Session:
         slow-log entries and the processlist (ref: session.ExecuteStmt's
         observability hooks, session/session.go:1614). `from_prepared`
         marks a COM_STMT_EXECUTE dispatch (server/__init__.py) — those
-        admissions classify as interactive regardless of shape."""
+        admissions classify as interactive regardless of shape.
+
+        One call is one request on the timeline. The wire server mints the
+        request id and holds the `stmt` root span (command received → last
+        byte written); called directly, execute() is the root itself. The
+        statements of a multi-statement text share the id."""
+        if self._request_id:
+            return self._execute(sql, from_prepared)
+        self._request_id = rid = timeline.new_request_id()
+        try:
+            with timeline.span("stmt", "stmt", pid=self.conn_id, req=rid):
+                return self._execute(sql, from_prepared)
+        finally:
+            self._request_id = 0
+
+    def _execute(self, sql: str, from_prepared: bool) -> List[ResultSet]:
         import time as _time
 
         from tidb_tpu.errors import QueryInterrupted
         from tidb_tpu.parser import parse_with_text
         from tidb_tpu.util import phases as phases_mod
-        from tidb_tpu.util import timeline
         from tidb_tpu.util.guard import PROCESS_REGISTRY, ExecutionGuard
         from tidb_tpu.util.memory import Tracker
         from tidb_tpu.util.observability import REGISTRY
         out = []
-        for s, one in parse_with_text(sql):
+        with timeline.span("parse", "parse"):
+            stmts = parse_with_text(sql)
+        for s, one in stmts:
             kind = type(s).__name__
+            # on the request's root span: which statement this is
+            timeline.tag(sql=one[:80])
             self._current_sql = one
             self.last_engine = "cpu"
             if PROCESS_REGISTRY.conn_killed(self.conn_id):
@@ -618,7 +642,8 @@ class Session:
             quota = int(self.vars.get("tidb_mem_quota_query", 0) or 0)
             guard = ExecutionGuard(self.conn_id, one[:256],
                                    timeout_ms / 1000.0,
-                                   Tracker("query", quota))
+                                   Tracker("query", quota),
+                                   request_id=self._request_id)
             # admission classification for the priority-aware scheduler:
             # the class + cost hint ride the guard into every
             # device_slot() acquire of this statement
@@ -656,8 +681,7 @@ class Session:
                 self._guard = None
                 phases_mod.set_current(None)
                 PROCESS_REGISTRY.stmt_end(self.conn_id)
-                if timeline.ENABLED:
-                    timeline.flush(force=False)
+                timeline.flush_if_due()
             dt = _time.perf_counter() - t0
             if not (isinstance(s, ast.ShowStmt) and s.kind == "warnings"):
                 self.warnings = list(guard.warnings)
@@ -1107,7 +1131,9 @@ class Session:
                 self._plan_cache.move_to_end(key)
                 from tidb_tpu.util.observability import REGISTRY
                 REGISTRY.inc("tidb_tpu_plan_cache_hits_total")
+                timeline.tag(cache="hit")
                 return hit
+        timeline.tag(cache="miss" if key is not None else "uncacheable")
         before = self._subq_execs
         plan = optimize(stmt, self.engine.catalog.info_schema, ctx)
         if key is not None and self._subq_execs == before \
@@ -1259,7 +1285,6 @@ class Session:
         this statement and return the Chrome-trace JSON as one row."""
         from tidb_tpu.util.tracing import Tracer
         if getattr(stmt, "format", "row") == "chrome":
-            from tidb_tpu.util import timeline
             with timeline.capture() as cap:
                 self._execute_stmt(stmt.stmt)
             return ResultSet(["trace"], [T.varchar()],

@@ -51,7 +51,8 @@ class ExecutionGuard:
                  "sched_steals", "sched_migrated")
 
     def __init__(self, conn_id: int = 0, sql: str = "",
-                 timeout_s: float = 0.0, mem_tracker=None):
+                 timeout_s: float = 0.0, mem_tracker=None,
+                 request_id: int = 0):
         from tidb_tpu.util.escalation import EscalationStats
         from tidb_tpu.util.phases import PhaseTimer
         self.conn_id = conn_id
@@ -63,7 +64,7 @@ class ExecutionGuard:
         # seconds, h2d/d2h/scan bytes, compile count — every ExecContext
         # of this statement shares it, and record_stmt folds it into the
         # digest profile at statement end
-        self.phases = PhaseTimer(conn_id)
+        self.phases = PhaseTimer(conn_id, request_id)
         self.started = time.monotonic()
         self.deadline = (self.started + timeout_s
                          if timeout_s and timeout_s > 0 else None)

@@ -24,7 +24,10 @@ import re
 import threading
 import time
 from collections import OrderedDict, deque
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
+
+from tidb_tpu.util import timeline
 
 _BUCKETS = (0.001, 0.005, 0.02, 0.1, 0.5, 2.0, 10.0)
 
@@ -386,3 +389,27 @@ def normalize_sql(sql: str) -> str:
 
 
 REGISTRY = Registry()
+
+# the stages of a column's first touch, host encode to device upload
+FIRST_TOUCH_STAGES = ("materialize", "layout", "dict", "pack", "upload")
+
+
+@contextmanager
+def first_touch(stage: str, **tags):
+    """One stage of one column's (or column slab's) first touch: its
+    seconds go to the always-on counter
+    `tidb_tpu_first_touch_seconds_total{stage=...}` — first touch happens
+    once, usually before anyone has switched the timeline on — and, while
+    the timeline is on, to an `encode.<stage>` span (`upload` has its own
+    lane through PhaseTimer and gets the counter only). One addition per
+    column and slab; the warm path never comes here."""
+    t0 = time.perf_counter()
+    try:
+        if stage == "upload":
+            yield
+        else:
+            with timeline.span("encode." + stage, "encode", **tags):
+                yield
+    finally:
+        REGISTRY.inc("tidb_tpu_first_touch_seconds_total",
+                     {"stage": stage}, by=time.perf_counter() - t0)

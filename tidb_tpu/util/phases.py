@@ -10,7 +10,10 @@ and how much host work was actually hidden behind device activity:
   upload   time spent issuing jax.device_put / jnp.asarray transfers
            (async dispatch — the transfer itself overlaps);
   compute  time spent issuing jitted partial/merge calls plus the final
-           drain wait (block_until_ready) for the device to finish;
+           drain wait (block_until_ready) for the device to finish — on
+           the timeline the two are told apart: one `launch` span per
+           jitted call (`PhaseTimer.launch`), one `drain` span per wait
+           (`PhaseTimer.drain`), both in this one seconds ledger;
   fetch    device→host result transfers (jax.device_get round trips);
   decode   host-side dictionary decode / Chunk assembly.
 
@@ -55,6 +58,10 @@ def set_current(pt: Optional["PhaseTimer"]) -> None:
     compile/eviction sites reached from the statement's call stack can
     attribute to it without threading a context through every layer."""
     _tls.pt = pt
+    if pt is None:
+        timeline.bind()
+    else:
+        timeline.bind(pt.conn_id, pt.req)
 
 
 def current() -> Optional["PhaseTimer"]:
@@ -70,9 +77,9 @@ class PhaseTimer:
                  "specialization_hits", "conn_id",
                  "h2d_logical_bytes", "scan_logical_bytes",
                  "slabs_skipped", "h2d_skipped_bytes", "delta_rows",
-                 "_delta_seen", "device_index", "tables")
+                 "_delta_seen", "device_index", "tables", "req")
 
-    def __init__(self, conn_id: int = 0):
+    def __init__(self, conn_id: int = 0, req: int = 0):
         self.seconds: Dict[str, float] = {p: 0.0 for p in PHASES}
         self.overlapped_s = 0.0   # encode seconds with device work in flight
         self.wall_s = 0.0         # device-path wall (set by the executor)
@@ -101,6 +108,10 @@ class PhaseTimer:
         self.delta_rows = 0
         self._delta_seen = set()
         self.conn_id = conn_id    # timeline pid (0 = unattributed)
+        # the request this statement belongs to (timeline `req`): sites
+        # that reach the ledger through phases.current(), or from another
+        # thread, record under it
+        self.req = req
         # pod-scale attribution: the device index the statement is
         # pinned to (scheduler placement stamps it; compile caches,
         # metric labels and timeline lanes read it) and the table ids
@@ -110,26 +121,46 @@ class PhaseTimer:
         self.tables = set()
 
     @contextmanager
-    def phase(self, name: str, sig: Optional[str] = None):
-        """`sig` labels the timeline span (the fused pipeline's signature
-        digest on per-slab compute spans); the seconds ledger is keyed by
-        `name` alone."""
+    def phase(self, name: str, sig: Optional[str] = None,
+              lane: Optional[str] = None, span: Optional[str] = None,
+              **tags):
+        """The seconds ledger is keyed by `name` alone. The timeline span
+        is called `span` on `lane` (both default to `name`); `sig` (the
+        fused pipeline's signature digest on per-slab launches) and `tags`
+        label it."""
+        if sig:
+            tags["sig"] = sig
+        if self.device_index:
+            tags["dev"] = self.device_index
         t0 = time.perf_counter()
         try:
-            yield
+            with timeline.span(span or name, lane or name,
+                               pid=self.conn_id, req=self.req, **tags):
+                yield
         finally:
             dt = time.perf_counter() - t0
             self.seconds[name] = self.seconds.get(name, 0.0) + dt
             if name == "encode" and self._in_flight:
                 self.overlapped_s += dt
-            if timeline.ENABLED:
-                # per-device compute lanes: device 0 keeps the PR 5 lane
-                # name; sibling devices' dispatches render separately
-                lane = f"{name}@dev{self.device_index}" \
-                    if name == "compute" and self.device_index else name
-                timeline.record(lane, name, dur_us=dt * 1e6,
-                                pid=self.conn_id,
-                                args={"sig": sig} if sig else None)
+
+    def launch(self, program: str, slab: Optional[int] = None,
+               sig: Optional[str] = None):
+        """`compute` seconds spent issuing ONE jitted call: a `launch` span
+        named after the program (`<kind>_<sig8>`). The call returns when
+        the program is queued, not when it has run."""
+        return self.phase("compute", sig=sig, lane="launch", span=program,
+                          program=program, slab=slab)
+
+    def drain(self):
+        """`compute` seconds spent waiting for the device to finish what
+        was launched (`block_until_ready`): a `drain` span."""
+        return self.phase("compute", lane="drain", span="drain")
+
+    def glue(self):
+        """`compute` seconds spent issuing the eager device work between
+        launches (stacking slab partials, slicing what the next fetch
+        takes): a `frag.glue` span, fragment set-up on the timeline."""
+        return self.phase("compute", lane="frag", span="frag.glue")
 
     def mark_in_flight(self) -> None:
         """First slab's device work has been issued: later encode time is

@@ -1,37 +1,76 @@
-"""Cross-session Chrome-trace timeline (ref: util/tracecpu + the
-TopSQL collector; rendering targets chrome://tracing / Perfetto).
+"""Process-wide span recorder: one Chrome-trace timeline from packet-in to
+last byte out (ref: util/tracecpu + the TopSQL collector; rendering targets
+chrome://tracing / Perfetto).
 
-The TRACE statement's span tree (util/tracing.py) sees ONE statement on
-ONE thread.  What it cannot show is the interaction BETWEEN sessions —
-a statement queued behind a sibling's device dispatch, a single-flight
-compile another connection is waiting on, an eviction triggered by a
-different statement's budget check.  This module is the process-wide
-recorder for exactly those events: every thread appends into one shared
-buffer, and the flush writes ONE Chrome-trace JSON
-(`{"traceEvents": [...]}`) where
+ONE recording site, `span(name, lane, **args)`, serves three renderers:
+
+  * the cross-session Chrome JSON (`SET tidb_tpu_trace_dir = '/path'`:
+    <dir>/tidb_tpu_trace_<os-pid>.json, written every 5 s from the
+    statement path, on `flush()` and on `stop_global()` — NOT after every
+    statement; the stopped collector's events stay readable through
+    `last_events()` until the next `start_global`);
+  * `TRACE FORMAT='chrome' <stmt>`: a scoped collector for one statement,
+    returned as a result row (executor/trace.go's chrome format analog);
+  * the `jax.profiler` trace: while a collector is attached every span also
+    enters a `jax.profiler.TraceAnnotation` named `tidb_tpu/<lane>/<name>`
+    carrying `req` and `conn`, so a profile taken while the timeline is on
+    holds the program's spans on the profiler's own clock beside the
+    device lines, in one file. A request's root span also carries the
+    timeline's own `ts_us`, which ties the two clocks together.
+  `TRACE <stmt>`'s rows (util/tracing.py) go through the same sites:
+  `maybe_span` records here under the same names.
+
+An event is a Chrome "X" event:
 
   * pid  = connection id (one process lane per session),
-  * tid  = device stream (sched / compile / encode / upload / compute /
-           fetch / decode / cache), named via thread_name metadata,
+  * tid  = lane (`STREAMS`), named via thread_name metadata,
   * ts   = microseconds on one shared monotonic epoch, so cross-thread
-           ordering in the viewer is real ordering.
+           ordering in the viewer is real ordering,
+  * args = `req` (the request id: a process-wide counter minted where the
+           request enters — the server's command loop, or Session.execute
+           when no server is above it; the statements of one command share
+           it), `id` / `parent` (the enclosing span on the same thread),
+           `cause` (the request that did the work this one waited for: a
+           micro-batch leader's launch) and the site's own tags.
 
-Opt-in and zero-cost when off: recording sites check the module-level
-`ENABLED` bool (flipped only by `start_global` / `capture`), so the off
-path is one attribute load — the perf_smoke tier pins that no events
-accumulate when tracing is off.  Two activation paths share the buffer
-machinery:
+Lanes, from packet to packet:
 
-  * `SET tidb_tpu_trace_dir = '/path'` starts the process-global
-    collector; the session flushes it after every statement (throttled)
-    into  <dir>/tidb_tpu_trace_<os-pid>.json  — the cross-session file.
-  * `TRACE FORMAT='chrome' <stmt>` attaches a scoped collector for one
-    statement and returns the JSON as a result row (executor/trace.go's
-    chrome format analog).
+  client   server blocked in read_packet for the next command
+  stmt     root, one per request: command received -> last result byte
+  wire     wire.read (decode, placeholder substitution), wire.write (row
+           encoding and send)
+  parse    parse_with_text
+  plan     planner.optimize (cache=hit|miss), optimize.*, rule.*,
+           executor.build
+  exec     executor.run (the TRACE site)
+  frag     device.fragment (the TRACE site, spec=hit|miss): one per device
+           fragment; its SELF time is fragment set-up — signature /
+           specialization lookup, prune_slabs, argument assembly,
+           everything between launches — beside its children frag.open
+           (warm open_table), frag.program (program lookup) and frag.glue
+           (eager stacking of slab partials)
+  sched    admission queue waits and slot holds
+  compile  cold program builds (compile:<kind>), JAX's trace / lower /
+           backend-compile durations, waits for another request's build
+  encode   first touch: encode.materialize / .layout / .dict / .pack
+  upload   host -> device transfers
+  launch   one per jitted call, program=<kind>_<sig8>, slab=<i>
+  drain    block_until_ready waits
+  fetch    device -> host result transfers
+  decode   host-side dictionary decode / Chunk assembly
+  cache    evictions, delta extensions (instants)
+  gc       generation-2 garbage collections, while the global collector
+           is attached
+
+Opt-in and zero-cost when off: `span()` returns one shared no-op object
+when `ENABLED` is false (no event, no TraceAnnotation, no clock read), and
+`record()`/`instant()` return at once.
 """
 
 from __future__ import annotations
 
+import gc
+import itertools
 import json
 import os
 import threading
@@ -44,23 +83,36 @@ from typing import Dict, List, Optional
 # is a single module-attribute load.
 ENABLED = False
 
-_LOCK = threading.Lock()
+# re-entrant: a generation-2 collection can start (and its callback record)
+# while this thread copies the event list under the lock
+_LOCK = threading.RLock()
 _T0 = time.perf_counter()          # shared epoch for every thread's ts
 
-# device-stream lanes: stable small tids so the viewer groups events the
-# same way run over run; thread_name metadata labels them at flush
+# lanes: stable small tids so the viewer groups events the same way run
+# over run; thread_name metadata labels them at flush
 STREAMS = {"sched": 1, "compile": 2, "encode": 3, "upload": 4,
            "compute": 5, "fetch": 6, "decode": 7, "cache": 8,
            # staged-exchange per-rank stage lanes: partition (stage 1),
            # checkpoint (stage 2 device→host + host routing), probe
            # (stage 3 receive/probe/dedup)
-           "partition": 9, "checkpoint": 10, "probe": 11}
+           "partition": 9, "checkpoint": 10, "probe": 11,
+           # packet to packet (module docstring)
+           "client": 12, "stmt": 13, "wire": 14, "parse": 15, "plan": 16,
+           "exec": 17, "frag": 18, "launch": 19, "drain": 20, "gc": 21,
+           "write": 22}
+_OTHER_TID = 31
 
 _GLOBAL: Optional["_Collector"] = None     # tidb_tpu_trace_dir sink
 _GLOBAL_PATH: Optional[str] = None
+_LAST: List[dict] = []                     # the stopped global's events
 _SCOPED: List["_Collector"] = []           # TRACE FORMAT='chrome' sinks
-_LAST_FLUSH = 0.0
-_FLUSH_MIN_INTERVAL_S = 0.25
+_NEXT_FLUSH = 0.0                          # time.monotonic() of the next
+FLUSH_INTERVAL_S = 5.0                     # statement-path write
+
+_REQUEST_IDS = itertools.count(1)
+_SPAN_IDS = itertools.count(1)
+_tls = threading.local()
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, bound on first use
 
 
 class _Collector:
@@ -71,34 +123,53 @@ class _Collector:
         self.dirty = False
 
 
+# JAX's own duration events → `compile`-lane spans: a program is traced,
+# lowered and compiled inside its first launch, not where it is built
+_JAX_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+               "/jax/core/compile/jaxpr_to_mlir_module_duration":
+                   "jax.lower",
+               "/jax/core/compile/backend_compile_duration":
+                   "jax.backend_compile"}
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_kw) -> None:
+    name = _JAX_EVENTS.get(event) if ENABLED else None
+    if name is not None:
+        record(name, "compile", dur_us=duration_secs * 1e6)
+
+
 def _refresh_enabled() -> None:
-    global ENABLED
-    ENABLED = _GLOBAL is not None or bool(_SCOPED)
+    global ENABLED, _ANNOTATION
+    on = _GLOBAL is not None or bool(_SCOPED)
+    if on and _ANNOTATION is None:
+        # first attach of the process: bind what needs JAX (never at import)
+        from tidb_tpu.ops.jax_env import jax
+        _ANNOTATION = jax.profiler.TraceAnnotation
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+    ENABLED = on
 
 
 def now_us() -> float:
     return (time.perf_counter() - _T0) * 1e6
 
 
-def record(name: str, stream: str, dur_us: float = 0.0, pid: int = 0,
-           ts_us: Optional[float] = None, args: Optional[dict] = None,
-           ph: str = "X") -> None:
-    """Append one complete ("X") or instant ("i") event to every attached
-    collector.  `ts_us` is the START timestamp; when omitted the event is
-    assumed to END now (ts = now - dur)."""
-    if not ENABLED:
-        return
-    end = now_us()
-    ts = ts_us if ts_us is not None else max(end - dur_us, 0.0)
-    ev = {"name": name, "cat": stream, "ph": ph,
-          "ts": round(ts, 1), "pid": int(pid),
-          "tid": STREAMS.get(stream, 15)}
-    if ph == "X":
-        ev["dur"] = round(max(dur_us, 0.0), 1)
-    else:
-        ev["s"] = "g"
-    if args:
-        ev["args"] = args
+def bind(pid: int = 0, req: int = 0) -> None:
+    """This thread now runs statement work of connection `pid`, request
+    `req` (util/phases.set_current calls it; zeros unbind): what a span
+    with no enclosing span and no ids of its own records under — a
+    collector attached in mid-request (TRACE FORMAT='chrome') never saw
+    the request's root open."""
+    _tls.bound = (pid, req) if pid or req else None
+
+
+def new_request_id() -> int:
+    """Mint the id every span of one request carries (server command loop,
+    or Session.execute when no server is above it)."""
+    return next(_REQUEST_IDS)
+
+
+def _append(ev: dict) -> None:
     with _LOCK:
         if _GLOBAL is not None:
             _GLOBAL.events.append(ev)
@@ -107,9 +178,158 @@ def record(name: str, stream: str, dur_us: float = 0.0, pid: int = 0,
             c.events.append(ev)
 
 
+# ---- spans ----------------------------------------------------------------
+
+class _NoSpan:
+    """What `span()` hands out while nothing is attached."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    __slots__ = ("name", "lane", "pid", "req", "args", "id", "parent",
+                 "ts", "_ann")
+
+    def __init__(self, name, lane, pid, req, args):
+        self.name = name
+        self.lane = lane
+        self.pid = pid
+        self.req = req
+        self.args = args
+
+    def __enter__(self):
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        up = stack[-1] if stack else None
+        if up is not None:
+            # the enclosing span on this thread is the parent, and gives
+            # the request and connection where the site has neither
+            self.parent = up.id
+            if self.req is None:
+                self.req = up.req
+            if self.pid is None:
+                self.pid = up.pid
+        else:
+            self.parent = 0
+            bound = getattr(_tls, "bound", None)
+            if bound is not None:
+                if self.pid is None:
+                    self.pid = bound[0]
+                if self.req is None:
+                    self.req = bound[1]
+        self.id = next(_SPAN_IDS)
+        stack.append(self)
+        req, pid = self.req or 0, self.pid or 0
+        self.ts = now_us()
+        # a root also carries the timeline's clock into the profile
+        clock = {"ts_us": self.ts} if up is None else {}
+        self._ann = _ANNOTATION(f"tidb_tpu/{self.lane}/{self.name}",
+                                req=req, conn=pid, **clock)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        end = now_us()
+        stack = _tls.stack
+        if self in stack:
+            del stack[stack.index(self):]
+        args = self.args
+        args["req"] = self.req or 0
+        args["id"] = self.id
+        args["parent"] = self.parent
+        _append({"name": self.name, "cat": self.lane, "ph": "X",
+                 "ts": round(self.ts, 1), "dur": round(end - self.ts, 1),
+                 "pid": int(self.pid or 0),
+                 "tid": STREAMS.get(self.lane, _OTHER_TID), "args": args})
+        return False
+
+
+def span(name: str, lane: str, pid: Optional[int] = None,
+         req: Optional[int] = None, **args):
+    """Context manager around one piece of host work: THE recording site.
+    Off → one shared no-op object. On → an "X" event on `lane` with the
+    request id, its own id and its parent's (the enclosing span of this
+    thread, which also supplies `pid`/`req` where the site has neither),
+    and a `jax.profiler.TraceAnnotation` of the same extent."""
+    if not ENABLED:
+        return _NO_SPAN
+    return _Span(name, lane, pid, req, args)
+
+
+def tag(**args) -> None:
+    """Add tags to the innermost open span of this thread (a cache lookup
+    that turned out a hit, inside the span that covers the lookup)."""
+    if not ENABLED:
+        return
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        stack[-1].args.update(args)
+
+
+def record(name: str, stream: str, dur_us: float = 0.0, pid: int = 0,
+           ts_us: Optional[float] = None, args: Optional[dict] = None,
+           ph: str = "X") -> None:
+    """Append one complete ("X") or instant ("i") event whose duration
+    was measured elsewhere (a queue wait the scheduler timed, a compile).
+    `ts_us` is the START timestamp; when omitted the event is assumed to
+    END now (ts = now - dur). The enclosing span of this thread, if any,
+    gives the request id and the parent."""
+    if not ENABLED:
+        return
+    end = now_us()
+    ts = ts_us if ts_us is not None else max(end - dur_us, 0.0)
+    ev = {"name": name, "cat": stream, "ph": ph,
+          "ts": round(ts, 1), "pid": int(pid),
+          "tid": STREAMS.get(stream, _OTHER_TID)}
+    if ph == "X":
+        ev["dur"] = round(max(dur_us, 0.0), 1)
+    else:
+        ev["s"] = "g"
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        up = stack[-1]
+        args = dict(args or (), req=up.req or 0, parent=up.id)
+        if not pid:
+            ev["pid"] = int(up.pid or 0)
+    else:
+        bound = getattr(_tls, "bound", None)
+        if bound is not None:
+            args = dict(args or (), req=bound[1])
+            if not pid:
+                ev["pid"] = bound[0]
+    if args:
+        ev["args"] = args
+    _append(ev)
+
+
 def instant(name: str, stream: str, pid: int = 0,
             args: Optional[dict] = None) -> None:
     record(name, stream, pid=pid, ts_us=now_us(), args=args, ph="i")
+
+
+def _gc_event(phase: str, info: dict) -> None:
+    """gc.callbacks hook while the global collector is attached: one `gc`
+    span per generation-2 collection, on the thread that triggered it."""
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        _tls.gc_t0 = now_us()
+    else:
+        t0 = getattr(_tls, "gc_t0", None)
+        if t0 is not None:
+            _tls.gc_t0 = None
+            record("gc.gen2", "gc", dur_us=now_us() - t0, ts_us=t0,
+                   args={"collected": info.get("collected", 0)})
 
 
 # ---- global (tidb_tpu_trace_dir) collector --------------------------------
@@ -117,10 +337,14 @@ def instant(name: str, stream: str, pid: int = 0,
 def start_global(trace_dir: str) -> str:
     """Idempotently attach the process-global collector writing to
     <trace_dir>/tidb_tpu_trace_<pid>.json.  → the file path."""
-    global _GLOBAL, _GLOBAL_PATH
+    global _GLOBAL, _GLOBAL_PATH, _NEXT_FLUSH
     with _LOCK:
         if _GLOBAL is None:
             _GLOBAL = _Collector()
+            _LAST.clear()
+            _NEXT_FLUSH = time.monotonic() + FLUSH_INTERVAL_S
+            if _gc_event not in gc.callbacks:
+                gc.callbacks.append(_gc_event)
         _GLOBAL_PATH = os.path.join(
             str(trace_dir), f"tidb_tpu_trace_{os.getpid()}.json")
     _refresh_enabled()
@@ -131,8 +355,12 @@ def stop_global() -> None:
     global _GLOBAL, _GLOBAL_PATH
     flush()
     with _LOCK:
+        if _GLOBAL is not None:
+            _LAST[:] = _GLOBAL.events
         _GLOBAL = None
         _GLOBAL_PATH = None
+        if _gc_event in gc.callbacks:
+            gc.callbacks.remove(_gc_event)
     _refresh_enabled()
 
 
@@ -140,18 +368,30 @@ def global_path() -> Optional[str]:
     return _GLOBAL_PATH
 
 
-def flush(force: bool = True) -> Optional[str]:
+def last_events() -> List[dict]:
+    """The global collector's events: the running one's so far, else the
+    last stopped one's (kept until the next `start_global`)."""
+    with _LOCK:
+        return list(_GLOBAL.events if _GLOBAL is not None else _LAST)
+
+
+def flush_if_due() -> None:
+    """The statement path's call: write the file at most once every
+    FLUSH_INTERVAL_S. Between writes it costs one clock read."""
+    global _NEXT_FLUSH
+    if _GLOBAL is None or time.monotonic() < _NEXT_FLUSH:
+        return
+    _NEXT_FLUSH = time.monotonic() + FLUSH_INTERVAL_S
+    flush()
+
+
+def flush() -> Optional[str]:
     """Write the global collector's events to its JSON file (atomic
-    tmp+rename).  force=False throttles to one write per
-    _FLUSH_MIN_INTERVAL_S — the per-statement flush path."""
-    global _LAST_FLUSH
+    tmp+rename).  → the path, or None when nothing is attached or the
+    write failed."""
     with _LOCK:
         if _GLOBAL is None or _GLOBAL_PATH is None or not _GLOBAL.dirty:
             return _GLOBAL_PATH
-        now = time.monotonic()
-        if not force and now - _LAST_FLUSH < _FLUSH_MIN_INTERVAL_S:
-            return _GLOBAL_PATH
-        _LAST_FLUSH = now
         events = list(_GLOBAL.events)
         _GLOBAL.dirty = False
         path = _GLOBAL_PATH
@@ -207,6 +447,7 @@ def render(events: List[dict]) -> str:
                        "displayTimeUnit": "ms"})
 
 
-__all__ = ["ENABLED", "STREAMS", "record", "instant", "start_global",
-           "stop_global", "global_path", "flush", "capture", "render",
-           "now_us"]
+__all__ = ["ENABLED", "STREAMS", "FLUSH_INTERVAL_S", "span", "tag", "bind",
+           "record", "instant", "new_request_id", "start_global",
+           "stop_global", "global_path", "last_events", "flush",
+           "flush_if_due", "capture", "render", "now_us"]

@@ -7,13 +7,21 @@ compile spans session.go:1615) and renders them with `TRACE SELECT …`
 zero-dependency span tree with microsecond offsets, attached to the
 session only while a TRACE statement runs (no overhead otherwise), plus
 the optimizer-trace hook (util/tracing/opt_trace.go analog) that records
-which rewrite rules fired."""
+which rewrite rules fired.
+
+`maybe_span` is also a recording site of the process-wide timeline
+(util/timeline.py): the boundaries that exist for TRACE — planner.optimize,
+optimize.logical/physical, rule.*, executor.build, executor.run,
+device.fragment — record there under the same names whenever the timeline
+is on, TRACE or not, and TRACE's own rows do not change."""
 
 from __future__ import annotations
 
 import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
+
+from tidb_tpu.util import timeline
 
 
 class Span:
@@ -77,10 +85,22 @@ class Tracer:
         return out
 
 
+def _lane(name: str) -> str:
+    """The timeline lane of a TRACE site: planning and executor build are
+    `plan`, one device fragment's whole run is `frag` (its self time is
+    fragment set-up), running the executor tree is `exec`."""
+    if name == "device.fragment":
+        return "frag"
+    head = name.split(".", 1)[0]
+    return "plan" if head in ("planner", "optimize", "rule") \
+        or name == "executor.build" else "exec"
+
+
 @contextmanager
 def maybe_span(tracer: Optional[Tracer], name: str, **tags):
-    if tracer is None:
-        yield None
-    else:
-        with tracer.span(name, **tags) as s:
-            yield s
+    with timeline.span(name, _lane(name), **tags):
+        if tracer is None:
+            yield None
+        else:
+            with tracer.span(name, **tags) as s:
+                yield s
