@@ -137,6 +137,14 @@ def test_stats_feed_group_cap(session):
     cap = _initial_group_cap(agg, 1 << 16, 1 << 23)
     assert cap == 1024           # small reliable estimate → floor
 
+    # no GROUP BY: one group, whatever the estimate or the default say
+    p = _plan(session, "SELECT COUNT(*), SUM(a) FROM st WHERE b > 5")
+    agg = _find(p, "PhysHashAgg")
+    assert not agg.group_exprs
+    assert _initial_group_cap(agg, 1 << 16, 1 << 23) == 1
+    agg.est_reliable = False
+    assert _initial_group_cap(agg, 1 << 16, 1 << 23) == 1
+
 
 def _wait_stats(eng, tid, pred=lambda st: True, timeout=5.0):
     import time as _t

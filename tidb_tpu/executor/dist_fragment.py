@@ -360,7 +360,7 @@ class StagedDistAgg:
         skips ng==0 passes)."""
         from tidb_tpu.executor.fragment import (FragmentFallback,
                                                 _GroupCapOverflow,
-                                                get_program)
+                                                _note_grouping, get_program)
         ckpts: List[Optional[dict]] = [None] * self.nd
         ng_true = [0] * self.nd
         caps_ran = [0] * self.nd
@@ -371,6 +371,7 @@ class StagedDistAgg:
             # between dispatch rounds is a guard checkpoint: a killed
             # query must not queue another per-rank compile
             self.ctx.check_killed("device-dispatch")
+            self.grouping = _note_grouping(self.root, None, self.group_cap)
             prog = get_program(self.chain, self.used_cols, self.in_types,
                                self.slab_cap, self.group_cap,
                                layouts=self.layouts or None)
@@ -446,7 +447,8 @@ class StagedDistAgg:
     def _attempt(self, r: int, dev, prog, prep_vals, site: str):
         """Upload rank r's host slice onto `dev`, run the partial there,
         fetch its checkpoint → ({"ng", "keys", "states"}, true_count)."""
-        from tidb_tpu.executor.fragment import _tree_delete
+        from tidb_tpu.executor.fragment import (_count_agg_partial,
+                                                _tree_delete)
         from tidb_tpu.ops.jax_env import jax, jnp
         from tidb_tpu.util import failpoint
         ph = self.ctx.phases
@@ -479,6 +481,7 @@ class StagedDistAgg:
                                        prep_vals)
             ph.note_launch()
             ph.note_fused()   # per-rank chain partial = fused local stage
+            _count_agg_partial(self.grouping)
             with ph.drain():
                 # drain outside the scheduler slot (GIL-released wait):
                 # sibling statements dispatch while this rank executes
@@ -1098,7 +1101,8 @@ class StagedDistExchange:
 
     def _attempt_stage3(self, r: int, dev, prog, prep_vals, site: str):
         from tidb_tpu.chunk import compress as _compress
-        from tidb_tpu.executor.fragment import _tree_delete
+        from tidb_tpu.executor.fragment import (_count_agg_partial,
+                                                _note_grouping, _tree_delete)
         from tidb_tpu.ops.jax_env import jax, jnp
         from tidb_tpu.util import failpoint, timeline
         from tidb_tpu.util.phases import tree_nbytes
@@ -1132,6 +1136,9 @@ class StagedDistExchange:
                         out = prog(dcols, rows, prep_vals)
                 ph.note_launch()
                 ph.note_fused()
+                if isinstance(root, PhysHashAgg):
+                    # the tag lands on this rank's `probe` span
+                    _count_agg_partial(_note_grouping(root, None, self.gcap))
                 with ph.drain():
                     jax.block_until_ready(out)
                 failpoint.inject("shard-checkpoint-write")

@@ -1076,12 +1076,36 @@ def _spec_note(ph, hit: bool) -> None:
                      {"engine": "device"})
 
 
+def _note_grouping(root: PhysHashAgg, key_bounds, group_cap: int) -> str:
+    """Tag the open `device.fragment` span with how this aggregate's
+    partials assign rows to slots and into how many: "global" (no GROUP
+    BY, one slot), "bounds" (a packed code over known key domains) or
+    "factorize" (sort-based). → the grouping, the label of
+    `tidb_tpu_agg_partials_total`."""
+    grouping = ("global" if not root.group_exprs else
+                "bounds" if key_bounds is not None else "factorize")
+    timeline.tag(grouping=grouping, gcap=int(group_cap))
+    return grouping
+
+
+def _count_agg_partial(grouping: str) -> None:
+    """One program holding an aggregate's partial was launched."""
+    from tidb_tpu.util.observability import REGISTRY
+    REGISTRY.inc("tidb_tpu_agg_partials_total", {"grouping": grouping})
+
+
 def _initial_group_cap(root: PhysHashAgg, default_cap: int,
                        max_cap: int) -> int:
     """Stats-informed factorize capacity: when the planner's group estimate
     came from real NDV stats (est_reliable, planner/physical.estimate), a
     1.5× headroom start avoids the overflow→retry recompile ladder both for
-    high-cardinality keys (e.g. GROUP BY orderkey) and tiny ones."""
+    high-cardinality keys (e.g. GROUP BY orderkey) and tiny ones.
+
+    An aggregate with no GROUP BY has exactly one group whatever the
+    estimate or `tidb_tpu_group_cap` say: one slot, so its partial states
+    are plain masked reductions (ops/segment.py) and nothing can overflow."""
+    if not root.group_exprs:
+        return 1
     if not getattr(root, "est_reliable", False):
         return default_cap
     from tidb_tpu.executor.device_cache import _pow2
@@ -1896,6 +1920,8 @@ class TpuFragmentExec:
                     out = prog(scan_inputs, scan_rows, prep_vals,
                                aligned_inputs)
             ph.note_launch()
+            if is_agg:
+                _count_agg_partial(_note_grouping(root, akb, gcap))
             fetch = {"ju": out["join_unique"], "jt": out["join_totals"]}
             host = None
             if is_agg:
@@ -2139,6 +2165,7 @@ class TpuFragmentExec:
         to_run: Optional[List[int]] = None     # None = cold first pass
         n_joins = len(walk_joins)
         while True:
+            grouping = _note_grouping(root, akb, gcap)
             with timeline.span("frag.program", "frag"):
                 prog, pipe_sig = get_pipeline_program(
                     root, pipe_caps, gcap, join_cfgs, akb, scan_layouts,
@@ -2157,6 +2184,7 @@ class TpuFragmentExec:
                         partials[s] = prog(si, sr, prep_vals, ai)
                 ph.note_launch()
                 ph.note_fused()
+                _count_agg_partial(grouping)
                 caps_ran[s] = gcap
                 pcaps[s] = pair_cap
                 pairs_cache[s] = None
@@ -2458,6 +2486,7 @@ class TpuFragmentExec:
                         out = prog(scan_inputs, scan_rows, prep_vals,
                                    aligned_inputs, rng)
                 self.ctx.phases.note_launch()
+                _count_agg_partial(_note_grouping(root, akb, gcap))
                 # flags first: a restart/overflow pass never transfers its
                 # (discarded) group arrays, and good passes transfer only
                 # ng live slots instead of the full gcap padding
@@ -2961,6 +2990,8 @@ class TpuFragmentExec:
                     with ph.launch(prog.name):
                         raw = prog(scan_inputs, scan_rows, prep_vals)
                 ph.note_launch()
+                if is_agg:
+                    _count_agg_partial(_note_grouping(root, None, gcap))
                 with ph.drain():
                     jax.block_until_ready(raw)
                 with ph.phase("fetch"):
@@ -3189,6 +3220,7 @@ class TpuFragmentExec:
             return p if pod_pin is None else jax.device_put(p, pod_pin)
 
         while True:
+            grouping = _note_grouping(root, key_bounds, group_cap)
             if spec_sig is not None:
                 psig, spec_sig = spec_sig, None
             else:
@@ -3213,6 +3245,7 @@ class TpuFragmentExec:
                                 cols, jnp.int32(n), prep_vals))
                     ph.note_launch()
                     ph.note_fused()   # a chain partial IS a fused pipeline
+                    _count_agg_partial(grouping)
                     caps[s] = group_cap
                     pcaps[s] = pair_cap
             else:
@@ -3226,6 +3259,7 @@ class TpuFragmentExec:
                                 cols, jnp.int32(n), prep_vals))
                     ph.note_launch()
                     ph.note_fused()
+                    _count_agg_partial(grouping)
                     caps[s] = group_cap
                     pcaps[s] = pair_cap
                     pairs_cache[s] = None

@@ -18,7 +18,11 @@ import numpy as np
 # Below this cap, grouped reductions use a masked broadcast-reduce instead of
 # a scatter: TPU scatter serializes updates (~70ms for 1M int64 rows on v4),
 # while `reduce(where(gid == iota_c, v, id))` stays a fused vector reduction
-# (~8ms at cap 16, ~14ms at cap 1024; measured on the target chip). Exact for
+# (~8ms at cap 16, ~14ms at cap 1024 for 1M rows on v4). The cost grows with
+# rows × cap: a v5e reads ≈ 18 ms per int64 state over an 8M-row slab at cap
+# 1024, blocked (PERF.md §6, PR 27), which is why an aggregate with no GROUP
+# BY asks for ONE segment (fragment._initial_group_cap) and reduces flat, with
+# no slot axis at all (≈ 1 ms a state). Exact for
 # int64 — no float round trip. The broadcast materializes n×cap values, so
 # beyond a materialization budget the reduction runs BLOCKED: lax.map over
 # row blocks, each block broadcast-reduced into (cap,) partials, partials
@@ -30,8 +34,9 @@ MASKED_REDUCE_WORK = 1 << 27
 
 
 def _masked_ok(data, num_segments: int) -> bool:
-    return (num_segments <= MASKED_REDUCE_CAP and
-            int(data.shape[0]) * num_segments <= MASKED_REDUCE_WORK)
+    return num_segments == 1 or (
+        num_segments <= MASKED_REDUCE_CAP and
+        int(data.shape[0]) * num_segments <= MASKED_REDUCE_WORK)
 
 
 def _is_np(xp) -> bool:
@@ -39,6 +44,12 @@ def _is_np(xp) -> bool:
 
 
 def _masked_reduce(xp, data, segment_ids, num_segments, identity, reducer):
+    if num_segments == 1:
+        # one segment: a plain reduction over the rows whose id is 0 — no
+        # (n, 1) slot axis for the compiler to lay out. Out-of-range ids
+        # (dead rows) still drop.
+        ident = xp.asarray(identity, dtype=data.dtype)
+        return reducer(xp.where(segment_ids == 0, data, ident))[None]
     iota = xp.arange(num_segments, dtype=segment_ids.dtype)
     m = segment_ids[:, None] == iota[None, :]
     ident = xp.asarray(identity, dtype=data.dtype)

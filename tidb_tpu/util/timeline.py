@@ -43,8 +43,9 @@ Lanes, from packet to packet:
   plan     planner.optimize (cache=hit|miss), optimize.*, rule.*,
            executor.build
   exec     executor.run (the TRACE site)
-  frag     device.fragment (the TRACE site, spec=hit|miss): one per device
-           fragment; its SELF time is fragment set-up — signature /
+  frag     device.fragment (the TRACE site, spec=hit|miss; over an
+           aggregate grouping=global|bounds|factorize, gcap=<slots>): one
+           per device fragment; its SELF time is fragment set-up — signature /
            specialization lookup, prune_slabs, argument assembly,
            everything between launches — beside its children frag.open
            (warm open_table), frag.program (program lookup) and frag.glue

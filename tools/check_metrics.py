@@ -20,7 +20,7 @@ import sys
 
 UNIT_SUFFIXES = ("_total", "_seconds", "_bytes")
 LABEL_VOCAB = {"stmt", "engine", "table", "site", "device", "phase",
-               "stage", "reason", "class", "le"}
+               "stage", "reason", "class", "le", "grouping"}
 PREFIX = "tidb_tpu_"
 
 
