@@ -55,6 +55,11 @@ def pod(monkeypatch):
     # (tests/test_pod_serving.py does), so it is parked out of reach here.
     from tidb_tpu.executor import scheduler
     monkeypatch.setattr(scheduler, "STEAL_PATIENCE_S", 3600.0)
+    # the pool is a process singleton: a health record left by whatever
+    # file this worker ran before (the schedule moves with every file the
+    # suite gains) must not decide `test_fault_free_pod_stays_on_fast_path`
+    with POOL.health._lock:
+        POOL.health._rec.clear()
     eng = Engine()
     eng.global_vars["tidb_enable_auto_analyze"] = False
     s = eng.new_session()

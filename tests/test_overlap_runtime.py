@@ -174,6 +174,13 @@ def _resumable_engine(per_slab_distinct, stride=5_000_000):
     return s, oracle
 
 
+# a COMPUTED key has no bounds to pack into a sort word, so these
+# statements keep the per-slab sort-factorize whose resumable ladder they
+# test (a bare bounded key with a domain this wide groups by sorted runs,
+# which has no per-slab capacity to overflow — tests/test_large_groups.py)
+RESUMABLE_SQL = "SELECT k + 0, SUM(v) FROM r GROUP BY k + 0"
+
+
 def _check_oracle(rows, oracle):
     got = {int(k): int(v) for k, v in rows}
     assert got == dict(oracle)
@@ -183,7 +190,7 @@ def test_group_overflow_reruns_only_overflowed_slabs():
     # slab 1 overflows the 64-group cap (200 distinct); slabs 0/2 do not:
     # the retry must re-execute exactly one slab and reuse two partials
     s, oracle = _resumable_engine((10, 200, 10))
-    res = s.query("SELECT k, SUM(v) FROM r GROUP BY k")
+    res = s.query(RESUMABLE_SQL)
     _check_oracle(res.rows, oracle)
     esc = s.last_guard.escalation
     assert esc.slabs_rerun == 1, esc.summary()
@@ -198,7 +205,7 @@ def test_merged_count_overflow_reruns_zero_slabs():
     # not: the retry reuses every checkpointed partial and only re-merges
     s, oracle = _resumable_engine((60, 60, 60), stride=5_000_000)
     # disjoint key ranges per slab: 60 × 3 = 180 merged groups
-    res = s.query("SELECT k, SUM(v) FROM r GROUP BY k")
+    res = s.query(RESUMABLE_SQL)
     _check_oracle(res.rows, oracle)
     esc = s.last_guard.escalation
     assert esc.slabs_rerun == 0, esc.summary()

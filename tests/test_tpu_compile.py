@@ -318,6 +318,62 @@ def test_finalize_and_merge_compile_over_sf10_partials(recorded, one_chip,
     _fits(stats)
 
 
+def test_sorted_runs_grouping_lowers_for_the_chip_with_two_sorts(one_chip):
+    """Grouping by sorted runs at the real geometry — six slabs of 8M rows,
+    millions of groups. The shared sort program holds exactly TWO sorts
+    (the rows by their packed key word with the aggregate's argument as
+    payload; the run ends, one uint32 operand) and no loop; it is only
+    LOWERED here, because the TPU compiler takes two to three minutes over
+    the int64 comparator (PERF.md §6, PR 28). A statement's finalize over
+    the sorted rows — states by blocked cumsums, keys unpacked, top-10 by
+    selection — holds NO sort and is compiled: its one loop is the
+    selection's."""
+    import re
+
+    from tidb_tpu.ops import factorize as F
+    from tidb_tpu.ops import jax_env
+    from tidb_tpu.ops.segment import SortedRuns
+    jax, jnp = jax_env.jax, jax_env.jnp
+    n, cap = 6 * (1 << 23), 1 << 21
+    bounds = [(0, 12_002_429), (8036, 10589)]
+
+    def arr(rows, dtype):
+        return jax.ShapeDtypeStruct((rows,), dtype, sharding=one_chip)
+
+    def sort_rows(k1, k2, live, v, m):
+        return F.sort_rows(F.pack_words([(k1, live), (k2, live)], bounds),
+                           live, [v, m])
+
+    text = jax.jit(sort_rows).lower(
+        arr(n, jnp.int64), arr(n, jnp.int32), arr(n, jnp.bool_),
+        arr(n, jnp.int64), arr(n, jnp.bool_)).as_text()
+    assert len(re.findall(r"stablehlo\.sort", text)) == 2, text[:2000]
+    assert "stablehlo.while" not in text
+
+    def finalize(word, v, m, ends, n_runs):
+        runs = SortedRuns(ends, n_runs, cap)
+        v = jnp.where(m, v, 0)
+        mask = (1 << 30) - 1
+        limbs = [runs.sum(v & mask), runs.sum((v >> 30) & mask),
+                 runs.sum(v >> 60)]
+        seen = runs.sum(m) > 0
+        keys = F.unpack_words([runs.at_ends(word)], bounds,
+                              [jnp.int64, jnp.int32])
+        idx, n_out = F.topn_select(
+            [(limbs[2], seen), (limbs[1], seen), keys[1], keys[0]],
+            [True, True, False, False], runs.slot_live, 10)
+        return [k[idx] for k, _ in keys], [a[idx] for a in limbs], n_out
+
+    compiled = jax.jit(finalize).lower(
+        arr(n, jnp.int64), arr(n, jnp.int64), arr(n, jnp.bool_),
+        arr(n, jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bsort\(", text)
+    assert len(re.findall(r"\bwhile\(", text)) == 1
+    _fits([("sorted-runs finalize", compiled.memory_analysis())])
+
+
 def test_shard_map_aggregate_step_compiles_for_four_chips(topo, monkeypatch):
     """The distributed Q3-shaped step (filter → all_to_all exchange of
     both sides → per-shard sort-probe join → two-phase aggregate) on a

@@ -172,6 +172,34 @@ def emit_root(ctx: EvalContext, live, root, aggs=None, group_cap: int = 0,
                      for v, m in out_cols], "live": live}
 
 
+def partials_of(partials):
+    """The per-slab partial outputs as the merge programs take them —
+    (key_cols, states, slot_live), each leaf a LIST with one array per
+    partial, which `stack_partials` concatenates inside the trace. No
+    device work here: the stacking used to be one eager concatenate per
+    array on the host's clock (27 ms a statement over six slabs)."""
+    p0 = partials[0]
+    key_cols = [tuple([p["keys"][kc][f] for p in partials]
+                      for f in range(2))
+                for kc in range(len(p0["keys"]))]
+    states = [tuple([p["states"][ai][f] for p in partials]
+                    for f in range(len(p0["states"][ai])))
+              for ai in range(len(p0["states"]))]
+    return key_cols, states, [p["slot_live"] for p in partials]
+
+
+@_staged("merge")
+def stack_partials(key_cols, states, slot_live):
+    """`partials_of`'s lists → one stacked partial (traced). Already
+    stacked arrays pass through."""
+    from tidb_tpu.ops.jax_env import jnp
+    if not isinstance(slot_live, (list, tuple)):
+        return key_cols, states, slot_live
+    cat = jnp.concatenate
+    return ([(cat(v), cat(m)) for v, m in key_cols],
+            [tuple(cat(f) for f in st) for st in states], cat(slot_live))
+
+
 @_staged("merge")
 def emit_merge(root, aggs: List[AggFunc], group_cap: int, key_cols,
                states, slot_live):
@@ -183,6 +211,7 @@ def emit_merge(root, aggs: List[AggFunc], group_cap: int, key_cols,
     program's merge and the fused pipeline's root-merge program."""
     from tidb_tpu.ops.jax_env import jnp
     from tidb_tpu.ops import factorize as F
+    key_cols, states, slot_live = stack_partials(key_cols, states, slot_live)
     cap = group_cap
     if root.group_exprs:
         gids, n_final, rep = F.factorize(key_cols, slot_live, cap)
@@ -205,15 +234,32 @@ def emit_merge(root, aggs: List[AggFunc], group_cap: int, key_cols,
     return {"keys": key_out, "states": out_states, "n_groups": n_final}
 
 
+def _order_keys(order_root, aggs, nk: int, keys, states, live):
+    """The order root's sort keys over an aggregate's output slots →
+    (keys [(values, valid)], their directions): a group key is its slot
+    column, an aggregate its `AggFunc.order_keys` (one or more columns,
+    all in the aggregate's direction)."""
+    from tidb_tpu.ops.jax_env import jnp
+    okeys, descs = [], []
+    for e, desc in zip(order_root.by, order_root.descs):
+        cols = [keys[e.index]] if e.index < nk else \
+            aggs[e.index - nk].order_keys(jnp, tuple(states[e.index - nk]))
+        for v, m in cols:
+            okeys.append((jnp.asarray(v), jnp.asarray(m) & live))
+            descs.append(desc)
+    return okeys, descs
+
+
 @_staged("finalize")
 def emit_finalize(root, order_root, aggs: List[AggFunc], group_cap: int,
                   key_cols, states, slot_live):
     """Fused finalize: agg merge → finalize expressions → root ORDER BY /
     TopN as ONE trace, so a warm analytic query is `slabs + 1` programs
     total. Order keys referencing group keys read the merged key slots;
-    keys referencing aggregate outputs evaluate AggFunc.final IN-TRACE
-    (the fragment gate only admits count/sum/avg/min/max over narrow
-    results — wide-decimal finals are host-only). The sort/TopN runs on
+    keys referencing aggregate outputs evaluate AggFunc.order_keys
+    IN-TRACE (the fragment gate admits count/sum/avg/min/max over narrow
+    results and SUM's limb planes — other wide-decimal finals are
+    host-only). The sort/TopN runs on
     the rank encoding of emit_sort/emit_topk, so direction + MySQL NULL
     ordering match executor/sort.py exactly.
 
@@ -226,19 +272,13 @@ def emit_finalize(root, order_root, aggs: List[AggFunc], group_cap: int,
     cap = group_cap
     live = jnp.arange(cap, dtype=jnp.int32) < merged["n_groups"]
     nk = len(root.group_exprs)
-    okeys = []
-    for e in order_root.by:
-        if e.index < nk:
-            v, m = merged["keys"][e.index]
-        else:
-            v, m = aggs[e.index - nk].final(
-                jnp, tuple(merged["states"][e.index - nk]))
-        okeys.append((jnp.asarray(v), jnp.asarray(m) & live))
+    okeys, descs = _order_keys(order_root, aggs, nk, merged["keys"],
+                               merged["states"], live)
     if isinstance(order_root, PhysTopN):
         k = min(order_root.count + order_root.offset, cap)
-        idx, n_out = emit_topk(okeys, order_root.descs, live, k)
+        idx, n_out = emit_topk(okeys, descs, live, k)
     else:
-        idx, n_out = emit_sort(okeys, order_root.descs, live)
+        idx, n_out = emit_sort(okeys, descs, live)
     keys_o = [(jnp.asarray(v)[idx], jnp.asarray(m)[idx])
               for v, m in merged["keys"]]
     states_o = [tuple(jnp.asarray(a)[idx] for a in st)
@@ -252,9 +292,10 @@ def emit_agg(ctx: EvalContext, live, root, aggs: List[AggFunc],
              group_cap: int, key_bounds=None, pairs_out: bool = False,
              pair_cap: int = 0):
     """Grouped-aggregation partial over one batch → {keys, states,
-    n_groups, slot_live}. With `key_bounds` (per-group-key (lo, hi)
-    domains) grouping is a direct packed code + segment ops — no sort
-    (the perfect-hash path); otherwise sort-based factorize.
+    n_groups, slot_live}. `key_bounds` (ops/factorize.KeyBounds) names
+    the lowering: SLOTS — a direct packed code + segment ops, no sort
+    (the perfect-hash path); RUNS — this slab only hands out its rows
+    (`group_rows`); None — sort-based factorize.
 
     With `pairs_out`, the result gains "pairs": {agg_idx: (cols,
     n_pairs)} — the deduped (group-keys, value) tuples of every DISTINCT
@@ -290,9 +331,11 @@ def emit_agg(ctx: EvalContext, live, root, aggs: List[AggFunc],
         key_out = [(jnp.asarray(v)[rep], jnp.asarray(m)[rep] &
                     (jnp.arange(cap) < n_groups)) for v, m in fkeys]
         slot_live = jnp.arange(cap, dtype=jnp.int32) < n_groups
-    elif root.group_exprs and key_bounds is not None:
+    elif root.group_exprs and F.grouping_mode(key_bounds) == F.RUNS:
+        return group_rows(ctx, live, root, key_bounds.bounds)
+    elif root.group_exprs and F.grouping_mode(key_bounds) == F.SLOTS:
         keys, gids, n_groups, key_out, slot_live = _perfect_groups(
-            ctx, live, root, cap, key_bounds)
+            ctx, live, root, cap, key_bounds.bounds)
     elif root.group_exprs:
         keys = [e.eval(ctx) for e in root.group_exprs]
         gids, n_groups, rep = F.factorize(keys, live, cap)
@@ -326,6 +369,110 @@ def emit_agg(ctx: EvalContext, live, root, aggs: List[AggFunc],
     if pairs_out:
         out["pairs"] = dpairs
     return out
+
+
+def sorted_runs_ok(root) -> bool:
+    """Can this aggregate's partials group by sorted runs
+    (`_sorted_runs_agg`)? Every state must be a sum of a per-row value —
+    COUNT, SUM, AVG over at most one 1-D argument; DISTINCT wants row
+    ids, MIN/MAX a scatter identity, ROLLUP the tiled factorize. And the
+    value must be an INTEGER (scaled DECIMALs are): a run's sum is the
+    difference of two prefix sums over all rows, exact in wrapping int64
+    and nowhere else — a float group near 1 beside one near 1e16 would
+    come out 0 or 8."""
+    from tidb_tpu.expression import ColumnRef
+    if not root.group_exprs or getattr(root, "rollup", False):
+        return False
+    if any(e.ftype.kind.is_string or e.ftype.is_wide_decimal
+           for e in root.group_exprs):
+        return False    # keys unpack into their numeric dtypes
+    for d in root.aggs:
+        if d.distinct or d.name not in ("count", "sum", "avg") \
+                or len(d.args) > 1:
+            return False
+        if d.args and (d.args[0].ftype.kind.is_string
+                       or d.args[0].ftype.kind.is_float or (
+                isinstance(d.args[0], ColumnRef)
+                and d.args[0].ftype.is_wide_decimal)):
+            return False
+    return True
+
+
+def group_rows(ctx: EvalContext, live, root, key_bounds):
+    """A slab's part in grouping by SORTED RUNS — for MANY groups whose
+    keys have known bounds: no reduction here, only each row's packed key
+    word(s) and, per aggregate with an argument, its value and validity.
+    The statement's rows are then sorted ONCE, all slabs together
+    (ops/factorize.sort_rows, a program of its own that every statement
+    shares), and `emit_runs_finalize` reduces the runs of equal keys by
+    scans. Against per-slab sort-factorize partials and their merge this
+    saves the per-key rank sorts, the scatters back to row order, the
+    int64 scatter-adds (1.1 s a state and 8M-row slab on a v5e) and the
+    re-sort of the partial slots.
+    → {"words", "payloads", "live", "n_groups": 0} (nothing here can
+    overflow a group capacity)."""
+    from tidb_tpu.ops.jax_env import jnp
+    from tidb_tpu.ops import factorize as F
+    keys = [e.eval(ctx) for e in root.group_exprs]
+    payloads = []
+    for desc in root.aggs:
+        if desc.args:
+            v, m = desc.args[0].eval(ctx)
+            payloads += [jnp.asarray(v), jnp.asarray(m) & live]
+    return {"words": F.pack_words(keys, key_bounds), "payloads": payloads,
+            "live": live, "n_groups": jnp.int32(0)}
+
+
+@_staged("agg")
+def _runs_states(root, aggs, runs, payloads, live_s):
+    from tidb_tpu.ops.jax_env import jnp
+    states, i = [], 0
+    for agg, desc in zip(aggs, root.aggs):
+        if desc.args:
+            v, m = payloads[i], payloads[i + 1]
+            i += 2
+        else:
+            v, m = jnp.zeros(live_s.shape[0], dtype=jnp.int64), live_s
+        states.append(agg.update(jnp, agg.init(jnp, runs.cap), runs,
+                                 runs.cap, v, m))
+    return states
+
+
+@_staged("finalize")
+def emit_runs_finalize(root, order_root, aggs: List[AggFunc], cap: int,
+                       key_bounds, key_dtypes, rows):
+    """The tail of a grouping by sorted runs, over `sort_rows`' output:
+    aggregate states by scans over the runs (scope `agg`), group keys
+    unpacked from the words at the run ends (arithmetic on `cap` gathered
+    words — no row gathers), then the root ORDER BY … LIMIT by selection
+    (`topn_select`; an ORDER BY without a limit sorts).
+    → {keys, states, n_groups[, n_out]} as emit_merge / emit_finalize
+    give them."""
+    from tidb_tpu.ops.jax_env import jnp
+    from tidb_tpu.ops import factorize as F
+    from tidb_tpu.ops.segment import SortedRuns
+    from tidb_tpu.planner.physical import PhysTopN
+    runs = SortedRuns(rows["ends"], rows["n_runs"], cap)
+    live_s = rows["words"][0] != jnp.int64(F.DEAD_WORD)
+    states = _runs_states(root, aggs, runs, rows["payloads"], live_s)
+    keys = [(v, m & runs.slot_live) for v, m in F.unpack_words(
+        [runs.at_ends(w) for w in rows["words"]], key_bounds, key_dtypes)]
+    out = {"keys": keys, "states": states, "n_groups": runs.n_runs}
+    if order_root is None:
+        return out
+    live = runs.slot_live
+    okeys, descs = _order_keys(order_root, aggs, len(keys), keys, states,
+                               live)
+    with stage("sort"):
+        if isinstance(order_root, PhysTopN):
+            idx, n_out = F.topn_select(
+                okeys, descs, live,
+                min(order_root.count + order_root.offset, cap))
+        else:
+            idx, n_out = F.sort_perm(okeys, descs, live)
+    return {"keys": [(v[idx], m[idx]) for v, m in keys],
+            "states": [tuple(a[idx] for a in st) for st in states],
+            "n_groups": runs.n_runs, "n_out": n_out}
 
 
 def _perfect_groups(ctx: EvalContext, live, root, cap: int,

@@ -48,6 +48,9 @@ from tidb_tpu.errors import (CapacityError, DeviceLost, ExecutionError,
                              QueryTimeout, ShardFailure)
 from tidb_tpu.expression import EvalContext, Expression, ColumnRef
 from tidb_tpu.expression.aggfuncs import AggFunc, build_agg
+from tidb_tpu.ops.factorize import (FACTORIZE, RUNS, SLOTS, KeyBounds,
+                                    bounds_sig, choose_key_bounds,
+                                    grouping_mode)
 from tidb_tpu.planner.physical import (PhysHashAgg, PhysHashJoin,
                                        PhysLimit, PhysProjection,
                                        PhysSelection, PhysSort,
@@ -118,9 +121,10 @@ def _order_over_agg_ok(order: PhysicalPlan, agg: PhysicalPlan) -> bool:
     """Can this ORDER BY / TopN root fuse into the device finalize of the
     HashAgg beneath it (device_emit.emit_finalize)?  Every sort key must
     be a bare ColumnRef into the agg's output row; keys referencing
-    aggregate outputs additionally require a final() that traces (the
-    count/sum/avg/min/max allowlist — wide-decimal finals run host-side
-    via numpy limb math) and a non-DISTINCT aggregate (device-merged
+    aggregate outputs additionally require order_keys() that trace (the
+    count/sum/avg/min/max allowlist; of the wide decimals, whose finals
+    run host-side via numpy limb math, only a SUM over a narrow argument
+    orders by its limb planes) and a non-DISTINCT aggregate (device-merged
     DISTINCT states dedup per-slab only; the exact cross-slab counts
     exist solely in the host pair merge, AFTER ordering would run)."""
     if not isinstance(agg, PhysHashAgg):
@@ -141,7 +145,10 @@ def _order_over_agg_ok(order: PhysicalPlan, agg: PhysicalPlan) -> bool:
             return False
         if d.name not in ("count", "sum", "avg", "min", "max"):
             return False
-        if d.ftype.is_wide_decimal or d.ftype.kind.is_string:
+        if d.ftype.kind.is_string:
+            return False
+        if d.ftype.is_wide_decimal and not (
+                d.name == "sum" and build_agg(d).orders_in_trace):
             return False
     return True
 
@@ -197,7 +204,8 @@ def _string_exprs_are_refs(exprs: Sequence[Expression]) -> bool:
                for e in exprs)
 
 
-def _exprs_device_ok(exprs: Sequence[Expression]) -> bool:
+def _exprs_device_ok(exprs: Sequence[Expression],
+                     wide_refs_ok: bool = False) -> bool:
     """Reject host-only builtins at plan time (quiet CPU routing instead
     of a traced failure + warning per query). Wide decimals (limb-plane
     representation) are rejected here too: only the SUM/AVG/COUNT agg
@@ -225,7 +233,9 @@ def _exprs_device_ok(exprs: Sequence[Expression]) -> bool:
             # wide-decimal COLUMNS arrive as 2-D limb planes no generic
             # kernel understands; computed wide-typed expressions are
             # ordinary 1-D scaled int64 and pass
-            if isinstance(sub, ColumnRef) and sub.ftype.is_wide_decimal:
+            # (a nested fragment's rows are 1-D too: `wide_refs_ok`)
+            if isinstance(sub, ColumnRef) and sub.ftype.is_wide_decimal \
+                    and not wide_refs_ok:
                 return False
     return True
 
@@ -241,10 +251,13 @@ def _fragment_ok(plan: PhysicalPlan, threshold: int) -> bool:
         return False
     reduction = isinstance(plan, (PhysHashAgg, PhysTopN, PhysSort))
     worthwhile = reduction or bool(scan.filters)
+    order_agg = _strip_order_root(plan)[0] is not None
     for node in chain:
         stage = _stage_exprs(node)
         if isinstance(node, PhysHashAgg):
             stage = list(node.group_exprs)   # agg args validated below
+        elif node is plan and order_agg:
+            stage = []      # refs into the agg's row: _order_over_agg_ok's
         if not _exprs_device_ok(stage):
             return False
         if isinstance(node, PhysHashAgg):
@@ -332,13 +345,48 @@ def extract_fragments(plan: PhysicalPlan, threshold: int) -> PhysicalPlan:
         frag = PhysTpuFragment(plan)
         frag.est_rows = plan.est_rows
         return frag
-    from tidb_tpu.executor.tree_fragment import tree_ok
+    from tidb_tpu.executor.tree_fragment import (nest_build_aggregates,
+                                                 tree_ok)
     if tree_ok(plan, threshold):
+        nest_build_aggregates(plan, threshold)
         frag = PhysTpuFragment(plan)
         frag.est_rows = plan.est_rows
         return frag
     plan.children = [extract_fragments(c, threshold) for c in plan.children]
     return plan
+
+
+def check_strict_plan(plan: PhysicalPlan, threshold: int) -> None:
+    """`tidb_tpu_strict = on`, the part no fragment can speak for: a plan
+    that leaves a device-sized base-table scan (est_rows ≥ the row
+    threshold) under a HOST join, aggregate, sort or window has fallen
+    back from the device as surely as a fragment that raised, and raises
+    the same typed error (counted as a `shape` fallback). A scan that
+    only returns its rows (under selections, projections, limits) and an
+    index read do not: there is no device work in them to lose."""
+    from tidb_tpu.planner.physical import (PhysIndexLookupJoin,
+                                           PhysMergeJoin, PhysStreamAgg)
+    heavy = (PhysHashJoin, PhysIndexLookupJoin, PhysMergeJoin, PhysHashAgg,
+             PhysStreamAgg, PhysSort, PhysTopN, PhysWindow)
+
+    def walk(node, under):
+        if isinstance(node, PhysTpuFragment):
+            return
+        if isinstance(node, PhysTableScan) and under is not None and \
+                getattr(node, "est_rows", 0.0) >= threshold:
+            from tidb_tpu.util.observability import REGISTRY
+            REGISTRY.inc("tidb_tpu_device_fallbacks_total",
+                         {"reason": "shape"})
+            raise ExecutionError(
+                f"tidb_tpu_strict: {under.name} runs on the host over a "
+                f"scan of {node.table.name} (~{node.est_rows:.0f} rows, "
+                f"device threshold {threshold})")
+        if isinstance(node, heavy):
+            under = node
+        for c in node.children:
+            walk(c, under)
+
+    walk(plan, None)
 
 
 # ---------------------------------------------------------------------------
@@ -438,6 +486,78 @@ def _get_or_build(sig: str, kind: str, build):
     return prog
 
 
+class DeviceAggRows:
+    """What a nested device-rows fragment hands its enclosing fragment:
+    the aggregate's output rows, still in HBM — `cols` [(values, valid)]
+    per output column and `live`, every array `cap` slots long; `bounds`
+    {column: (lo, hi)} for the group keys whose value bounds are known (a
+    join over them can then probe a table instead of sorting)."""
+
+    def __init__(self, cols, live, cap: int, bounds: dict):
+        self.cols = cols
+        self.live = live
+        self.cap = cap
+        self.bounds = bounds
+
+    def inputs(self):
+        return (self.cols, self.live)
+
+
+class _AggRowsProgram:
+    """Merged aggregate state → the aggregate's output rows, on the
+    device: group keys and each aggregate's final as one 1-D column
+    (AggFunc.final_narrow), live where a group is. One small launch after
+    a nested fragment's merge; `fits` says whether every final could be
+    held so."""
+
+    def __init__(self, agg_root, sig: str):
+        from tidb_tpu.ops.jax_env import named_jit, program_name
+        self.agg_root = agg_root
+        self.aggs = [build_agg(d) for d in agg_root.aggs]
+        self.name = program_name("rows", sig)
+        self.run = named_jit(self._run, self.name)
+
+    def _run(self, keys, states, n_groups):
+        from tidb_tpu.executor import device_emit
+        from tidb_tpu.ops.jax_env import jnp
+        _count_trace()
+        with device_emit.stage("finalize"):
+            live = jnp.arange(keys[0][0].shape[0],
+                              dtype=jnp.int32) < n_groups
+            cols = [(jnp.asarray(v), jnp.asarray(m) & live)
+                    for v, m in keys[:len(self.agg_root.group_exprs)]]
+            fits = jnp.bool_(True)
+            for agg, st in zip(self.aggs, states):
+                v, m, ok = agg.final_narrow(jnp, tuple(st))
+                cols.append((v, m & live))
+                fits = fits & ok
+        return cols, live, fits
+
+
+def _agg_rows(ctx, agg_root, out, cap: int, base_sig: str,
+              key_bounds) -> DeviceAggRows:
+    """Launch the rows program over a merged aggregate `out`."""
+    from tidb_tpu.ops.jax_env import jax
+    sig = "aggrows|" + base_sig
+    prog = _get_or_build(sig, "fused",
+                         lambda: _AggRowsProgram(agg_root, sig))
+    ph = ctx.phases
+    with ctx.device_slot():
+        with ph.launch(prog.name):
+            cols, live, fits = prog.run(list(out["keys"]),
+                                        [tuple(st) for st in out["states"]],
+                                        out["n_groups"])
+    ph.note_launch()
+    with ph.phase("fetch"):
+        fits = bool(jax.device_get(fits))
+    ph.add_d2h(1)
+    if not fits:
+        raise FragmentFallback("an aggregate's value exceeds 64 bits",
+                               reason="shape")
+    return DeviceAggRows(cols, live, cap, dict(enumerate(
+        key_bounds.bounds if key_bounds is not None else ())))
+
+
 def _tree_delete(tree) -> None:
     """Explicitly free every device array in a pytree of stale outputs
     (superseded slab partials / merge results on a ladder retry): without
@@ -475,7 +595,8 @@ def _chain_signature(chain: List[PhysicalPlan], used_cols: Sequence[int],
                      in_types: Sequence[FieldType], slab_cap: int,
                      group_cap: int, key_bounds=None,
                      layouts=None) -> str:
-    parts = [f"slab={slab_cap}", f"gcap={group_cap}", f"kb={key_bounds}",
+    parts = [f"slab={slab_cap}", f"gcap={group_cap}",
+             f"kb={bounds_sig(key_bounds)}",
              "cols=" + ",".join(f"{i}:{ft}" for i, ft in
                                 zip(used_cols, in_types)),
              # compressed physical layouts change the traced decode and
@@ -596,7 +717,7 @@ class _FragmentProgram:
         self.slab_cap = slab_cap
         self.group_cap = group_cap
         self.pair_cap = pair_cap   # distinct pair-set output capacity
-        self.key_bounds = key_bounds   # [(lo, hi)] → perfect-hash grouping
+        self.key_bounds = key_bounds   # ops/factorize.KeyBounds or None
         # col → ColLayout for compressed input slabs: decode is traced
         # into the chain ahead of every other stage
         self.layouts = dict(layouts) if layouts else {}
@@ -609,22 +730,16 @@ class _FragmentProgram:
                 for sub in e.walk():
                     if type(sub).prepare is not Expression.prepare:
                         self.prep_nodes.append(sub)
-        from tidb_tpu.ops.jax_env import named_jit, on_tpu, program_name
+        from tidb_tpu.ops.jax_env import named_jit, program_name
         # `sig` is the compile-cache signature: its digest names the
         # programs in the profile and in `launch` spans
         self.partial_name = program_name("partial_chain", sig)
         self.merge_name = program_name("merge", sig)
         self.partial = named_jit(self._partial, self.partial_name)
-        # donate the concatenated partial buffers into the merge: they are
-        # consumed exactly once, and donation lets XLA alias them as the
-        # merge's workspace — a ladder recompile right after a merge never
-        # holds both generations of group state in HBM. CPU backends don't
-        # support donation (it would warn per call), so gate on TPU.
-        if on_tpu():
-            self.merge = named_jit(self._merge, self.merge_name,
-                                   donate_argnums=(0, 1, 2))
-        else:
-            self.merge = named_jit(self._merge, self.merge_name)
+        # the merge takes the slab partials themselves and stacks them in
+        # the trace (device_emit.partials_of): nothing is donated, the
+        # partials stay alive as the checkpoints a ladder retry resumes from
+        self.merge = named_jit(self._merge, self.merge_name)
         # emit distinct (group, value) pair sets only when a multi-slab
         # execution will merge them — single-slab dedup is already exact
         self.has_distinct = want_pairs and \
@@ -915,17 +1030,12 @@ class _AggMergeProgram:
     the second and last device launch of a warm fused execution."""
 
     def __init__(self, root, group_cap: int, sig: str = ""):
-        from tidb_tpu.ops.jax_env import named_jit, on_tpu, program_name
+        from tidb_tpu.ops.jax_env import named_jit, program_name
         self.root = root
         self.group_cap = group_cap
         self.aggs = [build_agg(d) for d in root.aggs]
         self.merge_name = program_name("merge", sig)
-        if on_tpu():
-            # stacked partials are dead after the merge — donate them
-            self.merge = named_jit(self._merge, self.merge_name,
-                                   donate_argnums=(0, 1, 2))
-        else:
-            self.merge = named_jit(self._merge, self.merge_name)
+        self.merge = named_jit(self._merge, self.merge_name)
 
     def _merge(self, key_cols, states, slot_live):
         from tidb_tpu.executor import device_emit
@@ -939,6 +1049,54 @@ def get_merge_program(root, group_cap: int,
     sig = "fusedmerge|" + pipeline_sig
     return _get_or_build(sig, "fused",
                          lambda: _AggMergeProgram(root, group_cap, sig))
+
+
+class _SortRowsProgram:
+    """The ONE sort of a grouping by sorted runs (ops/factorize.sort_rows)
+    over every slab's rows at once: a program of its own whose signature
+    holds shapes and nothing of a statement, so that every statement of
+    the same geometry shares its executable — the TPU compiler charges
+    each sort's comparator to every program that holds one (PERF.md §6,
+    PR 28). Slabs are stacked in the trace."""
+
+    def __init__(self, sig: str):
+        from tidb_tpu.ops.jax_env import named_jit, program_name
+        self.name = program_name("sort_rows", sig)
+        self.run = named_jit(self._run, self.name)
+
+    def _run(self, words, payloads, lives):
+        from tidb_tpu.executor import device_emit
+        from tidb_tpu.ops import factorize as F
+        from tidb_tpu.ops.jax_env import jnp
+        _count_trace()
+        with device_emit.stage("agg"):
+            cat = jnp.concatenate
+            return F.sort_rows([cat(w) for w in words], cat(lives),
+                               [cat(p) for p in payloads])
+
+
+class _RunsFinalizeProgram:
+    """A statement's tail over its sorted rows: states by scans, keys,
+    ORDER BY … LIMIT (device_emit.emit_runs_finalize). No sort in it."""
+
+    def __init__(self, agg_root, order_root, cap: int, key_bounds,
+                 sig: str):
+        from tidb_tpu.ops.jax_env import named_jit, program_name
+        self.agg_root = agg_root
+        self.order_root = order_root
+        self.cap = cap
+        self.key_bounds = key_bounds
+        self.aggs = [build_agg(d) for d in agg_root.aggs]
+        self.key_dtypes = [e.ftype.np_dtype for e in agg_root.group_exprs]
+        self.name = program_name("finalize", sig)
+        self.run = named_jit(self._run, self.name)
+
+    def _run(self, rows):
+        from tidb_tpu.executor import device_emit
+        _count_trace()
+        return device_emit.emit_runs_finalize(
+            self.agg_root, self.order_root, self.aggs, self.cap,
+            self.key_bounds.bounds, self.key_dtypes, rows)
 
 
 def _order_sig(order_root) -> str:
@@ -956,18 +1114,13 @@ class _FusedFinalizeProgram:
 
     def __init__(self, agg_root, order_root, group_cap: int,
                  sig: str = ""):
-        from tidb_tpu.ops.jax_env import named_jit, on_tpu, program_name
+        from tidb_tpu.ops.jax_env import named_jit, program_name
         self.agg_root = agg_root
         self.order_root = order_root
         self.group_cap = group_cap
         self.aggs = [build_agg(d) for d in agg_root.aggs]
         self.name = program_name("finalize", sig)
-        if on_tpu():
-            # stacked partials are dead after the finalize — donate them
-            self.run = named_jit(self._run, self.name,
-                                 donate_argnums=(0, 1, 2))
-        else:
-            self.run = named_jit(self._run, self.name)
+        self.run = named_jit(self._run, self.name)
 
     def _run(self, key_cols, states, slot_live):
         from tidb_tpu.executor import device_emit
@@ -1076,16 +1229,57 @@ def _spec_note(ph, hit: bool) -> None:
                      {"engine": "device"})
 
 
+# the span tag and counter label of each lowering ("bounds" is older than
+# the mode's name)
+_GROUPING_TAG = {SLOTS: "bounds", RUNS: "runs", FACTORIZE: "factorize"}
+
+
 def _note_grouping(root: PhysHashAgg, key_bounds, group_cap: int) -> str:
     """Tag the open `device.fragment` span with how this aggregate's
     partials assign rows to slots and into how many: "global" (no GROUP
-    BY, one slot), "bounds" (a packed code over known key domains) or
-    "factorize" (sort-based). → the grouping, the label of
+    BY, one slot), "bounds" (a packed code over known key domains),
+    "runs" (sorted runs: the slab programs hand out rows, `gcap` 0 there)
+    or "factorize" (sort-based partials). → the grouping, the label of
     `tidb_tpu_agg_partials_total`."""
     grouping = ("global" if not root.group_exprs else
-                "bounds" if key_bounds is not None else "factorize")
+                _GROUPING_TAG[grouping_mode(key_bounds)])
     timeline.tag(grouping=grouping, gcap=int(group_cap))
     return grouping
+
+
+def _note_agg_io(partial, rows_in: int, groups: int) -> None:
+    """Tag the open `device.fragment` span with what its grouping took in
+    and gave out: `rows_in` (rows of the slabs whose partials launched,
+    re-runs included), `groups` (live groups out), and the bytes one group
+    holds on the device, `key_bytes` and `state_bytes` (read off a
+    partial's own arrays)."""
+    if not timeline.ENABLED:
+        return
+    timeline.tag(
+        rows_in=int(rows_in), groups=int(groups),
+        key_bytes=sum(v.dtype.itemsize + m.dtype.itemsize
+                      for v, m in partial["keys"]),
+        state_bytes=sum(a.dtype.itemsize for st in partial["states"]
+                        for a in st))
+
+
+def _merge_span(partials, cap: int):
+    """The `frag.merge` span around the launch that merges slab partials:
+    `slots_in` partial slots reduce into `slots_out`."""
+    return timeline.span(
+        "frag.merge", "frag", slots_out=int(cap),
+        slots_in=sum(int(p["slot_live"].shape[0]) for p in partials))
+
+
+def _tight_cap(cap: int, groups: int) -> int:
+    """The capacity the NEXT execution of a grouping by sorted runs starts
+    from (kept by the specialization cache): the groups it found plus an
+    eighth, not the planner's estimate. What such a finalize costs is its
+    gathers at the run ends, `cap` elements each (0.37 s per 16M on a
+    v5e), and an estimate can be a thousand times the groups a semijoin
+    leaves."""
+    from tidb_tpu.executor.device_cache import _pow2
+    return min(cap, _pow2(groups + groups // 8 + 16, lo=1024))
 
 
 def _count_agg_partial(grouping: str) -> None:
@@ -1114,6 +1308,13 @@ def _initial_group_cap(root: PhysHashAgg, default_cap: int,
 
 
 DOMAIN_CAP = 1 << 20    # max packed group-key domain for perfect hashing
+# Beyond the masked reduce's slot count a directly addressed partial is an
+# int64 scatter-add per state — 1.1 s a state and 8M-row slab on a v5e
+# (a GROUP BY over 150K customer keys at SF=1 took 2.9 s so, against
+# 0.9 s at SF=8 where its 1.2M keys already grouped by sorted runs:
+# PERF.md §6, PR 28). So a
+# wider key domain groups by sorted runs wherever the aggregates allow.
+SLOT_ADDRESS_CAP = 1024
 
 
 def _trace_to_scan_col(chain: List[PhysicalPlan], expr) -> Optional[int]:
@@ -1131,9 +1332,14 @@ def _trace_to_scan_col(chain: List[PhysicalPlan], expr) -> Optional[int]:
     return idx
 
 
-def _agg_key_bounds(chain: List[PhysicalPlan], ent) -> Optional[List[Tuple[int, int]]]:
+def _agg_key_bounds(chain: List[PhysicalPlan], ent) -> Optional[KeyBounds]:
     """Per-group-key (lo, hi) domains when every key is a scan column with
-    cached bounds and the packed domain stays small; None → sort factorize."""
+    cached bounds, and the lowering they allow
+    (ops/factorize.choose_key_bounds): a small packed domain addresses
+    the group slots directly, a large one packs the keys into sort words
+    for the sorted-runs grouping where the aggregates allow it; None →
+    sort factorize."""
+    from tidb_tpu.executor import device_emit
     root = chain[0]
     if not isinstance(root, PhysHashAgg) or not root.group_exprs:
         return None
@@ -1150,10 +1356,9 @@ def _agg_key_bounds(chain: List[PhysicalPlan], ent) -> Optional[List[Tuple[int, 
             return None
         lo, hi = b
         domain *= (hi - lo + 2)
-        if domain > DOMAIN_CAP:
-            return None
         bounds.append((lo, hi))
-    return bounds
+    return choose_key_bounds(bounds, domain, SLOT_ADDRESS_CAP, DOMAIN_CAP,
+                             device_emit.sorted_runs_ok(root))
 
 
 def _ent_layouts(ent, used):
@@ -1406,6 +1611,9 @@ class TpuFragmentExec:
         self._result: Optional[Chunk] = None
         self._cpu_root = None
         self._offset = 0
+        # set by the enclosing fragment's executor on a nested device-rows
+        # fragment: the aggregate drivers then return DeviceAggRows
+        self._rows_on_device = False
 
     def open(self, ctx) -> None:
         self.ctx = ctx
@@ -1715,9 +1923,9 @@ class TpuFragmentExec:
         # (open_table commits dictionaries/bounds EAGERLY — before the
         # stream runs — exactly so program construction can use them here)
         key_bounds = _agg_key_bounds(chain, ent)
-        if key_bounds is not None:
+        if grouping_mode(key_bounds) == SLOTS:
             group_cap = 1
-            for lo, hi in key_bounds:
+            for lo, hi in key_bounds.bounds:
                 group_cap *= (hi - lo + 2)
         elif isinstance(root, PhysHashAgg):
             group_cap = _initial_group_cap(root, group_cap, slab_cap)
@@ -1766,6 +1974,74 @@ class TpuFragmentExec:
         return self._execute_filter(prog, root, ent, dicts, prep_vals,
                                     stream, slab_ids=slab_ids)
 
+    def _runs_finalize(self, root, order_root, partials, n_slabs: int,
+                       cap: int, key_bounds, base_sig: str, sorted_rows):
+        """Grouping by sorted runs, after the slabs' `group_rows`
+        partials: sort every slab's rows ONCE (the shared sort program;
+        `sorted_rows` from an earlier round of the capacity ladder is
+        reused, the ladder only resizes the finalize) and reduce the runs.
+        → (out as a merge or fused finalize gives it, sorted_rows)."""
+        import hashlib
+
+        from tidb_tpu.ops.jax_env import jnp
+        ph = self.ctx.phases
+        p0 = partials[0]
+        n = int(p0["live"].shape[0])
+        with timeline.span("frag.merge", "frag", slots_in=0,
+                           slots_out=int(cap), rows=n * n_slabs):
+            if sorted_rows is None:
+                # a slab that zone maps pruned has no partial: it rides
+                # as dead rows, so the sort's shape (and executable) does
+                # not depend on what was pruned
+                pad = [dict(p0, live=jnp.zeros(n, dtype=bool))] * \
+                    (n_slabs - len(partials))
+                parts = list(partials) + pad
+                sig = (f"sortrows|{n_slabs}x{n}|"
+                       f"w={[str(w.dtype) for w in p0['words']]}|"
+                       f"p={[str(a.dtype) for a in p0['payloads']]}")
+                sp = _get_or_build(sig, "fused",
+                                   lambda: _SortRowsProgram(sig))
+                with self.ctx.device_slot():
+                    with ph.launch(sp.name):
+                        sorted_rows = sp.run(
+                            [[p["words"][i] for p in parts]
+                             for i in range(len(p0["words"]))],
+                            [[p["payloads"][i] for p in parts]
+                             for i in range(len(p0["payloads"]))],
+                            [p["live"] for p in parts])
+                ph.note_launch()
+            fsig = ("runsfinal|" + (_order_sig(order_root)
+                                    if order_root is not None else "-")
+                    + f"|cap={cap}|" + base_sig)
+            fp = _get_or_build(fsig, "finalize", lambda: _RunsFinalizeProgram(
+                root, order_root, cap, key_bounds, fsig))
+            fsig12 = hashlib.sha1(fsig.encode()).hexdigest()[:12]
+            with self.ctx.device_slot():
+                with ph.launch(fp.name, sig=f"fused-final:{fsig12}"):
+                    out = fp.run(sorted_rows)
+            ph.note_launch()
+        return out, sorted_rows
+
+    def _run_nested(self, frag: PhysTpuFragment) -> DeviceAggRows:
+        """Run a nested device-rows fragment to its merged groups, which
+        stay in HBM. It is a fragment like any other — own signature,
+        specialization entry, capacity ladder, launches in this
+        statement's ledger — except that nothing is fetched but the
+        counts its ladder validates."""
+        sub = TpuFragmentExec(frag)
+        sub.open(self.ctx)
+        sub._rows_on_device = True
+        with timeline.span("device.fragment", "frag", root=frag.root.name,
+                           rows="device"):
+            with sub._protect_tables():
+                rows = sub._run_device()
+        if not isinstance(rows, DeviceAggRows):
+            # a path that answers from the host (every slab pruned, the
+            # mega-slab loop): the enclosing tree cannot take host rows
+            raise FragmentFallback("nested fragment left the device",
+                                   reason="shape")
+        return rows
+
     # ---- join-tree / mega-slab device pipeline -----------------------------
     def _run_device_tree(self) -> Chunk:
         """Q3/Q5-shaped join trees (and multi-slab chains the per-slab
@@ -1810,6 +2086,17 @@ class TpuFragmentExec:
             ents.append((ent, used))
         caps = {id(s): (e.slab_cap, e.n_slabs)
                 for s, (e, _) in zip(scans, ents)}
+        # nested device-rows fragments (aggregates that are a join's build
+        # side) run FIRST, as fragments of their own whose merged groups
+        # stay in HBM; the programs below take them as inputs, shaped by
+        # the capacity each settled on
+        nested_rows, nested_bounds = [], {}
+        for nf in TF.nested_fragments(root):
+            rows = self._run_nested(nf)
+            caps[id(nf)] = (rows.cap, 1)
+            nested_bounds[id(nf)] = rows.bounds
+            nested_rows.append(rows.inputs())
+        nested_rows = tuple(nested_rows)
         # per-scan-slot ((col, ColLayout), ...) for compressed columns —
         # parallel to TF._scans(root) order, which matches the `scans`
         # walk order here (both left-to-right DFS)
@@ -1823,6 +2110,7 @@ class TpuFragmentExec:
         scan_dicts = {id(s): {i: e.dicts.get(i) for i in u}
                       for s, (e, u) in zip(scans, ents)}
         scan_bounds = {id(s): e.bounds for s, (e, _) in zip(scans, ents)}
+        scan_bounds.update(nested_bounds)
         flows, root_dicts = TF.dictionary_flows(root, scan_dicts)
         scan_inputs = tuple({i: list(e.dev[i]) for i in u}
                             for e, u in ents)
@@ -1867,9 +2155,9 @@ class TpuFragmentExec:
         aligned_inputs = tuple(aligned_inputs)
         akb = TF.tree_agg_key_bounds(root, scan_bounds, DOMAIN_CAP) \
             if is_agg else None
-        if akb is not None:
+        if grouping_mode(akb) == SLOTS:
             gcap = 1
-            for lo, hi in akb:
+            for lo, hi in akb.bounds:
                 gcap *= (hi - lo + 2)
         elif is_agg:
             gcap = _initial_group_cap(root, group_cap, max_cap)
@@ -1900,7 +2188,7 @@ class TpuFragmentExec:
                     root, caps, scans, ents, scan_inputs, scan_rows,
                     flow_list, flows, aligned_inputs, join_cfgs,
                     walk_joins, akb, gcap, max_cap, out_cap_max, ladder,
-                    anchor_i, scan_layouts, order_root)
+                    anchor_i, scan_layouts, order_root, nested_rows)
                 if res is not None:
                     return res
                 # a join's fan-out exceeded out_cap_max inside the fused
@@ -1918,7 +2206,7 @@ class TpuFragmentExec:
             with self.ctx.device_slot():
                 with ph.launch(prog.name):
                     out = prog(scan_inputs, scan_rows, prep_vals,
-                               aligned_inputs)
+                               aligned_inputs, nested=nested_rows)
             ph.note_launch()
             if is_agg:
                 _count_agg_partial(_note_grouping(root, akb, gcap))
@@ -1959,6 +2247,10 @@ class TpuFragmentExec:
                     cfg, uq, tot, out_cap_max,
                     flip_out_cap=_pow2(int(cfg.est * 1.3), lo=1024),
                     ladder=ladder)
+                if action == "over-max" and nested_rows:
+                    raise FragmentFallback(
+                        "blocked expand over a nested fragment",
+                        reason="blocked-expand")
                 if action == "over-max":
                     # runaway fan-out (many-to-many on a skewed key):
                     # too large to materialize in one batch — run the
@@ -1974,7 +2266,8 @@ class TpuFragmentExec:
                 if new_cfg is not None:
                     join_cfgs[ji] = new_cfg
                     retry = True
-            if is_agg and akb is None and int(flags["ng"]) > gcap:
+            if is_agg and grouping_mode(akb) != SLOTS and \
+                    int(flags["ng"]) > gcap:
                 if gcap >= max_cap:
                     ladder.fallback("group")
                     raise FragmentFallback("group cap overflow", reason="group-cap")
@@ -2033,8 +2326,8 @@ class TpuFragmentExec:
                             scan_rows, flow_list, flows, aligned_inputs,
                             join_cfgs, walk_joins, akb, gcap, max_cap,
                             out_cap_max, ladder, anchor_i,
-                            scan_layouts=None,
-                            order_root=None) -> Optional[Chunk]:
+                            scan_layouts=None, order_root=None,
+                            nested_rows=()) -> Optional[Chunk]:
         """Whole-pipeline fusion: ONE traced XLA program per probe-anchor
         slab covering scan → filter → project → join-probe → partial-agg,
         plus one shared root-merge program — intermediates never leave
@@ -2111,7 +2404,7 @@ class TpuFragmentExec:
                 getattr(self.ctx, "guard", None), "tree",
                 (tuple((id(e.td), getattr(e, "delta_version", 0),
                         e.slab_cap, e.n_slabs) for e, _ in ents),
-                 anchor_i, repr(akb), want_pairs, use_fin,
+                 anchor_i, bounds_sig(akb), want_pairs, use_fin,
                  _order_sig(order_root) if order_root is not None
                  else None, _plan_fingerprint(root)))
         spec = _spec_lookup(skey, lay_sig)
@@ -2159,6 +2452,11 @@ class TpuFragmentExec:
 
         from tidb_tpu.util import failpoint
         partials: List = [None] * n_run
+        rows_in = 0                    # rows of every launched slab
+        # grouping by sorted runs: the slab programs only hand out rows
+        # (no group capacity in them), one sort serves the statement
+        rows_mode = grouping_mode(akb) == RUNS
+        sorted_rows = None
         caps_ran = [0] * n_run         # group cap each partial ran at
         pcaps = [0] * n_run            # pair cap each partial ran at
         pairs_cache: List = [None] * n_run     # host distinct-pair sets
@@ -2168,8 +2466,8 @@ class TpuFragmentExec:
             grouping = _note_grouping(root, akb, gcap)
             with timeline.span("frag.program", "frag"):
                 prog, pipe_sig = get_pipeline_program(
-                    root, pipe_caps, gcap, join_cfgs, akb, scan_layouts,
-                    want_pairs, pair_cap, sig=spec_sig)
+                    root, pipe_caps, 0 if rows_mode else gcap, join_cfgs,
+                    akb, scan_layouts, want_pairs, pair_cap, sig=spec_sig)
                 prep_vals = prog.collect_preps(flow_list)
             spec_sig = None
             sig12 = hashlib.sha1(pipe_sig.encode()).hexdigest()[:12]
@@ -2181,10 +2479,13 @@ class TpuFragmentExec:
                 with self.ctx.device_slot():
                     with ph.launch(prog.name, slab=run_ids[s],
                                    sig=f"fused:{sig12}"):
-                        partials[s] = prog(si, sr, prep_vals, ai)
+                        partials[s] = prog(si, sr, prep_vals, ai,
+                                           nested=nested_rows)
                 ph.note_launch()
                 ph.note_fused()
                 _count_agg_partial(grouping)
+                rows_in += int(anchor_rows[run_ids[s]])
+                sorted_rows = None
                 caps_ran[s] = gcap
                 pcaps[s] = pair_cap
                 pairs_cache[s] = None
@@ -2239,49 +2540,30 @@ class TpuFragmentExec:
             # per-slab partials + root merge/finalize build the whole
             # device graph first; every control value returns in ONE
             # batched fetch
-            with self.ctx.device_slot():
-                if use_fin or n_run > 1:
-                    with ph.glue():
-                        # concatenate even for one slab: the finalize
-                        # donates its inputs, and fresh buffers keep the
-                        # checkpointed partials alive for resumable
-                        # retries
-                        key_cols = []
-                        # len(partials[0]["keys"]), not nk: rollup
-                        # partials carry a trailing grouping-level column
-                        for kc in range(len(partials[0]["keys"])):
-                            key_cols.append(tuple(
-                                jnp.concatenate([p["keys"][kc][f]
-                                                 for p in partials])
-                                for f in range(2)))
-                        states = []
-                        for ai_ in range(len(root.aggs)):
-                            states.append(tuple(
-                                jnp.concatenate([p["states"][ai_][f]
-                                                 for p in partials])
-                                for f in range(
-                                    len(partials[0]["states"][ai_]))))
-                        slot_live = jnp.concatenate([p["slot_live"]
-                                                     for p in partials])
-                if use_fin:
-                    pass          # launched below, in its own span
-                elif n_run == 1:
-                    out = partials[0]
-                else:
-                    mp = get_merge_program(root, gcap, pipe_sig)
-                    with ph.launch(mp.merge_name):
-                        out = mp.merge(key_cols, states, slot_live)
-                    ph.note_launch()
-            if use_fin:
+            from tidb_tpu.executor.device_emit import partials_of
+            if rows_mode:
+                out, sorted_rows = self._runs_finalize(
+                    root, order_root if use_fin else None, partials,
+                    n_slabs, gcap, akb, pipe_sig, sorted_rows)
+            elif use_fin:
                 # ONE launch for the whole query tail: agg merge →
-                # finalize expressions → root ORDER BY / TopN
+                # finalize expressions → root ORDER BY / TopN. It takes
+                # the partials as they are and stacks them in the trace
                 fprog, fsig = get_finalize_program(root, order_root,
                                                    gcap, pipe_sig)
                 fsig12 = hashlib.sha1(fsig.encode()).hexdigest()[:12]
-                with self.ctx.device_slot():
+                with _merge_span(partials, gcap), self.ctx.device_slot():
                     with ph.launch(fprog.name,
                                    sig=f"fused-final:{fsig12}"):
-                        out = fprog.run(key_cols, states, slot_live)
+                        out = fprog.run(*partials_of(partials))
+                ph.note_launch()
+            elif n_run == 1:
+                out = partials[0]
+            else:
+                mp = get_merge_program(root, gcap, pipe_sig)
+                with _merge_span(partials, gcap), self.ctx.device_slot():
+                    with ph.launch(mp.merge_name):
+                        out = mp.merge(*partials_of(partials))
                 ph.note_launch()
             with self.ctx.device_slot():
                 with ph.glue():
@@ -2291,7 +2573,10 @@ class TpuFragmentExec:
                              "jts": [p["join_totals"] for p in partials]}
                     if use_fin:
                         fetch["no"] = out["n_out"]
-                    small = _piggyback_agg(fetch, out, gcap)
+                    small = not self._rows_on_device and \
+                        _piggyback_agg(
+                            fetch, out, int(out["keys"][0][0].shape[0])
+                            if rows_mode and out["keys"] else gcap)
             with ph.drain():
                 jax.block_until_ready(fetch)
             with ph.phase("fetch"):
@@ -2339,7 +2624,7 @@ class TpuFragmentExec:
                         rerun.update(s for s in range(n_run)
                                      if int(jts[s, ji]) > cfg.out_cap)
             n_final = int(got["ng"])
-            if akb is None:
+            if grouping_mode(akb) != SLOTS:
                 over = [s for s in range(n_run)
                         if int(got["ngs"][s]) > caps_ran[s]]
                 if over or n_final > gcap:
@@ -2364,11 +2649,14 @@ class TpuFragmentExec:
                     # budget + guard checkpoint between recompiles (the
                     # join rungs above already recorded their own stats)
                     ladder.attempt("fused")
-                if n_run > 1 or use_fin:
+                if n_run > 1 or use_fin or rows_mode:
                     _tree_delete(out)     # stale merge generation
                 to_run = sorted(rerun)
                 continue
             break
+        cap_out = gcap
+        if rows_mode:
+            gcap = _tight_cap(gcap, n_final)
         if skey is not None and (spec is None
                                  or spec["group_cap"] != gcap
                                  or spec["pair_cap"] != pair_cap
@@ -2376,6 +2664,9 @@ class TpuFragmentExec:
             _spec_store(skey, {"group_cap": gcap, "pair_cap": pair_cap,
                                "join_cfgs": tuple(join_cfgs),
                                "sig": pipe_sig, "lay_sig": lay_sig})
+        _note_agg_io(out, rows_in, n_final)
+        if self._rows_on_device:
+            return _agg_rows(self.ctx, root, out, cap_out, pipe_sig, akb)
         if root.group_exprs and n_final == 0:
             from tidb_tpu.executor import _empty_chunk
             return _empty_chunk(self.schema)
@@ -2508,7 +2799,7 @@ class TpuFragmentExec:
                             join_cfgs[ji] = d_replace(cfg,
                                                       out_cap=_pow2(tot))
                             restart = True
-                if akb is None and int(got["ng"]) > gcap:
+                if grouping_mode(akb) != SLOTS and int(got["ng"]) > gcap:
                     if gcap >= max_cap:
                         raise FragmentFallback("group cap overflow", reason="group-cap")
                     gcap = min(gcap * 4, max_cap)
@@ -3191,7 +3482,7 @@ class TpuFragmentExec:
             skey = _spec_key(
                 getattr(self.ctx, "guard", None), "chain",
                 (id(ent.td), getattr(ent, "delta_version", 0), slab_cap,
-                 n_slabs, repr(key_bounds), want_pairs, use_fin,
+                 n_slabs, bounds_sig(key_bounds), want_pairs, use_fin,
                  _order_sig(order_root) if order_root is not None
                  else None, _plan_fingerprint(chain[0])))
         spec = _spec_lookup(skey, lay_sig)
@@ -3203,6 +3494,11 @@ class TpuFragmentExec:
             pair_cap = spec["pair_cap"] if want_pairs else 0
             spec_sig = spec["sig"]
         partials: List = [None] * n_run
+        rows_in = 0                     # rows of every launched slab
+        # grouping by sorted runs: the slab programs only hand out rows
+        # (no group capacity in them), one sort serves the statement
+        rows_mode = grouping_mode(key_bounds) == RUNS
+        sorted_rows = None
         caps = [0] * n_run              # group cap each partial ran at
         pcaps = [0] * n_run             # pair cap each partial ran at
         pairs_cache: List = [None] * n_run     # host distinct-pair sets
@@ -3225,13 +3521,16 @@ class TpuFragmentExec:
                 psig, spec_sig = spec_sig, None
             else:
                 psig = _chain_signature(chain, used, in_types, slab_cap,
-                                        group_cap, key_bounds, layouts) + \
+                                        0 if rows_mode else group_cap,
+                                        key_bounds, layouts) + \
                     f"|pairs={want_pairs},{pair_cap}"
             with timeline.span("frag.program", "frag"):
                 prog = get_program(chain, used, in_types, slab_cap,
-                                   group_cap, key_bounds, want_pairs,
+                                   0 if rows_mode else group_cap,
+                                   key_bounds, want_pairs,
                                    layouts, pair_cap, sig=psig)
                 prep_vals = prog.collect_preps(dicts)
+            cap_ran = group_cap if rows_mode else prog.group_cap
             if to_run is None:
                 for s, (cols, n) in enumerate(
                         self._slab_iter(ent, stream, prog.used_cols,
@@ -3246,6 +3545,8 @@ class TpuFragmentExec:
                     ph.note_launch()
                     ph.note_fused()   # a chain partial IS a fused pipeline
                     _count_agg_partial(grouping)
+                    rows_in += int(n)
+                    sorted_rows = None
                     caps[s] = group_cap
                     pcaps[s] = pair_cap
             else:
@@ -3260,6 +3561,8 @@ class TpuFragmentExec:
                     ph.note_launch()
                     ph.note_fused()
                     _count_agg_partial(grouping)
+                    rows_in += int(n)
+                    sorted_rows = None
                     caps[s] = group_cap
                     pcaps[s] = pair_cap
                     pairs_cache[s] = None
@@ -3321,49 +3624,31 @@ class TpuFragmentExec:
             # exceeds the cap it ran at clips gids (factorize clamps to
             # cap-1), silently conflating groups, while the merged
             # n_groups alone can look fine.
-            with self.ctx.device_slot():
-                if use_fin or n_run > 1:
-                    with ph.glue():
-                        # concatenate even for one slab: the finalize
-                        # donates its inputs, and fresh buffers keep the
-                        # checkpointed partials alive for resumable
-                        # retries
-                        key_cols = []
-                        # len(partials[0]["keys"]), not nk: rollup
-                        # partials carry a trailing grouping-level column
-                        for kc in range(len(partials[0]["keys"])):
-                            v = jnp.concatenate([p["keys"][kc][0]
-                                                 for p in partials])
-                            m = jnp.concatenate([p["keys"][kc][1]
-                                                 for p in partials])
-                            key_cols.append((v, m))
-                        states = []
-                        for ai in range(len(root.aggs)):
-                            states.append(tuple(
-                                jnp.concatenate([p["states"][ai][f]
-                                                 for p in partials])
-                                for f in range(
-                                    len(partials[0]["states"][ai]))))
-                        slot_live = jnp.concatenate([p["slot_live"]
-                                                     for p in partials])
-                if use_fin:
-                    pass          # launched below, in its own span
-                elif n_run == 1:
-                    out = partials[0]
-                else:
-                    with ph.launch(prog.merge_name):
-                        out = prog.merge(key_cols, states, slot_live)
-                    ph.note_launch()
-            if use_fin:
+            from tidb_tpu.executor.device_emit import partials_of
+            if rows_mode:
+                out, sorted_rows = self._runs_finalize(
+                    root, order_root if use_fin else None, partials,
+                    n_slabs, group_cap, key_bounds, psig, sorted_rows)
+            elif use_fin:
                 # ONE launch for the whole query tail: agg merge →
-                # finalize expressions → root ORDER BY / TopN
+                # finalize expressions → root ORDER BY / TopN. It takes
+                # the partials as they are and stacks them in the trace
                 fprog, fsig = get_finalize_program(root, order_root,
                                                    group_cap, psig)
                 fsig12 = hashlib.sha1(fsig.encode()).hexdigest()[:12]
-                with self.ctx.device_slot():
+                with _merge_span(partials, group_cap), \
+                        self.ctx.device_slot():
                     with ph.launch(fprog.name,
                                    sig=f"fused-final:{fsig12}"):
-                        out = fprog.run(key_cols, states, slot_live)
+                        out = fprog.run(*partials_of(partials))
+                ph.note_launch()
+            elif n_run == 1:
+                out = partials[0]
+            else:
+                with _merge_span(partials, group_cap), \
+                        self.ctx.device_slot():
+                    with ph.launch(prog.merge_name):
+                        out = prog.merge(*partials_of(partials))
                 ph.note_launch()
             with self.ctx.device_slot():
                 with ph.glue():
@@ -3371,7 +3656,10 @@ class TpuFragmentExec:
                              "ng": out["n_groups"]}
                     if use_fin:
                         fetch["no"] = out["n_out"]
-                    small = _piggyback_agg(fetch, out, prog.group_cap)
+                    small = not self._rows_on_device and \
+                        _piggyback_agg(
+                            fetch, out, int(out["keys"][0][0].shape[0])
+                            if rows_mode and out["keys"] else cap_ran)
             with ph.drain():
                 # drain inside "compute" so the flag fetch below measures
                 # pure transfer, not the device finishing its work — but
@@ -3413,7 +3701,7 @@ class TpuFragmentExec:
                     _tree_delete(out)     # stale merge generation
                 to_run = over
                 continue
-            if n_final > prog.group_cap:
+            if n_final > cap_ran:
                 # only the MERGED distinct count overflowed: every slab
                 # partial is a valid checkpoint — re-run NOTHING, just
                 # re-merge at the exact-need cap
@@ -3425,11 +3713,13 @@ class TpuFragmentExec:
                                           max_cap=cap_limit)
                 ladder.attempt("group", _GroupCapOverflow(n_final))
                 ladder.partial_resume("group", rerun=0, reused=n_run)
-                if n_run > 1 or use_fin:
+                if n_run > 1 or use_fin or rows_mode:
                     _tree_delete(out)
                 to_run = []
                 continue
             break
+        if rows_mode:
+            group_cap = _tight_cap(group_cap, n_final)
         if skey is not None and (spec is None
                                  or spec["group_cap"] != group_cap
                                  or spec["pair_cap"] != pair_cap):
@@ -3442,6 +3732,10 @@ class TpuFragmentExec:
                                for s in range(n_run)]
                           for ai in pairs_cache[0]} \
                 if pairs_cache[0] else {}
+        _note_agg_io(out, rows_in, n_final)
+        if self._rows_on_device:
+            return _agg_rows(self.ctx, root, out, cap_ran, psig,
+                             key_bounds)
         if root.group_exprs and n_final == 0:
             from tidb_tpu.executor import _empty_chunk
             return _empty_chunk(self.schema)

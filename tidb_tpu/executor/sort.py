@@ -25,18 +25,26 @@ from tidb_tpu.expression.runner import host_context
 
 def rank_keys(by: List[Expression], descs: List[bool],
               chunk: Chunk) -> List[np.ndarray]:
-    """Per sort key → int64 rank codes honoring direction + NULL order."""
+    """Per sort key → int64 rank codes honoring direction + NULL order.
+    Numbers (wide DECIMALs too) rank by value, strings by their text."""
     ctx = host_context(chunk)
     keys = []
     for e, desc in zip(by, descs):
         v, m = e.eval(ctx)
         v = np.asarray(v)
         m = np.asarray(m, dtype=bool)
-        if v.dtype == object:
+        if v.dtype == object and e.ftype.is_varlen:
             v = np.asarray([str(x) for x in v], dtype=object)
             if e.ftype.is_ci:
                 from tidb_tpu.types import fold_ci_array
                 v = fold_ci_array(v)
+        elif v.dtype == object:
+            # a wide DECIMAL: scaled Python ints, ranked by VALUE (their text
+            # would put 9976 above 495455). int64 where they fit, for speed.
+            try:
+                v = v.astype(np.int64)
+            except OverflowError:
+                pass
         uniq = np.unique(v[m]) if m.any() else v[:0]
         codes = (np.searchsorted(uniq, v) if len(uniq)
                  else np.zeros(len(v), dtype=np.int64)).astype(np.int64) + 1

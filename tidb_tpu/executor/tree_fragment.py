@@ -43,11 +43,13 @@ import numpy as np
 
 from tidb_tpu.expression import ColumnRef, EvalContext, Expression
 from tidb_tpu.expression.aggfuncs import build_agg
+from tidb_tpu.ops.factorize import KeyBounds, bounds_sig
 from tidb_tpu.planner.physical import (PhysHashAgg, PhysHashJoin,
                                        PhysLimit, PhysProjection,
                                        PhysSelection, PhysSort,
                                        PhysTableScan, PhysTopN,
-                                       PhysWindow, PhysicalPlan)
+                                       PhysTpuFragment, PhysWindow,
+                                       PhysicalPlan)
 
 JOIN_KINDS = ("inner", "left", "right", "semi", "anti")
 JOIN_DOMAIN_CAP = 1 << 25      # max packed build-key domain for LUT joins
@@ -79,25 +81,90 @@ def _string_key_ok(l: Expression, r: Expression) -> bool:
     return isinstance(l, ColumnRef) and isinstance(r, ColumnRef)
 
 
+def nested_fragments(plan: PhysicalPlan) -> List[PhysicalPlan]:
+    """The device-rows fragments nested in this tree as join build sides,
+    in _walk_nodes order."""
+    return [n for n in _walk_nodes(plan)
+            if isinstance(n, PhysTpuFragment) and n.device_rows]
+
+
+def device_rows_ok(agg: PhysicalPlan, threshold: int) -> bool:
+    """Can this aggregate run as a fragment of its own whose merged groups
+    stay on the device, as a join's build side? It must be a device
+    fragment in its own right (chain or tree, over a scan that clears the
+    row threshold), grouped, and every output column must finalize
+    in-trace: plain keys (no dictionary to carry across), and
+    count/sum/avg/min/max over narrow non-string results."""
+    from tidb_tpu.executor.fragment import _fragment_ok
+    if not isinstance(agg, PhysHashAgg) or not agg.group_exprs or \
+            getattr(agg, "rollup", False):
+        return False
+    if any(e.ftype.kind.is_string or e.ftype.is_wide_decimal
+           for e in agg.group_exprs):
+        return False
+    for d in agg.aggs:
+        if d.distinct or d.name not in ("count", "sum", "avg", "min", "max"):
+            return False
+        if d.ftype.kind.is_string:
+            return False
+        # of the wide results only a SUM over a 1-D argument has a 1-D
+        # final (AggFunc.final_narrow, checked at run time to fit)
+        if d.ftype.is_wide_decimal and not (
+                d.name == "sum" and build_agg(d).orders_in_trace):
+            return False
+    return _fragment_ok(agg, threshold) or tree_ok(agg, threshold)
+
+
+def nest_build_aggregates(plan: PhysicalPlan, threshold: int) -> None:
+    """Inside a tree that tree_ok admitted: wrap each aggregate that is a
+    semijoin's build side (under its HAVING selection and projection) in a
+    nested device-rows fragment."""
+    for node in _walk_nodes(plan):
+        if not (isinstance(node, PhysHashJoin) and node.kind == "semi"
+                and node.build_right):
+            continue
+        above = node
+        below = node.children[1]
+        while isinstance(below, (PhysSelection, PhysProjection)):
+            above, below = below, below.children[0]
+        if isinstance(below, PhysHashAgg) and \
+                device_rows_ok(below, threshold):
+            frag = PhysTpuFragment(below)
+            frag.est_rows = below.est_rows
+            frag.device_rows = True
+            above.children[1 if above is node else 0] = frag
+
+
 def tree_ok(plan: PhysicalPlan, threshold: int) -> bool:
     """Static eligibility of a join tree (runtime checks catch the rest)."""
     from tidb_tpu.executor.fragment import _string_exprs_are_refs
 
     max_scan = [0.0]
 
-    def walk(node: PhysicalPlan, is_root: bool) -> bool:
-        from tidb_tpu.executor.fragment import _exprs_device_ok
-        if not _exprs_device_ok(_stage_exprs(node)):
+    def walk(node: PhysicalPlan, is_root: bool, build: bool = False) -> bool:
+        # `build`: inside a semijoin's build side, where an aggregate may
+        # run as a nested fragment whose groups stay on the device
+        if isinstance(node, PhysTpuFragment):
+            return node.device_rows
+        if build and isinstance(node, PhysHashAgg):
+            return device_rows_ok(node, threshold)
+        from tidb_tpu.executor.fragment import (_exprs_device_ok,
+                                                _strip_order_root)
+        # an order root over the agg sorts by refs into the agg's row,
+        # which _order_over_agg_ok judges below
+        if not (is_root and _strip_order_root(node)[0] is not None) and \
+                not _exprs_device_ok(_stage_exprs(node),
+                                     wide_refs_ok=build):
             return False
         if isinstance(node, PhysTableScan):
             max_scan[0] = max(max_scan[0], getattr(node, "est_rows", 0.0))
             return True
         if isinstance(node, PhysSelection):
-            return walk(node.children[0], False)
+            return walk(node.children[0], False, build)
         if isinstance(node, PhysProjection):
             if not _string_exprs_are_refs(node.exprs):
                 return False
-            return walk(node.children[0], False)
+            return walk(node.children[0], False, build)
         if isinstance(node, PhysHashJoin):
             if node.kind not in JOIN_KINDS or not node.equi:
                 return False
@@ -110,7 +177,8 @@ def tree_ok(plan: PhysicalPlan, threshold: int) -> bool:
                 if not _string_key_ok(le, re):
                     return False
             return walk(node.children[0], False) and \
-                walk(node.children[1], False)
+                walk(node.children[1], False,
+                     node.kind == "semi" and node.build_right)
         if is_root and isinstance(node, PhysHashAgg):
             if getattr(node, "rollup", False) and \
                     any(d.distinct for d in node.aggs):
@@ -473,7 +541,8 @@ def _bounds_list(node: PhysicalPlan, scan_bounds
     """Per output column (lo, hi) value bounds, traced from the device
     cache's per-scan-column stats; schema-length list, None = unbounded."""
     from tidb_tpu.planner.physical import PhysExchange
-    if isinstance(node, PhysTableScan):
+    if isinstance(node, (PhysTableScan, PhysTpuFragment)):
+        # (a nested fragment's rows bring the bounds of their group keys)
         b = scan_bounds.get(id(node), {})
         return [b.get(i) for i in range(len(node.schema))]
     if isinstance(node, (PhysSelection, PhysExchange)):
@@ -580,9 +649,10 @@ def plan_join_configs(root: PhysicalPlan, scan_bounds) -> List[JoinCfg]:
 
 
 def tree_agg_key_bounds(root: PhysicalPlan, scan_bounds,
-                        domain_cap: int) -> Optional[List[Tuple[int, int]]]:
-    """Perfect-hash group-key domains for an agg root over a tree, when
-    every group key is a bounded column and the packed domain is small."""
+                        domain_cap: int) -> Optional[KeyBounds]:
+    """Group-key domains for an agg root over a tree, when every group
+    key is a bounded column, and the lowering they allow
+    (ops/factorize.choose_key_bounds); None → sort factorize."""
     if not isinstance(root, PhysHashAgg) or not root.group_exprs:
         return None
     if getattr(root, "rollup", False):
@@ -596,10 +666,12 @@ def tree_agg_key_bounds(root: PhysicalPlan, scan_bounds,
             return None
         lo, hi = inp[e.index]
         domain *= (hi - lo + 2)
-        if domain > domain_cap:
-            return None
         out.append((lo, hi))
-    return out
+    from tidb_tpu.executor import device_emit
+    from tidb_tpu.executor.fragment import SLOT_ADDRESS_CAP
+    from tidb_tpu.ops.factorize import choose_key_bounds
+    return choose_key_bounds(out, domain, SLOT_ADDRESS_CAP, domain_cap,
+                             device_emit.sorted_runs_ok(root))
 
 
 # ---------------------------------------------------------------------------
@@ -610,7 +682,8 @@ def tree_agg_key_bounds(root: PhysicalPlan, scan_bounds,
 def tree_signature(plan: PhysicalPlan, caps: Dict[int, Tuple[int, int]],
                    group_cap: int, join_cfgs: Optional[Sequence[JoinCfg]] = None,
                    agg_key_bounds=None, scan_layouts=None) -> str:
-    parts = ["tree", f"gcap={group_cap}", f"akb={agg_key_bounds}"]
+    parts = ["tree", f"gcap={group_cap}",
+             f"akb={bounds_sig(agg_key_bounds)}"]
     ji = 0
     si = 0
     for node in _walk_nodes(plan):
@@ -637,6 +710,10 @@ def tree_signature(plan: PhysicalPlan, caps: Dict[int, Tuple[int, int]],
             parts.append(f"Join({node.kind}, build_right={node.build_right},"
                          f" equi={node.equi!r}, "
                          f"other={node.other_conditions!r}, cfg={cfg_s})")
+        elif isinstance(node, PhysTpuFragment):
+            parts.append(
+                f"Rows(cap={caps[id(node)]}, "
+                f"types={[str(ft) for ft in node.schema.field_types]})")
         elif isinstance(node, PhysSelection):
             parts.append(f"Sel({node.conditions!r})")
         elif isinstance(node, PhysProjection):
@@ -703,6 +780,8 @@ class TreeProgram:
         self.join_cfgs = {id(n): c for n, c in zip(joins, join_cfgs)}
         self.join_order = {id(n): i for i, n in enumerate(joins)}
         self.scan_order = _scans(plan)
+        self.nested_order = {id(n): i
+                             for i, n in enumerate(nested_fragments(plan))}
         # per-scan-slot ((col, ColLayout), ...) pairs, parallel to
         # scan_order: compressed columns decode INSIDE the trace at the
         # scan emit — raw bytes never crossed PCIe
@@ -744,7 +823,7 @@ class TreeProgram:
 
     # -- trace ---------------------------------------------------------------
     def _run(self, scan_inputs, scan_rows, prep_vals, aligned_inputs=(),
-             ranges=None):
+             ranges=None, nested=()):
         from tidb_tpu.executor.fragment import _count_trace
         _count_trace()        # once per TRACE — perf_smoke retrace meter
         self._prepared = {id(n): v
@@ -754,6 +833,8 @@ class TreeProgram:
         self._join_totals = []
         self._aligned_inputs = aligned_inputs
         self._ranges = ranges         # (start, stop) for ranged scans
+        # per nested fragment: its rows, (cols, live)
+        self._nested = nested
         self._scan_sub = {}   # id(scan) → (cols, live0): FK-aligned build
         cols, live = self._emit(self.plan, scan_inputs, scan_rows)
         return self._finish(cols, live)
@@ -833,6 +914,11 @@ class TreeProgram:
                     v, m = f.eval(ctx)
                     live = live & (v != 0) & m
             return col_list, live
+        if isinstance(node, PhysTpuFragment):
+            # a nested fragment's output rows, left in HBM by its own
+            # programs (fragment._AggRowsProgram)
+            cols, live = self._nested[self.nested_order[id(node)]]
+            return list(cols), live
         if isinstance(node, PhysSelection):
             cols, live = self._emit(node.children[0], scan_inputs, scan_rows)
             ctx = self._ctx(cols)
@@ -1116,7 +1202,10 @@ class TreeProgram:
         return out
 
     def __call__(self, scan_inputs, scan_rows, prep_vals,
-                 aligned_inputs=(), ranges=None):
+                 aligned_inputs=(), ranges=None, nested=()):
+        if nested:
+            return self.run(scan_inputs, scan_rows, prep_vals,
+                            aligned_inputs, ranges, nested)
         if ranges is None:
             return self.run(scan_inputs, scan_rows, prep_vals,
                             aligned_inputs)
@@ -1136,6 +1225,10 @@ def dictionary_flows(plan: PhysicalPlan,
         if isinstance(node, PhysTableScan):
             d = scan_dicts.get(id(node), {})
             out = [d.get(i) for i in range(len(node.schema))]
+            flows[id(node)] = out
+            return out
+        if not node.children:      # a nested device-rows fragment
+            out = [None] * len(node.schema)
             flows[id(node)] = out
             return out
         child_flows = [rec(c) for c in node.children]

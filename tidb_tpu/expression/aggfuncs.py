@@ -23,7 +23,7 @@ import numpy as np
 
 from tidb_tpu import types as T
 from tidb_tpu.errors import PlanError
-from tidb_tpu.expression import Expression
+from tidb_tpu.expression import ColumnRef, Expression
 from tidb_tpu.ops import segment as seg
 from tidb_tpu.types import FieldType, TypeKind
 
@@ -98,6 +98,20 @@ class AggFunc:
     def final(self, xp, state: Tuple):
         """→ (values, validity) arrays of length G."""
         raise NotImplementedError
+
+    def final_narrow(self, xp, state: Tuple):
+        """→ (values, validity, fits): final() as ONE 1-D array a traced
+        program can go on computing with; `fits` is a scalar, False when
+        some group's value cannot be held so (only a wide SUM's can
+        not)."""
+        v, m = self.final(xp, state)
+        return v, m, True
+
+    def order_keys(self, xp, state: Tuple) -> List[Tuple]:
+        """→ [(values, validity)] most significant first: sort keys whose
+        lexicographic order is the order of the aggregate's value (what
+        an ORDER BY over the aggregate sorts by, in-trace)."""
+        return [self.final(xp, state)]
 
 
 # ---------------------------------------------------------------------------
@@ -293,6 +307,50 @@ class SumAgg(AggFunc):
     def final(self, xp, state):
         sums, counts = self._sum_of(xp, state)
         return sums, counts > 0
+
+    @property
+    def orders_in_trace(self) -> bool:
+        """Can ORDER BY this SUM run inside a device program? A narrow sum
+        is one int64. A wide RESULT over a 1-D int64 argument (narrow, or
+        computed and wide-typed) is three limb planes, which `order_keys`
+        normalizes into two int64 keys; a wide COLUMN argument arrives as
+        its own 2-D planes and stays host-ordered."""
+        arg = self.desc.args[0]
+        return not (isinstance(arg, ColumnRef) and arg.ftype.is_wide_decimal)
+
+    def final_narrow(self, xp, state):
+        if not (self._wide and len(state) > 2):
+            return super().final_narrow(xp, state)
+        # the limb planes recombined into one int64, as long as every
+        # group's sum (scale correction included) fits one
+        (top, valid), (low, _) = self.order_keys(xp, state)
+        mul = 10 ** max(self._out_scale - self._in_scale, 0)
+        v = (top << 60) + low           # garbage unless −8 ≤ top < 8
+        ok = (top >= -8) & (top < 8)
+        if mul > 1:
+            lim = xp.int64((1 << 63) // mul)
+            ok = ok & (v >= -lim) & (v < lim)
+        fits = xp.all(xp.logical_not(valid) | ok)
+        return v * xp.int64(mul), valid, fits
+
+    def order_keys(self, xp, state):
+        if not (self._wide and len(state) > 2):
+            return [self.final(xp, state)]
+        # a 1-D input fills planes 0..2 only (_input_limbs), and per-limb
+        # sums carry no normalization (_init_wide): propagate the carries
+        # (arithmetic shifts floor, so negatives work), then (top, middle ·
+        # 2³⁰ + low) orders like the value — the scale correction of
+        # _sum_of is a positive constant and cannot reorder
+        from tidb_tpu.executor.device_cache import (WIDE_LIMB_BASE,
+                                                    WIDE_LIMB_BITS)
+        assert self.orders_in_trace
+        l0, l1, l2 = state[:3]
+        mask = xp.int64(WIDE_LIMB_BASE - 1)
+        l1 = l1 + (l0 >> WIDE_LIMB_BITS)
+        top = l2 + (l1 >> WIDE_LIMB_BITS)
+        low = ((l1 & mask) << WIDE_LIMB_BITS) | (l0 & mask)
+        valid = state[-1] > 0
+        return [(top, valid), (low, valid)]
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,7 @@ the padding/masking discipline of SURVEY §7 "dynamic shapes vs XLA".
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -136,6 +136,173 @@ def factorize(keys: Sequence[Tuple], live, cap: int):
                               num_segments=cap)
     rep = jnp.minimum(rep, n - 1).astype(jnp.int32)
     return gids, n_groups, rep
+
+
+WORD_BITS = 62      # a packed key word stays a non-negative int64
+
+
+# How a grouped aggregate's partials find their groups — the three
+# lowerings, named where they are chosen (fragment._agg_key_bounds,
+# tree_fragment.tree_agg_key_bounds) and read everywhere else:
+SLOTS = "slots"          # key bounds address the group slots directly
+RUNS = "runs"            # key bounds pack the keys into sort words: sorted runs
+FACTORIZE = "factorize"  # no bounds: per-slab sort-factorize, ladder, merge
+
+
+class KeyBounds(NamedTuple):
+    """Per-group-key (lo, hi) value bounds and what the partials do with
+    them: SLOTS (device_emit._perfect_groups; a small packed domain) or
+    RUNS (`pack_words`; the domain may be as large as it likes). An
+    aggregate with no KeyBounds (None) sort-factorizes."""
+    mode: str
+    bounds: Tuple[Tuple[int, int], ...]
+
+
+def grouping_mode(key_bounds: Optional[KeyBounds]) -> str:
+    return FACTORIZE if key_bounds is None else key_bounds.mode
+
+
+def bounds_sig(key_bounds: Optional[KeyBounds]) -> str:
+    """The bounds' part of a program signature (a SLOTS signature reads as
+    it did when the bounds were a bare list, so cached programs keep
+    their names)."""
+    if key_bounds is None:
+        return "None"
+    text = repr(list(key_bounds.bounds))
+    return text if key_bounds.mode == SLOTS else key_bounds.mode + text
+
+
+def choose_key_bounds(bounds, domain: int, slot_cap: int, domain_cap: int,
+                      runs_ok: bool) -> Optional[KeyBounds]:
+    """The one place that picks a lowering from the keys' bounds: a
+    domain over `slot_cap` slots groups by sorted runs where the
+    aggregates allow it (`runs_ok`) and every key's code fits a word;
+    else up to `domain_cap` slots are addressed directly; else None."""
+    bounds = tuple(bounds)
+    if domain > slot_cap and runs_ok and \
+            all(_bits(lo, hi) <= WORD_BITS for lo, hi in bounds):
+        return KeyBounds(RUNS, bounds)
+    return KeyBounds(SLOTS, bounds) if domain <= domain_cap else None
+
+
+def _bits(lo: int, hi: int) -> int:
+    return max(int(hi - lo + 1).bit_length(), 1)     # codes 0..hi-lo+1
+
+
+def pack_words(keys: Sequence[Tuple],
+               bounds: Sequence[Tuple[int, int]]) -> List:
+    """Keys with known (lo, hi) bounds → as few int64 words as hold them:
+    per key the code 0 for NULL and 1 + v - lo otherwise, earlier keys in
+    the higher bits, a new word where 62 bits are full. Rows with equal
+    keys, and only they, have equal words."""
+    words, word, used = [], None, 0
+    for (v, m), (lo, hi) in zip(keys, bounds):
+        bits = _bits(lo, hi)
+        code = jnp.where(jnp.asarray(m),
+                         jnp.clip(jnp.asarray(v).astype(jnp.int64), lo, hi)
+                         - (lo - 1), jnp.int64(0))
+        if word is not None and used + bits > WORD_BITS:
+            words.append(word)
+            word, used = None, 0
+        word = code if word is None else (word << bits) | code
+        used += bits
+    words.append(word)
+    return words
+
+
+def unpack_words(words: Sequence, bounds: Sequence[Tuple[int, int]],
+                 dtypes: Sequence) -> List[Tuple]:
+    """The inverse of `pack_words` → [(values, valid)]."""
+    layout, w, used = [], 0, 0
+    for lo, hi in bounds:
+        bits = _bits(lo, hi)
+        if used and used + bits > WORD_BITS:
+            w, used = w + 1, 0
+        layout.append((w, bits))
+        used += bits
+    out = [None] * len(bounds)
+    shift = {}
+    for i in reversed(range(len(bounds))):      # last key in the low bits
+        w, bits = layout[i]
+        code = (words[w] >> shift.get(w, 0)) & jnp.int64((1 << bits) - 1)
+        shift[w] = shift.get(w, 0) + bits
+        lo = bounds[i][0]
+        out[i] = ((code + (lo - 1)).astype(dtypes[i]), code != 0)
+    return out
+
+
+DEAD_WORD = (1 << 63) - 1      # above every packed word: dead rows last
+
+
+def sort_rows(words: Sequence, live, payloads: Sequence):
+    """Sort rows by their key words, dead rows last, carrying `payloads` →
+    {"words", "payloads", "ends", "n_runs"}: the sorted arrays, the
+    positions of the last row of each run of equal words (ascending, then
+    garbage) and the number of runs.
+
+    What the TPU compiler charges for a sort is its comparator, once per
+    distinct sort in a program and again in every program (52 s for an
+    int64 key, 20 s for an int32 one, 25–30 s more per operand, whatever
+    the length — PERF.md §6, PR 28). So the dead flag rides in the words
+    (DEAD_WORD) instead of a key operand of its own, the run ends come
+    from a ONE-operand uint32 sort (flag in the top bit, position below),
+    and this function is its own program, shared by every statement
+    (fragment._SortRowsProgram): no statement's programs hold a sort."""
+    n = live.shape[0]
+    words = [jnp.where(live, w, jnp.int64(DEAD_WORD)) for w in words]
+    out = lax.sort(tuple(words) + tuple(payloads), num_keys=len(words))
+    words_s = list(out[:len(words)])
+    live_s = words_s[0] != jnp.int64(DEAD_WORD)
+    differs = jnp.zeros(n - 1, dtype=bool)
+    for w in words_s:
+        differs = differs | (w[1:] != w[:-1])
+    # last live row of a run: the next row starts a run, is dead, or none
+    is_last = live_s & jnp.concatenate([differs, jnp.ones(1, dtype=bool)])
+    assert n < (1 << 31)
+    tagged = jnp.where(is_last, jnp.uint32(0), jnp.uint32(1 << 31)) | \
+        jnp.arange(n, dtype=jnp.uint32)
+    ends = (lax.sort(tagged) & jnp.uint32((1 << 31) - 1)).astype(jnp.int32)
+    return {"words": words_s, "payloads": list(out[len(words):]),
+            "ends": ends, "n_runs": is_last.sum().astype(jnp.int32)}
+
+
+def topn_select(keys: Sequence[Tuple], descs: Sequence[bool], live, k: int):
+    """Top-k row indices under ORDER BY semantics WITHOUT a sort →
+    (idx (k,), n_out): k rounds of a lexicographic arg-best over the
+    remaining rows, each key narrowing the candidates to those that tie
+    on it (the last tie goes to the lowest row index). k is an ORDER BY …
+    LIMIT's, tens to hundreds; a round is a handful of reductions over
+    the rows, and the loop compiles once — against 50 s and more for a
+    multi-key sort's comparator."""
+    n = live.shape[0]
+    vals = []
+    for (v, m), desc in zip(keys, descs):
+        v, m = jnp.asarray(v), jnp.asarray(m)
+        if v.dtype == jnp.bool_:
+            v = v.astype(jnp.int32)
+        # best = greatest of (null rank, value): ASC wants NULLs first and
+        # small values, DESC NULLs last and great ones
+        null_rank = _not(m) if not desc else m
+        vals.append((null_rank.astype(jnp.int32),
+                     jnp.where(m, v if desc else -v if v.dtype.kind == "f"
+                               else ~v, jnp.zeros_like(v))))
+    iota = jnp.arange(n, dtype=jnp.int32)
+
+    def pick(_, carry):
+        left, idx, i = carry
+        cand = left
+        for part in [p for pair in vals for p in pair]:
+            lo = jnp.iinfo(part.dtype).min if part.dtype.kind in "iu" \
+                else -jnp.inf
+            best = jnp.max(jnp.where(cand, part, lo))
+            cand = cand & (part == best)
+        row = jnp.min(jnp.where(cand, iota, n))
+        row = jnp.minimum(row, n - 1).astype(jnp.int32)
+        return (left & (iota != row), idx.at[i].set(row), i + 1)
+
+    _, idx, _ = lax.fori_loop(
+        0, k, pick, (live, jnp.zeros(k, dtype=jnp.int32), jnp.int32(0)))
+    return idx, jnp.minimum(live.sum().astype(jnp.int32), jnp.int32(k))
 
 
 def _order_operands(keys: Sequence[Tuple], descs: Sequence[bool], live):

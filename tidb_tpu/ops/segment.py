@@ -86,7 +86,74 @@ def _blocked_masked_reduce(xp, data, segment_ids, num_segments, identity,
     return reducer(parts, axis=0)
 
 
+CUMSUM_BLOCK = 1 << 20
+
+
+def _cumsum(x):
+    """jnp.cumsum over a long vector, in blocks: the TPU compiler takes
+    70 s over one int64 cumsum of 48M elements (6 s at 8M, with `while`
+    loops), 1.2 s over the same sum as block-wise cumsums plus the blocks'
+    offsets — and no loop (PERF.md §6, PR 28)."""
+    from tidb_tpu.ops.jax_env import jnp
+    n = x.shape[0]
+    if n <= CUMSUM_BLOCK or n % CUMSUM_BLOCK:
+        return jnp.cumsum(x)
+    c = jnp.cumsum(x.reshape(-1, CUMSUM_BLOCK), axis=1)
+    totals = c[:, -1]
+    return (c + (jnp.cumsum(totals) - totals)[:, None]).reshape(-1)
+
+
+class SortedRuns:
+    """Segment ids of rows that are ALREADY SORTED by group, so that a
+    group is a run of adjacent rows: the scatter-free form of the segment
+    sums, for group counts beyond the masked reduce.
+
+    A TPU scatter-add serializes: an int64 `jax.ops.segment_sum` of an
+    8M-row slab into 8M slots reads 1.1 s a state on a v5e (PERF.md §6,
+    PR 28), where a sort of the slab costs 25–30 ms and an int64 cumsum
+    10 ms. So a grouped aggregate over many groups sorts its rows by key
+    once (ops/factorize.sort_rows) and every sum is a cumsum, ONE gather
+    of `cap` elements at the runs' last rows, and an adjacent difference —
+    exact in wrapping int64 arithmetic. COUNT and AVG are sums; MIN and MAX
+    keep the scatter lowering, so an aggregate that has one does not take
+    this path.
+
+    `ends` are the positions of the runs' last rows, ascending (garbage
+    beyond `n_runs`); slot g of a result is run g in sorted order. With
+    more runs than `cap` the results are invalid and `n_runs` says so (the
+    caller's capacity ladder retries)."""
+
+    def __init__(self, ends, n_runs, cap: int):
+        from tidb_tpu.ops.jax_env import jnp
+        n = ends.shape[0]
+        if cap > n:
+            ends = jnp.concatenate(
+                [ends, jnp.full(cap - n, n - 1, dtype=ends.dtype)])
+        self.cap = cap
+        self.ends = ends[:cap]
+        self.n_runs = n_runs
+        self.slot_live = jnp.arange(cap, dtype=jnp.int32) < n_runs
+
+    def at_ends(self, x, fill=0):
+        """x at each run's last row → (cap,), `fill` in the dead slots."""
+        from tidb_tpu.ops.jax_env import jnp
+        return jnp.where(self.slot_live, jnp.take(x, self.ends),
+                         jnp.asarray(fill, dtype=x.dtype))
+
+    def sum(self, data):
+        from tidb_tpu.ops.jax_env import jnp
+        # booleans and int32 scan in int32 (there are < 2³¹ rows)
+        acc = jnp.int32 if data.dtype in (jnp.bool_, jnp.int32) \
+            else data.dtype
+        ends = self.at_ends(_cumsum(data.astype(acc)))
+        prev = jnp.concatenate([jnp.zeros(1, dtype=ends.dtype), ends[:-1]])
+        return jnp.where(self.slot_live, ends - prev, 0) \
+            .astype(jnp.int64 if acc == jnp.int32 else data.dtype)
+
+
 def segment_sum(xp, data, segment_ids, num_segments: int):
+    if isinstance(segment_ids, SortedRuns):
+        return segment_ids.sum(data)
     if _is_np(xp):
         out = np.zeros(num_segments, dtype=data.dtype)
         np.add.at(out, segment_ids, data)

@@ -174,6 +174,43 @@ def _run_uncorrelated(builder, inner: LogicalPlan):
     return run_plan(inner)
 
 
+def _out_ref(plan: LogicalPlan, i: int) -> ColumnRef:
+    c = plan.schema.columns[i]
+    return ColumnRef(i, c.ftype, c.name)
+
+
+def _grouped_keys_over_large_scan(builder, inner: LogicalPlan) -> bool:
+    """Is `inner` SELECT k … GROUP BY … [HAVING …] with k one of its group
+    keys, over a base table whose live rows clear the device row
+    threshold? Such a subquery is worth a semijoin on the device; smaller
+    ones stay on the eager path (run once, folded into constants)."""
+    ctx = getattr(builder, "ctx", None)
+    if ctx is None or not getattr(ctx, "use_tpu", False) \
+            or len(inner.schema) != 1:
+        return False
+    node = inner
+    if not (isinstance(node, LogicalProjection)
+            and isinstance(node.exprs[0], ColumnRef)):
+        return False
+    col = node.exprs[0].index
+    node = node.children[0]
+    while isinstance(node, LogicalSelection):
+        node = node.children[0]
+    if not (isinstance(node, LogicalAggregation)
+            and col < len(node.group_exprs)):
+        return False
+    threshold = int(getattr(ctx, "tpu_row_threshold", 0))
+
+    def scans(p):
+        if isinstance(p, LogicalDataSource):
+            yield p
+        for c in p.children:
+            yield from scans(c)
+
+    return any(ctx.table_row_count(d.table.id) >= threshold
+               for d in scans(node))
+
+
 def rewrite_exists(builder, outer: LogicalPlan, node: ast.ExistsExpr
                    ) -> Optional[Tuple[LogicalPlan, List[Expression]]]:
     """EXISTS/NOT EXISTS conjunct → semi/anti join; uncorrelated
@@ -209,6 +246,13 @@ def rewrite_in(builder, outer: LogicalPlan, node: ast.InExpr,
     lifted correlations); NOT IN gets the null-aware condition."""
     inner = builder.build_subquery_plan(node.subquery.select, outer.schema)
     if not plan_is_correlated(inner):
+        if not node.negated and _grouped_keys_over_large_scan(builder, inner):
+            # x IN (SELECT k … GROUP BY k [HAVING …]) over a device-sized
+            # scan: a semijoin whose build side is the aggregate (unique
+            # on k). Nothing runs at plan time; the device fragments keep
+            # the qualifying keys in HBM and the outer tree probes them
+            return (LogicalJoin("semi", outer, inner,
+                                [(x, _out_ref(inner, 0))], []), [])
         ran = _run_uncorrelated(builder, inner)
         if ran is None:
             return None                  # no evaluator: eager path

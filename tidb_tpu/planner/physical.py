@@ -407,13 +407,18 @@ class PhysTpuFragment(PhysicalPlan):
         super().__init__(root.schema)
         self.root = root
         self.dist = 0        # >1 → compiled as an n-shard shard_map program
+        # an aggregate nested in an enclosing fragment's join tree as a
+        # join's build side: its merged groups stay in HBM and the
+        # enclosing program reads them (no host crossing)
+        self.device_rows = False
 
     def describe(self):
         return f"fused:[{self.root.name}]"
 
     def explain_lines(self, indent: int = 0):
         info = "engine:tpu" + (f", shards:{self.dist}" if self.dist > 1
-                               else "")
+                               else "") + \
+            (", rows:device" if self.device_rows else "")
         rows = [("  " * indent + ("└─" if indent else "") + "TpuFragment",
                  f"{self.est_rows:.0f}", info)]
         rows.extend(self.root.explain_lines(indent + 1))
@@ -618,9 +623,36 @@ def _table_rows(table, ctx) -> int:
 # ---------------------------------------------------------------------------
 
 
+def order_below_projection(plan: PhysicalPlan) -> PhysicalPlan:
+    """Sort/TopN(Projection(HashAgg)) → Projection(Sort/TopN(HashAgg))
+    when the projection only permutes or drops columns of the aggregate
+    (the select list in another order than keys-then-aggregates) and every
+    sort key is such a column. The order root then sits DIRECTLY over the
+    aggregate — the one shape the device finalize fuses — and the
+    projection runs over the rows the order root lets through."""
+    plan.children = [order_below_projection(c) for c in plan.children]
+    if not isinstance(plan, (PhysSort, PhysTopN)):
+        return plan
+    proj = plan.children[0]
+    if not (isinstance(proj, PhysProjection)
+            and isinstance(proj.children[0], PhysHashAgg)
+            and all(isinstance(e, ColumnRef) for e in proj.exprs)
+            and all(isinstance(e, ColumnRef) for e in plan.by)):
+        return plan
+    agg = proj.children[0]
+    by = [proj.exprs[e.index] for e in plan.by]
+    order = PhysTopN(by, plan.descs, plan.offset, plan.count, agg) \
+        if isinstance(plan, PhysTopN) else PhysSort(by, plan.descs, agg)
+    order.est_rows = plan.est_rows
+    proj.children = [order]
+    proj.est_rows = plan.est_rows
+    return proj
+
+
 def physical_optimize(plan: LogicalPlan, ctx) -> PhysicalPlan:
     phys = _to_physical(plan, ctx)
     phys.est_rows = estimate(phys, ctx)
+    phys = order_below_projection(phys)
     use_tpu = bool(getattr(ctx, "use_tpu", False))
     if use_tpu:
         from tidb_tpu.executor.fragment import extract_fragments
@@ -639,8 +671,12 @@ def _distribute_fragments(plan: PhysicalPlan, n_shards: int,
     insert exchange boundaries (the fragmentation pass) and mark them for
     shard_map compilation."""
     if isinstance(plan, PhysTpuFragment):
-        from tidb_tpu.executor.tree_fragment import dist_ok
-        if dist_ok(plan.root, threshold):
+        from tidb_tpu.executor.tree_fragment import (dist_ok,
+                                                     nested_fragments)
+        # a nested device-rows fragment lives on ONE device: its
+        # enclosing tree stays single-device too
+        if dist_ok(plan.root, threshold) and \
+                not nested_fragments(plan.root):
             plan.root = insert_exchanges(plan.root, n_shards)
             plan.dist = n_shards
         return

@@ -330,6 +330,35 @@ def _classify_admission(s, sql: str, from_prepared: bool):
     return "batch", REGISTRY.digest_cost(sql)
 
 
+def _note_host_rows(exec_root) -> None:
+    """What the HOST executors of a finished statement consumed: for each
+    executor above or outside a device fragment, the rows its children
+    handed it (a leaf: the rows it read) — counter
+    `tidb_tpu_host_rows_total{operator=}` and, while the recorder is on, a
+    zero-length `exec.<operator>` mark (lane `exec`, tags `rows` and the
+    operator's own `wall_ms`) under `executor.run`. A statement whose
+    joins, grouping, ordering and limit ran on the device leaves the host
+    its result rows."""
+    from tidb_tpu.executor.fragment import TpuFragmentExec
+    from tidb_tpu.util.observability import REGISTRY
+
+    def walk(ex):
+        if isinstance(ex, TpuFragmentExec):
+            return
+        kids = list(getattr(ex, "children", ()))
+        rows = sum(c.stats.rows for c in kids) if kids else ex.stats.rows
+        own_ns = ex.stats.wall_ns - sum(c.stats.wall_ns for c in kids)
+        op = type(ex).__name__.replace("Exec", "")
+        REGISTRY.inc("tidb_tpu_host_rows_total", {"operator": op}, rows)
+        timeline.record(f"exec.{op}", "exec", ts_us=timeline.now_us(),
+                        args={"rows": int(rows),
+                              "wall_ms": round(max(own_ns, 0) / 1e6, 3)})
+        for c in kids:
+            walk(c)
+
+    walk(exec_root)
+
+
 def _operator_spans(tr, exec_root) -> None:
     """Per-operator runtime stats rendered as a NESTED span tree (the
     executor Next-wrapper spans of executor.go:278); durations come from
@@ -1131,11 +1160,16 @@ class Session:
                 self._plan_cache.move_to_end(key)
                 from tidb_tpu.util.observability import REGISTRY
                 REGISTRY.inc("tidb_tpu_plan_cache_hits_total")
-                timeline.tag(cache="hit")
+                # (a plan that ran a subquery while it was built is never
+                # cached, so a hit ran none)
+                timeline.tag(cache="hit", eager_subqueries=0)
                 return hit
         timeline.tag(cache="miss" if key is not None else "uncacheable")
         before = self._subq_execs
         plan = optimize(stmt, self.engine.catalog.info_schema, ctx)
+        # subqueries executed at PLAN time and folded into the plan as
+        # constants: their rows crossed to the host before the statement ran
+        timeline.tag(eager_subqueries=self._subq_execs - before)
         if key is not None and self._subq_execs == before \
                 and not self._prepare_probe:
             self._plan_cache[key] = plan
@@ -1307,12 +1341,18 @@ class Session:
         with maybe_span(tr, "planner.optimize"):
             plan = self._plan(stmt)
         self.last_plan = plan
+        from tidb_tpu.executor.fragment import _var_bool, check_strict_plan
+        pctx = _PlanContext(self)
+        if _var_bool(self.vars.get("tidb_tpu_strict", False)) and \
+                pctx.use_tpu:
+            check_strict_plan(plan, pctx.tpu_row_threshold)
         with maybe_span(tr, "executor.build"):
             exec_root = build(plan)
         with maybe_span(tr, "executor.run"):
             ctx = self._exec_ctx()
             ctx.tracer = tr
             chunks = run_to_completion(exec_root, ctx)
+            _note_host_rows(exec_root)
         if tr is not None:
             _operator_spans(tr, exec_root)
         if want_root:

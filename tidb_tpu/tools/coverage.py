@@ -219,9 +219,9 @@ QUERIES: Dict[str, str] = {
 # taxonomy code the fragment layer reports — the ratchet allows these to
 # stay fallback but fails if a FUSED query joins them
 EXPECTED_FALLBACK: Dict[str, str] = {
-    # IN over a grouped-HAVING subquery decorrelates to a semijoin whose
-    # build side is an aggregation — interior aggs aren't tree-fusable
-    "q18": "shape",
+    # (q18 left this list in PR 28: its IN over a grouped-HAVING subquery
+    # plans as a semijoin whose build side is a nested device-rows
+    # fragment — tree_fragment.nest_build_aggregates)
     # the SUBSTRING(c_phone, ...) group key / IN-list is a COMPUTED
     # string: no dictionary to prepare codes against, host executes
     "q22": "shape",

@@ -129,9 +129,14 @@ class CapacityLadder:
         guard (KILL/deadline/OOM observed BETWEEN attempts — inside the
         sliced backoff sleep), and raises BackoffExhausted (chained to
         `err`) once a recompile-storm spends the budget."""
-        failpoint.inject("device-recompile")
-        self.stats.recompiles += 1
-        self.bo.backoff(err)
+        from tidb_tpu.util import timeline
+        from tidb_tpu.util.observability import REGISTRY
+        REGISTRY.inc("tidb_tpu_ladder_retries_total", {"rung": kind})
+        with timeline.span("ladder.retry", "frag", rung=kind,
+                           need=int(getattr(err, "need", 0) or 0)):
+            failpoint.inject("device-recompile")
+            self.stats.recompiles += 1
+            self.bo.backoff(err)
 
     def resize(self, kind: str, current: int, need: Optional[int] = None,
                max_cap: Optional[int] = None, factor: int = 4,

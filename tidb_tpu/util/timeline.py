@@ -44,7 +44,7 @@ Lanes, from packet to packet:
            executor.build
   exec     executor.run (the TRACE site)
   frag     device.fragment (the TRACE site, spec=hit|miss; over an
-           aggregate grouping=global|bounds|factorize, gcap=<slots>): one
+           aggregate grouping=global|bounds|runs|factorize, gcap=<slots>): one
            per device fragment; its SELF time is fragment set-up — signature /
            specialization lookup, prune_slabs, argument assembly,
            everything between launches — beside its children frag.open

@@ -20,7 +20,8 @@ import sys
 
 UNIT_SUFFIXES = ("_total", "_seconds", "_bytes")
 LABEL_VOCAB = {"stmt", "engine", "table", "site", "device", "phase",
-               "stage", "reason", "class", "le", "grouping"}
+               "stage", "reason", "class", "le", "grouping", "operator",
+               "rung"}
 PREFIX = "tidb_tpu_"
 
 
