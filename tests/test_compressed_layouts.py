@@ -140,6 +140,84 @@ def test_pack_roundtrip_every_width(width, hi):
     assert np.array_equal(dv[:500][valid], vals[valid])
 
 
+def _delta_case(width, dtype, cap, base, seed):
+    """A sorted fully-valid column of `dtype` whose widest gap needs
+    `width` bits, starting at `base`, in a slab of `cap` rows with a padded
+    tail where the slab has room for one → (layout, vals, n)."""
+    info = np.iinfo(dtype)
+    n = cap - min(cap // 3, 5)
+    hi = (1 << width) - 1
+    budget = info.max - base
+    rng = np.random.default_rng(seed)
+    d = rng.integers(0, hi, size=n, endpoint=True).astype(np.uint64)
+    d[0] = 0
+    if n > 1:
+        d[1] = min(hi, budget)              # the width's widest gap, if it fits
+    over = np.cumsum(d.astype(object)) > budget     # exact: Python integers
+    d[over] = 0
+    vals = np.zeros(cap, dtype=dtype)
+    vals[:n] = (base + np.cumsum(d.astype(object))).astype(dtype)
+    return ColLayout("delta", width, 0, np.dtype(dtype).name), vals, n
+
+
+def _delta_decodes(lay, vals, n, cap):
+    """pack → (jnp decode, numpy decode), each (values, validity)."""
+    from tidb_tpu.ops.jax_env import jnp
+    slab = compress.pack_slab(lay, vals, np.arange(cap) < n)
+    dv, dm = compress.decode_slab(lay, slab, cap, np)
+    jv, jm = compress.decode_slab(lay, tuple(jnp.asarray(a) for a in slab),
+                                  cap, jnp)
+    return (np.asarray(jv), np.asarray(jm)), (dv, dm)
+
+
+_B = compress.DELTA_BLOCK
+
+
+@pytest.mark.parametrize("base", ["zero", "negative", "dtype-min"])
+@pytest.mark.parametrize("cap", [_B, 8 * _B, 3 * _B + 17, 1],
+                         ids=["one-block", "many-blocks", "ragged", "1-row"])
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("width", [1, 2, 4, 8, 16, 32])
+def test_delta_roundtrip_traced_decode_equals_numpy_and_source(
+        width, dtype, cap, base):
+    """The traced (jnp) decode of a `delta` column — a narrow scan inside
+    blocks, the blocks' bases added in the logical width — against the
+    numpy oracle (one cumsum) against the source column: byte-exact, the
+    zero-padded tail included (the scan holds the last value there; the
+    packed validity masks it)."""
+    b = {"zero": 0, "negative": -12345,
+         "dtype-min": int(np.iinfo(dtype).min) + 3}[base]
+    lay, vals, n = _delta_case(width, dtype, cap, b, seed=width + cap)
+    compress.validate(lay)
+    (jv, jm), (dv, dm) = _delta_decodes(lay, vals, n, cap)
+    assert jv.dtype == dv.dtype == np.dtype(dtype)
+    assert jv.tobytes() == dv.tobytes(), "traced decode != numpy decode"
+    assert np.array_equal(dv[:n], vals[:n]), "numpy decode != source"
+    assert np.array_equal(jm, dm) and dm[:n].all() and not dm[n:].any()
+    assert (dv[n:] == vals[n - 1]).all(), "the tail holds the last value"
+    want = "plain" if cap % _B or cap <= _B else \
+        "int32" if width <= 16 or dtype is np.int32 else "wide"
+    assert compress.delta_scan(lay, cap) == want
+
+
+@pytest.mark.parametrize("span,scan", [(1 << 15, "int32"), (1 << 16, "wide")])
+def test_delta_scan_type_flips_at_the_int32_overflow_edge(monkeypatch, span,
+                                                          scan):
+    """Width 16 in an int64 column: a span of 32768 rows sums to at most
+    32768 · 65535 = 2^31 − 32768 (fits int32), one of 65536 rows to
+    2^32 − 65536 (does not) — every gap at the maximum, so a scan in the
+    wrong type would wrap."""
+    monkeypatch.setattr(compress, "DELTA_BLOCK", span)
+    lay = ColLayout("delta", 16, 0, "int64")
+    assert (span * 65535 < 1 << 31) == (scan == "int32")
+    cap = 4 * span
+    assert compress.delta_scan(lay, cap) == scan
+    vals = -(1 << 40) + 65535 * np.arange(cap, dtype=np.int64)
+    (jv, _jm), (dv, _dm) = _delta_decodes(lay, vals, cap, cap)
+    assert np.array_equal(dv, vals)
+    assert jv.tobytes() == dv.tobytes()
+
+
 def test_validate_rejects_corrupt_descriptors():
     good = ColLayout("pack", 8, 0, "int64")
     compress.validate(good)                         # sanity: passes
@@ -243,6 +321,56 @@ def test_edge_cases_byte_exact_chain_on_off_oracle():
     assert off == oracle
     ent2 = _cache_entry(eng, "ec")
     assert not any(l is not None for l in ent2.layouts.values())
+
+
+def _delta_programs(scan: str) -> float:
+    from tidb_tpu.util.observability import REGISTRY
+    return REGISTRY.counters.get(
+        ("tidb_tpu_delta_decode_programs_total", (("scan", scan),)), 0)
+
+
+def test_delta_decode_counts_its_scan_once_per_traced_program(tmp_path):
+    """A Q6-shaped statement over a sorted date column (int32, gaps of a
+    day at most: `delta` width 1) bumps
+    `tidb_tpu_delta_decode_programs_total{scan="int32"}` once when its
+    program is traced — not per slab, not per warm run — and the launch
+    that traced it carries `delta_scan=int32`; a sorted BIGINT whose gaps
+    need width 32 bumps "wide"."""
+    from tidb_tpu.util import timeline
+    eng = Engine()
+    s = eng.new_session()
+    s.execute("CREATE TABLE li (ship DATE, ts BIGINT, v BIGINT)")
+    n = 5000
+    s.execute("INSERT INTO li VALUES " + ",".join(
+        f"(DATE_ADD('1994-01-01', INTERVAL {i // 9} DAY), "
+        f"{-(1 << 45) + i * (1 << 31) + (i % 7)}, {i % 11})"
+        for i in range(n)))
+    q6 = ("SELECT SUM(v), COUNT(*) FROM li WHERE ship >= '1994-03-01' "
+          "AND ship < '1995-03-01'")
+    before = {k: _delta_programs(k) for k in ("int32", "wide", "plain")}
+    timeline.start_global(str(tmp_path))
+    try:
+        cold = run_device(s, q6, max_slab=2048)
+    finally:
+        timeline.stop_global()
+    ent = _cache_entry(eng, "li")
+    assert ent.slab_cap == 2048 and len(ent.dev[0]) == 3
+    assert ent.layouts[0].sig() == "delta:w1:r0:c0:int32"
+    assert _delta_programs("int32") - before["int32"] == 1
+    tagged = [e for e in timeline.last_events()
+              if e["ph"] == "X" and "delta_scan" in e["args"]]
+    assert [(e["cat"], e["args"]["delta_scan"]) for e in tagged] == \
+        [("launch", "int32")]
+    assert run_device(s, q6, max_slab=2048) == cold      # warm: no trace
+    assert _delta_programs("int32") - before["int32"] == 1
+    wide = f"SELECT COUNT(*), MAX(ts) FROM li WHERE ts >= {5 - (1 << 45)}"
+    assert run_device(s, wide, max_slab=2048) == s.query(wide).rows == \
+        [(n - 1, -(1 << 45) + (n - 1) * (1 << 31) + (n - 1) % 7)]
+    assert ent.layouts[1].sig() == "delta:w32:r0:c0:int64"
+    assert _delta_programs("wide") - before["wide"] == 1
+    assert _delta_programs("int32") - before["int32"] == 1
+    assert _delta_programs("plain") == before["plain"]
+    eng.close()
 
 
 def test_edge_cases_byte_exact_staged_dist():

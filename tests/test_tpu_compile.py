@@ -292,6 +292,45 @@ def test_global_aggregate_partial_has_no_slot_axis(recorded, one_chip,
         assert agg_slot_axes(text, 12) > 0
 
 
+def test_delta_decode_has_no_slab_wide_scan(recorded, one_chip, monkeypatch):
+    """Q1's and Q6's partials read `l_shipdate`, a `delta` column, over a
+    full 8M-row slab: its decode scans inside blocks of `DELTA_BLOCK` rows
+    by shifted adds, so the optimized program holds no `reduce-window`
+    (what a `cumsum` is to the TPU compiler) over as many elements as a
+    slab has rows, in any width, and no loop. The one 64-bit cumsum over
+    the slab was `%reduce-window.1`: 8.8 ms of every launched slab, two
+    thirds of the device's seconds (PERF.md §6, PR 29). What is left scans
+    the blocks' totals."""
+    import re
+    from tidb_tpu.chunk import compress
+    from tidb_tpu.executor import fragment
+    calls = [c for c in recorded if c[1] == "_partial"]
+    compiled = _compile_calls(calls, fragment._FragmentProgram, one_chip,
+                              monkeypatch)
+    slab = fragment.DEFAULT_MAX_SLAB_ROWS
+    checked = 0
+    for owner, _label, c in compiled:
+        deltas = [lay for lay in owner.layouts.values()
+                  if lay is not None and lay.kind == "delta"]
+        if not deltas:
+            continue
+        assert {compress.delta_scan(lay, slab) for lay in deltas} == {"int32"}
+        text = c.as_text()
+        assert re.search(r'op_name="[^"]*/decode/', text), \
+            "the optimized HLO names no decode stage: this guard reads nothing"
+        assert not re.search(r"\bwhile\(", text)
+        for line in text.splitlines():
+            m = re.search(r" = (.*?) reduce-window\(", line)
+            if m is None:
+                continue
+            sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+                     for dims in re.findall(r"\[([\d,]+)\]", m.group(1))]
+            assert max(sizes, default=0) <= slab // compress.DELTA_BLOCK + 1, \
+                line[:200]
+        checked += 1
+    assert checked >= 2, "Q1's and Q6's partials both read l_shipdate"
+
+
 def test_fused_pipeline_compiles_at_a_full_probe_slab(recorded, one_chip,
                                                       monkeypatch):
     """Q3: scan → filter → FK-aligned join probe → partial aggregate over

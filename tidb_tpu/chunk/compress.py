@@ -12,9 +12,11 @@ decision is the highest-leverage lever for scan-bound analytics):
     code width, with ONE shared dictionary values array per column;
   * delta — monotonically non-decreasing, fully-valid int columns
     (sorted PKs, event timestamps) store successive differences packed
-    at the max-gap width, with a per-slab base value; decode is one
-    cumulative sum. Constant runs pack at the zero-diff width, so delta
-    subsumes run-length encoding for sorted data.
+    at the max-gap width, with a per-slab base value; decode is a
+    cumulative sum — on the host one `np.cumsum`, in a trace a narrow
+    scan inside blocks of `DELTA_BLOCK` rows plus the blocks' bases
+    (`delta_scan`, `_delta_values`). Constant runs pack at the zero-diff
+    width, so delta subsumes run-length encoding for sorted data.
 
 The layout decision is workload-adaptive: `choose_layout` accepts
 hints distilled from the Registry's per-digest profiles (group-by
@@ -51,6 +53,13 @@ WORD_BITS = 32
 #: dictionary layout only below this cardinality (TiFlash's low-card
 #: dictionary threshold is the same order of magnitude)
 DICT_CARD_CAP = 4096
+#: rows per block of the traced `delta` decode (`_delta_values`). Chosen on
+#: the chip (PERF.md §6, PR 29): log-step scans of an 8M-row slab read
+#: 0.23–0.37 ms at 128 and 0.29–0.40 ms at 1024, but 128 leaves 65,536
+#: block totals whose 64-bit scan alone costs the TPU compiler 4–7 s a
+#: program, 1024 leaves 8192 (1.3–2.7 s). Every slab capacity of the
+#: device cache is a power of two ≥ 1024, so a block always divides it.
+DELTA_BLOCK = 1024
 
 
 @dataclass(frozen=True)
@@ -256,8 +265,57 @@ def decode_slab(layout: ColLayout, slab, cap: int, xp):
         return xp.take(xp.asarray(slab[2]), idx).astype(dt), mask
     if layout.kind == "delta":
         base = xp.asarray(slab[2]).astype(np.int64)[0]
-        return (base + xp.cumsum(codes.astype(np.int64))).astype(dt), mask
+        return _delta_values(layout, codes, base, cap, xp), mask
     return (codes.astype(np.int64) + np.int64(layout.ref)).astype(dt), mask
+
+
+def delta_scan(layout: ColLayout, cap: int) -> str:
+    """Which scan the TRACED decode of a `delta` column takes, from what is
+    static in the trace (the layout and the slab's capacity):
+
+      * "int32" — inside blocks of `DELTA_BLOCK` rows in 32 bits: a block's
+        sum cannot pass 2^31 (`DELTA_BLOCK · (2^width − 1)`; every width
+        up to 16), or the logical dtype has at most 32 bits itself, where
+        wrapping sums are exact;
+      * "wide" — blocked the same way, in 64 bits (an int64 column whose
+        gaps need width 32);
+      * "plain" — one 64-bit cumsum over the slab, as on the host: a
+        capacity that a block does not divide, or of one block at most
+        (toy slabs; the device cache's capacities are powers of two)."""
+    if cap <= DELTA_BLOCK or cap % DELTA_BLOCK:
+        return "plain"
+    if layout.np_dtype.itemsize <= 4 or \
+            DELTA_BLOCK * ((1 << layout.width) - 1) < 1 << 31:
+        return "int32"
+    return "wide"
+
+
+def _delta_values(layout: ColLayout, codes, base, cap: int, xp):
+    """`base + cumsum(codes)` in the logical dtype, exactly. A 64-bit
+    cumsum over an 8M-row slab is an emulated-64-bit `reduce-window` to the
+    TPU compiler: 9 ms a launched slab, two thirds of all device seconds of
+    the benchmark's scans (PERF.md §6, PR 29). So a trace scans inside
+    blocks of `DELTA_BLOCK` rows in the narrowest type that is exact there
+    — log2(block) shifted adds, no reduce-window, no loop — scans the
+    blocks' totals (cap / block of them) in the logical width, and widens
+    each row once: 0.37–0.43 ms (1.5 ms where the blocks need 64 bits).
+    The zero-padded tail holds the last value either way."""
+    dt = layout.np_dtype
+    scan = "plain" if xp is np else delta_scan(layout, cap)
+    if scan == "plain":
+        return (base + xp.cumsum(codes.astype(np.int64))).astype(dt)
+    s = codes.astype(np.int32 if scan == "int32" else np.int64) \
+        .reshape(-1, DELTA_BLOCK)
+    k = 1
+    while k < DELTA_BLOCK:
+        s = s + xp.pad(s[:, :-k], ((0, 0), (k, 0)))
+        k *= 2
+    # values of at most 32 bits never need 64: sums that wrap are exact
+    # modulo 2^32, and the true value fits the dtype
+    top = np.int64 if dt.itemsize == 8 else np.int32
+    totals = s[:, -1].astype(top)
+    block_base = base.astype(top) + (xp.cumsum(totals) - totals)
+    return (block_base[:, None] + s.astype(top)).reshape(-1).astype(dt)
 
 
 def raw_slab_bytes(layout: ColLayout, cap: int) -> int:

@@ -64,11 +64,23 @@ def emit_decode(layout, slab, cap: int):
     clones the unpack into each consuming fusion — one per aggregate —
     and the TPU compiler then took six minutes over Q1's partial at an
     8M-row slab (13 s with the barrier), for the price of one HBM round
-    trip of the decoded column per slab."""
+    trip of the decoded column per slab.
+
+    A `delta` column says which scan its decode took
+    (`compress.delta_scan`: int32 | wide | plain), once per column and
+    traced program since this runs while tracing: the always-on counter
+    `tidb_tpu_delta_decode_programs_total{scan=}` and the tag `delta_scan`
+    on the span that covers the trace (the program's first `launch`)."""
     from tidb_tpu.chunk import compress
     from tidb_tpu.ops.jax_env import jnp, lax
-    return lax.optimization_barrier(
-        compress.decode_slab(layout, slab, cap, jnp))
+    decoded = compress.decode_slab(layout, slab, cap, jnp)  # validates
+    if layout.kind == "delta":
+        from tidb_tpu.util import timeline
+        from tidb_tpu.util.observability import REGISTRY
+        scan = compress.delta_scan(layout, cap)
+        REGISTRY.inc("tidb_tpu_delta_decode_programs_total", {"scan": scan})
+        timeline.tag(delta_scan=scan)
+    return lax.optimization_barrier(decoded)
 
 
 @_staged("sort")

@@ -55,7 +55,9 @@ Lanes, from packet to packet:
            backend-compile durations, waits for another request's build
   encode   first touch: encode.materialize / .layout / .dict / .pack
   upload   host -> device transfers
-  launch   one per jitted call, program=<kind>_<sig8>, slab=<i>
+  launch   one per jitted call, program=<kind>_<sig8>, slab=<i>; the call
+           that traces a program over a `delta` column also carries
+           delta_scan=int32|wide|plain (device_emit.emit_decode)
   drain    block_until_ready waits
   fetch    device -> host result transfers
   decode   host-side dictionary decode / Chunk assembly
