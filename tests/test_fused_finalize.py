@@ -6,14 +6,15 @@ Pinned invariants:
 
 * an ORDER BY / TopN root over a HashAgg runs as ONE fused finalize
   launch (merge → finalize exprs → sort/topn → gather), byte-exact
-  against the host-ordered path (`tidb_tpu_fused_finalize='off'`), the
-  mega-slab tree path (`tidb_tpu_fused_pipeline='off'`) and the CPU
-  volcano — string ci keys, wide-decimal outputs and MySQL NULL
-  ordering (NULLs first ASC, last DESC) included;
+  against the mega-slab tree path (`tidb_tpu_fused_pipeline='off'`,
+  which orders on the host) and the CPU volcano — string ci keys,
+  wide-decimal outputs and MySQL NULL ordering (NULLs first ASC, last
+  DESC) included;
 * single-arg DISTINCT aggs no longer exclude a query from the fused
   pipeline: the (group, value) pair sets dedup on device, and a pair
   set clipped by `tidb_tpu_distinct_pair_cap` resizes through the
-  resumable 'pairs' ladder rung — never silently truncating;
+  resumable 'pairs' ladder rung — never silently truncating
+  (tests/test_overlap_runtime.py, with the other rungs);
 * the warm whole-query launch count is slabs + 1 (slab partials + the
   one fused finalize that replaced the root merge);
 * EXPLAIN ANALYZE `launches=`/`spec_hits=` and statements_summary's
@@ -29,11 +30,12 @@ import re
 
 import pytest
 
-from tidb_tpu.executor import build, fragment as frag_mod, run_to_completion
+from tidb_tpu.executor import (build, device_cache, fragment as frag_mod,
+                               run_to_completion)
 from tidb_tpu.executor.fragment import TpuFragmentExec
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine
-from tidb_tpu.util import failpoint
+from tidb_tpu.util import failpoint, timeline
 
 
 def agg_fixture(n=3000):
@@ -120,9 +122,7 @@ def test_fused_finalize_byte_exact(sql):
     _, s = agg_fixture()
     cpu = s.query(sql).rows
     fused = device_rows(s, sql)
-    host_ord = device_rows(s, sql, {"tidb_tpu_fused_finalize": "off"})
     mega = device_rows(s, sql, {"tidb_tpu_fused_pipeline": "off"})
-    assert fused == host_ord, "fused finalize vs host-order mismatch"
     assert fused == mega, "fused finalize vs mega-slab mismatch"
     assert fused == cpu, "fused finalize vs CPU volcano mismatch"
 
@@ -168,27 +168,6 @@ def test_distinct_join_tree_fused():
            "JOIN dm d ON f.b = d.id GROUP BY d.name ORDER BY d.name")
     cpu = s.query(sql).rows
     assert device_rows(s, sql) == cpu
-
-
-def test_distinct_pair_cap_overflow_resumable():
-    """A pair cap below the per-slab distinct pair count must clip, be
-    DETECTED (true counts travel with the clipped sets), resize through
-    the 'pairs' ladder rung to the exact need, re-run the clipped slabs
-    and still answer the oracle."""
-    _, s = agg_fixture()
-    cpu = s.query(DISTINCT_CHAIN).rows
-    s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
-                   "tidb_tpu_max_slab_rows": 1024,
-                   "tidb_tpu_distinct_pair_cap": 64})
-    try:
-        assert s.query(DISTINCT_CHAIN).rows == cpu
-        esc = s.last_guard.escalation
-        assert esc.exact_resizes >= 1, esc.summary()
-        assert esc.slabs_rerun >= 1, esc.summary()
-    finally:
-        for k in ("tidb_tpu_engine", "tidb_tpu_row_threshold",
-                  "tidb_tpu_max_slab_rows", "tidb_tpu_distinct_pair_cap"):
-            s.vars.pop(k, None)
 
 
 def test_finalize_fault_warned_cpu_fallback():
@@ -237,6 +216,55 @@ def test_explain_analyze_counts_finalize_as_one_launch():
         for k in ("tidb_tpu_engine", "tidb_tpu_row_threshold",
                   "tidb_tpu_max_slab_rows"):
             s.vars.pop(k, None)
+
+
+@pytest.mark.parametrize("name", ["tidb_tpu_fused_finalize",
+                                  "tidb_tpu_specialization_cache",
+                                  "tidb_tpu_aligned_join"])
+def test_a_removed_gate_is_an_unknown_variable(name):
+    """The three gates are gone: SET stores the name as it stores any
+    unknown variable, and an ORDER BY over a join's aggregate still runs
+    slabs + 1 programs, specialized, its join aligned."""
+    eng = Engine()
+    s = eng.new_session()
+    s.execute("CREATE TABLE gd (id INT PRIMARY KEY, name VARCHAR(16))")
+    s.execute("INSERT INTO gd VALUES " + ",".join(
+        f"({i}, 'name{i:02d}')" for i in range(8)))
+    s.execute("CREATE TABLE gf (b INT, v BIGINT)")
+    s.execute("INSERT INTO gf VALUES " + ",".join(
+        f"({i % 8}, {(i * 37) % 997})" for i in range(3000)))
+    s.execute("ANALYZE TABLE gd")
+    s.execute("ANALYZE TABLE gf")
+    assert name not in s.vars
+    for k, v in (("tidb_tpu_engine", "'on'"), ("tidb_tpu_row_threshold", 1),
+                 ("tidb_tpu_max_slab_rows", 1024)):
+        s.execute(f"SET {k} = {v}")
+    ea = ("EXPLAIN ANALYZE SELECT d.name, SUM(f.v) FROM gf f "
+          "JOIN gd d ON f.b = d.id GROUP BY d.name ORDER BY d.name")
+
+    def ledger():
+        """One run → (`launches=`, `spec_hits=`, the kinds of program
+        launched, aligned joins built since the cache was emptied)."""
+        device_cache._ALIGNED.clear()
+        with timeline.capture() as cap:
+            text = " ".join(str(c) for r in s.query(ea).rows for c in r)
+        hits = re.search(r"spec_hits=(\d+)", text)
+        return (int(re.search(r"launches=(\d+)", text).group(1)),
+                int(hits.group(1)) if hits else 0,
+                [e["name"].rpartition("_")[0] for e in cap.events
+                 if e["cat"] == "launch"],
+                len(device_cache._ALIGNED))
+
+    kinds = ["partial_fused"] * 3 + ["finalize"]
+    try:
+        assert ledger() == (4, 0, kinds, 1)     # cold: trace, first touch
+        assert ledger() == (4, 1, kinds, 1)
+        s.execute(f"SET {name} = 'off'")
+        assert s.vars[name] == "off"
+        assert ledger() == (4, 1, kinds, 1)
+    finally:
+        eng.close()
+        device_cache.clear()
 
 
 def test_statements_summary_specialization_hits_ledger():

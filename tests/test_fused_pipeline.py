@@ -1,5 +1,5 @@
-"""Whole-pipeline fragment fusion (executor/fragment.py
-_run_fused_pipeline + executor/device_emit.py emit layer).
+"""Whole-pipeline fragment fusion (executor/fragment.py _run_agg_slabs
+over a join tree + executor/device_emit.py emit layer).
 
 Pinned invariants:
 
@@ -10,17 +10,14 @@ Pinned invariants:
   string-dictionary group keys and exact decimal sums;
 * the Q1 chain shape (wide decimals included) runs its partials through
   the same emit layer and reports per-slab fused launches;
-* a group-cap overflow INSIDE the fused pipeline re-runs only the
-  overflowed slabs (EscalationStats slabs_rerun/slabs_reused) and the
-  resumed result matches a Python oracle;
+* (a group-cap overflow inside the fused pipeline re-runs only the
+  overflowed slabs: tests/test_overlap_runtime.py, with the chain's);
 * warm repeats retrace nothing (PROGRAM_TRACES frozen) and launch at
   most 2 device programs per slab (slab partial + amortized merge);
 * fused launch spans land in the Chrome timeline one-per-slab, labeled
   with the pipeline signature digest, and cold builds charge the
   `compile:fused` lane.
 """
-
-import collections
 
 import pytest
 
@@ -179,49 +176,6 @@ def test_statements_summary_matches_phase_ledger():
     l1, f1 = digest_counts()
     assert l1 - l0 == want_launch
     assert f1 - f0 == want_fused
-
-
-# ---------------------------------------------------------------------------
-# escalation mid-pipeline: rerun only the overflowed slabs
-# ---------------------------------------------------------------------------
-
-def test_fused_group_overflow_reruns_only_overflowed_slabs():
-    eng = Engine()
-    eng.global_vars["tidb_enable_auto_analyze"] = False
-    s = eng.new_session()
-    s.execute("CREATE TABLE dim (id INT, name VARCHAR(16))")
-    s.execute("INSERT INTO dim VALUES " + ",".join(
-        f"({i}, 'name{i:02d}')" for i in range(8)))
-    s.execute("CREATE TABLE fx (k BIGINT, b INT, v BIGINT)")
-    oracle = collections.defaultdict(int)
-    stride = 5_000_000       # key span defeats the perfect-hash gate
-    for slab, nd in enumerate((10, 200, 10)):
-        rows = []
-        for i in range(1024):
-            k = (slab * 1000 + i % nd) * stride
-            rows.append(f"({k}, {i % 8}, {i})")
-            oracle[k] += i
-        s.execute("INSERT INTO fx VALUES " + ",".join(rows))
-    s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
-                   "tidb_tpu_max_slab_rows": 1024,
-                   "tidb_tpu_group_cap": 64})
-    # a COMPUTED key has no bounds to pack into a sort word, so this is
-    # the per-slab sort-factorize whose ladder the test is about (a bare
-    # bounded key with a domain this wide groups by sorted runs, which has
-    # no per-slab capacity to overflow — tests/test_large_groups.py)
-    res = s.query("SELECT f.k + 0, SUM(f.v) FROM fx f "
-                  "JOIN dim d ON f.b = d.id GROUP BY f.k + 0")
-    assert {int(k): int(v) for k, v in res.rows} == dict(oracle)
-    esc = s.last_guard.escalation
-    # slab 1 (200 distinct) overflows the 64 cap; slabs 0/2 (10 each) are
-    # checkpointed fused partials merged back untouched
-    assert esc.slabs_rerun == 1, esc.summary()
-    assert esc.slabs_reused == 2, esc.summary()
-    assert esc.exact_resizes == 1, esc.summary()
-    assert esc.by_kind.get("group:partial-reuse") == 1, esc.summary()
-    ph = s.last_guard.phases
-    # 3 cold fused launches + 1 rerun launch (+2 merges)
-    assert ph.fused_pipelines == 4, ph.summary()
 
 
 # ---------------------------------------------------------------------------

@@ -245,7 +245,7 @@ def test_a_wrong_group_estimate_climbs_the_ladder_to_the_same_rows(
         loaded, sorted_runs, monkeypatch):
     eng, ref = loaded
     monkeypatch.setattr(fragment, "_initial_group_cap",
-                        lambda root, default, max_cap: 16)
+                        lambda root, default, max_cap, key_bounds: 16)
     s = device_session(eng, tidb_tpu_max_slab_rows=16384)
     retries = counter("tidb_tpu_ladder_retries_total", rung="group")
     assert text_rows(s.execute(LG.Q10)[0]) == ref["Q10"]

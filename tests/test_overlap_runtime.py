@@ -149,24 +149,28 @@ def test_streamed_slabs_byte_exact_vs_upload_all():
 # ---------------------------------------------------------------------------
 
 def _resumable_engine(per_slab_distinct, stride=5_000_000):
-    """3 slabs × 1024 rows; per-slab key cardinality from the given list.
-    Keys are spread by `stride` so the packed domain exceeds the
-    perfect-hash gate (DOMAIN_CAP) and the agg takes the sort-factorize
-    path whose per-slab group counts drive the resumable ladder. A FRESH
-    engine per case with auto-analyze pinned off: reliable NDV stats
-    would start the cap high enough to dodge the overflow entirely."""
+    """3 slabs × 1024 rows of `r`, per-slab key cardinality from the given
+    list, and an 8-row `dim` every `r.b` matches once. Keys are spread by
+    `stride` so the packed domain exceeds the perfect-hash gate
+    (DOMAIN_CAP) and the agg takes the sort-factorize path whose per-slab
+    group counts drive the resumable ladder. A FRESH engine per case with
+    auto-analyze pinned off: reliable NDV stats would start the cap high
+    enough to dodge the overflow entirely."""
     eng = Engine()
     eng.global_vars["tidb_enable_auto_analyze"] = False
     s = eng.new_session()
-    s.execute("CREATE TABLE r (k BIGINT, v BIGINT)")
-    rows = []
+    s.execute("CREATE TABLE dim (id INT, name VARCHAR(16))")
+    s.execute("INSERT INTO dim VALUES " + ",".join(
+        f"({i}, 'name{i:02d}')" for i in range(8)))
+    s.execute("CREATE TABLE r (k BIGINT, b INT, v BIGINT)")
     oracle = collections.defaultdict(int)
     for slab, nd in enumerate(per_slab_distinct):
+        rows = []
         for i in range(1024):
             k = (slab * 1000 + i % nd) * stride
-            rows.append(f"({k}, {i})")
+            rows.append(f"({k}, {i % 8}, {i})")
             oracle[k] += i
-    s.execute("INSERT INTO r VALUES " + ",".join(rows))
+        s.execute("INSERT INTO r VALUES " + ",".join(rows))
     s.vars["tidb_tpu_engine"] = "on"
     s.vars["tidb_tpu_row_threshold"] = 1
     s.vars["tidb_tpu_max_slab_rows"] = 1024
@@ -174,43 +178,66 @@ def _resumable_engine(per_slab_distinct, stride=5_000_000):
     return s, oracle
 
 
-# a COMPUTED key has no bounds to pack into a sort word, so these
+# The one slab-loop driver (fragment.TpuFragmentExec._run_agg_slabs) over
+# its two sources: a chain's slabs and a join tree's probe slabs. A
+# COMPUTED key has no bounds to pack into a sort word, so the grouped
 # statements keep the per-slab sort-factorize whose resumable ladder they
 # test (a bare bounded key with a domain this wide groups by sorted runs,
 # which has no per-slab capacity to overflow — tests/test_large_groups.py)
-RESUMABLE_SQL = "SELECT k + 0, SUM(v) FROM r GROUP BY k + 0"
+_TREE = "FROM r f JOIN dim d ON f.b = d.id "
+RESUMABLE_SQL = {
+    ("group", "chain"): "SELECT k + 0, SUM(v) FROM r GROUP BY k + 0",
+    ("group", "tree"): "SELECT f.k + 0, SUM(f.v) " + _TREE +
+                       "GROUP BY f.k + 0",
+    ("pairs", "chain"): "SELECT b, COUNT(DISTINCT k), SUM(v) FROM r "
+                        "GROUP BY b ORDER BY b",
+    ("pairs", "tree"): "SELECT d.name, COUNT(DISTINCT f.k), SUM(f.v) " +
+                       _TREE + "GROUP BY d.name ORDER BY d.name",
+}
+RESUMABLE_SQL.update({("merged-only", src): RESUMABLE_SQL["group", src]
+                      for src in ("chain", "tree")})
+
+
+@pytest.mark.parametrize("source", ["chain", "tree"])
+@pytest.mark.parametrize("rung", ["group", "merged-only", "pairs"])
+def test_a_ladder_rung_reruns_only_the_slabs_that_overflowed(rung, source):
+    sql = RESUMABLE_SQL[rung, source]
+    # group: slab 1 overflows the 64-group cap (200 distinct), slabs 0/2
+    # do not — the retry re-executes exactly one slab and merges the two
+    # checkpointed partials back untouched. merged-only: every slab fits
+    # (60 groups, disjoint key ranges) but the MERGED count (180) does
+    # not — the retry reuses every partial and only re-merges
+    s, oracle = _resumable_engine(
+        (60, 60, 60) if rung == "merged-only" else (10, 200, 10))
+    if rung == "pairs":
+        # a pair cap (64) below a slab's distinct (b, k) pairs — 40 in
+        # slabs 0/2, 200 in slab 1 — must clip, be DETECTED (true counts
+        # travel with the clipped sets), resize through the 'pairs' rung
+        # to the exact need, re-run the clipped slab and still answer the
+        # CPU engine's rows
+        s.vars["tidb_tpu_engine"] = "off"
+        want = s.query(sql).rows
+        s.vars.update({"tidb_tpu_engine": "on",
+                       "tidb_tpu_distinct_pair_cap": 64})
+        assert s.query(sql).rows == want
+    else:
+        _check_oracle(s.query(sql).rows, oracle)
+    assert s.last_engine == "tpu"
+    esc = s.last_guard.escalation
+    rerun = 0 if rung == "merged-only" else 1
+    assert esc.slabs_rerun == rerun, esc.summary()
+    assert esc.slabs_reused == 3 - rerun, esc.summary()
+    kind = "pairs" if rung == "pairs" else "group"
+    assert esc.by_kind.get(kind + ":partial-reuse") == 1, esc.summary()
+    # 3 cold slab launches + the re-runs (+ 2 merges)
+    assert s.last_guard.phases.fused_pipelines == 3 + rerun
+    assert esc.recompiles == 1, esc.summary()
+    assert esc.exact_resizes == 1, esc.summary()
 
 
 def _check_oracle(rows, oracle):
     got = {int(k): int(v) for k, v in rows}
     assert got == dict(oracle)
-
-
-def test_group_overflow_reruns_only_overflowed_slabs():
-    # slab 1 overflows the 64-group cap (200 distinct); slabs 0/2 do not:
-    # the retry must re-execute exactly one slab and reuse two partials
-    s, oracle = _resumable_engine((10, 200, 10))
-    res = s.query(RESUMABLE_SQL)
-    _check_oracle(res.rows, oracle)
-    esc = s.last_guard.escalation
-    assert esc.slabs_rerun == 1, esc.summary()
-    assert esc.slabs_reused == 2, esc.summary()
-    assert esc.recompiles == 1, esc.summary()
-    assert esc.exact_resizes == 1, esc.summary()
-    assert esc.by_kind.get("group:partial-reuse") == 1, esc.summary()
-
-
-def test_merged_count_overflow_reruns_zero_slabs():
-    # every slab fits the cap (60 groups) but the MERGED count (180) does
-    # not: the retry reuses every checkpointed partial and only re-merges
-    s, oracle = _resumable_engine((60, 60, 60), stride=5_000_000)
-    # disjoint key ranges per slab: 60 × 3 = 180 merged groups
-    res = s.query(RESUMABLE_SQL)
-    _check_oracle(res.rows, oracle)
-    esc = s.last_guard.escalation
-    assert esc.slabs_rerun == 0, esc.summary()
-    assert esc.slabs_reused == 3, esc.summary()
-    assert esc.recompiles == 1, esc.summary()
 
 
 # ---------------------------------------------------------------------------

@@ -8,8 +8,8 @@ speed rests on, which a correctness suite would never notice breaking:
   entry's device arrays keep their identities);
 * phase accounting: a cold multi-slab first touch must attribute time
   to every pipeline phase (encode/upload/compute/fetch/decode) with a
-  sane overlap-efficiency ratio, because bench.py and EXPLAIN ANALYZE
-  report those numbers as the optimization's evidence.
+  sane overlap-efficiency ratio, because EXPLAIN ANALYZE reports those
+  numbers as the optimization's evidence.
 """
 
 import numpy as np

@@ -365,6 +365,143 @@ def test_first_touch_counters_are_in_engine_metrics(served):
         sum(cold[k] for k in setup_encode_s.STAGES))
 
 
+# ---- what the chip must not notice of the aggregate driver ------------------
+
+def _slab_session():
+    eng = Engine()
+    eng.global_vars["tidb_enable_auto_analyze"] = False
+    s = eng.new_session()
+    s.execute("CREATE TABLE gd (id INT, name VARCHAR(16))")
+    s.execute("INSERT INTO gd VALUES " + ",".join(
+        f"({i}, 'name{i:02d}')" for i in range(8)))
+    s.execute("CREATE TABLE gf (k BIGINT, b INT, v BIGINT)")
+    s.execute("INSERT INTO gf VALUES " + ",".join(
+        f"({i % 300}, {i % 8}, {(i * 37) % 211 - 100})"
+        for i in range(3000)))
+    s.execute("ANALYZE TABLE gd")
+    s.execute("ANALYZE TABLE gf")
+    s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
+                   "tidb_tpu_max_slab_rows": 1024})      # three slabs
+    return eng, s
+
+
+_JOIN = "FROM gf f JOIN gd d ON f.b = d.id "
+# What a warm aggregate over three slabs shows the span recorder: its
+# launches in order (program, slab, `sig` tag), the tags of its
+# `device.fragment` and `frag.merge` spans, its `jax.device_get` calls. The
+# program names are digests of the compile-cache signatures, which key the
+# persistent compile cache on the chip: a change to the driver that is not
+# meant to recompile anything leaves them byte for byte.
+_SLOTS = {"slots_out": 9, "slots_in": 27}
+_RUNS = {"slots_in": 0, "slots_out": 1024, "rows": 3072}
+
+
+def _frag(root, key_bytes, state_bytes=24, grouping="bounds", gcap=9,
+          groups=8):
+    return {"root": root, "spec": "hit", "grouping": grouping, "gcap": gcap,
+            "rows_in": 3000, "groups": groups, "key_bytes": key_bytes,
+            "state_bytes": state_bytes}
+
+
+# case → (statement, slab program, its `sig` tag, the launches after the
+# slabs, `device.fragment` tags, `frag.merge` tags, device_gets)
+PINNED = {
+    "chain": (
+        "SELECT b, COUNT(*), SUM(v) FROM gf GROUP BY b",
+        "partial_chain_7b01d280", None, [("merge_7b01d280", None)],
+        _frag("HashAgg", 9), _SLOTS, 1),
+    "chain-topn": (
+        "SELECT b, COUNT(*), SUM(v) FROM gf GROUP BY b "
+        "ORDER BY SUM(v) DESC LIMIT 3",
+        "partial_chain_7b01d280", None,
+        [("finalize_94637dac", "fused-final:94637dac7685")],
+        _frag("TopN", 9), _SLOTS, 1),
+    "chain-distinct": (
+        "SELECT b, COUNT(DISTINCT v) FROM gf GROUP BY b ORDER BY b",
+        "partial_chain_3379760e", None,
+        [("finalize_d4301d6a", "fused-final:d4301d6a8439")],
+        _frag("Sort", 9, 8), _SLOTS, 3),
+    "tree": (
+        "SELECT d.name, COUNT(*), SUM(f.v) " + _JOIN + "GROUP BY d.name",
+        "partial_fused_efb25ddf", "fused:efb25ddf5808",
+        [("merge_aeb0628b", None)],
+        _frag("HashAgg", 5), _SLOTS, 1),
+    "tree-sort": (
+        "SELECT d.name, COUNT(*), SUM(f.v) " + _JOIN +
+        "GROUP BY d.name ORDER BY d.name",
+        "partial_fused_efb25ddf", "fused:efb25ddf5808",
+        [("finalize_4894e43c", "fused-final:4894e43cbfe4")],
+        _frag("Sort", 5), _SLOTS, 1),
+    "tree-distinct": (
+        "SELECT d.name, COUNT(DISTINCT f.v) " + _JOIN +
+        "GROUP BY d.name ORDER BY d.name",
+        "partial_fused_28ce7b9a", "fused:28ce7b9ae17f",
+        [("finalize_c28bcac4", "fused-final:c28bcac4ba4c")],
+        _frag("Sort", 5, 8), _SLOTS, 3),
+    "runs-chain": (
+        "SELECT k, COUNT(*), SUM(v) FROM gf GROUP BY k "
+        "ORDER BY SUM(v) DESC, k LIMIT 5",
+        "partial_chain_327230ad", None,
+        [("sort_rows_2a418386", None),
+         ("finalize_31666253", "fused-final:31666253c2ee")],
+        _frag("TopN", 9, grouping="runs", gcap=1024, groups=300), _RUNS, 1),
+    "runs-tree": (
+        "SELECT f.k, COUNT(*), SUM(f.v) " + _JOIN + "GROUP BY f.k "
+        "ORDER BY SUM(f.v) DESC, f.k LIMIT 5",
+        "partial_fused_38c9441f", "fused:38c9441f33af",
+        [("sort_rows_2a418386", None),
+         ("finalize_69c85889", "fused-final:69c85889d279")],
+        _frag("TopN", 9, grouping="runs", gcap=1024, groups=300), _RUNS, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PINNED))
+def test_a_warm_aggregate_launches_the_same_programs_in_the_same_order(
+        case, monkeypatch):
+    from tidb_tpu.executor import fragment
+    from tidb_tpu.ops.jax_env import jax
+    sql, slab_prog, slab_sig, tail, frag_tags, merge_tags, device_gets = \
+        PINNED[case]
+    launches = [(slab_prog, i, slab_sig) for i in range(3)] + \
+        [(name, None, sig) for name, sig in tail]
+    if frag_tags["grouping"] == "runs":
+        monkeypatch.setattr(fragment, "SLOT_ADDRESS_CAP", 64)
+    eng, s = _slab_session()
+    try:
+        want = eng.new_session().query(sql).rows
+        for _ in range(2):                  # compile, then specialize
+            s.query(sql)
+        calls = []
+        real = jax.device_get
+        with monkeypatch.context() as m, timeline.capture() as cap:
+            m.setattr(jax, "device_get",
+                      lambda t: calls.append(1) or real(t))
+            rows = s.query(sql).rows
+    finally:
+        eng.close()
+    assert s.last_engine == "tpu"
+    if "ORDER BY" not in sql:
+        rows, want = sorted(rows), sorted(want)
+    assert rows == want
+    evs = sorted((e for e in cap.events if e["ph"] == "X"),
+                 key=lambda e: e["ts"])
+    got = [(e["name"], e["args"]["slab"], e["args"].get("sig"))
+           for e in evs if e["cat"] == "launch"]
+
+    def tags(name):
+        (e,) = [e for e in evs if e["name"] == name]
+        return {k: v for k, v in e["args"].items()
+                if k not in ("req", "id", "parent", "conn")}
+
+    assert got == launches
+    assert tags("device.fragment") == frag_tags
+    assert tags("frag.merge") == merge_tags
+    assert len(calls) == device_gets
+    assert len([e for e in evs if e["cat"] == "fetch"]) == device_gets
+    assert len([e for e in evs if e["cat"] == "drain"]) == 1
+    assert not [e for e in evs if e["name"] == "ladder.retry"]
+
+
 # ---- names on the device ---------------------------------------------------
 
 def test_lowered_programs_carry_their_name_and_their_stages(monkeypatch):

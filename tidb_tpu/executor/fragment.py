@@ -415,12 +415,6 @@ _BUILD_LOCKS: Dict[str, threading.Lock] = {}
 # tier watches (a repeated identical query must leave it unchanged).
 PROGRAM_TRACES = 0
 
-# Cumulative cold-build counts per program kind ("chain" | "dist" |
-# "tree" | "fused") — bench.py snapshots deltas around each query to
-# report fused-vs-unfused compile counts in its JSON extras.
-COMPILE_COUNTS: Dict[str, int] = {}
-
-
 def _count_trace() -> None:
     global PROGRAM_TRACES
     with _CC_LOCK:
@@ -733,6 +727,7 @@ class _FragmentProgram:
         from tidb_tpu.ops.jax_env import named_jit, program_name
         # `sig` is the compile-cache signature: its digest names the
         # programs in the profile and in `launch` spans
+        self.sig = sig
         self.partial_name = program_name("partial_chain", sig)
         self.merge_name = program_name("merge", sig)
         self.partial = named_jit(self._partial, self.partial_name)
@@ -926,8 +921,6 @@ def _charge_compile(kind: str, t0: float) -> None:
     PhaseTimer compile counter (thread-local — the single-flight builders
     have no ExecContext in reach) and emit a timeline compile event."""
     from tidb_tpu.util import phases as _phases
-    with _CC_LOCK:
-        COMPILE_COUNTS[kind] = COMPILE_COUNTS.get(kind, 0) + 1
     cur = _phases.current()
     if cur is not None:
         cur.note_compile()
@@ -1099,6 +1092,12 @@ class _RunsFinalizeProgram:
             self.key_bounds.bounds, self.key_dtypes, rows)
 
 
+def _sig_tag(kind: str, sig: str) -> str:
+    """The `sig` tag of a `launch` span: `<kind>:<sig12>`."""
+    import hashlib
+    return f"{kind}:{hashlib.sha1(sig.encode()).hexdigest()[:12]}"
+
+
 def _order_sig(order_root) -> str:
     k = getattr(order_root, "count", None)
     off = getattr(order_root, "offset", 0)
@@ -1159,8 +1158,8 @@ MAX_SPECIALIZATIONS = 256
 
 
 def _spec_key(guard, kind: str, extra: tuple):
-    """None when the statement has no SQL text attached or the gate is
-    off — ad-hoc plan executions don't specialize."""
+    """None when the statement has no SQL text attached — ad-hoc plan
+    executions don't specialize."""
     sql = getattr(guard, "sql", None) if guard is not None else None
     if not sql:
         return None
@@ -1263,14 +1262,6 @@ def _note_agg_io(partial, rows_in: int, groups: int) -> None:
                         for a in st))
 
 
-def _merge_span(partials, cap: int):
-    """The `frag.merge` span around the launch that merges slab partials:
-    `slots_in` partial slots reduce into `slots_out`."""
-    return timeline.span(
-        "frag.merge", "frag", slots_out=int(cap),
-        slots_in=sum(int(p["slot_live"].shape[0]) for p in partials))
-
-
 def _tight_cap(cap: int, groups: int) -> int:
     """The capacity the NEXT execution of a grouping by sorted runs starts
     from (kept by the specialization cache): the groups it found plus an
@@ -1289,15 +1280,24 @@ def _count_agg_partial(grouping: str) -> None:
 
 
 def _initial_group_cap(root: PhysHashAgg, default_cap: int,
-                       max_cap: int) -> int:
-    """Stats-informed factorize capacity: when the planner's group estimate
-    came from real NDV stats (est_reliable, planner/physical.estimate), a
-    1.5× headroom start avoids the overflow→retry recompile ladder both for
+                       max_cap: int, key_bounds=None) -> int:
+    """The group capacity an aggregate starts from. Keys that address
+    their slots directly (`key_bounds` in SLOTS mode) need exactly their
+    packed domain: a slot per value and one for NULL, per key.
+
+    Otherwise stats-informed: when the planner's group estimate came from
+    real NDV stats (est_reliable, planner/physical.estimate), a 1.5×
+    headroom start avoids the overflow→retry recompile ladder both for
     high-cardinality keys (e.g. GROUP BY orderkey) and tiny ones.
 
     An aggregate with no GROUP BY has exactly one group whatever the
     estimate or `tidb_tpu_group_cap` say: one slot, so its partial states
     are plain masked reductions (ops/segment.py) and nothing can overflow."""
+    if grouping_mode(key_bounds) == SLOTS:
+        cap = 1
+        for lo, hi in key_bounds.bounds:
+            cap *= hi - lo + 2
+        return cap
     if not root.group_exprs:
         return 1
     if not getattr(root, "est_reliable", False):
@@ -1397,8 +1397,6 @@ def _plan_aligned_joins(ctx, root, scans, ents):
     from tidb_tpu.executor import device_cache
     from tidb_tpu.executor import tree_fragment as TF
     if getattr(ctx, "txn", None) is not None:
-        return {}
-    if not _var_bool(ctx.vars.get("tidb_tpu_aligned_join", True)):
         return {}
     store = getattr(ctx.snapshot, "store", None)
     if store is None:
@@ -1594,6 +1592,259 @@ def _plan_aligned_joins(ctx, root, scans, ents):
     return info_by_join
 
 
+class _SlabSource:
+    """What `TpuFragmentExec._run_agg_slabs` asks of the slabs it
+    aggregates; how a slab's arguments are laid out stays in here. A chain
+    (`_ChainSlabs`) reads one table's slabs, streamed on first touch; a
+    join tree (`_TreeSlabs`) reads its probe anchor's slabs against whole
+    build sides and has join capacities to escalate.
+
+    Each holds `kind` (of the specialization key), `root` (the aggregate),
+    `key_bounds`, `dicts` (for the decode), `run_ids` (physical ids of the
+    slabs zone maps left: the driver's per-slab lists index POSITIONS in
+    it), `n_slabs` and `slab_cap` (the table's geometry, whatever was
+    pruned, so signatures and ceilings don't depend on pruning), `max_cap`
+    (the group ladder's ceiling), and `lay_sig` and `geometry` (what the
+    specialization cache compares and keys)."""
+
+    kind = ""
+
+    def control(self, partials) -> dict:
+        """What the batched control fetch brings back besides the group
+        counts."""
+        return {}
+
+    def escalate(self, got, ladder):
+        """Classify what `control` fetched → (retry, positions to re-run),
+        or None to give the statement back to the caller."""
+        return False, set()
+
+    def learned(self) -> dict:
+        """What a specialization entry keeps besides capacities."""
+        return {}
+
+    def adopt(self, spec: dict) -> None:
+        """Take `learned` back from an earlier execution's entry."""
+
+
+class _ChainSlabs(_SlabSource):
+    """The slabs of one table under a linear chain (Q1, Q6)."""
+
+    kind = "chain"
+
+    def __init__(self, ex: "TpuFragmentExec", chain, ent, stream, used,
+                 in_types, dicts, key_bounds, layouts, slab_ids):
+        from tidb_tpu.executor import device_cache
+        self.ex, self.ctx = ex, ex.ctx
+        self.chain, self.root = chain, chain[0]
+        self.ent, self.stream = ent, stream
+        self.used, self.in_types = used, in_types
+        self.dicts, self.key_bounds, self.layouts = dicts, key_bounds, layouts
+        # ascending physical order — the cold stream's yield order
+        self.run_ids = list(slab_ids)
+        self.n_slabs, self.slab_cap = ent.n_slabs, ent.slab_cap
+        self.max_cap = ent.slab_cap * max(ent.n_slabs, 1)
+        self.lay_sig = ",".join(f"{i}:{l.sig()}"
+                                for i, l in sorted(layouts.items())) \
+            if layouts else "-"
+        self.geometry = (id(ent.td), getattr(ent, "delta_version", 0),
+                         ent.slab_cap, ent.n_slabs)
+        # pod-partitioned entry: each slab's partial computes on its
+        # owner device; re-pin every partial to the STATEMENT's device
+        # right after dispatch so the merge/finalize graph downstream
+        # (concatenate, piggyback packing, fetch) stays single-device —
+        # mixing committed arrays from different devices in one op raises
+        self.pod_pin = device_cache.device_handle(
+            device_cache._ctx_device(self.ctx)) \
+            if getattr(ent, "owners", None) is not None else None
+
+    def rows(self, pos: int) -> int:
+        return self.ent.slab_rows(self.run_ids[pos])
+
+    def program(self, gcap: int, pair_cap: int, want_pairs: bool, sig):
+        prog = get_program(self.chain, self.used, self.in_types,
+                           self.slab_cap, gcap, self.key_bounds, want_pairs,
+                           self.layouts, pair_cap, sig=sig)
+        return prog, prog.sig, prog.collect_preps(self.dicts)
+
+    def merge_program(self, prog, gcap: int, sig: str):
+        return prog
+
+    def launches(self, prog, prep_vals, to_run=None):
+        """→ (position, partial) of the slabs at `to_run`, each launched
+        as it is asked for; None = the first pass over every surviving
+        slab, which STREAMS a cold table's first touch."""
+        from tidb_tpu.ops.jax_env import jax, jnp
+        ex, ent, used = self.ex, self.ent, prog.used_cols
+        if to_run is None:
+            to_run = range(len(self.run_ids))
+            slabs = ex._slab_iter(ent, self.stream, used, self.run_ids)
+        else:
+            slabs = (ex._slab(ent, self.run_ids[p], used) for p in to_run)
+        # (slabs first: zip must run the stream past its last slab, where
+        # it commits the upload)
+        for (cols, n), pos in zip(slabs, to_run):
+            # slot per slab DISPATCH: the streamed encode of the next slab
+            # (inside _slab_iter) runs slot-free, so a sibling's dispatch
+            # interleaves with our host work
+            with self.ctx.device_slot():
+                with self.ctx.phases.launch(prog.partial_name, slab=pos):
+                    part = prog.partial(cols, jnp.int32(n), prep_vals)
+                    if self.pod_pin is not None:
+                        part = jax.device_put(part, self.pod_pin)
+            yield pos, part
+
+
+class _TreeSlabs(_SlabSource):
+    """The probe anchor's slabs under a join tree (Q3, Q5, Q10, Q18).
+
+    Join build sides ride inside each per-slab program at their FULL
+    (mega-slab) capacities — dimension tables, or FK-aligned columns
+    already in the anchor's row space — so every launch joins a partition
+    of the probe rows against complete build sides and the slab union of
+    agg partials is exact for every join kind (tree_ok pins outer joins to
+    preserve the probe side, the same argument that makes
+    _run_tree_blocked's row-range passes exact).
+
+    `join_cfgs` is the caller's list: what the join rungs learn here
+    (flips, resizes) the mega-slab loop keeps if the statement goes back
+    to it."""
+
+    kind = "tree"
+
+    def __init__(self, ctx, root, caps, scans, ents, scan_inputs, scan_rows,
+                 flow_list, flows, aligned_inputs, join_cfgs, walk_joins,
+                 akb, max_cap, out_cap_max, anchor_i, scan_layouts,
+                 nested_rows):
+        self.ctx, self.root, self.key_bounds = ctx, root, akb
+        self.scan_inputs, self.scan_rows = scan_inputs, scan_rows
+        self.flow_list, self.aligned_inputs = flow_list, aligned_inputs
+        self.join_cfgs, self.walk_joins = join_cfgs, walk_joins
+        self.max_cap, self.out_cap_max = max_cap, out_cap_max
+        self.anchor_i, self.scan_layouts = anchor_i, scan_layouts
+        self.nested_rows = nested_rows
+        self.dicts = dict(enumerate(flows.get(id(root), [])))
+        a_ent = ents[anchor_i][0]
+        self.n_slabs, self.slab_cap = a_ent.n_slabs, a_ent.slab_cap
+        self.caps = dict(caps)
+        self.caps[id(scans[anchor_i])] = (a_ent.slab_cap, 1)
+        # a zero row count IS zone maps' skip signal (_run_device_tree)
+        self.anchor_rows = scan_rows[anchor_i]
+        self.run_ids = [s for s in range(a_ent.n_slabs)
+                        if int(self.anchor_rows[s]) > 0]
+        self.lay_sig = ",".join(
+            f"{si}/{i}:{l.sig()}"
+            for si, slot in enumerate(scan_layouts or ())
+            for i, l in slot) if scan_layouts else "-"
+        self.geometry = (tuple((id(e.td), getattr(e, "delta_version", 0),
+                                e.slab_cap, e.n_slabs) for e, _ in ents),
+                         anchor_i)
+        self._launch_sig = ""
+
+    def rows(self, pos: int) -> int:
+        return int(self.anchor_rows[self.run_ids[pos]])
+
+    def learned(self) -> dict:
+        return {"join_cfgs": tuple(self.join_cfgs)}
+
+    def adopt(self, spec: dict) -> None:
+        self.join_cfgs[:] = list(spec["join_cfgs"])
+
+    def program(self, gcap: int, pair_cap: int, want_pairs: bool, sig):
+        prog, sig = get_pipeline_program(
+            self.root, self.caps, gcap, self.join_cfgs, self.key_bounds,
+            self.scan_layouts, want_pairs, pair_cap, sig=sig)
+        self._launch_sig = _sig_tag("fused", sig)
+        return prog, sig, prog.collect_preps(self.flow_list)
+
+    def merge_program(self, prog, gcap: int, sig: str):
+        return get_merge_program(self.root, gcap, sig)
+
+    def _joins_in_anchor_space(self) -> set:
+        """Joins whose aligned inputs live in the ANCHOR's row space — the
+        only ones whose matched/column slabs may be sliced per anchor
+        slab: the root's probe chain, plus recursively the build chains
+        of its ALIGNED joins (_plan_aligned_joins re-anchored those to
+        the fact row space via anchor_subs). An aligned join hanging
+        off a non-aligned build subtree keeps its own fact scan's row
+        space and passes its inputs through whole."""
+        from tidb_tpu.executor import tree_fragment as TF
+        spaced: set = set()
+        stack = list(TF.aligned_chain(self.root.children[0])[1])
+        while stack:
+            j = stack.pop()
+            spaced.add(id(j))
+            if self.join_cfgs[self.walk_joins.index(j)].mode == "aligned":
+                bi = 1 if j.build_right else 0
+                stack.extend(TF.aligned_chain(j.children[bi])[1])
+        return spaced
+
+    def launches(self, prog, prep_vals, to_run=None):
+        """→ (position, partial) of the slabs at `to_run` (None = every
+        surviving slab), each launched as it is asked for."""
+        spaced = self._joins_in_anchor_space()
+        for pos in (range(len(self.run_ids)) if to_run is None else to_run):
+            yield pos, self._launch(prog, self.run_ids[pos], prep_vals,
+                                    spaced)
+
+    def _launch(self, prog, s: int, prep_vals, spaced: set):
+        a = self.anchor_i
+        si = list(self.scan_inputs)
+        si[a] = {i: [slabs[s]] for i, slabs in self.scan_inputs[a].items()}
+        sr = list(self.scan_rows)
+        sr[a] = np.array([self.anchor_rows[s]], dtype=np.int32)
+        ai = []
+        for jn, (matched, jcols) in zip(self.walk_joins,
+                                        self.aligned_inputs):
+            if matched and id(jn) in spaced:
+                ai.append(((matched[s],),
+                           {c: (sl[s],) for c, sl in jcols.items()}))
+            else:
+                ai.append((matched, jcols))
+        # slot per slab DISPATCH (async queue) — one labeled compute span
+        # per fused slab program in the trace
+        with self.ctx.device_slot():
+            with self.ctx.phases.launch(prog.name, slab=s,
+                                        sig=self._launch_sig):
+                return prog(tuple(si), tuple(sr), prep_vals, tuple(ai),
+                            nested=self.nested_rows)
+
+    def control(self, partials) -> dict:
+        return {"jus": [p["join_unique"] for p in partials],
+                "jts": [p["join_totals"] for p in partials]}
+
+    def escalate(self, got, ladder):
+        from tidb_tpu.executor import tree_fragment as TF
+        from tidb_tpu.executor.device_cache import _pow2
+        n_run, n_joins = len(self.run_ids), len(self.join_cfgs)
+        jts = np.asarray(got["jts"]).reshape(n_run, n_joins)
+        jus = np.asarray(got["jus"]).reshape(n_run, n_joins)
+        retry, rerun = False, set()
+        for ji, cfg in enumerate(self.join_cfgs):
+            new_cfg, action = TF.escalate_join(
+                cfg, bool(jus[:, ji].all()), int(jts[:, ji].max()),
+                self.out_cap_max,
+                flip_out_cap=_pow2(int(cfg.est * 1.3), lo=1024),
+                ladder=ladder)
+            if action == "over-max":
+                # a join's fan-out exceeds out_cap_max: the caller's
+                # mega-slab loop owns the blocked multi-pass escalation
+                return None
+            if new_cfg is not None:
+                self.join_cfgs[ji] = new_cfg
+                retry = True
+                if action == "flip":
+                    # the join's trace changed: every checkpoint is from
+                    # the wrong program — full re-run
+                    rerun.update(range(n_run))
+                else:
+                    # exact resize: only slabs whose OWN fan-out
+                    # overflowed the old cap re-run
+                    rerun.update(s for s in range(n_run)
+                                 if int(jts[s, ji]) > cfg.out_cap)
+        return retry, rerun
+
+
 class TpuFragmentExec:
     """Volcano leaf running the fused device program (built by executor
     build(), the builder.go:144 seam)."""
@@ -1687,12 +1938,12 @@ class TpuFragmentExec:
                         # mid-compute
                         with self._protect_tables():
                             self._result = self._run_device()
-                    global LAST_DEVICE_EXEC_S, LAST_PHASES
-                    LAST_DEVICE_EXEC_S = _time.perf_counter() - _t0
+                    global LAST_PHASES
+                    exec_s = _time.perf_counter() - _t0
                     self.used_device = True
                     _ph = getattr(self.ctx, "phases", None)
                     if _ph is not None:
-                        _ph.add_wall(LAST_DEVICE_EXEC_S)
+                        _ph.add_wall(exec_s)
                         LAST_PHASES = _ph
                     _tr = getattr(self.ctx, "tracer", None)
                     _esc = getattr(self.ctx, "escalation", None)
@@ -1704,7 +1955,7 @@ class TpuFragmentExec:
                         # where the device wall went + how much host
                         # encode hid behind in-flight transfers/compute
                         _tr.event("device.phases",
-                                  duration_s=LAST_DEVICE_EXEC_S,
+                                  duration_s=exec_s,
                                   **_ph.as_dict())
                 except FragmentFallback as e:
                     # expected ineligibility (shape/feature gate) — quiet
@@ -1842,7 +2093,7 @@ class TpuFragmentExec:
             raise FragmentFallback("not a chain", reason="shape")
         # ORDER BY / TopN directly over the agg: strip the order root and
         # run the rest agg-rooted — the ordering becomes the agg's fused
-        # device finalize (or a host re-order when the gate is off)
+        # device finalize
         order_root = None
         if len(chain) > 1 and isinstance(chain[0], (PhysTopN, PhysSort)):
             k = 1
@@ -1871,7 +2122,7 @@ class TpuFragmentExec:
         if ent.total == 0:
             raise FragmentFallback("empty input", reason="empty-input")
         dicts = {i: ent.dicts.get(i) for i in used}
-        total, slab_cap, n_slabs = ent.total, ent.slab_cap, ent.n_slabs
+        slab_cap, n_slabs = ent.slab_cap, ent.n_slabs
 
         # zone-map slab pruning: the scan's conjuncts evaluated host-side
         # against per-slab stats (over dict codes / encoded ints, no
@@ -1903,41 +2154,31 @@ class TpuFragmentExec:
         if not slab_ids:
             # every slab pruned: ZERO launches. Drain the stream so the
             # skip accounting + hole placeholders still commit, then
-            # synthesize the result the device would have produced:
-            # grouped agg → empty, global agg → the CPU oracle's
-            # identity row (COUNT 0, SUM/MIN/MAX NULL — merge of zero
-            # passes), order/filter roots → empty.
+            # synthesize the result the device would have produced: an
+            # aggregate's is the driver's to give, order/filter roots →
+            # empty.
             if stream is not None:
                 for _ in stream:
                     pass
-            if isinstance(root, PhysHashAgg):
-                chunk = self._merge_tree_agg_passes(root, [], dicts)
-                if order_root is not None:
-                    chunk = _host_order(chunk, order_root, root.schema)
-                    chunk = _topn_slice(chunk, order_root)
-                return chunk
-            from tidb_tpu.executor import _empty_chunk
-            return _empty_chunk(self.schema)
-
-        # stats-informed grouping: small known key domains skip the sort
-        # (open_table commits dictionaries/bounds EAGERLY — before the
-        # stream runs — exactly so program construction can use them here)
-        key_bounds = _agg_key_bounds(chain, ent)
-        if grouping_mode(key_bounds) == SLOTS:
-            group_cap = 1
-            for lo, hi in key_bounds.bounds:
-                group_cap *= (hi - lo + 2)
-        elif isinstance(root, PhysHashAgg):
-            group_cap = _initial_group_cap(root, group_cap, slab_cap)
+            if not isinstance(root, PhysHashAgg):
+                from tidb_tpu.executor import _empty_chunk
+                return _empty_chunk(self.schema)
 
         layouts = _ent_layouts(ent, used)
         if isinstance(root, PhysHashAgg):
-            # grouped aggregation owns its ladder loop: overflow retries
-            # are RESUMABLE (only overflowed slab partials re-execute)
-            return self._execute_agg(chain, root, ent, dicts, stream,
-                                     used, in_types, slab_cap, group_cap,
-                                     key_bounds, layouts, order_root,
-                                     slab_ids=slab_ids)
+            from tidb_tpu.util.escalation import CapacityLadder
+            # stats-informed grouping: small known key domains skip the
+            # sort (open_table commits dictionaries/bounds EAGERLY — before
+            # the stream runs — exactly so program construction can use
+            # them here)
+            key_bounds = _agg_key_bounds(chain, ent)
+            return self._run_agg_slabs(
+                _ChainSlabs(self, chain, ent, stream, used, in_types, dicts,
+                            key_bounds, layouts, slab_ids),
+                _initial_group_cap(root, group_cap, slab_cap, key_bounds),
+                order_root,
+                CapacityLadder(guard=getattr(self.ctx, "guard", None),
+                               stats=self.ctx.escalation))
         # order/filter roots have no group capacity to overflow — one pass
         if isinstance(root, (PhysTopN, PhysSort)):
             prog = get_program(chain, used, in_types, slab_cap, group_cap,
@@ -1981,8 +2222,6 @@ class TpuFragmentExec:
         `sorted_rows` from an earlier round of the capacity ladder is
         reused, the ladder only resizes the finalize) and reduce the runs.
         → (out as a merge or fused finalize gives it, sorted_rows)."""
-        import hashlib
-
         from tidb_tpu.ops.jax_env import jnp
         ph = self.ctx.phases
         p0 = partials[0]
@@ -2015,9 +2254,8 @@ class TpuFragmentExec:
                     + f"|cap={cap}|" + base_sig)
             fp = _get_or_build(fsig, "finalize", lambda: _RunsFinalizeProgram(
                 root, order_root, cap, key_bounds, fsig))
-            fsig12 = hashlib.sha1(fsig.encode()).hexdigest()[:12]
             with self.ctx.device_slot():
-                with ph.launch(fp.name, sig=f"fused-final:{fsig12}"):
+                with ph.launch(fp.name, sig=_sig_tag("fused-final", fsig)):
                     out = fp.run(sorted_rows)
             ph.note_launch()
         return out, sorted_rows
@@ -2050,16 +2288,14 @@ class TpuFragmentExec:
         inside the program; join modes adapt at runtime (a lost uniqueness
         bet or an expansion-capacity overflow re-traces exactly once, never
         falls back to CPU)."""
-        from dataclasses import replace as d_replace
-
         from tidb_tpu.executor import device_cache
         from tidb_tpu.executor import tree_fragment as TF
         from tidb_tpu.executor.device_cache import _pow2
-        from tidb_tpu.ops.jax_env import jax, jnp
+        from tidb_tpu.ops.jax_env import jax
 
         root = self.plan.root
         # ORDER BY / TopN over the agg runs as the agg's fused device
-        # finalize (or a host re-order on the mega-slab path): everything
+        # finalize (a host re-order on the mega-slab path): everything
         # below — flows, signatures, key bounds — stays agg-rooted
         order_root, root = _strip_order_root(root)
         vars_ = self.ctx.vars
@@ -2155,14 +2391,8 @@ class TpuFragmentExec:
         aligned_inputs = tuple(aligned_inputs)
         akb = TF.tree_agg_key_bounds(root, scan_bounds, DOMAIN_CAP) \
             if is_agg else None
-        if grouping_mode(akb) == SLOTS:
-            gcap = 1
-            for lo, hi in akb.bounds:
-                gcap *= (hi - lo + 2)
-        elif is_agg:
-            gcap = _initial_group_cap(root, group_cap, max_cap)
-        else:
-            gcap = 1
+        gcap = _initial_group_cap(root, group_cap, max_cap, akb) \
+            if is_agg else 1
         from tidb_tpu.executor.tree_fragment import JOIN_OUT_CAP
         from tidb_tpu.util.escalation import CapacityLadder
         out_cap_max = int(vars_.get("tidb_tpu_join_out_cap", JOIN_OUT_CAP))
@@ -2175,23 +2405,24 @@ class TpuFragmentExec:
         # join-probe → partial-agg as ONE program PER PROBE SLAB plus one
         # root merge/finalize, instead of one mega-slab program:
         # intermediates stay in registers/HBM and warm launches drop to
-        # slabs + 1. DISTINCT aggs fuse too — the per-slab programs emit
-        # capped (group, args...) pair sets the host merges exactly;
-        # multi-arg DISTINCT (COUNT-only) dedups on a combined dense code
-        # in-slab and ships the raw argument columns in the pairs.
+        # slabs + 1. DISTINCT aggs fuse too; multi-arg DISTINCT
+        # (COUNT-only) dedups on a combined dense code in-slab and ships
+        # the raw argument columns in the pairs.
         if is_agg and _var_bool(vars_.get("tidb_tpu_fused_pipeline", "on")):
             anchor = TF.aligned_chain(root.children[0])[0]
             anchor_i = next((i for i, s in enumerate(scans)
                              if s is anchor), None)
             if anchor_i is not None:
-                res = self._run_fused_pipeline(
-                    root, caps, scans, ents, scan_inputs, scan_rows,
-                    flow_list, flows, aligned_inputs, join_cfgs,
-                    walk_joins, akb, gcap, max_cap, out_cap_max, ladder,
-                    anchor_i, scan_layouts, order_root, nested_rows)
+                res = self._run_agg_slabs(
+                    _TreeSlabs(self.ctx, root, caps, scans, ents,
+                               scan_inputs, scan_rows, flow_list, flows,
+                               aligned_inputs, join_cfgs, walk_joins, akb,
+                               max_cap, out_cap_max, anchor_i, scan_layouts,
+                               nested_rows),
+                    gcap, order_root, ladder)
                 if res is not None:
                     return res
-                # a join's fan-out exceeded out_cap_max inside the fused
+                # a join's fan-out exceeded out_cap_max inside the slab
                 # driver: fall through to the mega-slab loop, whose own
                 # over-max rung escalates to blocked multi-pass execution
                 # (learned flips/resizes persist in join_cfgs)
@@ -2321,372 +2552,6 @@ class TpuFragmentExec:
         # join/selection/projection/window root: compact by live on host
         return _compact_decode(host["cols"], host["live"],
                                root.schema.field_types, dicts_root)
-
-    def _run_fused_pipeline(self, root, caps, scans, ents, scan_inputs,
-                            scan_rows, flow_list, flows, aligned_inputs,
-                            join_cfgs, walk_joins, akb, gcap, max_cap,
-                            out_cap_max, ladder, anchor_i,
-                            scan_layouts=None, order_root=None,
-                            nested_rows=()) -> Optional[Chunk]:
-        """Whole-pipeline fusion: ONE traced XLA program per probe-anchor
-        slab covering scan → filter → project → join-probe → partial-agg,
-        plus one shared root-merge program — intermediates never leave
-        registers/HBM and the warm path launches ≤2 programs per slab.
-
-        Join build sides ride inside each per-slab program at their FULL
-        (mega-slab) capacities — dimension tables, or FK-aligned columns
-        already in the anchor's row space — so every launch joins a
-        partition of the probe rows against complete build sides and the
-        slab union of agg partials is exact for every join kind (tree_ok
-        pins outer joins to preserve the probe side, the same argument
-        that makes _run_tree_blocked's row-range passes exact).
-
-        RESUMABLE: per-slab partials are checkpoints. A lost unique bet
-        re-traces and re-runs every slab (the join's trace changed); an
-        expand-capacity resize or a group-cap overflow re-runs ONLY the
-        slabs that overflowed; a merged-count-only overflow re-runs zero
-        slabs (bigger-cap re-merge of the checkpoints). Returns None when
-        a join's fan-out exceeds out_cap_max — the caller's mega-slab
-        loop owns the blocked multi-pass escalation."""
-        import hashlib
-
-        from tidb_tpu.executor import tree_fragment as TF
-        from tidb_tpu.executor.device_cache import _pow2
-        from tidb_tpu.ops.jax_env import jax, jnp
-
-        ph = self.ctx.phases
-        vars_ = self.ctx.vars
-        anchor = scans[anchor_i]
-        a_ent = ents[anchor_i][0]
-        n_slabs, slab_cap = a_ent.n_slabs, a_ent.slab_cap
-        pipe_caps = dict(caps)
-        pipe_caps[id(anchor)] = (slab_cap, 1)
-        anchor_rows = scan_rows[anchor_i]
-        # zone-map pruning: _run_device_tree already zeroed the
-        # scan_rows entries of slabs the anchor scan's conjuncts prune
-        # (and charged the skip ledger), so a zero row count IS the
-        # skip signal — those slabs get no fused launch at all.
-        # run_ids are the surviving physical slab ids; every per-slab
-        # array below indexes POSITIONS in run_ids.
-        run_ids = [s for s in range(n_slabs) if int(anchor_rows[s]) > 0]
-        n_run = len(run_ids)
-        if not run_ids:
-            # every anchor slab pruned: zero fused launches — grouped
-            # agg → empty, global agg → the merge-of-zero-passes
-            # identity row (matches the CPU oracle)
-            inp_dicts = {i: d
-                         for i, d in enumerate(flows.get(id(root), []))}
-            chunk = self._merge_tree_agg_passes(root, [], inp_dicts)
-            if order_root is not None:
-                chunk = _host_order(chunk, order_root, root.schema)
-                chunk = _topn_slice(chunk, order_root)
-            return chunk
-        has_distinct = any(d.distinct and d.args for d in root.aggs)
-        want_pairs = has_distinct and n_slabs > 1
-        pair_cap = min(int(vars_.get("tidb_tpu_distinct_pair_cap", 65536)),
-                       slab_cap) if want_pairs else 0
-        use_fin = order_root is not None and \
-            _var_bool(vars_.get("tidb_tpu_fused_finalize", "on"))
-        # per-digest specialization (see _execute_agg): adopt the caps and
-        # learned join configs a previous execution of this statement
-        # shape settled on and reuse its exact pipeline signature
-        skey = None
-        lay_sig = ",".join(
-            f"{si}/{i}:{l.sig()}"
-            for si, slot in enumerate(scan_layouts or ())
-            for i, l in slot) if scan_layouts else "-"
-        if _var_bool(vars_.get("tidb_tpu_specialization_cache", "on")):
-            # layouts are NOT part of the key: a workload-adaptive
-            # re-choice must EVICT the stale entry (same statement shape,
-            # different physical layout), not shadow it — _spec_lookup
-            # compares the stored lay_sig and drops mismatches
-            skey = _spec_key(
-                getattr(self.ctx, "guard", None), "tree",
-                (tuple((id(e.td), getattr(e, "delta_version", 0),
-                        e.slab_cap, e.n_slabs) for e, _ in ents),
-                 anchor_i, bounds_sig(akb), want_pairs, use_fin,
-                 _order_sig(order_root) if order_root is not None
-                 else None, _plan_fingerprint(root)))
-        spec = _spec_lookup(skey, lay_sig)
-        if skey is not None:
-            _spec_note(ph, spec is not None)
-        spec_sig = None
-        if spec is not None:
-            gcap = spec["group_cap"]
-            pair_cap = spec["pair_cap"] if want_pairs else 0
-            join_cfgs[:] = list(spec["join_cfgs"])
-            spec_sig = spec["sig"]
-
-        # Joins whose aligned inputs live in the ANCHOR's row space — the
-        # only ones whose matched/column slabs may be sliced per anchor
-        # slab: the root's probe chain, plus recursively the build chains
-        # of its ALIGNED joins (_plan_aligned_joins re-anchored those to
-        # the fact row space via anchor_subs). An aligned join hanging
-        # off a non-aligned build subtree keeps its own fact scan's row
-        # space and passes its inputs through whole.
-        anchor_spaced: set = set()
-        stack = list(TF.aligned_chain(root.children[0])[1])
-        while stack:
-            j = stack.pop()
-            anchor_spaced.add(id(j))
-            ji = walk_joins.index(j)
-            if join_cfgs[ji].mode == "aligned":
-                bi = 1 if j.build_right else 0
-                stack.extend(TF.aligned_chain(j.children[bi])[1])
-
-        def slab_args(s):
-            si = list(scan_inputs)
-            si[anchor_i] = {i: [scan_inputs[anchor_i][i][s]]
-                            for i in scan_inputs[anchor_i]}
-            sr = list(scan_rows)
-            sr[anchor_i] = np.array([anchor_rows[s]], dtype=np.int32)
-            ai = []
-            for ji, jn in enumerate(walk_joins):
-                matched, jcols = aligned_inputs[ji]
-                if matched and id(jn) in anchor_spaced:
-                    ai.append(((matched[s],),
-                               {c: (sl[s],) for c, sl in jcols.items()}))
-                else:
-                    ai.append((matched, jcols))
-            return tuple(si), tuple(sr), tuple(ai)
-
-        from tidb_tpu.util import failpoint
-        partials: List = [None] * n_run
-        rows_in = 0                    # rows of every launched slab
-        # grouping by sorted runs: the slab programs only hand out rows
-        # (no group capacity in them), one sort serves the statement
-        rows_mode = grouping_mode(akb) == RUNS
-        sorted_rows = None
-        caps_ran = [0] * n_run         # group cap each partial ran at
-        pcaps = [0] * n_run            # pair cap each partial ran at
-        pairs_cache: List = [None] * n_run     # host distinct-pair sets
-        to_run: Optional[List[int]] = None     # None = cold first pass
-        n_joins = len(walk_joins)
-        while True:
-            grouping = _note_grouping(root, akb, gcap)
-            with timeline.span("frag.program", "frag"):
-                prog, pipe_sig = get_pipeline_program(
-                    root, pipe_caps, 0 if rows_mode else gcap, join_cfgs,
-                    akb, scan_layouts, want_pairs, pair_cap, sig=spec_sig)
-                prep_vals = prog.collect_preps(flow_list)
-            spec_sig = None
-            sig12 = hashlib.sha1(pipe_sig.encode()).hexdigest()[:12]
-            for s in (range(n_run) if to_run is None else to_run):
-                stale = partials[s]
-                si, sr, ai = slab_args(run_ids[s])
-                # slot per slab DISPATCH (async queue) — one labeled
-                # compute span per fused slab program in the trace
-                with self.ctx.device_slot():
-                    with ph.launch(prog.name, slab=run_ids[s],
-                                   sig=f"fused:{sig12}"):
-                        partials[s] = prog(si, sr, prep_vals, ai,
-                                           nested=nested_rows)
-                ph.note_launch()
-                ph.note_fused()
-                _count_agg_partial(grouping)
-                rows_in += int(anchor_rows[run_ids[s]])
-                sorted_rows = None
-                caps_ran[s] = gcap
-                pcaps[s] = pair_cap
-                pairs_cache[s] = None
-                if stale is not None:
-                    _tree_delete(stale)
-            if want_pairs:
-                # distinct (group, value) pair sets: fetch true counts,
-                # validate against the cap each slab ran at, then slice +
-                # fetch (mirrors _execute_agg — resumable "pairs" rung)
-                need = [s for s in range(n_run)
-                        if pairs_cache[s] is None]
-                if need:
-                    with ph.phase("fetch"):
-                        counts = jax.device_get(
-                            [{ai: partials[s]["pairs"][ai][1]
-                              for ai in partials[s]["pairs"]}
-                             for s in need])
-                    ph.add_d2h(tree_nbytes(counts))
-                    failpoint.inject("fused-finalize-overflow")
-                    pover = [s for si, s in enumerate(need)
-                             if any(int(c) > pcaps[s]
-                                    for c in counts[si].values())]
-                    if pover:
-                        if pair_cap >= slab_cap:
-                            ladder.fallback("pairs")
-                            raise FragmentFallback(
-                                "distinct pair overflow",
-                                reason="pair-cap")
-                        worst = max(int(c) for si, s in enumerate(need)
-                                    if s in pover
-                                    for c in counts[si].values())
-                        pair_cap = ladder.resize("pairs", pair_cap,
-                                                 need=worst,
-                                                 max_cap=slab_cap)
-                        ladder.attempt("pairs", _GroupCapOverflow(worst))
-                        ladder.partial_resume(
-                            "pairs", rerun=len(pover),
-                            reused=n_run - len(pover))
-                        to_run = pover
-                        continue
-                    with ph.phase("fetch"):
-                        sliced = [
-                            {ai: [(v[:int(counts[si][ai])],
-                                   m[:int(counts[si][ai])])
-                                  for v, m in partials[s]["pairs"][ai][0]]
-                             for ai in partials[s]["pairs"]}
-                            for si, s in enumerate(need)]
-                        per_slab = jax.device_get(sliced)
-                    ph.add_d2h(tree_nbytes(per_slab))
-                    for s, ps in zip(need, per_slab):
-                        pairs_cache[s] = ps
-            # per-slab partials + root merge/finalize build the whole
-            # device graph first; every control value returns in ONE
-            # batched fetch
-            from tidb_tpu.executor.device_emit import partials_of
-            if rows_mode:
-                out, sorted_rows = self._runs_finalize(
-                    root, order_root if use_fin else None, partials,
-                    n_slabs, gcap, akb, pipe_sig, sorted_rows)
-            elif use_fin:
-                # ONE launch for the whole query tail: agg merge →
-                # finalize expressions → root ORDER BY / TopN. It takes
-                # the partials as they are and stacks them in the trace
-                fprog, fsig = get_finalize_program(root, order_root,
-                                                   gcap, pipe_sig)
-                fsig12 = hashlib.sha1(fsig.encode()).hexdigest()[:12]
-                with _merge_span(partials, gcap), self.ctx.device_slot():
-                    with ph.launch(fprog.name,
-                                   sig=f"fused-final:{fsig12}"):
-                        out = fprog.run(*partials_of(partials))
-                ph.note_launch()
-            elif n_run == 1:
-                out = partials[0]
-            else:
-                mp = get_merge_program(root, gcap, pipe_sig)
-                with _merge_span(partials, gcap), self.ctx.device_slot():
-                    with ph.launch(mp.merge_name):
-                        out = mp.merge(*partials_of(partials))
-                ph.note_launch()
-            with self.ctx.device_slot():
-                with ph.glue():
-                    fetch = {"ngs": [p["n_groups"] for p in partials],
-                             "ng": out["n_groups"],
-                             "jus": [p["join_unique"] for p in partials],
-                             "jts": [p["join_totals"] for p in partials]}
-                    if use_fin:
-                        fetch["no"] = out["n_out"]
-                    small = not self._rows_on_device and \
-                        _piggyback_agg(
-                            fetch, out, int(out["keys"][0][0].shape[0])
-                            if rows_mode and out["keys"] else gcap)
-            with ph.drain():
-                jax.block_until_ready(fetch)
-            with ph.phase("fetch"):
-                got = jax.device_get(fetch)
-            ph.add_d2h(tree_nbytes(got))
-            # the fused-program capacity boundary: everything below
-            # classifies this round's overflows into rerun sets
-            failpoint.inject("fused-pipeline-overflow")
-            if use_fin:
-                # TopN k is a static trace constant, so the finalize
-                # itself cannot overflow — this site is defensive, and
-                # chaos injection proves a fault at the finalize
-                # boundary degrades to the CPU oracle
-                failpoint.inject("fused-finalize-overflow")
-            jts = np.asarray(got["jts"]).reshape(n_run, n_joins) \
-                if n_joins else np.zeros((n_run, 0), dtype=np.int64)
-            jus = np.asarray(got["jus"]).reshape(n_run, n_joins) \
-                if n_joins else np.zeros((n_run, 0), dtype=bool)
-            retry = False
-            charged = False
-            rerun: set = set()
-            for ji, cfg in enumerate(join_cfgs):
-                uq = bool(jus[:, ji].all())
-                tot = int(jts[:, ji].max()) if n_run else 0
-                new_cfg, action = TF.escalate_join(
-                    cfg, uq, tot, out_cap_max,
-                    flip_out_cap=_pow2(int(cfg.est * 1.3), lo=1024),
-                    ladder=ladder)
-                if action == "over-max":
-                    for p in partials:
-                        _tree_delete(p)
-                    if n_run > 1 or use_fin:
-                        _tree_delete(out)
-                    return None
-                if new_cfg is not None:
-                    join_cfgs[ji] = new_cfg
-                    retry = True
-                    if action == "flip":
-                        # the join's trace changed: every checkpoint is
-                        # from the wrong program — full re-run
-                        rerun.update(range(n_run))
-                    else:
-                        # exact resize: only slabs whose OWN fan-out
-                        # overflowed the old cap re-run
-                        rerun.update(s for s in range(n_run)
-                                     if int(jts[s, ji]) > cfg.out_cap)
-            n_final = int(got["ng"])
-            if grouping_mode(akb) != SLOTS:
-                over = [s for s in range(n_run)
-                        if int(got["ngs"][s]) > caps_ran[s]]
-                if over or n_final > gcap:
-                    if gcap >= max_cap:
-                        ladder.fallback("group")
-                        raise FragmentFallback("group cap overflow", reason="group-cap")
-                    # clipped slabs understate the merged count, so the
-                    # max overflowed per-slab count is the valid lower
-                    # bound; merged-only overflow is exact (rerun=0)
-                    need_cap = max([int(got["ngs"][s]) for s in over]
-                                   + [n_final])
-                    gcap = ladder.resize("group", gcap, need=need_cap,
-                                         max_cap=max_cap)
-                    ladder.attempt("group", _GroupCapOverflow(need_cap))
-                    ladder.partial_resume("group", rerun=len(over),
-                                          reused=n_run - len(over))
-                    charged = True
-                    rerun.update(over)
-                    retry = True
-            if retry:
-                if not charged:
-                    # budget + guard checkpoint between recompiles (the
-                    # join rungs above already recorded their own stats)
-                    ladder.attempt("fused")
-                if n_run > 1 or use_fin or rows_mode:
-                    _tree_delete(out)     # stale merge generation
-                to_run = sorted(rerun)
-                continue
-            break
-        cap_out = gcap
-        if rows_mode:
-            gcap = _tight_cap(gcap, n_final)
-        if skey is not None and (spec is None
-                                 or spec["group_cap"] != gcap
-                                 or spec["pair_cap"] != pair_cap
-                                 or list(spec["join_cfgs"]) != join_cfgs):
-            _spec_store(skey, {"group_cap": gcap, "pair_cap": pair_cap,
-                               "join_cfgs": tuple(join_cfgs),
-                               "sig": pipe_sig, "lay_sig": lay_sig})
-        _note_agg_io(out, rows_in, n_final)
-        if self._rows_on_device:
-            return _agg_rows(self.ctx, root, out, cap_out, pipe_sig, akb)
-        if root.group_exprs and n_final == 0:
-            from tidb_tpu.executor import _empty_chunk
-            return _empty_chunk(self.schema)
-        host_pairs = None
-        if want_pairs:
-            host_pairs = {ai: [pairs_cache[s][ai]
-                               for s in range(n_run)]
-                          for ai in pairs_cache[0]} \
-                if pairs_cache[0] else {}
-        inp_dicts = {i: d for i, d in enumerate(flows.get(id(root), []))}
-        host_tree = (got["keys"], got["states"]) if small else None
-        n_rows = int(got["no"]) if use_fin else n_final
-        with ph.phase("decode"):
-            chunk = self._agg_chunk(root, out, inp_dicts, max(n_rows, 1),
-                                    host_pairs, host_tree=host_tree)
-        if order_root is not None:
-            if not use_fin:
-                chunk = _host_order(chunk, order_root, root.schema)
-            chunk = _topn_slice(chunk, order_root)
-        return chunk
 
     def _run_tree_blocked(self, root, caps, join_cfgs, bji, walk_joins,
                           akb, gcap, max_cap, scans, ents, scan_inputs,
@@ -3420,78 +3285,74 @@ class TpuFragmentExec:
                 yield {i: cols[i] for i in used}, ent.slab_rows(s)
 
     # -- hash agg ------------------------------------------------------------
-    def _execute_agg(self, chain, root: PhysHashAgg, ent, dicts, stream,
-                     used, in_types, slab_cap, group_cap,
-                     key_bounds, layouts=None, order_root=None,
-                     slab_ids=None) -> Chunk:
-        """Grouped aggregation with RESUMABLE capacity escalation.
+    def _run_agg_slabs(self, src: _SlabSource, gcap: int, order_root,
+                       ladder) -> Optional[Chunk]:
+        """Every per-slab partial aggregate: ONE traced XLA program per
+        surviving slab of `src` (scan → filter → project → [join-probe →]
+        partial-agg) plus one root merge or finalize — intermediates never
+        leave registers/HBM and the warm path launches slabs + 1 programs.
+        An ORDER BY / TopN over the aggregate (`order_root`) is the fused
+        finalize's tail.
 
-        Per-slab partials are the checkpoint: on a group-cap overflow,
-        only the slabs whose true group count exceeded the cap they ran
-        at are re-executed after the exact-need recompile — partials that
-        fit merge back in untouched (ragged caps are fine: the merge
-        re-factorizes under slot_live masks). A merged-count-only
-        overflow re-runs ZERO slabs — the retry is just a bigger-cap
-        re-merge of the checkpointed partials. Only the re-run slabs cost
-        device time; each retry is still charged ONE recompile against
-        the ladder's backoff budget. EscalationStats.slabs_rerun/
-        slabs_reused make the reuse observable (EXPLAIN ANALYZE)."""
-        import hashlib
-
-        from tidb_tpu.ops.jax_env import jax, jnp
+        RESUMABLE capacity escalation: per-slab partials are the
+        checkpoints. On a group-cap overflow only the slabs whose TRUE
+        group count exceeded the cap they ran at re-execute after the
+        exact-need recompile — partials that fit merge back in untouched
+        (ragged caps are fine: the merge re-factorizes under slot_live
+        masks); a merged-count-only overflow re-runs ZERO slabs (a
+        bigger-cap re-merge of the checkpoints); a clipped DISTINCT pair
+        set re-runs the slabs that clipped; what else a source escalates
+        (a tree's join capacities) names its own re-run set. Each retry is
+        charged ONE recompile against the ladder's backoff budget, and
+        EscalationStats.slabs_rerun/slabs_reused make the reuse observable
+        (EXPLAIN ANALYZE). → None when the source gives the statement back
+        (a join's fan-out over out_cap_max)."""
+        from tidb_tpu.executor.device_emit import partials_of
+        from tidb_tpu.ops.jax_env import jax
         from tidb_tpu.util import failpoint
-        from tidb_tpu.util.escalation import CapacityLadder
         ph = self.ctx.phases
-        vars_ = self.ctx.vars
-        ladder = CapacityLadder(guard=getattr(self.ctx, "guard", None),
-                                stats=self.ctx.escalation)
-        n_slabs = ent.n_slabs
-        # zone-map survivors: partials/caps/pairs arrays index POSITIONS
-        # in slab_ids (ascending physical order — matches the cold
-        # stream's yield order); n_slabs stays the table geometry so
-        # signatures and capacity ceilings don't depend on pruning
-        slab_ids = list(slab_ids) if slab_ids is not None \
-            else list(range(n_slabs))
-        n_run = len(slab_ids)
-        cap_limit = slab_cap * max(n_slabs, 1)
-        has_distinct = any(d.distinct and d.args for d in root.aggs)
-        want_pairs = n_slabs > 1 and has_distinct
-        # pair-set output capacity: a slab can't emit more pairs than it
-        # has rows, so slab_cap is both the default clamp and the ladder's
-        # hard ceiling (resize through "pairs" rungs, never truncate)
-        pair_cap = min(int(vars_.get("tidb_tpu_distinct_pair_cap", 65536)),
+        root, key_bounds = src.root, src.key_bounds
+        if not src.run_ids:
+            # every slab pruned: ZERO launches — grouped agg → empty,
+            # global agg → the CPU oracle's identity row (COUNT 0,
+            # SUM/MIN/MAX NULL: the merge of zero passes)
+            chunk = self._merge_tree_agg_passes(root, [], src.dicts)
+            if order_root is not None:
+                chunk = _host_order(chunk, order_root, root.schema)
+                chunk = _topn_slice(chunk, order_root)
+            return chunk
+        n_run, slab_cap = len(src.run_ids), src.slab_cap
+        # multi-slab DISTINCT: the slab programs emit capped, deduped
+        # (group, args...) pair sets the host merges exactly. A slab can't
+        # emit more pairs than it has rows, so slab_cap is both the
+        # default clamp and the ladder's hard ceiling (resize through
+        # "pairs" rungs, never truncate)
+        want_pairs = src.n_slabs > 1 and \
+            any(d.distinct and d.args for d in root.aggs)
+        pair_cap = min(int(self.ctx.vars.get("tidb_tpu_distinct_pair_cap",
+                                             65536)),
                        slab_cap) if want_pairs else 0
-        use_fin = order_root is not None and \
-            _var_bool(vars_.get("tidb_tpu_fused_finalize", "on"))
-        # per-digest specialization: the second execution of this
-        # statement shape adopts the caps the first settled on and reuses
-        # its exact compile-cache signature, skipping both the ladder's
-        # discovery climb and signature construction. The key pins raw
-        # SQL (literals are trace constants), the data token (writes
-        # invalidate), geometry, layouts and key bounds — everything the
-        # signature would otherwise re-derive.
-        skey = None
-        lay_sig = ",".join(f"{i}:{l.sig()}"
-                           for i, l in sorted(layouts.items())) \
-            if layouts else "-"
-        if _var_bool(vars_.get("tidb_tpu_specialization_cache", "on")):
-            # layouts deliberately NOT in the key: a workload-adaptive
-            # layout re-choice must EVICT the old specialization (its
-            # cached signature names the stale physical layout), so
-            # _spec_lookup matches the stored lay_sig and evicts on drift
-            skey = _spec_key(
-                getattr(self.ctx, "guard", None), "chain",
-                (id(ent.td), getattr(ent, "delta_version", 0), slab_cap,
-                 n_slabs, bounds_sig(key_bounds), want_pairs, use_fin,
-                 _order_sig(order_root) if order_root is not None
-                 else None, _plan_fingerprint(chain[0])))
-        spec = _spec_lookup(skey, lay_sig)
+        use_fin = order_root is not None
+        # per-digest specialization (the cache's own comment, above): adopt
+        # the caps, and whatever else the source learned, that an earlier
+        # execution of this statement settled on, and reuse its signature.
+        # The key pins the data token (writes invalidate), geometry and key
+        # bounds — everything the signature would otherwise re-derive — and
+        # NOT the layouts, which _spec_lookup compares to evict on drift
+        skey = _spec_key(
+            getattr(self.ctx, "guard", None), src.kind,
+            src.geometry + (
+                bounds_sig(key_bounds), want_pairs,
+                _order_sig(order_root) if use_fin else None,
+                _plan_fingerprint(root)))
+        spec = _spec_lookup(skey, src.lay_sig)
         if skey is not None:
             _spec_note(ph, spec is not None)
         spec_sig = None
         if spec is not None:
-            group_cap = spec["group_cap"]
+            gcap = spec["group_cap"]
             pair_cap = spec["pair_cap"] if want_pairs else 0
+            src.adopt(spec)
             spec_sig = spec["sig"]
         partials: List = [None] * n_run
         rows_in = 0                     # rows of every launched slab
@@ -3499,73 +3360,28 @@ class TpuFragmentExec:
         # (no group capacity in them), one sort serves the statement
         rows_mode = grouping_mode(key_bounds) == RUNS
         sorted_rows = None
-        caps = [0] * n_run              # group cap each partial ran at
+        caps_ran = [0] * n_run          # group cap each partial ran at
         pcaps = [0] * n_run             # pair cap each partial ran at
         pairs_cache: List = [None] * n_run     # host distinct-pair sets
         to_run: Optional[List[int]] = None     # None = cold first pass
-        # pod-partitioned entry: each slab's partial computes on its
-        # owner device; re-pin every partial to the STATEMENT's device
-        # right after dispatch so the merge/finalize graph downstream
-        # (concatenate, piggyback packing, fetch) stays single-device —
-        # mixing committed arrays from different devices in one op raises
-        from tidb_tpu.executor import device_cache as _dc
-        pod_pin = _dc.device_handle(_dc._ctx_device(self.ctx)) \
-            if getattr(ent, "owners", None) is not None else None
-
-        def _pin(p):
-            return p if pod_pin is None else jax.device_put(p, pod_pin)
-
         while True:
-            grouping = _note_grouping(root, key_bounds, group_cap)
-            if spec_sig is not None:
-                psig, spec_sig = spec_sig, None
-            else:
-                psig = _chain_signature(chain, used, in_types, slab_cap,
-                                        0 if rows_mode else group_cap,
-                                        key_bounds, layouts) + \
-                    f"|pairs={want_pairs},{pair_cap}"
+            grouping = _note_grouping(root, key_bounds, gcap)
             with timeline.span("frag.program", "frag"):
-                prog = get_program(chain, used, in_types, slab_cap,
-                                   0 if rows_mode else group_cap,
-                                   key_bounds, want_pairs,
-                                   layouts, pair_cap, sig=psig)
-                prep_vals = prog.collect_preps(dicts)
-            cap_ran = group_cap if rows_mode else prog.group_cap
-            if to_run is None:
-                for s, (cols, n) in enumerate(
-                        self._slab_iter(ent, stream, prog.used_cols,
-                                        slab_ids)):
-                    # slot per slab DISPATCH: the streamed encode of the
-                    # next slab (inside _slab_iter) runs slot-free, so a
-                    # sibling's dispatch interleaves with our host work
-                    with self.ctx.device_slot():
-                        with ph.launch(prog.partial_name, slab=s):
-                            partials[s] = _pin(prog.partial(
-                                cols, jnp.int32(n), prep_vals))
-                    ph.note_launch()
-                    ph.note_fused()   # a chain partial IS a fused pipeline
-                    _count_agg_partial(grouping)
-                    rows_in += int(n)
-                    sorted_rows = None
-                    caps[s] = group_cap
-                    pcaps[s] = pair_cap
-            else:
-                for s in to_run:
-                    stale = partials[s]
-                    cols, n = self._slab(ent, slab_ids[s],
-                                         prog.used_cols)
-                    with self.ctx.device_slot():
-                        with ph.launch(prog.partial_name, slab=s):
-                            partials[s] = _pin(prog.partial(
-                                cols, jnp.int32(n), prep_vals))
-                    ph.note_launch()
-                    ph.note_fused()
-                    _count_agg_partial(grouping)
-                    rows_in += int(n)
-                    sorted_rows = None
-                    caps[s] = group_cap
-                    pcaps[s] = pair_cap
-                    pairs_cache[s] = None
+                prog, sig, prep_vals = src.program(
+                    0 if rows_mode else gcap, pair_cap, want_pairs,
+                    spec_sig)
+            spec_sig = None
+            for s, part in src.launches(prog, prep_vals, to_run):
+                stale, partials[s] = partials[s], part
+                ph.note_launch()
+                ph.note_fused()   # a chain partial IS a fused pipeline
+                _count_agg_partial(grouping)
+                rows_in += src.rows(s)
+                sorted_rows = None
+                caps_ran[s] = gcap
+                pcaps[s] = pair_cap
+                pairs_cache[s] = None
+                if stale is not None:
                     _tree_delete(stale)
             if want_pairs:
                 # per-slab deduped (group, value) pair sets ride inside
@@ -3592,7 +3408,9 @@ class TpuFragmentExec:
                     if pover:
                         if pair_cap >= slab_cap:
                             ladder.fallback("pairs")
-                            raise FragmentFallback("distinct pair overflow", reason="pair-cap")
+                            raise FragmentFallback(
+                                "distinct pair overflow",
+                                reason="pair-cap")
                         worst = max(int(c) for si, s in enumerate(need)
                                     if s in pover
                                     for c in counts[si].values())
@@ -3619,47 +3437,45 @@ class TpuFragmentExec:
             # build the whole device graph FIRST (per-slab partials +
             # merge — no host sync in between), then fetch every control
             # value in ONE batched round trip: the latency is paid per
-            # device_get, not per array. Per-slab n_groups
-            # must still be checked: a slab whose distinct-group count
-            # exceeds the cap it ran at clips gids (factorize clamps to
-            # cap-1), silently conflating groups, while the merged
-            # n_groups alone can look fine.
-            from tidb_tpu.executor.device_emit import partials_of
+            # device_get, not per array
             if rows_mode:
                 out, sorted_rows = self._runs_finalize(
-                    root, order_root if use_fin else None, partials,
-                    n_slabs, group_cap, key_bounds, psig, sorted_rows)
-            elif use_fin:
-                # ONE launch for the whole query tail: agg merge →
-                # finalize expressions → root ORDER BY / TopN. It takes
-                # the partials as they are and stacks them in the trace
-                fprog, fsig = get_finalize_program(root, order_root,
-                                                   group_cap, psig)
-                fsig12 = hashlib.sha1(fsig.encode()).hexdigest()[:12]
-                with _merge_span(partials, group_cap), \
-                        self.ctx.device_slot():
-                    with ph.launch(fprog.name,
-                                   sig=f"fused-final:{fsig12}"):
-                        out = fprog.run(*partials_of(partials))
-                ph.note_launch()
-            elif n_run == 1:
+                    root, order_root, partials, src.n_slabs, gcap,
+                    key_bounds, sig, sorted_rows)
+            elif n_run == 1 and not use_fin:
                 out = partials[0]
             else:
-                with _merge_span(partials, group_cap), \
+                if use_fin:
+                    # ONE launch for the whole query tail: agg merge →
+                    # finalize expressions → root ORDER BY / TopN
+                    fprog, fsig = get_finalize_program(root, order_root,
+                                                       gcap, sig)
+                    tail, name, tag = fprog.run, fprog.name, \
+                        _sig_tag("fused-final", fsig)
+                else:
+                    mp = src.merge_program(prog, gcap, sig)
+                    tail, name, tag = mp.merge, mp.merge_name, None
+                # either takes the partials as they are and stacks them in
+                # the trace: `slots_in` partial slots reduce into `slots_out`
+                with timeline.span(
+                        "frag.merge", "frag", slots_out=int(gcap),
+                        slots_in=sum(int(p["slot_live"].shape[0])
+                                     for p in partials)), \
                         self.ctx.device_slot():
-                    with ph.launch(prog.merge_name):
-                        out = prog.merge(*partials_of(partials))
+                    with ph.launch(name, sig=tag):
+                        out = tail(*partials_of(partials))
                 ph.note_launch()
             with self.ctx.device_slot():
                 with ph.glue():
                     fetch = {"ngs": [p["n_groups"] for p in partials],
-                             "ng": out["n_groups"]}
+                             "ng": out["n_groups"],
+                             **src.control(partials)}
                     if use_fin:
                         fetch["no"] = out["n_out"]
                     small = not self._rows_on_device and \
                         _piggyback_agg(
                             fetch, out, int(out["keys"][0][0].shape[0])
-                            if rows_mode and out["keys"] else cap_ran)
+                            if rows_mode and out["keys"] else gcap)
             with ph.drain():
                 # drain inside "compute" so the flag fetch below measures
                 # pure transfer, not the device finishing its work — but
@@ -3669,92 +3485,95 @@ class TpuFragmentExec:
             with ph.phase("fetch"):
                 got = jax.device_get(fetch)
             ph.add_d2h(tree_nbytes(got))
+            # the slab programs' capacity boundary: everything below
+            # classifies this round's overflows into re-run sets
+            failpoint.inject("fused-pipeline-overflow")
             if use_fin:
-                # TopN k-overflow validation: k = min(count+offset, cap)
-                # is static and n_groups overflow resizes through the
-                # group rung below, so this site is defensive — but it is
-                # the fused finalize's capacity boundary, and chaos
-                # injection proves the raise path degrades to the CPU
-                # oracle
+                # TopN k is a static trace constant and an n_groups
+                # overflow resizes through the group rung below, so the
+                # finalize itself cannot overflow — this site is
+                # defensive, and chaos injection proves a fault at the
+                # finalize boundary degrades to the CPU oracle
                 failpoint.inject("fused-finalize-overflow")
-            # overflow iff a slab's TRUE count exceeded the cap IT ran at
-            # (factorize counts before clamping, so per-slab ngs are true;
-            # reused partials ran at an older, smaller cap and stay valid)
-            over = [s for s in range(n_run)
-                    if int(got["ngs"][s]) > caps[s]]
-            n_final = int(got["ng"])
-            if over:
-                if group_cap >= cap_limit:
-                    ladder.fallback("group")
-                    raise FragmentFallback("group cap overflow", reason="group-cap")
-                # the MERGED count may be understated when slabs clipped,
-                # so the max overflowed per-slab count is a valid lower
-                # bound — the ladder resizes to it exactly and re-checks
-                need_cap = max(int(got["ngs"][s]) for s in over)
-                group_cap = ladder.resize("group", group_cap,
-                                          need=need_cap,
-                                          max_cap=cap_limit)
-                ladder.attempt("group", _GroupCapOverflow(need_cap))
-                ladder.partial_resume("group", rerun=len(over),
-                                      reused=n_run - len(over))
-                if n_run > 1 or use_fin:
-                    _tree_delete(out)     # stale merge generation
-                to_run = over
-                continue
-            if n_final > cap_ran:
-                # only the MERGED distinct count overflowed: every slab
-                # partial is a valid checkpoint — re-run NOTHING, just
-                # re-merge at the exact-need cap
-                if group_cap >= cap_limit:
-                    ladder.fallback("group")
-                    raise FragmentFallback("group cap overflow", reason="group-cap")
-                group_cap = ladder.resize("group", group_cap,
-                                          need=n_final,
-                                          max_cap=cap_limit)
-                ladder.attempt("group", _GroupCapOverflow(n_final))
-                ladder.partial_resume("group", rerun=0, reused=n_run)
-                if n_run > 1 or use_fin or rows_mode:
+            esc = src.escalate(got, ladder)
+            if esc is None:
+                for p in partials:
+                    _tree_delete(p)
+                if out is not partials[0]:
                     _tree_delete(out)
-                to_run = []
+                return None
+            retry, rerun = esc
+            charged = False
+            n_final = int(got["ng"])
+            if grouping_mode(key_bounds) != SLOTS:
+                # a slab overflowed iff its TRUE count exceeded the cap IT
+                # ran at (factorize counts before clamping to cap-1, which
+                # silently conflates groups while the merged n_groups can
+                # look fine; reused partials ran at an older, smaller cap
+                # and stay valid)
+                over = [s for s in range(n_run)
+                        if int(got["ngs"][s]) > caps_ran[s]]
+                if over or n_final > gcap:
+                    if gcap >= src.max_cap:
+                        ladder.fallback("group")
+                        raise FragmentFallback("group cap overflow",
+                                               reason="group-cap")
+                    # clipped slabs understate the merged count, so the
+                    # max overflowed per-slab count is the valid lower
+                    # bound — the ladder resizes to it exactly and
+                    # re-checks; a merged-only overflow is exact and
+                    # re-runs NOTHING: every slab partial is a valid
+                    # checkpoint, re-merged at the exact-need cap
+                    need_cap = max([int(got["ngs"][s]) for s in over]
+                                   + [n_final])
+                    gcap = ladder.resize("group", gcap, need=need_cap,
+                                         max_cap=src.max_cap)
+                    ladder.attempt("group", _GroupCapOverflow(need_cap))
+                    ladder.partial_resume("group", rerun=len(over),
+                                          reused=n_run - len(over))
+                    charged = True
+                    rerun.update(over)
+                    retry = True
+            if retry:
+                if not charged:
+                    # budget + guard checkpoint between recompiles (the
+                    # source's rungs already recorded their own stats)
+                    ladder.attempt("fused")
+                if out is not partials[0]:
+                    _tree_delete(out)     # stale merge generation
+                to_run = sorted(rerun)
                 continue
             break
+        cap_out = gcap
         if rows_mode:
-            group_cap = _tight_cap(group_cap, n_final)
-        if skey is not None and (spec is None
-                                 or spec["group_cap"] != group_cap
-                                 or spec["pair_cap"] != pair_cap):
-            _spec_store(skey, {"group_cap": group_cap,
-                               "pair_cap": pair_cap, "sig": psig,
-                               "lay_sig": lay_sig})
+            gcap = _tight_cap(gcap, n_final)
+        ent = {"group_cap": gcap, "pair_cap": pair_cap, "sig": sig,
+               "lay_sig": src.lay_sig, **src.learned()}
+        if skey is not None and spec != ent:
+            _spec_store(skey, ent)
+        _note_agg_io(out, rows_in, n_final)
+        if self._rows_on_device:
+            return _agg_rows(self.ctx, root, out, cap_out, sig, key_bounds)
+        if root.group_exprs and n_final == 0:
+            from tidb_tpu.executor import _empty_chunk
+            return _empty_chunk(self.schema)
         host_pairs = None
         if want_pairs:
             host_pairs = {ai: [pairs_cache[s][ai]
                                for s in range(n_run)]
                           for ai in pairs_cache[0]} \
                 if pairs_cache[0] else {}
-        _note_agg_io(out, rows_in, n_final)
-        if self._rows_on_device:
-            return _agg_rows(self.ctx, root, out, cap_ran, psig,
-                             key_bounds)
-        if root.group_exprs and n_final == 0:
-            from tidb_tpu.executor import _empty_chunk
-            return _empty_chunk(self.schema)
         host_tree = (got["keys"], got["states"]) if small else None
         n_rows = int(got["no"]) if use_fin else n_final
         with ph.phase("decode"):
-            chunk = self._agg_chunk(root, out, dicts, max(n_rows, 1),
+            chunk = self._agg_chunk(root, out, src.dicts, max(n_rows, 1),
                                     host_pairs, host_tree=host_tree)
-        if order_root is not None:
-            if not use_fin:
-                # finalize gate off: device agg as before, then a host
-                # re-order of the (small) final group rows
-                chunk = _host_order(chunk, order_root, root.schema)
+        if use_fin:
             chunk = _topn_slice(chunk, order_root)
         return chunk
 
     def _agg_chunk(self, root: PhysHashAgg, out, dicts, n_final,
                    distinct_pairs=None, host_tree=None) -> Chunk:
-        from tidb_tpu.ops.jax_env import jax
         if host_tree is not None:
             # keys/states already came back WITH the flag fetch (small
             # group caps piggyback on round trip #1); slice the padding
@@ -3901,12 +3720,8 @@ class _GroupCapOverflow(Exception):
         self.need = int(need)
 
 
-# Device execution time of the most recent fragment run (seconds), set by
-# TpuFragmentExec.next — lets the bench separate device compute+transfer
-# from host decode/planning (report exec-only time).
-LAST_DEVICE_EXEC_S: float = 0.0
 # PhaseTimer of the most recent device fragment run (encode/upload/compute/
-# fetch/decode seconds + overlap efficiency), for bench.py and tests.
+# fetch/decode seconds + overlap efficiency), for tests.
 LAST_PHASES = None
 
 

@@ -103,8 +103,8 @@ information_schema.processlist, EXPLAIN ANALYZE runtime info, and the
 per-class `sched-queue:<class>` timeline lanes.
 
 Counters: `stats()` / `reset_stats()` snapshot and clear under the same
-condition lock every mutation takes, so bench.py and tests never read a
-torn admissions/wait_s_total pair against concurrent dispatchers. Each
+condition lock every mutation takes, so the benchmark's
+`sched_wait_ms_per_op` reader and tests never read a torn admissions/wait_s_total pair against concurrent dispatchers. Each
 counter also keeps a per-class breakdown (`stats()["classes"]`).
 """
 
@@ -183,8 +183,8 @@ class DeviceScheduler:
         self._last_conn: Optional[int] = None
         self._consecutive = 0
         self.fairness_cap = fairness_cap
-        # cumulative counters (bench.py and tests read them through
-        # stats() — every mutation AND every read happens under _cv)
+        # cumulative counters (read through stats() — every mutation AND
+        # every read happens under _cv)
         self.admissions = 0
         self.waits = 0               # admissions that actually queued
         self.wait_s_total = 0.0
@@ -803,8 +803,8 @@ class SchedulerPool:
 
 
 POOL = SchedulerPool(1)
-# the single-device default queue — the module-level handle tests and
-# bench.py address directly (POOL.schedulers[0] is always this object)
+# the single-device default queue — the module-level handle tests
+# address directly (POOL.schedulers[0] is always this object)
 SCHEDULER = POOL.schedulers[0]
 
 

@@ -23,7 +23,7 @@ Per query the sweep reports:
 
 `tools/check_coverage.py` compares a fresh sweep against the committed
 COVERAGE.json baseline and fails when a query that was fused regresses
-to fallback; bench.py embeds the same table at benchmark scale.
+to fallback.
 """
 
 from __future__ import annotations
@@ -234,7 +234,7 @@ EXPECTED_FALLBACK: Dict[str, str] = {
 
 def build_schema(s, n_lineitem: int = 6000, seed: int = 42) -> None:
     """Create and populate all eight TPC-H tables at a size proportional
-    to `n_lineitem` (SF≈n/6M), via direct chunk appends like bench.py."""
+    to `n_lineitem` (SF≈n/6M), via direct chunk appends."""
     from tidb_tpu.chunk import Chunk, Column
 
     eng = s.engine if hasattr(s, "engine") else s._engine
@@ -451,7 +451,7 @@ def run_coverage(s, time_cpu: bool = True,
 
 
 def coverage_table(rows: List[dict]) -> str:
-    """Render the per-query table bench.py embeds in its log output."""
+    """Render the per-query table (`python -m tidb_tpu.tools.coverage`)."""
     hdr = (f"{'query':<6}{'fused':<7}{'frags':<7}{'fallback':<15}"
            f"{'prog/slab':<11}{'speedup':<8}")
     lines = [hdr, "-" * len(hdr)]

@@ -1,7 +1,7 @@
 """The repo's TPC-H-shaped deployment: schema, generator, loader, queries.
 
-One definition shared by `bench.py` and `chip_smoke.py` (the
-mockDataSource pattern of the reference's executor/benchmark_test.go — the
+`chip_smoke.py`'s definition; the benchmark keeps its own copy under
+`benchmarks/datasets/` (the mockDataSource pattern of the reference's executor/benchmark_test.go — the
 generated columns go straight into the columnar region store through its
 bulk append, no SQL INSERT round trip).
 
@@ -152,9 +152,8 @@ def load(eng, data: dict) -> None:
 
 def build_engine(n_rows: int, seed: int = 42):
     """→ (engine, session) with the three tables loaded and analyzed, plus
-    the small point-read table `pr` of bench.py's priority serving-tier
-    section (same-digest `WHERE k = ?` probes are the interactive class and
-    the micro-batch coalescing substrate)."""
+    the small point-read table `pr` (same-digest `WHERE k = ?` probes are
+    the interactive class and the micro-batch coalescing substrate)."""
     from tidb_tpu.session import Engine
 
     eng = Engine()

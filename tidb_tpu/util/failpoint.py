@@ -125,10 +125,11 @@ register("exchange-degraded-replan", "entry of degraded-mesh mode for an "
          "exchange-carrying fragment: the failed rank's partition or "
          "probe stage re-plans onto a surviving device "
          "(executor/dist_fragment.py StagedDistExchange)", mesh_only=True)
-register("fused-pipeline-overflow", "capacity boundary of the fused "
-         "per-slab pipeline driver — hit after every round's batched flag "
-         "fetch, right before join/group overflows are classified into "
-         "rerun sets (executor/fragment.py _run_fused_pipeline)")
+register("fused-pipeline-overflow", "capacity boundary of the slab-loop "
+         "aggregate driver, over a chain's slabs as over a join tree's — "
+         "hit after every round's batched flag fetch, right before "
+         "join/group overflows are classified into rerun sets "
+         "(executor/fragment.py _run_agg_slabs)")
 register("compressed-decode-mismatch", "layout-descriptor validation of "
          "the compressed device-resident columns a statement is about to "
          "decode — a value here models a corrupted descriptor, which must "
@@ -139,8 +140,7 @@ register("fused-finalize-overflow", "TopN / distinct-pair-cap validation "
          "distinct-pair count check (before clipped pair sets could be "
          "consumed) and after the finalize's flag fetch; overflow resizes "
          "through the resumable 'pairs' ladder rung, re-running only the "
-         "slabs that clipped (executor/fragment.py _execute_agg / "
-         "_run_fused_pipeline)")
+         "slabs that clipped (executor/fragment.py _run_agg_slabs)")
 register("delta-append", "atomic apply point of a staged write — hit "
          "inside Store.commit after validation, before the locked "
          "apply+version bump; a retryable raise here heals through the "

@@ -1,9 +1,9 @@
-"""Per-query roofline accounting (PAPER.md §roofline; bench.py's
-device-roofline section generalized to every statement).
+"""Per-query roofline accounting (PAPER.md §roofline), for EXPLAIN
+ANALYZE. The benchmark does not use it: `scan_hbm_share` divides by the
+published peak in `benchmarks/peaks.json` (PERF.md §3).
 
 A scan-bound query's floor is `bytes the program must move / sustained
-stream bandwidth`.  bench.py measures the device-HBM roofline offline
-with a big triad; for in-engine attribution we need something cheap
+stream bandwidth`.  For in-engine attribution we need something cheap
 enough to run lazily inside a session, so `measured_gbs()` times a
 single ~64 MiB device round trip once per process and caches it.  The
 per-query figure is then
@@ -68,9 +68,7 @@ def _measure() -> float:
 
 
 def set_measured_gbs(gbs: float) -> None:
-    """Override the cached bandwidth (bench.py injects its own big-triad
-    measurement so bench roofline fractions use the same denominator as
-    its roofline section; tests inject a constant)."""
+    """Override the cached bandwidth (tests inject a constant)."""
     global _GBS
     with _LOCK:
         _GBS = float(gbs)

@@ -104,9 +104,6 @@ def run(root: str = None):
         for dirpath, _dirs, files in os.walk(os.path.join(root, sub)):
             targets.extend(os.path.join(dirpath, f) for f in files
                            if f.endswith(".py"))
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        targets.append(bench)
 
     problems = []
     injects, dynamic, register_files = [], [], []

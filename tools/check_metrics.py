@@ -119,7 +119,7 @@ def check_file(path: str):
 
 
 def run(root: str = None):
-    """Lint every .py under the package + bench/tools. → problem list."""
+    """Lint every .py under the package + tools. → problem list."""
     if root is None:
         root = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                             "..")
@@ -129,9 +129,6 @@ def run(root: str = None):
         for dirpath, _dirs, files in os.walk(os.path.join(root, sub)):
             targets.extend(os.path.join(dirpath, f) for f in files
                            if f.endswith(".py"))
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        targets.append(bench)
     problems = []
     for path in sorted(targets):
         problems.extend(check_file(path))
