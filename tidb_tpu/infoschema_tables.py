@@ -187,10 +187,9 @@ def _partitions(session):
             continue
         counts = {k: 0 for k in range(p.n_parts)}
         if snap.has_table(t.id):
-            for r, alive in snap.scan(t.id):
+            for r in snap.table_data(t.id).regions:
                 if r.part is not None:
-                    counts[r.part] = counts.get(r.part, 0) + \
-                        int(alive.sum())
+                    counts[r.part] = counts.get(r.part, 0) + r.live_rows
         for i, name in enumerate(p.names):
             if p.kind == "range":
                 b = p.bounds[i]

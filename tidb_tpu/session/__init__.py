@@ -537,8 +537,10 @@ class _PlanContext:
         self.tracer = session._tracer     # optimizer-trace sink
 
     def table_row_count(self, table_id: int) -> int:
-        # exact live rows from the columnar store — cheap and fresher than
-        # any analyzed count (the reference must estimate; we needn't)
+        # exact live rows from the columnar store — fresher than any
+        # analyzed count (the reference must estimate; we needn't), and a
+        # field read: TableData sums the counts its regions carry when a
+        # commit builds it, so no deletion bitmap is scanned here
         snap = self.session._read_view_snapshot()
         if snap.has_table(table_id):
             return snap.table_data(table_id).live_rows
@@ -1182,7 +1184,9 @@ class Session:
         per-execution), inside an explicit transaction, or no statement
         text available. Referenced-table live row counts are part of the
         key — cardinality estimates bake into the plan (fragment routing,
-        join order), so any size change must re-plan."""
+        join order), so any size change must re-plan. The count is a
+        stored field of the snapshot's TableData (storage.Region carries
+        its own), so a hit costs one integer a table, whatever its rows."""
         if not isinstance(stmt, (ast.SelectStmt, ast.SetOpStmt)):
             return None
         if self._cte_map or self._current_sql is None or \

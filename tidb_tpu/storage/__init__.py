@@ -23,13 +23,14 @@ from __future__ import annotations
 import itertools
 import threading
 import time as _time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from tidb_tpu.chunk import Chunk, Column
 from tidb_tpu.errors import DeadlockError, TxnError, UnknownTableError
+from tidb_tpu.util.observability import REGISTRY
 
 REGION_ROWS = 1 << 16  # region split threshold (ref: TiKV region ~96MB)
 
@@ -41,29 +42,45 @@ class Region:
     table partition every row of this region belongs to (INSERT routes
     rows so regions never mix partitions — region-level colocation is the
     pruning unit, the slab-native analog of a partition's own region set
-    in table/tables/partition.go)."""
+    in table/tables/partition.go).
+
+    `live_rows` (rows whose `deleted` bit is clear) is a stored fact, fixed
+    with the bitmap: every site of `Store` that makes a region knows the
+    count and hands it over, so no reader scans `deleted` for it. Left
+    out (tests, tools), the bitmap is counted once, here, and
+    `tidb_tpu_live_rows_recounts_total` says that it was."""
 
     id: int
     chunk: Chunk
     deleted: np.ndarray  # bool (n_rows,)
     part: Optional[int] = None
+    live_rows: Optional[int] = None
+
+    def __post_init__(self):
+        if self.live_rows is None:
+            REGISTRY.inc("tidb_tpu_live_rows_recounts_total")
+            object.__setattr__(
+                self, "live_rows",
+                len(self.deleted) - int(np.count_nonzero(self.deleted)))
 
     @property
     def num_rows(self) -> int:
         return self.chunk.num_rows
 
-    @property
-    def live_rows(self) -> int:
-        return int((~self.deleted).sum())
-
 
 @dataclass(frozen=True)
 class TableData:
-    regions: Tuple[Region, ...]
+    """A table's regions, with the sum of their live rows made once, when
+    the tuple is (a commit rebuilds the tuple anyway): `live_rows` is a
+    field read for the plan-cache key, the planner's estimate and the
+    introspection tables."""
 
-    @property
-    def live_rows(self) -> int:
-        return sum(r.live_rows for r in self.regions)
+    regions: Tuple[Region, ...]
+    live_rows: int = field(init=False)
+
+    def __post_init__(self):
+        object.__setattr__(self, "live_rows",
+                           sum(r.live_rows for r in self.regions))
 
 
 class Snapshot:
@@ -275,7 +292,7 @@ class Store:
                                            chunk.num_rows))
             if (regions and regions[-1].num_rows + piece.num_rows
                     <= REGION_ROWS
-                    and not regions[-1].deleted.any()
+                    and regions[-1].live_rows == regions[-1].num_rows
                     and regions[-1].part == part
                     and regions[-1].chunk.num_cols == piece.num_cols):
                 # layouts must match: a region written before ADD COLUMN
@@ -286,11 +303,11 @@ class Store:
                 merged = Chunk.concat([last.chunk, piece])
                 regions[-1] = Region(last.id, merged,
                                      np.zeros(merged.num_rows, dtype=bool),
-                                     part)
+                                     part, merged.num_rows)
             else:
                 regions.append(Region(next(self._region_ids), piece,
                                       np.zeros(piece.num_rows, dtype=bool),
-                                      part))
+                                      part, piece.num_rows))
         self._tables[table_id] = TableData(tuple(regions))
 
     GC_DEAD_RATIO = 0.5     # compact when half a table is tombstones
@@ -320,21 +337,20 @@ class Store:
         if td is None or not td.regions:
             return
         total = sum(r.num_rows for r in td.regions)
-        dead = sum(int(r.deleted.sum()) for r in td.regions)
+        dead = total - td.live_rows
         if total == 0 or dead / total < self.GC_DEAD_RATIO:
             return
         regions = []
         for r in td.regions:
-            if not r.deleted.any():
+            if r.live_rows == r.num_rows:
                 regions.append(r)
                 continue
-            alive = ~r.deleted
-            if not alive.any():
+            if r.live_rows == 0:
                 continue            # fully dead region vanishes
-            kept = r.chunk.take(np.nonzero(alive)[0])
+            kept = r.chunk.take(np.nonzero(~r.deleted)[0])
             regions.append(Region(next(self._region_ids), kept,
                                   np.zeros(kept.num_rows, dtype=bool),
-                                  r.part))
+                                  r.part, kept.num_rows))
         self._tables[table_id] = TableData(tuple(regions))
 
     def drop_partition_rows(self, table_id: int, ordinal: int,
@@ -356,21 +372,23 @@ class Store:
                 if remap is not None and r.part is not None:
                     new_part = remap.get(r.part, r.part)
                     if new_part != r.part:
-                        r = Region(r.id, r.chunk, r.deleted, new_part)
+                        r = Region(r.id, r.chunk, r.deleted, new_part,
+                                   r.live_rows)
                 kept.append(r)
             self._tables[table_id] = TableData(tuple(kept))
             self._bump_locked()
             return removed
 
     def gc_stats(self, table_id: int):
-        """(live_rows, dead_rows, regions) — observability hook."""
+        """(live_rows, dead_rows, regions) — observability hook. Reads the
+        counts the regions carry: no bitmap is scanned, and the lock is
+        held for one dict lookup, so `snapshot()` never waits for it."""
         with self._lock:
             td = self._tables.get(table_id)
-            if td is None:
-                return (0, 0, 0)
-            total = sum(r.num_rows for r in td.regions)
-            dead = sum(int(r.deleted.sum()) for r in td.regions)
-            return (total - dead, dead, len(td.regions))
+        if td is None:
+            return (0, 0, 0)
+        total = sum(r.num_rows for r in td.regions)
+        return (td.live_rows, total - td.live_rows, len(td.regions))
 
     def _pad_mask(self, mask: np.ndarray, region: Region) -> np.ndarray:
         """A staged mask may be shorter than the region if rows were appended
@@ -414,9 +432,10 @@ class Store:
                 continue
             r = regions[idx]
             mask = self._pad_mask(mask, r)
-            effective = mask & ~r.deleted
-            deleted_count += int(effective.sum())
-            regions[idx] = Region(r.id, r.chunk, r.deleted | mask)
+            effective = int(np.count_nonzero(mask & ~r.deleted))
+            deleted_count += effective
+            regions[idx] = Region(r.id, r.chunk, r.deleted | mask, r.part,
+                                  r.live_rows - effective)
         self._tables[table_id] = TableData(tuple(regions))
         return deleted_count
 
@@ -476,7 +495,8 @@ class Store:
 
     # ---- introspection ---------------------------------------------------
     def stats(self) -> Dict[int, Tuple[int, int]]:
-        """table_id → (regions, live rows)."""
+        """table_id → (regions, live rows): two field reads a table, no
+        bitmap scanned under the lock."""
         with self._lock:
             return {tid: (len(td.regions), td.live_rows)
                     for tid, td in self._tables.items()}
