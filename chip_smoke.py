@@ -420,6 +420,31 @@ def phase_serve(eng, data, device: dict, meter: CompileMeter) -> None:
             raise SmokeFailed(f"the written row's key matches {clash} "
                               f"generated row(s); pick another --seed")
         count_sql = "SELECT COUNT(*) FROM lineitem"
+
+        def write_path():
+            """(extensions, declines by gate) of the device cache so far:
+            the written row must EXTEND the resident table. One inserted
+            row once cost the next Q6 22.3 s and a 226 MB re-upload (my
+            chip run, PR 23) with nothing but the clock to say so."""
+            _, rows = cli.query(
+                "SELECT METRIC, LABELS, VALUE FROM "
+                "information_schema.engine_metrics WHERE METRIC LIKE "
+                "'tidb_tpu_delta_%'")
+            ext = sum(float(v) for m, _l, v in rows
+                      if m == "tidb_tpu_delta_extensions_total")
+            fell = {l: float(v) for m, l, v in rows
+                    if m == "tidb_tpu_delta_declines_total"}
+            return ext, fell
+
+        def check_extended(before, what: str) -> dict:
+            ext, fell = write_path()
+            check(fell == before[1], f"the read after the {what} fell to "
+                  f"a rebuild: declines {before[1]} -> {fell}")
+            check(ext > before[0], f"the read after the {what} extended "
+                  f"nothing (extensions {before[0]:g} -> {ext:g})")
+            return {"extensions": ext - before[0], "declines": fell}
+
+        before = write_path()
         cli.execute(
             "INSERT INTO lineitem VALUES "
             f"({fmt_dec(w['qty'], 2)}, {fmt_dec(w['price'], 2)}, "
@@ -430,16 +455,19 @@ def phase_serve(eng, data, device: dict, meter: CompileMeter) -> None:
         check(with_row != expect["Q6"], "the written row must move Q6")
         rec = run_statement(cli, meter, "Q6+insert", T.Q6, with_row,
                             reps=("after_insert",))
+        rec["write_path"] = check_extended(before, "INSERT")
         _, cnt = cli.query(count_sql)
         rec["count"] = cnt[0][0]
         check(cnt == [(str(n + 1),)], f"COUNT(*) after INSERT: {cnt}")
         emit("write", device=device["kind"], **rec)
+        before = write_path()
         cli.execute(
             f"DELETE FROM lineitem WHERE l_orderkey = {w['okey']} "
             f"AND l_extendedprice = {fmt_dec(w['price'], 2)} "
             f"AND l_shipdate = '{w['ship']}'")
         rec = run_statement(cli, meter, "Q6+delete", T.Q6, expect["Q6"],
                             reps=("after_delete",))
+        rec["write_path"] = check_extended(before, "DELETE")
         _, cnt = cli.query(count_sql)
         rec["count"] = cnt[0][0]
         check(cnt == [(str(n),)], f"COUNT(*) after DELETE: {cnt}")

@@ -195,3 +195,96 @@ def test_blocked_expand_inside_build_subtree_is_safe():
     finally:
         _off(s)
     assert got == want, (got, want)
+
+
+# ---- a structure follows both tables' generations, or is rebuilt: never
+# ---- stale positions, and every rebuild is counted -----------------------
+
+JQ = ("SELECT prio, COUNT(*), SUM(price) FROM l JOIN o ON lk = ok "
+      "WHERE d < 70 GROUP BY prio ORDER BY prio")
+
+
+def _pair():
+    eng = Engine()
+    eng.global_vars["tidb_enable_auto_analyze"] = False
+    s = eng.new_session()
+    s.execute("CREATE TABLE o (ok BIGINT PRIMARY KEY, d BIGINT, "
+              "prio VARCHAR(4))")
+    s.execute("CREATE TABLE l (lk BIGINT, price BIGINT)")
+    rng = np.random.default_rng(1)
+    s.execute("INSERT INTO o VALUES " + ",".join(
+        f"({i},{i % 100},'p{i % 4}')" for i in range(3000)))
+    s.execute("INSERT INTO l VALUES " + ",".join(
+        f"({int(rng.integers(0, 3000))},{i % 1000})" for i in range(40000)))
+    for t in "ol":
+        s.execute(f"ANALYZE TABLE {t}")
+    s.vars["tidb_tpu_compaction"] = "off"
+    return s
+
+
+def _aligned_declines():
+    from tidb_tpu.util.observability import REGISTRY
+    return {dict(labels)["gate"]: v
+            for (name, labels), v in REGISTRY.counters.items()
+            if name == "tidb_tpu_delta_declines_total"
+            and dict(labels)["gate"].startswith("aligned-")}
+
+
+def test_an_updated_build_row_keeps_its_fact_rows():
+    """UPDATE of a build row is its key dying and arriving in one step:
+    the fact rows that carried it dangle, live, while it arrives — the
+    structure is rebuilt (counted), not advanced past them."""
+    s = _pair()
+    _check(s, JQ)
+    s.execute("INSERT INTO l VALUES (5, 7)")
+    _check(s, JQ)
+    before = _aligned_declines().get("aligned-dangling", 0)
+    s.execute("UPDATE o SET d = 0 WHERE ok = 5")
+    _check(s, JQ)
+    assert _aligned_declines().get("aligned-dangling", 0) == before + 1
+    s.execute("UPDATE o SET d = 99 WHERE ok < 500")
+    _check(s, JQ)
+
+
+def test_scattered_dead_build_keys_advance_without_a_rebuild():
+    """Dead keys in more runs than `ALIGNED_MAX_RANGES` unmatch their fact
+    rows by asking the lookup table (no dependence on contiguous keys);
+    a purge by key range compares ranges. Neither rebuilds."""
+    s = _pair()
+    _check(s, JQ)
+    before = dict(_aligned_declines())
+    s.execute("DELETE FROM o WHERE ok >= 2900")          # one range
+    _check(s, JQ)
+    s.execute("DELETE FROM o WHERE ok % 7 = 3")          # ~400 runs
+    _check(s, JQ)
+    assert _aligned_declines() == before
+    # (live fact rows now match no build row: a build row that arrives may
+    # be theirs, so its arrival rebuilds — counted)
+    s.execute("INSERT INTO o VALUES (2950, 1, 'p1')")
+    _check(s, JQ)
+    assert _aligned_declines().get("aligned-dangling", 0) == \
+        before.get("aligned-dangling", 0) + 1
+
+
+def test_a_compaction_moves_rows_under_an_unchanged_table():
+    """A compaction that keeps the table's data, capacity and slab count
+    still moves rows: the structure's positions are of another base build
+    (`AlignedJoin.space`), and it is rebuilt."""
+    from tidb_tpu.executor import delta
+    delta.run_pending_compactions()              # (earlier tests' jobs)
+    s = _pair()
+    _check(s, JQ)
+    s.execute("DELETE FROM l WHERE lk < 400")    # ≥ 1/8 dead, cap unchanged
+    _check(s, JQ)
+    device_cache._READERS.clear()                # (no warm-up: a stale swap)
+    assert delta.pending_compactions() == 1
+    assert delta.run_pending_compactions() == 1
+    _check(s, JQ)
+    s.execute("DELETE FROM l WHERE lk < 800")
+    _check(s, JQ)
+    # and with the warm-up: the structure over the new positions is built
+    # before the swap and installed with it
+    before = dict(_aligned_declines())
+    assert delta.run_pending_compactions() == 1
+    _check(s, JQ)
+    assert _aligned_declines() == before

@@ -120,6 +120,17 @@ def recorded():
         s.execute("INSERT INTO lineitem VALUES (10.00, 12345.67, 0.06, "
                   f"0.02, 'N', 'O', '{w['ship']}', 0)")
         assert s.query(T.Q6).rows and s.last_engine == "tpu"   # delta slab
+        # ... and the join tree over a delta generation of both tables: a
+        # new order with its lineitem in, the written rows out again
+        # (masks, the anchor's delta slab, the aligned join following)
+        s.execute("INSERT INTO orders VALUES (65543, '1995-01-01', "
+                  "'1', 0)")
+        s.execute("INSERT INTO lineitem VALUES (10.00, 12345.67, 0.06, "
+                  f"0.02, 'N', 'O', '{w['ship']}', 65543)")
+        assert s.query(T.Q3).rows and s.last_engine == "tpu"
+        s.execute("DELETE FROM lineitem WHERE l_orderkey = 65543")
+        s.execute("DELETE FROM orders WHERE o_orderkey = 65543")
+        assert s.query(T.Q3).rows and s.last_engine == "tpu"
         eng.close()
     finally:
         mp.undo()
@@ -141,6 +152,18 @@ def _scaled(jax, tree, factor, sharding, axis=-1):
             shape[axis] *= factor
         return jax.ShapeDtypeStruct(tuple(shape), x.dtype, sharding=sharding)
     return jax.tree.map(grow, tree)
+
+
+def _scaled_live(jax, rows, factor, sharding):
+    """A slab's liveness as a program takes it: the length of a live
+    prefix (a count, which does not grow with the slab) or, from a delta
+    generation, a mask of the slab's rows (which does)."""
+    def grow(x):
+        if not isinstance(x, jax.ShapeDtypeStruct):
+            return x
+        by = factor if x.dtype == np.dtype(bool) and x.shape else 1
+        return _scaled(jax, x, by, sharding)
+    return jax.tree.map(grow, rows)
 
 
 def _scaled_cols(jax, cols, factor, sharding):
@@ -166,7 +189,9 @@ def _rebuild(owner, factor):
     if isinstance(owner, TreeProgram):
         p = TreeProgram(
             owner.plan,
-            {k: (cap * factor, n) for k, (cap, n) in owner.caps.items()},
+            # (slab capacity, slabs[, capacity of a raw delta slab])
+            {k: (c[0] * factor, c[1]) + tuple(d * factor for d in c[2:])
+             for k, c in owner.caps.items()},
             owner.group_cap,
             [owner.join_cfgs[id(n)] for n in _walk_joins(owner.plan)],
             owner.agg_key_bounds, owner.scan_layouts, owner.pairs_out,
@@ -213,13 +238,13 @@ def _compile_calls(calls, kinds, one_chip, monkeypatch):
                 and method == "_partial":
             cols, n_rows, preps = shapes
             args = (_scaled_cols(jax, cols, factor, one_chip),
-                    _scaled(jax, n_rows, 1, one_chip),
+                    _scaled_live(jax, n_rows, factor, one_chip),
                     _scaled(jax, preps, 1, one_chip))
         elif isinstance(owner, TreeProgram):
             scans, rows, preps, *rest = shapes
             args = (tuple(_scaled_cols(jax, c, factor, one_chip)
                           for c in scans),
-                    _scaled(jax, rows, 1, one_chip),
+                    _scaled_live(jax, rows, factor, one_chip),
                     _scaled(jax, preps, 1, one_chip),
                     *(_scaled(jax, r, factor, one_chip) for r in rest))
         else:
@@ -243,12 +268,19 @@ def test_chain_partials_compile_at_a_full_slab(recorded, one_chip,
     """Q1 and Q6: scan → in-trace compressed decode → filter → partial
     aggregate over one 8M-row slab."""
     from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import delta
     calls = [c for c in recorded if c[1] == "_partial"]
-    assert all(c[0].layouts for c in calls), \
-        "a chain ran over raw slabs: decode is not in the trace"
+    # the one raw program is the delta slab's: Q6 again after the INSERT,
+    # at the delta slab's own capacity; and that read took the base slab's
+    # liveness as a MASK (the same program, a second variant)
+    raw = [c for c in calls if not c[0].layouts]
+    assert [c[0].slab_cap for c in raw] == [delta.delta_capacity(TOY_ROWS)]
+    assert any(np.dtype(bool) == getattr(c[2][1], "dtype", None)
+               and c[2][1].shape == (TOY_ROWS,) for c in calls
+               if c[0].layouts), "no base slab ran under a liveness mask"
     stats = _compile_all(calls, fragment._FragmentProgram, one_chip,
                          monkeypatch)
-    assert len(stats) >= 2          # Q1, Q6 (its delta slab reuses Q6's)
+    assert len(stats) >= 4     # Q1, Q6, Q6 masked, Q6 over the delta slab
     _fits(stats)
 
 
@@ -310,7 +342,7 @@ def test_delta_decode_has_no_slab_wide_scan(recorded, one_chip, monkeypatch):
     slab = fragment.DEFAULT_MAX_SLAB_ROWS
     checked = 0
     for owner, _label, c in compiled:
-        deltas = [lay for lay in owner.layouts.values()
+        deltas = [lay for lay in (owner.layouts or {}).values()
                   if lay is not None and lay.kind == "delta"]
         if not deltas:
             continue

@@ -191,6 +191,8 @@ class Client:
             code = struct.unpack("<H", first[1:3])[0]
             raise ClientError(code, first[9:].decode(errors="replace"))
         if first[0] == 0x00:
+            # OK packet: header, affected rows, last insert id (lenenc)
+            self.affected_rows, _ = self._lenenc(first, 1)
             return [], []
         ncols, _ = self._lenenc(first, 0)
         names = []
@@ -222,8 +224,12 @@ class Client:
             rows.append(tuple(row))
         return names, rows
 
-    def execute(self, sql: str) -> None:
+    def execute(self, sql: str) -> int:
+        """Send a statement that returns no rows → the rows it affected,
+        as its OK packet states them."""
+        self.affected_rows = 0
         self.query(sql)
+        return self.affected_rows
 
     def close(self) -> None:
         try:

@@ -1,77 +1,97 @@
-"""Delta slabs — the HTAP write path of the device cache.
+"""Delta generations — the HTAP write path of the device cache.
 
-Before this module, any DML invalidated the whole device cache entry
-(executor/device_cache.invalidate): one single-row INSERT discarded the
-compressed slabs, the zone maps and the aligned joins, and the next
-read re-uploaded every column. The TiFlash analog it breaks is delta
-trees (TiFlash's DeltaTree storage keeps a small sorted delta layer
-over immutable stable packs and merges them at read): committed base
-slabs should stay immutable while writes accumulate in a small
-device-resident delta, folded into reads, and a background compaction
-periodically rebuilds the base with freshly re-chosen layouts — the
-"Fine-Tuning Data Structures" load-time decision re-run when the data
-has moved (arXiv 2112.13099).
+A committed write must not cost a reader the table: TiFlash's DeltaTree
+keeps a small delta layer over immutable stable packs and merges the two
+at read. Here a cached table (executor/device_cache.CachedTable) whose
+`TableData` went stale is diffed region by region against the current
+snapshot (regions are immutable objects that only grow at the tail and
+only ever lose rows, so the diff is exact) and EXTENDS into a new
+generation that shares every base device array with its predecessor:
 
-`extend_entry` is the read-side half: a cached entry whose TableData
-went stale is diffed region-by-region against the current snapshot
-(regions are immutable and only ever grow at the tail, so the diff is
-exact), and when the change is expressible as appends + tombstones the
-entry EXTENDS instead of rebuilding:
+  * appended rows go into ONE delta slab at index `base_slabs`, RAW (no
+    compressed layout) and of a capacity of its own (`delta_capacity`).
+    Nothing a base layout assumes — a packed range, the order a `delta`
+    layout codes differences in, a numeric dictionary — can be broken by
+    a value that arrives later, so no value gate is left but one: a string
+    outside the column's global dictionary, whose codes the compiled
+    programs and the decoded results share. A write uploads the new rows
+    alone (a scatter into the resident slab), never the slab;
+  * a deleted row clears one bit of its slab's liveness mask
+    (`CachedTable.alive`). No resident row ever moves, so base positions,
+    zone maps, bounds and the FK-aligned join columns stay valid, and a
+    tombstone costs the same whatever layouts the table's columns have.
+    The slab programs take the mask where they took a row count
+    (fragment._eval_chain, tree_fragment.TreeProgram._emit), the same
+    programs under the same names.
 
-  * appended rows encode host-side into ONE extra slab — the delta
-    slab, at index `base_slabs`, using the SAME per-column layouts and
-    dictionaries as the base, so every scan path (chain, tree, fused
-    pipeline, staged dist) consumes it through the exact per-slab
-    program it already compiled: the base∪delta merge costs at most
-    one extra launch, zero recompiles, zero base re-uploads;
-  * tombstones rewrite ONLY the affected base slabs in-trace
-    (device_emit.emit_delta_merge): surviving rows stable-permute to
-    the front and the slab's live count shrinks — packed layouts
-    unpack/permute/repack without raw bytes ever materializing in HBM.
+Nothing of a generation is part of a program: no table data, no version.
+What an extension cannot express declines into the rebuild that is always
+correct, and every decline is counted by the gate that tripped
+(`tidb_tpu_delta_declines_total{gate=}`):
 
-Extension installs a NEW CachedTable generation that shares the
-untouched base device arrays with its predecessor — in-flight readers
-keep the old object (their snapshot), and the swap is atomic under the
-device-cache lock. A long list of gates (dictionary membership, layout
-range fit, bounds, delta-kind columns, holes) declines extension and
-falls back to the plain rebuild — extension is an optimization, never
-a correctness risk.
+  no-coverage       the entry was not built from a coverage ledger
+  schema            a resident column is no longer in the scan's schema
+  region-rescoped   an old region entered the scan's partition scope
+  region-shrank / row-resurrected / regions-rewritten
+                    the store rewrote regions (GC, TRUNCATE): positions
+                    the ledger holds are gone
+  dictionary        an appended string is outside the global dictionary
+  delta-full        the delta slab has no room (compaction did not keep up)
+  consumer          the statement runs on a path whose programs assume a
+                    live prefix and uniform slabs (order/filter roots,
+                    windows, the mega-slab loop, sorted-runs grouping): it
+                    gets a plain rebuild cached BESIDE the generation
+  aligned-<why>     an FK-aligned join structure could not follow its
+                    tables' generations and was rebuilt (device_cache.
+                    _advance_aligned names the reasons)
+  error             anything unexpected inside the extension
 
-`run_pending_compactions` / the background worker is the write-side
-half: once a generation's delta grows past `tidb_tpu_delta_compact_rows`,
-a compaction job rebuilds the base slabs from the current snapshot with
-re-chosen compression layouts and fresh zone maps, in the scheduler's
-idle heavy-batch slots (batch-class admission: interactive statements
-always rank ahead of it). The swap is crash-consistent around the
-`compaction-commit` failpoint: a fault BEFORE the commit deletes the
-rebuilt buffers and the old base+delta keep serving reads byte-exactly;
-after it, the delta is gone and the old generation's buffers are freed
-(jax.Array.delete) under the same protect discipline every eviction
-uses.
+Compaction is the write-side half: when the delta slab is `COMPACT_FILL`
+full or `COMPACT_DEAD` of the base's rows are dead — both measured on the
+entry — a job rebuilds the base from a snapshot with re-chosen layouts and
+fresh zone maps, in the scheduler's batch class, never in a statement. The
+table may move on meanwhile: the rebuilt generation carries its snapshot's
+ledger, so it extends like any other stale entry. Before the swap the
+compactor runs the fragments that lately read the table once over the
+rebuilt generation, extended to the newest snapshot (`_warm`): what a
+re-chosen layout compiles, and the aligned structures over the new row
+positions, cost its thread and not the first statement after the swap.
 
-Failpoints: `delta-merge-stale` (entry of extend_entry — a fault there
-surfaces as a typed LayoutError and the executor's warned CPU fallback,
-never silent wrong rows) and `compaction-commit` (above); the write
+Failpoints: `delta-merge-stale` (entry of extend_entry — a typed
+LayoutError and the executor's warned CPU fallback, never silent wrong
+rows) and `compaction-commit` (between a finished rebuild and its swap: the
+rebuilt buffers are deleted, the old generation keeps serving); the write
 side's `delta-append` lives in storage Store.commit.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import weakref
-from typing import Dict, List, Optional
+from typing import Dict, Optional
 
 import numpy as np
 
 from tidb_tpu.errors import LayoutError
 from tidb_tpu.util import failpoint, timeline
 
-#: delta live-rows + tombstones past this → schedule async compaction
-DEFAULT_COMPACT_ROWS = 1024
+#: the delta slab holds slab_cap // DELTA_CAP_SHARE rows, never fewer
+#: than MIN_DELTA_CAP (both powers of two, as every slab capacity is)
+DELTA_CAP_SHARE = 8
+MIN_DELTA_CAP = 1024
+#: compaction is due when the delta slab is this full, or this share of
+#: the base's rows is dead
+COMPACT_FILL = 0.5
+COMPACT_DEAD = 0.125
+#: appended rows upload in power-of-two buckets of at least this many
+MIN_BUCKET = 1024
+#: steps a generation remembers for the aligned joins to catch up by
+MAX_STEPS = 64
 
-# one extension at a time: extensions are short (a region diff, at most
-# one slab encode and a few slab rewrites), and serializing them removes
-# the same-entry race where two threads build sibling generations
+# one extension at a time: extensions are short (a region diff, one
+# chunk encode and a few mask updates), and serializing them removes the
+# same-entry race where two threads build sibling generations
 _EXT_LOCK = threading.Lock()
 
 
@@ -79,183 +99,240 @@ def _var_on(vars_, name: str, default: str = "on") -> bool:
     return str(vars_.get(name, default)).lower() not in ("off", "0", "false")
 
 
+def delta_capacity(slab_cap: int) -> int:
+    return max(MIN_DELTA_CAP, int(slab_cap) // DELTA_CAP_SHARE)
+
+
+def decline(gate: str, table_id) -> None:
+    """Count one full rebuild a stale (or unusable) entry fell to."""
+    from tidb_tpu.util.observability import REGISTRY
+    REGISTRY.inc("tidb_tpu_delta_declines_total", {"gate": gate})
+    if timeline.ENABLED:
+        timeline.instant("delta.decline", "delta",
+                         args={"gate": gate, "table": table_id})
+
+
+class _Declined(Exception):
+    def __init__(self, gate: str):
+        super().__init__(gate)
+        self.gate = gate
+
+
 # ---------------------------------------------------------------------------
-# region diff — build-time coverage vs the current TableData
+# the ledger: which row of which region sits where on the device
 # ---------------------------------------------------------------------------
 
-def _diff_regions(ent, td, scope):
-    """Diff the entry's base-build coverage against the current regions.
-    → (tombs_base, appends, base_total) or None when the change is not
-    expressible as appends+tombstones (region GC'd, truncated, re-scoped
-    via the part-reset on delete, or rows resurrected).
+def ledger_from_coverage(cov):
+    """The base build's coverage → (`seen`, `rowmap`): the Region object
+    last diffed per region id, and per region its row ranges with where
+    they sit — (row_start, row_stop, in_delta, offset, alive-at-build or
+    None): a base range's row j is at offset + (its rank among the rows
+    alive at build), a delta range's at offset + (j - row_start)."""
+    seen, rowmap = {}, {}
+    for rid, n_rows, alive, base_off, region in cov:
+        if region is None:
+            return None, None
+        seen[rid] = region
+        rowmap[rid] = ((0, n_rows, False, base_off,
+                        None if alive is None or alive.all() else alive),)
+    return seen, rowmap
 
-    tombs_base: int64 array of CUMULATIVE tombstoned positions in the
-    base build's live-row coordinate space (== slab space: slab s covers
-    [s*slab_cap, (s+1)*slab_cap)). appends: [(region, start_row,
-    alive_tail_mask)] of CUMULATIVE appended-and-still-alive rows, in
-    region order."""
-    cov = ent.cov
-    ci = 0
-    tombs: List[np.ndarray] = []
-    appends = []
-    base_total = 0
-    if cov:
-        rid, n_old, alive_old, base_off = cov[-1]
-        base_total = base_off + int(alive_old.sum())
+
+def _positions(segs, rows: np.ndarray):
+    """Region row numbers → (base positions, delta positions)."""
+    base, delta = [], []
+    for start, stop, in_delta, off, alive in segs:
+        sel = rows[(rows >= start) & (rows < stop)]
+        if not sel.size:
+            continue
+        if in_delta:
+            delta.append(off + (sel - start))
+        elif alive is None:
+            base.append(off + (sel - start))
+        else:
+            # rank among the rows alive at build (a row dead at build
+            # cannot die again: the diff only hands over fresh deaths)
+            rank = np.cumsum(alive) - 1
+            base.append(off + rank[sel - start])
+    return base, delta
+
+
+def _diff(ent, td, scope):
+    """What changed between the entry's ledger and `td` → (appended
+    [(region, row_start, row_stop, delta offset)], dead base positions,
+    dead delta positions, seen, rowmap, delta_rows). Raises _Declined."""
+    seen, rowmap = dict(ent.seen), dict(ent.rowmap)
+    cursor = ent.delta_rows
+    appended, dead_base, dead_delta = [], [], []
+    present = set()
     for r in td.regions:
         if scope is not None and r.part is not None and r.part not in scope:
             continue
-        if ci < len(cov) and r.id == cov[ci][0]:
-            _rid, n_old, alive_old, base_off = cov[ci]
-            ci += 1
-            if r.num_rows < n_old:
-                return None                     # region shrank
-            dnew = np.asarray(r.deleted[:n_old])
-            if ((~alive_old) & ~dnew).any():
-                return None                     # dead row resurrected
-            nd = dnew & alive_old
-            if nd.any():
-                alive_idx = np.nonzero(alive_old)[0]
-                pos = base_off + np.searchsorted(alive_idx,
-                                                 np.nonzero(nd)[0])
-                tombs.append(pos.astype(np.int64))
-            if r.num_rows > n_old:
-                tail_alive = ~np.asarray(r.deleted[n_old:])
-                if tail_alive.any():
-                    appends.append((r, n_old, tail_alive))
-        else:
+        prev = seen.get(r.id)
+        if prev is None:
             if r.id <= ent.max_rid:
                 # an OLD region this build never saw — deletes reset its
-                # partition tag to None, pulling it into scope: rebuild
-                return None
-            alive = ~np.asarray(r.deleted)
-            if alive.any():
-                appends.append((r, 0, alive))
-    if ci != len(cov):
-        return None                             # a build region vanished
-    out = np.sort(np.concatenate(tombs)) if tombs \
-        else np.empty(0, dtype=np.int64)
-    return out, appends, base_total
+                # partition tag to None, pulling it into scope
+                raise _Declined("region-rescoped")
+            n = r.num_rows
+            appended.append((r, 0, n, cursor))
+            rowmap[r.id] = ((0, n, True, cursor, None),)
+            if r.live_rows != n:
+                dead_delta.append(cursor + np.flatnonzero(r.deleted))
+            cursor += n
+            seen[r.id] = r
+            present.add(r.id)
+            continue
+        present.add(r.id)
+        if r is prev:
+            continue
+        n_prev = prev.num_rows
+        if r.num_rows < n_prev:
+            raise _Declined("region-shrank")
+        grew = r.num_rows - n_prev
+        if r.live_rows - grew != prev.live_rows:
+            now = np.asarray(r.deleted[:n_prev])
+            if prev.live_rows != n_prev and (prev.deleted & ~now).any():
+                raise _Declined("row-resurrected")
+            fresh = np.flatnonzero(now & ~prev.deleted) \
+                if prev.live_rows != n_prev else np.flatnonzero(now)
+            if fresh.size:
+                b, d = _positions(rowmap[r.id], fresh)
+                dead_base += b
+                dead_delta += d
+        if grew:
+            appended.append((r, n_prev, r.num_rows, cursor))
+            rowmap[r.id] = rowmap[r.id] + ((n_prev, r.num_rows, True,
+                                            cursor, None),)
+            tail = np.asarray(r.deleted[n_prev:])
+            if tail.any():
+                dead_delta.append(cursor + np.flatnonzero(tail))
+            cursor += grew
+        seen[r.id] = r
+    if len(present) != len(seen):
+        raise _Declined("regions-rewritten")
+    cat = lambda parts: (np.concatenate(parts).astype(np.int64)  # noqa: E731
+                         if parts else np.empty(0, dtype=np.int64))
+    return appended, cat(dead_base), cat(dead_delta), seen, rowmap, cursor
 
 
-def _append_col(appends, scan, col_idx: int):
-    """Materialize ONE column of the cumulative appended rows (aligned
-    to the scan schema, DDL-padded) → (vals, valid)."""
+def _rows_of(appended, scan, col_idx: int):
+    """ONE column of the appended rows → (vals, valid), aligned to the
+    scan's schema (DDL-padded)."""
     from tidb_tpu.executor.scan import align_chunk_to_schema
-    vals_list, valid_list = [], []
-    for r, start, alive_tail in appends:
-        chunk = align_chunk_to_schema(r.chunk, scan.table)
-        idx = start + np.nonzero(alive_tail)[0]
-        col = chunk.columns[col_idx]
-        vals_list.append(col.values[idx])
-        valid_list.append(col.valid_mask()[idx])
-    if len(vals_list) == 1:
-        return vals_list[0], valid_list[0]
-    return np.concatenate(vals_list), np.concatenate(valid_list)
+    vals, valid = [], []
+    for r, start, stop, _off in appended:
+        col = align_chunk_to_schema(r.chunk, scan.table).columns[col_idx]
+        vals.append(col.values[start:stop])
+        valid.append(col.valid_mask()[start:stop])
+    if len(vals) == 1:
+        return vals[0], valid[0]
+    return np.concatenate(vals), np.concatenate(valid)
 
 
-# ---------------------------------------------------------------------------
-# per-column gates + delta-slab prep
-# ---------------------------------------------------------------------------
-
-def _host_dictvals(ent, i: int):
-    """Host copy of a dict-layout column's dictionary values (fetched
-    from the shared device array once, then memoized on the entry)."""
-    dv = ent.dictvals_host.get(i)
-    if dv is None:
-        t = next((t for t in ent.dev[i] if t is not None), None)
-        if t is None or len(t) < 3:
-            return None
-        dv = np.asarray(t[2])
-        ent.dictvals_host[i] = dv
-    return dv
-
-
-def _delta_prep(ent, scan, i: int, ftype, appends, has_tombs: bool):
-    """Gate + prep for column `i` of the delta slab → a _slab_host-style
-    prep dict, or None when a gate trips (decline → full rebuild).
-    Every gate protects an invariant the compiled programs assume:
-    dictionary membership (global code space), layout range fit (packed
-    widths), bounds (perfect-hash group domains), delta-kind purity."""
+def _raw_chunk(ent, scan, i: int, ftype, appended):
+    """Column `i` of the appended rows as the delta slab holds it: the
+    values the base's programs DECODE to (dictionary codes for strings,
+    the device float for DOUBLE, limb planes for wide decimals), raw."""
     from tidb_tpu.ops.jax_env import device_float_dtype
-    lay = ent.layouts.get(i)
-    if lay is not None and lay.kind == "delta" and has_tombs:
-        return None     # diff codes don't survive a permutation
-    vals, valid = _append_col(appends, scan, i)
-    n = len(vals)
+    vals, valid = _rows_of(appended, scan, i)
     if ftype.is_wide_decimal:
-        return {"kind": "wide", "vals": vals, "valid": valid,
-                "n_limbs": ftype.wide_limb_count, "layout": None}
+        from tidb_tpu.executor.device_cache import wide_decimal_limbs
+        return wide_decimal_limbs(vals, ftype.wide_limb_count), valid
     if ftype.is_varlen:
         dictionary = ent.dicts.get(i)
         if dictionary is None:
-            return None
-        str_vals = np.array([str(v) for v in vals], dtype=object)
+            raise _Declined("dictionary")
+        folded = np.array([str(v) for v in vals], dtype=object)
+        keys = dictionary
         if ftype.is_ci:
             from tidb_tpu.types import fold_ci_array
-            folded = fold_ci_array(str_vals)
-            keys = fold_ci_array(dictionary)
-        else:
-            folded = str_vals
-            keys = dictionary
+            folded, keys = fold_ci_array(folded), fold_ci_array(dictionary)
+        codes = np.searchsorted(keys, folded).astype(np.int32) \
+            if len(keys) else np.zeros(len(folded), dtype=np.int32)
         if valid.any():
-            vv = folded[valid]
-            idx = np.searchsorted(keys, vv)
-            if (idx >= len(keys)).any() or (keys[np.clip(
-                    idx, 0, max(len(keys) - 1, 0))] != vv).any():
-                return None     # value outside the global dictionary
-        return {"kind": "str", "vals": folded, "valid": valid,
-                "keys": keys, "layout": lay}
+            hit = np.clip(codes, 0, max(len(keys) - 1, 0))
+            if not len(keys) or (keys[hit[valid]] != folded[valid]).any():
+                # its code would mean another string to every program
+                raise _Declined("dictionary")
+        return np.where(valid, codes, 0).astype(np.int32), valid
     if vals.dtype == np.dtype(np.float64):
-        return {"kind": "float", "vals": vals, "valid": valid,
-                "dtype": np.dtype(device_float_dtype()), "layout": None}
-    prep = {"kind": "num", "vals": vals, "valid": valid, "layout": lay}
-    if vals.dtype.kind in "iu" and valid.any():
-        vv = vals[valid].astype(np.int64)
-        bounds = ent.bounds.get(i)
-        if bounds is not None:
-            lo, hi = bounds
-            if int(vv.min()) < lo or int(vv.max()) > hi:
-                return None     # bounds feed perfect-hash group domains
-        if lay is not None:
-            if lay.kind == "pack":
-                if lay.width == 0:
-                    if (vv != lay.ref).any():
-                        return None
-                elif ((vv < lay.ref) |
-                      (vv - lay.ref >= (1 << lay.width))).any():
-                    return None
-            elif lay.kind == "dict":
-                dv = _host_dictvals(ent, i)
-                if dv is None:
-                    return None
-                idx = np.searchsorted(dv, vv)
-                if (idx >= len(dv)).any() or \
-                        (dv[np.clip(idx, 0, len(dv) - 1)] != vv).any():
-                    return None
-                prep["dictvals"] = dv
-            elif lay.kind == "delta":
-                if not valid.all() or n == 0:
-                    return None
-                diffs = np.diff(vv)
-                if diffs.size and (int(diffs.min()) < 0 or
-                                   int(diffs.max()).bit_length()
-                                   > lay.width):
-                    return None
-    elif lay is not None and lay.kind == "delta" and not valid.all():
-        return None
-    return prep
+        return vals.astype(np.dtype(device_float_dtype())), valid
+    return np.ascontiguousarray(vals), valid
+
+
+def _one_row(seen) -> tuple:
+    """One row of the table as `_raw_chunk` takes appended rows: an empty
+    delta slab's shapes and types are read off it."""
+    return tuple((r, 0, 1, 0) for r in seen.values() if r.num_rows)[:1]
+
+
+def _widen(bounds, lo: int, hi: int):
+    """Bounds that hold [lo, hi] too. A side that is exceeded moves out
+    geometrically (the span to the next power of two), so that a stream
+    of growing keys changes the bounds — which the perfect-hash group
+    domains and the joins' lookup tables are sized by, as trace constants
+    — a logarithmic number of times."""
+    b_lo, b_hi = bounds
+    if lo >= b_lo and hi <= b_hi:
+        return bounds
+    if hi > b_hi:
+        b_hi = b_lo + (1 << int(hi - b_lo).bit_length()) - 1
+    if lo < b_lo:
+        b_lo = b_hi - (1 << int(b_hi - lo).bit_length()) + 1
+    return (b_lo, b_hi)
+
+
+def _widen_for(ent, i: int, v: np.ndarray, m: np.ndarray) -> None:
+    """Column `i`'s bounds on `ent`, widened to hold the appended integer
+    values `v` where `m` (a dictionary-coded column keeps its code space)."""
+    if v.dtype.kind in "iu" and v.ndim == 1 and m.any() \
+            and ent.bounds.get(i) is not None and ent.dicts.get(i) is None:
+        vv = v[m]
+        ent.bounds[i] = _widen(ent.bounds[i], int(vv.min()), int(vv.max()))
+
+
+def _pad_chunk(v: np.ndarray, m: np.ndarray):
+    """Appended rows as the append program takes them: values and
+    validity padded with zeros to a power-of-two bucket of rows."""
+    from tidb_tpu.executor.device_cache import _pow2
+    n = int(m.shape[0])
+    bucket = _pow2(n, MIN_BUCKET)
+    pv = np.zeros(v.shape[:-1] + (bucket,), dtype=v.dtype)
+    pv[..., :n] = v
+    pm = np.zeros(bucket, dtype=bool)
+    pm[:n] = m
+    return pv, pm
+
+
+def _pad_idx(pos: np.ndarray, cap: int) -> np.ndarray:
+    """Positions as the mask programs take them: int32, padded with `cap`
+    (dropped by the scatter) to a power-of-two bucket, or empty."""
+    from tidb_tpu.executor.device_cache import _pow2
+    if not pos.size:
+        return np.empty(0, dtype=np.int32)
+    out = np.full(_pow2(pos.size, MIN_BUCKET), cap, dtype=np.int32)
+    out[:pos.size] = pos
+    return out
 
 
 # ---------------------------------------------------------------------------
-# extension — the read-side delta merge
+# extension — the read-side half
 # ---------------------------------------------------------------------------
 
-def extend_entry(ctx, scan, ent, max_slab: int, phases=None):
-    """Try to extend a stale cached entry with a delta slab + tombstone
-    rewrites instead of rebuilding it. → the NEW CachedTable generation
-    (sharing untouched base device arrays with `ent`), or None to
-    decline (caller rebuilds). Never mutates `ent`."""
+def extend_entry(ctx, scan, ent, max_slab: int, phases=None,
+                 masked: bool = False, quiet: bool = False,
+                 private: bool = False):
+    """Extend a stale cached entry into a NEW generation (sharing the
+    base device arrays with `ent`), or count the decline and → None (the
+    caller rebuilds). Never mutates `ent`. `masked`: a generation with
+    liveness masks although nothing changed (what `_warm` runs the masked
+    variants of a rebuilt table's programs over). `quiet`: → None
+    without counting, for a caller that will not rebuild. `private`: no
+    other thread can reach `ent` (a compaction's generation before the
+    swap), so the statements' extensions do not wait for this one — which
+    may cover minutes of writes in bucket sizes no statement ever
+    compiled — and nothing is counted."""
     from tidb_tpu.util.phases import PhaseTimer
     corrupted = failpoint.inject("delta-merge-stale")
     if corrupted is not None:
@@ -263,189 +340,242 @@ def extend_entry(ctx, scan, ent, max_slab: int, phases=None):
             f"delta extension diff failed validation "
             f"(failpoint: {corrupted!r}) — refusing the in-place merge")
     ph = phases if phases is not None else PhaseTimer()
-    with _EXT_LOCK:
+    quiet = quiet or private
+    with contextlib.nullcontext() if private else _EXT_LOCK:
         try:
-            return _extend_locked(ctx, scan, ent, max_slab, ph)
+            return _extend_locked(ctx, scan, ent, max_slab, ph, masked)
         except LayoutError:
             raise
+        except _Declined as d:
+            if not quiet:
+                decline(d.gate, scan.table.id)
         except Exception:  # noqa: BLE001 — extension is best-effort:
             # any unexpected fault (a raced buffer delete, an exotic
             # chunk dtype) declines into the always-correct rebuild
-            return None
+            if not quiet:
+                decline("error", scan.table.id)
+    return None
 
 
-def _extend_locked(ctx, scan, ent, max_slab, ph):
-    from tidb_tpu.chunk import compress
+def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
     from tidb_tpu.executor import device_cache as dc
     from tidb_tpu.executor import device_emit
-    from tidb_tpu.ops.jax_env import jax, jnp
+    from tidb_tpu.util.observability import REGISTRY
     table_id = scan.table.id
     td = ctx.snapshot.table_data(table_id)
-    if td is None or ent.cov is None or ent.holes or not ent.dev:
-        return None
+    if td is None or ent.seen is None or not ent.dev:
+        raise _Declined("no-coverage")
     pruned = getattr(scan, "partitions", None)
     scope = None if pruned is None else set(pruned)
-    diff = _diff_regions(ent, td, scope)
-    if diff is None:
-        return None
-    tombs_base, appends, base_total = diff
-    cap = ent.slab_cap
-    n_append = sum(int(a.sum()) for _r, _s, a in appends)
-    if n_append > cap:
-        return None                     # delta slab full → rebuild
     resident = sorted(ent.dev)
     ftypes = scan.schema.field_types
     if any(i >= len(ftypes) for i in resident):
-        return None
+        raise _Declined("schema")
+    with timeline.span("delta.diff", "delta", table=table_id,
+                       regions=len(td.regions)):
+        appended, dead_base, dead_delta, seen, rowmap, cursor = \
+            _diff(ent, td, scope)
+    cap, base_slabs = ent.slab_cap, ent.base_slabs
+    n_new = cursor - ent.delta_rows
+    dcap = ent.delta_cap or delta_capacity(cap)
+    if cursor > dcap:
+        raise _Declined("delta-full")
+    changed = bool(n_new or dead_base.size or dead_delta.size)
 
-    # cumulative → fresh tombstones, per base slab, in base coordinates
-    cum: Dict[int, np.ndarray] = {}
-    for s in sorted(set(int(p) // cap for p in tombs_base)):
-        sel = (tombs_base // cap) == s
-        cum[s] = tombs_base[sel] - s * cap
-    fresh: Dict[int, np.ndarray] = {}
-    for s, pos in cum.items():
-        applied = ent.tomb.get(s)
-        f = pos if applied is None else np.setdiff1d(pos, applied)
-        if f.size:
-            if s >= ent.base_slabs:
-                return None             # tombstone beyond the base?!
-            fresh[s] = f
-    has_tombs = bool(fresh)
-
-    # delta-slab preps (gates) for EVERY resident column — they all
-    # must extend or none does (ragged dev lists would corrupt reads)
-    preps = {}
-    if n_append:
-        with ph.phase("encode"):
-            for i in resident:
-                p = _delta_prep(ent, scan, i, ftypes[i], appends,
-                                has_tombs)
-                if p is None:
-                    return None
-                preps[i] = p
-    elif has_tombs:
-        for i in resident:
-            lay = ent.layouts.get(i)
-            if lay is not None and lay.kind == "delta":
-                return None
-
-    base_slabs = ent.base_slabs
-    total_tombs = int(tombs_base.size)
-    new_total = base_total - total_tombs + n_append
-    n_slabs = base_slabs + (1 if n_append else 0)
-
-    new = dc.CachedTable(td, ent.max_slab, new_total, cap, n_slabs,
-                         ent.parts, ent.n_cols, compressed=ent.compressed)
-    new.dicts = dict(ent.dicts)
-    new.bounds = dict(ent.bounds)
-    new.layouts = dict(ent.layouts)
-    new.zmaps = dict(ent.zmaps)
-    new.cov = ent.cov
-    new.max_rid = ent.max_rid
-    new.base_slabs = base_slabs
+    new = dc.CachedTable(td, ent.max_slab, ent.total + n_new
+                         - dead_base.size - dead_delta.size, cap,
+                         ent.n_slabs, ent.parts, ent.n_cols,
+                         compressed=ent.compressed)
+    new.dicts, new.bounds = dict(ent.dicts), dict(ent.bounds)
+    new.layouts, new.zmaps = dict(ent.layouts), dict(ent.zmaps)
+    new.holes = dict(ent.holes)
+    new.cov, new.base_slabs, new.base_total = \
+        ent.cov, base_slabs, ent.base_total
+    new.max_rid = max(ent.max_rid,
+                      max((r.id for r in td.regions), default=-1))
+    new.seen, new.rowmap = seen, rowmap
+    new.base_td, new.lineage = ent.base_td, ent.lineage
     new.delta_version = int(getattr(ctx.snapshot, "version", 0) or 0)
-    # an empty diff (the write landed in an out-of-scope partition, or
-    # it only touched rows this build never covered) is a pure
-    # REVALIDATION: same arrays, fresh td + version — keep the plain
-    # entry semantics (aligned joins stay usable, no rebuild-on-missing)
-    new.is_delta = bool(n_append or tombs_base.size)
-    new.tomb = dict(cum)
-    new.delta_rows = n_append
-    new.dictvals_host = ent.dictvals_host
-    # pod placement rides generations: the new entry keeps its
-    # predecessor's device pin; a pod entry's delta slab (index
-    # base_slabs) joins the last owner's span
-    new.device = getattr(ent, "device", 0)
-    owners = getattr(ent, "owners", None)
-    if owners is not None:
-        new.owners = (list(owners) + [owners[-1] if owners else 0]
-                      * n_slabs)[:n_slabs]
+    new.device, new.owners = ent.device, ent.owners
+    new.dev = {i: list(ent.dev[i]) for i in resident}
+    new.alive, new.rows_override = ent.alive, ent.rows_override
+    new.delta_cap, new.delta_rows = ent.delta_cap, ent.delta_rows
+    new.dead_rows, new.is_delta = ent.dead_rows, ent.is_delta
+    new.steps = ent.steps
+    if not changed and not masked:
+        # the write landed out of scope, or only touched rows this build
+        # never covered: a pure REVALIDATION (same arrays, fresh td)
+        return new
 
-    # complete per-slab live counts: the uniform slab_cap arithmetic is
-    # wrong for every slab once total shifts
-    rows_override: Dict[int, int] = {}
-    for s in range(base_slabs):
-        orig = min(cap, base_total - s * cap)
-        rows_override[s] = orig - int(cum.get(s, np.empty(0)).size)
-    if n_append:
-        rows_override[base_slabs] = n_append
-    new.rows_override = rows_override if new.is_delta else None
+    tail = ent.n_slabs - 1      # the delta slab lives with the tail owner
 
-    # keep masks for the freshly tombstoned slabs, in CURRENT slab
-    # coordinates (the slab may already have been compacted by earlier
-    # generations — map original positions through the applied set)
-    keeps: Dict[int, np.ndarray] = {}
-    for s, f in fresh.items():
-        applied = ent.tomb.get(s)
-        cur_pos = f if applied is None \
-            else f - np.searchsorted(applied, f)
-        n_cur = ent.slab_rows(s)
-        keep = np.zeros(cap, dtype=bool)
-        keep[:n_cur] = True
-        keep[cur_pos] = False
-        keeps[s] = keep
-
-    # encode + upload the delta slab; rewrite tombstoned base slabs.
-    # The delta slab commits to the entry's pinned device (for a pod
-    # entry: the tail owner's device — extension requires a hole-free
-    # entry, so the last base slab is resident there too).
-    if new.owners is not None:
-        pin = dc.device_handle(new.owners[-1] if new.owners else 0)
-    else:
-        pin = dc.device_handle(new.device)
-    new_dev: Dict[int, List] = {}
+    # appended rows: encode the new rows alone, write them into the
+    # resident delta slab (made empty on the device at the first append)
     h2d = 0
-    logical = 0
-    for i in resident:
-        slabs = list(ent.dev[i][:base_slabs])
-        lay = ent.layouts.get(i)
-        for s, keep in keeps.items():
-            slabs[s] = device_emit.emit_delta_merge(
-                lay, slabs[s], keep, rows_override[s], cap)
-        if n_append:
-            with ph.phase("encode"):
-                host_t = dc._slab_host(preps[i], 0, n_append, cap)
-            with ph.phase("upload"):
-                dev_t = tuple(jnp.asarray(a) if pin is None else
-                              jax.device_put(np.asarray(a), pin)
-                              for a in host_t)
-                if lay is not None and lay.kind == "dict":
-                    # shared dictvals from the LAST resident base slab:
-                    # on a pod entry that slab belongs to the tail
-                    # owner's span — the same device the delta slab
-                    # pins to, so the tuple stays single-device
-                    base_t = next(t for t in reversed(ent.dev[i])
-                                  if t is not None)
-                    dev_t = dev_t + (base_t[-1],)   # shared dictvals
-            h2d += sum(a.nbytes for a in host_t)
-            logical += compress.raw_slab_bytes(lay, cap) \
-                if lay is not None else sum(a.nbytes for a in host_t)
-            slabs.append(dev_t)
-        new_dev[i] = slabs
-    new.dev = new_dev
+    sample = ()
+    if not n_new and not ent.delta_cap:
+        # the delta slab belongs to a delta generation's shape from the
+        # first change on, like the masks below: a generation that only
+        # lost rows gets it empty (shapes and types read off one row), so
+        # the first insert later makes no statement a program
+        sample = _one_row(seen)
+    if n_new or sample:
+        with timeline.span("delta.encode", "delta", rows=n_new,
+                           table=table_id), ph.phase("encode"):
+            chunks = {i: _raw_chunk(ent, scan, i, ftypes[i],
+                                    appended or sample) for i in resident}
+            padded = {}
+            for i, (v, m) in chunks.items():
+                if not n_new:
+                    v, m = v[..., :0], m[:0]
+                padded[i] = _pad_chunk(v, m)
+                _widen_for(new, i, v, m)
+        if not ent.delta_cap:
+            slabs = _on_slab(ent, tail, device_emit.emit_delta_alloc,
+                             [(padded[i][0].shape[:-1], padded[i][0].dtype)
+                              for i in resident], dcap)
+            for i, t in zip(resident, slabs):
+                new.dev[i].append(t)
+            new.delta_cap = dcap
+    if n_new:
+        nbytes = sum(v.nbytes + m.nbytes for v, m in padded.values())
+        with timeline.span("delta.upload", "delta", bytes=nbytes,
+                           table=table_id), ph.phase("upload"):
+            for i in resident:
+                new.dev[i][base_slabs] = device_emit.emit_delta_append(
+                    new.dev[i][base_slabs], padded[i][0], padded[i][1],
+                    ent.delta_rows, n_new, dcap)
+        h2d += nbytes
+        new.delta_rows = cursor
+        REGISTRY.inc("tidb_tpu_delta_rows_total", {"kind": "append"},
+                     by=n_new)
+    new.n_slabs = base_slabs + (1 if new.delta_cap else 0)
+
+    # liveness: one mask a slab from the first change on, so that every
+    # statement of a delta generation runs the masked variant of its slab
+    # programs and none compiles when the first tombstone arrives later
+    alive = list(ent.alive) if ent.alive is not None else [
+        _on_slab(ent, s, device_emit.emit_alive_init,
+                 ent.slab_rows(s), cap) for s in range(base_slabs)]
+    rows = dict(ent.rows_override) if ent.rows_override is not None else {
+        s: ent.slab_rows(s) for s in range(base_slabs)}
+    if new.delta_cap and len(alive) == base_slabs:
+        alive.append(_on_slab(ent, tail, device_emit.emit_alive_init, 0,
+                              dcap))
+        rows[base_slabs] = 0
+    none = np.empty(0, dtype=np.int32)
+    for s in sorted(set((dead_base // cap).tolist())):
+        pos = dead_base[dead_base // cap == s] - s * cap
+        with timeline.span("delta.tombstone", "delta", slab=int(s),
+                           tombs=int(pos.size), rows=cap,
+                           table=table_id):
+            idx = _pad_idx(pos, cap)
+            alive[s] = device_emit.emit_alive_update(alive[s], none, idx,
+                                                     cap)
+        h2d += idx.nbytes
+        rows[s] -= int(pos.size)
+    if n_new or dead_delta.size:
+        born = _pad_idx(np.arange(ent.delta_rows, cursor), dcap)
+        idx = _pad_idx(dead_delta, dcap)
+        with timeline.span("delta.tombstone", "delta", slab=base_slabs,
+                           tombs=int(dead_delta.size), rows=dcap,
+                           table=table_id):
+            alive[base_slabs] = device_emit.emit_alive_update(
+                alive[base_slabs], born, idx, dcap)
+        h2d += born.nbytes + idx.nbytes
+        rows[base_slabs] += n_new - int(dead_delta.size)
+    n_dead = int(dead_base.size + dead_delta.size)
+    if n_dead:
+        REGISTRY.inc("tidb_tpu_delta_rows_total", {"kind": "tomb"},
+                     by=n_dead)
+    new.alive, new.rows_override = alive, rows
+    new.dead_rows = ent.dead_rows + n_dead
+    new.is_delta = True
+    if not changed:
+        return new
+    if new.owners is not None:
+        new.owners = (list(new.owners) + [new.owners[-1]]
+                      * new.n_slabs)[:new.n_slabs]
+    # what the FK-aligned joins need to follow this generation: the
+    # appended rows by region (their key values are read there), the dead
+    # rows' regions likewise
+    new.steps = (ent.steps + ({
+        "from": ent.td, "to": td, "appended": tuple(appended),
+        "offset": ent.delta_rows, "n_new": n_new,
+        "dead": _dead_rows_by_region(ent, seen) if n_dead else ()},))[
+            -MAX_STEPS:]
     if h2d:
-        ph.add_h2d(h2d, logical=logical)
-    ph.note_delta_rows(n_append, token=id(new))
-    if timeline.ENABLED:
-        timeline.instant("delta-extend", "cache",
-                         args={"rows": n_append, "tombs": total_tombs,
-                               "table": table_id})
-    from tidb_tpu.util.observability import REGISTRY
+        ph.add_h2d(h2d, logical=h2d)
+    ph.note_delta_rows(new.delta_rows, token=id(new))
     REGISTRY.inc("tidb_tpu_delta_extensions_total",
                  {"table": str(table_id)})
 
-    # past the threshold → hand the rebuild to the async compactor
-    threshold = int(ctx.vars.get("tidb_tpu_delta_compact_rows",
-                                 DEFAULT_COMPACT_ROWS))
-    if n_append + total_tombs >= max(threshold, 1):
+    cause = compaction_due(new)
+    if cause is not None:
         store = getattr(ctx.snapshot, "store", None)
         if store is not None:
             key = (getattr(new, "device", 0), id(store), table_id,
                    None if pruned is None else tuple(pruned))
             schedule_compaction(store, key, scan, resident, max_slab,
-                                dict(ctx.vars))
+                                dict(ctx.vars), cause)
     return new
+
+
+def _on_slab(ent, s: int, fn, *args):
+    """Run `fn` on the device that owns slab `s` of a pod entry."""
+    from tidb_tpu.executor import device_cache as dc
+    from tidb_tpu.ops.jax_env import jax
+    d = ent.owners[s] if ent.owners is not None and s < len(ent.owners) \
+        else ent.device
+    h = dc.device_handle(d) if (ent.owners is not None or d) else None
+    if h is None:
+        return fn(*args)
+    with jax.default_device(h):
+        # committed there: what a later program makes of it stays
+        return jax.device_put(fn(*args), h)
+
+
+def _dead_rows_by_region(ent, seen):
+    """[(region now, region before)] of the regions that lost rows in
+    this step: the aligned joins read the dead rows' keys from them."""
+    return tuple((r, ent.seen[rid]) for rid, r in seen.items()
+                 if rid in ent.seen and r is not ent.seen[rid]
+                 and r.live_rows - (r.num_rows - ent.seen[rid].num_rows)
+                 != ent.seen[rid].live_rows)
+
+
+def delta_column(ent, scan, i: int, ftype):
+    """The delta slab of a column the generation did not hold yet, from
+    the rows its ledger says were appended → (vals, mask) on the device."""
+    from tidb_tpu.executor import device_emit
+    appended = sorted(
+        ((ent.seen[rid], start, stop, off)
+         for rid, segs in ent.rowmap.items()
+         for start, stop, in_delta, off, _a in segs if in_delta),
+        key=lambda t: t[3])
+    rows = appended or _one_row(ent.seen)
+    if not rows:
+        raise _Declined("no-coverage")
+    v, m = _raw_chunk(ent, scan, i, ftype, rows)
+    if not appended:
+        v, m = v[..., :0], m[:0]    # (only rows died so far: it is empty)
+    _widen_for(ent, i, v, m)   # (its bounds were taken from the base's rows)
+    n = int(m.shape[0])
+    pv, pm = _pad_chunk(v, m)
+    slab = _on_slab(ent, ent.base_slabs, device_emit.emit_delta_alloc,
+                    [(pv.shape[:-1], pv.dtype)], ent.delta_cap)[0]
+    return device_emit.emit_delta_append(slab, pv, pm, 0, n,
+                                         ent.delta_cap), pv.nbytes + pm.nbytes
+
+
+def compaction_due(ent) -> Optional[str]:
+    """Why this generation should be compacted, from sizes it knows."""
+    if ent.delta_cap and ent.delta_rows >= COMPACT_FILL * ent.delta_cap:
+        return "delta-fill"
+    if ent.dead_rows and ent.dead_rows >= COMPACT_DEAD * max(
+            ent.base_total, 1):
+        return "dead-rows"
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -476,13 +606,14 @@ class _IdleGuard:
 
 
 def schedule_compaction(store, key, scan, cols, max_slab: int,
-                        vars_: dict) -> None:
+                        vars_: dict, cause: str = "delta-fill") -> None:
     """Queue one compaction job per cache key (newest wins) and make
     sure a worker will drain it (unless tidb_tpu_compaction=off — the
-    queue still fills, tests/bench drain it via
+    queue still fills, tests and tools drain it via
     run_pending_compactions)."""
     job = {"store": weakref.ref(store), "key": key, "scan": scan,
-           "cols": list(cols), "max_slab": max_slab, "vars": vars_}
+           "cols": list(cols), "max_slab": max_slab, "vars": vars_,
+           "cause": cause}
     with _PENDING_LOCK:
         _PENDING[key] = job
     if _var_on(vars_, "tidb_tpu_compaction"):
@@ -514,7 +645,6 @@ def _ensure_worker() -> None:
 
 def _worker_loop() -> None:
     while True:
-        job = None
         with _DRAIN_LOCK:
             job = _pop_job()
             if job is None:
@@ -523,13 +653,13 @@ def _worker_loop() -> None:
                 _compact_one(job)
             except Exception:  # noqa: BLE001 — a failed compaction
                 # (including an injected compaction-commit fault) leaves
-                # the old generation serving; the next extension past
-                # the threshold re-schedules
+                # the old generation serving; the next extension that
+                # finds compaction due re-schedules
                 pass
 
 
 def run_pending_compactions() -> int:
-    """Synchronously drain the compaction queue (tests, bench, chaos) —
+    """Synchronously drain the compaction queue (tests, tools, chaos) —
     → jobs that committed. Faults are swallowed per job: the old
     generation keeps serving and the job is consumed."""
     done = 0
@@ -546,11 +676,13 @@ def run_pending_compactions() -> int:
 
 
 def _compact_one(job) -> bool:
-    """Rebuild the job's cache entry from the current snapshot with
-    freshly re-chosen layouts + zone maps, then atomically swap it in.
-    The `compaction-commit` failpoint sits between the finished rebuild
-    and the swap: a fault there deletes the rebuilt buffers and leaves
-    the old base+delta serving byte-exactly."""
+    """Rebuild the job's cache entry from a snapshot with freshly
+    re-chosen layouts + zone maps, then swap it in. The table may have
+    moved on since the snapshot: the rebuilt generation is then stale on
+    arrival, and the next read extends it from its own ledger. The
+    `compaction-commit` failpoint sits between the finished rebuild and
+    the swap: a fault there deletes the rebuilt buffers and leaves the
+    old generation serving byte-exactly."""
     from tidb_tpu.executor import ExecContext
     from tidb_tpu.executor import device_cache as dc
     from tidb_tpu.executor.scheduler import SCHEDULER
@@ -566,13 +698,22 @@ def _compact_one(job) -> bool:
         return False
     with dc._LOCK:
         cur = dc._CACHE.get(key)
-    if cur is None or (cur.td is td
-                       and not getattr(cur, "is_delta", False)):
+    if cur is None or not getattr(cur, "is_delta", False):
         return False    # evicted, or already rebuilt fresh — nothing to do
     guard = _IdleGuard()
-    new = None
+    new = pv = None
     try:
+        # admission in the batch class: the rebuild STARTS when no
+        # statement waits for the device. Its host encode and uploads then
+        # run slot-free, as a statement's streamed first touch does — a
+        # slot is for dispatching programs, the rebuild dispatches none,
+        # and held through the encode it kept every statement waiting for
+        # as long as the rebuild took (0.5 s a table of 0.6M rows, chip)
         with SCHEDULER.slot(guard=guard, conn_id=guard.conn_id):
+            pass
+        with timeline.span("compact.run", "delta", table=table_id,
+                           cause=job.get("cause", ""),
+                           rows=int(td.live_rows)):
             ctx = ExecContext(snapshot=snapshot, vars=dict(job["vars"]))
             ph = PhaseTimer()
             parts, total, cov, max_rid = dc._collect_parts(ctx, scan,
@@ -589,51 +730,137 @@ def _compact_one(job) -> bool:
                 nd = max(_sched.pool_devices(ctx), 1)
                 new.owners = [min(s * nd // max(n_slabs, 1), nd - 1)
                               for s in range(n_slabs)]
-            new.cov = cov
-            new.max_rid = max_rid
+            new.set_coverage(cov, max_rid)
             new.delta_version = int(getattr(snapshot, "version", 0) or 0)
             ftypes = scan.schema.field_types
             cols = [i for i in job["cols"] if i < len(ftypes)]
-            if total:
-                preps = {}
-                for i in cols:
-                    # _col_prep re-runs choose_layout under the CURRENT
-                    # workload hints — the compaction-time layout
-                    # re-search of arXiv 2112.13099
-                    preps[i] = dc._col_prep(new, i, ftypes[i])
-                    new.dicts[i] = preps[i]["dict"]
-                    new.bounds[i] = preps[i]["bounds"]
-                    new.layouts[i] = preps[i]["layout"]
-                    if new.compressed:
-                        zm = dc._col_zone_stats(new, preps[i])
-                        if zm is not None:
-                            new.zmaps[i] = zm
-                for _ in dc._stream_slabs(ctx, new, None, cols, preps, ph):
-                    pass
+            with dc.beside_statements():
+                if total:
+                    preps = {}
+                    for i in cols:
+                        # _col_prep re-runs choose_layout under the CURRENT
+                        # workload hints — the compaction-time layout
+                        # re-search of arXiv 2112.13099
+                        preps[i] = dc._col_prep(new, i, ftypes[i])
+                        _keep_what_still_fits(preps[i], cur, i)
+                        new.dicts[i] = preps[i]["dict"]
+                        new.bounds[i] = preps[i]["bounds"]
+                        new.layouts[i] = preps[i]["layout"]
+                        if new.compressed:
+                            zm = dc._col_zone_stats(new, preps[i])
+                            if zm is not None:
+                                new.zmaps[i] = zm
+                    for _ in dc._stream_slabs(ctx, new, None, cols, preps, ph):
+                        pass
+            timeline.tag(slabs=n_slabs)
+            pv = _warm(store, key, scan, new, job["max_slab"])
+            new = pv.ent
             failpoint.inject("compaction-commit")
-            with dc._LOCK:
-                installed = dc._CACHE.get(key)
-                fresh_td = store.snapshot().table_data(table_id)
-                if fresh_td is not td or installed is None:
-                    # the table moved on mid-rebuild (or the entry was
-                    # evicted): our rebuild is already stale — abandon it
-                    raise _StaleRebuild()
-                dc._CACHE[key] = new
-                dc._CACHE.move_to_end(key)
-            # the replaced generation's buffers free NOW unless a live
-            # statement still computes on them (protect discipline)
-            dc._safe_delete(installed, key[1:3])
+            with timeline.span("compact.swap", "delta", table=table_id):
+                with dc._LOCK:
+                    installed = dc._CACHE.get(key)
+                    if installed is None or \
+                            installed.lineage != cur.lineage:
+                        # evicted, or rebuilt by a statement meanwhile:
+                        # nothing of ours is wanted any more
+                        raise _StaleRebuild()
+                    dc.install_preview(pv)
+                # the replaced generation's buffers free NOW unless a live
+                # statement still computes on them (protect discipline)
+                dc._safe_delete(installed, key[1:3])
     except BaseException:
         if new is not None:
             new.delete()    # exclusively owned — frees HBM immediately
+        for built in (pv.aligned.values() if pv is not None else ()):
+            built.delete()
         raise
     from tidb_tpu.util.observability import REGISTRY
-    REGISTRY.inc("tidb_tpu_compactions_total", {"table": str(table_id)})
-    if timeline.ENABLED:
-        timeline.instant("compaction", "cache",
-                         args={"table": table_id, "rows": total,
-                               "slabs": n_slabs})
+    REGISTRY.inc("tidb_tpu_compactions_total",
+                 {"table": str(table_id), "cause": job.get("cause", "")})
     return True
+
+
+def _warm(store, key, scan, new, max_slab: int):
+    """Before the swap, on the compactor's thread: every fragment that
+    lately read this table runs once over the rebuilt generation, brought
+    to the store's newest snapshot first (the table moved on while it was
+    rebuilt, and the statements after the swap will read such an
+    extension). Re-chosen layouts mean new programs; they trace and
+    compile HERE, where nobody waits, and the aligned structures over the
+    new row positions are built here too (`device_cache.Preview`). A
+    reader that cannot be warmed is skipped: the statement then pays what
+    it would have paid. → the preview to install."""
+    from tidb_tpu.executor import ExecContext
+    from tidb_tpu.executor import device_cache as dc
+    from tidb_tpu.executor.fragment import TpuFragmentExec
+    pv = dc.Preview(key, new)
+    table_id = scan.table.id
+
+    def follow(ctx) -> bool:
+        if ctx.snapshot.table_data(table_id) is not pv.ent.td:
+            nxt = extend_entry(ctx, scan, pv.ent, max_slab, private=True)
+            if nxt is None:
+                return False
+            pv.ent = nxt
+        return True
+
+    # (a pod entry's slabs live on several devices under placement the
+    # statements' admission makes: not warmed)
+    for plan, vars_ in dc.readers(key[1], table_id) if key[0] >= 0 else ():
+        # a plain generation (nothing written meanwhile) is read a second
+        # time under masks and with an empty delta slab: the variants the
+        # first write after the swap will ask for
+        for masked in (False, True):
+            # the newest snapshot each time: the other tables a reader
+            # joins are the shared cache's, and only ever move forward.
+            # No admission slot: the launches are a few, and a compile
+            # inside one would hold every statement up while it lasts
+            # (twice: the first step may cover minutes of writes, and the
+            # statement has to run at a snapshot no older than what the
+            # connections have made of the other tables by now)
+            for _ in range(2):
+                ctx = ExecContext(
+                    snapshot=store.snapshot(),
+                    vars={**vars_, "tidb_tpu_scheduler": "off"})
+                ctx.phases.device_index = key[0]    # the entry's device
+                if not follow(ctx):
+                    return pv       # it cannot follow: swap what there is
+            swap = pv.ent
+            if masked and swap.is_delta:
+                continue
+            with timeline.span("compact.warm", "delta", table=table_id,
+                               root=plan.root.name, masked=masked):
+                try:
+                    if masked:
+                        pv.ent = extend_entry(ctx, scan, swap, max_slab,
+                                              masked=True,
+                                              private=True) or swap
+                    ex = TpuFragmentExec(plan)
+                    ex.open(ctx)
+                    with pv, ex._protect_tables():
+                        ex._run_device()
+                except Exception as e:  # noqa: BLE001 — best effort
+                    timeline.tag(skipped=type(e).__name__)
+                finally:
+                    pv.ent = swap
+    return pv
+
+
+def _keep_what_still_fits(prep: dict, cur, i: int) -> None:
+    """Bounds and a packed frame of reference are constants of the
+    compiled programs: where the rebuilt column still fits the replaced
+    generation's, it keeps them, and the statements their programs."""
+    old_b, new_b = cur.bounds.get(i), prep.get("bounds")
+    if old_b is not None and new_b is not None and prep["dict"] is None \
+            and old_b[0] <= new_b[0] and new_b[1] <= old_b[1]:
+        prep["bounds"] = old_b
+    old, new = cur.layouts.get(i), prep.get("layout")
+    if old is not None and new is not None and prep["kind"] == "num" \
+            and old.kind == new.kind == "pack" and old.dtype == new.dtype \
+            and old.sig() != new.sig() and new_b is not None \
+            and new_b[0] >= old.ref \
+            and new_b[1] - old.ref < (1 << old.width):
+        prep["layout"] = old
 
 
 class _StaleRebuild(Exception):

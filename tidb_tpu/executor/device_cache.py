@@ -37,6 +37,7 @@ elsewhere (locate_tables is its oracle).
 from __future__ import annotations
 
 import threading
+import time
 from collections import OrderedDict, deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
@@ -73,8 +74,10 @@ class CachedTable:
     __slots__ = ("td", "max_slab", "total", "slab_cap", "n_slabs",
                  "parts", "dicts", "dev", "bounds", "n_cols", "layouts",
                  "compressed", "zmaps", "holes", "base_slabs",
-                 "delta_version", "rows_override", "is_delta", "cov",
-                 "max_rid", "tomb", "delta_rows", "dictvals_host",
+                 "base_total", "delta_version", "rows_override", "is_delta",
+                 "cov", "max_rid", "seen", "rowmap", "lineage", "base_td",
+                 "alive",
+                 "delta_cap", "delta_rows", "dead_rows", "steps",
                  "device", "owners", "lost")
 
     def __init__(self, td, max_slab: int, total: int, slab_cap: int,
@@ -88,26 +91,38 @@ class CachedTable:
         self.parts = parts              # [(aligned chunk, alive or None)]
         self.compressed = compressed    # tidb_tpu_compression at build
         # -- delta-generation state (executor/delta.py) ------------------
-        # base_slabs: slab count of the immutable committed base; equals
-        # n_slabs until a delta extension appends the delta slab at index
-        # base_slabs. delta_version: the store's monotonic commit version
-        # this generation serves (microbatch/specialization keys pin it).
-        # rows_override: per-slab LIVE row counts once tombstones or the
-        # delta slab make the uniform slab_cap arithmetic wrong.
-        # cov/max_rid: the base build's region coverage — what the next
-        # extension diffs the current TableData against. tomb: per-slab
-        # sorted arrays of ORIGINAL base-local row positions removed so
-        # far (fresh tombstones map through them into current slab
-        # coordinates). delta_rows: live rows in the delta slab.
+        # base_slabs / base_total: slabs and rows of the immutable base as
+        # built; no resident row ever moves. delta_cap: capacity of the
+        # RAW delta slab at index base_slabs (0 until the first append);
+        # delta_rows: rows written into it so far, dead ones included.
+        # alive: per slab (base and delta) the device liveness mask, from
+        # the first change on (None before: a live prefix). rows_override:
+        # per-slab LIVE row counts beside the masks; dead_rows: rows dead
+        # since the build. seen / rowmap: the ledger the next extension
+        # diffs the current TableData against (delta.ledger_from_coverage).
+        # lineage: a token all generations of one base build share — the
+        # identity of the TableData it was built from, held as base_td so
+        # that the id is not reused (the specialization cache keys it;
+        # nothing of a generation is part of a program). delta_version: the store's commit version this
+        # generation serves (micro-batches of one generation share it).
+        # steps: what each extension since the build changed, for the
+        # FK-aligned joins to follow.
         self.base_slabs = n_slabs
+        self.base_total = total
         self.delta_version = 0
         self.rows_override: Optional[Dict[int, int]] = None
         self.is_delta = False
-        self.cov = None           # [(rid, n_rows, alive mask, base_off)]
+        self.cov = None    # [(rid, n_rows, alive or None, base_off, region)]
         self.max_rid = -1         # max region id across the WHOLE td
-        self.tomb: Dict[int, np.ndarray] = {}
+        self.seen: Optional[Dict[int, object]] = None
+        self.rowmap: Optional[Dict[int, tuple]] = None
+        self.base_td = td
+        self.lineage = id(td)
+        self.alive: Optional[List] = None
+        self.delta_cap = 0
         self.delta_rows = 0
-        self.dictvals_host: Dict[int, np.ndarray] = {}
+        self.dead_rows = 0
+        self.steps: tuple = ()
         # pod-scale placement: the pool device index owning this entry's
         # arrays (-1 = pod-partitioned), and for pod entries the per-slab
         # owner device list (contiguous spans — slab s lives on owners[s])
@@ -135,6 +150,50 @@ class CachedTable:
         # holes re-streams that column in full
         self.holes: Dict[int, frozenset] = {}
 
+    def set_coverage(self, cov, max_rid: int) -> None:
+        """Adopt a base build's coverage ledger (`_collect_parts`)."""
+        from tidb_tpu.executor import delta
+        self.cov, self.max_rid = cov, max_rid
+        self.seen, self.rowmap = delta.ledger_from_coverage(cov) \
+            if cov is not None else (None, None)
+
+    def slab_live(self, s: int):
+        """What a slab program takes as the slab's liveness: the device
+        mask of a delta generation, else the live prefix's length."""
+        if self.alive is not None:
+            return self.alive[s]
+        return self.slab_rows(s)
+
+    def slab_mask(self, s: int):
+        """The slab's liveness as a device mask, made from the live
+        prefix where the generation holds none."""
+        if self.alive is not None:
+            return self.alive[s]
+        from tidb_tpu.executor import device_emit
+        return device_emit.emit_alive_init(self.slab_rows(s),
+                                           self.slab_shape(s)[0])
+
+    def slab_shape(self, s: int):
+        """(capacity, is the raw delta slab) of slab `s`."""
+        if self.delta_cap and s >= self.base_slabs:
+            return self.delta_cap, True
+        return self.slab_cap, False
+
+    def _arrays(self):
+        """(slab index, device array) of everything this generation holds:
+        column slabs (a shared dictionary once) and liveness masks."""
+        seen = set()
+        for slabs in self.dev.values():
+            for s, t in enumerate(slabs):
+                if t is None:
+                    continue            # pruned-away cold slab (hole)
+                for a in t:
+                    if id(a) not in seen:
+                        seen.add(id(a))
+                        yield s, a
+        for s, a in enumerate(self.alive or ()):
+            yield s, a
+
     def resident(self, col: int, skip=frozenset()) -> bool:
         """Column `col` is usable for a statement skipping `skip`: its
         device slabs exist and any holes fall inside the skip set."""
@@ -148,34 +207,24 @@ class CachedTable:
         return min(self.slab_cap, self.total - s * self.slab_cap)
 
     def hbm_bytes(self) -> int:
-        total = 0
-        seen = set()
-        for slabs in self.dev.values():
-            for t in slabs:
-                if t is None:
-                    continue            # pruned-away cold slab (hole)
-                for a in t:
-                    if id(a) in seen:
-                        continue        # shared dictvals counted once
-                    seen.add(id(a))
-                    total += a.nbytes
-        return total
+        return sum(a.nbytes for _s, a in self._arrays())
 
     def logical_bytes(self, cols=None) -> int:
         """Bytes the selected columns WOULD occupy uncompressed (raw
-        columns: physical == logical)."""
+        columns and the raw delta slab: physical == logical)."""
         from tidb_tpu.chunk import compress
         total = 0
         for i, slabs in self.dev.items():
             if cols is not None and i not in cols:
                 continue
             lay = self.layouts.get(i)
-            if lay is None:
-                total += sum(a.nbytes for t in slabs if t is not None
-                             for a in t)
-            else:
-                total += compress.raw_slab_bytes(lay, self.slab_cap) \
-                    * sum(1 for t in slabs if t is not None)
+            for s, t in enumerate(slabs):
+                if t is None:
+                    continue
+                if lay is None or self.slab_shape(s)[1]:
+                    total += sum(a.nbytes for a in t)
+                else:
+                    total += compress.raw_slab_bytes(lay, self.slab_cap)
         return total
 
     def delete(self) -> None:
@@ -183,17 +232,10 @@ class CachedTable:
         entry must not keep HBM resident until the GC happens to run —
         a recompile right after eviction would otherwise double the
         high-water mark."""
-        seen = set()
-        for slabs in self.dev.values():
-            for t in slabs:
-                if t is None:
-                    continue            # pruned-away cold slab (hole)
-                for a in t:
-                    if id(a) in seen:
-                        continue        # shared dictvals deleted once
-                    seen.add(id(a))
-                    _delete_array(a)
+        for _s, a in list(self._arrays()):
+            _delete_array(a)
         self.dev.clear()
+        self.alive = None
 
 
 def _delete_array(a) -> None:
@@ -241,6 +283,80 @@ _LOCK = threading.RLock()
 _PROTECT: Dict[int, frozenset] = {}
 
 
+# ---- what a compaction rebuilt, before the swap ---------------------------
+# The compactor runs the statements that read its table ONCE over the
+# rebuilt generation before it swaps it in (delta._warm), so the programs a
+# re-chosen layout needs compile on its thread and not in the first
+# statement after the swap. On that thread alone the table's key resolves
+# to the rebuilt generation and aligned structures are built aside; the
+# swap installs both.
+_PREVIEW = threading.local()
+MAX_READERS = 8
+# (store id, table id) → the plans of the fragments that last read it
+_READERS: Dict[Tuple[int, int], "OrderedDict[tuple, tuple]"] = {}
+
+
+class PreviewMiss(Exception):
+    """A warm-up statement would have had to change the shared cache."""
+
+
+class Preview:
+    def __init__(self, key, ent):
+        self.key, self.ent = key, ent
+        self.aligned: "OrderedDict[tuple, AlignedJoin]" = OrderedDict()
+
+    def __enter__(self):
+        _PREVIEW.cur = self
+        return self
+
+    def __exit__(self, *exc):
+        _PREVIEW.cur = None
+
+
+def _previewing() -> Optional[Preview]:
+    return getattr(_PREVIEW, "cur", None)
+
+
+def note_reader(store_id: int, table_ids, plan, vars_, what) -> None:
+    """Remember `plan` (a device fragment that just ran) as a reader of
+    its tables: what a compaction warms before its swap. `what` tells one
+    reader from another (the statement's text and the fragment's root: a
+    table that moves is re-planned at every statement, and the newest
+    plan of a statement stands for the older ones)."""
+    if _previewing() is not None:
+        return
+    with _LOCK:
+        for tid in table_ids:
+            seen = _READERS.setdefault((store_id, tid), OrderedDict())
+            known = seen.get(what)
+            if known is not None and known[0] is plan:
+                seen.move_to_end(what)
+                continue
+            seen[what] = (plan, dict(vars_))
+            seen.move_to_end(what)
+            while len(seen) > MAX_READERS:
+                seen.popitem(last=False)
+
+
+def readers(store_id: int, table_id: int) -> list:
+    with _LOCK:
+        return list(_READERS.get((store_id, table_id), {}).values())
+
+
+def install_preview(pv: Preview) -> None:
+    """The swap: the rebuilt generation takes its key and the structures
+    built over it take theirs (called under `_LOCK`)."""
+    _CACHE[pv.key] = pv.ent
+    _CACHE.move_to_end(pv.key)
+    for akey, new in pv.aligned.items():
+        old = _ALIGNED.get(akey)
+        _ALIGNED[akey] = new
+        _ALIGNED.move_to_end(akey)
+        if old is not None and old is not new:
+            _safe_delete(old)
+    pv.aligned.clear()
+
+
 def _all_protected() -> frozenset:
     with _LOCK:
         if not _PROTECT:
@@ -249,6 +365,14 @@ def _all_protected() -> frozenset:
         for pairs in _PROTECT.values():
             out |= pairs
         return frozenset(out)
+
+
+def _protected_elsewhere(pair) -> bool:
+    """Whether a statement of ANOTHER thread computes on the table."""
+    me = threading.get_ident()
+    with _LOCK:
+        return any(pair in pairs for tid, pairs in _PROTECT.items()
+                   if tid != me)
 
 
 @contextmanager
@@ -287,12 +411,22 @@ def _safe_delete(ent, pair=None) -> None:
     _entry_delete(ent)
 
 
+def _drop_entry(key, ent) -> None:
+    """Take `ent` out of the cache (if it is still what `key` holds) and
+    free what no statement in flight computes on."""
+    with _LOCK:
+        if _CACHE.get(key) is ent:
+            _CACHE.pop(key, None)
+    _safe_delete(ent, key[1:3])
+
+
 def clear():
     with _LOCK:
         cache = list(_CACHE.items())
         aligned = list(_ALIGNED.values())
         _CACHE.clear()
         _ALIGNED.clear()
+        _READERS.clear()
     for k, e in cache:
         _safe_delete(e, k[1:3])
     for e in aligned:
@@ -347,6 +481,8 @@ def _evict_store(store_id: int):
         dead_a = [_ALIGNED.pop(k) for k in list(_ALIGNED)
                   if k[0] == store_id]
         _STORE_FINALIZERS.pop(store_id, None)
+        for k in [k for k in _READERS if k[0] == store_id]:
+            del _READERS[k]
     for key, ent in dead_c:
         _safe_delete(ent, key[1:3])
     for ent in dead_a:
@@ -453,17 +589,9 @@ def _entry_dev_bytes(key, ent) -> Dict[int, int]:
     if d >= 0 or not owners:
         return {d if d >= 0 else 0: int(ent.hbm_bytes())}
     out: Dict[int, int] = {}
-    seen = set()
-    for slabs in ent.dev.values():
-        for s, t in enumerate(slabs):
-            if t is None:
-                continue            # pruned-away cold slab (hole)
-            o = owners[s] if s < len(owners) else owners[-1]
-            for a in t:
-                if id(a) in seen:
-                    continue        # shared dictvals counted once
-                seen.add(id(a))
-                out[o] = out.get(o, 0) + int(a.nbytes)
+    for s, a in ent._arrays():
+        o = owners[s] if s < len(owners) else owners[-1]
+        out[o] = out.get(o, 0) + int(a.nbytes)
     return out or {0: 0}
 
 
@@ -587,8 +715,9 @@ def _collect_parts(ctx, scan, coverage: bool = False):
         mask = None if alive.all() else alive
         n = chunk.num_rows if mask is None else int(mask.sum())
         if coverage and region is not None:
-            cov.append((region.id, region.num_rows, np.asarray(alive),
-                        total))
+            cov.append((region.id, region.num_rows,
+                        None if mask is None else np.asarray(alive),
+                        total, region))
         if n:
             parts.append((chunk, mask))
             total += n
@@ -675,6 +804,61 @@ def wide_decimal_unlimb(limbs: np.ndarray) -> np.ndarray:
     return out
 
 
+#: rows of strings one numpy call sorts or searches. Comparing objects,
+#: numpy keeps the interpreter's lock for the whole call: in pieces, the
+#: other threads run between two calls.
+STR_CHUNK = 1 << 16
+#: the same for a rebuild BESIDE the statements (a compaction's), and the
+#: nap after each piece. A statement takes the interpreter's lock back
+#: some thousand times (every launch, wait, packet and region of a scan
+#: gives it away) and waits each time for the piece in progress: beside
+#: one `np.unique` over a column of 24M strings a statement stood still
+#: for as long as it took (38 s, chip, SF=4), beside pieces of 65,536 rows
+#: (≈ 20 ms each) two operations in a row still took 14 s where the
+#: median is 0.33. A piece of 1,024 rows is done in a fraction of a
+#: millisecond, and the nap hands the lock to whoever waits for it (a
+#: bare release is won back by the releasing thread before the waiter
+#: wakes). First touch, which a statement waits for anyway, does not nap.
+BESIDE_CHUNK = 1 << 10
+BESIDE_NAP = 5e-5
+_BESIDE = threading.local()
+
+
+@contextmanager
+def beside_statements():
+    """Host work of this thread that no statement waits for: it gives
+    way to the threads that serve one."""
+    _BESIDE.on = True
+    try:
+        yield
+    finally:
+        _BESIDE.on = False
+
+
+def _str_pieces(n: int):
+    beside = getattr(_BESIDE, "on", False)
+    step = BESIDE_CHUNK if beside else STR_CHUNK
+    for a in range(0, n, step):
+        yield a, a + step
+        if beside:
+            time.sleep(BESIDE_NAP)
+
+
+def _str_unique(vals: np.ndarray) -> np.ndarray:
+    """`np.unique(vals)` of an object array of strings, piecewise."""
+    found = [np.unique(vals[a:b]) for a, b in _str_pieces(vals.shape[0])]
+    return np.unique(np.concatenate(found)) if len(found) > 1 \
+        else np.unique(vals)
+
+
+def _str_codes(keys: np.ndarray, vals: np.ndarray) -> np.ndarray:
+    """`np.searchsorted(keys, vals)` as int32 codes, piecewise."""
+    out = np.empty(vals.shape[0], dtype=np.int32)
+    for a, b in _str_pieces(vals.shape[0]):
+        out[a:b] = np.searchsorted(keys, vals[a:b])
+    return out
+
+
 def _col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
     """Once-per-column host prep for the streamed first-touch: materialize
     the column and build the GLOBAL dictionary/bounds. Per-slab encoding
@@ -693,7 +877,9 @@ def _col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
                 "dict": None, "bounds": None, "layout": None}
     if ftype.is_varlen:
         with first_touch("dict", col=col_idx):
-            str_vals = np.array([str(v) for v in vals], dtype=object)
+            str_vals = np.empty(vals.shape[0], dtype=object)
+            for a, b in _str_pieces(vals.shape[0]):
+                str_vals[a:b] = [str(v) for v in vals[a:b]]
             if ftype.is_ci:
                 from tidb_tpu.types import fold_ci_array
                 folded = fold_ci_array(str_vals)
@@ -702,7 +888,7 @@ def _col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
                 prep = {"kind": "str", "vals": folded, "valid": valid,
                         "keys": keys}
             else:
-                dictionary = np.unique(str_vals)
+                dictionary = _str_unique(str_vals)
                 prep = {"kind": "str", "vals": str_vals, "valid": valid,
                         "keys": dictionary}
         prep["dict"] = dictionary
@@ -765,13 +951,12 @@ def _col_zone_stats(ent: CachedTable, prep: dict):
     if k == "wide":
         return None
     if k == "str":
-        codes = np.searchsorted(prep["keys"],
-                                prep["vals"]).astype(np.int32)
+        codes = _str_codes(prep["keys"], prep["vals"])
         return zonemap.column_stats(codes, prep["valid"], ent.slab_cap,
-                                    ent.total, "code")
+                                    ent.base_total, "code")
     kind = "float" if k == "float" else "num"
     return zonemap.column_stats(prep["vals"], prep["valid"],
-                                ent.slab_cap, ent.total, kind)
+                                ent.slab_cap, ent.base_total, kind)
 
 
 def _est_slab_phys(prep: dict, slab_cap: int) -> int:
@@ -819,8 +1004,7 @@ def _slab_host(prep: dict, start: int, stop: int, slab_cap: int):
             v = pv
     else:
         if kind == "str":
-            v = np.searchsorted(prep["keys"],
-                                prep["vals"][start:stop]).astype(np.int32)
+            v = _str_codes(prep["keys"], prep["vals"][start:stop])
         elif kind == "float":
             v = prep["vals"][start:stop].astype(prep["dtype"])
         else:
@@ -866,7 +1050,7 @@ def _note_storage_metrics(ent: CachedTable, key) -> None:
 
 
 def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
-                  skip=frozenset(), fill=None):
+                  skip=frozenset(), fill=None, tail=None):
     """Generator behind open_table: per slab, encode the missing columns
     (host), issue their uploads (async device_put), and yield
     (slab_idx, {col: slab tuple}) covering EVERY used column so the
@@ -890,7 +1074,12 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
     (to the slab's NEW owner — evict_device already re-owned the
     range), warm slabs reuse the live tuples, and the commit splices
     the refilled slabs into the existing column instead of replacing
-    it."""
+    it.
+
+    A delta generation streams its BASE slabs (no resident row ever
+    moved, so the build's parts still say what they hold); `tail` (col →
+    slab tuple) is the delta slab of each streamed column, appended at
+    the commit."""
     from tidb_tpu.errors import DeviceLost
     from tidb_tpu.executor import zonemap
     from tidb_tpu.ops.jax_env import jax, jnp
@@ -952,7 +1141,7 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
             phases.add_h2d(int(t.nbytes), logical=0)
         return t
 
-    for s in range(ent.n_slabs):
+    for s in range(ent.base_slabs):
         if s in skip:
             # pruned cold slab: no encode, no PCIe, no dispatch — the
             # statement still answered for its rows, so the logical
@@ -968,7 +1157,7 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
                                            for i in used_cols))
             continue
         start = s * ent.slab_cap
-        stop = min(start + ent.slab_cap, ent.total)
+        stop = min(start + ent.slab_cap, ent.base_total)
         host = {}
         with phases.phase("encode"):
             for i, prep in preps.items():
@@ -1027,6 +1216,8 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
             # deterministic); the loser's arrays drop on the floor and
             # refcounting frees them — never a half-overwritten column
             if i not in ent.dev:
+                if tail and i in tail:
+                    slabs.append(tail[i])
                 ent.dev[i] = slabs
                 if skip:
                     ent.holes[i] = frozenset(skip)
@@ -1071,15 +1262,16 @@ def _decoded_slabs(ent: CachedTable, col: int):
     eager decode for aligned-join builds, whose outputs (midx/matched
     and gathered build columns) are cached raw in the fact slab layout,
     so the per-query tree/fused consumers of aligned columns never
-    carry an in-trace decode."""
+    carry an in-trace decode. A delta slab is raw as it stands."""
     slabs = ent.dev[col]
     lay = ent.layouts.get(col)
     if lay is None:
         return slabs
     from tidb_tpu.chunk import compress
     from tidb_tpu.ops.jax_env import jnp
-    return [compress.decode_slab(lay, t, ent.slab_cap, jnp)
-            for t in slabs]
+    return [t if ent.slab_shape(s)[1]
+            else compress.decode_slab(lay, t, ent.slab_cap, jnp)
+            for s, t in enumerate(slabs)]
 
 
 def storage_stats(store_id: Optional[int] = None) -> List[dict]:
@@ -1141,7 +1333,8 @@ def _protected(ctx) -> frozenset:
 
 
 def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
-               prune: bool = False):
+               prune: bool = False, delta_ok: bool = False,
+               _plain: bool = False):
     """→ (CachedTable, slab stream or None) — the streamed first-touch.
 
     Warm path (every used column already resident) returns stream=None.
@@ -1164,6 +1357,14 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
     answered for. Callers that need complete columns (the tree/dist
     mega-slab paths, aligned builds) leave prune off — a column whose
     holes exceed the statement's prune set is re-streamed in full.
+
+    `delta_ok`: the caller's slab programs take a delta generation as it
+    is (liveness masks, a raw delta slab of its own capacity). Every other
+    consumer assumes a live prefix and uniform slabs: where the table's
+    entry is a delta generation it gets a plain rebuild, counted as a
+    decline of gate `consumer` and cached BESIDE the generation (`_plain`:
+    the same key with its partitions tagged "plain"), which stays where it
+    is for the statements that extend it.
     """
     from tidb_tpu.util import failpoint
     from tidb_tpu.util.phases import PhaseTimer
@@ -1193,6 +1394,13 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         dev = -1
     key = (dev, id(store), table_id,
            None if parts is None else tuple(parts)) if cacheable else None
+    if _plain and key is not None:
+        key = key[:3] + (("plain", key[3]),)
+
+    def _plain_beside():
+        return open_table(ctx, scan, used_cols, max_slab, phases=phases,
+                          prune=prune, _plain=True)
+
     _reap_dead_stores()
     with _LOCK:
         if store is not None and id(store) not in _STORE_FINALIZERS:
@@ -1210,14 +1418,19 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
 
     stale = None
     extend_from = None
+    pv = _previewing()
     with _LOCK:
         ent = _CACHE.get(key) if cacheable else None
-        if ent is not None and not _usable(ent):
+        if pv is not None and key == pv.key:
+            ent = pv.ent
+            if not _usable(ent) or (ent.is_delta and not delta_ok):
+                raise PreviewMiss(f"table {table_id}")
+        elif ent is not None and not _usable(ent):
             if (ent.td is not None and td is not None
                     and ent.max_slab == max_slab
                     and ent.n_cols == len(scan.schema)
                     and ent.compressed == comp_on
-                    and ent.cov is not None):
+                    and ent.seen is not None and not _plain):
                 # stale ONLY because the data moved on (geometry, schema
                 # width and compression all still match): try the
                 # incremental delta extension before paying a rebuild
@@ -1234,8 +1447,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
     if extend_from is not None:
         from tidb_tpu.executor import delta as _delta
         new_ent = _delta.extend_entry(
-            ctx, scan, extend_from, max_slab,
-            phases if phases is not None else None)
+            ctx, scan, extend_from, max_slab, phases, quiet=pv is not None)
         if new_ent is not None:
             with _LOCK:
                 cur = _CACHE.get(key)
@@ -1244,12 +1456,15 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                     # old object (their snapshot), new statements see
                     # base∪delta−tombstones. The old generation is NOT
                     # deleted — it shares the base device arrays with the
-                    # new one; refcounting frees its delta-only buffers.
+                    # new one; refcounting frees what only it held.
                     _CACHE[key] = new_ent
                     _CACHE.move_to_end(key)
                     ent = new_ent
                 elif cur is not None and _usable(cur):
                     ent = cur    # raced another extension/rebuild: adopt
+        if ent is None and pv is not None:
+            # (a warm-up never costs the statements in flight an entry)
+            raise PreviewMiss(f"table {table_id}")
         if ent is None:
             # extension declined (a gate tripped) or lost the install
             # race. Drop the stale generation and rebuild — but only
@@ -1267,7 +1482,14 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                     ent = cur
             if dead is not None:
                 _safe_delete(dead, key[1:3])
+    if ent is not None and ent.is_delta and not delta_ok:
+        # a delta generation (just extended, or fresh), and a consumer
+        # that cannot take one
+        return _plain_beside()
     if ent is None:
+        if _plain:
+            from tidb_tpu.executor import delta as _delta
+            _delta.decline("consumer", table_id)
         if cacheable:
             parts, total, cov, max_rid = _collect_parts(ctx, scan,
                                                         coverage=True)
@@ -1287,8 +1509,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
             # n_slabs >= nd)
             built.owners = [min(s * nd // max(n_slabs, 1), nd - 1)
                             for s in range(n_slabs)]
-        built.cov = cov
-        built.max_rid = max_rid
+        built.set_coverage(cov, max_rid)
         built.delta_version = int(getattr(ctx.snapshot, "version", 0) or 0) \
             if cacheable else 0
         if cacheable:
@@ -1302,7 +1523,15 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                     _CACHE.move_to_end(key)
                 else:
                     if cur is not None:
-                        victims.append(_CACHE.pop(key))
+                        # another statement's generation for ANOTHER
+                        # snapshot: it shares its base arrays with what
+                        # that statement computes on, so while any other
+                        # thread protects the table its last reference
+                        # frees it, not we
+                        _CACHE.pop(key)
+                        if not _protected_elsewhere(key[1:3]):
+                            victims.append(cur)
+                        cur = None
                     ent = _CACHE[key] = built
                     # lazy replication: another device already holds this
                     # (store, table, parts) — this install is a replica
@@ -1329,6 +1558,8 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                              {"device": str(dev)})
         else:
             ent = built
+        if ent.is_delta and not delta_ok:
+            return _plain_beside()      # (adopted from a lost race)
 
     if not ent.total:
         return ent, None
@@ -1345,16 +1576,6 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         missing.append(i)
         if i in ent.dev:
             refill.append(i)
-    if missing and ent.is_delta and cacheable:
-        # a delta generation cannot cold-stream a column it never held:
-        # its parts ledger predates the delta rows and tombstones, so an
-        # encode from it would silently miss them — rebuild fresh
-        with _LOCK:
-            if _CACHE.get(key) is ent:
-                _CACHE.pop(key, None)
-        _safe_delete(ent, key[1:3])
-        return open_table(ctx, scan, used_cols, max_slab, phases=phases,
-                          prune=prune)
     fill = {}
     if refill:
         lost = set(getattr(ent, "lost", None) or ())
@@ -1437,16 +1658,37 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         # re-consult with the freshly prepped columns' statistics — the
         # skip set only ever grows, so warm columns' holes stay covered
         skip = zonemap.prune_slabs(ent, scan)
+    tail = None
+    if ent.delta_cap:
+        # the delta slab of the columns this generation did not hold yet
+        from tidb_tpu.executor import delta as _delta
+        tail = {}
+        try:
+            for i in missing:
+                if i not in fill:
+                    tail[i], nbytes = _delta.delta_column(ent, scan, i,
+                                                          ftypes[i])
+                    ph.add_h2d(nbytes, logical=nbytes)
+        except _delta._Declined as d:
+            # (an appended string the column's dictionary, built from the
+            # base's rows, does not hold): rebuild fresh
+            _delta.decline(d.gate, table_id)
+            if pv is not None:
+                raise PreviewMiss(f"table {table_id}") from d
+            _drop_entry(key, ent)
+            return open_table(ctx, scan, used_cols, max_slab, phases=phases,
+                              prune=prune, delta_ok=delta_ok, _plain=_plain)
     return ent, _stream_slabs(ctx, ent, key, list(used_cols), preps, ph,
-                              skip=skip, fill=fill or None)
+                              skip=skip, fill=fill or None, tail=tail)
 
 
 def get_table(ctx, scan, used_cols, max_slab: int,
-              phases=None) -> CachedTable:
+              phases=None, delta_ok: bool = False) -> CachedTable:
     """→ CachedTable with every column in `used_cols` uploaded (open_table
     drained — callers that can't interleave compute, e.g. the join-tree
     path, still get the per-slab encode∥upload pipelining)."""
-    ent, stream = open_table(ctx, scan, used_cols, max_slab, phases=phases)
+    ent, stream = open_table(ctx, scan, used_cols, max_slab, phases=phases,
+                             delta_ok=delta_ok)
     if stream is not None:
         for _ in stream:
             pass
@@ -1549,10 +1791,19 @@ def aligned_budget_check(ctx, keep_keys=frozenset(),
 
 
 class AlignedJoin:
-    """Cached FK-aligned join structure for ONE (fact path, build) pair."""
+    """Cached FK-aligned join structure for ONE (fact path, build) pair.
+
+    A structure follows its tables through delta generations
+    (`_advance_aligned`): no fact row ever moves, so what it holds for the
+    base slabs stays; a build row that dies unmatches the fact rows that
+    carry its key (a few key ranges compared against the fact key, no
+    gather), a build row that arrives enters the lookup table, a fact
+    row that arrives is probed and its build columns gathered — the new
+    rows alone, into the arrays of the fact's delta slab."""
 
     __slots__ = ("tds", "slab_cap", "n_slabs", "unique", "matched",
-                 "midx", "cols", "build_nb", "key")
+                 "midx", "cols", "build_nb", "key", "lut", "lo", "domain",
+                 "space", "dangling", "bcat")
 
     def __init__(self, key, tds, slab_cap, n_slabs, build_nb):
         self.key = key
@@ -1564,55 +1815,80 @@ class AlignedJoin:
         self.matched: List = []     # per fact slab: bool (slab_cap,)
         self.midx: List = []        # per fact slab: int32 (slab_cap,)
         self.cols: Dict[int, List[Tuple]] = {}   # build col → [(v, m)] slabs
+        self.lut = None             # key - lo → build row, -1 where none
+        self.lo, self.domain = 0, 0
+        # the base builds whose row POSITIONS this structure holds: the
+        # probe source's (the fact table's; a chained hop's, its parent
+        # structure's) and last the build table's. A compaction moves
+        # rows without changing the table's data, so freshness is the
+        # data's identity AND these
+        self.space: Tuple = ()
+        # live fact rows whose key matches no live build row (a device
+        # scalar, fetched only when a build row arrives: one that could
+        # adopt such a row means a rebuild)
+        self.dangling = None
+        self.bcat: Dict[int, Tuple] = {}    # build col → decoded base rows
 
-    def hbm_bytes(self) -> int:
-        total = 0
+    def _owned(self):
         for arrs in (self.matched, self.midx):
-            for a in arrs:
-                total += a.nbytes
+            yield from arrs
         for slabs in self.cols.values():
             for v, m in slabs:
-                total += v.nbytes + m.nbytes
-        return total
+                yield v
+                yield m
+        if self.lut is not None:
+            yield self.lut
+        for v, m in self.bcat.values():
+            yield v
+            yield m
+
+    def hbm_bytes(self) -> int:
+        return sum(a.nbytes for a in self._owned())
 
     def delete(self) -> None:
         """Free device buffers on eviction (see CachedTable.delete)."""
-        for arrs in (self.matched, self.midx):
-            for a in arrs:
-                _delete_array(a)
-        for slabs in self.cols.values():
-            for v, m in slabs:
-                _delete_array(v)
-                _delete_array(m)
-        self.matched = []
-        self.midx = []
+        for a in list(self._owned()):
+            _delete_array(a)
+        self.matched, self.midx, self.lut = [], [], None
         self.cols.clear()
+        self.bcat.clear()
 
 
 def _fresh(ctx, tds) -> bool:
     return all(ctx.snapshot.table_data(tid) is td for tid, td in tds.items())
 
 
-def _build_cat(ent: CachedTable, col: int):
+def _build_cat(ent: CachedTable, col: int, base_only: bool = False):
     """Build-side column slabs concatenated (build tables are usually one
     slab; concat is a no-op then). Wide decimals concat on the row axis.
     Compressed slabs decode here — the LUT/gather builds below run once
-    per cached structure, so the eager decode is off the per-query path."""
+    per cached structure, so the eager decode is off the per-query path.
+    A delta generation's raw delta slab follows its base slabs."""
     from tidb_tpu.ops.jax_env import jnp
     slabs = _decoded_slabs(ent, col)
+    if base_only:
+        slabs = slabs[:ent.base_slabs]
     if len(slabs) == 1:
         return slabs[0]
     return (jnp.concatenate([s[0] for s in slabs], axis=-1),
             jnp.concatenate([s[1] for s in slabs]))
 
 
+def _alive_cat(ent: CachedTable):
+    """Row liveness over `_build_cat`'s rows."""
+    from tidb_tpu.ops.jax_env import jnp
+    masks = [ent.slab_mask(s) for s in range(ent.n_slabs)]
+    return masks[0] if len(masks) == 1 else jnp.concatenate(list(masks))
+
+
 ALIGNED_DOMAIN_CAP = 1 << 26    # max build-key LUT size at cache build
+ALIGNED_MAX_RANGES = 8          # dead build keys as key ranges, up to here
 
 
-def get_aligned(ctx, key, tds: Dict[int, object],
-                fact_codes_slabs, fact_valid_slabs,
+def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
                 build_ent: CachedTable, build_key_col: int,
-                bounds: Tuple[int, int], slab_cap: int, n_slabs: int):
+                bounds: Tuple[int, int], slab_cap: int, n_slabs: int,
+                space: Tuple, fact=None):
     """→ AlignedJoin for `key`, building midx/matched on first use, or None
     when the build side turns out non-unique on the key (the negative
     result is cached too — one LUT build per key, not one per query).
@@ -1620,28 +1896,50 @@ def get_aligned(ctx, key, tds: Dict[int, object],
     key: hashable path signature (store id, probe-source path, build table,
     build key col). tds: table_id → TableData token for EVERY table on the
     path — freshness is identity of all of them.
-    fact_codes_slabs/fact_valid_slabs: per-fact-slab device arrays of the
-    probe key (raw ints or dictionary codes already in the build's code
-    space). bounds: the build key column's (lo, hi) value domain."""
-    from tidb_tpu.ops.jax_env import (jax, jnp, named_jit,
-                                      program_name)
-    if getattr(build_ent, "is_delta", False):
-        # delta generations break the LUT's prefix-liveness assumption
-        # (iota < total): tombstone-compacted slabs and the appended
-        # delta slab make liveness per-slab, not a global prefix — the
-        # regular join path handles them; compaction restores alignment
-        return None
+    fact_slabs: () → (per-fact-slab probe key arrays, validity arrays),
+    raw ints or dictionary codes already in the build's code space, asked
+    for only when something has to be built. bounds: the build key
+    column's (lo, hi) value domain. space: the lineages of the base builds
+    the probe source's rows are positioned in (`AlignedJoin.space` less
+    the build's). fact: (fact CachedTable, its key column) when the probe
+    key is a column of the fact scan itself — such a structure follows
+    both tables' delta generations."""
+    from tidb_tpu.ops.jax_env import jax, jnp, named_jit, program_name
     stale = None
+    space = tuple(space) + (build_ent.lineage,)
+    pv = _previewing()
+    # (a compaction's warm-up builds aside: `Preview`)
+    tbl = pv.aligned if pv is not None else _ALIGNED
     with _LOCK:
-        ent = _ALIGNED.get(key)
+        ent = tbl.get(key)
         if ent is not None:
             if _fresh(ctx, ent.tds) and ent.slab_cap == slab_cap \
-                    and ent.n_slabs == n_slabs:
-                _ALIGNED.move_to_end(key)
+                    and ent.n_slabs == n_slabs and ent.space == space:
+                tbl.move_to_end(key)
                 return ent if ent.unique else None
-            _ALIGNED.pop(key, None)
             stale = ent
+    if stale is not None and fact is not None and stale.unique:
+        with timeline.span("delta.aligned", "delta",
+                           table=next(iter(tds), 0)):
+            new = _advance_aligned(stale, tds, fact[0], fact[1], build_ent,
+                                   build_key_col, bounds)
+        if isinstance(new, str):
+            # the structure is rebuilt in full (a decode, a probe and a
+            # gather over every fact row): counted like a table's rebuild
+            if pv is None:
+                from tidb_tpu.executor import delta as _delta
+                _delta.decline("aligned-" + new, next(iter(tds), 0))
+            new = None
+        if new is not None:
+            with _LOCK:
+                if tbl.get(key) is stale:
+                    tbl[key] = new
+                    tbl.move_to_end(key)
+            return new
     if stale is not None:
+        with _LOCK:
+            if tbl.get(key) is stale:
+                tbl.pop(key, None)
         _safe_delete(stale)
 
     lo, hi = bounds
@@ -1649,78 +1947,338 @@ def get_aligned(ctx, key, tds: Dict[int, object],
     if domain > ALIGNED_DOMAIN_CAP:
         return None
     bk_v, bk_m = _build_cat(build_ent, build_key_col)
+    b_alive = _alive_cat(build_ent)
     nb = int(bk_v.shape[0])
-    n_live = build_ent.total
     ent = AlignedJoin(key, tds, slab_cap, n_slabs, nb)
+    ent.lo, ent.domain = lo, domain
+    ent.space = space
     # named after what the trace bakes in, and nothing of this process
-    # (`key` holds object ids): the name is part of the persistent cache's
-    # key, and a restarted server must find these programs again
-    sig = repr((lo, hi, nb, n_live, slab_cap))
+    # (`key` holds object ids) or of the data: the name is part of the
+    # persistent cache's key, and a restarted server must find these
+    # programs again
+    sig = repr((lo, hi, nb))
 
-    def _lut(bv, bm):
+    def _lut(bv, bm, alive_b):
         iota = jnp.arange(nb, dtype=jnp.int32)
-        alive = jnp.asarray(bm) & (iota < n_live)
+        alive = jnp.asarray(bm) & alive_b
         code = jnp.where(alive, jnp.asarray(bv).astype(jnp.int64) - lo,
                          jnp.int64(domain))
         code = jnp.clip(code, 0, domain).astype(jnp.int32)
         cnt = jnp.zeros(domain + 1, jnp.int32).at[code].add(
             jnp.where(alive, 1, 0).astype(jnp.int32))
-        lut = jnp.full(domain + 1, -1, jnp.int32).at[code].set(iota)
+        lut = jnp.full(domain + 1, -1, jnp.int32).at[code].set(
+            jnp.where(alive, iota, -1))
         return cnt[:domain].max() if domain else jnp.int32(0), lut
 
     maxcnt, lut = named_jit(_lut, program_name("gather_lut", sig))(
-        bk_v, bk_m)
+        bk_v, bk_m, b_alive)
     if int(jax.device_get(maxcnt)) > 1:
         ent.unique = False          # negative result cached
         with _LOCK:
-            if key not in _ALIGNED:
-                _ALIGNED[key] = ent
+            if key not in tbl:
+                tbl[key] = ent
         return None
+    ent.lut = lut
 
-    def _probe(lut_, pv, pm):
+    def _probe(lut_, pv, pm, alive_f):
         c = jnp.asarray(pv).astype(jnp.int64) - lo
         in_dom = (c >= 0) & (c <= (hi - lo))
         ci = jnp.clip(c, 0, domain - 1).astype(jnp.int32)
         midx = jnp.take(lut_, ci)
         matched = jnp.asarray(pm) & in_dom & (midx >= 0)
-        return jnp.clip(midx, 0, nb - 1), matched
+        return jnp.clip(midx, 0, nb - 1), matched, \
+            jnp.sum(alive_f & jnp.asarray(pm) & ~matched, dtype=jnp.int32)
 
     _probe = named_jit(_probe, program_name("gather_probe", sig))
-    for pv, pm in zip(fact_codes_slabs, fact_valid_slabs):
-        midx, matched = _probe(lut, pv, pm)
+    codes, valids = fact_slabs()
+    dangling = jnp.int32(0)
+    for s, (pv, pm) in enumerate(zip(codes, valids)):
+        # (a chained hop's fact rows are another structure's: all count)
+        alive_f = fact[0].slab_mask(s) if fact is not None else \
+            jnp.ones(int(pv.shape[-1]), dtype=bool)
+        midx, matched, n_dang = _probe(lut, pv, pm, alive_f)
         ent.midx.append(midx)
         ent.matched.append(matched)
+        dangling = dangling + n_dang
+    ent.dangling = dangling
     with _LOCK:
-        cur = _ALIGNED.get(key)
-        if cur is not None and _fresh(ctx, cur.tds) \
+        cur = tbl.get(key)
+        if cur is not None and _fresh(ctx, cur.tds) and cur.space == space \
                 and cur.slab_cap == slab_cap and cur.n_slabs == n_slabs:
             # lost a concurrent build race: adopt the installed entry
             # (byte-identical build), ours frees via refcount
             return cur if cur.unique else None
-        _ALIGNED[key] = ent
+        tbl[key] = ent
     return ent
+
+
+def _gather_program(col: int, bv, cap: int):
+    from tidb_tpu.executor import device_emit
+    from tidb_tpu.ops.jax_env import jnp
+
+    def _gather(bv_, bm_, midx, matched):
+        v = jnp.take(jnp.asarray(bv_), midx, axis=-1)
+        m = jnp.take(jnp.asarray(bm_), midx) & matched
+        return v, m
+
+    # the build column is an ARGUMENT: no table data in the program, so
+    # every data set of one shape shares one executable
+    return device_emit._delta_program(
+        "gather", (col, bv.shape, str(bv.dtype), cap), lambda: _gather)
 
 
 def aligned_col(ent: AlignedJoin, build_ent: CachedTable, col: int):
     """Ensure build column `col` is materialized in the fact row space;
     → per-fact-slab [(v, m)] (wide decimals keep their limb-plane axis)."""
-    from tidb_tpu.ops.jax_env import jnp, named_jit, program_name
     cached = ent.cols.get(col)
     if cached is not None:
         return cached
     bv, bm = _build_cat(build_ent, col)
-
-    def _gather(midx, matched):
-        v = jnp.take(jnp.asarray(bv), midx, axis=-1)
-        m = jnp.take(jnp.asarray(bm), midx) & matched
-        return v, m
-
-    _gather = named_jit(_gather, program_name(
-        "gather", repr((col, bv.shape, str(bv.dtype), ent.slab_cap))))
-    slabs = [_gather(midx, matched)
-             for midx, matched in zip(ent.midx, ent.matched)]
+    slabs = [_gather_program(col, bv, int(midx.shape[0]))(
+        bv, bm, midx, matched)
+        for midx, matched in zip(ent.midx, ent.matched)]
     with _LOCK:
         # first-commit-wins against a concurrent identical gather
         return ent.cols.setdefault(col, slabs)
+
+
+def _steps_since(ent: CachedTable, td_from):
+    """The extension steps that led from `td_from` to this generation, or
+    None when the chain is not (or no longer) in its memory."""
+    if ent.td is td_from:
+        return ()
+    for k, st in enumerate(ent.steps):
+        if st["from"] is td_from:
+            chain = ent.steps[k:]
+            ok = all(a["to"] is b["from"] for a, b in zip(chain, chain[1:]))
+            return chain if ok and chain[-1]["to"] is ent.td else None
+    return None
+
+
+def _key_col(region, col: int, start: int, stop: int):
+    if col >= region.chunk.num_cols:
+        return None
+    c = region.chunk.columns[col]
+    return (np.asarray(c.values[start:stop]),
+            np.asarray(c.valid_mask()[start:stop]))
+
+
+def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
+                     fact_col: int, build_ent: CachedTable, bcol: int,
+                     bounds):
+    """`old`, brought to the generations `fact_ent` and `build_ent` are
+    at → a new AlignedJoin sharing what did not change, or, where only a
+    rebuild is right, the reason as a word: `lineage` (another base
+    build), `steps` (a chain of steps no longer remembered), `key-domain`
+    (a key outside the lookup table), `schema`, `key-taken` (a build row
+    arriving onto a key a live one holds), `dangling` (a build row
+    arriving while live fact rows match none: one of them may be its —
+    an UPDATE of a build row is a key that dies and arrives)."""
+    from tidb_tpu.executor import delta, device_emit
+    from tidb_tpu.ops.jax_env import jax, jnp
+    fact_tid = next((t for t, td in tds.items() if td is fact_ent.td), None)
+    build_tid = next((t for t, td in tds.items()
+                      if td is build_ent.td and t != fact_tid), None)
+    if old.space != (fact_ent.lineage, build_ent.lineage) \
+            or fact_tid is None or build_tid is None \
+            or set(old.tds) != {fact_tid, build_tid} \
+            or fact_ent.slab_cap != old.slab_cap:
+        return "lineage"
+    fsteps = _steps_since(fact_ent, old.tds[fact_tid])
+    bsteps = _steps_since(build_ent, old.tds[build_tid])
+    if fsteps is None or bsteps is None:
+        return "steps"
+    lo, domain = old.lo, old.domain
+    if bounds[0] < lo or bounds[1] > lo + domain - 1:
+        return "key-domain"     # it outgrew the lookup table
+    new = AlignedJoin(old.key, dict(tds), old.slab_cap, fact_ent.n_slabs,
+                      old.build_nb)
+    new.lo, new.domain, new.space = lo, domain, old.space
+    new.lut, new.dangling = old.lut, old.dangling
+    new.matched, new.midx = list(old.matched), list(old.midx)
+    new.cols = {c: list(sl) for c, sl in old.cols.items()}
+    new.bcat = dict(old.bcat)
+    base_n = build_ent.base_slabs * build_ent.slab_cap
+    new.build_nb = base_n + build_ent.delta_cap
+    pad = delta._pad_idx
+
+    # ---- the build side, step by step: dead keys leave the lookup table
+    # and unmatch the fact rows that carry them, new keys enter it
+    dead_all = []
+    arrived, taken_at = False, []
+    for st in bsteps:
+        dead_keys = []
+        for now, before in st["dead"]:
+            n = before.num_rows
+            got = _key_col(now, bcol, 0, n)
+            if got is None:
+                return "schema"
+            fresh = np.asarray(now.deleted[:n]) & ~before.deleted & got[1]
+            dead_keys.append(got[0][fresh].astype(np.int64))
+        keys, rows = [], []
+        for region, start, stop, off in st["appended"]:
+            got = _key_col(region, bcol, start, stop)
+            if got is None:
+                return "schema"
+            live = got[1] & ~np.asarray(region.deleted[start:stop])
+            keys.append(got[0][live].astype(np.int64))
+            rows.append((base_n + off
+                         + np.flatnonzero(live)).astype(np.int32))
+        dk = np.concatenate(dead_keys) if dead_keys else \
+            np.empty(0, np.int64)
+        nk = np.concatenate(keys) if keys else np.empty(0, np.int64)
+        if not dk.size and not nk.size:
+            continue
+        if nk.size and (nk.min() < lo or nk.max() > lo + domain - 1):
+            return "key-domain"
+        if np.unique(nk).size != nk.size:
+            return "key-taken"
+        arrived = arrived or bool(nk.size)
+        dead_all.append(dk)
+        clr = pad((dk - lo)[(dk >= lo) & (dk < lo + domain)], domain + 1)
+        put = pad(nk - lo, domain + 1)
+        val = np.full(put.shape, -1, dtype=np.int32)
+        val[:nk.size] = np.concatenate(rows) if rows else val[:0]
+
+        def _lut_step(lut, clr_, put_, val_):
+            with jax.named_scope("delta_merge"):
+                lut = lut.at[clr_].set(-1, mode="drop")
+                taken = jnp.any(jnp.take(lut, put_, mode="fill",
+                                         fill_value=-1) >= 0)
+                return lut.at[put_].set(val_, mode="drop"), taken
+
+        step = device_emit._delta_program(
+            "delta_merge", ("lut", domain, clr.shape, put.shape),
+            lambda: _lut_step)
+        new.lut, taken = step(new.lut, clr, put, val)
+        taken_at.append(taken)
+    dk = np.unique(np.concatenate(dead_all)) if dead_all else \
+        np.empty(0, np.int64)
+    if dk.size:
+        cut = np.flatnonzero(np.diff(dk) != 1)
+        starts = np.concatenate([dk[:1], dk[cut + 1]])
+        stops = np.concatenate([dk[cut], dk[-1:]])
+        # a few runs of keys (a purge by key range) are compared with the
+        # decoded key; scattered ones ask the lookup table whether the
+        # key still leads to the row that was gathered from
+        by_lut = starts.size > ALIGNED_MAX_RANGES
+        ranges = np.full((ALIGNED_MAX_RANGES, 2), [1, 0], dtype=np.int64)
+        if not by_lut:
+            ranges[:starts.size, 0], ranges[:starts.size, 1] = starts, stops
+        lay = fact_ent.layouts.get(fact_col)
+        for s in range(min(len(new.matched), fact_ent.n_slabs)):
+            cap, raw = fact_ent.slab_shape(s)
+            lay_s = None if raw else lay
+
+            def _unmatch(slab, matched, midx, alive, rg, lut,
+                         lay_s=lay_s, cap=cap, by_lut=by_lut):
+                with jax.named_scope("delta_merge"):
+                    v, m = device_emit.emit_decode(lay_s, slab, cap) \
+                        if lay_s is not None else slab[:2]
+                    k = jnp.asarray(v).astype(jnp.int64)
+                    if by_lut:
+                        at = jnp.clip(k - lo, 0, domain - 1)
+                        hit = jnp.take(lut, at.astype(jnp.int32)) != midx
+                    else:
+                        hit = jnp.zeros(cap, dtype=bool)
+                        for r in range(ALIGNED_MAX_RANGES):
+                            hit = hit | ((k >= rg[r, 0]) & (k <= rg[r, 1]))
+                    hit = hit & matched
+                    return matched & ~hit, \
+                        jnp.sum(hit & alive, dtype=jnp.int32)
+
+            prog = device_emit._delta_program(
+                "delta_merge", ("unmatch", cap, None if lay_s is None
+                                else lay_s.sig(),
+                                (lo, domain) if by_lut else None),
+                lambda _unmatch=_unmatch: _unmatch)
+            new.matched[s], n_dang = prog(
+                fact_ent.dev[fact_col][s], new.matched[s], new.midx[s],
+                fact_ent.slab_mask(s), ranges, new.lut)
+            new.dangling = new.dangling + n_dang
+    if arrived:
+        # a build row may only arrive onto a key no live build row holds,
+        # and when no live fact row waits for a key (its own, if the
+        # key died in these very steps, dangles since the pass above)
+        n_dang, taken = jax.device_get((new.dangling, taken_at))
+        if any(taken):
+            return "key-taken"
+        if int(n_dang):
+            return "dangling"
+
+    # ---- the fact side: the rows that arrived, probed and gathered into
+    # the arrays of the fact's delta slab
+    appended = [a for st in fsteps for a in st["appended"]]
+    if fact_ent.delta_cap and len(new.matched) == fact_ent.base_slabs:
+        dcap = fact_ent.delta_cap
+        new.matched.append(jnp.zeros(dcap, dtype=bool))
+        new.midx.append(jnp.zeros(dcap, dtype=jnp.int32))
+        for c, sl in new.cols.items():
+            v0 = sl[0][0]
+            sl.append((jnp.zeros(v0.shape[:-1] + (dcap,), dtype=v0.dtype),
+                       jnp.zeros(dcap, dtype=bool)))
+    if appended:
+        dcap, d = fact_ent.delta_cap, fact_ent.base_slabs
+        parts = [_key_col(r, fact_col, a, b) for r, a, b, _o in appended]
+        if any(p is None for p in parts):
+            return "schema"
+        kv = np.concatenate([p[0] for p in parts]).astype(np.int64)
+        km = np.concatenate([p[1] for p in parts])
+        off, n = appended[0][3], int(kv.shape[0])
+        bucket = _pow2(n, delta.MIN_BUCKET)
+        pk = np.zeros(bucket, dtype=np.int64)
+        pk[:n] = kv
+        pm = np.zeros(bucket, dtype=bool)
+        pm[:n] = km
+        cols = sorted(new.cols)
+        for c in cols:
+            if c not in new.bcat:
+                new.bcat[c] = _build_cat(build_ent, c, base_only=True)
+        bdelta = {c: build_ent.dev[c][build_ent.base_slabs][:2]
+                  for c in cols} if build_ent.delta_cap else {}
+        nb = new.build_nb
+
+        def _extend(lut, keys, kmask, off_, n_, matched, midx, acols,
+                    bcat, bdel):
+            with jax.named_scope("delta_merge"):
+                i = jnp.arange(bucket, dtype=jnp.int32)
+                c = keys - lo
+                ok = kmask & (c >= 0) & (c < domain) & (i < n_)
+                mi = jnp.take(lut, jnp.clip(c, 0, domain - 1)
+                              .astype(jnp.int32))
+                hit = ok & (mi >= 0)
+                mi = jnp.clip(mi, 0, nb - 1)
+                at = jnp.where(i < n_, off_ + i, jnp.int32(dcap))
+                out = {}
+                for col, (av, am) in acols.items():
+                    bv, bm = bcat[col]
+                    in_base = mi < base_n
+                    gv = jnp.take(bv, jnp.clip(mi, 0, base_n - 1), axis=-1)
+                    gm = jnp.take(bm, jnp.clip(mi, 0, base_n - 1))
+                    if col in bdel:
+                        dv, dm = bdel[col]
+                        di = jnp.clip(mi - base_n, 0, dv.shape[-1] - 1)
+                        gv = jnp.where(in_base, gv,
+                                       jnp.take(dv, di, axis=-1))
+                        gm = jnp.where(in_base, gm, jnp.take(dm, di))
+                    out[col] = (av.at[..., at].set(gv, mode="drop"),
+                                am.at[at].set(gm & hit, mode="drop"))
+                return (matched.at[at].set(hit, mode="drop"),
+                        midx.at[at].set(mi, mode="drop"), out,
+                        jnp.sum(kmask & (i < n_) & ~hit, dtype=jnp.int32))
+
+        prog = device_emit._delta_program("delta_merge", (
+            "aligned", lo, domain, bucket, dcap, base_n, nb, tuple(cols),
+            bool(bdelta)), lambda: _extend)
+        with timeline.span("delta.upload", "delta",
+                           bytes=pk.nbytes + pm.nbytes):
+            new.matched[d], new.midx[d], out, n_dang = prog(
+                new.lut, pk, pm, jnp.int32(off), jnp.int32(n),
+                new.matched[d], new.midx[d],
+                {c: new.cols[c][d] for c in cols}, new.bcat, bdelta)
+        for c in cols:
+            new.cols[c][d] = out[c]
+        new.dangling = new.dangling + n_dang
+    return new
 
 

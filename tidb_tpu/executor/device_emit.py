@@ -24,7 +24,7 @@ operation. Trace-time only: nothing runs per call.
 from __future__ import annotations
 
 import functools
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Sequence
 
 from tidb_tpu.expression import EvalContext
 from tidb_tpu.expression.aggfuncs import AggFunc
@@ -785,81 +785,97 @@ def emit_batched(partial_fn, name: str):
 
 
 # ---------------------------------------------------------------------------
-# delta merge — tombstone compaction of one resident slab, in-trace
+# delta slab and row-liveness masks — what a write does on the device
 # ---------------------------------------------------------------------------
+#
+# A delta generation (executor/delta.py) never moves a resident row. The
+# appended rows of a table live RAW (no compressed layout, so no range,
+# order or dictionary-width invariant of the base can be broken) in one
+# delta slab of its own capacity, which a write extends by a scatter of
+# just the new rows; a deleted row clears one bit of its slab's liveness
+# mask. Both are functional updates (the old generation's arrays stay
+# valid for the readers that hold them), both are programs of their own,
+# keyed by shapes alone — no table data and no generation in a trace —
+# and traced under a `jax.named_scope` (`delta_merge`, `tombstone`).
 
-_DELTA_MERGE_CACHE: dict = {}
-
-
-def _emit_pack_codes(codes, width: int, cap: int):
-    """Traced inverse of compress._pack_codes: uint32 codes (< 2^width)
-    → packed uint32 words, byte-identical to the host encoder. Codes
-    occupy disjoint bit ranges of their word, so the reduction is a
-    plain sum — no carries can occur."""
-    from tidb_tpu.ops.jax_env import jnp
-    per = 32 // width
-    n_words = -(-cap // per)
-    c = jnp.pad(codes.astype(jnp.uint32), (0, n_words * per - cap))
-    shifts = (jnp.arange(per) * width).astype(jnp.uint32)
-    # planar order (plane j = codes [j*n_words, (j+1)*n_words))
-    return jnp.sum(c.reshape(per, n_words) << shifts[:, None], axis=0,
-                   dtype=jnp.uint32)
+DELTA_SCOPES = ("delta_merge", "tombstone")
+_DELTA_PROGRAMS: dict = {}
 
 
-def emit_delta_merge(layout, slab, keep, n_new: int, cap: int):
-    """Apply a tombstone set to ONE device-resident slab as a single XLA
-    program: stable-permute the surviving rows to the front (base row
-    order is preserved, so decoded values stay positionally aligned with
-    every other column of the slab) and re-establish the prefix-liveness
-    invariant (`rows < n_new` are live, the tail is padding).
-
-    Composes with the compressed layouts the same way emit_decode does —
-    packed columns unpack, permute and REPACK entirely in-trace, so raw
-    bytes never materialize in HBM and the rewritten slab is
-    byte-compatible with the host encoder (zeroed codes and a zeroed
-    validity tail beyond n_new, exactly like compress.pack_slab pads).
-
-    layout: the column's ColLayout or None (raw). slab: the resident
-    device tuple. keep: bool (cap,) — True for rows that survive
-    (already False at and beyond n_cur). Delta-kind layouts are the
-    caller's responsibility to reject: their codes are successive
-    diffs, which a permutation invalidates."""
-    from tidb_tpu.chunk import compress
-    from tidb_tpu.ops.jax_env import jnp, named_jit, program_name
-    kind = "raw" if layout is None else layout.kind
-    width = 0 if layout is None else layout.width
-    wide = layout is None and getattr(slab[0], "ndim", 1) == 2
-    ckey = (kind, width, cap, wide)
-
-    fn = _DELTA_MERGE_CACHE.get(ckey)
+def _delta_program(kind: str, key: tuple, build):
+    """The jitted program `<kind>_<sig8 of key>`, built once a process:
+    `key` holds every constant `build()`'s function closes over."""
+    from tidb_tpu.ops.jax_env import named_jit, program_name
+    fn = _DELTA_PROGRAMS.get((kind, key))
     if fn is None:
-        def _rewrite(vals_or_words, mask_or_words, keep_dev, n_new_dev):
-            iota = jnp.arange(cap, dtype=jnp.int32)
-            perm = jnp.argsort(~keep_dev, stable=True)
-            live_new = iota < n_new_dev
-            if kind == "raw":
-                v = jnp.take(jnp.asarray(vals_or_words), perm, axis=-1)
-                m = jnp.take(jnp.asarray(mask_or_words), perm) & live_new
-                return v, m
-            mb = compress._unpack_codes(mask_or_words, 1, cap, jnp) != 0
-            mb = jnp.take(mb, perm) & live_new
-            mwords = _emit_pack_codes(mb.astype(jnp.uint32), 1, cap)
-            if width == 0:
-                # nothing stored but the stub — only the mask rewrites
-                return jnp.asarray(vals_or_words), mwords
-            codes = compress._unpack_codes(vals_or_words, width, cap, jnp)
-            codes = jnp.where(live_new, jnp.take(codes, perm),
-                              jnp.uint32(0))
-            return _emit_pack_codes(codes, width, cap), mwords
+        fn = _DELTA_PROGRAMS[(kind, key)] = named_jit(
+            build(), program_name(kind, repr(key)))
+    return fn
 
-        fn = _DELTA_MERGE_CACHE[ckey] = named_jit(
-            _rewrite, program_name("delta_merge", repr(ckey)))
 
-    out_v, out_m = fn(slab[0], slab[1], jnp.asarray(keep),
-                      jnp.int32(n_new))
-    if layout is not None and kind == "dict":
-        return (out_v, out_m, slab[2])     # shared dictvals ride along
-    if layout is not None and kind == "delta":
-        raise AssertionError("delta-kind layouts cannot be rewritten "
-                             "in place (diff codes)")
-    return (out_v, out_m)
+def emit_delta_alloc(specs, cap: int):
+    """Empty raw delta slabs, made on the device (nothing crosses PCIe):
+    `specs` = [(leading shape, dtype name)] per column → [(vals, mask)]."""
+    from tidb_tpu.ops.jax_env import jax, jnp
+    specs = tuple((tuple(lead), str(dt)) for lead, dt in specs)
+
+    def build():
+        def _alloc():
+            with jax.named_scope("delta_merge"):
+                return [(jnp.zeros(lead + (cap,), dtype=dt),
+                         jnp.zeros(cap, dtype=bool)) for lead, dt in specs]
+        return _alloc
+    return _delta_program("delta_merge", ("alloc", specs, cap), build)()
+
+
+def emit_delta_append(slab, chunk_vals, chunk_mask, offset: int, n: int,
+                      cap: int):
+    """Write `n` appended rows (host arrays padded to a power-of-two
+    bucket) into a raw delta slab at row `offset` → the new (vals, mask).
+    A scatter with the padding's indices out of range, so the rows beyond
+    `n` are dropped and a write near the capacity cannot shift."""
+    from tidb_tpu.ops.jax_env import jax, jnp
+    bucket = int(chunk_mask.shape[0])
+    lead = tuple(chunk_vals.shape[:-1])
+    key = ("append", lead, str(chunk_vals.dtype), bucket, cap)
+
+    def build():
+        def _append(v, m, cv, cm, off, n_new):
+            with jax.named_scope("delta_merge"):
+                i = jnp.arange(bucket, dtype=jnp.int32)
+                idx = jnp.where(i < n_new, off + i, jnp.int32(cap))
+                return (v.at[..., idx].set(cv, mode="drop"),
+                        m.at[idx].set(cm, mode="drop"))
+        return _append
+    return _delta_program("delta_merge", key, build)(
+        slab[0], slab[1], chunk_vals, chunk_mask, jnp.int32(offset),
+        jnp.int32(n))
+
+
+def emit_alive_init(n_live: int, cap: int):
+    """The liveness mask of a slab that has only a live prefix."""
+    from tidb_tpu.ops.jax_env import jax, jnp
+
+    def build():
+        def _init(n):
+            with jax.named_scope("tombstone"):
+                return jnp.arange(cap, dtype=jnp.int32) < n
+        return _init
+    return _delta_program("tombstone", ("init", cap), build)(
+        jnp.int32(n_live))
+
+
+def emit_alive_update(alive, born, dead, cap: int):
+    """A slab's liveness mask with rows `born` set and rows `dead`
+    cleared (int32 host arrays padded with `cap`, which a scatter
+    drops) → the new mask; the old one stays as it was."""
+    from tidb_tpu.ops.jax_env import jax
+    key = ("update", int(born.shape[0]), int(dead.shape[0]), cap)
+
+    def build():
+        def _update(a, b, d):
+            with jax.named_scope("tombstone"):
+                return a.at[b].set(True, mode="drop") \
+                        .at[d].set(False, mode="drop")
+        return _update
+    return _delta_program("tombstone", key, build)(alive, born, dead)

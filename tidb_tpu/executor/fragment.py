@@ -758,7 +758,11 @@ class _FragmentProgram:
         from tidb_tpu.ops.jax_env import jnp
         prepared = {id(node): v for node, v in zip(self.prep_nodes, prep_vals)
                     if v is not None}
-        live = jnp.arange(self.slab_cap, dtype=jnp.int32) < n_rows
+        # a delta generation hands its slab's liveness MASK where a plain
+        # one hands the length of its live prefix (executor/delta.py)
+        n_rows = jnp.asarray(n_rows)
+        live = n_rows if n_rows.dtype == jnp.bool_ else \
+            jnp.arange(self.slab_cap, dtype=jnp.int32) < n_rows
         if self.layouts:
             from tidb_tpu.executor import device_emit
             cols = {i: (device_emit.emit_decode(self.layouts[i], t,
@@ -1414,12 +1418,17 @@ def _plan_aligned_joins(ctx, root, scans, ents):
         slabs = device_cache.aligned_col(entry, a_ent, idx)
         if any(v.ndim != 1 for v, _ in slabs):
             return None
-        return ([v for v, _ in slabs], [m for _, m in slabs],
-                ("al", entry.key, idx), dict(entry.tds))
+        return (lambda: ([v for v, _ in slabs], [m for _, m in slabs]),
+                (int(slabs[0][0].shape[-1]), len(slabs)),
+                ("al", entry.key, idx), dict(entry.tds), None,
+                entry.space)
 
     def resolve(nodeP, idx):
-        """Probe key column → (codes_slabs, valid_slabs, sig, tds) in the
-        fact scan's row space, or None."""
+        """Probe key column → (() → (codes_slabs, valid_slabs), (slab
+        capacity, slabs), sig, tds, (fact entry, column) or None, the
+        lineages its rows are positioned in) in the fact scan's row space,
+        or None. The slabs are decoded only when a
+        structure has to be built: a cache hit asks for none."""
         while True:
             if isinstance(nodeP, PhysTableScan):
                 sub = anchor_subs.get(id(nodeP))
@@ -1430,13 +1439,17 @@ def _plan_aligned_joins(ctx, root, scans, ents):
                     return None
                 if ent.dicts.get(idx) is not None:
                     return None        # string probe key: KeyRemap path
-                slabs = device_cache._decoded_slabs(ent, idx)
-                if any(v.ndim != 1 for v, _ in slabs):
+                if nodeP.schema.field_types[idx].is_wide_decimal:
                     return None        # wide-decimal planes can't be keys
-                return ([v for v, _ in slabs], [m for _, m in slabs],
+
+                def decoded(ent=ent, idx=idx):
+                    slabs = device_cache._decoded_slabs(ent, idx)
+                    return [v for v, _ in slabs], [m for _, m in slabs]
+                return (decoded, (ent.slab_cap, ent.n_slabs),
                         ("col", nodeP.table.id, idx),
                         {nodeP.table.id:
-                         ctx.snapshot.table_data(nodeP.table.id)})
+                         ctx.snapshot.table_data(nodeP.table.id)},
+                        (ent, idx), (ent.lineage,))
             if isinstance(nodeP, PhysSelection):
                 nodeP = nodeP.children[0]
                 continue
@@ -1542,13 +1555,17 @@ def _plan_aligned_joins(ctx, root, scans, ents):
         src = resolve(probe, pk.index)
         if src is None:
             return False
-        codes, valids, sig, tds = src
-        slab_cap, n_slabs = int(codes[0].shape[-1]), len(codes)
+        fact_slabs, (slab_cap, n_slabs), sig, tds, fact, space = src
         key = (id(store), sig, anchor.table.id, bcol)
         tds[anchor.table.id] = ctx.snapshot.table_data(anchor.table.id)
+        if fact is None and (build_ent.is_delta or any(
+                e.is_delta for e in ents_by_scan.values())):
+            # a chained hop's probe key lives in another structure's
+            # row space: only a direct one follows delta generations
+            return False
         entry = device_cache.get_aligned(
-            ctx, key, tds, codes, valids, build_ent, bcol, bounds,
-            slab_cap, n_slabs)
+            ctx, key, tds, fact_slabs, build_ent, bcol, bounds,
+            slab_cap, n_slabs, space, fact=fact)
         if entry is None:
             return False
         used = anchor.used_columns or list(range(len(anchor.schema)))
@@ -1569,20 +1586,28 @@ def _plan_aligned_joins(ctx, root, scans, ents):
     # outer join only becomes resolvable after its inner join aligns in a
     # previous pass
     changed = True
-    while changed:
-        changed = False
-        for node in reversed(TF._walk_joins(root)):
-            if id(node) in info_by_join:
-                continue
-            saved_info = dict(info_by_join)
-            saved_subs = dict(anchor_subs)
-            if try_align(node):
-                changed = True
-            else:
-                info_by_join.clear()
-                info_by_join.update(saved_info)
-                anchor_subs.clear()
-                anchor_subs.update(saved_subs)
+    try:
+        while changed:
+            changed = False
+            for node in reversed(TF._walk_joins(root)):
+                if id(node) in info_by_join:
+                    continue
+                saved_info = dict(info_by_join)
+                saved_subs = dict(anchor_subs)
+                if try_align(node):
+                    changed = True
+                else:
+                    info_by_join.clear()
+                    info_by_join.update(saved_info)
+                    anchor_subs.clear()
+                    anchor_subs.update(saved_subs)
+    finally:
+        # try_align calls itself: the function and its own cell are a
+        # reference cycle that holds every cell of this call — the scans'
+        # CachedTables and aligned structures among them — until the
+        # collector happens to run. A generation a write superseded must
+        # free its device arrays by reference count, so the cycle ends here
+        try_align = None
     # unconditional: failed attempts may have left freshly built entries
     # resident; never evict what THIS query executes with (aligned entries
     # in use + every scan's CachedTable)
@@ -1590,6 +1615,15 @@ def _plan_aligned_joins(ctx, root, scans, ents):
         ctx, {i["entry"].key for i in info_by_join.values()},
         keep_tables={(id(store), s.table.id) for s in scans})
     return info_by_join
+
+
+def _ent_geometry(ent) -> tuple:
+    """What of a cached table the specialization cache keys: the base
+    build it descends from and its shapes. Not the data's identity and no
+    generation number — the programs hold neither, so a write costs the
+    next statement no specialization."""
+    return (ent.lineage, ent.slab_cap, ent.base_slabs, ent.delta_cap,
+            ent.alive is not None)
 
 
 class _SlabSource:
@@ -1647,8 +1681,11 @@ class _ChainSlabs(_SlabSource):
         self.lay_sig = ",".join(f"{i}:{l.sig()}"
                                 for i, l in sorted(layouts.items())) \
             if layouts else "-"
-        self.geometry = (id(ent.td), getattr(ent, "delta_version", 0),
-                         ent.slab_cap, ent.n_slabs)
+        self.geometry = _ent_geometry(ent)
+        # the raw delta slab of a delta generation runs the SAME chain as
+        # a program of its own shape (its capacity, no layouts)
+        self.delta_id = ent.base_slabs if ent.delta_cap else -1
+        self.dprog = None
         # pod-partitioned entry: each slab's partial computes on its
         # owner device; re-pin every partial to the STATEMENT's device
         # right after dispatch so the merge/finalize graph downstream
@@ -1665,6 +1702,10 @@ class _ChainSlabs(_SlabSource):
         prog = get_program(self.chain, self.used, self.in_types,
                            self.slab_cap, gcap, self.key_bounds, want_pairs,
                            self.layouts, pair_cap, sig=sig)
+        if self.delta_id in self.run_ids:
+            self.dprog = get_program(
+                self.chain, self.used, self.in_types, self.ent.delta_cap,
+                gcap, self.key_bounds, want_pairs, None, pair_cap)
         return prog, prog.sig, prog.collect_preps(self.dicts)
 
     def merge_program(self, prog, gcap: int, sig: str):
@@ -1684,12 +1725,15 @@ class _ChainSlabs(_SlabSource):
         # (slabs first: zip must run the stream past its last slab, where
         # it commits the upload)
         for (cols, n), pos in zip(slabs, to_run):
+            p = self.dprog if self.run_ids[pos] == self.delta_id else prog
             # slot per slab DISPATCH: the streamed encode of the next slab
             # (inside _slab_iter) runs slot-free, so a sibling's dispatch
             # interleaves with our host work
             with self.ctx.device_slot():
-                with self.ctx.phases.launch(prog.partial_name, slab=pos):
-                    part = prog.partial(cols, jnp.int32(n), prep_vals)
+                with self.ctx.phases.launch(p.partial_name, slab=pos):
+                    part = p.partial(
+                        cols, jnp.int32(n) if isinstance(n, int) else n,
+                        prep_vals)
                     if self.pod_pin is not None:
                         part = jax.device_put(part, self.pod_pin)
             yield pos, part
@@ -1715,7 +1759,7 @@ class _TreeSlabs(_SlabSource):
     def __init__(self, ctx, root, caps, scans, ents, scan_inputs, scan_rows,
                  flow_list, flows, aligned_inputs, join_cfgs, walk_joins,
                  akb, max_cap, out_cap_max, anchor_i, scan_layouts,
-                 nested_rows):
+                 nested_rows, scan_counts):
         self.ctx, self.root, self.key_bounds = ctx, root, akb
         self.scan_inputs, self.scan_rows = scan_inputs, scan_rows
         self.flow_list, self.aligned_inputs = flow_list, aligned_inputs
@@ -1729,17 +1773,29 @@ class _TreeSlabs(_SlabSource):
         self.caps = dict(caps)
         self.caps[id(scans[anchor_i])] = (a_ent.slab_cap, 1)
         # a zero row count IS zone maps' skip signal (_run_device_tree)
-        self.anchor_rows = scan_rows[anchor_i]
+        self.anchor_rows = scan_counts[anchor_i]
         self.run_ids = [s for s in range(a_ent.n_slabs)
                         if int(self.anchor_rows[s]) > 0]
         self.lay_sig = ",".join(
             f"{si}/{i}:{l.sig()}"
             for si, slot in enumerate(scan_layouts or ())
             for i, l in slot) if scan_layouts else "-"
-        self.geometry = (tuple((id(e.td), getattr(e, "delta_version", 0),
-                                e.slab_cap, e.n_slabs) for e, _ in ents),
-                         anchor_i)
+        self.geometry = (tuple(_ent_geometry(e) for e, _ in ents), anchor_i)
         self._launch_sig = ""
+        # the anchor's raw delta slab runs the same tree as a program of
+        # its own anchor shape (its capacity, no layouts)
+        self.a_ent = a_ent
+        self.delta_id = a_ent.base_slabs if a_ent.delta_cap else -1
+        self.dprog = None
+        if self.delta_id >= 0:
+            self.dcaps = dict(self.caps)
+            self.dcaps[id(scans[anchor_i])] = (a_ent.delta_cap, 1)
+            self.dlayouts = tuple(
+                () if si == anchor_i else slot
+                for si, slot in enumerate(scan_layouts)) \
+                if scan_layouts else None
+            if self.dlayouts is not None and not any(self.dlayouts):
+                self.dlayouts = None
 
     def rows(self, pos: int) -> int:
         return int(self.anchor_rows[self.run_ids[pos]])
@@ -1755,6 +1811,10 @@ class _TreeSlabs(_SlabSource):
             self.root, self.caps, gcap, self.join_cfgs, self.key_bounds,
             self.scan_layouts, want_pairs, pair_cap, sig=sig)
         self._launch_sig = _sig_tag("fused", sig)
+        if self.delta_id in self.run_ids:
+            self.dprog, _dsig = get_pipeline_program(
+                self.root, self.dcaps, gcap, self.join_cfgs,
+                self.key_bounds, self.dlayouts, want_pairs, pair_cap)
         return prog, sig, prog.collect_preps(self.flow_list)
 
     def merge_program(self, prog, gcap: int, sig: str):
@@ -1792,7 +1852,10 @@ class _TreeSlabs(_SlabSource):
         si = list(self.scan_inputs)
         si[a] = {i: [slabs[s]] for i, slabs in self.scan_inputs[a].items()}
         sr = list(self.scan_rows)
-        sr[a] = np.array([self.anchor_rows[s]], dtype=np.int32)
+        sr[a] = (self.a_ent.alive[s],) if self.a_ent.alive is not None \
+            else np.array([self.anchor_rows[s]], dtype=np.int32)
+        if s == self.delta_id:
+            prog = self.dprog
         ai = []
         for jn, (matched, jcols) in zip(self.walk_joins,
                                         self.aligned_inputs):
@@ -1938,6 +2001,7 @@ class TpuFragmentExec:
                         # mid-compute
                         with self._protect_tables():
                             self._result = self._run_device()
+                        self._note_reader()
                     global LAST_PHASES
                     exec_s = _time.perf_counter() - _t0
                     self.used_device = True
@@ -2056,6 +2120,22 @@ class TpuFragmentExec:
         return device_cache.protect_tables(
             (id(store), s.table.id) for s in _scans(self.plan.root))
 
+    def _note_reader(self) -> None:
+        """This fragment read its tables on the device: a compaction of one
+        of them runs it once over the rebuilt generation before the swap
+        (delta._warm), so what a re-chosen layout compiles, it compiles
+        there."""
+        from tidb_tpu.executor import device_cache
+        from tidb_tpu.executor.tree_fragment import _scans
+        store = getattr(self.ctx.snapshot, "store", None)
+        if store is not None and getattr(self.ctx, "txn", None) is None:
+            tables = tuple(sorted({s.table.id
+                                   for s in _scans(self.plan.root)}))
+            sql = getattr(getattr(self.ctx, "guard", None), "sql", None)
+            device_cache.note_reader(
+                id(store), tables, self.plan, self.ctx.vars,
+                (sql or id(self.plan), self.plan.root.name, tables))
+
     # ---- device pipeline ---------------------------------------------------
     def _run_device(self) -> Chunk:
         from tidb_tpu.executor import device_cache, scheduler
@@ -2114,11 +2194,20 @@ class TpuFragmentExec:
         # version, reused across queries. First touch STREAMS: open_table
         # returns a per-slab generator the executors drive, so encode of
         # slab k+1 pipelines behind the (async) upload/compute of slab k.
-        with timeline.span("frag.open", "frag"):
-            ent, stream = device_cache.open_table(self.ctx, scan, used,
-                                                  max_slab,
-                                                  phases=self.ctx.phases,
-                                                  prune=True)
+        # the per-slab aggregate driver takes a delta generation as it is
+        # (grouping by sorted runs apart: it stacks slabs at one shape);
+        # order and filter roots assume live prefixes and uniform slabs
+        delta_ok = isinstance(chain[0], PhysHashAgg)
+        while True:
+            with timeline.span("frag.open", "frag"):
+                ent, stream = device_cache.open_table(
+                    self.ctx, scan, used, max_slab, phases=self.ctx.phases,
+                    prune=True, delta_ok=delta_ok)
+            if delta_ok and ent.is_delta and grouping_mode(
+                    _agg_key_bounds(chain, ent)) == RUNS:
+                delta_ok = False
+                continue
+            break
         if ent.total == 0:
             raise FragmentFallback("empty input", reason="empty-input")
         dicts = {i: ent.dicts.get(i) for i in used}
@@ -2280,6 +2369,16 @@ class TpuFragmentExec:
                                    reason="shape")
         return rows
 
+    def _run_tree_plain(self) -> Chunk:
+        """The tree again over plain tables: what only the mega-slab loop
+        or the sorted-runs grouping can run gets rebuilds of the delta
+        generations it was given (declines of gate `consumer`)."""
+        self._plain_tables = True
+        try:
+            return self._run_device_tree()
+        finally:
+            self._plain_tables = False
+
     # ---- join-tree / mega-slab device pipeline -----------------------------
     def _run_device_tree(self) -> Chunk:
         """Q3/Q5-shaped join trees (and multi-slab chains the per-slab
@@ -2288,7 +2387,7 @@ class TpuFragmentExec:
         inside the program; join modes adapt at runtime (a lost uniqueness
         bet or an expansion-capacity overflow re-traces exactly once, never
         falls back to CPU)."""
-        from tidb_tpu.executor import device_cache
+        from tidb_tpu.executor import device_cache, device_emit
         from tidb_tpu.executor import tree_fragment as TF
         from tidb_tpu.executor.device_cache import _pow2
         from tidb_tpu.ops.jax_env import jax
@@ -2304,6 +2403,16 @@ class TpuFragmentExec:
         group_cap = int(vars_.get("tidb_tpu_group_cap", DEFAULT_GROUP_CAP))
 
         scans = TF._scans(root)
+        # the fused per-slab pipeline (an aggregate over a join tree whose
+        # probe chain ends in a scan) takes delta generations as they are;
+        # the mega-slab loop below assumes live prefixes and uniform slabs
+        is_agg = isinstance(root, PhysHashAgg)
+        anchor = TF.aligned_chain(root.children[0])[0] if is_agg else None
+        anchor_i = next((i for i, s in enumerate(scans) if s is anchor),
+                        None)
+        delta_ok = is_agg and anchor_i is not None and _var_bool(
+            vars_.get("tidb_tpu_fused_pipeline", "on")) and \
+            not getattr(self, "_plain_tables", False)
         ents = []
         # every scan of THIS statement is already protected from sibling
         # evictions for the whole device run: next() wrapped _run_device
@@ -2316,11 +2425,13 @@ class TpuFragmentExec:
             with timeline.span("frag.open", "frag"):
                 ent = device_cache.get_table(self.ctx, scan, used,
                                              max_slab,
-                                             phases=self.ctx.phases)
+                                             phases=self.ctx.phases,
+                                             delta_ok=delta_ok)
             if ent.total == 0:
                 raise FragmentFallback("empty input", reason="empty-input")
             ents.append((ent, used))
-        caps = {id(s): (e.slab_cap, e.n_slabs)
+        caps = {id(s): ((e.slab_cap, e.base_slabs, e.delta_cap)
+                        if e.delta_cap else (e.slab_cap, e.n_slabs))
                 for s, (e, _) in zip(scans, ents)}
         # nested device-rows fragments (aggregates that are a join's build
         # side) run FIRST, as fragments of their own whose merged groups
@@ -2350,7 +2461,7 @@ class TpuFragmentExec:
         flows, root_dicts = TF.dictionary_flows(root, scan_dicts)
         scan_inputs = tuple({i: list(e.dev[i]) for i in u}
                             for e, u in ents)
-        scan_rows = tuple(
+        scan_counts = tuple(
             np.array([e.slab_rows(s) for s in range(e.n_slabs)],
                      dtype=np.int32) for e, _ in ents)
         # zone-map slab pruning, tree flavor: scan_rows is a RUNTIME
@@ -2362,16 +2473,24 @@ class TpuFragmentExec:
         # entirely.
         from tidb_tpu.executor import zonemap
         n_zeroed = 0
-        for sc, (e, _u), rows in zip(scans, ents, scan_rows):
-            for s in zonemap.prune_slabs(e, sc):
+        pruned = []
+        for sc, (e, _u), rows in zip(scans, ents, scan_counts):
+            pruned.append(zonemap.prune_slabs(e, sc))
+            for s in pruned[-1]:
                 rows[s] = 0
                 n_zeroed += 1
         if n_zeroed:
             zonemap.note_skipped(self.ctx.phases, n_zeroed)
+        # a delta generation's liveness is a mask a slab (a pruned slab's
+        # an empty one); a plain table's the counts themselves
+        scan_rows = tuple(
+            counts if e.alive is None else tuple(
+                device_emit.emit_alive_init(0, e.slab_shape(s)[0])
+                if s in skip else e.alive[s] for s in range(e.n_slabs))
+            for (e, _u), counts, skip in zip(ents, scan_counts, pruned))
         max_cap = max(e.slab_cap * e.n_slabs for e, _ in ents)
 
         flow_list = [flows.get(id(n), []) for n in TF._walk_nodes(root)]
-        is_agg = isinstance(root, PhysHashAgg)
         join_cfgs = TF.plan_join_configs(root, scan_bounds)
         # FK-aligned joins: verified-unique PK-FK joins run as pure streams
         # over cached fact-rowspace build columns (no per-query gathers)
@@ -2391,6 +2510,10 @@ class TpuFragmentExec:
         aligned_inputs = tuple(aligned_inputs)
         akb = TF.tree_agg_key_bounds(root, scan_bounds, DOMAIN_CAP) \
             if is_agg else None
+        if delta_ok and grouping_mode(akb) == RUNS and \
+                any(e.is_delta for e, _ in ents):
+            # sorted runs stack every slab's rows at one shape
+            return self._run_tree_plain()
         gcap = _initial_group_cap(root, group_cap, max_cap, akb) \
             if is_agg else 1
         from tidb_tpu.executor.tree_fragment import JOIN_OUT_CAP
@@ -2408,24 +2531,23 @@ class TpuFragmentExec:
         # slabs + 1. DISTINCT aggs fuse too; multi-arg DISTINCT
         # (COUNT-only) dedups on a combined dense code in-slab and ships
         # the raw argument columns in the pairs.
-        if is_agg and _var_bool(vars_.get("tidb_tpu_fused_pipeline", "on")):
-            anchor = TF.aligned_chain(root.children[0])[0]
-            anchor_i = next((i for i, s in enumerate(scans)
-                             if s is anchor), None)
-            if anchor_i is not None:
-                res = self._run_agg_slabs(
-                    _TreeSlabs(self.ctx, root, caps, scans, ents,
-                               scan_inputs, scan_rows, flow_list, flows,
-                               aligned_inputs, join_cfgs, walk_joins, akb,
-                               max_cap, out_cap_max, anchor_i, scan_layouts,
-                               nested_rows),
-                    gcap, order_root, ladder)
-                if res is not None:
-                    return res
-                # a join's fan-out exceeded out_cap_max inside the slab
-                # driver: fall through to the mega-slab loop, whose own
-                # over-max rung escalates to blocked multi-pass execution
-                # (learned flips/resizes persist in join_cfgs)
+        if is_agg and anchor_i is not None and _var_bool(
+                vars_.get("tidb_tpu_fused_pipeline", "on")):
+            res = self._run_agg_slabs(
+                _TreeSlabs(self.ctx, root, caps, scans, ents,
+                           scan_inputs, scan_rows, flow_list, flows,
+                           aligned_inputs, join_cfgs, walk_joins, akb,
+                           max_cap, out_cap_max, anchor_i, scan_layouts,
+                           nested_rows, scan_counts),
+                gcap, order_root, ladder)
+            if res is not None:
+                return res
+            # a join's fan-out exceeded out_cap_max inside the slab
+            # driver: fall through to the mega-slab loop, whose own
+            # over-max rung escalates to blocked multi-pass execution
+            # (learned flips/resizes persist in join_cfgs)
+            if any(e.is_delta for e, _ in ents):
+                return self._run_tree_plain()
         while True:
             prog = get_tree_program(root, caps, gcap, join_cfgs, akb,
                                     scan_layouts)
@@ -3265,7 +3387,7 @@ class TpuFragmentExec:
         # restrict to the program's used columns: a superset (uploaded by a
         # different query) would change the input pytree and force a retrace
         cols = {i: ent.dev[i][slab_idx] for i in used}
-        return cols, ent.slab_rows(slab_idx)
+        return cols, ent.slab_live(slab_idx)
 
     def _slab_iter(self, ent, stream, used: Sequence[int], slab_ids=None):
         """Per-slab (cols, n_rows) source: the open_table stream on a cold
@@ -3282,7 +3404,12 @@ class TpuFragmentExec:
                 yield self._slab(ent, s, used)
         else:
             for s, cols in stream:
-                yield {i: cols[i] for i in used}, ent.slab_rows(s)
+                yield {i: cols[i] for i in used}, ent.slab_live(s)
+            if ent.delta_cap and (slab_ids is None
+                                  or ent.base_slabs in slab_ids):
+                # the stream is the base's; the delta slab it committed
+                # behind its last slab follows
+                yield self._slab(ent, ent.base_slabs, used)
 
     # -- hash agg ------------------------------------------------------------
     def _run_agg_slabs(self, src: _SlabSource, gcap: int, order_root,

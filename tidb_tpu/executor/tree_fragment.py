@@ -694,8 +694,11 @@ def tree_signature(plan: PhysicalPlan, caps: Dict[int, Tuple[int, int]],
             # (and its input pytree), so they key the compile cache
             lays = scan_layouts[si] if scan_layouts else ()
             si += 1
+            # (a delta generation: base slabs + its raw delta slab)
+            shape = f"{cap[0]}x{cap[1]}" + (f"+{cap[2]}" if len(cap) > 2
+                                            else "")
             parts.append(
-                f"Scan(id={node.table.id}, cap={cap[0]}x{cap[1]}, "
+                f"Scan(id={node.table.id}, cap={shape}, "
                 f"types={[str(ft) for ft in node.schema.field_types]}, "
                 f"filters={node.filters!r}, "
                 f"parts={getattr(node, 'partitions', None)}, "
@@ -866,7 +869,10 @@ class TreeProgram:
             slot = next(i for i, s in enumerate(self.scan_order)
                         if s is node)
             in_cols = scan_inputs[slot]
-            slab_cap, n_slabs = self.caps[id(node)]
+            # (slab_cap, n_slabs), or for a delta generation (slab_cap,
+            # base slabs, capacity of the raw delta slab that follows)
+            slab_cap, n_slabs, *dcap = self.caps[id(node)]
+            dcap = dcap[0] if dcap else 0
             lays = dict(self.scan_layouts[slot]) \
                 if slot < len(self.scan_layouts) else {}
             col_list: List = []
@@ -880,7 +886,7 @@ class TreeProgram:
                     if isinstance(c, (list, tuple)) and c and \
                             isinstance(c[0], tuple):
                         c = [device_emit.emit_decode(lay, s, slab_cap)
-                             for s in c]
+                             for s in c[:n_slabs]] + list(c[n_slabs:])
                     else:
                         c = device_emit.emit_decode(lay, c,
                                                     slab_cap * n_slabs)
@@ -889,7 +895,7 @@ class TreeProgram:
                 elif isinstance(c, (list, tuple)) and c and \
                         isinstance(c[0], tuple):
                     if len(c) == 1:
-                        col_list.append(c[0])
+                        col_list.append(c[0][:2])
                     else:   # mega-slab: concatenate inside the program
                         # axis -1: rows are the LAST axis (wide-decimal
                         # limb columns are (n_limbs, cap) planes)
@@ -898,12 +904,17 @@ class TreeProgram:
                              jnp.concatenate([s[1] for s in c])))
                 else:
                     col_list.append(c)
-            rows = jnp.asarray(scan_rows[slot])
-            total_cap = slab_cap * n_slabs
+            rows = scan_rows[slot]
+            total_cap = slab_cap * n_slabs + dcap
             iota = jnp.arange(total_cap, dtype=jnp.int32)
-            if rows.ndim == 0:
-                live = iota < rows
+            if isinstance(rows, (tuple, list)):
+                # a delta generation: one liveness mask a slab
+                live = rows[0] if len(rows) == 1 else \
+                    jnp.concatenate(list(rows))
+            elif jnp.asarray(rows).ndim == 0:
+                live = iota < jnp.asarray(rows)
             else:
+                rows = jnp.asarray(rows)
                 live = (iota % slab_cap) < jnp.take(rows, iota // slab_cap)
             if id(node) in self.ranged_scans:
                 start, stop = self._ranges

@@ -918,14 +918,28 @@ class Parser:
         rows = []
         while True:
             self.expect_op("(")
-            row = [self.expr()]
+            row = [self._value()]
             while self.try_op(","):
-                row.append(self.expr())
+                row.append(self._value())
             self.expect_op(")")
             rows.append(row)
             if not self.try_op(","):
                 break
         return ast.Insert(table, columns, rows, replace=replace, ignore=ignore)
+
+    _PLAIN_LITERALS = ("int", "decimal", "float", "str")
+
+    def _value(self) -> ast.ExprNode:
+        """One value of a VALUES row: a bare literal (what a bulk INSERT
+        is made of) is taken as it stands, anything else is an
+        expression."""
+        t = self.cur
+        if t.kind in self._PLAIN_LITERALS:
+            nxt = self.toks[self.i + 1]
+            if nxt.kind == "op" and nxt.value in (",", ")"):
+                self.i += 1
+                return ast.Literal(t.value, t.kind)
+        return self.expr()
 
     def update(self) -> ast.Update:
         self.expect_kw("update")

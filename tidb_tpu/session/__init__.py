@@ -90,12 +90,10 @@ DEFAULT_VARS: Dict[str, object] = {
     "tidb_tpu_write_coalesce": "on",
     # async compaction of delta-extended cache entries (executor/
     # delta.py): rebuild base slabs with re-chosen layouts in idle
-    # batch-class slots; off = deltas accumulate until a test/bench
-    # drains them via delta.run_pending_compactions()
+    # batch-class slots, when the entry's own sizes say it is due
+    # (delta.compaction_due); off = deltas accumulate until a test or a
+    # tool drains them via delta.run_pending_compactions()
     "tidb_tpu_compaction": "on",
-    # delta rows (appends + tombstones) a cached table tolerates before
-    # a compaction job is scheduled
-    "tidb_tpu_delta_compact_rows": 1024,
 }
 
 
@@ -999,12 +997,18 @@ class Session:
             self.engine.store.truncate_table(info.id)
             self._reset_auto_ids(info.id)   # MySQL: TRUNCATE restarts at 1
             return ok()
-        if isinstance(stmt, ast.Insert):
-            return self._insert(stmt)
-        if isinstance(stmt, ast.Delete):
-            return self._delete(stmt)
-        if isinstance(stmt, ast.Update):
-            return self._update(stmt)
+        if isinstance(stmt, (ast.Insert, ast.Delete, ast.Update)):
+            # parse → staged rows or matched masks (and, autocommit, the
+            # commit: a `write.commit` span of its own inside this one)
+            write = {ast.Insert: self._insert, ast.Delete: self._delete,
+                     ast.Update: self._update}[type(stmt)]
+            table = stmt.table if isinstance(stmt.table, str) \
+                else getattr(stmt.table, "name", "")
+            with timeline.span("write.stage", "write", table=table,
+                               kind=type(stmt).__name__.lower()):
+                rs = write(stmt)
+                timeline.tag(rows=rs.affected_rows)
+                return rs
         if isinstance(stmt, ast.Explain):
             return self._explain(stmt)
         if isinstance(stmt, ast.SetStmt):
@@ -1632,16 +1636,29 @@ class Session:
             conflict_masks: Dict[int, np.ndarray] = {}
             staged_keep: List[np.ndarray] = []
             first_vals = np.array([k[0] for k in seen], dtype=object)
+            # an integer first key column is compared as integers, and
+            # against the new keys' range first: a region that holds
+            # none of that range costs two passes, no set membership
+            ints = None
+            if all(isinstance(k[0], (int, np.integer)) for k in seen):
+                ints = np.array([int(k[0]) for k in seen], dtype=np.int64)
             for region, ch, alive in txn.scan(info.id):
                 # vectorized prefilter on the first key column narrows the
                 # python tuple check to near-candidates (O(batch) not O(n))
                 c0 = ch.columns[idxs[0]]
-                c0_vals = c0.values.astype(object)
-                if c0.ftype.is_ci:
-                    from tidb_tpu.types import fold_ci_array
-                    c0_vals = fold_ci_array(c0_vals)  # seen keys are folded
-                cand = np.isin(c0_vals, first_vals) & \
-                    c0.valid_mask() & alive
+                if ints is not None and c0.values.dtype.kind in "iu":
+                    cand = (c0.values >= ints.min()) & \
+                        (c0.values <= ints.max())
+                    if cand.any():
+                        cand &= np.isin(c0.values, ints)
+                        cand &= c0.valid_mask() & alive
+                else:
+                    c0_vals = c0.values.astype(object)
+                    if c0.ftype.is_ci:
+                        from tidb_tpu.types import fold_ci_array
+                        c0_vals = fold_ci_array(c0_vals)  # seen: folded
+                    cand = np.isin(c0_vals, first_vals) & \
+                        c0.valid_mask() & alive
                 hit = np.zeros(ch.num_rows, dtype=bool)
                 if cand.any():
                     ex_keys = _key_tuples(ch.take(np.nonzero(cand)[0]),
@@ -1697,6 +1714,11 @@ class Session:
                 raise PlanError("Column count doesn't match value count")
             evaluated = []
             for v in vals:
+                if type(v) is ast.Literal:
+                    # a bare literal is its own constant (what a bulk
+                    # INSERT is made of): no rewrite, no fold
+                    evaluated.append(v.value)
+                    continue
                 folded = fold_expr(rw.rewrite(v))
                 if not isinstance(folded, Constant):
                     raise PlanError("INSERT values must be constants")
@@ -1811,10 +1833,12 @@ class Session:
             txn.snapshot = orig
 
     def _match_masks(self, info: TableInfo, where: Optional[ast.ExprNode],
-                     txn: Transaction):
+                     txn: Transaction, want_rows: bool = True):
         """Scan the table under `txn`, returning (region_masks, staged_keep,
         matched_chunks): committed-region delete masks keyed by region id,
-        keep-masks for staged inserts, and the matched rows themselves."""
+        keep-masks for staged inserts, and the matched rows themselves
+        (`want_rows`: a DELETE has no use for them, and gathering eight
+        columns of every region that holds a match is half its time)."""
         from tidb_tpu.executor.scan import align_chunk_to_schema
         schema = Schema.from_table(info)
         cond: Optional[Expression] = None
@@ -1824,18 +1848,39 @@ class Session:
         region_masks: Dict[int, np.ndarray] = {}
         staged_keep: List[np.ndarray] = []
         matched: List[Chunk] = []
-        for region, chunk, alive in txn.scan(info.id):
+
+        # `k >= a AND k < b` over an integer column — what a DELETE or an
+        # UPDATE by key range without an index is — compares the column
+        # itself: the expression evaluator's per-region cost is several
+        # times the comparisons' own on a table of hundreds of regions
+        bounds = _int_range_conjuncts(where, info) \
+            if where is not None else None
+
+        def match(item):
+            region, chunk, alive = item
             chunk = align_chunk_to_schema(chunk, info)
             hit = alive.copy()
-            if cond is not None:
+            if bounds is not None and all(
+                    _plain_int(chunk.columns[ci].ftype)
+                    for ci, _op, _v in bounds):
+                for ci, op, v in bounds:
+                    col = chunk.columns[ci]
+                    hit &= _COMPARE[op](col.values, v)
+                    if col.validity is not None:
+                        hit &= col.valid_mask()
+            elif cond is not None:
                 hit &= filter_mask(cond, chunk)
+            return region, chunk, hit
+
+        for region, chunk, hit in map(match, txn.scan(info.id)):
             if region is not None:
                 if hit.any():
                     region_masks[region.id] = hit
-                    matched.append(chunk.filter(hit))
+                    if want_rows:
+                        matched.append(chunk.filter(hit))
             else:
                 staged_keep.append(~hit)
-                if hit.any():
+                if want_rows and hit.any():
                     matched.append(chunk.filter(hit))
         return region_masks, staged_keep, matched
 
@@ -1850,8 +1895,9 @@ class Session:
             def _stage(txn):
                 self._note_touched(txn, info)
                 region_masks, staged_keep, _ = self._match_masks(
-                    info, stmt.where, txn)
-                n = sum(int(m.sum()) for m in region_masks.values())
+                    info, stmt.where, txn, want_rows=False)
+                n = sum(int(np.count_nonzero(m))
+                        for m in region_masks.values())
                 n += sum(int((~k).sum()) for k in staged_keep)
                 if region_masks:
                     txn.delete(info.id, region_masks)
@@ -1870,8 +1916,9 @@ class Session:
                     txn, info, stmt.where)
             else:
                 region_masks, staged_keep, _ = self._match_masks(
-                    info, stmt.where, txn)
-            n = sum(int(m.sum()) for m in region_masks.values())
+                    info, stmt.where, txn, want_rows=False)
+            n = sum(int(np.count_nonzero(m))
+                    for m in region_masks.values())
             n += sum(int((~k).sum()) for k in staged_keep)
             if region_masks:
                 txn.delete(info.id, region_masks)
@@ -2543,6 +2590,49 @@ def _references_table(node, name: str) -> bool:
         return False
 
     return walk(node)
+
+
+_COMPARE = {"eq": np.equal, "lt": np.less, "le": np.less_equal,
+            "gt": np.greater, "ge": np.greater_equal}
+_MIRROR = {"eq": "eq", "lt": "gt", "le": "ge", "gt": "lt", "ge": "le"}
+
+
+def _plain_int(ftype) -> bool:
+    """The stored integer IS the SQL value (a DECIMAL's is scaled, a DATE's
+    counts days, an ENUM's indexes its elements)."""
+    return ftype.kind.is_integer and not ftype.unsigned
+
+
+def _int_range_conjuncts(where, info: TableInfo):
+    """`where` as [(column index, comparison, integer)] when it is a
+    conjunction of comparisons between a SIGNED INTEGER column of the table
+    and an integer literal an int64 holds; else None (the expression
+    evaluator decides: a DECIMAL, a DATE or a time is stored as a scaled
+    integer too, and its literal has to be coerced)."""
+    col_of = {c.name.lower(): i for i, c in enumerate(info.columns)}
+    out, stack = [], [where]
+    while stack:
+        n = stack.pop()
+        if not isinstance(n, ast.BinaryOp):
+            return None
+        if n.op == "and":
+            stack += [n.left, n.right]
+            continue
+        if n.op not in _COMPARE:
+            return None
+        col, lit, op = n.left, n.right, n.op
+        if isinstance(col, ast.Literal):
+            col, lit, op = lit, col, _MIRROR[op]
+        if not (isinstance(col, ast.Name) and col.qualifier is None
+                and isinstance(lit, ast.Literal) and lit.kind == "int"
+                and col.column.lower() in col_of):
+            return None
+        ci, v = col_of[col.column.lower()], int(lit.value)
+        if not (_plain_int(info.columns[ci].ftype)
+                and -2 ** 63 <= v < 2 ** 63):
+            return None
+        out.append((ci, op, v))
+    return out
 
 
 def _key_tuples(chunk: Chunk, idxs: List[int]):

@@ -450,7 +450,14 @@ class Store:
             self._open_txns = max(self._open_txns - 1, 0)
 
     def commit(self, txn: "Transaction") -> None:
-        from tidb_tpu.util import failpoint
+        from tidb_tpu.util import timeline
+        with timeline.span(
+                "write.commit", "write",
+                tables=len(set(txn.staged_inserts) | set(txn.staged_deletes))):
+            self._commit(txn)
+
+    def _commit(self, txn: "Transaction") -> None:
+        from tidb_tpu.util import failpoint, timeline
         bo = None
         while True:
             try:
@@ -472,14 +479,17 @@ class Store:
                     for tid in txn.staged_inserts:
                         if tid not in self._tables:
                             raise TxnError("write conflict: table dropped")
+                    tombs = rows = 0
                     for tid, masks in txn.staged_deletes.items():
-                        self._delete_locked(tid, masks)
+                        tombs += self._delete_locked(tid, masks)
                     for tid, items in txn.staged_inserts.items():
                         for ch, part in items:
                             self._append_locked(tid, ch, part)
+                            rows += ch.num_rows
                     for tid in txn.staged_deletes:
                         self._maybe_compact_locked(tid, closing=1)
                     self._bump_locked()
+                timeline.tag(rows=rows, tombs=tombs)
                 return
             except TxnError as e:
                 # only errors marked retryable (transient region churn,

@@ -304,8 +304,7 @@ def _scenarios(mesh: Optional[int] = None) -> List[Scenario]:
                  dict(raise_=RuntimeError("chaos: compaction fault"),
                       times=1),
                  run="compact",
-                 vars={**device_on, "tidb_tpu_delta_compact_rows": "4",
-                       "tidb_tpu_compaction": "off"}),
+                 vars={**device_on, "tidb_tpu_compaction": "off"}),
         # -- DDL -----------------------------------------------------------
         Scenario("unique backfill dies mid-reorg", "index-backfill",
                  dict(raise_=ExecutionError("chaos: backfill"), times=1),
@@ -985,6 +984,11 @@ def run_sweep(verbose: bool = False, mesh: Optional[int] = None,
             elif sc.run == "compact":
                 from tidb_tpu.executor import delta as _delta
                 q = QUERIES[0]
+                # compaction due after four appended rows: the trigger is
+                # a share of the delta slab's capacity, squeezed for the
+                # scenario (restored where the scenario's vars are)
+                fill_saved = _delta.COMPACT_FILL
+                _delta.COMPACT_FILL = 4 / _delta.MIN_DELTA_CAP
                 s.query(q)
                 # pile IN-RANGE appends past the squeezed threshold so
                 # the next read's extension schedules a compaction job
@@ -1041,6 +1045,7 @@ def run_sweep(verbose: bool = False, mesh: Optional[int] = None,
                 if err2 is not None or rows2 != cpu2:
                     failures.append(
                         f"{sc.name}: compacted generation diverged")
+                _delta.COMPACT_FILL = fill_saved
             elif sc.run == "write":
                 write_seq += 1
                 ins = (f"insert into cs_facts values "

@@ -61,7 +61,14 @@ Lanes, from packet to packet:
   drain    block_until_ready waits
   fetch    device -> host result transfers
   decode   host-side dictionary decode / Chunk assembly
-  cache    evictions, delta extensions (instants)
+  cache    evictions (instants)
+  write    write.stage (a DML statement's rows staged or matched),
+           write.commit (Store.commit: tables, rows, tombs)
+  delta    what a write costs the next read of a cached table
+           (executor/delta.py): delta.diff, delta.encode, delta.upload,
+           delta.tombstone (one per slab whose liveness mask changed),
+           delta.aligned (the FK-aligned joins following a generation),
+           delta.decline (instant, gate=), compact.run, compact.swap
   gc       generation-2 garbage collections, while the global collector
            is attached
 
@@ -102,7 +109,7 @@ STREAMS = {"sched": 1, "compile": 2, "encode": 3, "upload": 4,
            # packet to packet (module docstring)
            "client": 12, "stmt": 13, "wire": 14, "parse": 15, "plan": 16,
            "exec": 17, "frag": 18, "launch": 19, "drain": 20, "gc": 21,
-           "write": 22}
+           "write": 22, "delta": 23}
 _OTHER_TID = 31
 
 _GLOBAL: Optional["_Collector"] = None     # tidb_tpu_trace_dir sink
