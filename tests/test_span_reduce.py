@@ -507,10 +507,14 @@ def test_a_warm_aggregate_launches_the_same_programs_in_the_same_order(
 def test_lowered_programs_carry_their_name_and_their_stages(monkeypatch):
     """The HLO of a chain partial over compressed columns: module
     `jit_partial_chain_<sig8>`, operations under `decode`, `filter` and
-    `agg`; the fused finalize holds `merge` and `sort` under `finalize`."""
+    `agg` — the one-hot contraction of the slot sums among them (forced
+    onto this small table: the threshold is a constant of `ops/segment.py`)
+    — and the fused finalize holds `merge` and `sort` under `finalize`."""
     from tidb_tpu.executor import device_cache as dc
     from tidb_tpu.executor import fragment
     from tidb_tpu.ops import jax_env
+    from tidb_tpu.ops import segment as seg
+    monkeypatch.setattr(seg, "SLOT_SUM_MIN_WORK", 2)
     seen = {}
     real = jax_env.named_jit
 
@@ -549,6 +553,9 @@ def test_lowered_programs_carry_their_name_and_their_stages(monkeypatch):
     assert f"module @jit_{partial} " in text
     for stage in ("decode", "filter", "agg"):
         assert f"jit({partial})/{stage}/" in text, stage
+    # ONE contraction, and it lies under `agg`
+    assert text.count("stablehlo.dot_general") == 1
+    assert re.search(r'jit\(%s\)/agg/[^"]*dot_general' % partial, text)
     jitted, args = seen[final]
     text = jitted.lower(*args).as_text(debug_info=True)
     assert f"module @jit_{final} " in text

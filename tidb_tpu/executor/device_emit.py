@@ -26,7 +26,7 @@ from __future__ import annotations
 import functools
 from typing import List, Sequence
 
-from tidb_tpu.expression import EvalContext
+from tidb_tpu.expression import ColumnRef, EvalContext
 from tidb_tpu.expression.aggfuncs import AggFunc
 
 
@@ -588,9 +588,26 @@ def agg_states(ctx, live, root, aggs, gids, cap: int, n: int):
 
 def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
                 distinct_first=None, distinct_vals=None):
+    """One state tuple per aggregate over the batch. Where the shapes
+    send slot sums to the matrix unit (`seg.slot_sum_lowering`: `mxu`),
+    every state that is a sum of a per-row integer — COUNT, SUM and AVG
+    over integers and scaled DECIMALs — comes out of ONE call of
+    `seg.slot_sums` for the whole aggregate: a column is evaluated once,
+    so `SUM(x)` and `AVG(x)` share their pieces and every aggregate over
+    one validity its count. Any other state, and every state of the
+    other lowerings, is the aggregate's own `update`. Says which lowering
+    the traced program took, once a trace: counter
+    `tidb_tpu_slot_sum_programs_total{lowering=}` and tag `slot_sums` =
+    `<lowering>:<n>` (under `mxu` the piece rows of the contraction, else
+    the state arrays, a reduction each) on the span that covers the
+    trace."""
     from tidb_tpu.ops.jax_env import jnp
     from tidb_tpu.ops import factorize as F
-    states = []
+    from tidb_tpu.ops import segment as seg
+    from tidb_tpu.util import timeline
+    from tidb_tpu.util.observability import REGISTRY
+    evaluated = {}      # an argument → its (values, validity)
+    inputs = []
     for ai, (agg, desc) in enumerate(zip(aggs, root.aggs)):
         if desc.distinct and desc.args and distinct_vals is not None \
                 and ai in distinct_vals:
@@ -598,9 +615,15 @@ def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
         elif desc.distinct and desc.args:
             v, m, _ = _distinct_arg(ctx, live, desc)
         elif desc.args:
-            v, m = desc.args[0].eval(ctx)
-            v = jnp.asarray(v)
-            m = jnp.asarray(m) & live
+            # a plain column is ONE pair of arrays however many aggregates
+            # name it (a computed argument is not compared: a parameter's
+            # repr leaves its value out)
+            arg = desc.args[0]
+            key = arg.index if isinstance(arg, ColumnRef) else id(arg)
+            if key not in evaluated:
+                v, m = arg.eval(ctx)
+                evaluated[key] = (jnp.asarray(v), jnp.asarray(m) & live)
+            v, m = evaluated[key]
         else:
             v = jnp.zeros(n, dtype=jnp.int64)
             m = live
@@ -610,8 +633,26 @@ def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
                 m = m & distinct_first[ai]
             else:
                 m = m & F.distinct_mask(gids, v, m, live)
+        inputs.append((v, m))
+    lowering = seg.slot_sum_lowering(jnp, n, cap)
+    plans = [agg.row_sums(jnp, v, m) if lowering == "mxu" else None
+             for agg, (v, m) in zip(aggs, inputs)]
+    columns = [c for plan in plans if plan for c in plan if c is not None]
+    if lowering == "mxu" and not columns:
+        lowering = "masked"             # MIN/MAX and their like alone
+    sums = iter(seg.slot_sums(jnp, columns, gids, cap))
+    states = []
+    for agg, plan, (v, m) in zip(aggs, plans, inputs):
         st = agg.init(jnp, cap)
-        states.append(agg.update(jnp, st, gids, cap, v, m))
+        if plan:
+            states.append(tuple(a if c is None else a + next(sums)
+                                for a, c in zip(st, plan)))
+        else:
+            states.append(agg.update(jnp, st, gids, cap, v, m))
+    width = seg.slot_sum_pieces(columns) if lowering == "mxu" \
+        else sum(len(st) for st in states)
+    REGISTRY.inc("tidb_tpu_slot_sum_programs_total", {"lowering": lowering})
+    timeline.tag(slot_sums=f"{lowering}:{width}")
     return states
 
 

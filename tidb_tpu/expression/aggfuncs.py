@@ -95,6 +95,16 @@ class AggFunc:
     def merge(self, xp, state: Tuple, gid, n: int, partial: Tuple) -> Tuple:
         raise NotImplementedError
 
+    def row_sums(self, xp, values, validity) -> Optional[List]:
+        """`update` as sums of per-row integers, for a caller that
+        computes every aggregate's sums in one pass
+        (ops/segment.slot_sums): → one seg.SumColumn per array of the
+        state tuple — its sum by slot IS what `update` adds to that array
+        — and None for an array `update` leaves alone. None altogether
+        when the update is anything else (MIN/MAX, FIRST, BIT, a float
+        sum)."""
+        return None
+
     def final(self, xp, state: Tuple):
         """→ (values, validity) arrays of length G."""
         raise NotImplementedError
@@ -132,6 +142,9 @@ class CountAgg(AggFunc):
     def update(self, xp, state, gid, n, values, validity):
         (counts,) = state
         return (counts + seg.segment_count(xp, validity, gid, n),)
+
+    def row_sums(self, xp, values, validity):
+        return [seg.SumColumn(None, validity)]
 
     def merge(self, xp, state, gid, n, partial):
         (counts,) = state
@@ -233,6 +246,28 @@ class SumAgg(AggFunc):
         out.extend(state[len(limbs):-1])     # untouched higher planes
         out.append(state[-1] + seg.segment_count(xp, validity, gid, n))
         return tuple(out)
+
+    def row_sums(self, xp, values, validity):
+        if self._float or (self._wide and xp is np and self._arg_obj):
+            return None
+        count = seg.SumColumn(None, validity)
+        if not self._wide:
+            return [seg.SumColumn(self._cast_in(xp, values), validity),
+                    count]
+        # the limb planes of _update_wide, each a bit field of the value
+        from tidb_tpu.executor.device_cache import WIDE_LIMB_BITS as B
+        if getattr(values, "ndim", 1) == 2:
+            limbs = [seg.SumColumn(values, validity, k)
+                     for k in range(values.shape[0])]
+        else:
+            if values.dtype != xp.int64:
+                values = values.astype(xp.int64)
+            limbs = [seg.SumColumn(values, validity, None, 0, B, False),
+                     seg.SumColumn(values, validity, None, B, B, False),
+                     seg.SumColumn(values, validity, None, 2 * B, 64 - 2 * B,
+                                   True)]
+        untouched = [None] * (self._n_limb_planes() - len(limbs))
+        return limbs + untouched + [count]
 
     def _merge_wide(self, xp, state, gid, n, partial):
         out = [st + seg.segment_sum(xp, p, gid, n)

@@ -2,33 +2,50 @@
 
 The TPU-first reformulation of TiDB's hash aggregation (SURVEY §7 stage 4):
 open-address hash tables have no efficient TPU form, so grouped reduction is
-expressed as segment ops — scatter-combine rows into dense group slots. On
-numpy these use `ufunc.at` (exact int64 — np.bincount would round through
-float64); under jit they lower to `jax.ops.segment_*`, which XLA turns into
-efficient sorted-scatter updates.
+expressed as segment ops — rows combined into dense group slots. On numpy
+these use `ufunc.at` (exact int64 — np.bincount would round through
+float64). Under jit there are five lowerings, chosen from static shapes
+alone; what each costs on a TPU v5e over one 8M-row slab (PERF.md §6):
+
+* `flat` — ONE segment (an aggregate without GROUP BY): a plain reduction,
+  no slot axis; ≈ 1 ms a state (PR 27).
+* `masked` — `reduce(where(gid == iota, v, identity))`, one broadcast
+  reduce per state; rows × slots of VPU work in emulated 64 bits: 0.26 ms a
+  state at 12 slots, 8.1 ms at 128 and 13.9 ms at 1024 (blocked). MIN, MAX,
+  FIRST and every merge of slab partials (slabs × cap rows) take it.
+* `mxu` — `slot_sums`: ALL the integer sums of a slot-addressed aggregate
+  (COUNT, SUM, AVG over integers and scaled DECIMALs) as one one-hot
+  contraction on the matrix unit over integer pieces of at most 8 bits:
+  2.4 ms for Q1's 29 states at 12 slots (7.5 ms masked), 6.6 ms at 128
+  slots (234), 13.6 ms at 1024 (402). Taken from SLOT_SUM_MIN_WORK rows ×
+  slots up, by `executor/device_emit._agg_states`.
+* sorted runs — `SortedRuns`: a cumsum, one gather of `cap` elements and
+  a difference a state over rows already sorted by group (PR 28); for
+  integer sums beyond MASKED_REDUCE_CAP slots.
+* scatter — `jax.ops.segment_*`: serialized updates, 1.1 s an int64 state
+  into 8M slots (PR 28); what is left for MIN/MAX beyond the cap.
 
 All functions take `num_segments` statically so jitted shapes stay static.
-Rows may carry gid == num_segments-1 padding; callers mask validity instead.
+Rows whose id is out of range (dead rows carry `num_segments`) drop.
 """
 
 from __future__ import annotations
 
+from typing import List, NamedTuple, Optional, Sequence
+
 import numpy as np
 
-# Below this cap, grouped reductions use a masked broadcast-reduce instead of
-# a scatter: TPU scatter serializes updates (~70ms for 1M int64 rows on v4),
-# while `reduce(where(gid == iota_c, v, id))` stays a fused vector reduction
-# (~8ms at cap 16, ~14ms at cap 1024 for 1M rows on v4). The cost grows with
-# rows × cap: a v5e reads ≈ 18 ms per int64 state over an 8M-row slab at cap
-# 1024, blocked (PERF.md §6, PR 27), which is why an aggregate with no GROUP
-# BY asks for ONE segment (fragment._initial_group_cap) and reduces flat, with
-# no slot axis at all (≈ 1 ms a state). Exact for
-# int64 — no float round trip. The broadcast materializes n×cap values, so
-# beyond a materialization budget the reduction runs BLOCKED: lax.map over
-# row blocks, each block broadcast-reduced into (cap,) partials, partials
-# combined — data streams from HBM once, materialization stays ≤ the budget,
-# and no scatter appears (at 64M rows × cap 7 this is ~100× faster than the
-# scatter lowering; the SF=10 Q3 regression was exactly this fallback).
+# Up to this many slots a grouped reduction is a masked broadcast-reduce and
+# never a scatter (a TPU scatter serializes its updates: 1.1 s an int64
+# state into 8M slots on a v5e, PERF.md §6 PR 28). The reduce stays a fused
+# vector reduction, exact in int64, but costs rows × slots select-adds in
+# emulated 64 bits: per state and 8M-row slab 0.26 ms at 12 slots, 13.9 ms
+# at 1024 (header). The broadcast materializes rows × slots values, so past
+# MASKED_REDUCE_WORK of them it runs BLOCKED: lax.map over row blocks, each
+# reduced into (cap,) partials — the data streams from HBM once and no
+# scatter appears. The integer SUMS of an aggregate leave this kernel for
+# the matrix unit from SLOT_SUM_MIN_WORK up (`slot_sums`), and an aggregate
+# without GROUP BY asks for ONE segment and reduces flat.
 MASKED_REDUCE_CAP = 1024
 MASKED_REDUCE_WORK = 1 << 27
 
@@ -166,6 +183,245 @@ def segment_sum(xp, data, segment_ids, num_segments: int):
                                       data.dtype.type(0), xp.sum)
     from tidb_tpu.ops.jax_env import jax
     return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
+
+
+# ---------------------------------------------------------------------------
+# slot sums on the matrix unit
+# ---------------------------------------------------------------------------
+
+#: rows × slots from which the sums of a slot-addressed aggregate go to the
+#: matrix unit (header). A merge of slab partials (slabs × cap rows) and
+#: a small batch stay far under it and keep the masked reduce.
+SLOT_SUM_MIN_WORK = 1 << 18
+#: rows of one block of the contraction. A block's int32 accumulator is
+#: exact while rows × 128 < 2³¹ (every piece lies in [-128, 128)), so
+#: any block below 2²⁴ rows is; this size keeps a block's pieces and its
+#: one-hot in the chip's fast memory.
+SLOT_SUM_BLOCK = 1 << 17
+
+
+class SumColumn(NamedTuple):
+    """One per-row integer whose sums by slot `slot_sums` computes: bits
+    [shift, shift + bits) of `values` — a bool, int32 or int64 vector, or
+    plane `plane` of a 2-D (planes, rows) int64 array — read as unsigned,
+    or as a signed number when `signed` (the field then ends at the top of
+    the dtype); `bits` None = the whole width, signed. A row that `valid`
+    (a bool vector) masks out counts 0; `values` None counts the valid
+    rows. Two columns that name the SAME arrays and field are computed
+    once."""
+    values: object
+    valid: object = None
+    plane: Optional[int] = None
+    shift: int = 0
+    bits: Optional[int] = None
+    signed: bool = True
+
+
+def _column_field(col: SumColumn):
+    """→ (shift, bits, signed) with the dtype's width filled in."""
+    if col.values is None or col.values.dtype == np.bool_:
+        return 0, 1, False
+    if col.bits is not None:
+        return col.shift, col.bits, col.signed
+    return 0, 8 * np.dtype(col.values.dtype).itemsize, True
+
+
+def _column_key(col: SumColumn):
+    return (id(col.values), col.plane, id(col.valid)) + _column_field(col)
+
+
+def _column_data(xp, col: SumColumn):
+    """The column as one int64 per row: what the other lowerings sum."""
+    if col.values is None:
+        return col.valid.astype(xp.int64)
+    v = col.values if col.plane is None else col.values[col.plane]
+    if col.valid is not None:
+        v = xp.where(col.valid, v, xp.zeros_like(v))
+    shift, bits, signed = _column_field(col)
+    v = v.astype(xp.int64) >> shift
+    return v if signed else v & xp.int64((1 << bits) - 1)
+
+
+def slot_sum_lowering(xp, n_rows: int, num_segments: int) -> str:
+    """Which kernel sums per-row integers into `num_segments` slots over
+    `n_rows` rows: `flat` (one slot: a plain reduction), `mxu` (the
+    one-hot contraction of `slot_sums`), `masked` (one broadcast reduce
+    per state) or `scatter` (beyond MASKED_REDUCE_CAP). From shapes alone,
+    so a trace can say it."""
+    if num_segments == 1:
+        return "flat"
+    if num_segments > MASKED_REDUCE_CAP:
+        return "scatter"
+    if not _is_np(xp) and n_rows * num_segments >= SLOT_SUM_MIN_WORK:
+        return "mxu"
+    return "masked"
+
+
+def _piece_plan(shift: int, bits: int, signed: bool):
+    """Cut bits [shift, shift + bits) into pieces of ≤ 8 bits that never
+    cross bit 32 (a piece is cut from ONE 32-bit word) → [(start, width,
+    signed)], the top piece signed where the field is."""
+    out = []
+    s, end = shift, shift + bits
+    while s < end:
+        w = min(8, end - s, 32 - s if s < 32 else 64)
+        out.append((s, w, signed and s + w == end))
+        s += w
+    return out
+
+
+#: piece rows a word's broadcast makes at once: the sublanes of a vector
+#: register, so that the groups stack without moving anything.
+_GROUP_ROWS = 8
+
+
+def _biased(width: int, signed: bool) -> bool:
+    """An unsigned byte does not fit int8: it is stored less 128."""
+    return width == 8 and not signed
+
+
+def _slot_sum_plan(columns: Sequence[SumColumn]):
+    """What the contraction holds, from dtypes alone → (sources, masks,
+    groups, where).
+
+    `sources` {(values, plane, validity): column}: the distinct values,
+    each read as one or two 32-bit WORDS ("lo", "hi"). `masks` {(values,
+    plane, validity): (bit, column)}: the distinct 0/1 columns (a validity
+    to count, a boolean value), each one bit of a word of bits whose bit
+    0 is always set (the slot's rows). `groups` [(word, [(start, width,
+    signed)])]: the distinct pieces by the word they are cut from, at
+    most _GROUP_ROWS a group. `where` {field: [(row of the piece matrix,
+    shift back, stored less 128)]}: the pieces of each distinct column;
+    `where[None]` is the column of ones."""
+    sources, masks, fields = {}, {}, {}
+    for c in columns:
+        key = _column_key(c)
+        if key in fields:
+            continue
+        shift, bits, signed = key[3:]
+        if bits == 1 and not signed:
+            bit = masks.setdefault(key[:3], (len(masks) + 1, c))[0]
+            fields[key] = [(("bits", bit // 32), bit % 32, 1, False, 0)]
+            continue
+        sources.setdefault(key[:3], c)
+        fields[key] = [
+            (("hi" if start >= 32 else "lo", key[:3]), start % 32, width,
+             top, start - shift)
+            for start, width, top in _piece_plan(shift, bits, signed)]
+    fields[None] = [(("bits", 0), 0, 1, False, 0)]
+    cuts = {}           # word → its distinct pieces, in order
+    for at in fields.values():
+        for word, start, width, top, _ in at:
+            cuts.setdefault(word, {})[start, width, top] = None
+    groups, row = [], {}
+    for word, cut in cuts.items():
+        cut = list(cut)
+        for g in range(0, len(cut), _GROUP_ROWS):
+            for r, piece in enumerate(cut[g:g + _GROUP_ROWS]):
+                row[(word,) + piece] = _GROUP_ROWS * len(groups) + r
+            groups.append((word, cut[g:g + _GROUP_ROWS]))
+    where = {key: [(row[word, start, width, top], up, _biased(width, top))
+                   for word, start, width, top, up in at]
+             for key, at in fields.items()}
+    return sources, masks, groups, where
+
+
+def slot_sum_pieces(columns: Sequence[SumColumn]) -> int:
+    """Rows of the piece matrix `slot_sums` contracts for these columns
+    (the groups' padding included)."""
+    return _GROUP_ROWS * len(_slot_sum_plan(columns)[2])
+
+
+def slot_sums(xp, columns: Sequence[SumColumn], segment_ids,
+              num_segments: int) -> List:
+    """Σ over the rows of each slot, for every column at once → one
+    (num_segments,) int64 array a column, equal bit for bit (wrapping
+    modulo 2⁶⁴) to `segment_sum` of the column. Rows whose id is out of
+    range drop.
+
+    Where `slot_sum_lowering` says `mxu`, all columns are ONE contraction
+    on the matrix unit, in blocks of rows: every distinct column is cut
+    into integer pieces of at most 8 bits (an invalid row's value is
+    zeroed first), the pieces form an int8 matrix (pieces, rows), the
+    slot ids a one-hot int8 matrix (slots, rows), and their product,
+    accumulated in int32, holds every piece's sum by slot. An unsigned
+    byte p is stored as p − 128 so that it fits int8, and 128 × the slot's
+    row count is added back; the blocks' partials are added in int64 and
+    the pieces shifted back together on `num_segments` elements. Exact by
+    construction: no piece is dropped, no accumulator can overflow
+    (SLOT_SUM_BLOCK).
+
+    The piece matrix is never assembled from row vectors (a vector of
+    rows lies across sublanes AND lanes, a matrix row along lanes only:
+    stacking 50 vectors cost more than the 29 masked reduces, PERF.md §6
+    PR 33): each 32-bit word of a value is BROADCAST over eight sublanes
+    and shifted and masked by a column of eight constants, which yields
+    eight piece rows in place, and such groups stack whole."""
+    if not columns or slot_sum_lowering(
+            xp, int(segment_ids.shape[0]), num_segments) != "mxu":
+        return [segment_sum(xp, _column_data(xp, c), segment_ids,
+                            num_segments) for c in columns]
+    from tidb_tpu.ops.jax_env import jnp, lax
+    n = int(segment_ids.shape[0])
+    sources, masks, groups, where = _slot_sum_plan(columns)
+    iota = jnp.arange(num_segments, dtype=jnp.int32)[:, None]
+    # a group's eight (shift, mask, bias), one a sublane
+    consts = []
+    for _, cut in groups:
+        c = np.zeros((3, _GROUP_ROWS, 1), dtype=np.int32)
+        for r, (start, width, top) in enumerate(cut):
+            c[:, r, 0] = (start, -1 if top else (1 << width) - 1,
+                          128 if _biased(width, top) else 0)
+        consts.append(c)
+
+    def block(start, size):
+        """Rows [start, start + size) → (num_segments, pieces) int32."""
+        def sl(a, plane=None):
+            if plane is None:
+                return lax.dynamic_slice(a, (jnp.int32(start),), (size,))
+            return lax.dynamic_slice(
+                a, (jnp.int32(plane), jnp.int32(start)), (1, size))[0]
+        words = {("bits", 0): jnp.ones(size, dtype=jnp.int32)}
+        for skey, c in sources.items():
+            v = sl(c.values, c.plane)
+            if c.valid is not None:
+                v = jnp.where(sl(c.valid), v, jnp.zeros_like(v))
+            words["lo", skey] = v.astype(jnp.int32)
+            if v.dtype.itemsize == 8:
+                words["hi", skey] = (v >> 32).astype(jnp.int32)
+        for bit, c in masks.values():
+            m = sl(c.valid) if c.values is None else sl(c.values)
+            if c.values is not None and c.valid is not None:
+                m = m & sl(c.valid)
+            word = ("bits", bit // 32)
+            m = m.astype(jnp.int32) << (bit % 32)
+            words[word] = m | words[word] if word in words else m
+        pieces = [((words[word][None, :] >> c[0]) & c[1]) - c[2]
+                  for (word, _), c in zip(groups, consts)]
+        onehot = sl(segment_ids).astype(jnp.int32)[None, :] == iota
+        return lax.dot_general(
+            onehot.astype(jnp.int8),
+            jnp.concatenate(pieces, axis=0).astype(jnp.int8),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
+
+    nb, tail = divmod(n, SLOT_SUM_BLOCK)
+    total = jnp.zeros((num_segments, _GROUP_ROWS * len(groups)),
+                      dtype=jnp.int64)
+    if nb:
+        total = lax.map(
+            lambda i: block(i * SLOT_SUM_BLOCK, SLOT_SUM_BLOCK),
+            jnp.arange(nb, dtype=jnp.int32)).astype(jnp.int64).sum(axis=0)
+    if tail:
+        total = total + block(nb * SLOT_SUM_BLOCK, tail).astype(jnp.int64)
+    rows = total[:, where[None][0][0]]
+    sums = {}
+    for key, at in where.items():
+        acc = jnp.zeros(num_segments, dtype=jnp.int64)
+        for j, up, biased in at:
+            piece = total[:, j] + rows * 128 if biased else total[:, j]
+            acc = acc + (piece << up)
+        sums[key] = acc
+    return [sums[_column_key(c)] for c in columns]
 
 
 def segment_sum_accurate(xp, data, segment_ids, num_segments: int):
