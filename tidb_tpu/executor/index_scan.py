@@ -8,6 +8,13 @@ first use of an index on a table version argsorts the key column once
 cache), after which every range probe is two binary searches plus a
 row gather — the same asymptotics as an index seek, with no extra
 write-path maintenance (append-only storage rebuilds lazily).
+
+On the timeline (lane `index`): `index.build` once a table version and
+index (`table`, `index`, `rows`: the live view gathered and its key
+sorted) and `index.probe` once an executor (`ranges`, `rows` out: the
+binary searches, the gather, the residual filters). Always-on counters:
+`tidb_tpu_index_builds_total{table}`, `tidb_tpu_index_probes_total`,
+`tidb_tpu_index_rows_total`.
 """
 
 from __future__ import annotations
@@ -22,8 +29,18 @@ from tidb_tpu.chunk import Chunk
 from tidb_tpu.executor import MaterializingExec, _empty_chunk
 from tidb_tpu.expression.runner import filter_mask
 from tidb_tpu.planner.ranger import Range
+from tidb_tpu.util import timeline
+from tidb_tpu.util.observability import REGISTRY
 
 MAX_CACHED_INDEXES = 16
+
+
+def _build_span(table_info, index: str):
+    """Around one build of a sorted view (a cache miss): counted, and on
+    the timeline with the rows it sorted (the builder tags them)."""
+    REGISTRY.inc("tidb_tpu_index_builds_total", {"table": table_info.name})
+    return timeline.span("index.build", "index", table=table_info.name,
+                         index=index)
 
 
 class SortedIndex:
@@ -161,17 +178,20 @@ def get_prefix_index(ctx, table_id: int, col_idxs, table_info
                 len(ent.view.columns) == len(table_info.columns):
             _PREFIX_CACHE.move_to_end(key)
             return ent
-    view = _live_view(ctx, table_id, table_info, cacheable, td, store)
-    ctx.check_killed()
-    keys = []
-    for ci in reversed(list(col_idxs)):     # np.lexsort: LAST is primary
-        col = view.columns[ci]
-        keys.append(_fill_nulls(col.values, col.valid_mask()))
-    order = np.lexsort(keys) if view.num_rows else \
-        np.empty(0, dtype=np.int64)
-    arrs = [k[order] for k in reversed(keys)]
-    ent = PrefixSortedIndex(td, arrs, order.astype(np.int64), view,
-                            tuple(col_idxs))
+    names = ",".join(table_info.columns[ci].name for ci in col_idxs)
+    with _build_span(table_info, names):
+        view = _live_view(ctx, table_id, table_info, cacheable, td, store)
+        ctx.check_killed()
+        keys = []
+        for ci in reversed(list(col_idxs)):  # np.lexsort: LAST is primary
+            col = view.columns[ci]
+            keys.append(_fill_nulls(col.values, col.valid_mask()))
+        order = np.lexsort(keys) if view.num_rows else \
+            np.empty(0, dtype=np.int64)
+        arrs = [k[order] for k in reversed(keys)]
+        ent = PrefixSortedIndex(td, arrs, order.astype(np.int64), view,
+                                tuple(col_idxs))
+        timeline.tag(rows=view.num_rows)
     if cacheable:
         with _LOCK:
             _PREFIX_CACHE[key] = ent
@@ -227,16 +247,18 @@ def get_index(ctx, table_id: int, col_idx: int, table_info) -> SortedIndex:
             _CACHE.move_to_end(key)
             return ent
 
-    view = _live_view(ctx, table_id, table_info, cacheable, td, store)
-    ctx.check_killed()
-    col = view.columns[col_idx]
-    vals, valid = col.values, col.valid_mask()
-    n = len(vals)
-    pos = np.arange(n, dtype=np.int64)
-    nn_pos = pos[valid]
-    order = np.argsort(vals[valid], kind="stable")
-    ent = SortedIndex(td, vals[valid][order], nn_pos[order], pos[~valid],
-                      n, view)
+    with _build_span(table_info, table_info.columns[col_idx].name):
+        view = _live_view(ctx, table_id, table_info, cacheable, td, store)
+        ctx.check_killed()
+        col = view.columns[col_idx]
+        vals, valid = col.values, col.valid_mask()
+        n = len(vals)
+        pos = np.arange(n, dtype=np.int64)
+        nn_pos = pos[valid]
+        order = np.argsort(vals[valid], kind="stable")
+        ent = SortedIndex(td, vals[valid][order], nn_pos[order],
+                          pos[~valid], n, view)
+        timeline.tag(rows=n)
     if cacheable:
         with _LOCK:
             _CACHE[key] = ent
@@ -257,6 +279,15 @@ class IndexScanExec(MaterializingExec):
         return f"index:{self.plan.index_name} ranges:{self.plan.ranges!r}"
 
     def _materialize(self) -> Chunk:
+        with timeline.span("index.probe", "index",
+                           ranges=len(self.plan.ranges)):
+            out = self._probe()
+            timeline.tag(rows=out.num_rows)
+        REGISTRY.inc("tidb_tpu_index_probes_total")
+        REGISTRY.inc("tidb_tpu_index_rows_total", by=out.num_rows)
+        return out
+
+    def _probe(self) -> Chunk:
         plan = self.plan
         key_cols = getattr(plan, "key_cols", None)
         if key_cols and len(key_cols) > 1:

@@ -23,6 +23,7 @@ clientConn.Run without an event loop)."""
 from __future__ import annotations
 
 import datetime
+import decimal
 import math
 import hashlib
 import os
@@ -190,6 +191,8 @@ def _sql_literal(v) -> str:
         if isinstance(v, float) and not math.isfinite(v):
             return "NULL"     # MySQL has no inf/nan literals
         return repr(v)
+    if isinstance(v, decimal.Decimal):
+        return format(v, "f") if v.is_finite() else "NULL"
     if isinstance(v, bytes):
         v = v.decode("utf-8", "replace")
     if isinstance(v, (datetime.datetime, datetime.date)):
@@ -295,7 +298,13 @@ def decode_binary_params(data: bytes, i: int, stmt: "PreparedStmt"
                 vals.append(f"{sign}{days * 24 + h:02d}:{mi:02d}:{s:02d}")
         elif tp == 0x06:    # NULL
             vals.append(None)
-        else:               # strings / decimals / blobs: length-encoded
+        elif tp in (0x00, 0xF6):    # DECIMAL / NEWDECIMAL: a NUMBER that
+            # travels as length-encoded text; bound as a quoted string it
+            # would compare as one
+            ln, i = _read_lenenc(data, i)
+            vals.append(decimal.Decimal(data[i:i + ln].decode("ascii")))
+            i += ln
+        else:               # strings / blobs: length-encoded
             ln, i = _read_lenenc(data, i)
             vals.append(data[i:i + ln].decode("utf-8", "replace"))
             i += ln
@@ -552,11 +561,11 @@ class _Conn:
                 elif cmd == COM_FIELD_LIST:
                     self.write_eof()
                 elif cmd == COM_QUERY:
-                    self._request(self._query, data)
+                    self._request(self._query, data, "text")
                 elif cmd == COM_STMT_PREPARE:
                     self._stmt_prepare(data.decode("utf-8", "replace"))
                 elif cmd == COM_STMT_EXECUTE:
-                    self._request(self._stmt_execute, data)
+                    self._request(self._stmt_execute, data, "binary")
                 elif cmd == COM_STMT_CLOSE:
                     self.stmts.pop(struct.unpack("<I", data[:4])[0], None)
                     # COM_STMT_CLOSE sends no response (protocol)
@@ -587,13 +596,15 @@ class _Conn:
             if PROCESS_REGISTRY.conn_killed(self.session.conn_id):
                 return
 
-    def _request(self, handler, data: bytes) -> None:
+    def _request(self, handler, data: bytes, proto: str) -> None:
         """One request, command received → last result byte written: mint
         its id, keep it on the session while the request runs, and hold
-        the timeline's `stmt` root span around the handler."""
+        the timeline's `stmt` root span around the handler, tagged with
+        the protocol the command came in (`proto=text|binary`)."""
         self.session._request_id = rid = timeline.new_request_id()
         try:
-            with timeline.span("stmt", "stmt", pid=self.conn_id, req=rid):
+            with timeline.span("stmt", "stmt", pid=self.conn_id, req=rid,
+                               proto=proto):
                 handler(data)
         finally:
             self.session._request_id = 0
@@ -649,7 +660,7 @@ class _Conn:
             self.write_err(1243, f"Unknown prepared statement handler "
                                  f"({sid}) given to EXECUTE", b"HY000")
             return
-        with timeline.span("wire.read", "wire"):
+        with timeline.span("wire.read", "wire", params=st.n_params):
             # flags (1) + iteration count (4)
             i = 9
             params: List[object] = []
@@ -776,6 +787,11 @@ class Server:
 
         class Handler(socketserver.BaseRequestHandler):
             def handle(self):
+                # a result set leaves in several small writes and the
+                # client answers none of them: without this each waits
+                # for the peer's delayed ACK (Nagle), 40 ms a statement
+                self.request.setsockopt(socket.IPPROTO_TCP,
+                                        socket.TCP_NODELAY, 1)
                 with outer._lock:
                     outer._next_conn += 1
                     cid = outer._next_conn

@@ -685,6 +685,9 @@ class Session:
                 # locality placement routes warm digests to the device
                 # already holding them (cold digests → least depth)
                 guard.sched_tables = REGISTRY.digest_tables(one)
+            # on the root too: a reader tells a point read from a scan
+            # without parsing SQL
+            timeline.tag(**{"class": guard.sched_class or "none"})
             self._guard = guard
             self.last_guard = guard
             PROCESS_REGISTRY.stmt_begin(self.conn_id, guard)
@@ -1171,6 +1174,9 @@ class Session:
                 timeline.tag(cache="hit", eager_subqueries=0)
                 return hit
         timeline.tag(cache="miss" if key is not None else "uncacheable")
+        if key is not None:
+            from tidb_tpu.util.observability import REGISTRY
+            REGISTRY.inc("tidb_tpu_plan_cache_misses_total")
         before = self._subq_execs
         plan = optimize(stmt, self.engine.catalog.info_schema, ctx)
         # subqueries executed at PLAN time and folded into the plan as

@@ -5,10 +5,11 @@ chrome://tracing / Perfetto).
 ONE recording site, `span(name, lane, **args)`, serves three renderers:
 
   * the cross-session Chrome JSON (`SET tidb_tpu_trace_dir = '/path'`:
-    <dir>/tidb_tpu_trace_<os-pid>.json, written every 5 s from the
-    statement path, on `flush()` and on `stop_global()` — NOT after every
-    statement; the stopped collector's events stay readable through
-    `last_events()` until the next `start_global`);
+    <dir>/tidb_tpu_trace_<os-pid>.json, written every 5 s (more rarely
+    as it grows) from the statement path, on `flush()` and on
+    `stop_global()` — NOT after every statement; the stopped collector's
+    events stay readable through `last_events()` until the next
+    `start_global`);
   * `TRACE FORMAT='chrome' <stmt>`: a scoped collector for one statement,
     returned as a result row (executor/trace.go's chrome format analog);
   * the `jax.profiler` trace: while a collector is attached every span also
@@ -37,8 +38,11 @@ Lanes, from packet to packet:
 
   client   server blocked in read_packet for the next command
   stmt     root, one per request: command received -> last result byte
-  wire     wire.read (decode, placeholder substitution), wire.write (row
-           encoding and send)
+           (proto=text|binary under the wire server; class=interactive|
+           batch|none, what the session's admission classifier decided;
+           sql=<first 80 characters>)
+  wire     wire.read (decode, placeholder substitution; params=<n> for a
+           COM_STMT_EXECUTE), wire.write (row encoding and send)
   parse    parse_with_text
   plan     planner.optimize (cache=hit|miss), optimize.*, rule.*,
            executor.build
@@ -69,6 +73,10 @@ Lanes, from packet to packet:
            delta.tombstone (one per slab whose liveness mask changed),
            delta.aligned (the FK-aligned joins following a generation),
            delta.decline (instant, gate=), compact.run, compact.swap
+  index    the host's index access path (executor/index_scan.py):
+           index.build (table, index, rows: once a table version — the
+           live view gathered and its key sorted), index.probe (ranges,
+           rows out: binary searches, gather, residual filters)
   gc       generation-2 garbage collections, while the global collector
            is attached
 
@@ -109,7 +117,7 @@ STREAMS = {"sched": 1, "compile": 2, "encode": 3, "upload": 4,
            # packet to packet (module docstring)
            "client": 12, "stmt": 13, "wire": 14, "parse": 15, "plan": 16,
            "exec": 17, "frag": 18, "launch": 19, "drain": 20, "gc": 21,
-           "write": 22, "delta": 23}
+           "write": 22, "delta": 23, "index": 24}
 _OTHER_TID = 31
 
 _GLOBAL: Optional["_Collector"] = None     # tidb_tpu_trace_dir sink
@@ -118,6 +126,7 @@ _LAST: List[dict] = []                     # the stopped global's events
 _SCOPED: List["_Collector"] = []           # TRACE FORMAT='chrome' sinks
 _NEXT_FLUSH = 0.0                          # time.monotonic() of the next
 FLUSH_INTERVAL_S = 5.0                     # statement-path write
+FLUSH_COST_SHARE = 0.02                    # of the time between two writes
 
 _REQUEST_IDS = itertools.count(1)
 _SPAN_IDS = itertools.count(1)
@@ -387,12 +396,23 @@ def last_events() -> List[dict]:
 
 def flush_if_due() -> None:
     """The statement path's call: write the file at most once every
-    FLUSH_INTERVAL_S. Between writes it costs one clock read."""
+    FLUSH_INTERVAL_S, and more rarely as it grows — the file is rewritten
+    whole, under the interpreter's lock, so a write costs what the
+    collector holds (2 s at half a million events: a window of point
+    reads), and the next falls due only when this one has been at most
+    FLUSH_COST_SHARE of the time between them. Between writes it costs
+    one clock read."""
     global _NEXT_FLUSH
-    if _GLOBAL is None or time.monotonic() < _NEXT_FLUSH:
+    if _GLOBAL is None:
         return
-    _NEXT_FLUSH = time.monotonic() + FLUSH_INTERVAL_S
+    t0 = time.monotonic()
+    if t0 < _NEXT_FLUSH:
+        return
+    _NEXT_FLUSH = t0 + FLUSH_INTERVAL_S     # no second thread starts one
     flush()
+    done = time.monotonic()
+    _NEXT_FLUSH = done + max(FLUSH_INTERVAL_S,
+                             (done - t0) / FLUSH_COST_SHARE)
 
 
 def flush() -> Optional[str]:
