@@ -169,6 +169,9 @@ def test_one_statement_over_the_wire_is_one_covered_root(served, tmp_path):
     try:
         for _ in range(3):
             assert len(cli.query(AGG)[1]) == 5
+        # a root is recorded after its response was sent: the answer to
+        # a ping (no root of its own) says the third one is there
+        cli.ping()
     finally:
         timeline.stop_global()
     evs = [e for e in timeline.last_events() if e["ph"] == "X"]

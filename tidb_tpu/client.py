@@ -24,6 +24,8 @@ import struct
 import time
 from typing import List, Optional, Sequence, Tuple
 
+from tidb_tpu.util.packetio import PacketReader
+
 
 class ClientError(RuntimeError):
     def __init__(self, code: int, msg: str):
@@ -118,6 +120,9 @@ class Client:
         # Nagle's algorithm against the peer's delayed ACK would hold it
         self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         self.seq = 0
+        # a reader of its own for every socket: what a dead connection
+        # left in the old one's buffer is dropped with it
+        self._reader = PacketReader(self.sock)
         try:
             self._handshake(user, password)
         except BaseException:
@@ -142,20 +147,16 @@ class Client:
         raise ClientError(2013, f"reconnect failed: {last}")
 
     # -- framing -------------------------------------------------------------
-    def _recv(self, n: int) -> bytes:
-        buf = b""
-        while len(buf) < n:
-            part = self.sock.recv(n - len(buf))
-            if not part:
-                raise ClientError(2013, "server closed connection")
-            buf += part
-        return buf
-
     def _read_packet(self) -> bytes:
-        h = self._recv(4)
-        ln = h[0] | (h[1] << 8) | (h[2] << 16)
-        self.seq = (h[3] + 1) & 0xFF
-        return self._recv(ln) if ln else b""
+        """The next packet, cut from the reader's buffer: one `recv`
+        takes a whole result set's burst of packets (the server sends a
+        response in one write)."""
+        try:
+            seq, payload = self._reader.read_packet()
+        except ConnectionError as e:
+            raise ClientError(2013, "server closed connection") from e
+        self.seq = (seq + 1) & 0xFF
+        return payload
 
     def _write_packet(self, payload: bytes) -> None:
         self.sock.sendall(struct.pack("<I", len(payload))[:3]
@@ -208,6 +209,9 @@ class Client:
                 ctx.check_hostname = False
                 ctx.verify_mode = _ssl_mod.CERT_NONE
             self.sock = ctx.wrap_socket(self.sock)
+            # nothing read ahead in the clear may be taken for an answer
+            # that came under TLS
+            self._reader = PacketReader(self.sock)
         resp = (struct.pack("<I", caps) + struct.pack("<I", 1 << 24)
                 + bytes([0xFF]) + b"\x00" * 23
                 + user.encode() + b"\x00"
@@ -294,6 +298,15 @@ class Client:
         self.affected_rows = 0
         self.query(sql)
         return self.affected_rows
+
+    def ping(self) -> None:
+        """COM_PING → OK. A connection's commands are answered in order
+        by one thread, so the answer also says that everything the
+        command before it did there is done — its spans recorded, which
+        happens after its response was sent."""
+        self.seq = 0
+        self._write_packet(b"\x0e")
+        self._raise_if_err(self._read_packet())
 
     # -- prepared statements (binary protocol) ------------------------------
     def prepare(self, sql: str) -> Prepared:

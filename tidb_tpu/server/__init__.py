@@ -18,7 +18,10 @@ both protocol paths the reference serves:
 
 One OS thread per connection (threads spend their life blocked on recv or
 inside numpy/XLA which release the GIL — the goroutine-per-conn shape of
-clientConn.Run without an event loop)."""
+clientConn.Run without an event loop). Every socket call hands the
+interpreter lock to the other connections' threads, so a connection frames
+its packets into a buffer and touches the socket once a response and once
+a burst of arriving bytes (`_Conn`, packet framing)."""
 
 from __future__ import annotations
 
@@ -32,11 +35,14 @@ import socketserver
 import struct
 import threading
 import traceback
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
 from tidb_tpu.errors import TiDBTPUError
 from tidb_tpu.types import FieldType, TypeKind
 from tidb_tpu.util import timeline
+from tidb_tpu.util.observability import REGISTRY
+from tidb_tpu.util.packetio import BUFFER_BYTES, PacketReader
 
 PROTOCOL_VERSION = 10
 SERVER_VERSION = b"8.0.11-tidb-tpu"
@@ -382,22 +388,39 @@ class _Conn:
         self.caps = SERVER_CAPS | (CLIENT_SSL if ssl_ctx else 0)
         self.stmts: Dict[int, PreparedStmt] = {}
         self._next_stmt_id = 0
+        # exact reads until `handshake` returns: bytes that follow an
+        # SSLRequest are the TLS layer's, and a read ahead would swallow
+        # them (`run` switches it on)
+        self._reader = PacketReader(sock, ahead=False)
+        self._out = bytearray()         # framed, not yet on the socket
+        self._out_packets = 0
+        self.sends = 0                  # socket calls made to send,
+        self.packets_sent = 0           # and the packets they carried
 
     # -- packet framing ------------------------------------------------------
-    def _recv_exact(self, n: int) -> bytes:
-        buf = b""
-        while len(buf) < n:
-            part = self.sock.recv(n - len(buf))
-            if not part:
-                raise ConnectionError("client closed")
-            buf += part
-        return buf
-
+    # (ref: server/packetio.go: buffered reader and writer, flushed when a
+    # command's response ends.) Packets are framed into `_out`, which goes
+    # to the socket (a) when a command's response ends, inside its
+    # `wire.write` span, (b) when it passes BUFFER_BYTES, so a large result
+    # set streams and a connection's memory stays bounded, and (c) ALWAYS
+    # before the thread blocks in a read: whatever path framed a packet
+    # (OK, ERR, the greeting, a response cut short by an error), the peer
+    # has it before this end waits for the peer. The bytes and their order
+    # are what one `sendall` a packet gave; only the calls differ.
     def read_packet(self) -> bytes:
-        header = self._recv_exact(4)
-        length = header[0] | (header[1] << 8) | (header[2] << 16)
-        self.seq = (header[3] + 1) & 0xFF
-        return self._recv_exact(length) if length else b""
+        self.flush()
+        rd = self._reader
+        seq, payload = rd.read_packet()
+        if rd.recvs:
+            # counted once a burst, never once a packet: a packet cut
+            # from the buffer is counted with the next burst
+            REGISTRY.inc("tidb_tpu_wire_socket_calls_total",
+                         {"kind": "recv"}, by=rd.recvs)
+            REGISTRY.inc("tidb_tpu_wire_packets_total", {"kind": "recv"},
+                         by=rd.packets)
+            rd.recvs = rd.packets = 0
+        self.seq = (seq + 1) & 0xFF
+        return payload
 
     def write_packet(self, payload: bytes) -> None:
         out = b""
@@ -409,7 +432,49 @@ class _Conn:
             self.seq = (self.seq + 1) & 0xFF
             if len(part) < 0xFFFFFF:
                 break
-        self.sock.sendall(out)
+        self._queue(out, 1)
+
+    def _queue(self, framed: bytes, packets: int) -> None:
+        """Framed packets join the buffer. A piece of BUFFER_BYTES or more
+        (a native chunk of rows) follows what precedes it directly,
+        uncopied."""
+        if len(framed) >= BUFFER_BYTES:
+            self.flush()
+            self._send(framed, packets)
+            return
+        self._out += framed
+        self._out_packets += packets
+        if len(self._out) >= BUFFER_BYTES:
+            self.flush()
+
+    def flush(self) -> None:
+        if self._out:
+            out, packets = self._out, self._out_packets
+            self._out, self._out_packets = bytearray(), 0
+            self._send(out, packets)
+
+    def _send(self, data, packets: int) -> None:
+        # counted as the call is made: whoever holds the response finds
+        # it counted
+        self.sends += 1
+        self.packets_sent += packets
+        REGISTRY.inc("tidb_tpu_wire_socket_calls_total", {"kind": "send"})
+        REGISTRY.inc("tidb_tpu_wire_packets_total", {"kind": "send"},
+                     by=packets)
+        self.sock.sendall(data)
+
+    @contextmanager
+    def _response(self):
+        """The `wire.write` span of one command's response: row encoding,
+        framing and the send, tagged with the socket calls it made and the
+        packets they carried (`packets` ÷ `sends` is what buffering buys:
+        1 with a send a packet, 7 for a one-row result set in one)."""
+        with timeline.span("wire.write", "wire"):
+            sends, packets = self.sends, self.packets_sent
+            yield
+            self.flush()
+            timeline.tag(sends=self.sends - sends,
+                         packets=self.packets_sent - packets)
 
     # -- generic packets -----------------------------------------------------
     def write_ok(self, affected: int = 0, insert_id: int = 0,
@@ -450,6 +515,7 @@ class _Conn:
             # handshake response over TLS (server/conn.go TLS branch)
             self.sock = self.ssl_ctx.wrap_socket(self.sock,
                                                  server_side=True)
+            self._reader = PacketReader(self.sock, ahead=False)
             resp = self.read_packet()
         if len(resp) < 32:
             raise ConnectionError("malformed handshake response")
@@ -504,7 +570,7 @@ class _Conn:
         if chunks is not None:
             # columnar fast path: the whole batch encodes to framed row
             # packets in C++ (tidb_tpu/native/rowcodec.cpp — the native
-            # dumpTextRow of server/util.go:390); one sendall per chunk
+            # dumpTextRow of server/util.go:390)
             from tidb_tpu import native
             for ch in chunks:
                 if ch.num_rows == 0:
@@ -514,7 +580,7 @@ class _Conn:
                     self._write_rows_python(ch.rows())
                     continue
                 payload, self.seq = enc
-                self.sock.sendall(payload)
+                self._queue(payload, ch.num_rows)
         else:
             self._write_rows_python(rows)
         self.write_eof(status)
@@ -531,7 +597,20 @@ class _Conn:
 
     # -- command loop --------------------------------------------------------
     def run(self) -> None:
-        self.handshake()
+        try:
+            self.handshake()
+            self._reader.ahead = True
+            self._serve()
+        finally:
+            # whatever ends the connection, what was framed for the peer
+            # leaves first: the ERR of a failed handshake, the response
+            # of the command a KILL arrived beside
+            try:
+                self.flush()
+            except OSError:
+                pass                # the peer is gone
+
+    def _serve(self) -> None:
         while True:
             self.seq = 0
             try:
@@ -670,7 +749,7 @@ class _Conn:
         # COM_STMT_EXECUTE admissions classify as interactive in the
         # priority scheduler regardless of statement shape
         results = self.session.execute(sql, from_prepared=True)
-        with timeline.span("wire.write", "wire"):
+        with self._response():
             for k, rs in enumerate(results):
                 status = 0x0002 | (SERVER_MORE_RESULTS_EXISTS
                                    if k + 1 < len(results) else 0)
@@ -707,7 +786,7 @@ class _Conn:
         with timeline.span("wire.read", "wire"):
             sql = data.decode("utf-8", "replace")
         results = self.session.execute(sql)
-        with timeline.span("wire.write", "wire"):
+        with self._response():
             for i, rs in enumerate(results):
                 # non-final resultsets carry SERVER_MORE_RESULTS_EXISTS so
                 # drivers keep reading (multi-statement COM_QUERY)
@@ -787,9 +866,10 @@ class Server:
 
         class Handler(socketserver.BaseRequestHandler):
             def handle(self):
-                # a result set leaves in several small writes and the
-                # client answers none of them: without this each waits
-                # for the peer's delayed ACK (Nagle), 40 ms a statement
+                # a result set that outgrows the connection's buffer
+                # leaves in several writes and the client answers none
+                # of them: without this each waits for the peer's
+                # delayed ACK (Nagle), 40 ms a statement
                 self.request.setsockopt(socket.IPPROTO_TCP,
                                         socket.TCP_NODELAY, 1)
                 with outer._lock:

@@ -42,7 +42,13 @@ Lanes, from packet to packet:
            batch|none, what the session's admission classifier decided;
            sql=<first 80 characters>)
   wire     wire.read (decode, placeholder substitution; params=<n> for a
-           COM_STMT_EXECUTE), wire.write (row encoding and send)
+           COM_STMT_EXECUTE), wire.write (row encoding, framing into the
+           connection's buffer and the send: sends=<socket calls>,
+           packets=<packets they carried> — one send a response unless
+           it outgrows the buffer). Always on beside them:
+           tidb_tpu_wire_socket_calls_total{kind=send|recv} and
+           tidb_tpu_wire_packets_total{kind=send|recv}, counted once a
+           flush and once a burst read, never once a packet
   parse    parse_with_text
   plan     planner.optimize (cache=hit|miss), optimize.*, rule.*,
            executor.build
