@@ -229,11 +229,15 @@ def test_trace_format_chrome_single_statement(dev_session):
         s.query("TRACE FORMAT='bogus' SELECT 1")
 
 
-def test_cross_session_trace_c8_storm(tmp_path):
+def test_cross_session_trace_c8_storm(tmp_path, monkeypatch):
     """8 concurrent sessions with tidb_tpu_trace_dir set produce ONE
-    Chrome-trace JSON: parseable, ts monotonic per (pid, tid), with
-    scheduler-queue, compile, upload-stream and eviction events from
-    at least 2 distinct connections."""
+    Chrome-trace JSON: parseable, ts monotonic per (pid, tid) within what
+    one rendering holds, with scheduler-queue, compile, upload-stream and
+    eviction events from at least 2 distinct connections."""
+    # ONE rendering: an event is rendered once, a batch at a time, so a
+    # span open across a rendering lands in the later batch
+    monkeypatch.setattr(timeline, "FLUSH_INTERVAL_S", 3600.0)
+    monkeypatch.setattr(timeline, "RENDER_BATCH", 10 ** 9)
     eng = Engine()
     boot = eng.new_session()
     boot.execute(

@@ -425,27 +425,3 @@ def test_the_readers_reduction_on_a_synthetic_nest():
     for name in ("point_stmt_ms", "index_ms_per_point", "plan_miss_share",
                  "point_parse_ms", "scan_stmt_ms"):
         assert _load("layer_metrics", name).read(ctx) is None
-
-
-def test_the_trace_file_is_written_more_rarely_as_it_grows(monkeypatch,
-                                                          tmp_path):
-    clock = [100.0]
-    monkeypatch.setattr(timeline.time, "monotonic", lambda: clock[0])
-    costs = []
-
-    def flush():
-        costs.append(clock[0])
-        clock[0] += 0.5             # a write that takes half a second
-
-    monkeypatch.setattr(timeline, "flush", flush)
-    timeline.start_global(str(tmp_path))
-    try:
-        for _ in range(400):        # a statement every 0.1 s for 40 s
-            clock[0] += 0.1
-            timeline.flush_if_due()
-    finally:
-        monkeypatch.undo()
-        timeline.stop_global()
-    # first after FLUSH_INTERVAL_S, then 0.5 s / FLUSH_COST_SHARE apart
-    assert len(costs) == 2
-    assert costs[1] - costs[0] >= 0.5 / timeline.FLUSH_COST_SHARE

@@ -494,7 +494,7 @@ def test_a_warm_aggregate_launches_the_same_programs_in_the_same_order(
     def tags(name):
         (e,) = [e for e in evs if e["name"] == name]
         return {k: v for k, v in e["args"].items()
-                if k not in ("req", "id", "parent", "conn")}
+                if k not in ("req", "id", "parent", "conn", "cpu")}
 
     assert got == launches
     assert tags("device.fragment") == frag_tags

@@ -92,7 +92,7 @@ MAX_STEPS = 64
 # one extension at a time: extensions are short (a region diff, one
 # chunk encode and a few mask updates), and serializing them removes the
 # same-entry race where two threads build sibling generations
-_EXT_LOCK = threading.Lock()
+_EXT_LOCK = timeline.named_lock("delta_extend")
 
 
 def _var_on(vars_, name: str, default: str = "on") -> bool:

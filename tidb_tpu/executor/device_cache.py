@@ -273,7 +273,7 @@ _ALIGNED: "OrderedDict[tuple, AlignedJoin]" = OrderedDict()
 # encoding, uploads, LUT builds — happens OUTSIDE the lock; only dict
 # lookups/insertions/evictions are serialized, so concurrent first
 # touches of DIFFERENT tables still overlap.
-_LOCK = threading.RLock()
+_LOCK = timeline.named_lock("device_cache", reentrant=True)
 
 # thread ident → frozenset of (store_id, table_id) pairs that thread's
 # in-flight statement is actively computing on. The per-THREAD successor

@@ -404,7 +404,7 @@ MAX_COMPILED_PROGRAMS = 64
 
 # guards _COMPILE_CACHE / PROGRAM_TRACES / _BUILD_LOCKS — connection
 # threads share one program cache
-_CC_LOCK = threading.RLock()
+_CC_LOCK = timeline.named_lock("compile_cache", reentrant=True)
 # per-signature build locks: two threads cold-compiling the SAME
 # signature serialize (one trace, the loser adopts it); different
 # signatures still compile concurrently
@@ -460,7 +460,7 @@ def _get_or_build(sig: str, kind: str, build):
     lock = _build_lock(sig)
     if not lock.acquire(blocking=False):
         with timeline.span("compile.wait", "compile",
-                           cause=_BUILDING.get(sig, 0)):
+                           cause=_BUILDING.get(sig, 0), wait="build"):
             lock.acquire()
     try:
         prog = _cache_get(sig)      # double-checked: one trace per sig
@@ -930,7 +930,8 @@ def _charge_compile(kind: str, t0: float) -> None:
         cur.note_compile()
     timeline.record(f"compile:{kind}", "compile",
                     dur_us=(time.perf_counter() - t0) * 1e6,
-                    pid=cur.conn_id if cur is not None else 0)
+                    pid=cur.conn_id if cur is not None else 0,
+                    args={"wait": "build"})
 
 
 def get_program(chain, used_cols, in_types, slab_cap, group_cap,

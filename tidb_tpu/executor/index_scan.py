@@ -19,7 +19,6 @@ binary searches, the gather, the residual filters). Always-on counters:
 
 from __future__ import annotations
 
-import threading
 from collections import OrderedDict
 from typing import List, Optional, Tuple
 
@@ -142,7 +141,7 @@ _VIEW_CACHE: "OrderedDict[Tuple, Tuple]" = OrderedDict()
 # host-side caches shared across connection threads: the lock covers the
 # dict operations only (index builds run outside it and commit
 # last-writer-wins — builds are deterministic over the same snapshot)
-_LOCK = threading.Lock()
+_LOCK = timeline.named_lock("index_views")
 
 
 def clear():

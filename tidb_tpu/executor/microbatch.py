@@ -148,7 +148,7 @@ def _follow(batch: _Batch, m: _Member, guard) -> Optional[object]:
     polling. → the demuxed Chunk, or None for individual fallback."""
     t0 = time.monotonic()
     with timeline.span("microbatch.wait", "sched", pid=m.conn_id,
-                       cause=batch.leader_req):
+                       cause=batch.leader_req, wait="queue"):
         while not m.event.wait(POLL_S):
             if guard is None:
                 continue

@@ -362,7 +362,7 @@ class DeviceScheduler:
             # elsewhere, recorded as ending now
             lane = "sched-queue" if cls is None else f"sched-queue:{cls}"
             timeline.record(lane + dev_sfx, "sched", dur_us=waited * 1e6,
-                            pid=conn_id)
+                            pid=conn_id, args={"wait": "queue"})
         with timeline.span("sched-slot" + dev_sfx, "sched", pid=conn_id):
             try:
                 if waited and guard is not None:
@@ -574,7 +574,7 @@ class SchedulerPool:
 
     def __init__(self, n: int = 1,
                  fairness_cap: int = DEFAULT_FAIRNESS_CAP):
-        self._lock = threading.Lock()
+        self._lock = timeline.named_lock("device_pool")
         self.schedulers: List[DeviceScheduler] = [
             DeviceScheduler(i, fairness_cap, pool=self)
             for i in range(max(1, n))]
@@ -953,7 +953,8 @@ def admit_statement(ctx) -> None:
         guard.queue_waits += 1
         timeline.record("sched-queue:batch"
                         + (f"@dev{idx}" if idx else ""), "sched",
-                        dur_us=waited_total * 1e6, pid=conn_id)
+                        dur_us=waited_total * 1e6, pid=conn_id,
+                        args={"wait": "queue"})
 
 
 def device_fault(ctx, err) -> Optional[int]:

@@ -14,16 +14,17 @@ import ctypes
 import logging
 import os
 import subprocess
-import threading
 from typing import List, Optional, Tuple
 
 import numpy as np
+
+from tidb_tpu.util import timeline
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "rowcodec.cpp")
 _LIB = os.path.join(_DIR, "_rowcodec.so")
 
-_lock = threading.Lock()
+_lock = timeline.named_lock("rowcodec")
 _lib = None
 _tried = False
 

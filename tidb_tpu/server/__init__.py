@@ -617,7 +617,7 @@ class _Conn:
                 # the server waits here for the client: device time idle
                 # under this span is the client's, not the server's
                 with timeline.span("client.wait", "client",
-                                   pid=self.conn_id):
+                                   pid=self.conn_id, wait="socket"):
                     pkt = self.read_packet()
             except ConnectionError:
                 return

@@ -382,7 +382,7 @@ class Engine:
         from tidb_tpu.session.auth import AuthManager
         self.catalog = Catalog()
         self.store = Store()
-        self.stats_lock = threading.Lock()
+        self.stats_lock = timeline.named_lock("table_stats")
         # table_id → statistics.TableStats (histograms/NDV/TopN; ref:
         # statistics/handle — the Domain-owned stats cache)
         self.table_stats: Dict[int, object] = {}

@@ -14,10 +14,10 @@ the reference's bootstrap user (session/bootstrap.go)."""
 from __future__ import annotations
 
 import hashlib
-import threading
 from typing import Dict, List, Optional, Set, Tuple
 
 from tidb_tpu.errors import TiDBTPUError
+from tidb_tpu.util import timeline
 
 DEFAULT_DB = "test"      # the engine's single implicit database
 
@@ -56,7 +56,7 @@ class AuthManager:
     hold — sessions run on server threads concurrently."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = timeline.named_lock("auth")
         self.users: Dict[str, bytes] = {"root": b""}
         # user → {(db, tbl) → privileges}
         self.grants: Dict[str, Dict[Tuple[str, str], Set[str]]] = {

@@ -21,7 +21,6 @@ Concurrency model (ref: optimistic txns, session/txn.go + Percolator):
 from __future__ import annotations
 
 import itertools
-import threading
 import time as _time
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -30,6 +29,7 @@ import numpy as np
 
 from tidb_tpu.chunk import Chunk, Column
 from tidb_tpu.errors import DeadlockError, TxnError, UnknownTableError
+from tidb_tpu.util import timeline
 from tidb_tpu.util.observability import REGISTRY
 
 REGION_ROWS = 1 << 16  # region split threshold (ref: TiKV region ~96MB)
@@ -122,7 +122,7 @@ class Store:
     GC_LIFE_SECONDS = 600.0
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = timeline.named_lock("store")
         self._tables: Dict[int, TableData] = {}
         self._region_ids = itertools.count(1)
         self._version = 0

@@ -30,7 +30,6 @@ statement refuses to run and the wire server closes the socket.
 
 from __future__ import annotations
 
-import threading
 import time
 import weakref
 from collections import deque
@@ -38,6 +37,7 @@ from contextlib import contextmanager
 from typing import Dict, Optional
 
 from tidb_tpu.errors import QueryInterrupted, QueryTimeout
+from tidb_tpu.util import timeline
 
 
 class ExecutionGuard:
@@ -143,7 +143,7 @@ class ProcessRegistry:
     info/kill/snapshot, only its dict slot lingers until then."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = timeline.named_lock("processlist")
         self._conns: Dict[int, dict] = {}
         self._dead: deque = deque()     # conn ids queued by finalizers
 

@@ -21,7 +21,6 @@ memtables (infoschema_tables.py)."""
 from __future__ import annotations
 
 import re
-import threading
 import time
 from collections import OrderedDict, deque
 from contextlib import contextmanager
@@ -72,7 +71,7 @@ def hist_quantile(h: list, q: float) -> float:
 
 class Registry:
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = timeline.named_lock("metrics")
         self.counters: Dict[Tuple[str, Tuple], float] = {}
         self.hists: Dict[Tuple[str, Tuple], List] = {}
         self.slow_log: deque = deque(maxlen=256)

@@ -127,7 +127,11 @@ class PhaseTimer:
         """The seconds ledger is keyed by `name` alone. The timeline span
         is called `span` on `lane` (both default to `name`); `sig` (the
         fused pipeline's signature digest on per-slab launches) and `tags`
-        label it."""
+        label it. A `fetch` is a designed wait for the device
+        (`wait=device`: every site is a `device_get`, which blocks until
+        the result is computed and has crossed)."""
+        if name == "fetch":
+            tags["wait"] = "device"
         if sig:
             tags["sig"] = sig
         if self.device_index:
@@ -153,8 +157,10 @@ class PhaseTimer:
 
     def drain(self):
         """`compute` seconds spent waiting for the device to finish what
-        was launched (`block_until_ready`): a `drain` span."""
-        return self.phase("compute", lane="drain", span="drain")
+        was launched (`block_until_ready`): a `drain` span, a designed
+        wait for the device (`wait=device`)."""
+        return self.phase("compute", lane="drain", span="drain",
+                          wait="device")
 
     def glue(self):
         """`compute` seconds spent issuing the eager device work between

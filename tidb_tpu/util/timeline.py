@@ -5,11 +5,16 @@ chrome://tracing / Perfetto).
 ONE recording site, `span(name, lane, **args)`, serves three renderers:
 
   * the cross-session Chrome JSON (`SET tidb_tpu_trace_dir = '/path'`:
-    <dir>/tidb_tpu_trace_<os-pid>.json, written every 5 s (more rarely
-    as it grows) from the statement path, on `flush()` and on
-    `stop_global()` — NOT after every statement; the stopped collector's
-    events stay readable through `last_events()` until the next
-    `start_global`);
+    <dir>/tidb_tpu_trace_<os-pid>.json, written every 5 s from the
+    statement path, on `flush()` and on `stop_global()` — NOT after every
+    statement. An event is rendered ONCE: the statement path renders
+    what is pending a batch at a time (`RENDER_BATCH` events, in `ts`
+    order within the batch), so a statement pays for about its own events
+    and none for a window's; a write renders what is left, and the file
+    write — the whole file again, in pieces of `WRITE_PIECE` bytes,
+    which releases the interpreter's lock — carries the rest. The
+    stopped collector's events stay readable through `last_events()`
+    until the next `start_global`);
   * `TRACE FORMAT='chrome' <stmt>`: a scoped collector for one statement,
     returned as a result row (executor/trace.go's chrome format analog);
   * the `jax.profiler` trace: while a collector is attached every span also
@@ -32,7 +37,40 @@ An event is a Chrome "X" event:
            when no server is above it; the statements of one command share
            it), `id` / `parent` (the enclosing span on the same thread),
            `cause` (the request that did the work this one waited for: a
-           micro-batch leader's launch) and the site's own tags.
+           micro-batch leader's launch), `cpu` and `wait` (below) and the
+           site's own tags.
+
+Waiting told from working. A span's wall time is work plus waiting, and
+under one interpreter lock most of a busy server's wall time is waiting:
+
+  * `cpu`: microseconds THIS THREAD ran between the span's entry and its
+    exit (`time.thread_time_ns`, read beside the wall clock). A span's
+    parent is the enclosing span of the same thread, so on one clock a
+    span's *self CPU* = its `cpu` less its children's, and its *self
+    off-CPU* = its self wall time less its self CPU. Off-CPU means the
+    thread was not running: it waited for a lock, blocked in a call, or
+    handed the work to another thread. It does not say which. Events
+    measured elsewhere (`record`: `sched-queue*`, `jax.*`, `gc.gen2`)
+    carry no `cpu`. The clock is the kernel's, and so are its step and
+    its price: where the kernel ticks thread time coarsely (a sandboxed
+    kernel may, 10 ms a step) ONE span's `cpu` reads 0 or a whole step
+    whatever it ran, and only sums over many spans mean anything — a
+    reducer must not floor or cap span by span; where the read is a real
+    system call it is the dearest thing a span does.
+  * `wait=<kind>`: a span that blocks BY DESIGN says so at its site —
+    `device` (`drain`; `fetch`, whose `device_get` waits for the
+    transfer), `queue` (`sched-queue*`, `microbatch.wait`), `socket`
+    (`client.wait`), `build` (`compile.wait`, `compile:*`,
+    `jax.backend_compile`: the work runs on XLA's threads), `lock`
+    (`lock.wait`: one of the program's own locks, lane `lock`).
+  * so a request's account closes: its `stmt` root = the sum of self CPU
+    (work) + the off-CPU self time of `wait`-tagged spans by kind + the
+    off-CPU self time of untagged spans. The last term is what nothing
+    in the program designed: the wait for the interpreter's lock, plus
+    the operating system's run-queue delay — off-CPU cannot tell those
+    two apart (`/proc/<pid>/task/*/schedstat` can), nor a native call
+    that blocks without a tag (tag it). `benchmarks/span_cpu.py` is the
+    reducer.
 
 Lanes, from packet to packet:
 
@@ -85,10 +123,16 @@ Lanes, from packet to packet:
            rows out: binary searches, gather, residual filters)
   gc       generation-2 garbage collections, while the global collector
            is attached
+  lock     lock.wait (name=<lock>, wait=lock): a CONTENDED acquire of one
+           of the program's own locks on the statement path
+           (`named_lock`: the device cache, the compiled-program cache,
+           the store, the index views, the metrics registry, ...); an
+           uncontended acquire records nothing
 
 Opt-in and zero-cost when off: `span()` returns one shared no-op object
-when `ENABLED` is false (no event, no TraceAnnotation, no clock read), and
-`record()`/`instant()` return at once.
+when `ENABLED` is false (no event, no TraceAnnotation, no clock read),
+`record()`/`instant()`/`tag()` return at once, and a `named_lock` does its
+plain acquire.
 """
 
 from __future__ import annotations
@@ -96,6 +140,7 @@ from __future__ import annotations
 import gc
 import itertools
 import json
+import operator
 import os
 import threading
 import time
@@ -115,7 +160,7 @@ _T0 = time.perf_counter()          # shared epoch for every thread's ts
 # lanes: stable small tids so the viewer groups events the same way run
 # over run; thread_name metadata labels them at flush
 STREAMS = {"sched": 1, "compile": 2, "encode": 3, "upload": 4,
-           "compute": 5, "fetch": 6, "decode": 7, "cache": 8,
+           "fetch": 6, "decode": 7, "cache": 8,
            # staged-exchange per-rank stage lanes: partition (stage 1),
            # checkpoint (stage 2 device→host + host routing), probe
            # (stage 3 receive/probe/dedup)
@@ -123,7 +168,7 @@ STREAMS = {"sched": 1, "compile": 2, "encode": 3, "upload": 4,
            # packet to packet (module docstring)
            "client": 12, "stmt": 13, "wire": 14, "parse": 15, "plan": 16,
            "exec": 17, "frag": 18, "launch": 19, "drain": 20, "gc": 21,
-           "write": 22, "delta": 23, "index": 24}
+           "write": 22, "delta": 23, "index": 24, "lock": 25}
 _OTHER_TID = 31
 
 _GLOBAL: Optional["_Collector"] = None     # tidb_tpu_trace_dir sink
@@ -132,7 +177,10 @@ _LAST: List[dict] = []                     # the stopped global's events
 _SCOPED: List["_Collector"] = []           # TRACE FORMAT='chrome' sinks
 _NEXT_FLUSH = 0.0                          # time.monotonic() of the next
 FLUSH_INTERVAL_S = 5.0                     # statement-path write
-FLUSH_COST_SHARE = 0.02                    # of the time between two writes
+RENDER_BATCH = 512                         # events the statement path
+                                           # renders at once
+WRITE_PIECE = 1 << 23                      # bytes a `write` of the file
+_FLUSH_LOCK = threading.Lock()             # one writer of the file at a time
 
 _REQUEST_IDS = itertools.count(1)
 _SPAN_IDS = itertools.count(1)
@@ -141,11 +189,17 @@ _ANNOTATION = None      # jax.profiler.TraceAnnotation, bound on first use
 
 
 class _Collector:
-    __slots__ = ("events", "dirty")
+    __slots__ = ("events", "dirty", "rendered", "chunks", "lanes")
 
     def __init__(self):
         self.events: List[dict] = []
         self.dirty = False
+        # the global collector's file, kept as rendered: how many of
+        # `events` the chunks hold, one chunk of JSON text per write, and
+        # the (pid, tid) → lane pairs the metadata names
+        self.rendered = 0
+        self.chunks: List[bytes] = []
+        self.lanes: Dict[tuple, str] = {}
 
 
 # JAX's own duration events → `compile`-lane spans: a program is traced,
@@ -160,7 +214,10 @@ _JAX_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "jax.trace",
 def _on_jax_duration(event: str, duration_secs: float, **_kw) -> None:
     name = _JAX_EVENTS.get(event) if ENABLED else None
     if name is not None:
-        record(name, "compile", dur_us=duration_secs * 1e6)
+        # the backend's compile runs on XLA's threads: a designed wait
+        record(name, "compile", dur_us=duration_secs * 1e6,
+               args={"wait": "build"} if name == "jax.backend_compile"
+               else None)
 
 
 def _refresh_enabled() -> None:
@@ -221,7 +278,7 @@ _NO_SPAN = _NoSpan()
 
 class _Span:
     __slots__ = ("name", "lane", "pid", "req", "args", "id", "parent",
-                 "ts", "_ann")
+                 "ts", "cpu", "_ann")
 
     def __init__(self, name, lane, pid, req, args):
         self.name = name
@@ -255,6 +312,7 @@ class _Span:
         stack.append(self)
         req, pid = self.req or 0, self.pid or 0
         self.ts = now_us()
+        self.cpu = time.thread_time_ns()    # both clocks at one place
         # a root also carries the timeline's clock into the profile
         clock = {"ts_us": self.ts} if up is None else {}
         self._ann = _ANNOTATION(f"tidb_tpu/{self.lane}/{self.name}",
@@ -265,6 +323,7 @@ class _Span:
     def __exit__(self, *exc):
         self._ann.__exit__(*exc)
         end = now_us()
+        cpu = time.thread_time_ns() - self.cpu
         stack = _tls.stack
         if self in stack:
             del stack[stack.index(self):]
@@ -272,6 +331,7 @@ class _Span:
         args["req"] = self.req or 0
         args["id"] = self.id
         args["parent"] = self.parent
+        args["cpu"] = round(cpu * 1e-3, 1)  # µs this thread ran inside it
         _append({"name": self.name, "cat": self.lane, "ph": "X",
                  "ts": round(self.ts, 1), "dur": round(end - self.ts, 1),
                  "pid": int(self.pid or 0),
@@ -285,7 +345,9 @@ def span(name: str, lane: str, pid: Optional[int] = None,
     Off → one shared no-op object. On → an "X" event on `lane` with the
     request id, its own id and its parent's (the enclosing span of this
     thread, which also supplies `pid`/`req` where the site has neither),
-    and a `jax.profiler.TraceAnnotation` of the same extent."""
+    `cpu` (µs this thread ran inside it), and a
+    `jax.profiler.TraceAnnotation` of the same extent. A site that blocks
+    by design passes `wait=<kind>` (module docstring)."""
     if not ENABLED:
         return _NO_SPAN
     return _Span(name, lane, pid, req, args)
@@ -299,6 +361,43 @@ def tag(**args) -> None:
     stack = getattr(_tls, "stack", None)
     if stack:
         stack[-1].args.update(args)
+
+
+class _NamedLock:
+    """What `named_lock` hands out: a `threading.Lock` / `RLock` whose
+    CONTENDED acquires are `lock.wait` spans while the recorder is on."""
+    __slots__ = ("name", "_lock", "release")
+
+    def __init__(self, name: str, lock):
+        self.name = name
+        self._lock = lock
+        self.release = lock.release
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if not ENABLED:
+            return self._lock.acquire(blocking, timeout)
+        if self._lock.acquire(False):       # free, or this thread's RLock
+            return True
+        if not blocking:
+            return False
+        with _Span("lock.wait", "lock", None, None,
+                   {"name": self.name, "wait": "lock"}):
+            return self._lock.acquire(True, timeout)
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
+def named_lock(name: str, reentrant: bool = False) -> _NamedLock:
+    """One of the program's own locks on the statement path, under a name.
+    Off, or on and uncontended: the plain acquire, no span, no clock read.
+    On and contended: a `lock.wait` span on lane `lock` (`name=<name>`,
+    `wait=lock`) around the blocking acquire. Behaves as the
+    `threading.Lock` (`reentrant`: `RLock`) it wraps."""
+    return _NamedLock(name,
+                      threading.RLock() if reentrant else threading.Lock())
 
 
 def record(name: str, stream: str, dur_us: float = 0.0, pid: int = 0,
@@ -339,7 +438,8 @@ def record(name: str, stream: str, dur_us: float = 0.0, pid: int = 0,
 
 def instant(name: str, stream: str, pid: int = 0,
             args: Optional[dict] = None) -> None:
-    record(name, stream, pid=pid, ts_us=now_us(), args=args, ph="i")
+    if ENABLED:
+        record(name, stream, pid=pid, ts_us=now_us(), args=args, ph="i")
 
 
 def _gc_event(phase: str, info: dict) -> None:
@@ -401,47 +501,96 @@ def last_events() -> List[dict]:
 
 
 def flush_if_due() -> None:
-    """The statement path's call: write the file at most once every
-    FLUSH_INTERVAL_S, and more rarely as it grows — the file is rewritten
-    whole, under the interpreter's lock, so a write costs what the
-    collector holds (2 s at half a million events: a window of point
-    reads), and the next falls due only when this one has been at most
-    FLUSH_COST_SHARE of the time between them. Between writes it costs
+    """The statement path's call, once a statement: render what has been
+    recorded once RENDER_BATCH events are pending (so a statement pays for
+    about its own events, ≈ 1 ms a batch, and never for a window's), and
+    write the file at most once every FLUSH_INTERVAL_S. Otherwise it costs
     one clock read."""
     global _NEXT_FLUSH
-    if _GLOBAL is None:
+    c = _GLOBAL
+    if c is None:
         return
     t0 = time.monotonic()
-    if t0 < _NEXT_FLUSH:
+    if t0 >= _NEXT_FLUSH:
+        _NEXT_FLUSH = t0 + FLUSH_INTERVAL_S     # no second thread starts one
+        flush()
+    elif (len(c.events) - c.rendered >= RENDER_BATCH
+          and _FLUSH_LOCK.acquire(False)):      # busy: the holder renders
+        try:
+            _render_pending(c)
+        finally:
+            _FLUSH_LOCK.release()
+
+
+def _render_pending(c: _Collector) -> None:
+    """Render what `c` recorded since its last rendering into one more
+    chunk of the file's text, in `ts` order within the chunk, and note the
+    events' lanes. The caller holds `_FLUSH_LOCK`."""
+    with _LOCK:
+        events = c.events[c.rendered:]
+        c.rendered = len(c.events)
+    if not events:
         return
-    _NEXT_FLUSH = t0 + FLUSH_INTERVAL_S     # no second thread starts one
-    flush()
-    done = time.monotonic()
-    _NEXT_FLUSH = done + max(FLUSH_INTERVAL_S,
-                             (done - t0) / FLUSH_COST_SHARE)
+    events.sort(key=operator.itemgetter("ts"))
+    for pid, tid, cat in {(e["pid"], e["tid"], e["cat"]) for e in events}:
+        c.lanes.setdefault((pid, tid), cat)
+    c.chunks.append(json.dumps(events)[1:-1].encode())
+
+
+def _pieces(chunks: List[bytes]) -> List[bytes]:
+    """The rendered chunks joined into pieces of at least WRITE_PIECE
+    bytes (the last may be smaller): every `write` gives the interpreter
+    away and queues to get it back, so the file goes out in few large
+    ones; a full piece is never copied again."""
+    out: List[bytes] = []
+    run: List[bytes] = []
+    size = 0
+    for chunk in chunks:
+        if not run and len(chunk) >= WRITE_PIECE:
+            out.append(chunk)
+            continue
+        run.append(chunk)
+        size += len(chunk)
+        if size >= WRITE_PIECE:
+            out.append(b", ".join(run))
+            run, size = [], 0
+    if run:
+        out.append(b", ".join(run))
+    return out
 
 
 def flush() -> Optional[str]:
     """Write the global collector's events to its JSON file (atomic
     tmp+rename).  → the path, or None when nothing is attached or the
-    write failed."""
-    with _LOCK:
-        if _GLOBAL is None or _GLOBAL_PATH is None or not _GLOBAL.dirty:
-            return _GLOBAL_PATH
-        events = list(_GLOBAL.events)
-        _GLOBAL.dirty = False
-        path = _GLOBAL_PATH
-    body = render(events)
-    try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            f.write(body)
-        os.replace(tmp, path)
-    except OSError:
-        # tracing must never sink the statement that triggered the flush
-        return None
-    return path
+    write failed. An event is rendered ONCE (under the interpreter's
+    lock: the statement path does it a batch at a time, `flush_if_due`,
+    and this renders what is left); the text of the earlier ones is kept
+    and written again as bytes, in pieces of WRITE_PIECE, and a file
+    write releases the lock."""
+    with _FLUSH_LOCK:
+        with _LOCK:
+            c, path = _GLOBAL, _GLOBAL_PATH
+            if c is None or path is None or not c.dirty:
+                return path
+            c.dirty = False
+        _render_pending(c)
+        head = json.dumps(_metadata(c.lanes))[:-1].encode()
+        try:
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            tmp = path + ".tmp"
+            c.chunks = _pieces(c.chunks)
+            with open(tmp, "wb") as f:
+                f.write(b'{"traceEvents": ' + head)
+                for piece in c.chunks:
+                    f.write(b", " + piece)
+                f.write(b'], "displayTimeUnit": "ms"}')
+            os.replace(tmp, path)
+        except OSError:
+            # tracing must never sink the statement that triggered the
+            # flush; what was rendered leaves with the next write
+            c.dirty = True
+            return None
+        return path
 
 
 # ---- scoped capture (TRACE FORMAT='chrome') -------------------------------
@@ -465,6 +614,18 @@ def capture():
         _refresh_enabled()
 
 
+def _metadata(lanes: Dict[tuple, str]) -> List[dict]:
+    """process_name / thread_name events for {(pid, tid): lane}."""
+    meta: List[dict] = []
+    for pid in sorted({p for p, _ in lanes}):
+        meta.append({"name": "process_name", "ph": "M", "pid": pid,
+                     "tid": 0, "args": {"name": f"conn {pid}"}})
+    for (pid, tid), cat in sorted(lanes.items()):
+        meta.append({"name": "thread_name", "ph": "M", "pid": pid,
+                     "tid": tid, "args": {"name": cat}})
+    return meta
+
+
 def render(events: List[dict]) -> str:
     """Chrome-trace JSON: events sorted by ts (so every tid's sequence is
     monotonically non-decreasing) plus process/thread_name metadata."""
@@ -472,18 +633,11 @@ def render(events: List[dict]) -> str:
     seen: Dict[tuple, str] = {}
     for e in ordered:
         seen.setdefault((e["pid"], e["tid"]), e["cat"])
-    meta: List[dict] = []
-    for pid in sorted({p for p, _ in seen}):
-        meta.append({"name": "process_name", "ph": "M", "pid": pid,
-                     "tid": 0, "args": {"name": f"conn {pid}"}})
-    for (pid, tid), cat in sorted(seen.items()):
-        meta.append({"name": "thread_name", "ph": "M", "pid": pid,
-                     "tid": tid, "args": {"name": cat}})
-    return json.dumps({"traceEvents": meta + ordered,
+    return json.dumps({"traceEvents": _metadata(seen) + ordered,
                        "displayTimeUnit": "ms"})
 
 
 __all__ = ["ENABLED", "STREAMS", "FLUSH_INTERVAL_S", "span", "tag", "bind",
-           "record", "instant", "new_request_id", "start_global",
+           "record", "instant", "named_lock", "new_request_id", "start_global",
            "stop_global", "global_path", "last_events", "flush",
            "flush_if_due", "capture", "render", "now_us"]
