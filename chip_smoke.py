@@ -19,8 +19,10 @@ anything but a TPU, when a phase raises, or when any check fails. Each phase
 prints one JSON object; the LAST line of stdout is
 `{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}`.
 
-Phases (one chip): device → load → serve (Q1/Q3/Q5/Q6 cold then warm, one
-acknowledged INSERT read back, its DELETE read back) → check → memory.
+Phases (one chip): device → load → serve (Q1/Q3/Q5/Q6 cold, then the
+digest's second execution — ONE statement program, compiled there — then
+warm; one acknowledged INSERT read back, its DELETE read back) → check →
+memory.
 With `--chips 4`: device → load → pod (Q1/Q3 on the pod-partitioned default
 path and with `tidb_tpu_dist_devices=4`, each against the numpy reference
 and against the same statement pinned to one device) → memory.
@@ -310,11 +312,15 @@ def explain_device(cli, sql: str) -> str:
 
 
 def run_statement(cli, meter: CompileMeter, name: str, sql: str,
-                  expect, reps=("cold", "warm"), resident=True) -> dict:
+                  expect, reps=("cold", "second", "warm"),
+                  resident=True) -> dict:
     """Run `sql` once per rep over the wire; check every answer against
     `expect`, that the device ran it, and that the warm rep compiled
     nothing and — where the path serves from `resident` tables — uploaded
-    nothing. → the printed record."""
+    nothing. (The cold rep launches a program a slab and settles the
+    capacities; the second runs the whole statement as ONE program, which
+    it compiles; from the third on a statement is warm.) → the printed
+    record."""
     from tidb_tpu.executor import fragment
     rec: dict = {"statement": name}
     fb0 = fallbacks_total(cli)

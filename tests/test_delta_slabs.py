@@ -145,6 +145,7 @@ def test_delta_version_in_plan_keys():
     e0 = _entry(eng)
     s.query("INSERT INTO t VALUES (3, 1234, 'k2')")
     s.query(Q)                  # first delta generation: its programs
+    s.query(Q)                  # … and, specialized, its statement program
     e1 = _entry(eng)
     assert e1.delta_version > e0.delta_version
     assert e1.lineage == e0.lineage
@@ -575,8 +576,11 @@ def test_a_compaction_that_changes_a_layout_compiles_nothing_in_a_statement(
         eager_compaction):
     """Unordered dates folded into the base end the `delta` layout of `d`,
     so every statement that reads it has new programs: the compactor's
-    warm-up traces them before the swap, the statements after it none —
-    neither over the swapped generation nor over its next extension."""
+    warm-up traces them before the swap — the slab programs and, run
+    twice under the statement's text, the ONE statement program of a
+    specialized digest — and the statements after it none: neither over
+    the swapped generation nor over its next extension, neither at their
+    first execution there nor at their second."""
     from tidb_tpu.executor import fragment
     eng, s = _sorted_engine()
     s.vars["tidb_tpu_compaction"] = "off"
@@ -597,11 +601,11 @@ def test_a_compaction_that_changes_a_layout_compiles_nothing_in_a_statement(
     assert ent1.lineage != ent0.lineage and "delta" not in {
         l.kind for l in ent1.layouts.values() if l is not None}
     t1, dec0 = fragment.PROGRAM_TRACES, _declines()
-    for q in (QF, QF_PRUNED, QF_GROUPED):
+    for q in (QF, QF_PRUNED, QF_GROUPED) * 2:
         assert s.query(q).rows == _oracle(s, q), q
     s.query("INSERT INTO f VALUES (6000, '1996-02-02', 5)")
     s.query("DELETE FROM f WHERE k = 17")
-    for q in (QF, QF_PRUNED, QF_GROUPED):
+    for q in (QF, QF_PRUNED, QF_GROUPED) * 2:
         assert s.query(q).rows == _oracle(s, q), q
     assert fragment.PROGRAM_TRACES == t1 and _declines() == dec0
 

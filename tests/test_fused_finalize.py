@@ -196,18 +196,22 @@ def test_explain_analyze_counts_finalize_as_one_launch():
     try:
         s.query(sql)                       # cold: trace + first touch
         # the spec key pins RAW SQL (literals are trace constants), so
-        # the EA statement is its own shape: run it once cold, then
-        # assert on its warm repetition
+        # the EA statement is its own shape: its first execution launches
+        # a program a slab, and the fused finalize counts as exactly ONE
+        # program over the slabs
         s.query("EXPLAIN ANALYZE " + sql)
+        ph = s.last_guard.phases
+        assert ph.programs_launched == ph.fused_pipelines + 1 == 4, \
+            ph.summary()
+        # ... then assert on its warm repetition: ONE statement program
         ea = s.query("EXPLAIN ANALYZE " + sql).rows
         text = " ".join(str(c) for r in ea for c in r)
         m = re.search(r"launches=(\d+)", text)
         assert m, f"no launches= in EXPLAIN ANALYZE: {text}"
         ph = s.last_guard.phases
-        # byte-exact vs the ledger of the EA execution itself, and the
-        # fused finalize counts as exactly ONE program over the slabs
+        # byte-exact vs the ledger of the EA execution itself
         assert int(m.group(1)) == ph.programs_launched
-        assert ph.programs_launched == ph.fused_pipelines + 1, ph.summary()
+        assert ph.programs_launched == ph.fused_pipelines == 1, ph.summary()
         sh = re.search(r"spec_hits=(\d+)", text)
         assert sh and int(sh.group(1)) == ph.specialization_hits
         assert ph.specialization_hits >= 1, \
@@ -224,7 +228,8 @@ def test_explain_analyze_counts_finalize_as_one_launch():
 def test_a_removed_gate_is_an_unknown_variable(name):
     """The three gates are gone: SET stores the name as it stores any
     unknown variable, and an ORDER BY over a join's aggregate still runs
-    slabs + 1 programs, specialized, its join aligned."""
+    slabs + 1 programs at its first execution and ONE statement program
+    once specialized, its join aligned."""
     eng = Engine()
     s = eng.new_session()
     s.execute("CREATE TABLE gd (id INT PRIMARY KEY, name VARCHAR(16))")
@@ -258,10 +263,10 @@ def test_a_removed_gate_is_an_unknown_variable(name):
     kinds = ["partial_fused"] * 3 + ["finalize"]
     try:
         assert ledger() == (4, 0, kinds, 1)     # cold: trace, first touch
-        assert ledger() == (4, 1, kinds, 1)
+        assert ledger() == (1, 1, ["stmt_fused"], 1)
         s.execute(f"SET {name} = 'off'")
         assert s.vars[name] == "off"
-        assert ledger() == (4, 1, kinds, 1)
+        assert ledger() == (1, 1, ["stmt_fused"], 1)
     finally:
         eng.close()
         device_cache.clear()
@@ -316,7 +321,8 @@ def test_specialization_distinguishes_literals():
 
 
 # ---------------------------------------------------------------------------
-# perf pins: slabs + 1 warm launches, zero retrace on a repeated digest
+# perf pins: slabs + 1 launches at a digest's first execution, ONE warm,
+# zero retrace on a repeated digest
 # ---------------------------------------------------------------------------
 
 @pytest.mark.perf_smoke
@@ -324,18 +330,22 @@ def test_specialization_distinguishes_literals():
                                  ORDER_SHAPES[4]],
                          ids=["order-null-key", "order-string",
                               "topn-agg-key"])
-def test_warm_whole_query_is_slabs_plus_one(sql):
+def test_whole_query_is_slabs_plus_one_then_one_launch(sql):
     _, s = agg_fixture()
     s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
                    "tidb_tpu_max_slab_rows": 1024})   # 3 slabs
     try:
+        frag_mod._SPEC_CACHE.clear()
         cold = s.query(sql).rows
+        ph = s.last_guard.phases
+        assert ph.fused_pipelines == 3, ph.summary()
+        assert ph.programs_launched == ph.fused_pipelines + 1, ph.summary()
+        assert s.query(sql).rows == cold    # traces the statement program
         traces = frag_mod.PROGRAM_TRACES
         for _ in range(2):
             assert s.query(sql).rows == cold
             ph = s.last_guard.phases
-            assert ph.fused_pipelines == 3, ph.summary()
-            assert ph.programs_launched <= ph.fused_pipelines + 1, \
+            assert ph.programs_launched == ph.fused_pipelines == 1, \
                 ph.summary()
             assert ph.specialization_hits >= 1, ph.summary()
         assert frag_mod.PROGRAM_TRACES == traces, \

@@ -136,16 +136,17 @@ def test_fused_join_launch_accounting():
     _, s = join_fixture()
     s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
                    "tidb_tpu_max_slab_rows": 1024})
-    cpu_rows = None
-    for _ in range(2):             # cold then warm — same counts
-        rows = s.query(Q3_SHAPE).rows
-        cpu_rows = cpu_rows or rows
-        assert rows == cpu_rows
+    frag_mod._SPEC_CACHE.clear()
+    cpu_rows = s.query(Q3_SHAPE).rows
+    ph = s.last_guard.phases
+    # a digest's first execution: 3 probe slabs × 1 fused program + 1 root
+    # merge
+    assert ph.fused_pipelines == 3, ph.summary()
+    assert ph.programs_launched == 4, ph.summary()
+    for _ in range(2):             # specialized: ONE statement program
+        assert s.query(Q3_SHAPE).rows == cpu_rows
         ph = s.last_guard.phases
-        # 3 probe slabs × 1 fused program + 1 root merge
-        assert ph.fused_pipelines == 3, ph.summary()
-        assert ph.programs_launched == 4, ph.summary()
-        assert ph.programs_launched <= 2 * ph.fused_pipelines
+        assert ph.fused_pipelines == ph.programs_launched == 1, ph.summary()
 
 
 def test_statements_summary_matches_phase_ledger():
@@ -179,7 +180,7 @@ def test_statements_summary_matches_phase_ledger():
 
 
 # ---------------------------------------------------------------------------
-# warm repeat: zero retraces, ≤2 launches per slab
+# warm repeat: zero retraces, ONE launch (first execution: ≤2 per slab)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.perf_smoke
@@ -187,13 +188,17 @@ def test_fused_warm_repeat_zero_retrace_two_launches_per_slab():
     _, s = join_fixture()
     s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
                    "tidb_tpu_max_slab_rows": 1024})
+    frag_mod._SPEC_CACHE.clear()
     cold = s.query(STR_KEY).rows
+    ph = s.last_guard.phases
+    assert ph.fused_pipelines == 3, ph.summary()
+    assert ph.programs_launched <= 2 * ph.fused_pipelines, ph.summary()
+    assert s.query(STR_KEY).rows == cold    # traces the statement program
     traces = frag_mod.PROGRAM_TRACES
     for _ in range(3):
         assert s.query(STR_KEY).rows == cold
         ph = s.last_guard.phases
-        assert ph.fused_pipelines == 3, ph.summary()
-        assert ph.programs_launched <= 2 * ph.fused_pipelines, ph.summary()
+        assert ph.fused_pipelines == ph.programs_launched == 1, ph.summary()
     assert frag_mod.PROGRAM_TRACES == traces, \
         "warm fused repeat must not retrace"
 

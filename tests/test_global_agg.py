@@ -244,16 +244,19 @@ def test_global_aggregate_over_delta_slab_and_tombstones(tmp_path,
 
 def test_partials_counter_counts_slabs_for_global_and_none_for_grouped(
         session, tmp_path, monkeypatch):
-    """`tidb_tpu_agg_partials_total{grouping="global"}` rises by the slabs
-    launched for a warm Q6-shaped statement, and by 0 for a grouped one
-    (which counts under its own grouping)."""
+    """`tidb_tpu_agg_partials_total{grouping="global"}` rises by the
+    programs launched with a partial in them — a slab each at a Q6-shaped
+    statement's first execution, ONE statement program from its second on
+    — and by 0 for a grouped one (which counts under its own grouping)."""
     vars_ = {"tidb_tpu_max_slab_rows": 1024}
-    run_checked(session, SHAPES["q6_shaped"], tmp_path, monkeypatch, **vars_)
+    fragment._SPEC_CACHE.clear()
+    _, first = run_checked(session, SHAPES["q6_shaped"], tmp_path,
+                           monkeypatch, **vars_)
     _, warm = run_checked(session, SHAPES["q6_shaped"], tmp_path,
                           monkeypatch, **vars_)
     # a >= 500 AND a < 4000 over slabs of 1024 rows in insertion order:
     # the zone maps keep slabs 0..3 of 5
-    assert warm == 4
+    assert (first, warm) == (4, 1)
     s = session
     s.vars.update(tidb_tpu_engine="on", tidb_tpu_row_threshold=1, **vars_)
     try:

@@ -74,7 +74,8 @@ def test_warm_concurrency_zero_retraces_zero_reuploads(session):
     import threading
     eng, s = session
     rows_cold = s.query(SQL).rows          # cold: trace + first touch
-    ent = _entry(eng)
+    assert s.query(SQL).rows == rows_cold  # specialized: the statement
+    ent = _entry(eng)                      # program's trace
     dev_ids = {i: [id(v) for v, _m in slabs]
                for i, slabs in ent.dev.items()}
     traces = fragment.PROGRAM_TRACES
@@ -135,11 +136,12 @@ def test_repeat_query_zero_retraces_and_no_reupload(session):
 
 
 def test_warm_selective_scan_launches_only_surviving_slabs():
-    """Zone-map slab skipping on the warm path: a selective predicate
-    over a sorted column launches exactly `surviving_slabs + 1` programs
-    (one partial per surviving slab + the merge), re-uploads ZERO bytes,
-    and the Chrome trace carries NO compute spans for the skipped slabs
-    — the skip is free, not merely cheap."""
+    """Zone-map slab skipping: a selective predicate over a sorted column
+    launches exactly `surviving_slabs + 1` programs at its first execution
+    (one partial per surviving slab + the merge) and ONE statement program
+    over the surviving slabs once warm, re-uploads ZERO bytes, and the
+    Chrome trace carries NO compute spans for the skipped slabs — the skip
+    is free, not merely cheap."""
     import json
     eng = Engine()
     eng.global_vars["tidb_enable_auto_analyze"] = False
@@ -152,7 +154,10 @@ def test_warm_selective_scan_launches_only_surviving_slabs():
     s.vars["tidb_tpu_max_slab_rows"] = 1024   # 3 slabs, sorted → partitioned
     sel = "SELECT COUNT(*), SUM(a) FROM q WHERE a >= 1024"
     full = "SELECT COUNT(*), SUM(a) FROM q"
+    fragment._SPEC_CACHE.clear()
     rows_cold = s.query(sel).rows              # cold: encode + upload
+    surviving = 2
+    assert s.last_guard.phases.programs_launched == surviving + 1
     tid = eng.catalog.info_schema.table("q").id
     ent = next(e for (_d, sid, t, _p), e in dc._CACHE.items()
                if sid == id(eng.store) and t == tid)
@@ -162,14 +167,14 @@ def test_warm_selective_scan_launches_only_surviving_slabs():
         "cold prune must leave holes, not upload pruned slabs"
     dev_ids = {i: [None if t is None else id(t[0]) for t in slabs]
                for i, slabs in ent.dev.items()}
+    assert s.query(sel).rows == rows_cold      # traces the two-slab program
     traces = fragment.PROGRAM_TRACES
 
     rows_warm = s.query(sel).rows
     assert rows_warm == rows_cold
     ph = s.last_guard.phases
     assert ph.slabs_skipped == 1, "slab 0 (a in [0,1023]) must be pruned"
-    surviving = 2
-    assert ph.programs_launched == surviving + 1, \
+    assert ph.programs_launched == 1, \
         f"warm selective launches: {ph.programs_launched}"
     assert ph.h2d_bytes == 0 and ph.as_dict()["upload_s"] == 0.0
     assert fragment.PROGRAM_TRACES == traces, "warm repeat re-traced"
@@ -193,14 +198,17 @@ def test_warm_selective_scan_launches_only_surviving_slabs():
 def test_warm_read_after_appends_no_base_reupload_one_extra_launch(session):
     """The HTAP write-path pin: K single-row appends between two warm
     reads must cost the reader ONE delta-slab upload and at most ONE
-    extra program launch — ZERO base slabs re-encoded or re-uploaded
-    (they are shared by identity across delta generations), and the
-    second warm read uploads nothing at all."""
+    extra program launch over a first execution's (the delta slab's
+    partial) — ZERO base slabs re-encoded or re-uploaded (they are shared
+    by identity across delta generations), and the second warm read
+    uploads nothing at all and is ONE launch again."""
     eng, s = session
     s.vars["tidb_tpu_compaction"] = "off"     # no async rebuild mid-test
+    fragment._SPEC_CACHE.clear()
     s.query(SQL)                               # cold: trace + first touch
+    base_launches = s.last_guard.phases.programs_launched   # slabs + 1
     s.query(SQL)                               # warm baseline
-    base_launches = s.last_guard.phases.programs_launched
+    assert s.last_guard.phases.programs_launched == 1
     ent = _entry(eng)
     n_base = ent.base_slabs
     base_ids = {i: [id(t[0]) for t in slabs[:n_base] if t is not None]
@@ -228,7 +236,7 @@ def test_warm_read_after_appends_no_base_reupload_one_extra_launch(session):
     ph2 = s.last_guard.phases
     assert ph2.h2d_bytes == 0 and ph2.as_dict()["upload_s"] == 0.0, \
         "second warm read after appends must upload nothing"
-    assert ph2.programs_launched <= base_launches + 1
+    assert ph2.programs_launched == 1
     assert sorted(map(str, rows2)) == sorted(map(str, rows))
     # and the rows are RIGHT: the appended 'ant' rows are visible
     got = {r[0]: r[1] for r in rows}

@@ -96,6 +96,13 @@ def test_q1_q3_q5_over_several_slabs(ds, shaped, on_the_matrix_unit,
             assert grew.pop(lowering) == 1, (q, grew)
             assert set(grew) <= {"masked"} and sum(grew.values()) \
                 == len(tags) - 1 <= fragment.PROGRAM_TRACES - traces
+            # the digest's second execution traces ONE statement program,
+            # a body a surviving slab: the lowering is said once a body
+            before = _lowerings()
+            assert _text(s.query(sql).rows) == rows
+            grew = _grew(before)
+            assert 1 <= grew.pop(lowering) <= 4, (q, grew)
+            assert set(grew) <= {"masked"}, (q, grew)
             # warm: nothing traces, so nothing is said
             before = _lowerings()
             timeline.start_global(str(tmp_path))

@@ -389,72 +389,84 @@ def _slab_session():
 
 
 _JOIN = "FROM gf f JOIN gd d ON f.b = d.id "
-# What a warm aggregate over three slabs shows the span recorder: its
-# launches in order (program, slab, `sig` tag), the tags of its
-# `device.fragment` and `frag.merge` spans, its `jax.device_get` calls. The
-# program names are digests of the compile-cache signatures, which key the
-# persistent compile cache on the chip: a change to the driver that is not
-# meant to recompile anything leaves them byte for byte.
+# What an aggregate over three slabs shows the span recorder. Its FIRST
+# execution launches a program a slab and the merge or finalize (program,
+# slab, `sig` tag, in order); once the digest is specialized a statement the
+# driver can run whole is ONE launch, `stmt_<kind>_<sig8>` (`launch_plan`
+# `whole`: no `frag.merge` span, there is no merge launch), and one it cannot
+# (DISTINCT pair sets, sorted runs) launches the same programs again. Beside
+# them the tags of the `device.fragment` and `frag.merge` spans and the
+# `jax.device_get` calls. The program names are digests of the compile-cache
+# signatures, which key the persistent compile cache on the chip: a change to
+# the driver that is not meant to recompile anything leaves them byte for
+# byte.
 _SLOTS = {"slots_out": 9, "slots_in": 27}
 _RUNS = {"slots_in": 0, "slots_out": 1024, "rows": 3072}
 
 
 def _frag(root, key_bytes, state_bytes=24, grouping="bounds", gcap=9,
-          groups=8):
+          groups=8, plan="whole"):
     return {"root": root, "spec": "hit", "grouping": grouping, "gcap": gcap,
             "rows_in": 3000, "groups": groups, "key_bytes": key_bytes,
-            "state_bytes": state_bytes}
+            "state_bytes": state_bytes, "launch_plan": plan}
 
 
 # case → (statement, slab program, its `sig` tag, the launches after the
-# slabs, `device.fragment` tags, `frag.merge` tags, device_gets)
+# slabs, the ONE warm launch or None where the slabs launch again,
+# `device.fragment` tags, `frag.merge` tags, device_gets)
 PINNED = {
     "chain": (
         "SELECT b, COUNT(*), SUM(v) FROM gf GROUP BY b",
         "partial_chain_7b01d280", None, [("merge_7b01d280", None)],
+        ("stmt_chain_fac53939", "stmt:fac53939afd1"),
         _frag("HashAgg", 9), _SLOTS, 1),
     "chain-topn": (
         "SELECT b, COUNT(*), SUM(v) FROM gf GROUP BY b "
         "ORDER BY SUM(v) DESC LIMIT 3",
         "partial_chain_7b01d280", None,
         [("finalize_94637dac", "fused-final:94637dac7685")],
+        ("stmt_chain_d3fbc7c6", "stmt:d3fbc7c6433c"),
         _frag("TopN", 9), _SLOTS, 1),
     "chain-distinct": (
         "SELECT b, COUNT(DISTINCT v) FROM gf GROUP BY b ORDER BY b",
         "partial_chain_3379760e", None,
-        [("finalize_d4301d6a", "fused-final:d4301d6a8439")],
-        _frag("Sort", 9, 8), _SLOTS, 3),
+        [("finalize_d4301d6a", "fused-final:d4301d6a8439")], None,
+        _frag("Sort", 9, 8, plan="slabs:pairs"), _SLOTS, 3),
     "tree": (
         "SELECT d.name, COUNT(*), SUM(f.v) " + _JOIN + "GROUP BY d.name",
         "partial_fused_efb25ddf", "fused:efb25ddf5808",
         [("merge_aeb0628b", None)],
+        ("stmt_fused_648bceef", "stmt:648bceef2d8a"),
         _frag("HashAgg", 5), _SLOTS, 1),
     "tree-sort": (
         "SELECT d.name, COUNT(*), SUM(f.v) " + _JOIN +
         "GROUP BY d.name ORDER BY d.name",
         "partial_fused_efb25ddf", "fused:efb25ddf5808",
         [("finalize_4894e43c", "fused-final:4894e43cbfe4")],
+        ("stmt_fused_6ea650cf", "stmt:6ea650cf7368"),
         _frag("Sort", 5), _SLOTS, 1),
     "tree-distinct": (
         "SELECT d.name, COUNT(DISTINCT f.v) " + _JOIN +
         "GROUP BY d.name ORDER BY d.name",
         "partial_fused_28ce7b9a", "fused:28ce7b9ae17f",
-        [("finalize_c28bcac4", "fused-final:c28bcac4ba4c")],
-        _frag("Sort", 5, 8), _SLOTS, 3),
+        [("finalize_c28bcac4", "fused-final:c28bcac4ba4c")], None,
+        _frag("Sort", 5, 8, plan="slabs:pairs"), _SLOTS, 3),
     "runs-chain": (
         "SELECT k, COUNT(*), SUM(v) FROM gf GROUP BY k "
         "ORDER BY SUM(v) DESC, k LIMIT 5",
         "partial_chain_327230ad", None,
         [("sort_rows_2a418386", None),
-         ("finalize_31666253", "fused-final:31666253c2ee")],
-        _frag("TopN", 9, grouping="runs", gcap=1024, groups=300), _RUNS, 1),
+         ("finalize_31666253", "fused-final:31666253c2ee")], None,
+        _frag("TopN", 9, grouping="runs", gcap=1024, groups=300,
+              plan="slabs:runs"), _RUNS, 1),
     "runs-tree": (
         "SELECT f.k, COUNT(*), SUM(f.v) " + _JOIN + "GROUP BY f.k "
         "ORDER BY SUM(f.v) DESC, f.k LIMIT 5",
         "partial_fused_38c9441f", "fused:38c9441f33af",
         [("sort_rows_2a418386", None),
-         ("finalize_69c85889", "fused-final:69c85889d279")],
-        _frag("TopN", 9, grouping="runs", gcap=1024, groups=300), _RUNS, 1),
+         ("finalize_69c85889", "fused-final:69c85889d279")], None,
+        _frag("TopN", 9, grouping="runs", gcap=1024, groups=300,
+              plan="slabs:runs"), _RUNS, 1),
 }
 
 
@@ -463,17 +475,27 @@ def test_a_warm_aggregate_launches_the_same_programs_in_the_same_order(
         case, monkeypatch):
     from tidb_tpu.executor import fragment
     from tidb_tpu.ops.jax_env import jax
-    sql, slab_prog, slab_sig, tail, frag_tags, merge_tags, device_gets = \
-        PINNED[case]
-    launches = [(slab_prog, i, slab_sig) for i in range(3)] + \
+    sql, slab_prog, slab_sig, tail, whole, frag_tags, merge_tags, \
+        device_gets = PINNED[case]
+    by_slab = [(slab_prog, i, slab_sig) for i in range(3)] + \
         [(name, None, sig) for name, sig in tail]
     if frag_tags["grouping"] == "runs":
         monkeypatch.setattr(fragment, "SLOT_ADDRESS_CAP", 64)
     eng, s = _slab_session()
+
+    def launches(cap):
+        evs = sorted((e for e in cap.events if e["ph"] == "X"),
+                     key=lambda e: e["ts"])
+        return evs, [(e["name"], e["args"]["slab"], e["args"].get("sig"))
+                     for e in evs if e["cat"] == "launch"]
+
     try:
         want = eng.new_session().query(sql).rows
-        for _ in range(2):                  # compile, then specialize
+        fragment._SPEC_CACHE.clear()        # the first execution of a digest
+        with timeline.capture() as cap:
             s.query(sql)
+        assert launches(cap)[1] == by_slab
+        s.query(sql)                        # specialized: compiles `whole`
         calls = []
         real = jax.device_get
         with monkeypatch.context() as m, timeline.capture() as cap:
@@ -486,19 +508,20 @@ def test_a_warm_aggregate_launches_the_same_programs_in_the_same_order(
     if "ORDER BY" not in sql:
         rows, want = sorted(rows), sorted(want)
     assert rows == want
-    evs = sorted((e for e in cap.events if e["ph"] == "X"),
-                 key=lambda e: e["ts"])
-    got = [(e["name"], e["args"]["slab"], e["args"].get("sig"))
-           for e in evs if e["cat"] == "launch"]
+    evs, got = launches(cap)
 
     def tags(name):
         (e,) = [e for e in evs if e["name"] == name]
         return {k: v for k, v in e["args"].items()
                 if k not in ("req", "id", "parent", "conn", "cpu")}
 
-    assert got == launches
     assert tags("device.fragment") == frag_tags
-    assert tags("frag.merge") == merge_tags
+    if whole is not None:
+        assert got == [(whole[0], None, whole[1])]
+        assert not [e for e in evs if e["name"] == "frag.merge"]
+    else:
+        assert got == by_slab
+        assert tags("frag.merge") == merge_tags
     assert len(calls) == device_gets
     assert len([e for e in evs if e["cat"] == "fetch"]) == device_gets
     assert len([e for e in evs if e["cat"] == "drain"]) == 1

@@ -1,0 +1,457 @@
+"""One launch a warm aggregate statement (`fragment._StatementProgram`).
+
+Once an earlier execution of a statement's digest has settled its
+capacities and every slab is resident, `_run_agg_slabs` issues the slab
+bodies and the merge or fused finalize as ONE traced program under ONE hold
+of the batch slot (`launch_plan=whole` on the `device.fragment` span,
+`tidb_tpu_statement_programs_total{plan=whole}`); the loop over slabs stays
+the cold and the escalating path (`launch_plan=slabs:<why>`). These tests
+pin: the answers of both plans are the host's, over chains and join trees,
+under zone-map pruning, over a delta generation (delta slab, liveness
+masks) and over one slab; the second execution of a digest is whole, the
+third launches exactly one program and traces nothing; an overflow read
+back from a statement program is answered by the per-slab driver; sorted
+runs, DISTINCT pair sets and a cold stream never leave it; and a cached
+statement program holds nothing of the statement that built it; and a
+DOUBLE sum is the per-slab driver's to the bit.
+"""
+
+import functools
+
+import pytest
+
+from tidb_tpu.executor import device_cache as dc
+from tidb_tpu.executor import fragment
+from tidb_tpu.executor.tree_fragment import TreeProgram
+from tidb_tpu.session import Engine
+from tidb_tpu.util import failpoint, timeline
+from tidb_tpu.util.observability import REGISTRY
+
+ROWS, SLAB = 3000, 1024             # three slabs
+
+
+@pytest.fixture
+def db():
+    eng = Engine()
+    eng.global_vars["tidb_enable_auto_analyze"] = False
+    s = eng.new_session()
+    s.execute("CREATE TABLE d (id INT PRIMARY KEY, name VARCHAR(8))")
+    s.execute("INSERT INTO d VALUES " + ",".join(
+        f"({i}, 'n{i % 5}')" for i in range(8)))
+    # `a` ascends, so zone maps can skip slabs; `k` has 300 values
+    s.execute("CREATE TABLE f (a BIGINT, k BIGINT, b INT, v BIGINT, "
+              "c VARCHAR(8))")
+    s.execute("INSERT INTO f VALUES " + ",".join(
+        f"({i}, {i % 300}, {i % 8}, {(i * 37) % 211 - 100}, 'c{i % 3}')"
+        for i in range(ROWS)))
+    s.execute("ANALYZE TABLE d")
+    s.execute("ANALYZE TABLE f")
+    s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
+                   "tidb_tpu_max_slab_rows": SLAB,
+                   "tidb_tpu_compaction": "off"})
+    fragment._SPEC_CACHE.clear()
+    yield eng, s
+    eng.close()
+    dc.clear()
+
+
+def oracle(s, sql):
+    s.vars["tidb_tpu_engine"] = "off"
+    try:
+        return s.query(sql).rows
+    finally:
+        s.vars["tidb_tpu_engine"] = "on"
+
+
+def run(s, sql):
+    """One execution → (rows, launch plan, launched program names, traces)."""
+    traces = fragment.PROGRAM_TRACES
+    with timeline.capture() as cap:
+        rows = s.query(sql).rows
+    assert s.last_engine == "tpu", sql
+    evs = [e for e in cap.events if e["ph"] == "X"]
+    (plan,) = [e["args"]["launch_plan"] for e in evs
+               if e["name"] == "device.fragment"
+               and "launch_plan" in e["args"]]
+    return (rows, plan,
+            [e["name"] for e in sorted(evs, key=lambda e: e["ts"])
+             if e["cat"] == "launch"],
+            fragment.PROGRAM_TRACES - traces)
+
+
+def same(rows, want, sql):
+    if "ORDER BY" not in sql:
+        rows, want = sorted(map(str, rows)), sorted(map(str, want))
+    assert rows == want, sql
+
+
+def thrice(s, sql, first="slabs:", slabs=3):
+    """A digest's first three executions: a launch a slab (+ the tail),
+    then the statement program (traced there, unless an earlier test left
+    it in the process's compile cache), then exactly ONE launch and no
+    trace; every answer the host's. → the statement program's name."""
+    want = oracle(s, sql)
+    rows, plan, launched, _t = run(s, sql)
+    same(rows, want, sql)
+    assert plan.startswith(first), plan
+    assert slabs <= len(launched) <= slabs + 1, launched
+    rows, plan, launched, _t = run(s, sql)
+    same(rows, want, sql)
+    assert plan == "whole" and len(launched) == 1
+    rows, plan, again, traced = run(s, sql)
+    same(rows, want, sql)
+    assert (plan, again, traced) == ("whole", launched, 0)
+    assert s.last_guard.phases.programs_launched == 1
+    return launched[0]
+
+
+_JOIN = "FROM f JOIN d ON f.b = d.id "
+STATEMENTS = {
+    "chain-bounds": "SELECT b, COUNT(*), SUM(v) FROM f GROUP BY b",
+    "chain-global": "SELECT COUNT(*), SUM(v), MIN(a), AVG(k) FROM f "
+                    "WHERE v > -50",
+    "chain-factorize": "SELECT v % 7, COUNT(*), MAX(a) FROM f "
+                       "GROUP BY v % 7",
+    "chain-string-key": "SELECT c, COUNT(*), SUM(v) FROM f GROUP BY c "
+                        "ORDER BY c",
+    "chain-topn": "SELECT b, COUNT(*), SUM(v) FROM f GROUP BY b "
+                  "ORDER BY SUM(v) DESC, b LIMIT 3",
+    "chain-rollup": "SELECT b, c, COUNT(*) FROM f GROUP BY b, c "
+                    "WITH ROLLUP",
+    "tree-bounds": "SELECT d.name, COUNT(*), SUM(f.v) " + _JOIN +
+                   "GROUP BY d.name",
+    "tree-sort": "SELECT d.name, COUNT(*), SUM(f.v) " + _JOIN +
+                 "GROUP BY d.name ORDER BY d.name",
+    "tree-global": "SELECT COUNT(*), SUM(f.v) " + _JOIN +
+                   "WHERE d.name <> 'n1'",
+    "tree-factorize": "SELECT f.v % 5, MAX(d.id), COUNT(*) " + _JOIN +
+                      "GROUP BY f.v % 5",
+}
+
+
+@pytest.mark.parametrize("case", sorted(STATEMENTS))
+def test_whole_statement_answers_equal_the_per_slab_drivers(db, case):
+    _, s = db
+    name = thrice(s, STATEMENTS[case])
+    kind = "stmt_fused" if case.startswith("tree") else "stmt_chain"
+    assert name.startswith(kind + "_"), name
+
+
+def test_the_plan_is_tagged_and_counted(db):
+    """`launch_plan` on the `device.fragment` span and
+    `tidb_tpu_statement_programs_total{plan=}`: a statement counts once,
+    under the plan that answered it."""
+    _, s = db
+
+    def counted():
+        return {p: REGISTRY.counters.get(
+            ("tidb_tpu_statement_programs_total", (("plan", p),)), 0)
+            for p in ("whole", "slabs")}
+
+    sql = STATEMENTS["chain-bounds"]
+    before = counted()
+    plans = [run(s, sql)[1] for _ in range(3)]
+    assert plans == ["slabs:cold", "whole", "whole"]
+    after = counted()
+    assert after["slabs"] - before["slabs"] == 1
+    assert after["whole"] - before["whole"] == 2
+    # a second statement over the now resident table misses only the
+    # specialization
+    assert run(s, STATEMENTS["chain-topn"])[1] == "slabs:spec-miss"
+
+
+DOUBLES = {
+    "sum-avg": "SELECT c, COUNT(*), SUM(x), AVG(x) FROM g GROUP BY c",
+    "global": "SELECT SUM(x), AVG(x), COUNT(*) FROM g WHERE a % 3 <> 1",
+    "topn": "SELECT b, SUM(x) FROM g GROUP BY b ORDER BY SUM(x) DESC, b "
+            "LIMIT 4",
+    "variance": "SELECT c, VAR_POP(x), STDDEV_SAMP(x) FROM g GROUP BY c",
+    "tree": "SELECT d.name, SUM(g.x), AVG(g.x) FROM g JOIN d ON g.b = d.id "
+            "GROUP BY d.name",
+}
+
+
+@pytest.mark.parametrize("seed", [3, 11])
+@pytest.mark.parametrize("case", sorted(DOUBLES))
+def test_a_double_sum_is_the_per_slab_drivers_to_the_bit(db, case, seed):
+    """A floating-point sum depends on the order of its additions. The
+    slab bodies are the same traced functions in both plans, and the merge
+    folds a float sum partial by partial in slab order
+    (`device_emit.emit_merge`, `AggFunc.float_sums`), so a statement
+    program answers what the per-slab driver answered, to the last bit:
+    over the base slabs, and over a delta generation, where the loop over
+    the base slabs is followed by the delta slab's body."""
+    import numpy as np
+    _, s = db
+    rng = np.random.default_rng(seed)
+    s.execute("CREATE TABLE g (a BIGINT, b INT, x DOUBLE, c VARCHAR(8))")
+    xs = rng.normal(size=ROWS) * 10.0 ** rng.integers(-3, 4, size=ROWS)
+    cs = rng.integers(0, 4, size=ROWS)
+    s.execute("INSERT INTO g VALUES " + ",".join(
+        f"({i}, {i % 8}, {xs[i]:.6f}, 'c{cs[i]}')" for i in range(ROWS)))
+    sql = DOUBLES[case]
+
+    def both():
+        per_slab, plan, _l, _t = run(s, sql)
+        assert plan.startswith("slabs:")
+        for _ in range(2):
+            whole, plan, launched, _t = run(s, sql)
+            assert plan == "whole" and len(launched) == 1
+            same(whole, per_slab, sql)      # text-equal: every bit
+        return per_slab
+
+    first = both()
+    s.execute("INSERT INTO g VALUES " + ",".join(
+        f"({ROWS + i}, {i % 8}, {float(rng.normal()):.6f}, 'c{i % 4}')"
+        for i in range(5)))
+    s.execute("DELETE FROM g WHERE a % 101 = 7")
+    assert both() != first
+
+
+def test_a_statement_program_compiles_where_it_is_built_not_in_the_slot(db):
+    """A statement-sized compile is seconds on the chip's host. It runs
+    when the program is built — under the signature's build lock, by one
+    statement — and the launch that follows, inside the batch slot, finds
+    the executable: no compile overlaps a hold of the slot."""
+    _, s = db
+    sql = "SELECT b, MIN(v), MAX(k), COUNT(*) FROM f WHERE v <> 13 GROUP BY b"
+    run(s, sql)
+    with timeline.capture() as cap:
+        s.query(sql)
+    evs = [e for e in cap.events if e["ph"] == "X"]
+    holds = [(e["ts"], e["ts"] + e["dur"]) for e in evs
+             if e["name"].startswith("sched-slot")]
+    compiles = [(e["ts"], e["ts"] + e["dur"]) for e in evs
+                if e["name"] == "jax.backend_compile"]
+    (launch,) = [e for e in evs if e["cat"] == "launch"]
+    assert launch["name"].startswith("stmt_chain_") and holds and compiles
+    assert not [(c, h) for c in compiles for h in holds
+                if c[0] < h[1] and h[0] < c[1]]
+    assert max(c[1] for c in compiles) <= launch["ts"]
+
+
+@pytest.fixture
+def built(monkeypatch):
+    """The statement programs asked for: (signature, slabs)."""
+    asked = []
+    real = fragment.get_statement_program
+
+    def recording(src, prog, n_run, *tail):
+        sprog = real(src, prog, n_run, *tail)
+        asked.append((sprog.sig, n_run))
+        return sprog
+
+    monkeypatch.setattr(fragment, "get_statement_program", recording)
+    return asked
+
+
+@pytest.mark.parametrize("lo,survive", [(0, 3), (1024, 2), (2048, 1)])
+def test_zone_map_pruning_changes_the_program_not_the_answer(
+        db, built, lo, survive):
+    """How MANY slabs survive joins the statement program's signature
+    (`slabs=<n>`: the loop's trip count); the answers stay the host's."""
+    _, s = db
+    for sql in (f"SELECT COUNT(*), SUM(v) FROM f WHERE a >= {lo}",
+                f"SELECT d.name, COUNT(*) {_JOIN}WHERE f.a >= {lo} "
+                "GROUP BY d.name ORDER BY d.name"):
+        del built[:]
+        thrice(s, sql, slabs=survive)
+        assert built and {b for _sig, b in built} == {survive}, built
+        assert all(f"|slabs={survive}|" in sig for sig, _b in built)
+        assert s.last_guard.phases.slabs_skipped == 3 - survive
+
+
+def test_one_slab_folds_the_finalize_into_the_launch(db, built):
+    """A one-slab table takes the same path: partial + finalize in one
+    program, and without an ORDER BY the one partial is the answer."""
+    _, s = db
+    s.vars["tidb_tpu_max_slab_rows"] = 4096
+    s.execute("CREATE TABLE one (b INT, v BIGINT)")
+    s.execute("INSERT INTO one VALUES " + ",".join(
+        f"({i % 6}, {i})" for i in range(500)))
+    thrice(s, "SELECT b, SUM(v) FROM one GROUP BY b ORDER BY b", slabs=1)
+    thrice(s, "SELECT b, SUM(v) FROM one GROUP BY b", slabs=1)
+    assert [b for _sig, b in built] == [1] * 4
+
+
+@pytest.mark.parametrize("case", ["chain-bounds", "chain-topn",
+                                  "chain-global", "tree-sort",
+                                  "tree-bounds"])
+def test_a_delta_generation_runs_whole_with_its_delta_slab_and_masks(
+        db, built, case):
+    """`tests/test_delta_slabs.py`'s shapes: appended rows in the raw
+    delta slab (a body of its own shape, after the loop over the base
+    slabs), deleted rows as cleared bits of the liveness masks (the
+    body's masked variant). The first
+    write changes the table's shapes, so its first read specializes
+    again; a later write costs the next read neither a specialization nor
+    a trace: ONE launch."""
+    eng, s = db
+    sql = STATEMENTS[case]
+    thrice(s, sql)
+    assert all("|delta=-|" in sig for sig, _b in built)
+    s.execute("INSERT INTO f VALUES (5000, 7, 3, 41, 'c1'), "
+              "(5001, 8, 4, -17, 'c2')")
+    s.execute("DELETE FROM f WHERE a % 97 = 5")
+    del built[:]
+    thrice(s, sql, first="slabs:spec-miss", slabs=4)
+    assert built and all(b == 4 and "|delta=-|" not in sig
+                         for sig, b in built), built
+    # a tombstone in the delta slab itself, another append, another base
+    # tombstone: same shapes, so the digest stays specialized
+    s.execute("DELETE FROM f WHERE a = 5001")
+    s.execute("INSERT INTO f VALUES (5002, 9, 5, 12, 'c0')")
+    s.execute("DELETE FROM f WHERE a = 77")
+    rows, plan, launched, traced = run(s, sql)
+    same(rows, oracle(s, sql), sql)
+    assert (plan, len(launched), traced) == ("whole", 1, 0)
+
+
+def test_a_forced_overflow_answers_through_the_per_slab_driver(db):
+    """The failpoint's VALUE at the capacity boundary reads as an overflow
+    of a statement program: the per-slab driver answers at the same
+    capacities (`launch_plan=slabs:overflow`), and the counter says so."""
+    _, s = db
+    for sql, n in ((STATEMENTS["chain-topn"], 3),
+                   (STATEMENTS["tree-sort"], 3)):
+        thrice(s, sql)
+        whole = ("tidb_tpu_statement_programs_total", (("plan", "whole"),))
+        slabs = ("tidb_tpu_statement_programs_total", (("plan", "slabs"),))
+        before = (REGISTRY.counters.get(whole, 0),
+                  REGISTRY.counters.get(slabs, 0))
+        with failpoint.enabled("fused-pipeline-overflow", value=True,
+                               times=1):
+            rows, plan, launched, _t = run(s, sql)
+        same(rows, oracle(s, sql), sql)
+        assert plan == "slabs:overflow"
+        # the statement program, then a program a slab and the finalize
+        assert len(launched) == 1 + n + 1 and \
+            launched[0].startswith("stmt_"), launched
+        assert (REGISTRY.counters.get(whole, 0),
+                REGISTRY.counters.get(slabs, 0)) == (before[0],
+                                                     before[1] + 1)
+        assert run(s, sql)[1] == "whole"      # and the next is whole again
+
+
+def test_a_real_group_overflow_escalates_through_the_per_slab_driver(db):
+    """A capacity the digest settled on stops holding: groups arrive that
+    the specialized cap cannot hold. The statement program's control
+    fetch shows it, the per-slab driver re-runs and the ladder resizes;
+    the next execution is whole at the new cap."""
+    _, s = db
+    s.vars["tidb_tpu_group_cap"] = 16
+    sql = "SELECT v % 1000, COUNT(*) FROM f GROUP BY v % 1000"
+    s.query(sql)                                    # resident, then the
+    s.execute("INSERT INTO f VALUES (4000, 1, 1, 1, 'c0')")   # delta shapes
+    want = oracle(s, sql)
+    plans = []
+    for _ in range(3):          # the first climbs the ladder from 16 slots
+        rows, plan, _launched, _t = run(s, sql)
+        same(rows, want, sql)
+        plans.append(plan)
+    assert plans == ["slabs:spec-miss", "whole", "whole"]
+    # hundreds of new groups in the delta slab, same shapes: a spec HIT
+    s.execute("INSERT INTO f VALUES " + ",".join(
+        f"({4100 + i}, 1, 1, {1000 + i}, 'c0')" for i in range(400)))
+    rows, plan, launched, _t = run(s, sql)
+    same(rows, oracle(s, sql), sql)
+    assert plan == "slabs:overflow", plan
+    assert launched[0].startswith("stmt_chain_") and len(launched) > 5
+    rows, plan, launched, _t = run(s, sql)      # traces at the new cap
+    same(rows, oracle(s, sql), sql)
+    assert plan == "whole" and len(launched) == 1
+    assert run(s, sql)[1:] == ("whole", launched, 0)
+
+
+def test_distinct_pairs_and_a_cold_stream_stay_per_slab(db):
+    _, s = db
+    sql = "SELECT b, COUNT(DISTINCT v) FROM f GROUP BY b ORDER BY b"
+    want = oracle(s, sql)
+    for _ in range(3):
+        rows, plan, launched, _t = run(s, sql)
+        assert rows == want and plan == "slabs:pairs"
+        assert len(launched) == 4
+    # a statement whose column is not resident streams its first touch
+    assert run(s, "SELECT c, MAX(a) FROM f GROUP BY c")[1] == "slabs:cold"
+
+
+def test_sorted_runs_stay_on_their_own_driver(db, monkeypatch):
+    _, s = db
+    monkeypatch.setattr(fragment, "SLOT_ADDRESS_CAP", 64)
+    sql = ("SELECT k, COUNT(*), SUM(v) FROM f GROUP BY k "
+           "ORDER BY SUM(v) DESC, k LIMIT 5")
+    want = oracle(s, sql)
+    for _ in range(3):
+        rows, plan, launched, _t = run(s, sql)
+        assert rows == want and plan == "slabs:runs"
+        assert [n.rpartition("_")[0] for n in launched] == \
+            ["partial_chain"] * 3 + ["sort_rows", "finalize"]
+
+
+def test_live_rows_are_device_values_of_the_entry(db):
+    """The slabs' live-row counts are uploaded once a table version and
+    handed to every launch as they are (no `jnp.int32(n)`, no
+    `np.array([rows])` inside a `launch` span); a generation with masks
+    hands the masks."""
+    eng, s = db
+    s.query(STATEMENTS["chain-bounds"])
+    tid = eng.catalog.info_schema.table("f").id
+    (ent,) = [e for (_d, sid, t, _p), e in dc._CACHE.items()
+              if sid == id(eng.store) and t == tid]
+    lives = [ent.live_arg(i) for i in range(ent.n_slabs)]
+    assert [int(n) for n in lives] == [1024, 1024, ROWS - 2048]
+    assert all(a is b for a, b in zip(
+        lives, (ent.live_arg(i) for i in range(ent.n_slabs))))
+    assert ent.live_counts() is ent.live_counts()
+    assert ent.live_counts(frozenset({1})).tolist() == [1024, 0, 952]
+    s.execute("DELETE FROM f WHERE a = 3")
+    s.query(STATEMENTS["chain-bounds"])
+    (new,) = [e for (_d, sid, t, _p), e in dc._CACHE.items()
+              if sid == id(eng.store) and t == tid]
+    assert new is not ent and new.live_arg(0) is new.alive[0]
+
+
+def test_a_cached_statement_program_holds_nothing_of_a_statement(db):
+    """The compile cache outlives statements and tables: a statement
+    program keeps the slab programs and static functions, never a source,
+    an entry or a device array (a superseded generation must free its
+    arrays by reference count)."""
+    _, s = db
+    for case in ("chain-topn", "tree-sort"):
+        thrice(s, STATEMENTS[case])
+    progs = [p for p in fragment._COMPILE_CACHE.values()
+             if isinstance(p, fragment._StatementProgram)]
+    assert progs
+    for p in progs:
+        assert p.control in (fragment._ChainSlabs.control,
+                             fragment._TreeSlabs.control)
+        for body in filter(None, (p.body, p.dbody)):
+            assert isinstance(body, functools.partial)
+            assert body.func in (fragment._ChainSlabs._slab_body,
+                                 fragment._TreeSlabs._slab_body)
+            assert all(isinstance(a, (fragment._FragmentProgram,
+                                      TreeProgram, int))
+                       for a in body.args), body.args
+
+
+def test_the_control_fetch_packs_into_a_vector_a_kind_and_back():
+    """What a statement program hands the host is ONE vector for every
+    integer and boolean leaf and one a float dtype; the host cuts them
+    back into the tree by the shapes and dtypes kept at trace time."""
+    import numpy as np
+    from tidb_tpu.ops.jax_env import jax, jnp
+    tree = {"ngs": jnp.array([3, 5], dtype=jnp.int32),
+            "ng": jnp.int32(7),
+            "keys": [(jnp.arange(4, dtype=jnp.int64) - 2 ** 40,
+                      jnp.array([True, False, True, True]))],
+            "states": [(jnp.array([[1, 2], [3, 4]], dtype=jnp.uint32),
+                        jnp.array([0.5, -1.25], dtype=jnp.float32)),
+                       (jnp.zeros((0,), dtype=jnp.int64),)]}
+    packed = jax.device_get(jax.jit(fragment._pack)(tree))
+    assert sorted(packed) == ["float32", "int64"]
+    like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        tree)
+    back = fragment._unpack(packed, like)
+    for got, want in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.array_equal(got, np.asarray(want))

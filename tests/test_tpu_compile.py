@@ -394,6 +394,58 @@ def test_finalize_and_merge_compile_over_sf10_partials(recorded, one_chip,
     _fits(stats)
 
 
+def test_a_statement_program_is_one_body_in_a_loop(recorded, one_chip,
+                                                   monkeypatch):
+    """Q6 warm at SF=8 after zone-map pruning: three full 8M-row slabs and
+    their merge in ONE program (`_StatementProgram`). The slabs' body is
+    the body of ONE loop — a copy of it a slab read 113 s of cold compile
+    and 20 s of every later run's set-up for Q1 over six slabs on the
+    chip's host (PERF.md §6, PR 39) — so the program's code and
+    temporaries stay near one slab's, and the stages keep their names
+    under the statement program's."""
+    import functools
+    import re
+    from tidb_tpu.executor import fragment
+    from tidb_tpu.ops import jax_env
+    jax = jax_env.jax
+    calls = [c for c in recorded if c[1] == "_partial" and c[0].layouts
+             and not c[0].chain[0].group_exprs
+             and getattr(c[2][1], "dtype", None) != np.dtype(bool)]
+    assert calls, "the toy run launched no Q6 partial over a live prefix"
+    (owner, _label, one), = _compile_calls(
+        calls[:1], fragment._FragmentProgram, one_chip, monkeypatch)
+    factor = fragment.DEFAULT_MAX_SLAB_ROWS // TOY_ROWS
+    p = fragment._FragmentProgram(
+        owner.chain, owner.used_cols, owner.in_types,
+        owner.slab_cap * factor, owner.group_cap, owner.key_bounds,
+        owner.has_distinct, owner.layouts, owner.pair_cap)
+    cols, n_rows, preps = calls[0][2]
+    slab = (_scaled_cols(jax, cols, factor, one_chip),
+            _scaled_live(jax, n_rows, factor, one_chip))
+    args = _scaled(jax, preps, 1, one_chip), (slab,) * 3
+    sp = fragment._StatementProgram(
+        "stmt_chain", functools.partial(fragment._ChainSlabs._slab_body, p),
+        None, p._merge, fragment._ChainSlabs.control, True, "toy", args)
+    assert set(sp.like) == {"ngs", "ng", "keys", "states"}
+    compiled = sp.run.lower(*args).compile()    # the build's own executable
+    m, m1 = compiled.memory_analysis(), one.memory_analysis()
+    _fits([("_StatementProgram._run", m)])
+    assert m.temp_size_in_bytes < 2 * m1.temp_size_in_bytes, (m, m1)
+    assert m.generated_code_size_in_bytes < \
+        2 * m1.generated_code_size_in_bytes, (m, m1)
+    text = compiled.as_text()
+    assert "jit_stmt_chain_" in text.splitlines()[0], text[:200]
+    assert len(re.findall(r"\bwhile\(", text)) == 1
+    for stage in ("decode", "filter", "agg", "merge"):
+        assert re_search_stage(text, stage), stage
+
+
+def re_search_stage(text: str, stage: str):
+    import re
+    return re.search(r'op_name="jit\(stmt_chain_[0-9a-f]{8}\)/[^"]*/?%s/'
+                     % stage, text)
+
+
 def test_sorted_runs_grouping_lowers_for_the_chip_with_two_sorts(one_chip):
     """Grouping by sorted runs at the real geometry — six slabs of 8M rows,
     millions of groups. The shared sort program holds exactly TWO sorts

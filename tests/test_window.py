@@ -558,7 +558,9 @@ def test_range_frame_device_matches_cpu():
 def test_warm_window_launch_count(session):
     """Warm single-fragment window query stays <= slabs + 1 programs:
     the segmented scans ride inside the fused program, not extra
-    launches."""
+    launches. (A window root is no aggregate: it never becomes a
+    statement program, whose ONE launch `tests/test_statement_program.py`
+    pins.)"""
     s = session
     sql = DEVICE_WINDOW_QUERIES[0]
     s.vars["tidb_tpu_engine"] = "on"

@@ -788,11 +788,16 @@ def _warm(store, key, scan, new, max_slab: int):
     extension). Re-chosen layouts mean new programs; they trace and
     compile HERE, where nobody waits, and the aligned structures over the
     new row positions are built here too (`device_cache.Preview`). A
-    reader that cannot be warmed is skipped: the statement then pays what
-    it would have paid. → the preview to install."""
+    reader whose statement's text is known runs TWICE, under a guard that
+    carries the text: the first run settles the digest's specialization
+    over the rebuilt shapes, the second is the ONE statement program the
+    statements after the swap will launch (`fragment._StatementProgram`).
+    A reader that cannot be warmed is skipped: the statement then pays
+    what it would have paid. → the preview to install."""
     from tidb_tpu.executor import ExecContext
     from tidb_tpu.executor import device_cache as dc
     from tidb_tpu.executor.fragment import TpuFragmentExec
+    from tidb_tpu.util.guard import ExecutionGuard
     pv = dc.Preview(key, new)
     table_id = scan.table.id
 
@@ -806,7 +811,8 @@ def _warm(store, key, scan, new, max_slab: int):
 
     # (a pod entry's slabs live on several devices under placement the
     # statements' admission makes: not warmed)
-    for plan, vars_ in dc.readers(key[1], table_id) if key[0] >= 0 else ():
+    for plan, vars_, sql in \
+            dc.readers(key[1], table_id) if key[0] >= 0 else ():
         # a plain generation (nothing written meanwhile) is read a second
         # time under masks and with an empty delta slab: the variants the
         # first write after the swap will ask for
@@ -819,10 +825,14 @@ def _warm(store, key, scan, new, max_slab: int):
             # statement has to run at a snapshot no older than what the
             # connections have made of the other tables by now)
             for _ in range(2):
+                guard = ExecutionGuard(sql=sql) if sql else None
                 ctx = ExecContext(
                     snapshot=store.snapshot(),
-                    vars={**vars_, "tidb_tpu_scheduler": "off"})
+                    vars={**vars_, "tidb_tpu_scheduler": "off"},
+                    guard=guard)
                 ctx.phases.device_index = key[0]    # the entry's device
+                if guard is not None:
+                    guard.device_index = key[0]
                 if not follow(ctx):
                     return pv       # it cannot follow: swap what there is
             swap = pv.ent
@@ -835,10 +845,11 @@ def _warm(store, key, scan, new, max_slab: int):
                         pv.ent = extend_entry(ctx, scan, swap, max_slab,
                                               masked=True,
                                               private=True) or swap
-                    ex = TpuFragmentExec(plan)
-                    ex.open(ctx)
-                    with pv, ex._protect_tables():
-                        ex._run_device()
+                    for _rep in range(2 if sql else 1):
+                        ex = TpuFragmentExec(plan)
+                        ex.open(ctx)
+                        with pv, ex._protect_tables():
+                            ex._run_device()
                 except Exception as e:  # noqa: BLE001 — best effort
                     timeline.tag(skipped=type(e).__name__)
                 finally:

@@ -36,6 +36,7 @@ elsewhere (locate_tables is its oracle).
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from collections import OrderedDict, deque
@@ -78,7 +79,7 @@ class CachedTable:
                  "cov", "max_rid", "seen", "rowmap", "lineage", "base_td",
                  "alive",
                  "delta_cap", "delta_rows", "dead_rows", "steps",
-                 "device", "owners", "lost")
+                 "device", "owners", "lost", "live_dev")
 
     def __init__(self, td, max_slab: int, total: int, slab_cap: int,
                  n_slabs: int, parts, n_cols: int, compressed: bool = False):
@@ -133,6 +134,12 @@ class CachedTable:
         # onto survivors) — open_table refills EXACTLY these slabs on
         # next touch instead of re-streaming whole columns
         self.lost: set = set()
+        # the slabs' live-row counts as DEVICE values, uploaded once a
+        # generation (`live_arg`, `live_counts`): a generation is a new
+        # CachedTable, so nothing here outlives the counts it was made of.
+        # One vector of `n_slabs` int32 a DISTINCT set of pruned slabs:
+        # zone maps prune runs of slabs, so at most n_slabs² / 2 of them
+        self.live_dev: dict = {}
         self.dicts: Dict[int, Optional[np.ndarray]] = {}
         self.dev: Dict[int, List[Tuple]] = {}  # col → [(vals, valid)] slabs
         # col → ColLayout for packed columns; None/absent = raw layout
@@ -163,6 +170,33 @@ class CachedTable:
         if self.alive is not None:
             return self.alive[s]
         return self.slab_rows(s)
+
+    def _live_upload(self, key, make):
+        """`make()`'s host counts on this entry's device, once a
+        generation and `key` (two statements racing upload twice)."""
+        got = self.live_dev.get(key)
+        if got is None:
+            from tidb_tpu.ops.jax_env import jax
+            got = self.live_dev[key] = jax.device_put(
+                make(), device_handle(self.device))
+        return got
+
+    def live_arg(self, s: int):
+        """`slab_live` as the slab programs of an aggregate take it: the
+        device mask, else the live prefix's length as a device int32
+        scalar — every slab's in one upload a generation, none a launch."""
+        if self.alive is not None:
+            return self.alive[s]
+        return self._live_upload("slabs", lambda: [
+            np.int32(self.slab_rows(i)) for i in range(self.n_slabs)])[s]
+
+    def live_counts(self, zeroed=frozenset()):
+        """Every slab's live rows as ONE device int32 vector (what a tree
+        program takes for a whole build side), the slabs zone maps pruned
+        (`zeroed`) as 0: an upload a generation and pruned set."""
+        return self._live_upload(frozenset(zeroed), lambda: np.array(
+            [0 if i in zeroed else self.slab_rows(i)
+             for i in range(self.n_slabs)], dtype=np.int32))
 
     def slab_mask(self, s: int):
         """The slab's liveness as a device mask, made from the live
@@ -339,8 +373,11 @@ def note_reader(store_id: int, table_ids, plan, vars_, what) -> None:
 
 
 def readers(store_id: int, table_id: int) -> list:
+    """→ [(plan, vars, the statement's text or None)]."""
     with _LOCK:
-        return list(_READERS.get((store_id, table_id), {}).values())
+        return [(plan, vars_, what[0] if isinstance(what[0], str) else None)
+                for what, (plan, vars_) in
+                _READERS.get((store_id, table_id), {}).items()]
 
 
 def install_preview(pv: Preview) -> None:
@@ -1026,8 +1063,10 @@ def _slab_host(prep: dict, start: int, stop: int, slab_cap: int):
 
 
 def _tuple_nbytes(t) -> int:
-    """Physical bytes of one slab tuple (raw or packed)."""
-    return sum(a.nbytes for a in t)
+    """Physical bytes of one slab tuple (raw or packed). From shape and
+    dtype: a device array's own `nbytes` costs the host several times a
+    numpy array's, and a warm `open_table` asks once a resident array."""
+    return sum(math.prod(a.shape) * a.dtype.itemsize for a in t)
 
 
 def _logical_tuple_bytes(ent: CachedTable, i: int, t) -> int:
@@ -1609,8 +1648,8 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         logi = 0
         for i in used_cols:
             slabs = ent.dev[i]
+            logi += ent.n_slabs * _slab_logical_est(ent, i)
             for s in range(ent.n_slabs):
-                logi += _slab_logical_est(ent, i)
                 if s in skip:
                     continue
                 t = slabs[s] if s < len(slabs) else None

@@ -24,6 +24,7 @@ operation. Trace-time only: nothing runs per call.
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import List, Sequence
 
 from tidb_tpu.expression import ColumnRef, EvalContext
@@ -223,6 +224,9 @@ def emit_merge(root, aggs: List[AggFunc], group_cap: int, key_cols,
     program's merge and the fused pipeline's root-merge program."""
     from tidb_tpu.ops.jax_env import jnp
     from tidb_tpu.ops import factorize as F
+    # where each partial's slots end in the stack (None: handed in stacked)
+    ends = list(itertools.accumulate(int(a.shape[0]) for a in slot_live)) \
+        if isinstance(slot_live, (list, tuple)) else None
     key_cols, states, slot_live = stack_partials(key_cols, states, slot_live)
     cap = group_cap
     if root.group_exprs:
@@ -242,7 +246,19 @@ def emit_merge(root, aggs: List[AggFunc], group_cap: int, key_cols,
                       else jnp.zeros_like(arr))
             for arr in partial)
         st = agg.init(jnp, cap)
-        out_states.append(agg.merge(jnp, st, gids, cap, clean))
+        if agg.float_sums and ends:
+            # a floating-point sum folds partial by partial, in slab order:
+            # a partial holds a group once, so each reduction adds one
+            # value to zeros, and the adds between them are the program's
+            # own. ONE reduction over the stack adds in whatever order the
+            # compiler gives the program it stands in, and the per-slab
+            # merge and a statement program then differ in the last place
+            for lo, hi in zip([0] + ends, ends):
+                st = agg.merge(jnp, st, gids[lo:hi], cap,
+                               tuple(arr[lo:hi] for arr in clean))
+        else:
+            st = agg.merge(jnp, st, gids, cap, clean)
+        out_states.append(st)
     return {"keys": key_out, "states": out_states, "n_groups": n_final}
 
 

@@ -33,7 +33,9 @@ analog for the device engine).
 
 from __future__ import annotations
 
+import functools
 import logging
+import math
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -1145,6 +1147,158 @@ def get_finalize_program(agg_root, order_root, group_cap: int,
     return prog, sig
 
 
+def _control_of(partials, control) -> dict:
+    """What the driver's ONE control fetch reads off the slab partials:
+    each slab's true group count and the source's own (`control`)."""
+    return {"ngs": [p["n_groups"] for p in partials], **control(partials)}
+
+
+def _control_tree(ctl: dict, out, small: bool) -> dict:
+    """The tree one control fetch brings to the host: the slabs' control
+    values `ctl`, the merged group count, a finalize's row count, and —
+    where the group capacity is `small` — the result itself, which then
+    rides the same round trip."""
+    fetch = {**ctl, "ng": out["n_groups"]}
+    if "n_out" in out:
+        fetch["no"] = out["n_out"]
+    if small:
+        fetch["keys"], fetch["states"] = out["keys"], out["states"]
+    return fetch
+
+
+def _pack_key(dtype) -> str:
+    """Which packed vector a leaf of `dtype` rides: every integer and
+    boolean the int64 one, anything else its own dtype's."""
+    return "int64" if np.dtype(dtype).kind in "biu" else str(dtype)
+
+
+def _pack(tree) -> dict:
+    """`tree`'s leaves flattened into ONE vector a `_pack_key` (traced).
+    What a statement program hands the host costs it by the PIECE, not by
+    the byte: ≈ 45 µs an output array at the launch and ≈ 60 µs a leaf at
+    the `device_get` on the chip's host (PERF.md §6, PR 39), and Q1's
+    control fetch has thirty leaves."""
+    from tidb_tpu.ops.jax_env import jax, jnp
+    by: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        key = _pack_key(leaf.dtype)
+        by.setdefault(key, []).append(jnp.ravel(leaf).astype(key))
+    return {key: jnp.concatenate(parts) for key, parts in by.items()}
+
+
+def _unpack(packed: dict, like):
+    """`_pack`'s vectors, on the host, cut back into the tree whose leaves'
+    shapes and dtypes `like` holds, in the same order."""
+    from tidb_tpu.ops.jax_env import jax
+    leaves, treedef = jax.tree.flatten(like)
+    at = dict.fromkeys(packed, 0)
+    out = []
+    for leaf in leaves:
+        key, n = _pack_key(leaf.dtype), math.prod(leaf.shape)
+        out.append(np.asarray(packed[key][at[key]:at[key] + n])
+                   .astype(leaf.dtype).reshape(leaf.shape))
+        at[key] += n
+    return treedef.unflatten(out)
+
+
+class _StatementProgram:
+    """A warm aggregate statement as ONE jitted call (`_run_agg_slabs`,
+    launch plan `whole`): the body of every surviving slab — what the
+    chain's `partial` or the fused pipeline's tree program traces a launch
+    each — then the merge or the fused finalize over their partials
+    (`tail`, its traced function; None where one slab's partial is the
+    answer), composed in one trace under the stages' own named scopes.
+
+    The base slabs share one shape, so their body is traced ONCE, as the
+    body of a loop over them (`lax.scan`): a program with a copy of the
+    body a slab compiles, and loads from the persistent cache, a slab's
+    worth of seconds a copy (Q1 over six 8M-row slabs: 113 s cold and 20 s
+    from the cache on the chip's host, every run's set-up; PERF.md §6,
+    PR 39). The slabs enter as they lie in the device cache, a pytree
+    each, and nothing stacks the table: each turn of the loop picks its
+    slab with a `lax.switch` over them, which costs a copy of ONE slab's
+    arguments a turn (its compressed columns, and a join tree's aligned
+    columns: ≈ 1.5 ms an operation at SF=8, PERF.md §6). The raw delta
+    slab has a shape of its own: its body (`dbody`) follows the loop. Of
+    the partials only what the control fetch reads leaves the program,
+    packed (`_pack`; `like`, the control tree's shapes and dtypes read off
+    the arguments `args` when the program is built — and compiled — says
+    how to cut it): an overflow it shows sends the statement to the
+    per-slab driver, whose partials are the ladder's checkpoints. `small`:
+    the result itself rides the fetch, and nothing else is handed out.
+    → (the result on the device, or None where it rides; the packed
+    control tree)."""
+
+    def __init__(self, kind: str, body, dbody, tail, control, small: bool,
+                 sig: str, args):
+        from tidb_tpu.ops.jax_env import jax, named_jit, program_name
+        self.body, self.dbody = body, dbody
+        self.tail, self.control, self.small = tail, control, small
+        self.sig = sig
+        self.name = program_name(kind, sig)
+        self.run = named_jit(self._run, self.name)
+        self.like = jax.eval_shape(self._fetch, *args)[1]
+        # compiled HERE — under the signature's build lock, by one
+        # statement, outside the batch slot — and not by the first launch
+        # inside it (the call finds the executable: JAX keeps one cache
+        # for both), so a statement-sized compile holds nobody's slot
+        self.run.lower(*args).compile()
+
+    def _run(self, shared, slabs):
+        _count_trace()
+        out, fetch = self._fetch(shared, slabs)
+        return out, _pack(fetch)
+
+    def _fetch(self, shared, slabs):
+        """→ (the result, or None where it rides the fetch; the control
+        tree): what `_run` packs."""
+        from tidb_tpu.executor.device_emit import partials_of
+        from tidb_tpu.ops.jax_env import jax, jnp, lax
+        base = slabs[:-1] if self.dbody is not None else slabs
+        partials = []       # each leaf with a leading axis of slabs
+        if len(base) == 1:
+            partials.append(jax.tree.map(
+                lambda a: a[None], self.body(shared, base[0])))
+        elif base:
+            picks = [lambda *ops, i=i: ops[i] for i in range(len(base))]
+            partials.append(lax.scan(
+                lambda _c, k: (None, self.body(
+                    shared, lax.switch(k, picks, *base))),
+                None, jnp.arange(len(base), dtype=jnp.int32))[1])
+        if self.dbody is not None:
+            partials.append(jax.tree.map(
+                lambda a: a[None], self.dbody(shared, slabs[-1])))
+        ctl = {k: jnp.concatenate(v) for k, v in
+               _control_of(partials, self.control).items()}
+        if self.tail is None:
+            out = jax.tree.map(lambda a: a[0], partials[0])
+        else:
+            # a partial a slab again, as the per-slab driver hands them to
+            # the same tail (whose merge folds a float sum slab by slab)
+            out = self.tail(*partials_of([
+                jax.tree.map(lambda a, i=i: a[i], p)
+                for p in partials for i in range(p["n_groups"].shape[0])]))
+        return (None if self.small else out,
+                _control_tree(ctl, out, self.small))
+
+
+def get_statement_program(src: "_SlabSource", prog, n_run: int, tail,
+                          tail_sig: str, small: bool,
+                          args) -> _StatementProgram:
+    """`tail_sig` is the signature of what follows the slabs (which holds
+    the slab program's own). How MANY slabs survived joins it, not which,
+    and the raw delta slab's program where one runs. `args`: what the
+    program will be run with (`statement_args`); a build reads shapes off
+    them and keeps none."""
+    delta = src.delta_id in src.run_ids
+    sig = (f"stmt|slabs={n_run}|delta={src.dsig if delta else '-'}|"
+           f"small={small}|{tail_sig}")
+    return _get_or_build(sig, "stmt", lambda: _StatementProgram(
+        src.stmt_kind, src.statement_body(prog),
+        src.statement_body(src.dprog) if delta else None, tail,
+        type(src).control, small, sig, args))
+
+
 # ---------------------------------------------------------------------------
 # Per-digest specialization cache
 # ---------------------------------------------------------------------------
@@ -1254,9 +1408,10 @@ def _note_grouping(root: PhysHashAgg, key_bounds, group_cap: int) -> str:
 def _note_agg_io(partial, rows_in: int, groups: int) -> None:
     """Tag the open `device.fragment` span with what its grouping took in
     and gave out: `rows_in` (rows of the slabs whose partials launched,
-    re-runs included), `groups` (live groups out), and the bytes one group
-    holds on the device, `key_bytes` and `state_bytes` (read off a
-    partial's own arrays)."""
+    re-runs included, and those of a statement program that overflowed
+    and was answered again slab by slab), `groups` (live groups out), and
+    the bytes one group holds on the device, `key_bytes` and `state_bytes`
+    (read off a partial's own arrays)."""
     if not timeline.ENABLED:
         return
     timeline.tag(
@@ -1279,9 +1434,26 @@ def _tight_cap(cap: int, groups: int) -> int:
 
 
 def _count_agg_partial(grouping: str) -> None:
-    """One program holding an aggregate's partial was launched."""
+    """One program holding an aggregate's partial was launched (a slab's,
+    or a statement program with every slab's)."""
     from tidb_tpu.util.observability import REGISTRY
     REGISTRY.inc("tidb_tpu_agg_partials_total", {"grouping": grouping})
+
+
+def _launch_plan(src: "_SlabSource", spec, want_pairs: bool,
+                 rows_mode: bool) -> str:
+    """How `_run_agg_slabs` issues a statement's device work, from what it
+    can observe: `whole` — ONE statement program — when an earlier
+    execution of the digest settled the capacities (`spec`) and every slab
+    is resident on one device; else `slabs:<why>`, a launch a slab and the
+    merge. Sorted runs are a driver of their own, DISTINCT pair sets are
+    fetched between the slabs and the merge, a pod's slabs lie on several
+    devices, a cold table's first touch streams slab by slab. (An overflow
+    read back from a statement program makes it `slabs:overflow`.)"""
+    why = ("runs" if rows_mode else "pairs" if want_pairs else
+           "pod" if src.pod else "cold" if src.stream is not None else
+           "spec-miss" if spec is None else None)
+    return "whole" if why is None else "slabs:" + why
 
 
 def _initial_group_cap(root: PhysHashAgg, default_cap: int,
@@ -1640,19 +1812,49 @@ class _SlabSource:
     it), `n_slabs` and `slab_cap` (the table's geometry, whatever was
     pruned, so signatures and ceilings don't depend on pruning), `max_cap`
     (the group ladder's ceiling), and `lay_sig` and `geometry` (what the
-    specialization cache compares and keys)."""
+    specialization cache compares and keys), `stream` (a cold table's
+    first touch in progress, else None), `pod` (slabs on several devices)
+    and `delta_id` / `dprog` / `dsig` (the raw delta slab's physical id,
+    the program of its shape and that program's signature).
+
+    A slab's arguments come in two parts, for the per-slab launches and
+    for the one statement program alike: `shared` (what every slab's body
+    takes) and the slab's own; `statement_body(prog)` is the traced body
+    of a slab over them. It and `control` hold nothing of a statement: a
+    cached statement program keeps them."""
 
     kind = ""
+    stmt_kind = ""      # what the statement program is called in a profile
+    stream = None
+    pod = False
+    delta_id = -1
+    dprog, dsig = None, "-"
 
-    def control(self, partials) -> dict:
+    @staticmethod
+    def control(partials) -> dict:
         """What the batched control fetch brings back besides the group
-        counts."""
+        counts (traced inside a statement program)."""
         return {}
+
+    def overflowed(self, got) -> bool:
+        """Whether what `control` fetched shows a capacity of this
+        source's own exceeded (no side effect: `escalate` acts on it)."""
+        return False
 
     def escalate(self, got, ladder):
         """Classify what `control` fetched → (retry, positions to re-run),
         or None to give the statement back to the caller."""
         return False, set()
+
+    def statement_body(self, prog):
+        """→ `body(shared, slab)`, the partial of one slab through `prog`
+        (the slab program, or the delta slab's) as a traced function."""
+        raise NotImplementedError
+
+    def statement_args(self, prog, prep_vals):
+        """→ (shared, the surviving slabs' own): what a statement program
+        takes. Every slab is resident."""
+        raise NotImplementedError
 
     def learned(self) -> dict:
         """What a specialization entry keeps besides capacities."""
@@ -1666,6 +1868,7 @@ class _ChainSlabs(_SlabSource):
     """The slabs of one table under a linear chain (Q1, Q6)."""
 
     kind = "chain"
+    stmt_kind = "stmt_chain"
 
     def __init__(self, ex: "TpuFragmentExec", chain, ent, stream, used,
                  in_types, dicts, key_bounds, layouts, slab_ids):
@@ -1695,6 +1898,7 @@ class _ChainSlabs(_SlabSource):
         self.pod_pin = device_cache.device_handle(
             device_cache._ctx_device(self.ctx)) \
             if getattr(ent, "owners", None) is not None else None
+        self.pod = self.pod_pin is not None
 
     def rows(self, pos: int) -> int:
         return self.ent.slab_rows(self.run_ids[pos])
@@ -1707,6 +1911,7 @@ class _ChainSlabs(_SlabSource):
             self.dprog = get_program(
                 self.chain, self.used, self.in_types, self.ent.delta_cap,
                 gcap, self.key_bounds, want_pairs, None, pair_cap)
+            self.dsig = self.dprog.sig
         return prog, prog.sig, prog.collect_preps(self.dicts)
 
     def merge_program(self, prog, gcap: int, sig: str):
@@ -1716,7 +1921,7 @@ class _ChainSlabs(_SlabSource):
         """→ (position, partial) of the slabs at `to_run`, each launched
         as it is asked for; None = the first pass over every surviving
         slab, which STREAMS a cold table's first touch."""
-        from tidb_tpu.ops.jax_env import jax, jnp
+        from tidb_tpu.ops.jax_env import jax
         ex, ent, used = self.ex, self.ent, prog.used_cols
         if to_run is None:
             to_run = range(len(self.run_ids))
@@ -1725,19 +1930,33 @@ class _ChainSlabs(_SlabSource):
             slabs = (ex._slab(ent, self.run_ids[p], used) for p in to_run)
         # (slabs first: zip must run the stream past its last slab, where
         # it commits the upload)
-        for (cols, n), pos in zip(slabs, to_run):
-            p = self.dprog if self.run_ids[pos] == self.delta_id else prog
+        for (cols, _n), pos in zip(slabs, to_run):
+            rid = self.run_ids[pos]
+            p = self.dprog if rid == self.delta_id else prog
+            live = ent.live_arg(rid)    # on the device since the version's
             # slot per slab DISPATCH: the streamed encode of the next slab
             # (inside _slab_iter) runs slot-free, so a sibling's dispatch
             # interleaves with our host work
             with self.ctx.device_slot():
                 with self.ctx.phases.launch(p.partial_name, slab=pos):
-                    part = p.partial(
-                        cols, jnp.int32(n) if isinstance(n, int) else n,
-                        prep_vals)
+                    part = p.partial(cols, live, prep_vals)
                     if self.pod_pin is not None:
                         part = jax.device_put(part, self.pod_pin)
             yield pos, part
+
+    @staticmethod
+    def _slab_body(prog, prep_vals, slab):
+        cols, live = slab
+        return prog._partial(cols, live, prep_vals)
+
+    def statement_body(self, prog):
+        return functools.partial(self._slab_body, prog)
+
+    def statement_args(self, prog, prep_vals):
+        ent = self.ent
+        return prep_vals, tuple(
+            (self.ex._slab(ent, rid, prog.used_cols)[0], ent.live_arg(rid))
+            for rid in self.run_ids)
 
 
 class _TreeSlabs(_SlabSource):
@@ -1756,13 +1975,20 @@ class _TreeSlabs(_SlabSource):
     to it."""
 
     kind = "tree"
+    stmt_kind = "stmt_fused"
 
     def __init__(self, ctx, root, caps, scans, ents, scan_inputs, scan_rows,
                  flow_list, flows, aligned_inputs, join_cfgs, walk_joins,
                  akb, max_cap, out_cap_max, anchor_i, scan_layouts,
                  nested_rows, scan_counts):
         self.ctx, self.root, self.key_bounds = ctx, root, akb
-        self.scan_inputs, self.scan_rows = scan_inputs, scan_rows
+        # a plain table's live-row counts as the device vector its entry
+        # keeps a version (slabs that zone maps zeroed as 0): no launch
+        # uploads them again
+        self.scan_inputs, self.scan_rows = scan_inputs, tuple(
+            rows if e.alive is not None else e.live_counts(
+                frozenset(np.flatnonzero(counts == 0).tolist()))
+            for (e, _u), rows, counts in zip(ents, scan_rows, scan_counts))
         self.flow_list, self.aligned_inputs = flow_list, aligned_inputs
         self.join_cfgs, self.walk_joins = join_cfgs, walk_joins
         self.max_cap, self.out_cap_max = max_cap, out_cap_max
@@ -1782,6 +2008,8 @@ class _TreeSlabs(_SlabSource):
             for si, slot in enumerate(scan_layouts or ())
             for i, l in slot) if scan_layouts else "-"
         self.geometry = (tuple(_ent_geometry(e) for e, _ in ents), anchor_i)
+        self.pod = any(getattr(e, "owners", None) is not None
+                       for e, _ in ents)
         self._launch_sig = ""
         # the anchor's raw delta slab runs the same tree as a program of
         # its own anchor shape (its capacity, no layouts)
@@ -1813,7 +2041,7 @@ class _TreeSlabs(_SlabSource):
             self.scan_layouts, want_pairs, pair_cap, sig=sig)
         self._launch_sig = _sig_tag("fused", sig)
         if self.delta_id in self.run_ids:
-            self.dprog, _dsig = get_pipeline_program(
+            self.dprog, self.dsig = get_pipeline_program(
                 self.root, self.dcaps, gcap, self.join_cfgs,
                 self.key_bounds, self.dlayouts, want_pairs, pair_cap)
         return prog, sig, prog.collect_preps(self.flow_list)
@@ -1840,49 +2068,100 @@ class _TreeSlabs(_SlabSource):
                 stack.extend(TF.aligned_chain(j.children[bi])[1])
         return spaced
 
+    def _shared(self, prep_vals, spaced: set):
+        """What every slab's body takes: the build sides whole, the
+        anchor's place in them left open, the joins sliced by anchor slab
+        (`spaced`) likewise."""
+        a = self.anchor_i
+        si, sr = list(self.scan_inputs), list(self.scan_rows)
+        si[a] = sr[a] = None
+        ai = tuple(((), {}) if matched and id(jn) in spaced
+                   else (matched, jcols)
+                   for jn, (matched, jcols) in zip(self.walk_joins,
+                                                   self.aligned_inputs))
+        return tuple(si), tuple(sr), prep_vals, ai, self.nested_rows
+
+    def _slab_arg(self, s: int, spaced: set):
+        """Anchor slab `s`'s own: its columns, its liveness (mask, or the
+        live prefix's length as a device scalar kept on the entry), its
+        slice of the joins aligned in its row space (None elsewhere)."""
+        cols = {i: [slabs[s]] for i, slabs in
+                self.scan_inputs[self.anchor_i].items()}
+        live = self.a_ent.live_arg(s)
+        if self.a_ent.alive is not None:
+            live = (live,)
+        return cols, live, tuple(
+            ((matched[s],), {c: (sl[s],) for c, sl in jcols.items()})
+            if matched and id(jn) in spaced else None
+            for jn, (matched, jcols) in zip(self.walk_joins,
+                                            self.aligned_inputs))
+
+    @staticmethod
+    def _assemble(a: int, shared, slab):
+        """`_shared` and `_slab_arg` → a tree program's arguments."""
+        si, sr, prep_vals, ai, nested = shared
+        cols, live, sliced = slab
+        si, sr = list(si), list(sr)
+        si[a], sr[a] = cols, live
+        ai = tuple(w if sl is None else sl for w, sl in zip(ai, sliced))
+        return tuple(si), tuple(sr), prep_vals, ai, nested
+
     def launches(self, prog, prep_vals, to_run=None):
         """→ (position, partial) of the slabs at `to_run` (None = every
         surviving slab), each launched as it is asked for."""
         spaced = self._joins_in_anchor_space()
+        shared = self._shared(prep_vals, spaced)
         for pos in (range(len(self.run_ids)) if to_run is None else to_run):
-            yield pos, self._launch(prog, self.run_ids[pos], prep_vals,
-                                    spaced)
+            s = self.run_ids[pos]
+            p = self.dprog if s == self.delta_id else prog
+            si, sr, pv, ai, nested = self._assemble(
+                self.anchor_i, shared, self._slab_arg(s, spaced))
+            # slot per slab DISPATCH (async queue) — one labeled compute
+            # span per fused slab program in the trace
+            with self.ctx.device_slot():
+                with self.ctx.phases.launch(p.name, slab=s,
+                                            sig=self._launch_sig):
+                    part = p(si, sr, pv, ai, nested=nested)
+            yield pos, part
 
-    def _launch(self, prog, s: int, prep_vals, spaced: set):
-        a = self.anchor_i
-        si = list(self.scan_inputs)
-        si[a] = {i: [slabs[s]] for i, slabs in self.scan_inputs[a].items()}
-        sr = list(self.scan_rows)
-        sr[a] = (self.a_ent.alive[s],) if self.a_ent.alive is not None \
-            else np.array([self.anchor_rows[s]], dtype=np.int32)
-        if s == self.delta_id:
-            prog = self.dprog
-        ai = []
-        for jn, (matched, jcols) in zip(self.walk_joins,
-                                        self.aligned_inputs):
-            if matched and id(jn) in spaced:
-                ai.append(((matched[s],),
-                           {c: (sl[s],) for c, sl in jcols.items()}))
-            else:
-                ai.append((matched, jcols))
-        # slot per slab DISPATCH (async queue) — one labeled compute span
-        # per fused slab program in the trace
-        with self.ctx.device_slot():
-            with self.ctx.phases.launch(prog.name, slab=s,
-                                        sig=self._launch_sig):
-                return prog(tuple(si), tuple(sr), prep_vals, tuple(ai),
-                            nested=self.nested_rows)
+    @staticmethod
+    def _slab_body(prog, a: int, shared, slab):
+        si, sr, pv, ai, nested = _TreeSlabs._assemble(a, shared, slab)
+        return prog._run(si, sr, pv, ai, None, nested)
 
-    def control(self, partials) -> dict:
+    def statement_body(self, prog):
+        return functools.partial(self._slab_body, prog, self.anchor_i)
+
+    def statement_args(self, prog, prep_vals):
+        spaced = self._joins_in_anchor_space()
+        return self._shared(prep_vals, spaced), tuple(
+            self._slab_arg(s, spaced) for s in self.run_ids)
+
+    @staticmethod
+    def control(partials) -> dict:
         return {"jus": [p["join_unique"] for p in partials],
                 "jts": [p["join_totals"] for p in partials]}
+
+    def _join_flags(self, got):
+        """→ (unique_ok, totals), each [surviving slab, join]."""
+        n_run, n_joins = len(self.run_ids), len(self.join_cfgs)
+        return (np.asarray(got["jus"]).reshape(n_run, n_joins),
+                np.asarray(got["jts"]).reshape(n_run, n_joins))
+
+    def overflowed(self, got) -> bool:
+        from tidb_tpu.executor import tree_fragment as TF
+        jus, jts = self._join_flags(got)
+        return any(
+            TF.escalate_join(cfg, bool(jus[:, ji].all()),
+                             int(jts[:, ji].max()), self.out_cap_max,
+                             0)[1] is not None
+            for ji, cfg in enumerate(self.join_cfgs))
 
     def escalate(self, got, ladder):
         from tidb_tpu.executor import tree_fragment as TF
         from tidb_tpu.executor.device_cache import _pow2
-        n_run, n_joins = len(self.run_ids), len(self.join_cfgs)
-        jts = np.asarray(got["jts"]).reshape(n_run, n_joins)
-        jus = np.asarray(got["jus"]).reshape(n_run, n_joins)
+        n_run = len(self.run_ids)
+        jus, jts = self._join_flags(got)
         retry, rerun = False, set()
         for ji, cfg in enumerate(self.join_cfgs):
             new_cfg, action = TF.escalate_join(
@@ -3413,6 +3692,25 @@ class TpuFragmentExec:
                 yield self._slab(ent, ent.base_slabs, used)
 
     # -- hash agg ------------------------------------------------------------
+    @staticmethod
+    def _agg_tail(src: _SlabSource, prog, order_root, gcap: int, sig: str,
+                  n_run: int):
+        """The program that follows the slab partials → (its traced
+        function, the same jitted, its name, its launch's `sig` tag, its
+        signature): the fused finalize under an ORDER BY / TopN — ONE
+        launch for the whole query tail, agg merge → finalize expressions
+        → root ORDER BY / TopN — else the merge; no function, and the slab
+        program's signature, where one slab's partial is the answer."""
+        if order_root is not None:
+            fprog, fsig = get_finalize_program(src.root, order_root, gcap,
+                                               sig)
+            return (fprog._run, fprog.run, fprog.name,
+                    _sig_tag("fused-final", fsig), fsig)
+        if n_run == 1:
+            return None, None, None, None, sig
+        mp = src.merge_program(prog, gcap, sig)
+        return mp._merge, mp.merge, mp.merge_name, None, "merge|" + sig
+
     def _run_agg_slabs(self, src: _SlabSource, gcap: int, order_root,
                        ladder) -> Optional[Chunk]:
         """Every per-slab partial aggregate: ONE traced XLA program per
@@ -3434,10 +3732,20 @@ class TpuFragmentExec:
         charged ONE recompile against the ladder's backoff budget, and
         EscalationStats.slabs_rerun/slabs_reused make the reuse observable
         (EXPLAIN ANALYZE). → None when the source gives the statement back
-        (a join's fan-out over out_cap_max)."""
+        (a join's fan-out over out_cap_max).
+
+        A WARM statement is one launch (`_launch_plan`, `_StatementProgram`):
+        once an earlier execution of its digest has settled the capacities
+        and every slab is resident, the slab bodies and the merge/finalize
+        run as ONE traced program under ONE hold of the batch slot. The
+        loop over slabs stays the cold path (it streams a first touch) and
+        the escalating one: an overflow that the statement program's
+        control fetch shows sends the statement back here, at the same
+        capacities, for partials to resume from."""
         from tidb_tpu.executor.device_emit import partials_of
         from tidb_tpu.ops.jax_env import jax
         from tidb_tpu.util import failpoint
+        from tidb_tpu.util.observability import REGISTRY
         ph = self.ctx.phases
         root, key_bounds = src.root, src.key_bounds
         if not src.run_ids:
@@ -3488,10 +3796,12 @@ class TpuFragmentExec:
         # (no group capacity in them), one sort serves the statement
         rows_mode = grouping_mode(key_bounds) == RUNS
         sorted_rows = None
+        plan = _launch_plan(src, spec, want_pairs, rows_mode)
         caps_ran = [0] * n_run          # group cap each partial ran at
         pcaps = [0] * n_run             # pair cap each partial ran at
         pairs_cache: List = [None] * n_run     # host distinct-pair sets
         to_run: Optional[List[int]] = None     # None = cold first pass
+
         while True:
             grouping = _note_grouping(root, key_bounds, gcap)
             with timeline.span("frag.program", "frag"):
@@ -3499,7 +3809,12 @@ class TpuFragmentExec:
                     0 if rows_mode else gcap, pair_cap, want_pairs,
                     spec_sig)
             spec_sig = None
-            for s, part in src.launches(prog, prep_vals, to_run):
+            if not rows_mode:
+                tail, run_tail, tail_name, tail_tag, tail_sig = \
+                    self._agg_tail(src, prog, order_root, gcap, sig, n_run)
+            packed = None       # a statement program packs what is fetched
+            for s, part in () if plan == "whole" else \
+                    src.launches(prog, prep_vals, to_run):
                 stale, partials[s] = partials[s], part
                 ph.note_launch()
                 ph.note_fused()   # a chain partial IS a fused pipeline
@@ -3564,58 +3879,82 @@ class TpuFragmentExec:
                         pairs_cache[s] = ps
             # build the whole device graph FIRST (per-slab partials +
             # merge — no host sync in between), then fetch every control
-            # value in ONE batched round trip: the latency is paid per
-            # device_get, not per array
-            if rows_mode:
-                out, sorted_rows = self._runs_finalize(
-                    root, order_root, partials, src.n_slabs, gcap,
-                    key_bounds, sig, sorted_rows)
-            elif n_run == 1 and not use_fin:
-                out = partials[0]
-            else:
-                if use_fin:
-                    # ONE launch for the whole query tail: agg merge →
-                    # finalize expressions → root ORDER BY / TopN
-                    fprog, fsig = get_finalize_program(root, order_root,
-                                                       gcap, sig)
-                    tail, name, tag = fprog.run, fprog.name, \
-                        _sig_tag("fused-final", fsig)
-                else:
-                    mp = src.merge_program(prog, gcap, sig)
-                    tail, name, tag = mp.merge, mp.merge_name, None
-                # either takes the partials as they are and stacks them in
-                # the trace: `slots_in` partial slots reduce into `slots_out`
-                with timeline.span(
-                        "frag.merge", "frag", slots_out=int(gcap),
-                        slots_in=sum(int(p["slot_live"].shape[0])
-                                     for p in partials)), \
-                        self.ctx.device_slot():
-                    with ph.launch(name, sig=tag):
-                        out = tail(*partials_of(partials))
+            # value in ONE batched round trip (a statement program packs
+            # them besides: the host pays a fetch by the leaf)
+            if plan == "whole":
+                # ONE launch, ONE hold of the slot, for the whole statement
+                args = src.statement_args(prog, prep_vals)
+                small = not self._rows_on_device and \
+                    gcap <= SMALL_GROUP_CAP
+                sprog = get_statement_program(src, prog, n_run, tail,
+                                              tail_sig, small, args)
+                with self.ctx.device_slot():
+                    with ph.launch(sprog.name,
+                                   sig=_sig_tag("stmt", sprog.sig)):
+                        out, packed = sprog.run(*args)
+                fetch = sprog.like
                 ph.note_launch()
-            with self.ctx.device_slot():
-                with ph.glue():
-                    fetch = {"ngs": [p["n_groups"] for p in partials],
-                             "ng": out["n_groups"],
-                             **src.control(partials)}
-                    if use_fin:
-                        fetch["no"] = out["n_out"]
-                    small = not self._rows_on_device and \
-                        _piggyback_agg(
-                            fetch, out, int(out["keys"][0][0].shape[0])
-                            if rows_mode and out["keys"] else gcap)
+                ph.note_fused()
+                _count_agg_partial(grouping)
+                rows_in += sum(src.rows(s) for s in range(n_run))
+                caps_ran = [gcap] * n_run
+            else:
+                if rows_mode:
+                    out, sorted_rows = self._runs_finalize(
+                        root, order_root, partials, src.n_slabs, gcap,
+                        key_bounds, sig, sorted_rows)
+                elif tail is None:
+                    out = partials[0]
+                else:
+                    # either tail takes the partials as they are and
+                    # stacks them in the trace: `slots_in` partial slots
+                    # reduce into `slots_out`
+                    with timeline.span(
+                            "frag.merge", "frag", slots_out=int(gcap),
+                            slots_in=sum(int(p["slot_live"].shape[0])
+                                         for p in partials)), \
+                            self.ctx.device_slot():
+                        with ph.launch(tail_name, sig=tail_tag):
+                            out = run_tail(*partials_of(partials))
+                    ph.note_launch()
+                with self.ctx.device_slot(), ph.glue():
+                    small = not self._rows_on_device and (
+                        int(out["keys"][0][0].shape[0])
+                        if rows_mode and out["keys"] else gcap) \
+                        <= SMALL_GROUP_CAP
+                    fetch = _control_tree(
+                        _control_of(partials, src.control), out, small)
             with ph.drain():
                 # drain inside "compute" so the flag fetch below measures
                 # pure transfer, not the device finishing its work — but
                 # OUTSIDE the scheduler slot: the wait releases the GIL,
                 # siblings dispatch meanwhile
-                jax.block_until_ready(fetch)
+                jax.block_until_ready(fetch if packed is None else packed)
             with ph.phase("fetch"):
-                got = jax.device_get(fetch)
-            ph.add_d2h(tree_nbytes(got))
+                if packed is None:
+                    got = jax.device_get(fetch)
+                else:
+                    packed = jax.device_get(packed)
+                    got = _unpack(packed, fetch)
+                    if out is None:     # small: the result rode the fetch
+                        out = got
+            ph.add_d2h(tree_nbytes(got if packed is None else packed))
             # the slab programs' capacity boundary: everything below
             # classifies this round's overflows into re-run sets
-            failpoint.inject("fused-pipeline-overflow")
+            forced = failpoint.inject("fused-pipeline-overflow")
+            if plan == "whole" and (
+                    forced or src.overflowed(got) or (
+                        grouping_mode(key_bounds) != SLOTS and max(
+                            int(got["ng"]), *map(int, got["ngs"])) > gcap)):
+                # a capacity the digest had settled on no longer holds (or
+                # a failpoint's value says so): the per-slab driver runs
+                # the statement at the same capacities, finds the overflow
+                # in partials it can resume from, and escalates. (The
+                # launch stays counted and its rows stay in `rows_in`, as
+                # a re-run slab's do: the device did read them.)
+                _tree_delete(out)
+                plan = "slabs:overflow"
+                continue
             if use_fin:
                 # TopN k is a static trace constant and an n_groups
                 # overflow resizes through the group rung below, so the
@@ -3672,6 +4011,9 @@ class TpuFragmentExec:
                 to_run = sorted(rerun)
                 continue
             break
+        timeline.tag(launch_plan=plan)
+        REGISTRY.inc("tidb_tpu_statement_programs_total",
+                     {"plan": plan.partition(":")[0]})
         cap_out = gcap
         if rows_mode:
             gcap = _tight_cap(gcap, n_final)

@@ -80,6 +80,10 @@ class AggFunc:
     """One aggregate's state machine. State = tuple of (G,)-arrays."""
 
     device_capable = True  # set False for host-only (string/object states)
+    # `merge` ADDS floating-point states (a float SUM/AVG, a variance): its
+    # result depends on the order of the additions, which one reduction
+    # over stacked partials leaves to the compiler
+    float_sums = False
 
     def __init__(self, desc: AggDesc):
         self.desc = desc
@@ -166,7 +170,7 @@ class SumAgg(AggFunc):
 
     def __init__(self, desc: AggDesc):
         super().__init__(desc)
-        self._float = self.ftype.kind.is_float
+        self._float = self.float_sums = self.ftype.kind.is_float
         self._in_scale = desc.args[0].ftype.scale
         self._out_scale = self.ftype.scale
         # wide result (> 18 digits): EXACT Python-int accumulation on the
@@ -566,6 +570,8 @@ class FirstRowAgg(AggFunc):
 
 
 class VarianceAgg(AggFunc):
+    float_sums = True
+
     def __init__(self, desc: AggDesc, sample: bool, stddev: bool):
         super().__init__(desc)
         self.sample = sample

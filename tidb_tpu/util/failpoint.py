@@ -128,7 +128,9 @@ register("exchange-degraded-replan", "entry of degraded-mesh mode for an "
 register("fused-pipeline-overflow", "capacity boundary of the slab-loop "
          "aggregate driver, over a chain's slabs as over a join tree's — "
          "hit after every round's batched flag fetch, right before "
-         "join/group overflows are classified into rerun sets "
+         "join/group overflows are classified into rerun sets; a VALUE "
+         "reads as an overflow of a whole-statement program, which the "
+         "per-slab driver then answers "
          "(executor/fragment.py _run_agg_slabs)")
 register("compressed-decode-mismatch", "layout-descriptor validation of "
          "the compressed device-resident columns a statement is about to "

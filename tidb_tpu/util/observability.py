@@ -20,6 +20,7 @@ memtables (infoschema_tables.py)."""
 
 from __future__ import annotations
 
+import functools
 import re
 import time
 from collections import OrderedDict, deque
@@ -372,7 +373,16 @@ _NORM_SIGN = re.compile(
 
 def normalize_sql(sql: str) -> str:
     """SQL digest: literals → ?, collapsed whitespace (the reference's
-    parser.Normalize)."""
+    parser.Normalize). One statement asks for its digest several times
+    (the summary, the scheduler's cost and placement hints, a fragment's
+    specialization key — five times a TPC-H Q1, 0.07 ms each), so short
+    texts are remembered; a bulk INSERT's is not kept."""
+    if len(sql) > _NORM_MEMO_TEXT:
+        return _normalize(sql)
+    return _normalize_memo(sql)
+
+
+def _normalize(sql: str) -> str:
     s = _NORM_STR.sub("?", sql)
     s = _NORM_NUM.sub("?", s)
     # collapse unary sign into the placeholder (repeat for `- - 5`)
@@ -385,6 +395,10 @@ def normalize_sql(sql: str) -> str:
     s = re.sub(r"\((\s*\?\s*,)+\s*\?\s*\)", "(?)", s)
     s = re.sub(r"(\(\?\)\s*,\s*)+\(\?\)", "(?)", s)
     return s[:512]
+
+
+_NORM_MEMO_TEXT = 4096      # characters: the longest text worth keeping
+_normalize_memo = functools.lru_cache(maxsize=1024)(_normalize)
 
 
 REGISTRY = Registry()
