@@ -197,7 +197,11 @@ def test_a_superseded_generation_frees_by_reference_count(ds):
     raises its young threshold: `server._tune_gc`). With the collector
     off, ten operations leave the device's live bytes where they were
     (`fragment._plan_aligned_joins`' recursive closure used to hold every
-    generation it had seen: 14 GB of a 16 GB chip in a 40 s window)."""
+    generation it had seen: 14 GB of a 16 GB chip in a 40 s window). The
+    cache keeps `KEPT_GENERATIONS` older generations behind each newest
+    one for the readers a commit overtakes, so the bytes are steady once
+    that many commits have gone by since the last rebuild (the first
+    RF1's `aligned-key-domain`): six operations before the count."""
     import gc
 
     from tidb_tpu.executor import device_cache
@@ -213,7 +217,8 @@ def test_a_superseded_generation_frees_by_reference_count(ds):
         return sum(a.nbytes for a in jax.live_arrays())
 
     try:
-        _drive(ds, client, data, 3)
+        assert device_cache.KEPT_GENERATIONS <= 8
+        _drive(ds, client, data, 6)
         gc.collect()
         before = live()
         gc.disable()
@@ -221,7 +226,7 @@ def test_a_superseded_generation_frees_by_reference_count(ds):
             kind = _load("ops", "refresh_pair")
             op = kind.bind({"kind": "refresh_pair", "orders": K,
                             "reads": ["Q1", "Q3", "Q6"]}, ds, None)
-            op["next"][0] = 3
+            op["next"][0] = 6
             for _ in range(10):
                 kind.run(client, op)
             held = live()

@@ -4,6 +4,7 @@ have a lane, and the benchmark's reducer (`benchmarks/span_cpu.py`: pure
 arithmetic, so tier-1 checks it on a synthetic nest) closes a request's
 account: root = CPU + named waits + what is left, the interpreter's lock."""
 
+import gc
 import importlib.util
 import json
 import os
@@ -499,16 +500,23 @@ def test_each_write_renders_only_the_events_since_the_last(recorder,
             rendered.append(len(obj))
         return dumps(obj, *a, **k)
     monkeypatch.setattr(timeline.json, "dumps", counting)
-    for n in (500, 20, 3):
-        for i in range(n):
-            with timeline.span(f"s{i}", "exec", pid=n):
-                pass
-        path = timeline.flush()
-    assert rendered == [500, 20, 3]
-    assert timeline.flush() == path and rendered == [500, 20, 3]   # clean
-    monkeypatch.undo()
-    got = _file_events(path)
-    assert len(got) == 523 == len(timeline.last_events())
+    # (a full collection that happens to fall due in here — it depends on
+    # what the tests before this one allocated — records a `gc.gen2` span
+    # of its own while the recorder is on)
+    gc.disable()
+    try:
+        for n in (500, 20, 3):
+            for i in range(n):
+                with timeline.span(f"s{i}", "exec", pid=n):
+                    pass
+            path = timeline.flush()
+        assert rendered == [500, 20, 3]
+        assert timeline.flush() == path and rendered == [500, 20, 3]  # clean
+        monkeypatch.undo()
+        got = _file_events(path)
+        assert len(got) == 523 == len(timeline.last_events())
+    finally:
+        gc.enable()
     with open(path) as f:
         meta = [e for e in json.load(f)["traceEvents"] if e["ph"] == "M"]
     assert {m["pid"] for m in meta} == {500, 20, 3}     # later lanes named

@@ -41,6 +41,11 @@ correct, and every decline is counted by the gate that tripped
                     live prefix and uniform slabs (order/filter roots,
                     windows, the mega-slab loop, sorted-runs grouping): it
                     gets a plain rebuild cached BESIDE the generation
+  behind            the statement's snapshot is OLDER than every generation
+                    the cache keeps of the table (device_cache: a key's
+                    newest and `KEPT_GENERATIONS` behind it): a plain
+                    rebuild cached BESIDE them in a slot of its own, never
+                    in the newest's place nor in the `consumer` gate's
   aligned-<why>     an FK-aligned join structure could not follow its
                     tables' generations and was rebuilt (device_cache.
                     _advance_aligned names the reasons)
@@ -322,7 +327,7 @@ def _pad_idx(pos: np.ndarray, cap: int) -> np.ndarray:
 
 def extend_entry(ctx, scan, ent, max_slab: int, phases=None,
                  masked: bool = False, quiet: bool = False,
-                 private: bool = False):
+                 private: bool = False, made=None, then=None):
     """Extend a stale cached entry into a NEW generation (sharing the
     base device arrays with `ent`), or count the decline and → None (the
     caller rebuilds). Never mutates `ent`. `masked`: a generation with
@@ -332,7 +337,12 @@ def extend_entry(ctx, scan, ent, max_slab: int, phases=None,
     other thread can reach `ent` (a compaction's generation before the
     swap), so the statements' extensions do not wait for this one — which
     may cover minutes of writes in bucket sizes no statement ever
-    compiled — and nothing is counted."""
+    compiled — and nothing is counted. `made`: () → the generation of
+    this snapshot where another statement made it while this one waited
+    for its turn (several connections meet one commit at once: the first
+    extends, the others take what it made), else None; `then(generation)`
+    is run before the next statement gets its turn (the install: a waiting
+    statement must find what this one made)."""
     from tidb_tpu.util.phases import PhaseTimer
     corrupted = failpoint.inject("delta-merge-stale")
     if corrupted is not None:
@@ -343,7 +353,12 @@ def extend_entry(ctx, scan, ent, max_slab: int, phases=None,
     quiet = quiet or private
     with contextlib.nullcontext() if private else _EXT_LOCK:
         try:
-            return _extend_locked(ctx, scan, ent, max_slab, ph, masked)
+            got = made() if made is not None else None
+            if got is None:
+                got = _extend_locked(ctx, scan, ent, max_slab, ph, masked)
+            if then is not None:
+                then(got)
+            return got
         except LayoutError:
             raise
         except _Declined as d:
@@ -623,6 +638,20 @@ def schedule_compaction(store, key, scan, cols, max_slab: int,
 def pending_compactions() -> int:
     with _PENDING_LOCK:
         return len(_PENDING)
+
+
+def forget_store(store, timeout_s: float = 10.0) -> None:
+    """An engine closes: its queued compactions are dropped, and the one in
+    flight is waited for (bounded) — a compaction left running goes on
+    compiling and warming in a process whose next engine's statements it
+    has nothing to do with."""
+    with _PENDING_LOCK:
+        for key in [k for k, job in _PENDING.items()
+                    if job["store"]() in (store, None)]:
+            del _PENDING[key]
+    if threading.current_thread() is not _WORKER \
+            and _DRAIN_LOCK.acquire(timeout=timeout_s):
+        _DRAIN_LOCK.release()
 
 
 def _pop_job():

@@ -20,6 +20,23 @@ TiFlash availability per table); coprocessor cache
 (store/copr/coprocessor_cache.go) is the reference's other read-cache
 precedent.
 
+One key, two generations. A key's entry is the NEWEST generation any
+statement has read; behind it the entry keeps the generation it superseded
+(`CachedTable.kept`, found by the identity of its `TableData`): a statement
+whose snapshot was taken before a commit that another connection's statement
+has already extended the cache past is served from the generation of ITS
+snapshot, with no rebuild and no upload of resident rows. Generations of one
+base build share every base array; a kept one owns only what the commit
+replaced (the changed slabs' liveness masks, or the delta slab's arrays), so
+it is bounded by a count and by those bytes (`KEPT_GENERATIONS`,
+`KEPT_BYTES`) and dropped once the store's history cannot hand its
+`TableData` to a snapshot any more. The entry never moves backwards: a
+snapshot older than what is kept gets the counted plain rebuild BESIDE the
+cache (gate `behind`, a slot of its own). The FK-aligned join structures keep
+theirs the same way (`AlignedJoin.kept`), paired by the `TableData` of ONE
+snapshot. `tidb_tpu_delta_generation_reads_total{age=}` says what every
+cached read was served from.
+
 Pod-scale serving shards this cache BY DEVICE: keys carry the owning
 pool device index — `(dev, store_id, table_id, parts)` — each entry's
 arrays are committed to that device via jax.device_put, and the HBM
@@ -59,6 +76,17 @@ DEFAULT_HBM_BUDGET_BYTES = 8 << 30
 # a per-device replica of a fact table would blow every device's budget
 # for no locality win
 DEFAULT_PARTITION_MIN_ROWS = 1 << 22
+# older generations kept behind a key's newest one, for the statement whose
+# snapshot another connection's commit-and-read has overtaken. ONE: under
+# three query streams beside a refresh stream a statement arrives behind the
+# cache about once in a thousand and never by more than a commit (PERF.md,
+# PR 40: a commit lands every 250 ms, a statement takes 30); a reader further
+# behind (`AS OF TIMESTAMP` minutes back) gets the rebuild beside. And at
+# most this many bytes of what it owns beyond the arrays the newest
+# generation holds too (liveness masks, the delta slab's arrays; a key's
+# aligned structures: the lookup table, the changed slabs' match masks)
+KEPT_GENERATIONS = 1
+KEPT_BYTES = 128 << 20
 
 
 class CachedTable:
@@ -79,7 +107,7 @@ class CachedTable:
                  "cov", "max_rid", "seen", "rowmap", "lineage", "base_td",
                  "alive",
                  "delta_cap", "delta_rows", "dead_rows", "steps",
-                 "device", "owners", "lost", "live_dev")
+                 "device", "owners", "lost", "live_dev", "kept", "kept_bytes")
 
     def __init__(self, td, max_slab: int, total: int, slab_cap: int,
                  n_slabs: int, parts, n_cols: int, compressed: bool = False):
@@ -124,6 +152,11 @@ class CachedTable:
         self.delta_rows = 0
         self.dead_rows = 0
         self.steps: tuple = ()
+        # older generations of this base build, oldest first, while this
+        # one is the key's newest (`_install_generation`), and the bytes
+        # they own beyond what this one holds
+        self.kept: tuple = ()
+        self.kept_bytes = 0
         # pod-scale placement: the pool device index owning this entry's
         # arrays (-1 = pod-partitioned), and for pod entries the per-slab
         # owner device list (contiguous spans — slab s lives on owners[s])
@@ -241,7 +274,9 @@ class CachedTable:
         return min(self.slab_cap, self.total - s * self.slab_cap)
 
     def hbm_bytes(self) -> int:
-        return sum(a.nbytes for _s, a in self._arrays())
+        """What the generation holds, and what the kept ones behind it own
+        beyond that."""
+        return sum(a.nbytes for _s, a in self._arrays()) + self.kept_bytes
 
     def logical_bytes(self, cols=None) -> int:
         """Bytes the selected columns WOULD occupy uncompressed (raw
@@ -295,6 +330,145 @@ def _entry_delete(ent) -> None:
     delete = getattr(ent, "delete", None)
     if delete is not None:
         delete()
+
+
+# ---- the generations kept behind a key's newest one ------------------------
+# (tables and aligned structures alike: `arrays(g)` → g's device arrays,
+# `version(g)` → the store version of the snapshot it was made for)
+
+def _table_arrays(g):
+    """A generation's device arrays (one that several slabs share comes
+    more than once: `_keep_behind` tells them apart by identity)."""
+    for slabs in g.dev.values():
+        for t in slabs:
+            if t is not None:       # (a pruned-away cold slab: a hole)
+                yield from t
+    yield from g.alive or ()
+
+
+def _keep_behind(newest, older, arrays, live=None) -> None:
+    """`older` (oldest first) become the generations kept behind `newest`.
+    The oldest go while there are more than `KEPT_GENERATIONS`, or while
+    what they own beyond `newest`'s arrays exceeds `KEPT_BYTES`; one that
+    `live` says no snapshot can ask for any more goes at once. What goes is
+    dropped, never deleted: it shares arrays with what stays, and its last
+    reference frees what it alone held."""
+    kept = [g for g in older if live is None or live(g)]
+    kept = kept[max(len(kept) - KEPT_GENERATIONS, 0):]
+    held = {id(a) for a in arrays(newest)}
+    own = []
+    for g in reversed(kept):                # the youngest claims first
+        n = 0
+        for a in arrays(g):
+            if id(a) not in held:
+                held.add(id(a))
+                n += a.nbytes
+        own.append(n)
+    own.reverse()
+    while kept and sum(own) > KEPT_BYTES:
+        kept.pop(0)
+        own.pop(0)
+    newest.kept, newest.kept_bytes = tuple(kept), sum(own)
+
+
+def _install_generation(tbl, key, cur, new, same, version, arrays,
+                        live=None):
+    """`new` takes its place under `key` of `tbl`, whose entry `cur` is
+    None or of `new`'s base build (called under `_LOCK`) → (the generation
+    to serve, "newest" | "kept"). Newer than the entry: it becomes the
+    entry, and the entry what is kept behind it. Older (its statement
+    waited while others stepped the cache past its snapshot): it serves
+    that statement alone and the entry stays — an entry never moves
+    backwards. `same(g)`: another thread made this generation meanwhile,
+    adopt its."""
+    if cur is None:
+        tbl[key] = new
+        tbl.move_to_end(key)
+        return new, "newest"
+    for g in (cur,) + cur.kept:
+        if same(g):
+            return g, "newest" if g is cur else "kept"
+    if version(new) <= version(cur):
+        return new, "kept"
+    older = cur.kept + (cur,)
+    cur.kept, cur.kept_bytes = (), 0
+    tbl[key] = new
+    tbl.move_to_end(key)
+    _keep_behind(new, older, arrays, live)
+    _note_kept()
+    return new, "newest"
+
+
+def _generation_for(newest, version: int, usable):
+    """A snapshot of store version `version` whose data is not `newest`'s
+    → (the kept generation of that very data, None), else (None, `newest`)
+    to extend from for a snapshot ahead of the cache, else (None, None):
+    the snapshot is older than what is kept."""
+    for g in newest.kept:
+        if usable(g):
+            return g, None
+    return None, newest if newest.delta_version < version else None
+
+
+def _generation_of(entry, td, lineage):
+    """The generation of `td` among `entry` and those kept behind it, of
+    the base build `lineage`, or None."""
+    if entry is None or entry.lineage != lineage:
+        return None
+    return next((g for g in (entry,) + entry.kept if g.td is td), None)
+
+
+class _OneCommit:
+    """A statement's context as an extension reads it, at ONE commit's
+    view of one table."""
+    __slots__ = ("snapshot", "vars")
+
+    def __init__(self, ctx, snapshot):
+        self.snapshot, self.vars = snapshot, ctx.vars
+
+
+def _commits_to(ctx, store, table_id: int, base, td, version: int) -> list:
+    """The contexts to extend `base` through, one a commit that changed
+    the table since `base`'s snapshot, the last of them `ctx` itself
+    (whose snapshot holds `td`); `[ctx]` where the store's history does
+    not reach back that far, and [] without a `base`."""
+    if base is None:
+        return []
+    versions = getattr(store, "table_versions", None)
+    path = [] if versions is None else \
+        [(v, t) for v, t in versions(table_id, base.delta_version, version)
+         if t is not base.td]
+    if len(path) < 2 or path[-1][1] is not td:
+        return [ctx]
+    from tidb_tpu.storage import Snapshot
+    return [_OneCommit(ctx, Snapshot({table_id: t}, v, store))
+            for v, t in path[:-1]] + [ctx]
+
+
+def _store_holds(store, table_id: int):
+    """ids of the `TableData` of `table_id` that a snapshot of `store` can
+    still be handed, or None where the store does not say."""
+    holds = getattr(store, "table_history", None)
+    return None if holds is None else holds(table_id)
+
+
+def _note_kept() -> None:
+    """The gauges: generations kept behind the newest ones, and the bytes
+    they own (called under `_LOCK`)."""
+    from tidb_tpu.util.observability import REGISTRY
+    ents = [e for e in list(_CACHE.values()) + list(_ALIGNED.values())
+            if getattr(e, "kept", None)]    # (tests' doubles have none)
+    REGISTRY.set_gauge("tidb_tpu_delta_generations_kept",
+                       sum(len(e.kept) for e in ents))
+    REGISTRY.set_gauge("tidb_tpu_delta_generations_kept_bytes",
+                       sum(e.kept_bytes for e in ents))
+
+
+def _count_read(age: str) -> None:
+    """What a cached read was served from: the key's `newest` generation,
+    one `kept` behind it, or a plain table `rebuilt` beside the cache."""
+    from tidb_tpu.util.observability import REGISTRY
+    REGISTRY.inc("tidb_tpu_delta_generation_reads_total", {"age": age})
 
 
 _CACHE: "OrderedDict[int, CachedTable]" = OrderedDict()
@@ -452,9 +626,12 @@ def _drop_entry(key, ent) -> None:
     """Take `ent` out of the cache (if it is still what `key` holds) and
     free what no statement in flight computes on."""
     with _LOCK:
-        if _CACHE.get(key) is ent:
+        held = _CACHE.get(key) is ent
+        if held:
             _CACHE.pop(key, None)
-    _safe_delete(ent, key[1:3])
+    if held:
+        # (a generation kept behind the entry shares its arrays)
+        _safe_delete(ent, key[1:3])
 
 
 def clear():
@@ -464,6 +641,7 @@ def clear():
         _CACHE.clear()
         _ALIGNED.clear()
         _READERS.clear()
+        _note_kept()
     for k, e in cache:
         _safe_delete(e, k[1:3])
     for e in aligned:
@@ -482,6 +660,7 @@ def invalidate(table_id: int):
             ent = _ALIGNED.pop(key, None)
             if ent is not None:
                 dead_a.append(ent)
+        _note_kept()
     for key, ent in dead_c:
         _safe_delete(ent, key[1:3])
     for ent in dead_a:
@@ -520,6 +699,7 @@ def _evict_store(store_id: int):
         _STORE_FINALIZERS.pop(store_id, None)
         for k in [k for k in _READERS if k[0] == store_id]:
             del _READERS[k]
+        _note_kept()
     for key, ent in dead_c:
         _safe_delete(ent, key[1:3])
     for ent in dead_a:
@@ -1331,14 +1511,17 @@ def storage_stats(store_id: Optional[int] = None) -> List[dict]:
             lay = ent.layouts.get(i)
             seen = set()
             phys = 0
-            for t in ent.dev[i]:
-                if t is None:
-                    continue            # pruned-away cold slab (hole)
-                for a in t:
-                    if id(a) in seen:
-                        continue
-                    seen.add(id(a))
-                    phys += a.nbytes
+            # (with what the generations kept behind this one own of the
+            # column: their delta slab's arrays)
+            for g in (ent,) + tuple(getattr(ent, "kept", ())):
+                for t in g.dev.get(i, ()):
+                    if t is None:
+                        continue        # pruned-away cold slab (hole)
+                    for a in t:
+                        if id(a) in seen:
+                            continue
+                        seen.add(id(a))
+                        phys += a.nbytes
             zm = ent.zmaps.get(i)
             zlo = zhi = None
             if zm is not None:
@@ -1373,7 +1556,7 @@ def _protected(ctx) -> frozenset:
 
 def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                prune: bool = False, delta_ok: bool = False,
-               _plain: bool = False):
+               _plain: str = ""):
     """→ (CachedTable, slab stream or None) — the streamed first-touch.
 
     Warm path (every used column already resident) returns stream=None.
@@ -1401,9 +1584,12 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
     is (liveness masks, a raw delta slab of its own capacity). Every other
     consumer assumes a live prefix and uniform slabs: where the table's
     entry is a delta generation it gets a plain rebuild, counted as a
-    decline of gate `consumer` and cached BESIDE the generation (`_plain`:
-    the same key with its partitions tagged "plain"), which stays where it
-    is for the statements that extend it.
+    decline of gate `consumer` and cached BESIDE the generation (`_plain`,
+    the gate's name: the same key with its partitions tagged "plain"),
+    which stays where it is for the statements that extend it. A snapshot
+    older than what the key keeps gets one the same way, gate `behind`,
+    tagged "behind". The tagged slot of `consumer` never moves backwards
+    either; the slot of `behind` holds the last such reader's snapshot.
     """
     from tidb_tpu.util import failpoint
     from tidb_tpu.util.phases import PhaseTimer
@@ -1434,11 +1620,14 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
     key = (dev, id(store), table_id,
            None if parts is None else tuple(parts)) if cacheable else None
     if _plain and key is not None:
-        key = key[:3] + (("plain", key[3]),)
+        # (a slot a gate: a reader far behind never costs the order/filter
+        # roots their plain table of the newest snapshot)
+        key = key[:3] + (("plain" if _plain == "consumer" else _plain,
+                          key[3]),)
 
-    def _plain_beside():
+    def _plain_beside(gate="consumer"):
         return open_table(ctx, scan, used_cols, max_slab, phases=phases,
-                          prune=prune, _plain=True)
+                          prune=prune, _plain=gate)
 
     _reap_dead_stores()
     with _LOCK:
@@ -1456,8 +1645,11 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                 and e.compressed == comp_on)
 
     stale = None
-    extend_from = None
+    newest = None           # the key's entry, where `td` is not its data
+    age = "newest"
     pv = _previewing()
+    version = int(getattr(ctx.snapshot, "version", 0) or 0) \
+        if cacheable else 0
     with _LOCK:
         ent = _CACHE.get(key) if cacheable else None
         if pv is not None and key == pv.key:
@@ -1470,10 +1662,15 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                     and ent.n_cols == len(scan.schema)
                     and ent.compressed == comp_on
                     and ent.seen is not None and not _plain):
-                # stale ONLY because the data moved on (geometry, schema
-                # width and compression all still match): try the
-                # incremental delta extension before paying a rebuild
-                extend_from = ent
+                # not this snapshot's ONLY because the data moved on
+                # (geometry, schema width and compression all still
+                # match): the snapshot's own generation if it is kept,
+                # else the incremental delta extension of the nearest
+                # older one, before paying a rebuild
+                newest, ent = ent, None
+            elif _plain == "consumer" and ent.delta_version > version:
+                # a later snapshot's plain table stays where it is (built
+                # below: this statement's serves it alone)
                 ent = None
             else:
                 _CACHE.pop(key, None)
@@ -1483,44 +1680,88 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
             _CACHE.move_to_end(key)
     if stale is not None:
         _safe_delete(stale, key[1:3])
-    if extend_from is not None:
+    if newest is not None:
         from tidb_tpu.executor import delta as _delta
-        new_ent = _delta.extend_entry(
-            ctx, scan, extend_from, max_slab, phases, quiet=pv is not None)
-        if new_ent is not None:
-            with _LOCK:
-                cur = _CACHE.get(key)
-                if cur is extend_from:
-                    # atomic generation swap: in-flight readers keep the
-                    # old object (their snapshot), new statements see
-                    # base∪delta−tombstones. The old generation is NOT
-                    # deleted — it shares the base device arrays with the
-                    # new one; refcounting frees what only it held.
-                    _CACHE[key] = new_ent
-                    _CACHE.move_to_end(key)
-                    ent = new_ent
-                elif cur is not None and _usable(cur):
-                    ent = cur    # raced another extension/rebuild: adopt
-        if ent is None and pv is not None:
-            # (a warm-up never costs the statements in flight an entry)
-            raise PreviewMiss(f"table {table_id}")
-        if ent is None:
-            # extension declined (a gate tripped) or lost the install
-            # race. Drop the stale generation and rebuild — but only
-            # delete it if WE pop it: when another thread replaced the
-            # slot (e.g. its own extension won), that entry may share
-            # the base device arrays with extend_from, and an explicit
-            # delete here would free buffers it is serving.
-            dead = None
-            with _LOCK:
-                cur = _CACHE.get(key)
-                if cur is extend_from:
-                    _CACHE.pop(key, None)
-                    dead = extend_from
-                elif cur is not None and _usable(cur):
-                    ent = cur
-            if dead is not None:
-                _safe_delete(dead, key[1:3])
+        with timeline.span("delta.generation", "delta", table=table_id):
+            ent, base = _generation_for(newest, version, _usable)
+            if ent is not None:
+                age = "kept"
+            forward = base is not None
+            live = _store_holds(store, table_id)
+            still_held = None if live is None else \
+                (lambda g: id(g.td) in live)
+            # one commit at a time: every extension then has the shapes of
+            # ONE transaction, whatever the number of commits since the
+            # cache was last read (no program of a new bucket size in a
+            # statement)
+            for step in _commits_to(ctx, store, table_id, base, td, version):
+                ent, moved = None, []
+
+                def _swap(new_ent):
+                    # (under the extensions' lock: the next statement to
+                    # get its turn finds this generation installed)
+                    with _LOCK:
+                        cur = _CACHE.get(key)
+                        if cur is None or cur.lineage == new_ent.lineage:
+                            # the generation swap: in-flight readers keep
+                            # the object they hold (their snapshot), a
+                            # statement at this snapshot or a later one
+                            # finds this. No generation is deleted here —
+                            # those of one base build share its device
+                            # arrays; refcounting frees what a dropped one
+                            # alone held.
+                            moved[:] = _install_generation(
+                                _CACHE, key, cur, new_ent,
+                                lambda g: g.td is new_ent.td,
+                                lambda g: g.delta_version, _table_arrays,
+                                still_held)
+                        elif _usable(cur):
+                            # (rebuilt meanwhile, at this snapshot)
+                            moved[:] = cur, "newest"
+                        else:
+                            # the key went to another base build meanwhile
+                            # (a rebuild, a compaction): served as it is
+                            moved[:] = new_ent, "newest" \
+                                if new_ent.delta_version \
+                                > cur.delta_version else "kept"
+
+                if _delta.extend_entry(
+                        step, scan, base, max_slab, phases,
+                        quiet=pv is not None, then=_swap,
+                        made=lambda: _generation_of(
+                            _CACHE.get(key),
+                            step.snapshot.table_data(table_id),
+                            base.lineage)) is None:
+                    break
+                base = ent = moved[0]
+                age = moved[1]
+                if ent.td is td:
+                    break
+            if ent is None and pv is not None:
+                # (a warm-up never costs the statements in flight an entry)
+                raise PreviewMiss(f"table {table_id}")
+            if ent is None and forward:
+                # the extension of the newest generation declined (a gate
+                # tripped): drop it and rebuild — but only delete it if
+                # WE pop it: when another thread replaced the slot (its
+                # own extension won), that entry may share the base
+                # device arrays with `newest`, and an explicit delete
+                # here would free buffers it is serving.
+                dead = None
+                with _LOCK:
+                    cur = _CACHE.get(key)
+                    if cur is newest:
+                        _CACHE.pop(key, None)
+                        dead = newest
+                    elif cur is not None and _usable(cur):
+                        ent = cur
+                if dead is not None:
+                    _safe_delete(dead, key[1:3])
+            timeline.tag(age="rebuilt" if ent is None else age)
+        if ent is None and not forward:
+            # a snapshot older than what is kept: the counted plain rebuild
+            # BESIDE the cache. The key keeps its newest generation.
+            return _plain_beside("behind")
     if ent is not None and ent.is_delta and not delta_ok:
         # a delta generation (just extended, or fresh), and a consumer
         # that cannot take one
@@ -1528,7 +1769,8 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
     if ent is None:
         if _plain:
             from tidb_tpu.executor import delta as _delta
-            _delta.decline("consumer", table_id)
+            _delta.decline(_plain, table_id)
+            age = "rebuilt"
         if cacheable:
             parts, total, cov, max_rid = _collect_parts(ctx, scan,
                                                         coverage=True)
@@ -1560,6 +1802,12 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                     # lost a cold-build race: adopt the winner, drop ours
                     ent = cur
                     _CACHE.move_to_end(key)
+                elif cur is not None and _plain != "behind" \
+                        and cur.delta_version > built.delta_version:
+                    # a statement at a later snapshot got there meanwhile:
+                    # its generation stays (an entry never moves
+                    # backwards) and this build serves its statement alone
+                    ent = built
                 else:
                     if cur is not None:
                         # another statement's generation for ANOTHER
@@ -1600,6 +1848,8 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         if ent.is_delta and not delta_ok:
             return _plain_beside()      # (adopted from a lost race)
 
+    if cacheable:
+        _count_read(age)
     if not ent.total:
         return ent, None
     ph = phases if phases is not None else PhaseTimer()
@@ -1842,7 +2092,8 @@ class AlignedJoin:
 
     __slots__ = ("tds", "slab_cap", "n_slabs", "unique", "matched",
                  "midx", "cols", "build_nb", "key", "lut", "lo", "domain",
-                 "space", "dangling", "bcat")
+                 "space", "dangling", "bcat", "version", "kept",
+                 "kept_bytes")
 
     def __init__(self, key, tds, slab_cap, n_slabs, build_nb):
         self.key = key
@@ -1867,6 +2118,12 @@ class AlignedJoin:
         # adopt such a row means a rebuild)
         self.dangling = None
         self.bcat: Dict[int, Tuple] = {}    # build col → decoded base rows
+        # the store version of the snapshot whose generations it pairs,
+        # and the older structures kept behind it while it is the key's
+        # newest (as `CachedTable.kept`)
+        self.version = 0
+        self.kept: tuple = ()
+        self.kept_bytes = 0
 
     def _owned(self):
         for arrs in (self.matched, self.midx):
@@ -1882,7 +2139,7 @@ class AlignedJoin:
             yield m
 
     def hbm_bytes(self) -> int:
-        return sum(a.nbytes for a in self._owned())
+        return sum(a.nbytes for a in self._owned()) + self.kept_bytes
 
     def delete(self) -> None:
         """Free device buffers on eviction (see CachedTable.delete)."""
@@ -1949,33 +2206,70 @@ def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
     pv = _previewing()
     # (a compaction's warm-up builds aside: `Preview`)
     tbl = pv.aligned if pv is not None else _ALIGNED
+    version = int(getattr(ctx.snapshot, "version", 0) or 0)
+
+    def _family(g):
+        # of these base builds, in this layout: its arrays are positioned
+        # as this snapshot's generations are
+        return g.space == space and g.slab_cap == slab_cap
+
+    def _this(g):
+        # the structure of THIS snapshot's generations of every table
+        return _fresh(ctx, g.tds) and _family(g) and g.n_slabs == n_slabs
+
+    def _install(new):
+        """Under the key, before or behind what it holds → what serves."""
+        new.version = version
+        with _LOCK:
+            cur = tbl.get(key)
+            if cur is None or (_family(cur) and cur.unique):
+                new, _age = _install_generation(
+                    tbl, key, cur, new, _this, lambda g: g.version,
+                    AlignedJoin._owned)
+            elif _this(cur):
+                new = cur       # (lost a concurrent build race: adopt)
+            elif cur.version <= version:
+                tbl[key] = new
+                tbl.move_to_end(key)
+            # (else: another base build's, and newer: served uninstalled)
+        return new
+
     with _LOCK:
         ent = tbl.get(key)
         if ent is not None:
-            if _fresh(ctx, ent.tds) and ent.slab_cap == slab_cap \
-                    and ent.n_slabs == n_slabs and ent.space == space:
-                tbl.move_to_end(key)
-                return ent if ent.unique else None
+            # the structure that pairs the generations of this snapshot:
+            # the key's newest, or one kept behind it for the statements
+            # another connection's commit has overtaken
+            for g in (ent,) + ent.kept[::-1]:
+                if _this(g):
+                    if g is ent:
+                        tbl.move_to_end(key)
+                    return g if g.unique else None
             stale = ent
     if stale is not None and fact is not None and stale.unique:
         with timeline.span("delta.aligned", "delta",
                            table=next(iter(tds), 0)):
-            new = _advance_aligned(stale, tds, fact[0], fact[1], build_ent,
-                                   build_key_col, bounds)
+            # from the youngest structure whose tables' generations these
+            # ones were extended from (`steps`: this one's are not)
+            for src in (stale,) + stale.kept[::-1]:
+                new = _advance_aligned(src, tds, fact[0], fact[1],
+                                       build_ent, build_key_col, bounds)
+                if new != "steps":
+                    break
         if isinstance(new, str):
             # the structure is rebuilt in full (a decode, a probe and a
             # gather over every fact row): counted like a table's rebuild
             if pv is None:
                 from tidb_tpu.executor import delta as _delta
                 _delta.decline("aligned-" + new, next(iter(tds), 0))
-            new = None
-        if new is not None:
-            with _LOCK:
-                if tbl.get(key) is stale:
-                    tbl[key] = new
-                    tbl.move_to_end(key)
-            return new
-    if stale is not None:
+        else:
+            return _install(new)
+    if stale is not None and not (_family(stale) and stale.unique) \
+            and stale.version <= version:
+        # another base build's rows (a compaction, a rebuild): nothing of
+        # it serves any snapshot's generations again. (One of a LATER
+        # snapshot stays: this statement is behind the cache, on tables
+        # rebuilt beside it, and what it builds serves it alone.)
         with _LOCK:
             if tbl.get(key) is stale:
                 tbl.pop(key, None)
@@ -2040,15 +2334,8 @@ def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
         ent.matched.append(matched)
         dangling = dangling + n_dang
     ent.dangling = dangling
-    with _LOCK:
-        cur = tbl.get(key)
-        if cur is not None and _fresh(ctx, cur.tds) and cur.space == space \
-                and cur.slab_cap == slab_cap and cur.n_slabs == n_slabs:
-            # lost a concurrent build race: adopt the installed entry
-            # (byte-identical build), ours frees via refcount
-            return cur if cur.unique else None
-        tbl[key] = ent
-    return ent
+    got = _install(ent)
+    return got if got.unique else None
 
 
 def _gather_program(col: int, bv, cap: int):
@@ -2247,7 +2534,6 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
 
     # ---- the fact side: the rows that arrived, probed and gathered into
     # the arrays of the fact's delta slab
-    appended = [a for st in fsteps for a in st["appended"]]
     if fact_ent.delta_cap and len(new.matched) == fact_ent.base_slabs:
         dcap = fact_ent.delta_cap
         new.matched.append(jnp.zeros(dcap, dtype=bool))
@@ -2256,7 +2542,9 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
             v0 = sl[0][0]
             sl.append((jnp.zeros(v0.shape[:-1] + (dcap,), dtype=v0.dtype),
                        jnp.zeros(dcap, dtype=bool)))
-    if appended:
+    # (a step at a time: each has the shapes of ONE commit's rows, so the
+    # program is the one every commit before it ran)
+    for appended in (st["appended"] for st in fsteps if st["appended"]):
         dcap, d = fact_ent.delta_cap, fact_ent.base_slabs
         parts = [_key_col(r, fact_col, a, b) for r, a, b, _o in appended]
         if any(p is None for p in parts):
@@ -2278,7 +2566,7 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
         nb = new.build_nb
 
         def _extend(lut, keys, kmask, off_, n_, matched, midx, acols,
-                    bcat, bdel):
+                    bcat, bdel, bucket=bucket, dcap=dcap, nb=nb):
             with jax.named_scope("delta_merge"):
                 i = jnp.arange(bucket, dtype=jnp.int32)
                 c = keys - lo
@@ -2308,7 +2596,7 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
 
         prog = device_emit._delta_program("delta_merge", (
             "aligned", lo, domain, bucket, dcap, base_n, nb, tuple(cols),
-            bool(bdelta)), lambda: _extend)
+            bool(bdelta)), lambda _extend=_extend: _extend)
         with timeline.span("delta.upload", "delta",
                            bytes=pk.nbytes + pm.nbytes):
             new.matched[d], new.midx[d], out, n_dang = prog(

@@ -462,12 +462,17 @@ class Engine:
 
     def close(self) -> None:
         """Stop the background analyzer and WAIT for an in-flight pass —
-        close() is a barrier (GC also ends the worker via its weakref)."""
+        close() is a barrier (GC also ends the worker via its weakref) —
+        and drop this store's queued compactions, waiting for the one in
+        flight."""
         self._analyze_stop = True
         self._analyze_event.set()
         t = self._analyze_thread
         if t is not None and t is not threading.current_thread():
             t.join(timeout=10.0)
+        # (likewise the device cache's compactor, for this engine's store)
+        from tidb_tpu.executor import delta
+        delta.forget_store(self.store)
 
     def _auto_analyze_pass(self) -> None:
         """One trigger sweep: any table whose modified-row count since

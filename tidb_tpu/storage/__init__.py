@@ -166,6 +166,32 @@ class Store:
                     "(tidb_gc_life_time)")
             return Snapshot(dict(best[1]), best[0], self)
 
+    def table_history(self, table_id: int) -> set:
+        """ids of every `TableData` of the table that a snapshot can still
+        be handed: the current one and those of the version history. The
+        device cache frees a kept generation whose data is none of them."""
+        with self._lock:
+            held = {id(tables.get(table_id))
+                    for _v, _t, tables in self._history}
+            held.add(id(self._tables.get(table_id)))
+        return held
+
+    def table_versions(self, table_id: int, after: int, upto: int) -> list:
+        """[(version, TableData)] of the table at every commit that changed
+        it with `after` < version <= `upto`, oldest first, as far back as
+        the version history reaches: what the device cache steps through
+        when a read finds it several commits behind."""
+        out, last = [], None
+        with self._lock:
+            for v, _t, tables in self._history:
+                if v > upto:
+                    break
+                td = tables.get(table_id)
+                if td is not last and v > after and td is not None:
+                    out.append((v, td))
+                last = td
+        return out
+
     # ---- pessimistic row locks -------------------------------------------
     def lock_rows(self, txn: "Transaction", table_id: int,
                   region_masks: Dict[int, np.ndarray],
@@ -470,7 +496,10 @@ class Store:
                 # surfaces typed with the old delta version intact; it can
                 # never leave a torn delta because nothing is applied yet.
                 failpoint.inject("delta-append")
-                with self._lock:
+                # the commit gate: the one lock every snapshot take and
+                # every commit passes; a wait here has a name of its own
+                self._lock.acquire(span="commit.gate")
+                try:
                     # first-committer-wins: validate EVERYTHING before
                     # applying anything, so a conflict leaves no partial
                     # writes behind
@@ -489,6 +518,8 @@ class Store:
                     for tid in txn.staged_deletes:
                         self._maybe_compact_locked(tid, closing=1)
                     self._bump_locked()
+                finally:
+                    self._lock.release()
                 timeline.tag(rows=rows, tombs=tombs)
                 return
             except TxnError as e:

@@ -62,7 +62,9 @@ under one interpreter lock most of a busy server's wall time is waiting:
     transfer), `queue` (`sched-queue*`, `microbatch.wait`), `socket`
     (`client.wait`), `build` (`compile.wait`, `compile:*`,
     `jax.backend_compile`: the work runs on XLA's threads), `lock`
-    (`lock.wait`: one of the program's own locks, lane `lock`).
+    (lane `lock`: `lock.wait`, one of the program's own locks, and
+    `commit.gate`, a COMMIT waiting for the store's lock while a snapshot
+    is taken or another commit applies).
   * so a request's account closes: its `stmt` root = the sum of self CPU
     (work) + the off-CPU self time of `wait`-tagged spans by kind + the
     off-CPU self time of untagged spans. The last term is what nothing
@@ -111,12 +113,21 @@ Lanes, from packet to packet:
   decode   host-side dictionary decode / Chunk assembly
   cache    evictions (instants)
   write    write.stage (a DML statement's rows staged or matched),
-           write.commit (Store.commit: tables, rows, tombs)
+           write.commit (Store.commit: tables, rows, tombs; a CONTENDED
+           acquire of the store's lock under it is `commit.gate`, lane
+           `lock` like every wait for a program lock)
   delta    what a write costs the next read of a cached table
            (executor/delta.py): delta.diff, delta.encode, delta.upload,
            delta.tombstone (one per slab whose liveness mask changed),
            delta.aligned (the FK-aligned joins following a generation),
-           delta.decline (instant, gate=), compact.run, compact.swap
+           delta.decline (instant, gate=), compact.run, compact.swap;
+           delta.generation (age=newest|kept|rebuilt) around the look-up
+           of a snapshot's generation where the key's entry is another
+           snapshot's (device_cache.open_table: the kept generation, the
+           extension, or the rebuild beside; always on beside it,
+           tidb_tpu_delta_generation_reads_total{age=} counts EVERY
+           cached read, and the gauges tidb_tpu_delta_generations_kept
+           and ..._kept_bytes what is kept behind the newest ones)
   index    the host's index access path (executor/index_scan.py):
            index.build (table, index, rows: once a table version — the
            live view gathered and its key sorted), index.probe (ranges,
@@ -375,14 +386,18 @@ class _NamedLock:
         self._lock = lock
         self.release = lock.release
 
-    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+    def acquire(self, blocking: bool = True, timeout: float = -1,
+                span: str = "lock.wait") -> bool:
+        """`span`: a site whose wait for this lock is a step of its own
+        design records it under its own name (`commit.gate`), in the same
+        lane."""
         if not ENABLED:
             return self._lock.acquire(blocking, timeout)
         if self._lock.acquire(False):       # free, or this thread's RLock
             return True
         if not blocking:
             return False
-        with _Span("lock.wait", "lock", None, None,
+        with _Span(span, "lock", None, None,
                    {"name": self.name, "wait": "lock"}):
             return self._lock.acquire(True, timeout)
 

@@ -21,7 +21,7 @@ import sys
 UNIT_SUFFIXES = ("_total", "_seconds", "_bytes")
 LABEL_VOCAB = {"stmt", "engine", "table", "site", "device", "phase",
                "stage", "reason", "class", "le", "grouping", "operator",
-               "rung", "scan", "gate", "kind", "cause", "lowering", "plan"}
+               "rung", "scan", "gate", "kind", "cause", "lowering", "plan", "age"}
 PREFIX = "tidb_tpu_"
 
 
