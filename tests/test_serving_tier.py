@@ -369,3 +369,33 @@ def test_in_list_arity_shares_digest():
     # unary minus folds into the placeholder: x = -5 and x = 5 coalesce
     assert normalize_sql("SELECT v FROM mb WHERE k = -5") \
         == normalize_sql("SELECT v FROM mb WHERE k = 5")
+
+
+def test_the_cost_hint_forgets_a_first_touch_and_leaves_out_the_wait():
+    """The scheduler's cheap/heavy line is drawn by `digest_cost`: what a
+    digest's device path takes once it HAS the slot, as of its last few
+    executions. A first touch of seconds is forgotten within a few dozen
+    runs (the lifetime mean kept Q1 heavy for hundreds), and the wait for
+    the slot is no cost of the statement's (counted, it kept a statement
+    that was made to wait heavy: PERF.md §6 PR 41)."""
+    from types import SimpleNamespace
+    from tidb_tpu.executor import scheduler
+    from tidb_tpu.util.observability import Registry
+    from tidb_tpu.util.phases import PhaseTimer
+
+    def run(reg, sql, device_path_s, waited_s):
+        ph = PhaseTimer()
+        ph.add_wall(device_path_s + waited_s)   # the slot is taken inside
+        reg.record_stmt(sql, device_path_s + waited_s, 1, "tpu", 10.0,
+                        SimpleNamespace(phases=ph, queue_wait_s=waited_s,
+                                        queue_waits=1))
+    reg = Registry()
+    assert reg.digest_cost("SELECT 1 FROM t") is None
+    run(reg, "SELECT 1 FROM t", 17.0, 0.0)           # first touch
+    assert reg.digest_cost("SELECT 1 FROM t") == 17.0
+    for _ in range(60):
+        run(reg, "SELECT 2 FROM t", 0.020, 0.200)    # same digest, waiting
+    cost = reg.digest_cost("SELECT 3 FROM t")
+    assert 0.020 <= cost < scheduler.CHEAP_BATCH_S
+    lifetime = reg.stmt_summary[next(iter(reg.stmt_summary))]
+    assert lifetime["device_s"] / lifetime["count"] > scheduler.CHEAP_BATCH_S

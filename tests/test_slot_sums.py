@@ -117,11 +117,14 @@ def test_equal_columns_are_cut_once(small_blocks):
              seg.SumColumn(v, m1, None, 30, 30, False),
              seg.SumColumn(v, m1, None, 60, 4, True)]
     once = limbs + [seg.SumColumn(None, m1)]
-    # 5 pieces of the low word, 5 of the high, one group of bits each
-    assert seg.slot_sum_pieces(once) == 3 * 8
-    assert seg.slot_sum_pieces(once + once) == 3 * 8
+    # 5 pieces of the low word, 5 of the high, the validity's bit and the
+    # row bit: twelve pieces fill two groups (three words, were a word's
+    # pieces a group of their own)
+    assert seg.slot_sum_pieces(once) == 2 * 8
+    assert seg.slot_sum_pieces(once + once) == 2 * 8
+    assert seg.slot_sum_pieces(once, a_word_a_group=True) == 3 * 8
     other = [seg.SumColumn(v, m2, None, 0, 30, False), seg.SumColumn(None, m2)]
-    assert seg.slot_sum_pieces(once + other) == 4 * 8
+    assert seg.slot_sum_pieces(once + other) == 3 * 8   # 17 pieces
     got = _run(once + once + other, gid, 6)
     for a, b in zip(got[:4], got[4:8]):
         assert (a == b).all()
@@ -212,3 +215,113 @@ def test_who_keeps_the_lowering_it_had(monkeypatch):
     text = jax.jit(lambda g: seg.slot_sums(
         jnp, [seg.SumColumn(v)], g, 5)).lower(gid).as_text()
     assert text.count("dot_general") == 1
+
+
+# ---------------------------------------------------------------------------
+# columns that name only the bits their value can hold (PR 41)
+# ---------------------------------------------------------------------------
+
+WIDTHS = [0, 1, 7, 8, 13, 24, 30, 31, 32, 37, 59, 62]
+DEC = T.decimal(15, 2, True)
+
+
+def _sum_columns(name, ftype, v, valid, bits):
+    plan = _agg(name, ftype).row_sums(jnp, v, valid, bits)
+    return [c for c in plan if c is not None]
+
+
+@pytest.mark.parametrize("edge", ["2^k-1", "2^k"])
+@pytest.mark.parametrize("k", WIDTHS)
+def test_ranged_columns_equal_segment_sum(small_blocks, k, edge):
+    """A value known to lie in [0, hi], hi = 2^k − 1 and 2^k: a narrow
+    SUM's one field, a wide SUM's three limb fields (the third empty up to
+    60 bits, the second partly filled between 30 and 60) and a column of
+    unknown range, all in ONE call, with NULL rows, dead rows (id = the
+    slot count) and the range's ends among the values — every sum equal
+    bit for bit to `segment_sum` of the column."""
+    hi = 2 ** k - (edge == "2^k-1")
+    bits = hi.bit_length()
+    n, cap = 2 * BLOCK + 77, 12
+    rng = np.random.default_rng(k * 2 + len(edge))
+    gid = rng.integers(0, cap + 1, n).astype(np.int32)   # cap = a dead row
+    valid = rng.random(n) < 0.9
+    v = rng.integers(0, hi + 1, n, dtype=np.int64)
+    v[:3] = 0, hi, hi
+    valid[:3] = True
+    gid[1] = 0
+    other = rng.integers(I64.min, I64.max, n, dtype=np.int64)
+    jv, jvalid, jother = jnp.asarray(v), jnp.asarray(valid), \
+        jnp.asarray(other)
+    narrow = _sum_columns("sum", T.bigint(True), jv, jvalid, bits)
+    wide = _sum_columns("sum", DEC, jv, jvalid, bits)
+    whole = _sum_columns("sum", T.bigint(True), jother, jvalid, None)
+    assert [(c.shift, c.bits, c.signed) for c in narrow[:1]] \
+        == [(0, bits, False)]
+    assert [(c.shift, c.bits, c.signed) for c in wide[:3]] == [
+        (0, min(bits, 30), False), (30, min(max(bits - 30, 0), 30), False),
+        (60, max(bits - 60, 0), False)]
+    assert (whole[0].bits, whole[0].signed) == (None, True)
+    v0 = np.where(valid, v, 0)
+    want = [v0, valid,
+            v0 & (2 ** 30 - 1), (v0 >> 30) & (2 ** 30 - 1), v0 >> 60, valid,
+            np.where(valid, other, 0), valid]
+    cols = narrow + wide + whole
+    for got, w in zip(_run(cols, gid, cap), want):
+        assert (got == _ref(gid, cap, w)).all()
+    # and the lowering of a small batch (the masked reduce a column) reads
+    # the same fields
+    for c, w in zip(cols, want):
+        assert (np.asarray(seg._column_data(jnp, c)) == w).all()
+    # the limb STATES are what they were: the same three sums as the
+    # whole-width plan's, limb for limb
+    unranged = _sum_columns("sum", DEC, jv, jvalid, None)
+    for a, b in zip(_run(wide, gid, cap), _run(unranged, gid, cap)):
+        assert (a == b).all()
+    assert seg.slot_sum_pieces(wide) <= seg.slot_sum_pieces(unranged)
+
+
+def test_a_range_below_zero_keeps_the_whole_width():
+    """lo < 0 gives no width (`ranges.sum_bits`), and no width is today's
+    plan: the signed whole-width fields."""
+    from tidb_tpu.expression import ranges
+    assert ranges.sum_bits((-1, 100)) is None
+    v, m = jnp.arange(8, dtype=jnp.int64), jnp.ones(8, dtype=bool)
+    cols = _sum_columns("sum", DEC, v, m, ranges.sum_bits((-1, 100)))
+    assert [(c.shift, c.bits, c.signed) for c in cols[:3]] \
+        == [(0, 30, False), (30, 30, False), (60, 4, True)]
+    assert _sum_columns("sum", T.bigint(True), v, m, None)[0].bits is None
+    # a scale correction that could leave 63 bits keeps it too
+    up = _agg("avg", T.decimal(4, 2, True))     # DECIMAL(8,6): × 10⁴
+    assert up.row_sums(jnp, v, m, 40)[0].bits == (
+        (2 ** 40 - 1) * 10 ** 4).bit_length()
+    assert up.row_sums(jnp, v, m, 51)[0].bits is None
+
+
+def _q1_columns(ranged: bool):
+    """Q1's states as `device_emit._agg_states` gathers them at the
+    benchmark's TPC-H domains: SUM and AVG of one argument share its
+    arrays, every argument has a validity of its own."""
+    n = 16
+    args = {"qty": 5000, "price": 10494950, "disc_price": 1049495000,
+            "charge": 113345460000, "disc": 10}
+    arrays = {a: (jnp.zeros(n, dtype=jnp.int64), jnp.ones(n, dtype=bool))
+              for a in args}
+    cols = []
+    for name, arg in [("sum", "qty"), ("sum", "price"),
+                      ("sum", "disc_price"), ("sum", "charge"),
+                      ("avg", "qty"), ("avg", "price"), ("avg", "disc")]:
+        v, m = arrays[arg]
+        cols += _sum_columns(name, DEC, v, m,
+                             args[arg].bit_length() if ranged else None)
+    return cols + [seg.SumColumn(None, jnp.ones(n, dtype=bool))]
+
+
+def test_q1s_piece_matrix():
+    """Q1 at the benchmark's domains: 13 + 24 + 30 + 37 + 4 value bits, a
+    bit a validity and the row bit fill four 32-bit words and 22 pieces:
+    three groups, where the whole widths took eleven with a word's pieces
+    a group (PR 33's plan, the `slot_sums` tag's yardstick) and take
+    eight filled."""
+    assert seg.slot_sum_pieces(_q1_columns(True)) == 24
+    assert seg.slot_sum_pieces(_q1_columns(False), a_word_a_group=True) == 88
+    assert seg.slot_sum_pieces(_q1_columns(False)) == 64

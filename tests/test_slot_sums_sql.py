@@ -74,7 +74,8 @@ def test_q1_q3_q5_over_several_slabs(ds, shaped, on_the_matrix_unit,
         host = {q: _text(s.query(sql).rows)
                 for q, sql in shaped.STATEMENTS.items()}
         s.vars.update(SETTINGS)
-        expect = {"Q1": "mxu:88", "Q3": "mxu:24", "Q5": "mxu:24",
+        # (rows of the piece matrix / rows at whole width, a group a word)
+        expect = {"Q1": "mxu:24/88", "Q3": "mxu:8/24", "Q5": "mxu:8/24",
                   "Q6": "flat:6"}
         for q, sql in shaped.STATEMENTS.items():
             before, traces = _lowerings(), fragment.PROGRAM_TRACES
@@ -191,3 +192,83 @@ def test_the_contraction_under_vmap(on_the_matrix_unit):
         assert (np.asarray(sums[k]) == want).all()
         assert (np.asarray(counts[k])
                 == np.bincount(gid[k][ok], minlength=cap)).all()
+
+
+# ---------------------------------------------------------------------------
+# the contraction cuts what a value can hold (PR 41)
+# ---------------------------------------------------------------------------
+
+def _widths() -> dict:
+    return {dict(labels)["range"]: v
+            for (name, labels), v in REGISTRY.counters.items()
+            if name == "tidb_tpu_slot_sum_columns_total"}
+
+
+WIDENING = {
+    "chain": "SELECT a, COUNT(*), SUM(b) FROM t GROUP BY a ORDER BY a",
+    "tree": "SELECT d.n, COUNT(*), SUM(t.b) FROM t JOIN d ON t.a = d.k "
+            "GROUP BY d.n ORDER BY d.n",
+}
+
+
+@pytest.mark.parametrize("shape", sorted(WIDENING))
+def test_an_append_past_a_power_of_two_gets_a_new_program(
+        on_the_matrix_unit, shape):
+    """`b` holds 13 bits when the programs are first compiled: its sum is
+    two pieces. A row of 2⁵⁰ appended through the delta slab widens the
+    cached bounds, the digest's next execution compiles a program whose
+    piece matrix grew, and the answer is exact — over the base slabs and
+    over the delta slab alike."""
+    from test_delta_slabs import _engine as _t_engine, _entry, _oracle
+    q = WIDENING[shape]
+    eng, s = _t_engine()
+    try:
+        s.execute("CREATE TABLE d (k BIGINT PRIMARY KEY, n BIGINT)")
+        s.execute("INSERT INTO d VALUES " + ",".join(
+            f"({k}, {k % 4})" for k in range(40)))
+
+        def run():
+            with timeline.capture() as cap:
+                rows = s.query(q).rows
+            assert s.last_engine == "tpu"
+            return rows, [e["args"]["slot_sums"] for e in cap.events
+                          if e["ph"] == "X" and e["cat"] == "launch"
+                          and e["args"].get("slot_sums", "")[:3] == "mxu"]
+
+        def rows_of(tag):
+            got, _, whole = tag[4:].partition("/")
+            return int(got), int(whole)
+
+        before = _widths()
+        rows, tags = run()
+        assert rows == _oracle(s, q) and tags, tags
+        first = rows_of(tags[0])
+        assert first[0] < first[1]
+        assert _widths().get("bounded", 0) > before.get("bounded", 0)
+        assert _widths().get("whole", 0) == before.get("whole", 0)
+        run(), run()                # the statement program; warm
+        assert run()[1] == []
+        col = 1                     # t.b
+        assert _entry(eng).bounds[col] == (0, 4999)
+        declines = {k: v for k, v in REGISTRY.counters.items()
+                    if k[0] == "tidb_tpu_delta_declines_total"}
+        s.execute(f"INSERT INTO t VALUES (3, {2 ** 50}, 'k1')")
+        rows, tags = run()
+        assert rows == _oracle(s, q)
+        ent = _entry(eng)
+        assert ent.is_delta and ent.bounds[col][0] == 0 \
+            and ent.bounds[col][1] >= 2 ** 50
+        assert declines == {k: v for k, v in REGISTRY.counters.items()
+                            if k[0] == "tidb_tpu_delta_declines_total"}
+        # new programs, base slabs' and delta slab's: seven pieces where
+        # two were
+        assert tags and all(rows_of(t)[0] > first[0] for t in tags), tags
+        assert all(rows_of(t)[1] == first[1] for t in tags)
+        # a value inside the widened bounds mints nothing more
+        s.execute(f"INSERT INTO t VALUES (4, {2 ** 50 - 7}, 'k2')")
+        run(), run()
+        s.execute(f"INSERT INTO t VALUES (5, {2 ** 49}, 'k2')")
+        rows, tags = run()
+        assert rows == _oracle(s, q) and tags == []
+    finally:
+        eng.close()

@@ -391,7 +391,8 @@ def emit_agg(ctx: EvalContext, live, root, aggs: List[AggFunc],
         if pairs is not None:
             dpairs[ai] = pairs
     states = _agg_states(ctx, live, root, aggs, gids, cap, n,
-                         dfirst, dvals)
+                         dfirst, dvals,
+                         key_bounds.arg_bits if key_bounds is not None else ())
     out = {"keys": key_out, "states": states, "n_groups": n_groups,
            "slot_live": slot_live}
     if pairs_out:
@@ -603,7 +604,7 @@ def agg_states(ctx, live, root, aggs, gids, cap: int, n: int):
 
 
 def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
-                distinct_first=None, distinct_vals=None):
+                distinct_first=None, distinct_vals=None, arg_bits=()):
     """One state tuple per aggregate over the batch. Where the shapes
     send slot sums to the matrix unit (`seg.slot_sum_lowering`: `mxu`),
     every state that is a sum of a per-row integer — COUNT, SUM and AVG
@@ -611,12 +612,17 @@ def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
     `seg.slot_sums` for the whole aggregate: a column is evaluated once,
     so `SUM(x)` and `AVG(x)` share their pieces and every aggregate over
     one validity its count. Any other state, and every state of the
-    other lowerings, is the aggregate's own `update`. Says which lowering
-    the traced program took, once a trace: counter
+    other lowerings, is the aggregate's own `update`. `arg_bits`
+    (ops/factorize.KeyBounds) holds per aggregate the width its argument
+    is known to have, which its columns are then cut by. Says which
+    lowering the traced program took, once a trace: counter
     `tidb_tpu_slot_sum_programs_total{lowering=}` and tag `slot_sums` =
-    `<lowering>:<n>` (under `mxu` the piece rows of the contraction, else
-    the state arrays, a reduction each) on the span that covers the
-    trace."""
+    `<lowering>:<n>` (the state arrays, a reduction each) or, of a
+    contraction, `mxu:<rows>/<rows at whole width>` (the piece rows it
+    holds, and those it would hold knowing no width) on the span that
+    covers the trace; and of a contraction counter
+    `tidb_tpu_slot_sum_columns_total{range=bounded|whole}`, its distinct
+    summed values by whether a width was known."""
     from tidb_tpu.ops.jax_env import jnp
     from tidb_tpu.ops import factorize as F
     from tidb_tpu.ops import segment as seg
@@ -651,8 +657,9 @@ def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
                 m = m & F.distinct_mask(gids, v, m, live)
         inputs.append((v, m))
     lowering = seg.slot_sum_lowering(jnp, n, cap)
-    plans = [agg.row_sums(jnp, v, m) if lowering == "mxu" else None
-             for agg, (v, m) in zip(aggs, inputs)]
+    widths = arg_bits or [None] * len(aggs)
+    plans = [agg.row_sums(jnp, v, m, bits) if lowering == "mxu" else None
+             for agg, (v, m), bits in zip(aggs, inputs, widths)]
     columns = [c for plan in plans if plan for c in plan if c is not None]
     if lowering == "mxu" and not columns:
         lowering = "masked"             # MIN/MAX and their like alone
@@ -665,10 +672,20 @@ def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
                                 for a, c in zip(st, plan)))
         else:
             states.append(agg.update(jnp, st, gids, cap, v, m))
-    width = seg.slot_sum_pieces(columns) if lowering == "mxu" \
-        else sum(len(st) for st in states)
     REGISTRY.inc("tidb_tpu_slot_sum_programs_total", {"lowering": lowering})
-    timeline.tag(slot_sums=f"{lowering}:{width}")
+    if lowering != "mxu":
+        timeline.tag(
+            slot_sums=f"{lowering}:{sum(len(st) for st in states)}")
+        return states
+    whole = [c for agg, plan, (v, m) in zip(aggs, plans, inputs) if plan
+             for c in agg.row_sums(jnp, v, m) if c is not None]
+    timeline.tag(slot_sums=f"mxu:{seg.slot_sum_pieces(columns)}"
+                           f"/{seg.slot_sum_pieces(whole, True)}")
+    summed = {id(v): "whole" if bits is None else "bounded"
+              for plan, (v, _m), bits in zip(plans, inputs, widths)
+              if plan and any(c and c.values is not None for c in plan)}
+    for known in summed.values():
+        REGISTRY.inc("tidb_tpu_slot_sum_columns_total", {"range": known})
     return states
 
 

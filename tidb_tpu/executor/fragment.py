@@ -1510,32 +1510,40 @@ def _trace_to_scan_col(chain: List[PhysicalPlan], expr) -> Optional[int]:
 
 
 def _agg_key_bounds(chain: List[PhysicalPlan], ent) -> Optional[KeyBounds]:
-    """Per-group-key (lo, hi) domains when every key is a scan column with
-    cached bounds, and the lowering they allow
+    """What the aggregate's programs read from the cached bounds
+    (ops/factorize.KeyBounds). Per-group-key (lo, hi) domains when every
+    key is a scan column with cached bounds, and the lowering they allow
     (ops/factorize.choose_key_bounds): a small packed domain addresses
     the group slots directly, a large one packs the keys into sort words
-    for the sorted-runs grouping where the aggregates allow it; None →
-    sort factorize."""
+    for the sorted-runs grouping where the aggregates allow it; else
+    sort factorize. And the widths of the summed arguments, by interval
+    arithmetic from the scan's columns up through the chain's projections
+    (tree_fragment._bounds_list, expression/ranges)."""
     from tidb_tpu.executor import device_emit
+    from tidb_tpu.expression import ranges
     root = chain[0]
     if not isinstance(root, PhysHashAgg) or not root.group_exprs:
         return None
     if getattr(root, "rollup", False):
         return None     # level tiling needs the sort factorize
-    bounds: List[Tuple[int, int]] = []
+    bounds: Optional[List[Tuple[int, int]]] = []
     domain = 1
     for e in root.group_exprs:
         idx = _trace_to_scan_col(chain, e)
-        if idx is None:
-            return None
-        b = ent.bounds.get(idx)
+        b = ent.bounds.get(idx) if idx is not None else None
         if b is None:
-            return None
+            bounds = None
+            break
         lo, hi = b
         domain *= (hi - lo + 2)
         bounds.append((lo, hi))
-    return choose_key_bounds(bounds, domain, SLOT_ADDRESS_CAP, DOMAIN_CAP,
-                             device_emit.sorted_runs_ok(root))
+    from tidb_tpu.executor import tree_fragment as TF
+    return choose_key_bounds(
+        bounds, domain, SLOT_ADDRESS_CAP, DOMAIN_CAP,
+        device_emit.sorted_runs_ok(root), ranges.agg_arg_bits(
+            root, tuple(sorted(ent.bounds.items())),
+            lambda: TF._bounds_list(chain[1], {id(chain[-1]): ent.bounds},
+                                    True)))
 
 
 def _ent_layouts(ent, used):

@@ -536,24 +536,34 @@ def escalate_join(cfg: JoinCfg, unique_ok: bool, total: int,
     return None, None
 
 
-def _bounds_list(node: PhysicalPlan, scan_bounds
+def _bounds_list(node: PhysicalPlan, scan_bounds, quantities: bool = False
                  ) -> List[Optional[Tuple[int, int]]]:
     """Per output column (lo, hi) value bounds, traced from the device
-    cache's per-scan-column stats; schema-length list, None = unbounded."""
+    cache's per-scan-column stats; schema-length list, None = unbounded.
+    For keys (group, join) a column is bounded where it IS a scan column,
+    whatever it holds (a dictionary's codes too). With `quantities` the
+    bounds are those of scaled integers alone and a computed projection
+    is bounded by interval arithmetic (expression/ranges): what an
+    aggregate's summed argument can hold."""
+    from tidb_tpu.expression import ranges
     from tidb_tpu.planner.physical import PhysExchange
     if isinstance(node, (PhysTableScan, PhysTpuFragment)):
         # (a nested fragment's rows bring the bounds of their group keys)
         b = scan_bounds.get(id(node), {})
+        if quantities:
+            return ranges.column_ranges(node.schema.field_types, b)
         return [b.get(i) for i in range(len(node.schema))]
     if isinstance(node, (PhysSelection, PhysExchange)):
-        return _bounds_list(node.children[0], scan_bounds)
+        return _bounds_list(node.children[0], scan_bounds, quantities)
     if isinstance(node, PhysProjection):
-        inp = _bounds_list(node.children[0], scan_bounds)
+        inp = _bounds_list(node.children[0], scan_bounds, quantities)
+        if quantities:
+            return [ranges.value_range(e, inp) for e in node.exprs]
         return [inp[e.index] if isinstance(e, ColumnRef)
                 and e.index < len(inp) else None for e in node.exprs]
     if isinstance(node, PhysHashJoin):
-        l = _bounds_list(node.children[0], scan_bounds)
-        r = _bounds_list(node.children[1], scan_bounds)
+        l = _bounds_list(node.children[0], scan_bounds, quantities)
+        r = _bounds_list(node.children[1], scan_bounds, quantities)
         nl = len(node.children[0].schema)
         nr = len(node.children[1].schema)
         l = (l + [None] * nl)[:nl]
@@ -650,28 +660,36 @@ def plan_join_configs(root: PhysicalPlan, scan_bounds) -> List[JoinCfg]:
 
 def tree_agg_key_bounds(root: PhysicalPlan, scan_bounds,
                         domain_cap: int) -> Optional[KeyBounds]:
-    """Group-key domains for an agg root over a tree, when every group
-    key is a bounded column, and the lowering they allow
-    (ops/factorize.choose_key_bounds); None → sort factorize."""
+    """What an agg root over a tree reads from the cached bounds
+    (ops/factorize.KeyBounds): group-key domains when every group key is
+    a bounded column, and the lowering they allow
+    (ops/factorize.choose_key_bounds), else sort factorize; and the
+    widths of the summed arguments."""
     if not isinstance(root, PhysHashAgg) or not root.group_exprs:
         return None
     if getattr(root, "rollup", False):
         return None     # level tiling needs the sort factorize
     inp = _bounds_list(root.children[0], scan_bounds)
-    out: List[Tuple[int, int]] = []
+    out: Optional[List[Tuple[int, int]]] = []
     domain = 1
     for e in root.group_exprs:
         if not (isinstance(e, ColumnRef) and e.index < len(inp)
                 and inp[e.index] is not None):
-            return None
+            out = None
+            break
         lo, hi = inp[e.index]
         domain *= (hi - lo + 2)
         out.append((lo, hi))
     from tidb_tpu.executor import device_emit
     from tidb_tpu.executor.fragment import SLOT_ADDRESS_CAP
+    from tidb_tpu.expression import ranges
     from tidb_tpu.ops.factorize import choose_key_bounds
-    return choose_key_bounds(out, domain, SLOT_ADDRESS_CAP, domain_cap,
-                             device_emit.sorted_runs_ok(root))
+    return choose_key_bounds(
+        out, domain, SLOT_ADDRESS_CAP, domain_cap,
+        device_emit.sorted_runs_ok(root), ranges.agg_arg_bits(
+            root, tuple((n, tuple(sorted(b.items())))
+                        for n, b in scan_bounds.items()),
+            lambda: _bounds_list(root.children[0], scan_bounds, True)))
 
 
 # ---------------------------------------------------------------------------

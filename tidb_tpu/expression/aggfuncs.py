@@ -99,14 +99,17 @@ class AggFunc:
     def merge(self, xp, state: Tuple, gid, n: int, partial: Tuple) -> Tuple:
         raise NotImplementedError
 
-    def row_sums(self, xp, values, validity) -> Optional[List]:
+    def row_sums(self, xp, values, validity,
+                 bits: Optional[int] = None) -> Optional[List]:
         """`update` as sums of per-row integers, for a caller that
         computes every aggregate's sums in one pass
         (ops/segment.slot_sums): → one seg.SumColumn per array of the
         state tuple — its sum by slot IS what `update` adds to that array
         — and None for an array `update` leaves alone. None altogether
         when the update is anything else (MIN/MAX, FIRST, BIT, a float
-        sum)."""
+        sum). `bits`: every valid row's value is known to lie in
+        [0, 2^bits) (expression/ranges.sum_bits), so the columns name no
+        bit above; None = nothing is known."""
         return None
 
     def final(self, xp, state: Tuple):
@@ -147,7 +150,7 @@ class CountAgg(AggFunc):
         (counts,) = state
         return (counts + seg.segment_count(xp, validity, gid, n),)
 
-    def row_sums(self, xp, values, validity):
+    def row_sums(self, xp, values, validity, bits=None):
         return [seg.SumColumn(None, validity)]
 
     def merge(self, xp, state, gid, n, partial):
@@ -251,13 +254,19 @@ class SumAgg(AggFunc):
         out.append(state[-1] + seg.segment_count(xp, validity, gid, n))
         return tuple(out)
 
-    def row_sums(self, xp, values, validity):
+    def row_sums(self, xp, values, validity, bits=None):
         if self._float or (self._wide and xp is np and self._arg_obj):
             return None
         count = seg.SumColumn(None, validity)
         if not self._wide:
-            return [seg.SumColumn(self._cast_in(xp, values), validity),
-                    count]
+            v = self._cast_in(xp, values)
+            if bits is not None:    # _cast_in's scale correction widens it
+                mul = 10 ** max(self._out_scale - self._in_scale, 0) \
+                    if self.ftype.kind is TypeKind.DECIMAL else 1
+                bits = (((1 << bits) - 1) * mul).bit_length()
+            if bits is None or bits > 63:
+                return [seg.SumColumn(v, validity), count]
+            return [seg.SumColumn(v, validity, None, 0, bits, False), count]
         # the limb planes of _update_wide, each a bit field of the value
         from tidb_tpu.executor.device_cache import WIDE_LIMB_BITS as B
         if getattr(values, "ndim", 1) == 2:
@@ -266,10 +275,15 @@ class SumAgg(AggFunc):
         else:
             if values.dtype != xp.int64:
                 values = values.astype(xp.int64)
-            limbs = [seg.SumColumn(values, validity, None, 0, B, False),
-                     seg.SumColumn(values, validity, None, B, B, False),
-                     seg.SumColumn(values, validity, None, 2 * B, 64 - 2 * B,
-                                   True)]
+            def limb(at, width, top=False):
+                # of its field a limb keeps the bits the value can hold
+                # (none: the constant 0), unsigned once it is known ≥ 0
+                if bits is None:
+                    return seg.SumColumn(values, validity, None, at, width,
+                                         top)
+                return seg.SumColumn(values, validity, None, at,
+                                     min(max(bits - at, 0), width), False)
+            limbs = [limb(0, B), limb(B, B), limb(2 * B, 64 - 2 * B, True)]
         untouched = [None] * (self._n_limb_planes() - len(limbs))
         return limbs + untouched + [count]
 

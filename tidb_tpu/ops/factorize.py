@@ -150,12 +150,20 @@ FACTORIZE = "factorize"  # no bounds: per-slab sort-factorize, ladder, merge
 
 
 class KeyBounds(NamedTuple):
-    """Per-group-key (lo, hi) value bounds and what the partials do with
-    them: SLOTS (device_emit._perfect_groups; a small packed domain) or
-    RUNS (`pack_words`; the domain may be as large as it likes). An
-    aggregate with no KeyBounds (None) sort-factorizes."""
+    """What an aggregate's programs are compiled against, read from the
+    device cache's per-column bounds. Per-group-key (lo, hi) value bounds
+    and what the partials do with them: SLOTS (device_emit._perfect_groups;
+    a small packed domain), RUNS (`pack_words`; the domain may be as large
+    as it likes) or FACTORIZE (no bounds: the sort-factorize; so does an
+    aggregate with no KeyBounds at all, None). And per aggregate the WIDTH
+    of its argument, `arg_bits`: every value lies in [0, 2^bits)
+    (expression/ranges.sum_bits; None = unknown, () = none known) — what
+    the contraction of `ops/segment.slot_sums` cuts its pieces by. The
+    width and not the range: a minimum that moves, or a maximum that
+    stays under its power of two, mints no program."""
     mode: str
     bounds: Tuple[Tuple[int, int], ...]
+    arg_bits: Tuple[Optional[int], ...] = ()
 
 
 def grouping_mode(key_bounds: Optional[KeyBounds]) -> str:
@@ -165,24 +173,37 @@ def grouping_mode(key_bounds: Optional[KeyBounds]) -> str:
 def bounds_sig(key_bounds: Optional[KeyBounds]) -> str:
     """The bounds' part of a program signature (a SLOTS signature reads as
     it did when the bounds were a bare list, so cached programs keep
-    their names)."""
+    their names), the arguments' widths after it where any is known."""
     if key_bounds is None:
         return "None"
     text = repr(list(key_bounds.bounds))
-    return text if key_bounds.mode == SLOTS else key_bounds.mode + text
+    if key_bounds.mode != SLOTS:
+        text = key_bounds.mode + text
+    if key_bounds.arg_bits:
+        text += "|ab=" + ",".join(
+            "-" if b is None else str(b) for b in key_bounds.arg_bits)
+    return text
 
 
 def choose_key_bounds(bounds, domain: int, slot_cap: int, domain_cap: int,
-                      runs_ok: bool) -> Optional[KeyBounds]:
-    """The one place that picks a lowering from the keys' bounds: a
-    domain over `slot_cap` slots groups by sorted runs where the
-    aggregates allow it (`runs_ok`) and every key's code fits a word;
-    else up to `domain_cap` slots are addressed directly; else None."""
-    bounds = tuple(bounds)
-    if domain > slot_cap and runs_ok and \
-            all(_bits(lo, hi) <= WORD_BITS for lo, hi in bounds):
-        return KeyBounds(RUNS, bounds)
-    return KeyBounds(SLOTS, bounds) if domain <= domain_cap else None
+                      runs_ok: bool, arg_bits=()) -> Optional[KeyBounds]:
+    """The one place that picks a lowering from the keys' bounds (`bounds`
+    None: some key has none): a domain over `slot_cap` slots groups by
+    sorted runs where the aggregates allow it (`runs_ok`) and every key's
+    code fits a word; else up to `domain_cap` slots are addressed
+    directly; else the sort-factorize. `arg_bits` ride along wherever the
+    sums may be a contraction (sorted runs never are), and are dropped
+    where none is known."""
+    arg_bits = tuple(arg_bits) if any(b is not None for b in arg_bits) \
+        else ()
+    if bounds is not None:
+        bounds = tuple(bounds)
+        if domain > slot_cap and runs_ok and \
+                all(_bits(lo, hi) <= WORD_BITS for lo, hi in bounds):
+            return KeyBounds(RUNS, bounds)
+        if domain <= domain_cap:
+            return KeyBounds(SLOTS, bounds, arg_bits)
+    return KeyBounds(FACTORIZE, (), arg_bits) if arg_bits else None
 
 
 def _bits(lo: int, hi: int) -> int:

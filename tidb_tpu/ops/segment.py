@@ -15,10 +15,17 @@ alone; what each costs on a TPU v5e over one 8M-row slab (PERF.md §6):
   FIRST and every merge of slab partials (slabs × cap rows) take it.
 * `mxu` — `slot_sums`: ALL the integer sums of a slot-addressed aggregate
   (COUNT, SUM, AVG over integers and scaled DECIMALs) as one one-hot
-  contraction on the matrix unit over integer pieces of at most 8 bits:
-  2.4 ms for Q1's 29 states at 12 slots (7.5 ms masked), 6.6 ms at 128
-  slots (234), 13.6 ms at 1024 (402). Taken from SLOT_SUM_MIN_WORK rows ×
-  slots up, by `executor/device_emit._agg_states`.
+  contraction on the matrix unit over integer pieces of at most 8 bits,
+  and of only the bits each value can hold where the caller knows them
+  (`SumColumn.bits`, from the cached column bounds): Q1's 29 states at 12
+  slots are 24 piece rows and 2.35 ms where the whole widths are 88
+  rows and 3.95 ms (7.5 ms masked; PR 41, the host's clock around
+  back-to-back calls, so both carry a call's fixed cost; the device's
+  reading of the whole-width loop was 2.4 ms, PR 33), Q3/Q5's revenue
+  and count 8 rows and 0.54 ms against 24 and 0.90; at whole width 6.6 ms
+  at 128 slots (234 masked), 13.6 ms at 1024 (402). Taken from
+  SLOT_SUM_MIN_WORK rows × slots up, by
+  `executor/device_emit._agg_states`.
 * sorted runs — `SortedRuns`: a cumsum, one gather of `cap` elements and
   a difference a state over rows already sorted by group (PR 28); for
   integer sums beyond MASKED_REDUCE_CAP slots.
@@ -31,6 +38,7 @@ Rows whose id is out of range (dead rows carry `num_segments`) drop.
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import List, NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -217,13 +225,19 @@ class SumColumn(NamedTuple):
     signed: bool = True
 
 
+def _is_mask(col: SumColumn) -> bool:
+    """A 0/1 column: a validity to count, or a boolean value."""
+    return col.values is None or col.values.dtype == np.bool_
+
+
 def _column_field(col: SumColumn):
-    """→ (shift, bits, signed) with the dtype's width filled in."""
-    if col.values is None or col.values.dtype == np.bool_:
+    """→ (shift, bits, signed) with the dtype's width filled in (a
+    narrower integer is read from its sign-extended 32-bit word)."""
+    if _is_mask(col):
         return 0, 1, False
     if col.bits is not None:
         return col.shift, col.bits, col.signed
-    return 0, 8 * np.dtype(col.values.dtype).itemsize, True
+    return 0, max(8 * np.dtype(col.values.dtype).itemsize, _WORD), True
 
 
 def _column_key(col: SumColumn):
@@ -257,79 +271,133 @@ def slot_sum_lowering(xp, n_rows: int, num_segments: int) -> str:
     return "masked"
 
 
-def _piece_plan(shift: int, bits: int, signed: bool):
-    """Cut bits [shift, shift + bits) into pieces of ≤ 8 bits that never
-    cross bit 32 (a piece is cut from ONE 32-bit word) → [(start, width,
-    signed)], the top piece signed where the field is."""
-    out = []
-    s, end = shift, shift + bits
-    while s < end:
-        w = min(8, end - s, 32 - s if s < 32 else 64)
-        out.append((s, w, signed and s + w == end))
-        s += w
-    return out
-
-
+#: bits of a packed word, and of the words a value is read as
+_WORD = 32
+#: bits of a piece: what an int8 holds
+_PIECE = 8
 #: piece rows a word's broadcast makes at once: the sublanes of a vector
 #: register, so that the groups stack without moving anything.
 _GROUP_ROWS = 8
 
 
+def _field_chunks(shift: int, bits: int, signed: bool):
+    """Cut bits [shift, shift + bits) of a value where its 32-bit words
+    meet → [(start, width, signed)], the top chunk signed where the field
+    is (a signed field ends at the top of its dtype, so that chunk ends
+    at the top of its word)."""
+    out = []
+    s, end = shift, shift + bits
+    while s < end:
+        w = min(end - s, _WORD - s % _WORD)
+        out.append((s, w, signed and s + w == end))
+        s += w
+    return out
+
+
 def _biased(width: int, signed: bool) -> bool:
     """An unsigned byte does not fit int8: it is stored less 128."""
-    return width == 8 and not signed
+    return width == _PIECE and not signed
 
 
-def _slot_sum_plan(columns: Sequence[SumColumn]):
-    """What the contraction holds, from dtypes alone → (sources, masks,
-    groups, where).
+class _Plan(NamedTuple):
+    """What the contraction holds, from dtypes and fields alone.
 
-    `sources` {(values, plane, validity): column}: the distinct values,
-    each read as one or two 32-bit WORDS ("lo", "hi"). `masks` {(values,
-    plane, validity): (bit, column)}: the distinct 0/1 columns (a validity
-    to count, a boolean value), each one bit of a word of bits whose bit
-    0 is always set (the slot's rows). `groups` [(word, [(start, width,
-    signed)])]: the distinct pieces by the word they are cut from, at
-    most _GROUP_ROWS a group. `where` {field: [(row of the piece matrix,
-    shift back, stored less 128)]}: the pieces of each distinct column;
-    `where[None]` is the column of ones."""
-    sources, masks, fields = {}, {}, {}
+    `sources` {(values, plane, validity): (column, reads its high word)}:
+    the distinct values. `words` [[(chunk, offset)]]: the packed 32-bit
+    words, each the chunks or-ed into it and where; a chunk is `None`
+    (the constant 1: the slot's rows), `(source,)` (a 0/1 column) or
+    `(source, start, width, signed)` (bits of a value that lie in one of
+    its words). `groups` [[(word, start, width, signed)]]: the piece rows,
+    _GROUP_ROWS a group, each ≤ 8 bits of one packed word. `where`
+    {field: [(row of the piece matrix, shift back, stored less 128)]}:
+    the pieces of each distinct column; `where[None]` is the column of
+    ones."""
+    sources: dict
+    words: list
+    groups: list
+    where: dict
+
+
+def _slot_sum_plan(columns: Sequence[SumColumn]) -> _Plan:
+    """Every distinct column's field is cut where the value's 32-bit
+    words meet, the chunks of ALL columns are packed into as few 32-bit
+    words as hold them (widest first, each into the first word with
+    room; a signed chunk stays at the top of a word, where the value
+    holds it), and each chunk is cut into pieces of at most 8 bits: so
+    a value that is known to hold 13 bits makes two pieces where its 64
+    would make eight, and shares its word with others. A field of no
+    bits is the constant 0 and makes none."""
+    sources, chunks, fields = {}, {None: (1, False)}, {None: [(None, 0)]}
     for c in columns:
         key = _column_key(c)
         if key in fields:
             continue
-        shift, bits, signed = key[3:]
-        if bits == 1 and not signed:
-            bit = masks.setdefault(key[:3], (len(masks) + 1, c))[0]
-            fields[key] = [(("bits", bit // 32), bit % 32, 1, False, 0)]
+        skey, (shift, bits, signed) = key[:3], key[3:]
+        if _is_mask(c):
+            sources.setdefault(skey, [c, False])
+            chunks[skey,] = (1, False)
+            fields[key] = [((skey,), 0)]
             continue
-        sources.setdefault(key[:3], c)
-        fields[key] = [
-            (("hi" if start >= 32 else "lo", key[:3]), start % 32, width,
-             top, start - shift)
-            for start, width, top in _piece_plan(shift, bits, signed)]
-    fields[None] = [(("bits", 0), 0, 1, False, 0)]
-    cuts = {}           # word → its distinct pieces, in order
-    for at in fields.values():
-        for word, start, width, top, _ in at:
-            cuts.setdefault(word, {})[start, width, top] = None
-    groups, row = [], {}
-    for word, cut in cuts.items():
-        cut = list(cut)
-        for g in range(0, len(cut), _GROUP_ROWS):
-            for r, piece in enumerate(cut[g:g + _GROUP_ROWS]):
-                row[(word,) + piece] = _GROUP_ROWS * len(groups) + r
-            groups.append((word, cut[g:g + _GROUP_ROWS]))
-    where = {key: [(row[word, start, width, top], up, _biased(width, top))
-                   for word, start, width, top, up in at]
+        fields[key] = []
+        for start, width, top in _field_chunks(shift, bits, signed):
+            src = sources.setdefault(skey, [c, False])
+            src[1] = src[1] or start >= _WORD
+            chunks[skey, start, width, top] = (width, top)
+            fields[key].append(((skey, start, width, top), start - shift))
+    # pack: [bits taken from the bottom, bits free below a signed chunk]
+    words, room = [], []
+    for chunk, (width, top) in chunks.items():
+        if top:
+            words.append([(chunk, _WORD - width)])
+            room.append([0, _WORD - width])
+    for chunk, (width, top) in sorted(
+            chunks.items(), key=lambda kv: -kv[1][0]):
+        if top:
+            continue
+        for at, r in zip(words, room):
+            if r[0] + width <= r[1]:
+                break
+        else:
+            at, r = [], [0, _WORD]
+            words.append(at)
+            room.append(r)
+        at.append((chunk, r[0]))
+        r[0] += width
+    # pieces, in the order of the words: a group is filled before the
+    # next begins, so it may hold pieces of two words
+    groups, rows = [[]], {}
+    for w, at in enumerate(words):
+        for chunk, off in sorted(at, key=lambda t: t[1]):
+            width, top = chunks[chunk]
+            rows[chunk] = []
+            for s in range(0, width, _PIECE):
+                p = min(_PIECE, width - s)
+                signed = top and s + p == width
+                if len(groups[-1]) == _GROUP_ROWS:
+                    groups.append([])
+                rows[chunk].append(
+                    (_GROUP_ROWS * (len(groups) - 1) + len(groups[-1]), s,
+                     _biased(p, signed)))
+                groups[-1].append((w, off + s, p, signed))
+    where = {key: [(row, up + s, biased) for chunk, up in at
+                   for row, s, biased in rows[chunk]]
              for key, at in fields.items()}
-    return sources, masks, groups, where
+    return _Plan({k: tuple(v) for k, v in sources.items()}, words, groups,
+                 where)
 
 
-def slot_sum_pieces(columns: Sequence[SumColumn]) -> int:
+def slot_sum_pieces(columns: Sequence[SumColumn],
+                    a_word_a_group: bool = False) -> int:
     """Rows of the piece matrix `slot_sums` contracts for these columns
-    (the groups' padding included)."""
-    return _GROUP_ROWS * len(_slot_sum_plan(columns)[2])
+    (the last group's padding included). With `a_word_a_group`, the rows
+    it held while every word's pieces were groups of their own (PR 33's
+    plan: 88 for Q1's states at whole width) — the yardstick the
+    `slot_sums` tag sets the rows beside."""
+    groups = _slot_sum_plan(columns).groups
+    if not a_word_a_group:
+        return _GROUP_ROWS * len(groups)
+    per_word = Counter(w for g in groups for w, *_piece in g)
+    return _GROUP_ROWS * sum(-(-n // _GROUP_ROWS) for n in per_word.values())
 
 
 def slot_sums(xp, columns: Sequence[SumColumn], segment_ids,
@@ -348,31 +416,38 @@ def slot_sums(xp, columns: Sequence[SumColumn], segment_ids,
     byte p is stored as p − 128 so that it fits int8, and 128 × the slot's
     row count is added back; the blocks' partials are added in int64 and
     the pieces shifted back together on `num_segments` elements. Exact by
-    construction: no piece is dropped, no accumulator can overflow
-    (SLOT_SUM_BLOCK).
+    construction: no bit of a column's field is dropped, no accumulator
+    can overflow (SLOT_SUM_BLOCK). How many pieces that is follows the
+    FIELDS (`_slot_sum_plan`): a caller that knows a value's range names
+    the bits it can hold, and the contraction cuts no others.
 
     The piece matrix is never assembled from row vectors (a vector of
     rows lies across sublanes AND lanes, a matrix row along lanes only:
     stacking 50 vectors cost more than the 29 masked reduces, PERF.md §6
-    PR 33): each 32-bit word of a value is BROADCAST over eight sublanes
-    and shifted and masked by a column of eight constants, which yields
-    eight piece rows in place, and such groups stack whole."""
+    PR 33): the fields are first packed into 32-bit words by shift-and-or
+    on the row vectors, then each packed word is BROADCAST over eight
+    sublanes and shifted and masked by a column of eight constants, which
+    yields eight piece rows in place, and such groups stack whole."""
     if not columns or slot_sum_lowering(
             xp, int(segment_ids.shape[0]), num_segments) != "mxu":
         return [segment_sum(xp, _column_data(xp, c), segment_ids,
                             num_segments) for c in columns]
     from tidb_tpu.ops.jax_env import jnp, lax
     n = int(segment_ids.shape[0])
-    sources, masks, groups, where = _slot_sum_plan(columns)
+    plan = _slot_sum_plan(columns)
     iota = jnp.arange(num_segments, dtype=jnp.int32)[:, None]
-    # a group's eight (shift, mask, bias), one a sublane
+    # a group's eight (shift, mask, bias), one a sublane, and which of
+    # its words each sublane reads
     consts = []
-    for _, cut in groups:
+    for cut in plan.groups:
         c = np.zeros((3, _GROUP_ROWS, 1), dtype=np.int32)
-        for r, (start, width, top) in enumerate(cut):
+        for r, (_, start, width, top) in enumerate(cut):
             c[:, r, 0] = (start, -1 if top else (1 << width) - 1,
                           128 if _biased(width, top) else 0)
-        consts.append(c)
+        of = {}
+        for r, (w, *_rest) in enumerate(cut):
+            of.setdefault(w, np.zeros((_GROUP_ROWS, 1), dtype=bool))[r] = True
+        consts.append((c, list(of.items())))
 
     def block(start, size):
         """Rows [start, start + size) → (num_segments, pieces) int32."""
@@ -381,23 +456,43 @@ def slot_sums(xp, columns: Sequence[SumColumn], segment_ids,
                 return lax.dynamic_slice(a, (jnp.int32(start),), (size,))
             return lax.dynamic_slice(
                 a, (jnp.int32(plane), jnp.int32(start)), (1, size))[0]
-        words = {("bits", 0): jnp.ones(size, dtype=jnp.int32)}
-        for skey, c in sources.items():
+        read = {}       # source → its 0/1 vector, or its (low, high) words
+        for skey, (c, high) in plan.sources.items():
+            if _is_mask(c):
+                m = sl(c.valid) if c.values is None else sl(c.values)
+                if c.values is not None and c.valid is not None:
+                    m = m & sl(c.valid)
+                read[skey] = m.astype(jnp.int32)
+                continue
             v = sl(c.values, c.plane)
             if c.valid is not None:
                 v = jnp.where(sl(c.valid), v, jnp.zeros_like(v))
-            words["lo", skey] = v.astype(jnp.int32)
-            if v.dtype.itemsize == 8:
-                words["hi", skey] = (v >> 32).astype(jnp.int32)
-        for bit, c in masks.values():
-            m = sl(c.valid) if c.values is None else sl(c.values)
-            if c.values is not None and c.valid is not None:
-                m = m & sl(c.valid)
-            word = ("bits", bit // 32)
-            m = m.astype(jnp.int32) << (bit % 32)
-            words[word] = m | words[word] if word in words else m
-        pieces = [((words[word][None, :] >> c[0]) & c[1]) - c[2]
-                  for (word, _), c in zip(groups, consts)]
+            read[skey] = (v.astype(jnp.int32),
+                          (v >> _WORD).astype(jnp.int32) if high else None)
+        words = []
+        for at in plan.words:
+            word = None
+            for chunk, off in at:
+                if chunk is None:
+                    x = jnp.ones(size, dtype=jnp.int32)
+                elif len(chunk) == 1:
+                    x = read[chunk[0]]
+                else:
+                    skey, s, width, top = chunk
+                    x = read[skey][s // _WORD]
+                    if top:         # it lies where it is wanted
+                        x, off = x & jnp.int32(-1 << (_WORD - width)), 0
+                    elif width < _WORD:
+                        x = (x >> (s % _WORD)) & jnp.int32((1 << width) - 1)
+                x = x << off if off else x
+                word = x if word is None else word | x
+            words.append(word)
+        pieces = []
+        for c, of in consts:
+            src = words[of[0][0]][None, :]
+            for w, rows in of[1:]:
+                src = jnp.where(rows, words[w][None, :], src)
+            pieces.append(((src >> c[0]) & c[1]) - c[2])
         onehot = sl(segment_ids).astype(jnp.int32)[None, :] == iota
         return lax.dot_general(
             onehot.astype(jnp.int8),
@@ -405,7 +500,7 @@ def slot_sums(xp, columns: Sequence[SumColumn], segment_ids,
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
 
     nb, tail = divmod(n, SLOT_SUM_BLOCK)
-    total = jnp.zeros((num_segments, _GROUP_ROWS * len(groups)),
+    total = jnp.zeros((num_segments, _GROUP_ROWS * len(plan.groups)),
                       dtype=jnp.int64)
     if nb:
         total = lax.map(
@@ -413,9 +508,9 @@ def slot_sums(xp, columns: Sequence[SumColumn], segment_ids,
             jnp.arange(nb, dtype=jnp.int32)).astype(jnp.int64).sum(axis=0)
     if tail:
         total = total + block(nb * SLOT_SUM_BLOCK, tail).astype(jnp.int64)
-    rows = total[:, where[None][0][0]]
+    rows = total[:, plan.where[None][0][0]]
     sums = {}
-    for key, at in where.items():
+    for key, at in plan.where.items():
         acc = jnp.zeros(num_segments, dtype=jnp.int64)
         for j, up, biased in at:
             piece = total[:, j] + rows * 128 if biased else total[:, j]

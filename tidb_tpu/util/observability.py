@@ -30,6 +30,9 @@ from typing import Dict, List, Optional, Tuple
 from tidb_tpu.util import timeline
 
 _BUCKETS = (0.001, 0.005, 0.02, 0.1, 0.5, 2.0, 10.0)
+# executions a digest's cost hint looks back over (an exponential mean of
+# that weight): a first touch is forgotten within a few dozen
+COST_RUNS = 8
 
 
 def _hist_new() -> list:
@@ -173,7 +176,7 @@ class Registry:
                      "delta_rows": 0,
                      "queue_wait_s": 0.0, "queue_waits": 0,
                      "queue_hist": _hist_new(),
-                     "sched_class": None,
+                     "sched_class": None, "cost_s": None,
                      "phase_s": {}, "engine": engine}
                 self.stmt_summary[digest] = s
                 while len(self.stmt_summary) > 512:
@@ -204,6 +207,12 @@ class Registry:
                 _hist_observe(h, queue_wait_s)
             if ph is not None:
                 s["device_s"] += ph.wall_s
+                # the scheduler's cost hint (`digest_cost`): what the
+                # device path takes once the statement HAS the slot, as
+                # of late
+                own = max(ph.wall_s - queue_wait_s, 0.0)
+                s["cost_s"] = own if s["cost_s"] is None else \
+                    s["cost_s"] + (own - s["cost_s"]) / COST_RUNS
                 s["h2d_bytes"] += ph.h2d_bytes
                 s["d2h_bytes"] += ph.d2h_bytes
                 s["scan_bytes"] += ph.scan_bytes
@@ -245,15 +254,21 @@ class Registry:
                 self.slow_log.append(entry)
 
     def digest_cost(self, sql: str) -> Optional[float]:
-        """Historical average device seconds of this statement's digest —
+        """Device seconds of this statement's digest as of its last
+        COST_RUNS executions or so, its own waits for the slot left out —
         the scheduler's batch cost hint (None until the digest has run
-        with device attribution at least once)."""
+        with device attribution at least once). Not the lifetime mean: a
+        first touch of seconds kept a digest over the cheap/heavy line
+        (scheduler.CHEAP_BATCH_S) for hundreds of executions. And not
+        the wait: ranked heavy, a statement waits for the slot behind
+        every cheap one, and counting that wait as its cost kept it
+        heavy — Q1 beside seven other connections at SF=1 stayed so for
+        a whole window once the others' programs grew shorter (PERF.md
+        §6, PR 41)."""
         digest = normalize_sql(sql)
         with self._lock:
             s = self.stmt_summary.get(digest)
-            if s is None or not s["count"] or s["device_s"] <= 0.0:
-                return None
-            return s["device_s"] / s["count"]
+            return None if s is None or not s["cost_s"] else s["cost_s"]
 
     def digest_tables(self, sql: str) -> Optional[list]:
         """Table ids this statement's digest historically opened on the

@@ -21,7 +21,8 @@ import sys
 UNIT_SUFFIXES = ("_total", "_seconds", "_bytes")
 LABEL_VOCAB = {"stmt", "engine", "table", "site", "device", "phase",
                "stage", "reason", "class", "le", "grouping", "operator",
-               "rung", "scan", "gate", "kind", "cause", "lowering", "plan", "age"}
+               "rung", "scan", "gate", "kind", "cause", "lowering", "plan", "age",
+               "range"}
 PREFIX = "tidb_tpu_"
 
 
