@@ -32,6 +32,7 @@ import pytest
 from tidb_tpu.errors import ExecutionError
 from tidb_tpu.executor import fragment
 from tidb_tpu.session import Engine
+from tidb_tpu.util import timeline
 from tidb_tpu.util.observability import REGISTRY, normalize_sql
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -153,6 +154,160 @@ def test_statement_equals_the_plain_reference_by_sorted_runs(
     retries = counter("tidb_tpu_ladder_retries_total")
     assert text_rows(s.execute(LG.STATEMENTS[name])[0]) == ref[name]
     assert counter("tidb_tpu_ladder_retries_total") == retries
+
+
+def _traced(s, sql):
+    """Run `sql` once under the span recorder → (rows as text, the
+    `launch` spans in order as (program name, its `run_sums` tag))."""
+    with timeline.capture() as cap:
+        rows = text_rows(s.execute(sql)[0])
+    return rows, [(e["name"], e["args"].get("run_sums"))
+                  for e in sorted((e for e in cap.events if e["ph"] == "X"
+                                   and e["cat"] == "launch"),
+                                  key=lambda e: e["ts"])]
+
+
+def _scans() -> dict:
+    return {dict(labels)["range"]: v
+            for (name, labels), v in REGISTRY.counters.items()
+            if name == "tidb_tpu_run_sum_scans_total"}
+
+
+# statement → the `run_sums` tags of its traced finalizes, <words scanned>/
+# <state arrays>. Q18's SUM(l_quantity) holds 13 bits, so the three limbs
+# and the count of its wide SUM are ONE word, in the nested aggregate and
+# in the outer one (whose width comes through the join tree). Q3's and
+# Q10's revenue is a wide SUM of FOUR limb planes (its argument's type is
+# wide) and a count: with the generator's data (0 ≤ discount ≤ 0.10) its
+# 33 bits are two limb fields, which with their rows' growth and the count
+# fill two words, here as at the benchmark's scale; the PLANTED data holds
+# a discount over 1.00, the revenue's range reaches below zero and it has
+# no width: three limbs and the count a word each (limb 2 signed, at whole
+# width), as the parent scanned them.
+RUN_SUMS = {
+    "planted": {"Q3": ["4/5"], "Q10": ["4/5"], "Q18": ["1/4", "1/4"]},
+    "generated": {"Q3": ["2/5"], "Q10": ["2/5"], "Q18": ["1/4", "1/4"]},
+}
+
+
+@pytest.mark.parametrize("name", ["Q3", "Q10", "Q18"])
+@pytest.mark.parametrize("data", sorted(RUN_SUMS))
+def test_the_traced_finalizes_say_how_many_words_they_scan(sorted_runs,
+                                                           data, name):
+    """The first execution traces the statement's finalize(s), the second
+    the same at the capacity the first settled (`_tight_cap`): tag
+    `run_sums` on the `launch` span that traced it — on a finalize and on
+    nothing else — and the scans counted by whether their fields had
+    known widths; the rows are the reference's; a warm execution says
+    nothing."""
+    made = planted(3) if data == "planted" else LG.generate(SCALE, 3)
+    eng = Engine()
+    eng.global_vars["tidb_enable_auto_analyze"] = False
+    LG.load(eng, made)
+    try:
+        s = device_session(eng, tidb_tpu_max_slab_rows=16384)
+        want = RUN_SUMS[data][name]
+        before, said = _scans(), []
+        for _execution in range(2):
+            rows, launches = _traced(s, LG.STATEMENTS[name])
+            assert rows == LG.reference(made)[name]
+            assert not [t for prog, t in launches
+                        if t and not prog.startswith("finalize_")]
+            said += [t for _prog, t in launches if t]
+        assert said[:len(want)] == want and set(said) == set(want), said
+        grew = {k: v - before.get(k, 0) for k, v in _scans().items()}
+        assert sum(grew.values()) == sum(int(t.partition("/")[0])
+                                         for t in said), grew
+        # the signed limb of a revenue that may be negative, and no other
+        assert grew.get("whole", 0) == said.count("4/5")
+        before = _scans()
+        rows, launches = _traced(s, LG.STATEMENTS[name])
+        assert rows == LG.reference(made)[name] and _scans() == before
+        assert not [t for _prog, t in launches if t]
+    finally:
+        eng.close()
+
+
+WIDENED = ("SELECT k, SUM(q), AVG(q), COUNT(*) FROM w GROUP BY k "
+           "ORDER BY SUM(q) DESC, k LIMIT 5")
+
+
+def test_an_append_past_a_power_of_two_mints_one_finalize(sorted_runs):
+    """`q` holds 13 bits when the finalize is first traced. A quantity of
+    2²⁰ appended through SQL widens the cached bounds (a sorted-runs
+    statement reads a plain rebuild over a delta generation, gate
+    `consumer`): the next execution traces ONE new finalize — the slab
+    program and the shared sort keep their names, the widths are in no
+    signature of theirs — and answers exactly; a later value inside the
+    widened bounds mints nothing. (Compression off: a column's LAYOUT
+    follows its values too, and a new layout is a new slab program for a
+    reason that is not this one.)"""
+    eng = Engine()
+    eng.global_vars["tidb_enable_auto_analyze"] = False
+    s = eng.new_session()
+    s.execute("CREATE TABLE w (k BIGINT, q BIGINT)")
+    s.execute("INSERT INTO w VALUES " + ",".join(
+        f"({(i * 37) % 1000}, {(i * 7919) % 5000})" for i in range(3000)))
+    s.execute("ANALYZE TABLE w")
+    host = eng.new_session()
+    host.vars["tidb_tpu_engine"] = "off"
+    dev = device_session(eng, tidb_tpu_compression="off",
+                         tidb_tpu_compaction="off")
+
+    def run():
+        rows, launches = _traced(dev, WIDENED)
+        assert dev.last_engine == "tpu"
+        assert rows == text_rows(host.execute(WIDENED)[0])
+        return launches
+
+    try:
+        first = run()
+        slab, sort, fin = [prog for prog, _t in first]
+        assert slab.startswith("partial_chain_") \
+            and sort.startswith("sort_rows_") and fin.startswith("finalize_")
+        # SUM(q) and AVG(q) share q's 13-bit field, all three the count:
+        # seven state arrays, one word
+        assert [t for _p, t in first] == [None, None, "1/7"]
+        assert run() == [(slab, None), (sort, None), (fin, None)]
+        traces = fragment.PROGRAM_TRACES
+        dev.execute(f"INSERT INTO w VALUES (3, {2 ** 20})")
+        wider = run()
+        assert [p for p, _t in wider[:2]] == [slab, sort]
+        assert wider[2][0].startswith("finalize_") and wider[2][0] != fin
+        assert wider[2][1] == "1/7"         # 21 + 12 and 1 + 12 bits
+        assert fragment.PROGRAM_TRACES == traces + 1
+        assert text_rows(dev.execute(WIDENED)[0])[0][:2] == \
+            ("3", str(2 ** 20 + sum((i * 7919) % 5000 for i in range(3000)
+                                    if (i * 37) % 1000 == 3)))
+        dev.execute(f"INSERT INTO w VALUES (4, {2 ** 20 - 7})")
+        assert run() == [(slab, None), (sort, None), (wider[2][0], None)]
+        assert fragment.PROGRAM_TRACES == traces + 1
+    finally:
+        eng.close()
+
+
+def test_sorted_runs_carry_the_widths_and_the_slab_programs_do_not():
+    """`KeyBounds(RUNS)` keeps `arg_bits` (the finalize packs its scans by
+    them), and the bounds' part of a slab program's signature — which the
+    shared sort's and the statement's `aggrows` names follow — reads as
+    the parent's: the mode and the key bounds, no width."""
+    from tidb_tpu.ops import factorize as F
+    bounds = [(1, 12_000_000)]
+    kb = F.choose_key_bounds(bounds, 12_000_001, 1024, 1 << 22, True,
+                             (13, None))
+    assert kb == F.KeyBounds(F.RUNS, ((1, 12_000_000),), (13, None))
+    assert F.bounds_sig(kb) == "runs[(1, 12000000)]" \
+        == F.bounds_sig(F.KeyBounds(F.RUNS, ((1, 12_000_000),)))
+    assert F.widths_sig(kb) == "|ab=13,-"
+    # no width known: nothing rides and nothing is appended
+    bare = F.choose_key_bounds(bounds, 12_000_001, 1024, 1 << 22, True,
+                               (None, None))
+    assert bare.arg_bits == () and F.widths_sig(bare) == ""
+    # the other lowerings' signatures read as they did
+    slots = F.choose_key_bounds([(0, 5)], 7, 1024, 1 << 22, True, (13,))
+    assert F.bounds_sig(slots) == "[(0, 5)]|ab=13"
+    assert F.bounds_sig(F.KeyBounds(F.FACTORIZE, (), (13,))) \
+        == "factorize[]|ab=13"
 
 
 @pytest.mark.parametrize("name", ["Q3", "Q10", "Q18"])

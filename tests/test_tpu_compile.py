@@ -502,6 +502,56 @@ def test_sorted_runs_grouping_lowers_for_the_chip_with_two_sorts(one_chip):
     _fits([("sorted-runs finalize", compiled.memory_analysis())])
 
 
+def test_q18s_nested_finalize_gathers_a_word_and_the_key_word(one_chip):
+    """`lgstream1.sf2`'s dearest program at its geometry — 2²⁴ sorted rows
+    (two slabs at the first's capacity), 3.2M run ends, SUM(l_quantity)
+    over a value of 13 bits under a wide result: the three limbs and the
+    count are ONE int64 word (`ops/segment.run_sums`), so the program
+    holds two int64 gathers at the run ends, the word's and the key
+    word's (an int64 is two uint32 planes: four gather instructions; a
+    gather a state made ten), and one int64 scan over the rows (its
+    blocks' and their totals' `reduce-window`s: four; sixteen before).
+    With no width known every non-constant state is a word again."""
+    import re
+
+    from tidb_tpu import types as T
+    from tidb_tpu.executor import device_emit
+    from tidb_tpu.expression import ColumnRef
+    from tidb_tpu.expression.aggfuncs import AggDesc, build_agg
+    from tidb_tpu.ops import jax_env
+    from tidb_tpu.planner.physical import PhysHashAgg
+    jax, jnp = jax_env.jax, jax_env.jnp
+    n, cap = 1 << 24, 3_200_000
+    root = PhysHashAgg.__new__(PhysHashAgg)
+    root.group_exprs = [ColumnRef(0, T.bigint(True))]
+    root.aggs = [AggDesc("sum", [ColumnRef(1, T.decimal(15, 2, True))])]
+    aggs = [build_agg(d) for d in root.aggs]
+
+    def arr(rows, dtype):
+        return jax.ShapeDtypeStruct((rows,), dtype, sharding=one_chip)
+
+    rows = {"words": [arr(n, jnp.int64)],
+            "payloads": [arr(n, jnp.int64), arr(n, jnp.bool_)],
+            "ends": arr(n, jnp.int32),
+            "n_runs": jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)}
+
+    def compiled(arg_bits):
+        return jax.jit(lambda r: device_emit.emit_runs_finalize(
+            root, None, aggs, cap, ((1, 12_000_000),), [jnp.int64], r,
+            arg_bits)).lower(rows).compile()
+
+    def count(text):
+        return (len(re.findall(r" gather\(", text)),
+                len(re.findall(r" reduce-window\(", text)))
+
+    packed = compiled((13,))
+    assert count(packed.as_text()) == (4, 4)
+    assert not re.search(r"\bsort\(", packed.as_text())
+    _fits([("Q18's nested finalize", packed.memory_analysis())])
+    # limbs 0 and 1 (30-bit fields), the signed limb 2, the count, the key
+    assert count(compiled(()).as_text()) == (10, 16)
+
+
 def test_shard_map_aggregate_step_compiles_for_four_chips(topo, monkeypatch):
     """The distributed Q3-shaped step (filter → all_to_all exchange of
     both sides → per-shard sort-probe join → two-phase aggregate) on a

@@ -453,25 +453,76 @@ def group_rows(ctx: EvalContext, live, root, key_bounds):
 
 
 @_staged("agg")
-def _runs_states(root, aggs, runs, payloads, live_s):
+def _runs_states(root, aggs, runs, payloads, live_s, arg_bits=()):
+    """One state tuple per aggregate over rows sorted into runs. Every
+    state that is a sum of a per-row integer (`AggFunc.row_sums`: all that
+    `sorted_runs_ok` admits) comes out of ONE call of `seg.run_sums` for
+    the whole aggregate: the fields whose width is known — `arg_bits`
+    (ops/factorize.KeyBounds) per aggregate, a validity's one bit — share
+    int64 words, and a WORD is scanned and gathered at the run ends, not a
+    state; the constant-0 limbs of a narrow value under a wide SUM cost
+    nothing. An argument that several aggregates name is read from the
+    first one's payload (the sort carried equal copies), so `SUM(x)` and
+    `AVG(x)` share their fields and every aggregate over one validity its
+    count. Says what the traced program holds, once a trace: tag
+    `run_sums` = `<words scanned>/<state arrays>` on the span that covers
+    the trace, and counter `tidb_tpu_run_sum_scans_total{range=
+    bounded|whole}`, the scans by whether the word's fields had known
+    widths."""
     from tidb_tpu.ops.jax_env import jnp
-    states, i = [], 0
-    for agg, desc in zip(aggs, root.aggs):
+    from tidb_tpu.ops import segment as seg
+    from tidb_tpu.util import timeline
+    from tidb_tpu.util.observability import REGISTRY
+    n = live_s.shape[0]
+    inputs, named, i = [], {}, 0
+    for desc in root.aggs:
         if desc.args:
-            v, m = payloads[i], payloads[i + 1]
+            arg = desc.args[0]
+            key = arg.index if isinstance(arg, ColumnRef) else id(arg)
+            if key not in named:
+                named[key] = (payloads[i], payloads[i + 1] & live_s)
+            inputs.append(named[key])
             i += 2
         else:
-            v, m = jnp.zeros(live_s.shape[0], dtype=jnp.int64), live_s
-        states.append(agg.update(jnp, agg.init(jnp, runs.cap), runs,
-                                 runs.cap, v, m))
+            inputs.append((jnp.zeros(n, dtype=jnp.int64), live_s))
+    plans = [agg.row_sums(jnp, v, m, bits) for agg, (v, m), bits in zip(
+        aggs, inputs, arg_bits or [None] * len(aggs))]
+    columns = [c for plan in plans if plan for c in plan if c is not None]
+    states = _fill_states(aggs, plans, inputs, runs, runs.cap,
+                          seg.run_sums(columns, runs, n))
+    bounded, whole = seg.run_sum_scans(columns, n)
+    timeline.tag(run_sums=f"{bounded + whole}"
+                          f"/{sum(len(st) for st in states)}")
+    for known, scans in (("bounded", bounded), ("whole", whole)):
+        if scans:
+            REGISTRY.inc("tidb_tpu_run_sum_scans_total", {"range": known},
+                         scans)
+    return states
+
+
+def _fill_states(aggs, plans, inputs, gids, cap: int, sums):
+    """The state tuples from the sums of the aggregates' planned columns
+    (`AggFunc.row_sums`, in the columns' order); an aggregate without a
+    plan runs its own `update`."""
+    from tidb_tpu.ops.jax_env import jnp
+    sums = iter(sums)
+    states = []
+    for agg, plan, (v, m) in zip(aggs, plans, inputs):
+        st = agg.init(jnp, cap)
+        if plan:
+            states.append(tuple(a if c is None else a + next(sums)
+                                for a, c in zip(st, plan)))
+        else:
+            states.append(agg.update(jnp, st, gids, cap, v, m))
     return states
 
 
 @_staged("finalize")
 def emit_runs_finalize(root, order_root, aggs: List[AggFunc], cap: int,
-                       key_bounds, key_dtypes, rows):
+                       key_bounds, key_dtypes, rows, arg_bits=()):
     """The tail of a grouping by sorted runs, over `sort_rows`' output:
-    aggregate states by scans over the runs (scope `agg`), group keys
+    aggregate states by scans over the runs, a packed word of states at a
+    time (scope `agg`; `arg_bits` the arguments' known widths), group keys
     unpacked from the words at the run ends (arithmetic on `cap` gathered
     words — no row gathers), then the root ORDER BY … LIMIT by selection
     (`topn_select`; an ORDER BY without a limit sorts).
@@ -483,7 +534,8 @@ def emit_runs_finalize(root, order_root, aggs: List[AggFunc], cap: int,
     from tidb_tpu.planner.physical import PhysTopN
     runs = SortedRuns(rows["ends"], rows["n_runs"], cap)
     live_s = rows["words"][0] != jnp.int64(F.DEAD_WORD)
-    states = _runs_states(root, aggs, runs, rows["payloads"], live_s)
+    states = _runs_states(root, aggs, runs, rows["payloads"], live_s,
+                          arg_bits)
     keys = [(v, m & runs.slot_live) for v, m in F.unpack_words(
         [runs.at_ends(w) for w in rows["words"]], key_bounds, key_dtypes)]
     out = {"keys": keys, "states": states, "n_groups": runs.n_runs}
@@ -663,15 +715,8 @@ def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
     columns = [c for plan in plans if plan for c in plan if c is not None]
     if lowering == "mxu" and not columns:
         lowering = "masked"             # MIN/MAX and their like alone
-    sums = iter(seg.slot_sums(jnp, columns, gids, cap))
-    states = []
-    for agg, plan, (v, m) in zip(aggs, plans, inputs):
-        st = agg.init(jnp, cap)
-        if plan:
-            states.append(tuple(a if c is None else a + next(sums)
-                                for a, c in zip(st, plan)))
-        else:
-            states.append(agg.update(jnp, st, gids, cap, v, m))
+    states = _fill_states(aggs, plans, inputs, gids, cap,
+                          seg.slot_sums(jnp, columns, gids, cap))
     REGISTRY.inc("tidb_tpu_slot_sum_programs_total", {"lowering": lowering})
     if lowering != "mxu":
         timeline.tag(

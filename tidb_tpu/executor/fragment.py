@@ -52,7 +52,7 @@ from tidb_tpu.expression import EvalContext, Expression, ColumnRef
 from tidb_tpu.expression.aggfuncs import AggFunc, build_agg
 from tidb_tpu.ops.factorize import (FACTORIZE, RUNS, SLOTS, KeyBounds,
                                     bounds_sig, choose_key_bounds,
-                                    grouping_mode)
+                                    grouping_mode, widths_sig)
 from tidb_tpu.planner.physical import (PhysHashAgg, PhysHashJoin,
                                        PhysLimit, PhysProjection,
                                        PhysSelection, PhysSort,
@@ -1096,7 +1096,8 @@ class _RunsFinalizeProgram:
         _count_trace()
         return device_emit.emit_runs_finalize(
             self.agg_root, self.order_root, self.aggs, self.cap,
-            self.key_bounds.bounds, self.key_dtypes, rows)
+            self.key_bounds.bounds, self.key_dtypes, rows,
+            self.key_bounds.arg_bits)
 
 
 def _sig_tag(kind: str, sig: str) -> str:
@@ -2628,7 +2629,7 @@ class TpuFragmentExec:
                 ph.note_launch()
             fsig = ("runsfinal|" + (_order_sig(order_root)
                                     if order_root is not None else "-")
-                    + f"|cap={cap}|" + base_sig)
+                    + f"|cap={cap}|" + base_sig + widths_sig(key_bounds))
             fp = _get_or_build(fsig, "finalize", lambda: _RunsFinalizeProgram(
                 root, order_root, cap, key_bounds, fsig))
             with self.ctx.device_slot():

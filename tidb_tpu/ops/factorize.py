@@ -158,9 +158,11 @@ class KeyBounds(NamedTuple):
     aggregate with no KeyBounds at all, None). And per aggregate the WIDTH
     of its argument, `arg_bits`: every value lies in [0, 2^bits)
     (expression/ranges.sum_bits; None = unknown, () = none known) — what
-    the contraction of `ops/segment.slot_sums` cuts its pieces by. The
-    width and not the range: a minimum that moves, or a maximum that
-    stays under its power of two, mints no program."""
+    the contraction of `ops/segment.slot_sums` cuts its pieces by, and
+    what the sorted-runs finalize packs its scanned words by
+    (`ops/segment.run_sums`). The width and not the range: a minimum that
+    moves, or a maximum that stays under its power of two, mints no
+    program."""
     mode: str
     bounds: Tuple[Tuple[int, int], ...]
     arg_bits: Tuple[Optional[int], ...] = ()
@@ -170,19 +172,33 @@ def grouping_mode(key_bounds: Optional[KeyBounds]) -> str:
     return FACTORIZE if key_bounds is None else key_bounds.mode
 
 
+def widths_sig(key_bounds: Optional[KeyBounds]) -> str:
+    """The arguments' widths as a program signature holds them
+    (`|ab=13,-,30`: widths, never the bounds' values), nothing where none
+    is known."""
+    if key_bounds is None or not key_bounds.arg_bits:
+        return ""
+    return "|ab=" + ",".join(
+        "-" if b is None else str(b) for b in key_bounds.arg_bits)
+
+
 def bounds_sig(key_bounds: Optional[KeyBounds]) -> str:
     """The bounds' part of a program signature (a SLOTS signature reads as
     it did when the bounds were a bare list, so cached programs keep
-    their names), the arguments' widths after it where any is known."""
+    their names), the arguments' widths after it where any is known —
+    but not for RUNS: there this names the SLAB programs, which only hand
+    out rows, and with them the statement's `aggrows`; the widths are
+    trace constants of the finalize alone, which appends `widths_sig`
+    itself (fragment._runs_finalize), so a width that moves renames no
+    slab program and no shared sort."""
     if key_bounds is None:
         return "None"
     text = repr(list(key_bounds.bounds))
+    if key_bounds.mode == RUNS:
+        return RUNS + text
     if key_bounds.mode != SLOTS:
         text = key_bounds.mode + text
-    if key_bounds.arg_bits:
-        text += "|ab=" + ",".join(
-            "-" if b is None else str(b) for b in key_bounds.arg_bits)
-    return text
+    return text + widths_sig(key_bounds)
 
 
 def choose_key_bounds(bounds, domain: int, slot_cap: int, domain_cap: int,
@@ -191,16 +207,17 @@ def choose_key_bounds(bounds, domain: int, slot_cap: int, domain_cap: int,
     None: some key has none): a domain over `slot_cap` slots groups by
     sorted runs where the aggregates allow it (`runs_ok`) and every key's
     code fits a word; else up to `domain_cap` slots are addressed
-    directly; else the sort-factorize. `arg_bits` ride along wherever the
-    sums may be a contraction (sorted runs never are), and are dropped
-    where none is known."""
+    directly; else the sort-factorize. `arg_bits` ride along in every
+    lowering — the slots' and the factorize's contraction cuts its pieces
+    by them, the sorted runs' finalize packs its scans by them — and are
+    dropped where none is known."""
     arg_bits = tuple(arg_bits) if any(b is not None for b in arg_bits) \
         else ()
     if bounds is not None:
         bounds = tuple(bounds)
         if domain > slot_cap and runs_ok and \
                 all(_bits(lo, hi) <= WORD_BITS for lo, hi in bounds):
-            return KeyBounds(RUNS, bounds)
+            return KeyBounds(RUNS, bounds, arg_bits)
         if domain <= domain_cap:
             return KeyBounds(SLOTS, bounds, arg_bits)
     return KeyBounds(FACTORIZE, (), arg_bits) if arg_bits else None

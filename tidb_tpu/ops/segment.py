@@ -26,9 +26,14 @@ alone; what each costs on a TPU v5e over one 8M-row slab (PERF.md §6):
   at 128 slots (234 masked), 13.6 ms at 1024 (402). Taken from
   SLOT_SUM_MIN_WORK rows × slots up, by
   `executor/device_emit._agg_states`.
-* sorted runs — `SortedRuns`: a cumsum, one gather of `cap` elements and
-  a difference a state over rows already sorted by group (PR 28); for
-  integer sums beyond MASKED_REDUCE_CAP slots.
+* sorted runs — `SortedRuns`, `run_sums`: over rows already sorted by
+  group a cumsum, one gather of `cap` elements at the run ends and a
+  difference — a packed int64 WORD of states, not a state (PR 44): every
+  summed field whose width is known (`SumColumn.bits`, a validity's one
+  bit) shares a word with others, with room for its rows' growth, so
+  Q18's three limbs and count are one scan and one gather of 3M run ends
+  where they were four; a gather costs 0.37 s per 16M elements and plane
+  (PR 28). For integer sums beyond MASKED_REDUCE_CAP slots.
 * scatter — `jax.ops.segment_*`: serialized updates, 1.1 s an int64 state
   into 8M slots (PR 28); what is left for MIN/MAX beyond the cap.
 
@@ -139,9 +144,12 @@ class SortedRuns:
     10 ms. So a grouped aggregate over many groups sorts its rows by key
     once (ops/factorize.sort_rows) and every sum is a cumsum, ONE gather
     of `cap` elements at the runs' last rows, and an adjacent difference —
-    exact in wrapping int64 arithmetic. COUNT and AVG are sums; MIN and MAX
-    keep the scatter lowering, so an aggregate that has one does not take
-    this path.
+    exact in wrapping int64 arithmetic. That is `sum`, of one vector; an
+    aggregate's states go through `run_sums`, which packs every field of
+    known width into shared int64 words and calls `sum` once a WORD: the
+    gather, the dearest step, is paid a word and not a state. COUNT and
+    AVG are sums; MIN and MAX keep the scatter lowering, so an aggregate
+    that has one does not take this path.
 
     `ends` are the positions of the runs' last rows, ascending (garbage
     beyond `n_runs`); slot g of a result is run g in sorted order. With
@@ -517,6 +525,105 @@ def slot_sums(xp, columns: Sequence[SumColumn], segment_ids,
             acc = acc + (piece << up)
         sums[key] = acc
     return [sums[_column_key(c)] for c in columns]
+
+
+# ---------------------------------------------------------------------------
+# run sums by packed words
+# ---------------------------------------------------------------------------
+
+class _RunPlan(NamedTuple):
+    """What `run_sums` scans, from fields and the row count alone.
+    `words` [[(column key, offset)]]: the int64 words, each the fields
+    or-ed into it and where — or one whole-width column (offset None):
+    the scan `SortedRuns.sum` makes. `where` {column key: (word, offset,
+    width)}: where each distinct column's sums are cut from (width None =
+    the whole word); a column that is the constant 0 has no entry."""
+    words: list
+    where: dict
+
+
+def _run_sum_plan(columns: Sequence[SumColumn], n_rows: int) -> _RunPlan:
+    """An unsigned field of b bits (a mask: 1) summed over at most
+    `n_rows` rows stays under b + ⌈log2(n_rows + 1)⌉ bits — even the
+    PREFIX sum over every row does, so packed beside others it never
+    carries into its neighbour. Such fields are packed, widest first,
+    each into the first word with room (64 bits a word: the top field may
+    reach bit 63, the arithmetic wraps and only differences are read). A
+    field of no bits is the constant 0 and is not scanned; a column whose
+    width is unknown, signed, or past 63 bits with its rows is a word of
+    its own at whole width."""
+    grow = int(n_rows).bit_length()
+    fields, words, where = {}, [], {}
+    for c in columns:
+        key = _column_key(c)
+        if key in fields or key in where:
+            continue
+        _shift, bits, signed = _column_field(c)
+        if signed or bits + grow > 63:
+            where[key] = (len(words), 0, None)
+            words.append([(key, None)])
+        elif bits:
+            fields[key] = bits + grow
+    room = []           # bits taken of each packed word, from the bottom
+    first = len(words)
+    for key, width in sorted(fields.items(), key=lambda kv: -kv[1]):
+        for w, used in enumerate(room):
+            if used + width <= 64:
+                break
+        else:
+            w = len(room)
+            room.append(0)
+            words.append([])
+        where[key] = (first + w, room[w], width)
+        words[first + w].append((key, room[w]))
+        room[w] += width
+    return _RunPlan(words, where)
+
+
+def run_sum_scans(columns: Sequence[SumColumn], n_rows: int):
+    """→ (packed words of fields of known width, words of one column at
+    whole width): the scans — a cumsum with its gather at the run ends —
+    `run_sums` makes for these columns. From fields and the row count
+    alone, so a trace can say it."""
+    words = _run_sum_plan(columns, n_rows).words
+    whole = sum(1 for at in words if at[0][1] is None)
+    return len(words) - whole, whole
+
+
+def run_sums(columns: Sequence[SumColumn], runs: SortedRuns,
+             n_rows: int) -> List:
+    """Σ over the rows of each run, for every column at once → one
+    (runs.cap,) int64 array a column, equal bit for bit (wrapping modulo
+    2⁶⁴) to `runs.sum` of the column — by ONE scan a packed WORD, not one
+    a column (`_run_sum_plan`): the word is built by shift-and-or on the
+    row vectors (a masked row contributes 0), scanned, gathered at the
+    run ends and differenced once (`SortedRuns.sum`), and the fields are
+    cut out of the `cap` differences by logical shift and mask. A word of
+    one whole-width column is `runs.sum` of it and nothing else."""
+    from tidb_tpu.ops.jax_env import jnp, lax
+    plan = _run_sum_plan(columns, n_rows)
+    by_key = {_column_key(c): c for c in columns}
+    sums = []
+    for at in plan.words:
+        word = None
+        for key, off in at:
+            x = _column_data(jnp, by_key[key])
+            x = x << off if off else x
+            word = x if word is None else word | x
+        sums.append(runs.sum(word))
+    out = []
+    for c in columns:
+        at = plan.where.get(_column_key(c))
+        if at is None:
+            out.append(jnp.zeros(runs.cap, dtype=jnp.int64))
+            continue
+        w, off, width = at
+        s = sums[w]
+        if width is not None and len(plan.words[w]) > 1:
+            s = lax.shift_right_logical(s, jnp.int64(off)) \
+                & jnp.int64((1 << width) - 1)
+        out.append(s)
+    return out
 
 
 def segment_sum_accurate(xp, data, segment_ids, num_segments: int):
