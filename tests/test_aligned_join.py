@@ -288,3 +288,130 @@ def test_a_compaction_moves_rows_under_an_unchanged_table():
     assert delta.run_pending_compactions() == 1
     _check(s, JQ)
     assert _aligned_declines() == before
+
+
+# ---- a structure's arrays in the fact's row space are ONE array each once a
+# ---- statement program reads them (PR 46) ----------------------------------
+
+def _stacks() -> float:
+    from tidb_tpu.util.observability import REGISTRY
+    return sum(v for (n, _ls), v in list(REGISTRY.counters.items())
+               if n == "tidb_tpu_slab_stacks_total")
+
+
+def _slices() -> float:
+    from tidb_tpu.util.observability import REGISTRY
+    return sum(v for (n, _ls), v in list(REGISTRY.counters.items())
+               if n == "tidb_tpu_slab_slices_total")
+
+
+def _structures(s) -> list:
+    """This session's engine's (unique) aligned structures."""
+    return [a for k, a in list(device_cache._ALIGNED.items())
+            if k[0] == id(s.engine.store) and a.unique]
+
+
+def test_generations_of_a_structure_share_what_no_commit_rewrites():
+    """Five fact slabs under a statement program: the match mask and the
+    gathered build columns are stacked once (`tidb_tpu_slab_stacks_total`),
+    with the fact's columns. The FIRST commit that kills build rows
+    unmatches the fact rows that carried their keys — ONE program over the
+    stacks, a new stacked mask of the new generation's own — and stacks
+    what it alone reads, the matched build rows, that once; it shares
+    every other stack with the structure kept behind it by identity. No
+    later commit, no warm statement and no read of the kept generation
+    stacks anything again, and `hbm_bytes()` / the kept bytes count a
+    stacked array once."""
+    from tidb_tpu.executor import fragment
+    from tidb_tpu.util.observability import REGISTRY
+    s = _pair()
+    s.vars["tidb_tpu_max_slab_rows"] = 8192        # 40000 rows: 5 slabs
+    fragment._SPEC_CACHE.clear()
+    for _ in range(3):
+        _check(s, JQ)                              # cold, whole, warm
+    (old,) = _structures(s)
+    assert old.matched.is_stacked and old.matched.n_base == 5
+    assert all(c.is_stacked for c in old.cols.values())
+    # (no statement program reads the matched rows: the first unmatch
+    # of dead build keys does, beside the match mask, and stacks them)
+    assert not old.midx.is_stacked
+    s.execute("INSERT INTO l VALUES (5, 7)")       # the first generation:
+    s.execute("DELETE FROM l WHERE price = 999")   # delta slab and masks
+    for _ in range(3):
+        _check(s, JQ)
+    (mid,) = _structures(s)
+    assert mid is not old and len(mid.matched) == 6
+    stacks = _stacks()
+    declines = dict(_aligned_declines())
+    s.execute("DELETE FROM o WHERE ok >= 2900")    # build rows die
+    _check(s, JQ)
+    _check(s, JQ)
+    (new,) = _structures(s)
+    assert new is not mid and new.kept == (mid,)
+    assert _aligned_declines() == declines
+    # the match masks: rewritten whole, on the device, in one array
+    assert new.matched.is_stacked
+    assert new.matched.stack_leaf() is not mid.matched.stack_leaf()
+    assert new.matched.stack_leaf().shape == mid.matched.stack_leaf().shape
+    # everything else of the base: the same arrays
+    for c in new.cols:
+        assert [id(a) for s_, a in new.cols[c].arrays() if s_ < 5] == \
+            [id(a) for s_, a in mid.cols[c].arrays() if s_ < 5]
+    assert new.midx.base is mid.midx.base and new.midx.is_stacked
+    assert _stacks() == stacks + 1, "the matched build rows, once"
+    stacks += 1
+    slices = _slices()
+    s.execute("DELETE FROM o WHERE ok >= 2800 AND ok < 2900")
+    _check(s, JQ)
+    _check(s, JQ)
+    assert _stacks() == stacks, "a commit or a warm statement stacked"
+    assert _slices() == slices, "… or sliced a slab out of a stack"
+    (newest,) = _structures(s)
+    owned = list(newest._owned())
+    assert len({id(a) for a in owned}) == len(owned)
+    assert newest.hbm_bytes() == sum(a.nbytes for a in owned) \
+        + newest.kept_bytes
+    held = {id(a) for a in owned}
+    (kept,) = newest.kept
+    assert newest.kept_bytes == sum(
+        a.nbytes for a in {id(a): a for a in kept._owned()
+                           if id(a) not in held}.values())
+    assert REGISTRY.counters[("tidb_tpu_delta_generations_kept_bytes", ())] \
+        >= newest.kept_bytes
+
+
+def test_the_first_unmatch_stacks_what_no_statement_program_did():
+    """Build rows die under a five-slab fact BEFORE any statement program
+    was built over it (one cold execution: per-slab programs, lists). The
+    unmatch of their keys has ONE form over the base slabs — the program
+    whose loop indexes the stacks — so it stacks what it reads then (the
+    fact's key column, the match mask, the matched build rows; the fact
+    has no masks yet: made from the live prefixes, born stacked), once:
+    the next dead keys find the stacks and move no counter. The answers
+    stay the reference's and the structure is advanced, not rebuilt."""
+    from tidb_tpu.executor import fragment
+    s = _pair()
+    s.vars["tidb_tpu_max_slab_rows"] = 8192        # 40000 rows: 5 slabs
+    fragment._SPEC_CACHE.clear()
+    _check(s, JQ)                                  # cold: lists
+    (old,) = _structures(s)
+    assert not old.matched.is_stacked and old.matched.n_base == 5
+    stacks, declines = _stacks(), dict(_aligned_declines())
+    s.execute("DELETE FROM o WHERE ok >= 2900")
+    _check(s, JQ)
+    (new,) = _structures(s)
+    assert new is not old and _aligned_declines() == declines
+    assert new.matched.is_stacked and new.midx.is_stacked
+    assert new.midx.base is old.midx.base          # (stacked where it lay)
+    assert _stacks() > stacks
+    slices = _slices()
+    s.execute("DELETE FROM o WHERE ok >= 2800 AND ok < 2900")
+    _check(s, JQ)
+    _check(s, JQ)
+    # (the second execution's statement program stacks the columns IT
+    # reads and no unmatch did; the match structures' stay as they are)
+    (newest,) = _structures(s)
+    assert newest.matched.stack_leaf() is not new.matched.stack_leaf()
+    assert newest.midx.base is new.midx.base
+    assert _aligned_declines() == declines
+    assert _slices() == slices, "an unmatch sliced a slab out of a stack"

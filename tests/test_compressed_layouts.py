@@ -464,8 +464,7 @@ def test_eviction_budget_charges_physical_bytes():
     # ev1 survived: charging logical bytes would have evicted it
     e1b = _cache_entry(eng, "ev1")
     assert e1b is e1
-    assert not any(a.is_deleted() for slabs in e1.dev.values()
-                   for t in slabs for a in t)
+    assert not any(a.is_deleted() for _s, a in e1._arrays())
 
 
 def test_effective_roofline_fraction_reported():

@@ -144,7 +144,8 @@ def test_eviction_never_deletes_protected_sibling(serving):
             key0 = (dev, sid, t, _parts)
     assert key0 is not None, "d0 not cached after its query"
     ent0 = dc._CACHE[key0]
-    dev_ids = {i: [id(v) for v, _m in slabs] for i, slabs in ent0.dev.items()}
+    dev_ids = {i: [id(a) for _s, a in col.arrays()]
+               for i, col in ent0.dev.items()}
     assert dev_ids
 
     with dc.protect_tables({(id(eng.store), tid0)}):
@@ -156,7 +157,7 @@ def test_eviction_never_deletes_protected_sibling(serving):
         ent_after = dc._CACHE[key0]
         assert ent_after is ent0, "protected entry replaced mid-flight"
         for i, ids in dev_ids.items():
-            assert [id(v) for v, _m in ent_after.dev[i]] == ids, \
+            assert [id(a) for _s, a in ent_after.dev[i].arrays()] == ids, \
                 f"protected column {i} re-uploaded/deleted under pressure"
     # after release, normal LRU applies again on the next open
     s.query(_dev_sql(0))

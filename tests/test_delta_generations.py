@@ -171,11 +171,11 @@ def test_a_read_behind_the_cache_is_served_from_a_kept_generation():
         assert _reads("newest") == new0 + 1
         assert _count("tidb_tpu_delta_extensions_total") == ext0
         # generations of one base build share every base array
-        base = {id(a) for c in newest.dev.values()
-                for t in c[:newest.base_slabs] for a in t}
+        def base_arrays(g):
+            return {id(a) for c in g.dev.values()
+                    for s, a in c.arrays() if s < g.base_slabs}
         for g in newest.kept:
-            assert {id(a) for c in g.dev.values()
-                    for t in c[:g.base_slabs] for a in t} == base
+            assert base_arrays(g) == base_arrays(newest)
     finally:
         eng.close()
 

@@ -77,10 +77,8 @@ def _entry(eng, name="t"):
 
 def _base_ids(ent):
     """id() of every base-slab device array, per column."""
-    n_base = ent.base_slabs
-    return {i: [None if t is None else tuple(id(a) for a in t)
-                for t in slabs[:n_base]]
-            for i, slabs in ent.dev.items()}
+    return {i: [(s, id(a)) for s, a in col.arrays() if s < ent.base_slabs]
+            for i, col in ent.dev.items()}
 
 
 @pytest.mark.parametrize("compression", ["on", "off"])

@@ -245,12 +245,9 @@ def _check_oracle(rows, oracle):
 # ---------------------------------------------------------------------------
 
 def _held_arrays(ent):
-    out = []
-    for slabs in ent.dev.values():
-        for t in slabs:
-            if t is not None:        # zone-map hole: never uploaded
-                out.extend(t)        # raw (v, m) or packed 2/3-tuple
-    return out
+    # (what the columns' storage holds: raw (v, m) or packed 2/3-tuples a
+    # slab, or one stacked array a leaf; a zone-map hole holds nothing)
+    return [a for col in ent.dev.values() for _s, a in col.arrays()]
 
 
 def test_evicted_entries_free_device_buffers():

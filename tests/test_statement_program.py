@@ -14,6 +14,14 @@ back from a statement program is answered by the per-slab driver; sorted
 runs, DISTINCT pair sets and a cold stream never leave it; and a cached
 statement program holds nothing of the statement that built it; and a
 DOUBLE sum is the per-slab driver's to the bit.
+
+How a statement program reads its slabs (PR 46): the device cache holds a
+column's base slabs as ONE array a leaf (`device_cache.SlabColumn`, stacked
+when the first statement program over the table is built) and the loop
+indexes it — over six slabs, with the first, a middle or the last slab
+pruned, over a delta generation with one generation kept behind it, over a
+join tree's FK-aligned columns, over one slab; no `conditional` in the
+lowered program, and `tidb_tpu_slab_stacks_total` moves once a column.
 """
 
 import functools
@@ -70,9 +78,10 @@ def run(s, sql):
         rows = s.query(sql).rows
     assert s.last_engine == "tpu", sql
     evs = [e for e in cap.events if e["ph"] == "X"]
+    # (an aggregate's fragment says its plan; a row root has none)
     (plan,) = [e["args"]["launch_plan"] for e in evs
                if e["name"] == "device.fragment"
-               and "launch_plan" in e["args"]]
+               and "launch_plan" in e["args"]] or [None]
     return (rows, plan,
             [e["name"] for e in sorted(evs, key=lambda e: e["ts"])
              if e["cat"] == "launch"],
@@ -408,7 +417,10 @@ def test_live_rows_are_device_values_of_the_entry(db):
     s.query(STATEMENTS["chain-bounds"])
     (new,) = [e for (_d, sid, t, _p), e in dc._CACHE.items()
               if sid == id(eng.store) and t == tid]
-    assert new is not ent and new.live_arg(0) is new.alive[0]
+    # (three slabs on one device: the masks are ONE array from their
+    # birth, and a launch takes the array itself and the slab's row)
+    assert new is not ent and new.alive.is_stacked
+    assert new.live_arg(0).a is new.alive.stack_leaf()
 
 
 def test_a_cached_statement_program_holds_nothing_of_a_statement(db):
@@ -455,3 +467,370 @@ def test_the_control_fetch_packs_into_a_vector_a_kind_and_back():
     for got, want in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
         assert got.dtype == want.dtype and got.shape == want.shape
         assert np.array_equal(got, np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# the loop indexes ONE array a column (PR 46)
+# ---------------------------------------------------------------------------
+
+SIX = 6 * SLAB - 100        # six slabs, the last one short
+
+
+@pytest.fixture
+def six(db):
+    """`g`: six slabs. `a` ascends (zone maps skip a prefix or a suffix);
+    `z` is 0 in slab 2 alone (`z > 0` skips a MIDDLE slab)."""
+    eng, s = db
+    s.execute("CREATE TABLE g (a BIGINT, z INT, b INT, v BIGINT, "
+              "c VARCHAR(8))")
+    s.execute("INSERT INTO g VALUES " + ",".join(
+        f"({i}, {0 if 2 * SLAB <= i < 3 * SLAB else 1 + i % 9}, {i % 8}, "
+        f"{(i * 37) % 211 - 100}, 'c{i % 3}')" for i in range(SIX)))
+    s.execute("ALTER TABLE d ADD COLUMN w BIGINT")
+    s.execute("UPDATE d SET w = id * 11 + 3")
+    s.execute("ANALYZE TABLE g")
+    s.execute("ANALYZE TABLE d")
+    return eng, s
+
+
+def _stacks(table=None) -> float:
+    return sum(v for (n, ls), v in list(REGISTRY.counters.items())
+               if n == "tidb_tpu_slab_stacks_total"
+               and (table is None or ("table", str(table)) in ls))
+
+
+def _slices() -> float:
+    return sum(v for (n, _ls), v in list(REGISTRY.counters.items())
+               if n == "tidb_tpu_slab_slices_total")
+
+
+def _cached(eng, name):
+    tid = eng.catalog.info_schema.table(name).id
+    (ent,) = [e for (_d, sid, t, p), e in dc._CACHE.items()
+              if sid == id(eng.store) and t == tid and p is None]
+    return tid, ent
+
+
+_GJOIN = "FROM g JOIN d ON g.b = d.id "
+SIX_SLABS = {
+    # case: (statement, surviving slabs)
+    "six": ("SELECT b, COUNT(*), SUM(v), MIN(a) FROM g GROUP BY b", 6),
+    "first-missing": ("SELECT c, COUNT(*), SUM(v) FROM g "
+                      f"WHERE a >= {SLAB} GROUP BY c", 5),
+    "middle-missing": ("SELECT b, COUNT(*), SUM(v) FROM g WHERE z > 0 "
+                       "GROUP BY b ORDER BY b", 5),
+    "last-missing": (f"SELECT COUNT(*), SUM(v), MAX(a) FROM g "
+                     f"WHERE a < {5 * SLAB}", 5),
+    "first-and-last-missing": (
+        "SELECT b, COUNT(*) FROM g "
+        f"WHERE a >= {SLAB} AND a < {5 * SLAB} GROUP BY b", 4),
+    "tree-aligned": ("SELECT d.name, COUNT(*), SUM(g.v), SUM(d.w) " + _GJOIN
+                     + "GROUP BY d.name ORDER BY d.name", 6),
+    "tree-aligned-pruned": (
+        "SELECT d.name, SUM(d.w), MAX(g.a) " + _GJOIN
+        + f"WHERE g.a >= {2 * SLAB} GROUP BY d.name ORDER BY d.name", 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SIX_SLABS))
+def test_the_loop_indexes_one_array_a_column(six, built, monkeypatch, case):
+    """The looped statement program's answer is the per-slab driver's (the
+    digest's first execution) and the host's, over six base slabs and with
+    the first, a middle, the last slab pruned — which rows of the stacks
+    the turns read is an ARGUMENT (`picks`), the program names only how
+    many — and over a join tree whose FK-aligned match mask and gathered
+    build columns are indexed the same way. Its lowered HLO holds no
+    `conditional`; every column it reads is stacked once."""
+    eng, s = six
+    sql, survive = SIX_SLABS[case]
+    launched = []
+    real = fragment._StatementProgram.__init__
+
+    def init(self, *a):
+        real(self, *a)
+        launched.append((self, a[-1]))
+    monkeypatch.setattr(fragment._StatementProgram, "__init__", init)
+    tid = eng.catalog.info_schema.table("g").id
+    stacks0, of_g0 = _stacks(), _stacks(tid)
+    name = thrice(s, sql, slabs=survive)
+    assert name.startswith("stmt_fused_" if "tree" in case
+                           else "stmt_chain_")
+    assert {b for _sig, b in built} == {survive}
+    assert s.last_guard.phases.slabs_skipped == 6 - survive
+    tid, ent = _cached(eng, "g")
+    used = [c for c in ent.dev.values() if c.is_stacked]
+    assert used and all(len(c) == 6 and c.n_base == 6 for c in used)
+    # (a column, and in the tree the match mask, the matched build rows'
+    # gathered columns: each once)
+    assert _stacks() - stacks0 == _stacks(tid) - of_g0 >= len(used)
+    for sprog, args in launched:    # (none, if an earlier test built it)
+        hlo = sprog.run.lower(*args).as_text(dialect="hlo")
+        assert "conditional" not in hlo and "dynamic-slice" in hlo
+        _shared, base, _delta, picks = args
+        # (one vector of stack rows; a second where this statement's own
+        # cold first touch left the columns holes: they stack their
+        # resident slabs alone, the live-row counts every slab's)
+        assert 1 <= len(picks) <= 2
+        assert {int(v.shape[0]) for v in picks} == {survive}
+        assert sprog.said is None       # (tagged on its first launch)
+    # warm again: nothing is stacked twice, no vector uploaded twice
+    rows, plan, _l, traced = run(s, sql)
+    assert (plan, traced) == ("whole", 0)
+    assert _stacks() - stacks0 == _stacks(tid) - of_g0
+
+
+def test_the_first_launch_of_a_statement_program_says_how_it_picks(six):
+    """`slab_pick=index` rides the `launch` span of the first call of a
+    statement program built over stacked columns; a one-slab table's
+    program indexes nothing and says nothing."""
+    _eng, s = six
+    s.vars["tidb_tpu_max_slab_rows"] = 8192
+    s.execute("CREATE TABLE one (b INT, v BIGINT)")
+    s.execute("INSERT INTO one VALUES " + ",".join(
+        f"({i % 6}, {i})" for i in range(500)))
+    said = {}
+    for table in ("one", "g"):
+        s.vars["tidb_tpu_max_slab_rows"] = 8192 if table == "one" else SLAB
+        sql = f"SELECT b, MAX(v), COUNT(*) FROM {table} GROUP BY b"
+        s.query(sql)
+        traces = fragment.PROGRAM_TRACES
+        with timeline.capture() as cap:
+            s.query(sql)
+        if fragment.PROGRAM_TRACES == traces:
+            pytest.skip("the statement program was built by an earlier test")
+        said[table] = [e["args"].get("slab_pick") for e in cap.events
+                       if e["ph"] == "X" and e["cat"] == "launch"]
+    assert said == {"one": [None], "g": ["index"]}
+
+
+def test_a_delta_generation_and_the_one_kept_behind_it_share_the_stacks(six):
+    """Six base slabs, a delta slab, liveness masks, and ONE generation
+    kept behind the newest: both are read by the same statement program
+    over the SAME stacked base arrays (generations of one base build share
+    a column's stack by identity; a generation's masks are a stacked array
+    of its own), and neither a commit, a warm statement nor the read of the
+    kept generation stacks anything again."""
+    import datetime
+    import time
+    eng, s = six
+    sql = "SELECT b, COUNT(*), SUM(v) FROM g{asof} GROUP BY b ORDER BY b"
+    thrice(s, sql.format(asof=""), slabs=6)
+    tid, ent0 = _cached(eng, "g")
+    s.execute("INSERT INTO g VALUES (9000, 1, 3, 41, 'c1')")
+    s.execute(f"DELETE FROM g WHERE a IN (5, {SLAB + 5}, {5 * SLAB + 5})")
+    thrice(s, sql.format(asof=""), first="slabs:spec-miss", slabs=7)
+    _tid, ent1 = _cached(eng, "g")
+    assert ent1.is_delta and ent1.alive.is_stacked and ent1.delta_cap
+    stacks, slices = _stacks(), _slices()
+    time.sleep(0.02)
+    at = datetime.datetime.fromtimestamp(time.time()).isoformat(sep=" ")
+    time.sleep(0.02)
+    want_before = oracle(s, sql.format(asof=""))
+    s.execute(f"DELETE FROM g WHERE a IN (7, {3 * SLAB + 7}, 9000)")
+    rows, plan, launched, traced = run(s, sql.format(asof=""))
+    same(rows, oracle(s, sql.format(asof="")), sql)
+    assert (plan, len(launched), traced) == ("whole", 1, 0)
+    _tid, ent2 = _cached(eng, "g")
+    assert ent2 is not ent1 and len(ent2.kept) == 1
+    kept = ent2.kept[0]
+    # the read one commit behind: the kept generation, the same program
+    reads = REGISTRY.counters.get(
+        ("tidb_tpu_delta_generation_reads_total", (("age", "kept"),)), 0)
+    behind = sql.format(asof=f" AS OF TIMESTAMP '{at}'")
+    rows, plan, _l, _t = run(s, behind)     # (its text's first execution)
+    same(rows, want_before, sql)
+    assert plan == "slabs:spec-miss"
+    rows, plan, launched2, traced = run(s, behind)
+    same(rows, want_before, sql)
+    assert REGISTRY.counters[
+        ("tidb_tpu_delta_generation_reads_total",
+         (("age", "kept"),))] == reads + 2
+    assert (plan, launched2, traced) == ("whole", launched, 0)
+    assert _stacks() == stacks, \
+        "a commit, a warm statement or a kept generation's read stacked"
+    assert _slices() == slices, "… or sliced a slab out of a stack"
+
+    def base_arrays(g):
+        return {i: [id(a) for s_, a in c.arrays() if s_ < g.base_slabs]
+                for i, c in g.dev.items() if c.is_stacked}
+    assert base_arrays(ent2) == base_arrays(kept) and base_arrays(ent2)
+    assert all(len(ids) <= 3 for ids in base_arrays(ent2).values())
+    # each generation's masks: ONE array of its own
+    assert ent2.alive.stack_leaf() is not kept.alive.stack_leaf()
+    assert ent2.alive.stack_leaf().shape[0] == 6
+    # a stacked array is counted once: by the generation, by the kept
+    # bytes (what the older one owns beyond the newest: its masks and its
+    # delta slab's arrays), by `information_schema.table_storage`
+    seen = {}
+    for _s, a in ent2._arrays():
+        assert id(a) not in seen
+        seen[id(a)] = a
+    own = {id(a): a for _s, a in kept._arrays() if id(a) not in seen}
+    assert ent2.kept_bytes == sum(a.nbytes for a in own.values())
+    assert id(kept.alive.stack_leaf()) in own
+    assert ent2.hbm_bytes() == sum(a.nbytes for a in seen.values()) \
+        + ent2.kept_bytes
+    phys = {r["column"]: r["physical_bytes"]
+            for r in dc.storage_stats(id(eng.store)) if r["table_id"] == tid}
+    for i, col in ent2.dev.items():
+        held = {id(a): a.nbytes for g in (ent2, kept)
+                for _s, a in g.dev[i].arrays()}
+        assert phys[i] == sum(held.values())
+
+
+MIXED = {
+    # what reads `g` beside the statement program that stacked it
+    "whole": "SELECT b, COUNT(*), SUM(v), MIN(a) FROM g GROUP BY b",
+    "whole-tree": "SELECT d.name, COUNT(*), SUM(g.v), SUM(d.w) " + _GJOIN
+                  + "GROUP BY d.name ORDER BY d.name",
+    "pairs": "SELECT b, COUNT(DISTINCT v) FROM g GROUP BY b ORDER BY b",
+    "runs": "SELECT v, COUNT(*), SUM(a) FROM g GROUP BY v "
+            "ORDER BY SUM(a) DESC, v LIMIT 5",
+    "filter-root": "SELECT a, v FROM g WHERE v = 100 AND b = 3",
+    "order-root": "SELECT a, v FROM g ORDER BY v DESC, a LIMIT 4",
+    "join-rows": "SELECT g.a, d.name FROM g JOIN d ON g.b = d.id "
+                 "WHERE g.v = 110 ORDER BY g.a LIMIT 6",
+    "build-side": "SELECT COUNT(*), SUM(g.v) FROM f JOIN g ON f.a = g.a "
+                  "WHERE f.b < 4",
+}
+
+
+def test_a_warm_window_of_mixed_plans_slices_no_slab(six, monkeypatch):
+    """One server runs, over the SAME stacked table, a statement program
+    (chain and join tree), the per-slab plans (`slabs:pairs`, `slabs:runs`),
+    filter and ORDER BY roots, a join's row root and a statement that reads
+    it whole as a build side. Warm, none of them copies a slab out of the
+    stacks (`tidb_tpu_slab_slices_total` stands still: the per-slab
+    programs index the stack inside their traces, `SlabColumn.at`; a table
+    read whole lists its slabs there, `SlabColumn.whole`), nothing is
+    stacked again, and every answer is the host's."""
+    _eng, s = six
+    monkeypatch.setattr(fragment, "SLOT_ADDRESS_CAP", 64)
+    want = {k: oracle(s, sql) for k, sql in MIXED.items()}
+    plans = {}
+    for _ in range(3):
+        for k, sql in MIXED.items():
+            rows, plans[k], _l, _t = run(s, sql)
+            same(rows, want[k], sql)
+    assert plans["whole"] == plans["whole-tree"] == "whole"
+    assert (plans["pairs"], plans["runs"]) == ("slabs:pairs", "slabs:runs")
+    _tid, ent = _cached(_eng, "g")
+    assert all(c.is_stacked for c in ent.dev.values())
+    slices, stacks = _slices(), _stacks()
+    for k, sql in MIXED.items():
+        rows, plan, _l, traced = run(s, sql)
+        same(rows, want[k], sql)
+        assert (plan, traced) == (plans[k], 0), k
+        assert _slices() == slices, f"{k} sliced a slab out of a stack"
+    assert _stacks() == stacks
+
+
+@pytest.mark.parametrize("n", [77, 1000, 1024])
+def test_a_stack_gives_back_every_slab_whatever_its_length(n):
+    """A leaf's rows lie folded to whole lanes of 128 in its column's
+    stack — padded where the slab's length is no multiple — and every way
+    of reading a slab gives the slab back: `col[s]` (a slice, counted),
+    `at(s)` and `whole()` resolved inside a trace, a turn of a statement
+    program's loop; a hole stays a hole, the delta slab its own arrays."""
+    from tidb_tpu.ops.jax_env import jax, jnp
+    slabs = [(jnp.arange(n, dtype=jnp.int64) + s * n,
+              jnp.arange(n) % 3 == s % 3) for s in range(4)]
+    slabs[2] = None                             # (pruned at first touch)
+    delta = (jnp.zeros(16, jnp.int64), jnp.zeros(16, bool))
+    col = dc.SlabColumn(slabs, delta)
+    col.stack("t")
+    assert col.is_stacked and col.holes() == {2} and len(col) == 5
+    (values, _mask) = [a for _s, a in col.arrays()][:2]
+    assert values.shape == (3, -(-n // 128), 128)
+    slices = _slices()
+
+    def same_slab(got, s):
+        if slabs[s] is None:
+            return got is None
+        return all(bool((g == w).all()) and g.shape == w.shape
+                   for g, w in zip(got, slabs[s]))
+    assert all(same_slab(col[s], s) for s in range(4))
+    assert _slices() - slices == 3 and col[4] is delta
+    here = jax.jit(dc.in_place)
+    assert all(same_slab(here(col.at(s)), s) for s in range(4))
+    whole = here(col.whole())
+    assert all(same_slab(whole[s], s) for s in range(4)) \
+        and len(whole) == 5
+    picks = (jnp.asarray(col.rows_of((3, 0)), jnp.int32),)
+    pick = jax.jit(dc.in_place, static_argnums=2)
+    assert same_slab(pick(col.stacked(), picks, 0), 3)
+    assert same_slab(pick(col.stacked(), picks, 1), 0)
+    assert _slices() - slices == 3              # (only `col[s]` copies)
+
+
+def test_a_stacked_mask_is_rewritten_by_row_position_in_the_base():
+    """The tombstone rewrite of a generation's stacked masks addresses a
+    row by its position in the whole base, whatever the fold's padding."""
+    from tidb_tpu.executor import device_emit
+    from tidb_tpu.ops.jax_env import jnp
+    import numpy as np
+    cap = 1000                                  # (no multiple of 128)
+    col = dc.SlabColumn(jnp.ones(cap, bool) for _ in range(3))
+    col.stack("t")
+    dead = np.array([5, cap + 5, 3 * cap - 1, 3 * cap, 3 * cap],
+                    dtype=np.int32)             # (padded with the size)
+    col.set_stack(device_emit.emit_alive_update(
+        col.stack_leaf(), np.empty(0, np.int32), dead, cap, stacked=True))
+    got = np.concatenate([np.asarray(col[s]) for s in range(3)])
+    want = np.ones(3 * cap, bool)
+    want[[5, cap + 5, 3 * cap - 1]] = False
+    assert (got == want).all()
+    # (a stacked base is rewritten whole, never a slab at a time)
+    with pytest.raises(TypeError, match="set_stack"):
+        col[1] = jnp.zeros(cap, bool)
+
+
+def test_masks_are_born_stacked():
+    """A table's first commit makes its liveness masks on the device from
+    the live prefixes: ONE array where the base slabs can be held so
+    (several, on one device, none lost) — one program: no fill, no
+    counter, nothing through the host — whether or not its columns are
+    stacked yet, so that the masks and the program that rewrites them have
+    one form for the table's life; a mask a slab where slabs have owners.
+    The same rows either way, whatever the fold's padding."""
+    import types
+    import numpy as np
+    from tidb_tpu.executor import delta
+    from tidb_tpu.ops.jax_env import jnp
+    cap, rows = 1000, (1000, 1000, 17)
+    col = dc.SlabColumn((jnp.zeros(cap, jnp.int32),) for _ in rows)
+    ent = types.SimpleNamespace(
+        slab_cap=cap, base_slabs=3, owners=None, lost=set(), device=0,
+        dev={0: col}, slab_rows=lambda s: rows[s])
+    stacks, slices = _stacks(), _slices()
+    born = delta.base_masks(ent, "t")
+    assert not col.is_stacked
+    assert born.is_stacked and born.n_base == 3 and not born.holes()
+    assert born.stack_leaf().shape == (3, 8, 128)
+    assert _stacks() == stacks, "a commit filled a stack"
+    ent.owners = (0, 0, 0)
+    listed = delta.base_masks(ent, "t")
+    assert not listed.is_stacked and listed.n_base == 3
+    for s, n in enumerate(rows):
+        want = np.arange(cap) < n
+        assert (np.asarray(born[s]) == want).all()
+        assert (np.asarray(listed[s]) == want).all()
+    assert _slices() == slices + 3                  # (only `col[s]` copies)
+
+
+def test_slabs_on_several_devices_or_with_a_lost_slab_are_never_stacked():
+    """`SlabPicks.of` — the one place a column is stacked — leaves an
+    entry whose slabs have owners, or that lost one, its lists: a lost
+    slab's refill writes a slab, and several devices hold no one array."""
+    import types
+    from tidb_tpu.ops.jax_env import jnp
+    for owners, lost in (((0, 1), set()), (None, {1})):
+        ent = types.SimpleNamespace(owners=owners, lost=lost)
+        col = dc.SlabColumn((jnp.zeros(8),) for _ in range(2))
+        with pytest.raises(AssertionError, match="stack"):
+            dc.SlabPicks(ent, (0, 1), "t").of(col)
+        assert not col.is_stacked
+        col[1] = (jnp.ones(8),)                 # (the refill's write)
+    ent = types.SimpleNamespace(owners=None, lost=None)
+    dc.SlabPicks(ent, (0, 1), "t").of(col)
+    assert col.is_stacked

@@ -175,11 +175,25 @@ def _scaled_cols(jax, cols, factor, sharding):
             for i, col in cols.items()}
 
 
+def _rebuild_tree(owner, factor):
+    """A recorded `TreeProgram` again at the real slab geometry."""
+    from tidb_tpu.executor.tree_fragment import TreeProgram, _walk_joins
+    return TreeProgram(
+        owner.plan,
+        # (slab capacity, slabs[, capacity of a raw delta slab])
+        {k: (c[0] * factor, c[1]) + tuple(d * factor for d in c[2:])
+         for k, c in owner.caps.items()},
+        owner.group_cap,
+        [owner.join_cfgs[id(n)] for n in _walk_joins(owner.plan)],
+        owner.agg_key_bounds, owner.scan_layouts, owner.pairs_out,
+        owner.pair_cap)
+
+
 def _rebuild(owner, factor):
     """The same program through its own constructor at the real slab
     geometry → (program, jitted entry point by method name)."""
     from tidb_tpu.executor import fragment
-    from tidb_tpu.executor.tree_fragment import TreeProgram, _walk_joins
+    from tidb_tpu.executor.tree_fragment import TreeProgram
     if isinstance(owner, fragment._FragmentProgram):
         p = fragment._FragmentProgram(
             owner.chain, owner.used_cols, owner.in_types,
@@ -187,16 +201,7 @@ def _rebuild(owner, factor):
             owner.has_distinct, owner.layouts, owner.pair_cap)
         return {"_partial": p.partial, "_merge": p.merge}
     if isinstance(owner, TreeProgram):
-        p = TreeProgram(
-            owner.plan,
-            # (slab capacity, slabs[, capacity of a raw delta slab])
-            {k: (c[0] * factor, c[1]) + tuple(d * factor for d in c[2:])
-             for k, c in owner.caps.items()},
-            owner.group_cap,
-            [owner.join_cfgs[id(n)] for n in _walk_joins(owner.plan)],
-            owner.agg_key_bounds, owner.scan_layouts, owner.pairs_out,
-            owner.pair_cap)
-        return {"_run": p.run}
+        return {"_run": _rebuild_tree(owner, factor).run}
     if isinstance(owner, fragment._FusedFinalizeProgram):
         p = fragment._FusedFinalizeProgram(owner.agg_root, owner.order_root,
                                            owner.group_cap)
@@ -422,7 +427,8 @@ def test_a_statement_program_is_one_body_in_a_loop(recorded, one_chip,
     cols, n_rows, preps = calls[0][2]
     slab = (_scaled_cols(jax, cols, factor, one_chip),
             _scaled_live(jax, n_rows, factor, one_chip))
-    args = _scaled(jax, preps, 1, one_chip), (slab,) * 3
+    args = (_scaled(jax, preps, 1, one_chip), _stacked(jax, slab, 6),
+            None, (jax.ShapeDtypeStruct((3,), np.int32, sharding=one_chip),))
     sp = fragment._StatementProgram(
         "stmt_chain", functools.partial(fragment._ChainSlabs._slab_body, p),
         None, p._merge, fragment._ChainSlabs.control, True, "toy", args)
@@ -438,6 +444,269 @@ def test_a_statement_program_is_one_body_in_a_loop(recorded, one_chip,
     assert len(re.findall(r"\bwhile\(", text)) == 1
     for stage in ("decode", "filter", "agg", "merge"):
         assert re_search_stage(text, stage), stage
+
+
+def _stacked(jax, slab, n_slabs: int):
+    """One slab's arguments (shapes) as a statement program takes the base
+    slabs': every leaf that a slab has of its own — what grew with the
+    slab, and a live-row count — as `device_cache.Stacked` over the
+    column's ONE array (`SlabColumn.stack`'s shape), a dictionary or a
+    delta base as it is."""
+    from tidb_tpu.executor import device_cache as dc
+    node = dc._node(dc.Stacked)
+
+    def stack(x):
+        if not isinstance(x, jax.ShapeDtypeStruct):
+            return x
+        if x.shape and x.shape[-1] < TOY_ROWS // 64:
+            return x            # (a dictionary, a delta base: shared)
+        return node(jax.ShapeDtypeStruct(
+            (n_slabs,) + dc._folded(x.shape), x.dtype, sharding=x.sharding),
+            None, 0, tuple(x.shape))
+    return jax.tree.map(stack, slab)
+
+
+def _slab_sized_ops(text: str, op: str, rows: int, rank=None):
+    """→ [(enclosing computation, line)] of the `op`s of optimized HLO
+    `text` whose result holds at least `rows` elements (in `rank`
+    dimensions, if given)."""
+    import re
+    found, comp = [], ""
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY )?%([^ ]+) \(.*\) -> .* \{$", line)
+        if head:
+            comp = head.group(1)
+        m = re.search(r" = ([^ ]+) %s\(" % re.escape(op), line)
+        if m is None:
+            continue
+        dims = re.search(r"\[([\d,]*)\]", m.group(1))
+        dims = [int(d) for d in dims.group(1).split(",") if d] if dims \
+            else []
+        if int(np.prod(dims)) >= rows and rank in (None, len(dims)):
+            found.append((comp, line.strip()[:160]))
+    return found
+
+
+# temporaries of the SAME two programs at the parent (PR 44's tree: the
+# slabs a pytree each, a `lax.switch` a turn → one `conditional` whose
+# branches copy a slab), compiled there for the same described chip. This
+# tree's read 301,810,688 and 131,480,576 (compiled here, PR 46): the copies'
+# buffers went, the staged words of the narrow columns came — within 5%
+PARENT_TEMP_BYTES = {"stmt_chain": 287_900_160,
+                     "stmt_fused": 242_004_992}
+
+
+def test_a_six_slab_statement_program_indexes_its_slabs_in_place(
+        recorded, one_chip, monkeypatch):
+    """Q1 (`stmt_chain`) over six full 8M-row base slabs and Q3
+    (`stmt_fused`: masks, the FK-aligned match mask and gathered build
+    columns in the fact's row space, the raw delta slab behind) as the
+    statement programs the chip runs at SF=8: the base slabs are ONE array
+    a leaf and the loop indexes it — no `conditional` (PR 39's
+    `lax.switch`: a copy of a slab's every array a turn, 12% of
+    `qstream8.sf8`'s device time; the parent's Q1 program holds 24 such
+    copies of a million elements or more, each a 1-D slab array), no copy
+    of a slab's 1-D array (what is left are the contraction's own
+    relayouts, rank 4, the same thirteen the parent has), and every
+    `dynamic-slice` of a stack INSIDE a fused computation: the 32-bit
+    column in the fusion that unpacks it, the narrower packed words each
+    in a fusion of their own that hands the slab's words to the fast
+    memory space (`S(1)`) its several readers then share. The stack
+    folds a 1-D leaf to (slabs, rows / 128, 128): the compiler then keeps
+    the slab axis OUTSIDE the tile for every dtype — a bool mask comes out
+    `pred[6,65536,128]{2,1,0:T(8,128)(4,1)}`, six slabs take the bytes of
+    six — where (slabs, rows) is tiled `T(8,128)` with the slab axis
+    inside: padded to eight and read with a stride."""
+    import functools
+    import re
+    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor.tree_fragment import TreeProgram
+    from tidb_tpu.ops import jax_env
+    jax = jax_env.jax
+    monkeypatch.setattr(jax_env, "on_tpu", lambda: True)
+    factor = fragment.DEFAULT_MAX_SLAB_ROWS // TOY_ROWS
+    slab_rows, n_slabs = fragment.DEFAULT_MAX_SLAB_ROWS, 6
+    picks = (jax.ShapeDtypeStruct((n_slabs,), np.int32, sharding=one_chip),)
+
+    # -- Q1: a chain over six slabs under live-row counts ------------------
+    owner, _m, (cols, n_rows, preps) = next(
+        c for c in recorded if c[1] == "_partial" and c[0].layouts
+        and c[0].chain[0].group_exprs
+        and getattr(c[2][1], "dtype", None) != np.dtype(bool))
+    p = fragment._FragmentProgram(
+        owner.chain, owner.used_cols, owner.in_types,
+        owner.slab_cap * factor, owner.group_cap, owner.key_bounds,
+        owner.has_distinct, owner.layouts, owner.pair_cap)
+    slab = (_scaled_cols(jax, cols, factor, one_chip),
+            _scaled_live(jax, n_rows, factor, one_chip))
+    chain_args = (_scaled(jax, preps, 1, one_chip),
+                  _stacked(jax, slab, n_slabs), None, picks)
+    chain = fragment._StatementProgram(
+        "stmt_chain", functools.partial(fragment._ChainSlabs._slab_body, p),
+        None, p._merge, fragment._ChainSlabs.control, True, "toy-q1",
+        chain_args)
+
+    # -- Q3: the join tree's statement program over a delta generation -----
+    sp, _m, (shared, base, delta, _picks) = next(
+        c for c in recorded if isinstance(c[0], fragment._StatementProgram))
+    assert isinstance(sp.body.args[0], TreeProgram) and delta is not None
+    bodies = [functools.partial(
+        fragment._TreeSlabs._slab_body, _rebuild_tree(b.args[0], factor),
+        b.args[1]) for b in (sp.body, sp.dbody)]
+    si, sr, pv, ai, nested = shared
+    grow = functools.partial(_scaled, jax, factor=factor, sharding=one_chip)
+    shared = (tuple(c if c is None else _scaled_cols(jax, c, factor, one_chip)
+                    for c in si),
+              _scaled_live(jax, sr, factor, one_chip),
+              _scaled(jax, pv, 1, one_chip), ai, nested)
+
+    def own(slab_arg):
+        cols, live, sliced = slab_arg
+        return ({i: [_scaled_cols(jax, {0: t}, factor, one_chip)[0]
+                     for t in ts] for i, ts in cols.items()},
+                grow(live), grow(sliced))
+    fused_args = (shared, _stacked(jax, own(base), n_slabs), own(delta),
+                  picks)
+    fused = fragment._StatementProgram(
+        "stmt_fused", bodies[0], bodies[1], sp.tail, sp.control, sp.small,
+        "toy-q3", fused_args)
+
+    for kind, prog, args in (("stmt_chain", chain, chain_args),
+                             ("stmt_fused", fused, fused_args)):
+        assert prog.said == {"slab_pick": "index"}
+        compiled = prog.run.lower(*args).compile()
+        text, m = compiled.as_text(), compiled.memory_analysis()
+        _fits([(kind, m)])
+        assert f"jit_{kind}_" in text.splitlines()[0], text[:200]
+        assert not re.search(r"\bconditional\(", text), kind
+        assert not _slab_sized_ops(text, "copy", slab_rows // 32, rank=1), \
+            kind
+        # (a slab's narrowest leaf: its validity words, a bit a row)
+        slices = _slab_sized_ops(text, "dynamic-slice", slab_rows // 32)
+        assert len(slices) >= 8, f"{kind}: the loop reads no stack"
+        outside = [s for s in slices if "fused_computation" not in s[0]]
+        assert not outside, (kind, outside[:3])
+        assert m.temp_size_in_bytes <= 1.05 * PARENT_TEMP_BYTES[kind], \
+            (kind, m.temp_size_in_bytes)
+        # the stacked bool masks: the slab axis outside the tile, unpadded
+        if kind == "stmt_fused":
+            masks = re.findall(
+                r"pred\[%d,%d,128\]\{2,1,0:T\(8,128\)\(4,1\)"
+                % (n_slabs, slab_rows // 128), text)
+            assert masks, "no stacked bool mask among the parameters"
+            assert not re.search(r"pred\[%d,%d\]" % (n_slabs, slab_rows),
+                                 text)
+
+    # -- Q1's slab program over the SAME stacks (a digest's first execution,
+    # the `slabs:*` plans): which slab is a device scalar beside the stack
+    # (`SlabColumn.at`), the program indexes the stack in its own fusions —
+    # no slab is copied out
+    from tidb_tpu.executor import device_cache as dc
+    at = dc._node(dc.Stacked)
+    row = jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)
+    is_stack = lambda x: isinstance(x, dc.Stacked)      # noqa: E731
+    cols_at = jax.tree.map(
+        lambda x: at(x.a, row, 0, x.shape) if is_stack(x) else x,
+        chain_args[1][0], is_leaf=is_stack)
+    compiled = p.partial.lower(cols_at, slab[1], chain_args[0]).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bconditional\(", text)
+    assert not _slab_sized_ops(text, "copy", slab_rows // 32, rank=1)
+    slices = _slab_sized_ops(text, "dynamic-slice", slab_rows // 32)
+    assert len(slices) >= 8
+    assert not [s for s in slices if "fused_computation" not in s[0]]
+
+
+def test_a_short_leaf_takes_whole_lanes_in_its_stack(one_chip):
+    """Slabs whose leaf is no multiple of 128 long (a small table's: 1000
+    rows): the stack pads the leaf to whole lanes, (slabs, 8, 128) — the
+    slab axis outside the tile, six slabs in the bytes of six folds —
+    where (slabs, 1, rows) would put a dimension of ONE inside the tile,
+    padded to eight. A per-slab program (the slab's row beside the stack)
+    and a statement program's loop read a slab of it inside the consuming
+    fusion."""
+    import re
+    from tidb_tpu.executor import device_cache as dc
+    from tidb_tpu.ops.jax_env import jax, jnp, lax
+    n, n_slabs = 1000, 6
+    assert dc._folded((n,)) == (8, 128)
+    stack = jax.ShapeDtypeStruct((n_slabs, 8, 128), np.int32,
+                                 sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((n_slabs, 8, 128), np.bool_,
+                                sharding=one_chip)
+    picks = (jax.ShapeDtypeStruct((n_slabs,), np.int32, sharding=one_chip),)
+    row = jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)
+    node = dc._node(dc.Stacked)
+
+    def loop(base, picks, one):
+        def turn(c, k):
+            v, m = dc.in_place(base, picks, k)
+            assert v.shape == m.shape == (n,)
+            return c + jnp.sum(jnp.where(m, v, 0)), None
+        v, m = dc.in_place(one)
+        return lax.scan(turn, jnp.sum(jnp.where(m, v, 1)),
+                        jnp.arange(n_slabs, dtype=jnp.int32))[0]
+    compiled = jax.jit(loop).lower(
+        (node(stack, None, 0, (n,)), node(mask, None, 0, (n,))), picks,
+        (node(stack, row, 0, (n,)), node(mask, row, 0, (n,)))).compile()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    assert re.search(r"s32\[6,8,128\]\{2,1,0:T\(8,128\)", text)
+    assert not re.search(r"\bconditional\(|\[6,1,1000\]", text)
+    # two int32 stacks and two masks, a byte a row, and the small vectors
+    assert m.argument_size_in_bytes <= 2 * 6 * 1024 * (4 + 1) + 4096, m
+    outside = [s for s in _slab_sized_ops(text, "dynamic-slice", n)
+               if "fused_computation" not in s[0]]
+    assert not outside, outside
+
+
+def test_a_slab_goes_into_its_stack_in_place(one_chip):
+    """The fill of a stack (`device_cache._fill`, PR 46): one 8M-row slab
+    of a 32-bit leaf written into the DONATED six-slab stack. Compiled for
+    the v5e the output IS the argument's buffer (`input_output_alias`), the
+    program holds no copy of the stack and no temporaries to speak of: a
+    fill never holds two stacks, and the slab's 1-D → folded form costs no
+    pass of its own."""
+    import re
+    from tidb_tpu.executor import device_cache as dc, fragment
+    from tidb_tpu.ops.jax_env import jax
+    rows, n = fragment.DEFAULT_MAX_SLAB_ROWS, 6
+    first, put = dc._stack_programs(n, (rows,), "uint32")
+    stack = jax.ShapeDtypeStruct((n, rows // 128, 128), np.uint32,
+                                 sharding=one_chip)
+    slab = jax.ShapeDtypeStruct((rows,), np.uint32, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)
+    assert jax.eval_shape(first, slab).shape == stack.shape
+    compiled = put.lower(stack, slab, row).compile()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    assert "jit_slab_stack_" in text.splitlines()[0]
+    assert re.search(r"input_output_alias=\{[^}]*\{\}: \(0,", text), \
+        text[:400]
+    assert not _slab_sized_ops(text, "copy", rows)
+    assert m.alias_size_in_bytes == n * rows * 4, m
+    assert m.temp_size_in_bytes <= 1 << 20, m
+
+
+def test_masks_are_born_in_a_filled_stacks_layout(one_chip):
+    """A table's first commit makes its liveness masks stacked with ONE
+    program (`device_emit.emit_alive_stack`; no fill). Compiled for the
+    v5e at six slabs of 8M rows the result has the layout a filled stack
+    of masks has — the slab axis outside the tile, unpadded: a byte a row
+    — and the program holds no temporaries to speak of."""
+    import re
+    from tidb_tpu.executor import device_emit, fragment
+    from tidb_tpu.ops.jax_env import jax
+    rows, n = fragment.DEFAULT_MAX_SLAB_ROWS, 6
+    born = device_emit.emit_alive_stack([rows] * 5 + [17], rows)
+    assert born.shape == (n, rows // 128, 128) and born.dtype == bool
+    assert int(born[5].sum()) == 17 and bool(born[4].all())
+    prog = device_emit._DELTA_PROGRAMS[("tombstone", ("init", rows, n))]
+    compiled = prog.lower(jax.ShapeDtypeStruct(
+        (n,), np.int32, sharding=one_chip)).compile()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    assert re.search(r"pred\[%d,%d,128\]\{2,1,0:T\(8,128\)\(4,1\)"
+                     % (n, rows // 128), text)
+    assert m.output_size_in_bytes <= n * rows + 4096, m
+    assert m.temp_size_in_bytes <= 1 << 20, m
 
 
 def re_search_stage(text: str, stage: str):

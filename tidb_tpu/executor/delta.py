@@ -412,7 +412,10 @@ def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
     new.base_td, new.lineage = ent.base_td, ent.lineage
     new.delta_version = int(getattr(ctx.snapshot, "version", 0) or 0)
     new.device, new.owners = ent.device, ent.owners
-    new.dev = {i: list(ent.dev[i]) for i in resident}
+    new.pick_dev = ent.pick_dev     # (of the base build, as the stacks are)
+    # (the base slabs by identity — a stacked column's ONE array — and a
+    # delta slab of this generation's own)
+    new.dev = {i: ent.dev[i].fork() for i in resident}
     new.alive, new.rows_override = ent.alive, ent.rows_override
     new.delta_cap, new.delta_rows = ent.delta_cap, ent.delta_rows
     new.dead_rows, new.is_delta = ent.dead_rows, ent.is_delta
@@ -469,9 +472,12 @@ def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
     # liveness: one mask a slab from the first change on, so that every
     # statement of a delta generation runs the masked variant of its slab
     # programs and none compiles when the first tombstone arrives later
-    alive = list(ent.alive) if ent.alive is not None else [
-        _on_slab(ent, s, device_emit.emit_alive_init,
-                 ent.slab_rows(s), cap) for s in range(base_slabs)]
+    if ent.alive is not None:
+        # (the base's masks under a structure of this generation's own: a
+        # rewrite below leaves the older generation's as they were)
+        alive = ent.alive.fork(own=True)
+    else:
+        alive = base_masks(ent, table_id)
     rows = dict(ent.rows_override) if ent.rows_override is not None else {
         s: ent.slab_rows(s) for s in range(base_slabs)}
     if new.delta_cap and len(alive) == base_slabs:
@@ -479,8 +485,26 @@ def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
                               dcap))
         rows[base_slabs] = 0
     none = np.empty(0, dtype=np.int32)
+    if alive.is_stacked and dead_base.size:
+        # ONE rewrite of the stacked masks, whatever slabs the rows died in
+        # (a generation's masks are one array: it is new whole — every
+        # slab's mask is read and written, `base_slabs` of them). `rows`
+        # stays what the yardstick defines, the LEAST a rewrite had to
+        # move: the capacity of the slabs a row died in (`touched`)
+        touched = int(np.unique(dead_base // cap).size)
+        with timeline.span("delta.tombstone", "delta",
+                           slab=int(dead_base[0] // cap),
+                           tombs=int(dead_base.size), touched=touched,
+                           rows=cap * touched, table=table_id):
+            idx = _pad_idx(dead_base, cap * base_slabs)
+            alive.set_stack(device_emit.emit_alive_update(
+                alive.stack_leaf(), none, idx, cap, stacked=True))
+        h2d += idx.nbytes
     for s in sorted(set((dead_base // cap).tolist())):
         pos = dead_base[dead_base // cap == s] - s * cap
+        rows[s] -= int(pos.size)
+        if alive.is_stacked:
+            continue
         with timeline.span("delta.tombstone", "delta", slab=int(s),
                            tombs=int(pos.size), rows=cap,
                            table=table_id):
@@ -488,7 +512,6 @@ def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
             alive[s] = device_emit.emit_alive_update(alive[s], none, idx,
                                                      cap)
         h2d += idx.nbytes
-        rows[s] -= int(pos.size)
     if n_new or dead_delta.size:
         born = _pad_idx(np.arange(ent.delta_rows, cursor), dcap)
         idx = _pad_idx(dead_delta, dcap)
@@ -534,6 +557,28 @@ def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
             schedule_compaction(store, key, scan, resident, max_slab,
                                 dict(ctx.vars), cause)
     return new
+
+
+def base_masks(ent, table_id) -> "dc.SlabColumn":
+    """The liveness masks of `ent`'s base slabs, made on the device from
+    their live prefixes. Where the base slabs can be held as ONE array
+    (several of them, on one device, none lost) the masks are BORN so —
+    one program, nothing filled, nothing through the host — whether or
+    not a statement program has stacked the table's columns yet: a
+    generation's masks then have one form for the table's whole life, and
+    so has the program that rewrites them (a warm-up that ran it over a
+    mask a slab would leave the stacked one to compile inside some later
+    window). Else a mask a slab."""
+    from tidb_tpu.executor import device_cache as dc, device_emit
+    cap, n = ent.slab_cap, ent.base_slabs
+    if n > 1 and ent.owners is None and not ent.lost:
+        return dc.SlabColumn.born_stacked(
+            _on_slab(ent, 0, device_emit.emit_alive_stack,
+                     [ent.slab_rows(s) for s in range(n)], cap),
+            (cap,), table_id)
+    return dc.SlabColumn(
+        _on_slab(ent, s, device_emit.emit_alive_init, ent.slab_rows(s), cap)
+        for s in range(n))
 
 
 def _on_slab(ent, s: int, fn, *args):

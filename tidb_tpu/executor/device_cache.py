@@ -89,16 +89,625 @@ KEPT_GENERATIONS = 1
 KEPT_BYTES = 128 << 20
 
 
+class Stacked:
+    """One leaf of a column's base slabs held as ONE device array with a
+    leading axis of slabs (`SlabColumn.stacked`), as a program takes it:
+    the stack `a`, and which of its rows the program reads. A statement
+    program's loop reads a row a turn — `which` names the vector of stack
+    rows (one a distinct set of resident slabs, `SlabPicks.vectors`) that
+    turn `k` indexes; a program of ONE slab takes the slab's `row` beside
+    the stack, a device scalar (`SlabColumn.at`: which slab is an
+    argument, so six slabs are one program); a program that reads the
+    table whole asks for rows it knows (`WholeColumn`). Each is `slab(row)`:
+    an index of the stack inside the fusion that reads the slab. `shape`:
+    the leaf's own in ONE slab — a stack holds a 1-D leaf folded
+    (`_folded`), and a slab of it is reshaped back, which is a bitcast. A
+    pytree node: the array and `row` (or None) are its children, `which`
+    and `shape` static."""
+
+    __slots__ = ("a", "row", "which", "shape")
+
+    def __init__(self, a, row, which: int, shape: tuple):
+        self.a, self.row, self.which, self.shape = a, row, which, shape
+
+    def children(self):
+        return (self.a, self.row), (self.which, self.shape)
+
+    def slab(self, row):
+        """Row `row` of the stack — a traced scalar, or a number — as the
+        leaf in its own shape (traced)."""
+        from tidb_tpu.ops.jax_env import lax
+        return _unfold(self.a[row] if isinstance(row, int) else
+                       lax.dynamic_index_in_dim(self.a, row, 0,
+                                                keepdims=False), self.shape)
+
+
+_NODES: set = set()
+
+
+def _node(cls):
+    """`cls` — `Stacked`, `WholeColumn` — registered as a pytree node on
+    first use (jax is not imported with this module): `children` → (its
+    arrays, what is static), `cls(*children, *static)` back."""
+    if cls not in _NODES:
+        from tidb_tpu.ops.jax_env import jax
+        jax.tree_util.register_pytree_node(
+            cls, cls.children, lambda aux, kids: cls(*kids, *aux))
+        _NODES.add(cls)
+    return cls
+
+
+def in_place(tree, picks=(), k=None):
+    """A program's arguments with what stands for slabs of stacked columns
+    resolved INSIDE its trace — slices of the stacks that fuse into what
+    reads them: a `Stacked` leaf as its slab (the row it came with, else
+    turn `k`'s of a statement program's `picks`), a `WholeColumn` as its
+    list of slabs. The statement programs' loop
+    (`fragment._StatementProgram`), the slab programs
+    (`fragment._Program._partial`) and the tree programs
+    (`TreeProgram._run`) call it first; anything else is handed `col[s]`,
+    a copy (`tidb_tpu_slab_slices_total`)."""
+    from tidb_tpu.ops.jax_env import jax
+
+    def read(x):
+        if isinstance(x, WholeColumn):
+            return x.slabs()
+        if not isinstance(x, Stacked):
+            return x
+        if x.row is not None:
+            return x.slab(x.row)
+        return x.slab(picks[x.which][k]) if k is not None else x
+    return jax.tree.map(
+        read, tree, is_leaf=lambda x: isinstance(x, (WholeColumn, Stacked)))
+
+
+LANES = 128     # the minor dimension of a device tile
+
+
+def _folded(shape: tuple) -> tuple:
+    """The shape a slab's leaf has inside its column's stack. The device
+    tiles an array's two minor dimensions: stacked as (slabs, rows), the
+    SLAB axis of a 1-D leaf would lie inside a tile — padded to 8 (six
+    slabs take the HBM of eight) and every slab read with a stride. Folded
+    to (rows / 128, 128) the slab axis stays outside, a slab is as
+    contiguous as it was alone, and slab ↔ its 1-D form is a bitcast
+    (compiled for the v5e: `tests/test_tpu_compile.py`). A length that is
+    no multiple of 128 (a small table's) is padded up to one: its slab is
+    the fold's first rows."""
+    if len(shape) != 1:
+        return shape
+    return (-(-shape[0] // LANES), LANES)
+
+
+def _fold(a):
+    """One slab's leaf as its column's stack holds it (`_folded`)."""
+    if a.ndim != 1:
+        return a
+    from tidb_tpu.ops.jax_env import jnp
+    pad = -a.shape[0] % LANES
+    return (jnp.pad(a, (0, pad)) if pad else a).reshape(_folded(a.shape))
+
+
+def _unfold(a, shape: tuple):
+    """A slab of a stack → the leaf in its own shape (a bitcast, and a
+    cut where the fold was padded)."""
+    if len(shape) != 1:
+        return a
+    a = a.reshape(-1)
+    return a if a.shape[0] == shape[0] else a[:shape[0]]
+
+
+def _stack_programs(n: int, shape: tuple, dtype: str):
+    """→ (`first`, `put`): a new stack of `n` slabs of one leaf with its
+    first slab in row 0 (allocated where that slab lies: the entry's
+    device), and one slab written into a row of it — the stack DONATED, so
+    the write is in place and a fill never holds two stacks (names of
+    their own kind, `slab_stack_<sig8>`; which row is an argument: one
+    `put` a leaf)."""
+    from tidb_tpu.executor import device_emit
+    from tidb_tpu.ops.jax_env import jnp, lax
+    key = (n, tuple(shape), dtype)
+
+    def _put(stack, slab, row):
+        return lax.dynamic_update_index_in_dim(stack, _fold(slab), row, 0)
+
+    def _first(slab):
+        return _put(jnp.zeros((n,) + _folded(tuple(shape)), dtype=dtype),
+                    slab, 0)
+    return (device_emit._delta_program("slab_stack", ("first",) + key,
+                                       lambda: _first),
+            device_emit._delta_program("slab_stack", ("put",) + key,
+                                       lambda: _put, donate_argnums=(0,)))
+
+
+def _write_slab(write, *args):
+    """One slab into a stack (`first(slab)`, `put(stack, slab, row)`) →
+    the stack, WRITTEN: dispatch runs ahead, and every slab a fill
+    uploaded before its first write ended would lie beside the stack at
+    once."""
+    from tidb_tpu.ops.jax_env import jax
+    return jax.block_until_ready(write(*args))
+
+
+def _on_host(a, device):
+    """`a`'s bytes in host memory of their own. (The CPU backend hands
+    out a view of the device buffer, which would keep it: copied there.)"""
+    h = np.asarray(a)
+    return h.copy() if device.platform == "cpu" else h
+
+
+def _fill(col: list, device):
+    """The resident slabs of ONE leaf (`col`, emptied as it goes) → their
+    stack, holding a SLAB twice and never the leaf: the stack's own bytes
+    must be free before its first row is written, and the slabs lie where
+    they would have to be — so all but the first wait on the HOST (dropped
+    from the device as each arrives there), the stack is allocated beside
+    the one that stayed, and each goes in through a donated write (the
+    first from where it lies, the others uploaded one at a time). On the
+    device at any moment: the leaf's bytes and at most one slab's more.
+    The arrays are dropped, never `.delete()`d: a launch in flight that
+    took one still reads it."""
+    from tidb_tpu.ops.jax_env import jax
+    first, put = _stack_programs(len(col), tuple(col[0].shape),
+                                 str(col[0].dtype))
+    # (the stack is committed to its device iff its slabs were — an
+    # entry pinned to a pool member's; on one device nothing is, as at
+    # the upload: a program compiles once an argument's commitment, and
+    # a stack of another than the arrays around it compiles each twice)
+    there = device if col[0].committed else None
+    for r in range(1, len(col)):
+        col[r] = _on_host(col[r], device)
+    stack = None
+    for r, slab in enumerate(col):
+        if isinstance(slab, np.ndarray):
+            slab = jax.device_put(slab, there)
+        stack = _write_slab(first, slab) if r == 0 else \
+            _write_slab(put, stack, slab, np.int32(r))
+        col[r] = slab = None
+    return stack
+
+
+# a column's base slabs become one array under this lock (`SlabColumn.stack`:
+# once a column and base build; a stacked column's readers take none)
+_STACK_LOCK = timeline.named_lock("slab_stack")
+
+# what `_BaseSlabs.slabs` reads while a fill holds the column's arrays
+_IN_TRANSIT = object()
+
+
+def _leaves(t) -> tuple:
+    """The arrays of one slab of a column: a tuple's, or the one (a mask);
+    none of a hole's."""
+    return () if t is None else tuple(t) if isinstance(t, (tuple, list)) \
+        else (t,)
+
+
+def _count_slice(b: "_BaseSlabs") -> None:
+    """A slab was SLICED out of a stacked column — a device copy of its
+    arrays, for a per-slab consumer. Always-on counter
+    `tidb_tpu_slab_slices_total{table=}`: 0 in a warm window of statements
+    that run `launch_plan=whole` (nothing hides a copy a statement)."""
+    from tidb_tpu.util.observability import REGISTRY
+    REGISTRY.inc("tidb_tpu_slab_slices_total", {"table": b.table})
+
+
+class _BaseSlabs:
+    """The base slabs of one column: what every generation of one base
+    build shares by identity. A list of per-slab pytrees (None = a hole)
+    until `SlabColumn.stack` makes them ONE array a leaf — the stack IS
+    the storage from then on, the per-slab arrays are dropped as they go
+    in. Readers ask `lists()`: while a fill holds the arrays (`slabs` is
+    `_IN_TRANSIT`) there is nothing to read, and they wait it out."""
+
+    __slots__ = ("slabs", "leaves", "shared", "shapes", "treedef", "pos",
+                 "table", "row_dev")
+
+    def __init__(self, slabs):
+        self.slabs = list(slabs)    # (None once stacked)
+        # once stacked: the slab pytree's leaves, each with a leading axis
+        # of the RESIDENT slabs and its 1-D rows folded (`_folded`; a leaf
+        # every slab's tuple shares — a dictionary — as it is: `shared`),
+        # each leaf's own shape in one slab, the slab's tree structure,
+        # and slab → row of the stack (None: a hole)
+        self.leaves: Optional[list] = None
+        self.shared: tuple = ()
+        self.shapes: tuple = ()
+        self.treedef = None
+        self.pos: tuple = ()
+        self.table = ""             # (for the counters: whose column)
+        self.row_dev: dict = {}     # stack row → it, a device int32 scalar
+
+    def tree(self, leaf):
+        """One slab's pytree over the stacks: `leaf(stack, the leaf's own
+        shape in one slab)` for every stacked leaf, a shared one as it is."""
+        return self.treedef.unflatten(
+            a if sh else leaf(a, shape)
+            for a, sh, shape in zip(self.leaves, self.shared, self.shapes))
+
+    def lists(self) -> Optional[list]:
+        """The per-slab list, or None once the column is stacked (a fill
+        in progress is over when its lock is free)."""
+        slabs = self.slabs
+        if slabs is _IN_TRANSIT:
+            with _STACK_LOCK:
+                slabs = self.slabs
+        return slabs
+
+    def copy(self) -> "_BaseSlabs":
+        """The same arrays under a structure of its own: what a generation
+        that CHANGES base slabs (liveness, match masks) writes into."""
+        slabs = self.lists()
+        new = _BaseSlabs(slabs or ())
+        if slabs is None:
+            new.slabs, new.leaves = None, list(self.leaves)
+            new.shared, new.shapes, new.treedef, new.pos, new.table = \
+                self.shared, self.shapes, self.treedef, self.pos, self.table
+            new.row_dev = self.row_dev
+        return new
+
+
+class SlabColumn:
+    """One resident column's slabs as the device holds them — a table
+    column's packed or raw tuples, a generation's liveness masks, an
+    aligned join's match masks or gathered build columns in the fact's row
+    space. List-like for everything that reads slab `s`: `col[s]`,
+    `len(col)`, iteration; the raw delta slab (a shape of its own) sits
+    behind the base slabs at `col[n_base]`.
+
+    The base slabs share one shape and never change after the build, so a
+    column with more than one can be held as ONE array a leaf with a
+    leading axis of slabs (`stack`, when the first statement program over
+    the table is built: the code observes it, nothing switches it on; the
+    stack is filled a slab at a time, `_fill`, so the device never holds
+    a leaf's slabs beside their stack; a generation's liveness masks are
+    MADE so at the table's first commit, `born_stacked`, columns stacked
+    or not: one form for the table's life).
+    From then on every PROGRAM reads a slab where it lies: `stacked()`
+    hands a statement program the array itself (`Stacked` leaves its loop
+    indexes), `at(s)` a per-slab program the array and the slab's row
+    (`Stacked` too), `whole()` a program that reads the table whole the array
+    and the rows (`WholeColumn`) — each resolved inside the program's
+    trace. `col[s]` is a SLICE, a device copy, for what is no program of
+    those (the aligned structures' one-off builds, the compactor, the
+    delta path's host-driven steps), counted
+    (`tidb_tpu_slab_slices_total`). A one-slab column is its own stack:
+    nothing is copied, nothing indexed. Generations of one base build
+    share the base (`fork`); what changes a commit (a mask) is a new
+    stacked array (`fork(own=True)`, `set_stack`). An entry whose slabs
+    lie on several devices (`owners`) or that lost one is never stacked
+    (`SlabPicks.of`)."""
+
+    __slots__ = ("base", "delta")
+
+    def __init__(self, slabs=(), delta=None, base: _BaseSlabs = None):
+        self.base = _BaseSlabs(slabs) if base is None else base
+        self.delta = delta
+
+    @classmethod
+    def born_stacked(cls, stack, shape: tuple, table="") -> "SlabColumn":
+        """A one-leaf column (a generation's liveness masks) whose base
+        slabs a program MADE as one array (`stack`: slabs × a slab's leaf
+        of `shape`, folded): nothing to fill, nothing crosses the host."""
+        from tidb_tpu.ops.jax_env import jax
+        b = _BaseSlabs(())
+        b.slabs, b.leaves, b.shared = None, [stack], (False,)
+        b.shapes, b.treedef = (tuple(shape),), jax.tree.structure(0)
+        b.pos, b.table = tuple(range(stack.shape[0])), str(table)
+        return cls(base=b)
+
+    def fork(self, own: bool = False) -> "SlabColumn":
+        """This column for a later generation: the same base (by identity;
+        `own`: under a structure that can be written to), its own delta
+        slab."""
+        return SlabColumn(delta=self.delta,
+                          base=self.base.copy() if own else self.base)
+
+    # -- list-like ----------------------------------------------------------
+    @property
+    def n_base(self) -> int:
+        slabs = self.base.lists()
+        return len(self.base.pos) if slabs is None else len(slabs)
+
+    @property
+    def is_stacked(self) -> bool:
+        return self.base.lists() is None
+
+    def __len__(self) -> int:
+        return self.n_base + (self.delta is not None)
+
+    def __iter__(self):
+        return (self[s] for s in range(len(self)))
+
+    def __getitem__(self, s):
+        if isinstance(s, slice):
+            return [self[i] for i in range(*s.indices(len(self)))]
+        n = self.n_base
+        if s < 0:
+            s += len(self)
+        if s == n and self.delta is not None:
+            return self.delta
+        if not 0 <= s < n:
+            raise IndexError(s)
+        b = self.base
+        slabs = b.lists()           # (read once: `stack` swaps it for None)
+        if slabs is not None:
+            return slabs[s]
+        row = b.pos[s]
+        if row is None:
+            return None
+        _count_slice(b)
+        return b.tree(lambda a, shape: _unfold(a[row], shape))
+
+    def __setitem__(self, s: int, t) -> None:
+        slabs = self.base.lists()
+        if s == self.n_base:
+            self.delta = t
+        elif slabs is None:
+            raise TypeError("a stacked base is rewritten by set_stack")
+        else:
+            slabs[s] = t
+
+    def append(self, t) -> None:
+        """The raw delta slab takes its place behind the base."""
+        assert self.delta is None
+        self.delta = t
+
+    def holes(self) -> frozenset:
+        slabs = self.base.lists()
+        seq = self.base.pos if slabs is None else slabs
+        return frozenset(s for s, t in enumerate(seq) if t is None)
+
+    # -- accounting (no slice, no device work) ------------------------------
+    def arrays(self):
+        """(slab index, device array) of everything held, each array once
+        (a stacked leaf under slab 0)."""
+        b, seen = self.base, set()
+        slabs = b.lists()
+        per_slab = list(enumerate(slabs)) if slabs is not None else \
+            [(0, b.leaves)]
+        for s, t in per_slab + [(self.n_base, self.delta)]:
+            for a in _leaves(t):
+                if id(a) not in seen:
+                    seen.add(id(a))
+                    yield s, a
+
+    def slab_nbytes(self, s: int) -> int:
+        """Physical bytes a reader of slab `s` reads (0 for a hole), from
+        shapes and dtypes alone."""
+        b = self.base
+        slabs = b.lists()
+        if s >= self.n_base or slabs is not None:
+            return _tuple_nbytes(
+                _leaves(self.delta if s >= self.n_base else slabs[s]))
+        if b.pos[s] is None:
+            return 0
+        return sum(math.prod(a.shape if sh else a.shape[1:])
+                   * a.dtype.itemsize for a, sh in zip(b.leaves, b.shared))
+
+    def specs(self) -> tuple:
+        """(shape, dtype) of each leaf of ONE base slab (no slice)."""
+        b = self.base
+        slabs = b.lists()
+        if slabs is None:
+            return tuple((shape, a.dtype)
+                         for shape, a in zip(b.shapes, b.leaves))
+        one = next((t for t in slabs if t is not None), self.delta)
+        return tuple((tuple(a.shape), a.dtype) for a in _leaves(one))
+
+    # -- the stack ----------------------------------------------------------
+    def stack(self, table="") -> None:
+        """Hold the base slabs as ONE array a leaf from now on (no-op for
+        a one-slab or an already stacked column): once a column and base
+        build (`tidb_tpu_slab_stacks_total{table=}`, span `slab.stack`).
+        From the first line under the lock the arrays are the fill's alone
+        (`_IN_TRANSIT`: a reader waits) and go into their stack a leaf and
+        a slab at a time (`_fill`), so no list lies beside its stack."""
+        b = self.base
+        slabs = b.lists()
+        if slabs is None or len(slabs) < 2:
+            return
+        del slabs
+        from tidb_tpu.ops.jax_env import jax
+        from tidb_tpu.util.observability import REGISTRY
+        with _STACK_LOCK:
+            slabs = b.slabs         # (under the lock: never in transit)
+            if slabs is None:
+                return
+            pos, held = [], 0
+            for t in slabs:
+                pos.append(None if t is None else held)
+                held += t is not None
+            if not held:
+                return
+            flat = [jax.tree.flatten(t) for t in slabs if t is not None]
+            treedef = flat[0][1]
+            # leaf → its array a resident slab: the only references the
+            # cache keeps from here on (frames of launches in flight aside)
+            src = [list(col) for col in zip(*(lv for lv, _td in flat))]
+            b.slabs = _IN_TRANSIT
+            del slabs, flat, t
+            shared = tuple(held > 1 and all(a is col[0] for a in col)
+                           for col in src)
+            shapes = tuple(tuple(col[0].shape) for col in src)
+            device = next(iter(src[0][0].devices()))
+            try:
+                with timeline.span("slab.stack", "cache", table=table,
+                                   slabs=held):
+                    leaves = [col[0] if sh else _fill(col, device)
+                              for col, sh in zip(src, shared)]
+            except BaseException:
+                # (the device is out of memory, or an interrupt: the
+                # arrays went with the fill — nothing of the column is
+                # resident, and `SlabPicks.of` drops the table's entries)
+                b.slabs = [None] * len(pos)
+                raise
+            b.pos, b.shapes, b.shared = tuple(pos), shapes, shared
+            b.leaves, b.treedef, b.table = leaves, treedef, str(table)
+            b.slabs = None          # (last: readers look at it first)
+        REGISTRY.inc("tidb_tpu_slab_stacks_total", {"table": str(table)})
+
+    def stack_leaf(self):
+        """The stack of a one-leaf column (a mask), or None if unstacked."""
+        return None if self.base.lists() is not None \
+            else self.base.leaves[0]
+
+    def set_stack(self, leaf) -> None:
+        """A one-leaf stacked column's stack, rewritten (a NEW array: the
+        old one is another generation's; the base must be this
+        generation's own, `fork(own=True)`)."""
+        assert self.base.lists() is None
+        self.base.leaves[0] = leaf
+
+    def stacked(self, which: int = 0):
+        """The base slabs as a statement program takes them: the slab's
+        pytree with every stacked leaf a `Stacked`; a one-slab column's
+        own arrays as they are."""
+        b = self.base
+        slabs = b.lists()
+        if slabs is not None:
+            assert len(slabs) == 1, "stack() first"
+            return slabs[0]
+        node = _node(Stacked)
+        return b.tree(lambda a, shape: node(a, None, which, shape))
+
+    def rows_of(self, ids) -> tuple:
+        """The stack rows of slabs `ids` (stacked, all resident)."""
+        return tuple(self.base.pos[s] for s in ids)
+
+    def at(self, s: int):
+        """Slab `s` for a PROGRAM that reads one slab (the per-slab plans,
+        a digest's first execution, a filter or ORDER BY root): as `col[s]`
+        — but of a stacked column the stack itself and the slab's row in
+        it (`Stacked` leaves), which the program indexes inside its trace
+        (`in_place`), where `col[s]` would copy the slab first."""
+        b = self.base
+        if b.lists() is not None or not 0 <= s < self.n_base \
+                or b.pos[s] is None:
+            return self[s]
+        row = b.pos[s]
+        idx = b.row_dev.get(row)
+        if idx is None:
+            from tidb_tpu.ops.jax_env import jax
+            idx = b.row_dev[row] = jax.device_put(
+                np.int32(row), next(iter(b.leaves[0].devices())))
+        node = _node(Stacked)
+        return b.tree(lambda a, shape: node(a, idx, 0, shape))
+
+    def whole(self):
+        """Every slab, for a program that reads the table WHOLE (a build
+        side, the mega-slab tree program): the list itself, or of a
+        stacked column a `WholeColumn` — the program lists the slabs
+        inside its trace (`in_place`), so no statement slices one."""
+        slabs = self.base.lists()
+        if slabs is not None:       # (every warm tree statement: no loop)
+            return slabs + [self.delta] if self.delta is not None \
+                else list(slabs)
+        return _node(WholeColumn)(self.stacked(), self.delta, self.base.pos)
+
+
+class WholeColumn:
+    """A stacked column handed WHOLE to a program: its stacks (`base`, the
+    slab's pytree of `Stacked` leaves), the raw delta slab's own arrays or
+    None, and slab → row of the stacks (`rows`, static; None = a hole).
+    `slabs()`, inside the trace, is the per-slab list the program read
+    before the column was stacked: a STATIC index of the stack, which the
+    compiler reads where it lies. A pytree node."""
+
+    __slots__ = ("base", "delta", "rows")
+
+    def __init__(self, base, delta, rows: tuple):
+        self.base, self.delta, self.rows = base, delta, rows
+
+    def children(self):
+        return (self.base, self.delta), (self.rows,)
+
+    def slabs(self) -> list:
+        from tidb_tpu.ops.jax_env import jax
+        is_stack = lambda x: isinstance(x, Stacked)     # noqa: E731
+        out = [None if row is None else jax.tree.map(
+            lambda x, row=row: x.slab(row) if is_stack(x) else x,
+            self.base, is_leaf=is_stack) for row in self.rows]
+        return out if self.delta is None else out + [self.delta]
+
+
+class SlabPicks:
+    """Which stack rows the turns of ONE statement program index: the
+    surviving base slabs `ids`, as one int32 vector a distinct position
+    map among the columns it reads (one, unless first touches pruned
+    columns differently: a column with holes stacks its resident slabs
+    alone). `of(col)` stacks `col` if it is not yet and hands out its
+    `stacked()` form, bound to its vector."""
+
+    def __init__(self, ent: "CachedTable", ids, table=""):
+        self.ent, self.ids, self.table = ent, tuple(ids), table
+        self._rows: list = []
+
+    def _which(self, rows: tuple) -> int:
+        if rows not in self._rows:
+            self._rows.append(rows)
+        return self._rows.index(rows)
+
+    def of(self, col: SlabColumn):
+        if col.n_base == 1 and not col.is_stacked:
+            return col.stacked()    # (a one-slab table: its own arrays)
+        ent = self.ent
+        if ent.owners is None and not getattr(ent, "lost", None):
+            # (slabs on several devices are no one array, and a lost
+            # slab's refill writes a slab: such an entry keeps its lists —
+            # and takes the per-slab plan, `fragment._launch_plan`)
+            try:
+                col.stack(self.table)
+            except BaseException:
+                # a fill that dies (the device out of memory, an
+                # interrupt) takes the column's arrays with it: nothing
+                # serves from this table's entries again, and the next
+                # statement's open builds them anew
+                if isinstance(self.table, int):
+                    invalidate(self.table)
+                raise
+        if not col.is_stacked:
+            return col.stacked()
+        return col.stacked(self._which(col.rows_of(self.ids)))
+
+    def live(self):
+        """The base slabs' liveness as a slab's body takes it: the
+        generation's masks, else the live prefixes' lengths — the entry's
+        ONE device vector read like a stacked leaf (a one-slab table's:
+        its scalar)."""
+        ent = self.ent
+        if ent.alive is not None:
+            return self.of(ent.alive)
+        if ent.base_slabs == 1:
+            return ent.live_arg(0)
+        return _node(Stacked)(ent.live_counts(), None,
+                              self._which(self.ids), ())
+
+    def vectors(self) -> tuple:
+        return tuple(self.ent.pick_vector(r) for r in self._rows)
+
+
 class CachedTable:
-    """Per-table device payload: per-column slab lists + dictionaries.
+    """Per-table device payload: a `SlabColumn` a resident column (`dev`),
+    one for the liveness masks of a delta generation (`alive`), and the
+    dictionaries.
+
+    A column's storage is its `SlabColumn`: per-slab tuples as the first
+    touch uploaded them, until the first statement program over the table
+    is built — from then on ONE device array a leaf with a leading axis of
+    base slabs, which the program's loop indexes in place and which every
+    generation of the base build shares by identity (the raw delta slab
+    keeps its own arrays). Everything that reads slab `s` asks the column
+    (`ent.dev[col][s]`: a slice of a stacked column, a device copy).
 
     With compression on, a column's slabs may be PACKED tuples
     (words, mask_words[, dictvals]) per chunk/compress.py — `layouts`
     records the per-column descriptor (None = raw), and the dictvals
     device array of a dict-layout column is the SAME object in every
-    slab tuple, so byte accounting and deletion dedupe it by identity.
+    slab tuple (and stays one array beside the stack), so byte accounting
+    and deletion dedupe it by identity.
     hbm_bytes() therefore charges PHYSICAL (compressed) bytes — the
-    budget/eviction accounting sees what HBM actually holds."""
+    budget/eviction accounting sees what HBM actually holds, a stacked
+    array once."""
 
     __slots__ = ("td", "max_slab", "total", "slab_cap", "n_slabs",
                  "parts", "dicts", "dev", "bounds", "n_cols", "layouts",
@@ -107,7 +716,8 @@ class CachedTable:
                  "cov", "max_rid", "seen", "rowmap", "lineage", "base_td",
                  "alive",
                  "delta_cap", "delta_rows", "dead_rows", "steps",
-                 "device", "owners", "lost", "live_dev", "kept", "kept_bytes")
+                 "device", "owners", "lost", "live_dev", "pick_dev", "kept",
+                 "kept_bytes")
 
     def __init__(self, td, max_slab: int, total: int, slab_cap: int,
                  n_slabs: int, parts, n_cols: int, compressed: bool = False):
@@ -147,7 +757,7 @@ class CachedTable:
         self.rowmap: Optional[Dict[int, tuple]] = None
         self.base_td = td
         self.lineage = id(td)
-        self.alive: Optional[List] = None
+        self.alive: Optional[SlabColumn] = None
         self.delta_cap = 0
         self.delta_rows = 0
         self.dead_rows = 0
@@ -173,8 +783,13 @@ class CachedTable:
         # One vector of `n_slabs` int32 a DISTINCT set of pruned slabs:
         # zone maps prune runs of slabs, so at most n_slabs² / 2 of them
         self.live_dev: dict = {}
+        # stack rows → the int32 device vector by which a statement
+        # program's turns index the stacked columns (`pick_vector`): a few
+        # bytes a distinct set of surviving slabs, shared by every
+        # generation of this base build (no resident row ever moves)
+        self.pick_dev: dict = {}
         self.dicts: Dict[int, Optional[np.ndarray]] = {}
-        self.dev: Dict[int, List[Tuple]] = {}  # col → [(vals, valid)] slabs
+        self.dev: Dict[int, SlabColumn] = {}  # col → its (vals, valid) slabs
         # col → ColLayout for packed columns; None/absent = raw layout
         self.layouts: Dict[int, Optional[object]] = {}
         # col → (lo, hi) over valid values; None for floats/empty — feeds
@@ -199,9 +814,10 @@ class CachedTable:
 
     def slab_live(self, s: int):
         """What a slab program takes as the slab's liveness: the device
-        mask of a delta generation, else the live prefix's length."""
+        mask of a delta generation (as a program takes a slab of it,
+        `SlabColumn.at`), else the live prefix's length."""
         if self.alive is not None:
-            return self.alive[s]
+            return self.alive.at(s)
         return self.slab_rows(s)
 
     def _live_upload(self, key, make):
@@ -214,12 +830,22 @@ class CachedTable:
                 make(), device_handle(self.device))
         return got
 
+    def pick_vector(self, rows: tuple):
+        """`rows` (of the columns' stacks) as an int32 device vector: an
+        upload a base build and distinct set, none a statement or commit."""
+        got = self.pick_dev.get(rows)
+        if got is None:
+            from tidb_tpu.ops.jax_env import jax
+            got = self.pick_dev[rows] = jax.device_put(
+                np.asarray(rows, dtype=np.int32), device_handle(self.device))
+        return got
+
     def live_arg(self, s: int):
         """`slab_live` as the slab programs of an aggregate take it: the
         device mask, else the live prefix's length as a device int32
         scalar — every slab's in one upload a generation, none a launch."""
         if self.alive is not None:
-            return self.alive[s]
+            return self.alive.at(s)
         return self._live_upload("slabs", lambda: [
             np.int32(self.slab_rows(i)) for i in range(self.n_slabs)])[s]
 
@@ -249,17 +875,11 @@ class CachedTable:
     def _arrays(self):
         """(slab index, device array) of everything this generation holds:
         column slabs (a shared dictionary once) and liveness masks."""
-        seen = set()
-        for slabs in self.dev.values():
-            for s, t in enumerate(slabs):
-                if t is None:
-                    continue            # pruned-away cold slab (hole)
-                for a in t:
-                    if id(a) not in seen:
-                        seen.add(id(a))
-                        yield s, a
-        for s, a in enumerate(self.alive or ()):
-            yield s, a
+        cols = list(self.dev.values())
+        if self.alive is not None:
+            cols.append(self.alive)
+        for col in cols:
+            yield from col.arrays()
 
     def resident(self, col: int, skip=frozenset()) -> bool:
         """Column `col` is usable for a statement skipping `skip`: its
@@ -287,11 +907,12 @@ class CachedTable:
             if cols is not None and i not in cols:
                 continue
             lay = self.layouts.get(i)
-            for s, t in enumerate(slabs):
-                if t is None:
+            holes = slabs.holes()
+            for s in range(len(slabs)):
+                if s in holes:
                     continue
                 if lay is None or self.slab_shape(s)[1]:
-                    total += sum(a.nbytes for a in t)
+                    total += slabs.slab_nbytes(s)
                 else:
                     total += compress.raw_slab_bytes(lay, self.slab_cap)
         return total
@@ -337,13 +958,10 @@ def _entry_delete(ent) -> None:
 # `version(g)` → the store version of the snapshot it was made for)
 
 def _table_arrays(g):
-    """A generation's device arrays (one that several slabs share comes
-    more than once: `_keep_behind` tells them apart by identity)."""
-    for slabs in g.dev.values():
-        for t in slabs:
-            if t is not None:       # (a pruned-away cold slab: a hole)
-                yield from t
-    yield from g.alive or ()
+    """A generation's device arrays, each once (a stacked column's: the
+    stack; `_keep_behind` tells what two generations share by identity)."""
+    for _s, a in g._arrays():
+        yield a
 
 
 def _keep_behind(newest, older, arrays, live=None) -> None:
@@ -1204,8 +1822,10 @@ def _slab_logical_est(ent: CachedTable, i: int, preps=None) -> int:
     if preps and i in preps:
         # raw layout: physical == logical
         return _est_slab_phys(preps[i], ent.slab_cap)
-    t = next((t for t in ent.dev.get(i, ()) if t is not None), None)
-    return _tuple_nbytes(t) if t is not None else 0
+    col = ent.dev.get(i)
+    if col is None:
+        return 0
+    return max((col.slab_nbytes(s) for s in range(col.n_base)), default=0)
 
 
 def _slab_host(prep: dict, start: int, stop: int, slab_cap: int):
@@ -1418,7 +2038,7 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
                 # arrays; a raced identical refill loses harmlessly
                 # (refcounting frees the loser's uploads)
                 cur = ent.dev.get(i)
-                if cur is None or len(cur) != len(slabs):
+                if cur is None or cur.n_base != len(slabs):
                     continue
                 for fs in fill[i]:
                     if cur[fs] is None:
@@ -1435,9 +2055,8 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
             # deterministic); the loser's arrays drop on the floor and
             # refcounting frees them — never a half-overwritten column
             if i not in ent.dev:
-                if tail and i in tail:
-                    slabs.append(tail[i])
-                ent.dev[i] = slabs
+                ent.dev[i] = SlabColumn(
+                    slabs, delta=tail[i] if tail and i in tail else None)
                 if skip:
                     ent.holes[i] = frozenset(skip)
                 else:
@@ -1514,12 +2133,9 @@ def storage_stats(store_id: Optional[int] = None) -> List[dict]:
             # (with what the generations kept behind this one own of the
             # column: their delta slab's arrays)
             for g in (ent,) + tuple(getattr(ent, "kept", ())):
-                for t in g.dev.get(i, ()):
-                    if t is None:
-                        continue        # pruned-away cold slab (hole)
-                    for a in t:
-                        if id(a) in seen:
-                            continue
+                col = g.dev.get(i)
+                for _s, a in col.arrays() if col is not None else ():
+                    if id(a) not in seen:
                         seen.add(id(a))
                         phys += a.nbytes
             zm = ent.zmaps.get(i)
@@ -1899,12 +2515,9 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         for i in used_cols:
             slabs = ent.dev[i]
             logi += ent.n_slabs * _slab_logical_est(ent, i)
-            for s in range(ent.n_slabs):
-                if s in skip:
-                    continue
-                t = slabs[s] if s < len(slabs) else None
-                if t is not None:
-                    phys += _tuple_nbytes(t)
+            for s in range(min(ent.n_slabs, len(slabs))):
+                if s not in skip:
+                    phys += slabs.slab_nbytes(s)     # (0 for a hole)
         ph.add_scan(phys, logical=logi)
         return ent, None
     failpoint.inject("device-transfer")
@@ -2082,6 +2695,14 @@ def aligned_budget_check(ctx, keep_keys=frozenset(),
 class AlignedJoin:
     """Cached FK-aligned join structure for ONE (fact path, build) pair.
 
+    What it holds a fact slab — the match mask, the matched build row, the
+    gathered build columns — is a `SlabColumn` each, in the fact's row
+    space: per-slab arrays as built, ONE array with a leading axis of base
+    slabs once a statement program reads them (`SlabPicks.of`), the delta
+    slab's arrays behind. `midx` is read by the builds and by the unmatch
+    of dead build keys alone, which stacks it beside the match mask the
+    first time it runs (`_advance_aligned`).
+
     A structure follows its tables through delta generations
     (`_advance_aligned`): no fact row ever moves, so what it holds for the
     base slabs stays; a build row that dies unmatches the fact rows that
@@ -2102,9 +2723,9 @@ class AlignedJoin:
         self.n_slabs = n_slabs
         self.build_nb = build_nb    # build-side padded row count
         self.unique = True
-        self.matched: List = []     # per fact slab: bool (slab_cap,)
-        self.midx: List = []        # per fact slab: int32 (slab_cap,)
-        self.cols: Dict[int, List[Tuple]] = {}   # build col → [(v, m)] slabs
+        self.matched = SlabColumn()     # per fact slab: bool (slab_cap,)
+        self.midx = SlabColumn()        # per fact slab: int32 (slab_cap,)
+        self.cols: Dict[int, SlabColumn] = {}   # build col → (v, m) slabs
         self.lut = None             # key - lo → build row, -1 where none
         self.lo, self.domain = 0, 0
         # the base builds whose row POSITIONS this structure holds: the
@@ -2126,12 +2747,9 @@ class AlignedJoin:
         self.kept_bytes = 0
 
     def _owned(self):
-        for arrs in (self.matched, self.midx):
-            yield from arrs
-        for slabs in self.cols.values():
-            for v, m in slabs:
-                yield v
-                yield m
+        for col in (self.matched, self.midx, *self.cols.values()):
+            for _s, a in col.arrays():
+                yield a
         if self.lut is not None:
             yield self.lut
         for v, m in self.bcat.values():
@@ -2145,7 +2763,7 @@ class AlignedJoin:
         """Free device buffers on eviction (see CachedTable.delete)."""
         for a in list(self._owned()):
             _delete_array(a)
-        self.matched, self.midx, self.lut = [], [], None
+        self.matched, self.midx, self.lut = SlabColumn(), SlabColumn(), None
         self.cols.clear()
         self.bcat.clear()
 
@@ -2325,14 +2943,19 @@ def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
     _probe = named_jit(_probe, program_name("gather_probe", sig))
     codes, valids = fact_slabs()
     dangling = jnp.int32(0)
+    midxs, matcheds = [], []
     for s, (pv, pm) in enumerate(zip(codes, valids)):
         # (a chained hop's fact rows are another structure's: all count)
         alive_f = fact[0].slab_mask(s) if fact is not None else \
             jnp.ones(int(pv.shape[-1]), dtype=bool)
         midx, matched, n_dang = _probe(lut, pv, pm, alive_f)
-        ent.midx.append(midx)
-        ent.matched.append(matched)
+        midxs.append(midx)
+        matcheds.append(matched)
         dangling = dangling + n_dang
+    # (a delta generation's last slab is the raw delta slab's)
+    n_base = fact[0].base_slabs if fact is not None else len(midxs)
+    ent.midx = SlabColumn(midxs[:n_base], *midxs[n_base:])
+    ent.matched = SlabColumn(matcheds[:n_base], *matcheds[n_base:])
     ent.dangling = dangling
     got = _install(ent)
     return got if got.unique else None
@@ -2363,6 +2986,8 @@ def aligned_col(ent: AlignedJoin, build_ent: CachedTable, col: int):
     slabs = [_gather_program(col, bv, int(midx.shape[0]))(
         bv, bm, midx, matched)
         for midx, matched in zip(ent.midx, ent.matched)]
+    n_base = ent.matched.n_base
+    slabs = SlabColumn(slabs[:n_base], *slabs[n_base:])
     with _LOCK:
         # first-commit-wins against a concurrent identical gather
         return ent.cols.setdefault(col, slabs)
@@ -2401,7 +3026,7 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
     arriving while live fact rows match none: one of them may be its —
     an UPDATE of a build row is a key that dies and arrives)."""
     from tidb_tpu.executor import delta, device_emit
-    from tidb_tpu.ops.jax_env import jax, jnp
+    from tidb_tpu.ops.jax_env import jax, jnp, lax
     fact_tid = next((t for t, td in tds.items() if td is fact_ent.td), None)
     build_tid = next((t for t, td in tds.items()
                       if td is build_ent.td and t != fact_tid), None)
@@ -2421,8 +3046,7 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
                       old.build_nb)
     new.lo, new.domain, new.space = lo, domain, old.space
     new.lut, new.dangling = old.lut, old.dangling
-    new.matched, new.midx = list(old.matched), list(old.midx)
-    new.cols = {c: list(sl) for c, sl in old.cols.items()}
+    new.cols = {c: sl.fork() for c, sl in old.cols.items()}
     new.bcat = dict(old.bcat)
     base_n = build_ent.base_slabs * build_ent.slab_cap
     new.build_nb = base_n + build_ent.delta_cap
@@ -2492,9 +3116,33 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
         if not by_lut:
             ranges[:starts.size, 0], ranges[:starts.size, 1] = starts, stops
         lay = fact_ent.layouts.get(fact_col)
+        fcol, n_base = fact_ent.dev[fact_col], fact_ent.base_slabs
+        # the base slabs of an entry that can hold them as ONE array are
+        # read so, as the statement programs read them — stacked here if
+        # no statement program came first: one program whose loop hands
+        # out the new stacked mask, and no slab is sliced out. (Slabs on
+        # several devices, or a lost one, keep their lists and the
+        # program of one slab, which the raw delta slab runs anyway.)
+        whole = n_base > 1 and fact_ent.owners is None \
+            and not fact_ent.lost
+        if whole:
+            if fcol.holes():
+                return "holes"
+            picks = SlabPicks(fact_ent, range(n_base), fact_tid)
+            masks = fact_ent.alive
+            base = (picks.of(fcol), picks.of(old.matched),
+                    picks.of(old.midx),
+                    picks.of(delta.base_masks(fact_ent, fact_tid)
+                             if masks is None else masks))
+    # (what no advance rewrites is shared by identity; the match masks of
+    # the base are written under a structure of this generation's own)
+    new.matched, new.midx = old.matched.fork(own=True), old.midx.fork()
+    if dk.size:
         for s in range(min(len(new.matched), fact_ent.n_slabs)):
             cap, raw = fact_ent.slab_shape(s)
             lay_s = None if raw else lay
+            if whole and not raw and s:
+                continue            # (slab 0's turn ran the whole base)
 
             def _unmatch(slab, matched, midx, alive, rg, lut,
                          lay_s=lay_s, cap=cap, by_lut=by_lut):
@@ -2513,14 +3161,32 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
                     return matched & ~hit, \
                         jnp.sum(hit & alive, dtype=jnp.int32)
 
-            prog = device_emit._delta_program(
-                "delta_merge", ("unmatch", cap, None if lay_s is None
-                                else lay_s.sig(),
-                                (lo, domain) if by_lut else None),
-                lambda _unmatch=_unmatch: _unmatch)
-            new.matched[s], n_dang = prog(
-                fact_ent.dev[fact_col][s], new.matched[s], new.midx[s],
-                fact_ent.slab_mask(s), ranges, new.lut)
+            key = ("unmatch", cap, None if lay_s is None else lay_s.sig(),
+                   (lo, domain) if by_lut else None)
+            if whole and not raw:
+                def _unmatch_base(base, rows, rg, lut, _unmatch=_unmatch):
+                    def turn(c, k):
+                        matched, n_dang = _unmatch(
+                            *in_place(base, rows, k), rg, lut)
+                        # (as the stack holds a slab's mask: the loop
+                        # hands out the new stack itself)
+                        return c, (_fold(matched), n_dang)
+                    _c, (matched, n_dang) = lax.scan(
+                        turn, None, jnp.arange(n_base, dtype=jnp.int32))
+                    return matched, jnp.sum(n_dang, dtype=jnp.int32)
+
+                prog = device_emit._delta_program(
+                    "delta_merge", key + (n_base,),
+                    lambda _f=_unmatch_base: _f)
+                matched, n_dang = prog(base, picks.vectors(), ranges,
+                                       new.lut)
+                new.matched.set_stack(matched)
+            else:
+                prog = device_emit._delta_program(
+                    "delta_merge", key, lambda _unmatch=_unmatch: _unmatch)
+                new.matched[s], n_dang = prog(
+                    fcol[s], new.matched[s], new.midx[s],
+                    fact_ent.slab_mask(s), ranges, new.lut)
             new.dangling = new.dangling + n_dang
     if arrived:
         # a build row may only arrive onto a key no live build row holds,
@@ -2539,8 +3205,8 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
         new.matched.append(jnp.zeros(dcap, dtype=bool))
         new.midx.append(jnp.zeros(dcap, dtype=jnp.int32))
         for c, sl in new.cols.items():
-            v0 = sl[0][0]
-            sl.append((jnp.zeros(v0.shape[:-1] + (dcap,), dtype=v0.dtype),
+            v_shape, v_dtype = sl.specs()[0]
+            sl.append((jnp.zeros(v_shape[:-1] + (dcap,), dtype=v_dtype),
                        jnp.zeros(dcap, dtype=bool)))
     # (a step at a time: each has the shapes of ONE commit's rows, so the
     # program is the one every commit before it ran)

@@ -921,14 +921,15 @@ DELTA_SCOPES = ("delta_merge", "tombstone")
 _DELTA_PROGRAMS: dict = {}
 
 
-def _delta_program(kind: str, key: tuple, build):
+def _delta_program(kind: str, key: tuple, build, **jit_kwargs):
     """The jitted program `<kind>_<sig8 of key>`, built once a process:
-    `key` holds every constant `build()`'s function closes over."""
+    `key` holds every constant `build()`'s function closes over (and
+    stands for `jit_kwargs`, e.g. a donated argument)."""
     from tidb_tpu.ops.jax_env import named_jit, program_name
     fn = _DELTA_PROGRAMS.get((kind, key))
     if fn is None:
         fn = _DELTA_PROGRAMS[(kind, key)] = named_jit(
-            build(), program_name(kind, repr(key)))
+            build(), program_name(kind, repr(key)), **jit_kwargs)
     return fn
 
 
@@ -984,16 +985,47 @@ def emit_alive_init(n_live: int, cap: int):
         jnp.int32(n_live))
 
 
-def emit_alive_update(alive, born, dead, cap: int):
+def emit_alive_stack(counts, cap: int):
+    """The liveness masks of base slabs that have only live prefixes
+    (`counts` rows each, a host int32 vector), made as ONE array the way
+    `device_cache.SlabColumn` stacks a column: slabs × a slab's rows
+    folded in two."""
+    from tidb_tpu.executor.device_cache import _folded
+    from tidb_tpu.ops.jax_env import jax, jnp
+    fold = _folded((cap,))
+
+    def build():
+        def _init(n):
+            with jax.named_scope("tombstone"):
+                pos = jnp.arange(fold[0] * fold[1],
+                                 dtype=jnp.int32).reshape(fold)
+                return pos[None] < n[:, None, None]
+        return _init
+    return _delta_program("tombstone", ("init", cap, len(counts)), build)(
+        jnp.asarray(list(counts), dtype=jnp.int32))
+
+
+def emit_alive_update(alive, born, dead, cap: int, stacked: bool = False):
     """A slab's liveness mask with rows `born` set and rows `dead`
     cleared (int32 host arrays padded with `cap`, which a scatter
-    drops) → the new mask; the old one stays as it was."""
+    drops) → the new mask; the old one stays as it was. `stacked`: `alive`
+    is the base slabs' masks as ONE array (`device_cache.SlabColumn`:
+    slabs × a slab's rows folded in two) and the rows are positions in the
+    whole base (padded with its size): one rewrite whatever slabs they
+    fall in."""
     from tidb_tpu.ops.jax_env import jax
-    key = ("update", int(born.shape[0]), int(dead.shape[0]), cap)
+    key = ("update", int(born.shape[0]), int(dead.shape[0]), cap) + \
+        (tuple(alive.shape) if stacked else ())
 
     def build():
         def _update(a, b, d):
             with jax.named_scope("tombstone"):
+                if stacked:
+                    # (a slab's rows lie folded in two, `cap` of them
+                    # from the fold's start: `device_cache._fold`)
+                    c = a.shape[2]
+                    b, d = ((p // cap, p % cap // c, p % cap % c)
+                            for p in (b, d))
                 return a.at[b].set(True, mode="drop") \
                         .at[d].set(False, mode="drop")
         return _update

@@ -799,8 +799,10 @@ class _FragmentProgram:
         # root reduction in one trace.  The root dispatch lives in
         # device_emit.emit_root so the linear-chain, join-tree and fused
         # per-slab programs share one emit layer.
-        from tidb_tpu.executor import device_emit
+        from tidb_tpu.executor import device_cache, device_emit
         _count_trace()
+        # (a slab of a stacked column is indexed here, inside the trace)
+        cols, n_rows = device_cache.in_place((cols, n_rows))
         ctx, live = self._eval_chain(cols, n_rows, prep_vals)
         return device_emit.emit_root(
             ctx, live, self.root, aggs=getattr(self, "aggs", None),
@@ -1215,12 +1217,16 @@ class _StatementProgram:
     body a slab compiles, and loads from the persistent cache, a slab's
     worth of seconds a copy (Q1 over six 8M-row slabs: 113 s cold and 20 s
     from the cache on the chip's host, every run's set-up; PERF.md §6,
-    PR 39). The slabs enter as they lie in the device cache, a pytree
-    each, and nothing stacks the table: each turn of the loop picks its
-    slab with a `lax.switch` over them, which costs a copy of ONE slab's
-    arguments a turn (its compressed columns, and a join tree's aligned
-    columns: ≈ 1.5 ms an operation at SF=8, PERF.md §6). The raw delta
-    slab has a shape of its own: its body (`dbody`) follows the loop. Of
+    PR 39). The base slabs enter as they lie in the device cache: ONE
+    array a leaf with a leading axis of slabs (`base`, a slab's pytree
+    whose stacked leaves are `device_cache.Stacked`), and each turn of the
+    loop INDEXES its slab (`in_place`: a dynamic slice inside the fusion
+    that reads it — no slab is copied; PERF.md §6, PR 46). Which rows of
+    the stacks the turns read (`picks`: the slabs zone maps left, as small
+    int32 device vectors) is an argument, so pruning names no program. A
+    one-slab table's arrays come as they are: nothing to index. The raw
+    delta slab has a shape of its own and its own arrays (`delta`): its
+    body (`dbody`) follows the loop. Of
     the partials only what the control fetch reads leaves the program,
     packed (`_pack`; `like`, the control tree's shapes and dtypes read off
     the arguments `args` when the program is built — and compiled — says
@@ -1238,6 +1244,9 @@ class _StatementProgram:
         self.sig = sig
         self.name = program_name(kind, sig)
         self.run = named_jit(self._run, self.name)
+        # what the trace said of itself (`slab_pick`), for the launch span
+        # of the first call: the build below traces under no launch
+        self.said: Optional[dict] = None
         self.like = jax.eval_shape(self._fetch, *args)[1]
         # compiled HERE — under the signature's build lock, by one
         # statement, outside the batch slot — and not by the first launch
@@ -1245,30 +1254,35 @@ class _StatementProgram:
         # for both), so a statement-sized compile holds nobody's slot
         self.run.lower(*args).compile()
 
-    def _run(self, shared, slabs):
+    def _run(self, shared, base, delta, picks):
         _count_trace()
-        out, fetch = self._fetch(shared, slabs)
+        out, fetch = self._fetch(shared, base, delta, picks)
         return out, _pack(fetch)
 
-    def _fetch(self, shared, slabs):
+    def _fetch(self, shared, base, delta, picks):
         """→ (the result, or None where it rides the fetch; the control
         tree): what `_run` packs."""
+        from tidb_tpu.executor.device_cache import in_place
         from tidb_tpu.executor.device_emit import partials_of
         from tidb_tpu.ops.jax_env import jax, jnp, lax
-        base = slabs[:-1] if self.dbody is not None else slabs
         partials = []       # each leaf with a leading axis of slabs
-        if len(base) == 1:
+        if base is not None:
+            # (no vector: a one-slab table, whose arrays are the slab)
+            n_run = picks[0].shape[0] if picks else 1
+            if picks:
+                self.said = {"slab_pick": "index"}
+
+            def turn(k):
+                return self.body(shared, in_place(base, picks, k))
+            if n_run == 1:
+                partials.append(jax.tree.map(lambda a: a[None], turn(0)))
+            else:
+                partials.append(lax.scan(
+                    lambda _c, k: (None, turn(k)), None,
+                    jnp.arange(n_run, dtype=jnp.int32))[1])
+        if delta is not None:
             partials.append(jax.tree.map(
-                lambda a: a[None], self.body(shared, base[0])))
-        elif base:
-            picks = [lambda *ops, i=i: ops[i] for i in range(len(base))]
-            partials.append(lax.scan(
-                lambda _c, k: (None, self.body(
-                    shared, lax.switch(k, picks, *base))),
-                None, jnp.arange(len(base), dtype=jnp.int32))[1])
-        if self.dbody is not None:
-            partials.append(jax.tree.map(
-                lambda a: a[None], self.dbody(shared, slabs[-1])))
+                lambda a: a[None], self.dbody(shared, delta)))
         ctl = {k: jnp.concatenate(v) for k, v in
                _control_of(partials, self.control).items()}
         if self.tail is None:
@@ -1598,10 +1612,11 @@ def _plan_aligned_joins(ctx, root, scans, ents):
         if a_ent.dicts.get(idx) is not None:
             return None
         slabs = device_cache.aligned_col(entry, a_ent, idx)
-        if any(v.ndim != 1 for v, _ in slabs):
+        v_shape = slabs.specs()[0][0]   # (of the values; no slab is read)
+        if len(v_shape) != 1:
             return None
         return (lambda: ([v for v, _ in slabs], [m for _, m in slabs]),
-                (int(slabs[0][0].shape[-1]), len(slabs)),
+                (int(v_shape[-1]), len(slabs)),
                 ("al", entry.key, idx), dict(entry.tds), None,
                 entry.space)
 
@@ -1808,6 +1823,28 @@ def _ent_geometry(ent) -> tuple:
             ent.alive is not None)
 
 
+def _whole_cols(cols: dict) -> dict:
+    """A scan's columns as a program that reads the table WHOLE takes
+    them: every slab of each (`SlabColumn.whole`: a list, or a stacked
+    column's stacks themselves — `TreeProgram._run` lists their slabs
+    inside the trace; no statement slices one)."""
+    return {i: col.whole() for i, col in cols.items()}
+
+
+def _whole_masks(alive):
+    """A generation's liveness masks over every slab, likewise."""
+    w = alive.whole()
+    return tuple(w) if isinstance(w, list) else w
+
+
+def _whole_aligned(matched, jcols):
+    """An aligned join's inputs over every fact slab, likewise."""
+    def whole(col):
+        w = col.whole() if len(col) else ()     # (`()`: no aligned join)
+        return tuple(w) if isinstance(w, list) else w
+    return whole(matched), {c: whole(sl) for c, sl in jcols.items()}
+
+
 class _SlabSource:
     """What `TpuFragmentExec._run_agg_slabs` asks of the slabs it
     aggregates; how a slab's arguments are laid out stays in here. A chain
@@ -1861,9 +1898,17 @@ class _SlabSource:
         raise NotImplementedError
 
     def statement_args(self, prog, prep_vals):
-        """→ (shared, the surviving slabs' own): what a statement program
-        takes. Every slab is resident."""
+        """→ (shared, base, delta, picks): what a statement program takes.
+        `base`: the surviving base slabs' own arguments as ONE slab's
+        pytree over the columns' stacked storage (`SlabPicks.of`: a column
+        is stacked here, the first time a statement program reads it),
+        None where none survived; `delta`: the raw delta slab's own, or
+        None; `picks`: the stack rows the loop's turns read. Every slab is
+        resident on one device."""
         raise NotImplementedError
+
+    def _base_ids(self) -> list:
+        return [s for s in self.run_ids if s != self.delta_id]
 
     def learned(self) -> dict:
         """What a specialization entry keeps besides capacities."""
@@ -1962,10 +2007,16 @@ class _ChainSlabs(_SlabSource):
         return functools.partial(self._slab_body, prog)
 
     def statement_args(self, prog, prep_vals):
-        ent = self.ent
-        return prep_vals, tuple(
-            (self.ex._slab(ent, rid, prog.used_cols)[0], ent.live_arg(rid))
-            for rid in self.run_ids)
+        from tidb_tpu.executor import device_cache
+        ent, used = self.ent, prog.used_cols
+        ids, base = self._base_ids(), None
+        picks = device_cache.SlabPicks(ent, ids, self.chain[-1].table.id)
+        if ids:
+            base = {i: picks.of(ent.dev[i]) for i in used}, picks.live()
+        delta = (self.ex._slab(ent, self.delta_id, used)[0],
+                 ent.live_arg(self.delta_id)) \
+            if self.delta_id in self.run_ids else None
+        return prep_vals, base, delta, picks.vectors()
 
 
 class _TreeSlabs(_SlabSource):
@@ -1994,8 +2045,10 @@ class _TreeSlabs(_SlabSource):
         # a plain table's live-row counts as the device vector its entry
         # keeps a version (slabs that zone maps zeroed as 0): no launch
         # uploads them again
+        # (`scan_inputs`: a `SlabColumn` a column; `scan_rows`: the
+        # anchor's place is None — its liveness comes a slab, `_slab_arg`)
         self.scan_inputs, self.scan_rows = scan_inputs, tuple(
-            rows if e.alive is not None else e.live_counts(
+            rows if rows is None or e.alive is not None else e.live_counts(
                 frozenset(np.flatnonzero(counts == 0).tolist()))
             for (e, _u), rows, counts in zip(ents, scan_rows, scan_counts))
         self.flow_list, self.aligned_inputs = flow_list, aligned_inputs
@@ -2023,6 +2076,7 @@ class _TreeSlabs(_SlabSource):
         # the anchor's raw delta slab runs the same tree as a program of
         # its own anchor shape (its capacity, no layouts)
         self.a_ent = a_ent
+        self.anchor_tid = scans[anchor_i].table.id
         self.delta_id = a_ent.base_slabs if a_ent.delta_cap else -1
         self.dprog = None
         if self.delta_id >= 0:
@@ -2082,28 +2136,34 @@ class _TreeSlabs(_SlabSource):
         anchor's place in them left open, the joins sliced by anchor slab
         (`spaced`) likewise."""
         a = self.anchor_i
-        si, sr = list(self.scan_inputs), list(self.scan_rows)
-        si[a] = sr[a] = None
-        ai = tuple(((), {}) if matched and id(jn) in spaced
-                   else (matched, jcols)
+        si = [None if i == a else _whole_cols(cols)
+              for i, cols in enumerate(self.scan_inputs)]
+        sr = list(self.scan_rows)
+        ai = tuple(((), {}) if len(matched) and id(jn) in spaced
+                   else _whole_aligned(matched, jcols)
                    for jn, (matched, jcols) in zip(self.walk_joins,
                                                    self.aligned_inputs))
         return tuple(si), tuple(sr), prep_vals, ai, self.nested_rows
 
-    def _slab_arg(self, s: int, spaced: set):
-        """Anchor slab `s`'s own: its columns, its liveness (mask, or the
-        live prefix's length as a device scalar kept on the entry), its
-        slice of the joins aligned in its row space (None elsewhere)."""
-        cols = {i: [slabs[s]] for i, slabs in
+    def _slab_arg(self, of, live, spaced: set):
+        """One anchor slab's own — or, for a statement program, the base
+        slabs' as one slab's pytree over stacked storage: `of(column)` →
+        its columns' arrays, `live` its liveness (mask, or the live
+        prefix's length as a device scalar kept on the entry), its part of
+        the joins aligned in its row space (None elsewhere)."""
+        cols = {i: [of(col)] for i, col in
                 self.scan_inputs[self.anchor_i].items()}
-        live = self.a_ent.live_arg(s)
         if self.a_ent.alive is not None:
             live = (live,)
         return cols, live, tuple(
-            ((matched[s],), {c: (sl[s],) for c, sl in jcols.items()})
-            if matched and id(jn) in spaced else None
+            ((of(matched),), {c: (of(sl),) for c, sl in jcols.items()})
+            if len(matched) and id(jn) in spaced else None
             for jn, (matched, jcols) in zip(self.walk_joins,
                                             self.aligned_inputs))
+
+    def _one_slab(self, s: int, spaced: set):
+        return self._slab_arg(lambda col: col.at(s), self.a_ent.live_arg(s),
+                              spaced)
 
     @staticmethod
     def _assemble(a: int, shared, slab):
@@ -2124,7 +2184,7 @@ class _TreeSlabs(_SlabSource):
             s = self.run_ids[pos]
             p = self.dprog if s == self.delta_id else prog
             si, sr, pv, ai, nested = self._assemble(
-                self.anchor_i, shared, self._slab_arg(s, spaced))
+                self.anchor_i, shared, self._one_slab(s, spaced))
             # slot per slab DISPATCH (async queue) — one labeled compute
             # span per fused slab program in the trace
             with self.ctx.device_slot():
@@ -2142,9 +2202,15 @@ class _TreeSlabs(_SlabSource):
         return functools.partial(self._slab_body, prog, self.anchor_i)
 
     def statement_args(self, prog, prep_vals):
+        from tidb_tpu.executor import device_cache
         spaced = self._joins_in_anchor_space()
-        return self._shared(prep_vals, spaced), tuple(
-            self._slab_arg(s, spaced) for s in self.run_ids)
+        ids, base = self._base_ids(), None
+        picks = device_cache.SlabPicks(self.a_ent, ids, self.anchor_tid)
+        if ids:
+            base = self._slab_arg(picks.of, picks.live(), spaced)
+        delta = self._one_slab(self.delta_id, spaced) \
+            if self.delta_id in self.run_ids else None
+        return self._shared(prep_vals, spaced), base, delta, picks.vectors()
 
     @staticmethod
     def control(partials) -> dict:
@@ -2748,8 +2814,9 @@ class TpuFragmentExec:
         scan_bounds = {id(s): e.bounds for s, (e, _) in zip(scans, ents)}
         scan_bounds.update(nested_bounds)
         flows, root_dicts = TF.dictionary_flows(root, scan_dicts)
-        scan_inputs = tuple({i: list(e.dev[i]) for i in u}
-                            for e, u in ents)
+        # (the columns themselves: what reads a table whole lists their
+        # slabs where it launches, `_whole_cols`)
+        scan_inputs = tuple({i: e.dev[i] for i in u} for e, u in ents)
         scan_counts = tuple(
             np.array([e.slab_rows(s) for s in range(e.n_slabs)],
                      dtype=np.int32) for e, _ in ents)
@@ -2762,21 +2829,23 @@ class TpuFragmentExec:
         # entirely.
         from tidb_tpu.executor import zonemap
         n_zeroed = 0
-        pruned = []
         for sc, (e, _u), rows in zip(scans, ents, scan_counts):
-            pruned.append(zonemap.prune_slabs(e, sc))
-            for s in pruned[-1]:
+            for s in zonemap.prune_slabs(e, sc):
                 rows[s] = 0
                 n_zeroed += 1
         if n_zeroed:
             zonemap.note_skipped(self.ctx.phases, n_zeroed)
-        # a delta generation's liveness is a mask a slab (a pruned slab's
-        # an empty one); a plain table's the counts themselves
-        scan_rows = tuple(
-            counts if e.alive is None else tuple(
-                device_emit.emit_alive_init(0, e.slab_shape(s)[0])
-                if s in skip else e.alive[s] for s in range(e.n_slabs))
-            for (e, _u), counts, skip in zip(ents, scan_counts, pruned))
+        # a delta generation's liveness is a mask a slab; a plain table's
+        # the counts themselves
+        def scan_rows_but(anchor=None):
+            # (the anchor of the per-slab pipeline reads its own a slab)
+            return tuple(
+                None if i == anchor else counts if e.alive is None
+                # (the masks are read where they lie, pruned slabs' too:
+                # no row of one passes the scan's own predicate)
+                else _whole_masks(e.alive)
+                for i, ((e, _u), counts) in enumerate(
+                    zip(ents, scan_counts)))
         max_cap = max(e.slab_cap * e.n_slabs for e, _ in ents)
 
         flow_list = [flows.get(id(n), []) for n in TF._walk_nodes(root)]
@@ -2793,9 +2862,7 @@ class TpuFragmentExec:
                 continue
             join_cfgs[ji] = TF.JoinCfg(
                 "aligned", aligned_cols=tuple(sorted(info["cols"])))
-            aligned_inputs.append(
-                (tuple(info["entry"].matched),
-                 {c: tuple(s) for c, s in info["cols"].items()}))
+            aligned_inputs.append((info["entry"].matched, info["cols"]))
         aligned_inputs = tuple(aligned_inputs)
         akb = TF.tree_agg_key_bounds(root, scan_bounds, DOMAIN_CAP) \
             if is_agg else None
@@ -2824,7 +2891,8 @@ class TpuFragmentExec:
                 vars_.get("tidb_tpu_fused_pipeline", "on")):
             res = self._run_agg_slabs(
                 _TreeSlabs(self.ctx, root, caps, scans, ents,
-                           scan_inputs, scan_rows, flow_list, flows,
+                           scan_inputs, scan_rows_but(anchor_i), flow_list,
+                           flows,
                            aligned_inputs, join_cfgs, walk_joins, akb,
                            max_cap, out_cap_max, anchor_i, scan_layouts,
                            nested_rows, scan_counts),
@@ -2837,6 +2905,11 @@ class TpuFragmentExec:
             # (learned flips/resizes persist in join_cfgs)
             if any(e.is_delta for e, _ in ents):
                 return self._run_tree_plain()
+        # the mega-slab program reads every table whole
+        scan_inputs = tuple(_whole_cols(cols) for cols in scan_inputs)
+        scan_rows = scan_rows_but()
+        aligned_inputs = tuple(_whole_aligned(m, jc)
+                               for m, jc in aligned_inputs)
         while True:
             prog = get_tree_program(root, caps, gcap, join_cfgs, akb,
                                     scan_layouts)
@@ -3675,7 +3748,9 @@ class TpuFragmentExec:
     def _slab(ent, slab_idx: int, used: Sequence[int]):
         # restrict to the program's used columns: a superset (uploaded by a
         # different query) would change the input pytree and force a retrace
-        cols = {i: ent.dev[i][slab_idx] for i in used}
+        # (`at`: of a stacked column the stack and the slab's row — the
+        # program reads the slab in place, nothing is sliced out for it)
+        cols = {i: ent.dev[i].at(slab_idx) for i in used}
         return cols, ent.slab_live(slab_idx)
 
     def _slab_iter(self, ent, stream, used: Sequence[int], slab_ids=None):
@@ -3901,6 +3976,10 @@ class TpuFragmentExec:
                     with ph.launch(sprog.name,
                                    sig=_sig_tag("stmt", sprog.sig)):
                         out, packed = sprog.run(*args)
+                        if sprog.said:
+                            # (the first call of a program built here)
+                            timeline.tag(**sprog.said)
+                            sprog.said = None
                 fetch = sprog.like
                 ph.note_launch()
                 ph.note_fused()

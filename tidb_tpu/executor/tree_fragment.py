@@ -845,8 +845,13 @@ class TreeProgram:
     # -- trace ---------------------------------------------------------------
     def _run(self, scan_inputs, scan_rows, prep_vals, aligned_inputs=(),
              ranges=None, nested=()):
+        from tidb_tpu.executor.device_cache import in_place
         from tidb_tpu.executor.fragment import _count_trace
         _count_trace()        # once per TRACE — perf_smoke retrace meter
+        # (of a stacked column: a table read whole lists its slabs here,
+        # the anchor's one slab is indexed here — read where they lie)
+        scan_inputs, scan_rows, aligned_inputs = in_place(
+            (scan_inputs, scan_rows, aligned_inputs))
         self._prepared = {id(n): v
                           for n, v in zip(self.prep_nodes, prep_vals)
                           if v is not None}
