@@ -321,12 +321,12 @@ def run_statement(cli, meter: CompileMeter, name: str, sql: str,
     capacities; the second runs the whole statement as ONE program, which
     it compiles; from the third on a statement is warm.) → the printed
     record."""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import compile_cache
     rec: dict = {"statement": name}
     fb0 = fallbacks_total(cli)
     led = summary_row(cli, sql)
     for rep in reps:
-        m0, tr0 = meter.snapshot(), fragment.PROGRAM_TRACES
+        m0, tr0 = meter.snapshot(), compile_cache.PROGRAM_TRACES
         t0 = time.perf_counter()
         _, rows = cli.query(sql)
         wall = time.perf_counter() - t0
@@ -338,7 +338,7 @@ def run_statement(cli, meter: CompileMeter, name: str, sql: str,
                     "h2d_bytes": d["h2d_bytes"], "d2h_bytes": d["d2h_bytes"],
                     "scan_bytes": d["scan_bytes"],
                     "device_s": d["device_seconds"],
-                    "program_traces": fragment.PROGRAM_TRACES - tr0,
+                    "program_traces": compile_cache.PROGRAM_TRACES - tr0,
                     **{"xla_" + k: v
                        for k, v in delta(meter.snapshot(), m0).items()}}
         check(sorted(rows) == expect,
@@ -494,8 +494,8 @@ def placement(eng) -> dict:
     from tidb_tpu.executor import device_cache as dc
     names = {t.id: t.name for t in eng.catalog.info_schema.list_tables()}
     out = {}
-    with dc._LOCK:
-        entries = [(k, e) for k, e in dc._CACHE.items()
+    with dc.LOCK:
+        entries = [(k, e) for k, e in dc.CACHE.items()
                    if k[1] == id(eng.store)]
     for k, ent in entries:
         devs = set()
@@ -512,7 +512,7 @@ def placement(eng) -> dict:
 
 def phase_pod(eng, data, device: dict, meter: CompileMeter) -> None:
     from tidb_tpu.executor import device_cache as dc
-    from tidb_tpu.executor import fragment
+    from tidb_tpu import sysvars
     from tidb_tpu.server import Server
     from tidb_tpu.tools import tpch_shaped as T
     ref = Reference(data)
@@ -522,7 +522,7 @@ def phase_pod(eng, data, device: dict, meter: CompileMeter) -> None:
     try:
         cli = connect(server)
         lower_threshold_for_rehearsal(cli, ref.n)
-        if ref.n < n_dev * fragment.DEFAULT_MAX_SLAB_ROWS:
+        if ref.n < n_dev * sysvars.DEFAULT_MAX_SLAB_ROWS:
             # fewer slabs than chips at the defaults: nothing to spread
             slab = 1 << max((ref.n // n_dev).bit_length() - 1, 10)
             cli.execute("SET tidb_tpu_partition_min_rows = 1024")
@@ -530,8 +530,8 @@ def phase_pod(eng, data, device: dict, meter: CompileMeter) -> None:
             emit("note", what="rehearsal size: SET "
                  "tidb_tpu_partition_min_rows = 1024, "
                  f"tidb_tpu_max_slab_rows = {slab} (lineitem has {ref.n} "
-                 f"rows; defaults {dc.DEFAULT_PARTITION_MIN_ROWS}, "
-                 f"{fragment.DEFAULT_MAX_SLAB_ROWS})")
+                 f"rows; defaults {sysvars.DEFAULT_PARTITION_MIN_ROWS}, "
+                 f"{sysvars.DEFAULT_MAX_SLAB_ROWS})")
         queries = (("Q1", T.Q1), ("Q3", T.Q3))
         # (0) the comparison: the same statements pinned to one device
         cli.execute("SET tidb_tpu_device_queues = 'off'")

@@ -322,11 +322,11 @@ def test_generations_of_a_structure_share_what_no_commit_rewrites():
     later commit, no warm statement and no read of the kept generation
     stacks anything again, and `hbm_bytes()` / the kept bytes count a
     stacked array once."""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
     from tidb_tpu.util.observability import REGISTRY
     s = _pair()
     s.vars["tidb_tpu_max_slab_rows"] = 8192        # 40000 rows: 5 slabs
-    fragment._SPEC_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     for _ in range(3):
         _check(s, JQ)                              # cold, whole, warm
     (old,) = _structures(s)
@@ -389,10 +389,10 @@ def test_the_first_unmatch_stacks_what_no_statement_program_did():
     has no masks yet: made from the live prefixes, born stacked), once:
     the next dead keys find the stacks and move no counter. The answers
     stay the reference's and the structure is advanced, not rebuilt."""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
     s = _pair()
     s.vars["tidb_tpu_max_slab_rows"] = 8192        # 40000 rows: 5 slabs
-    fragment._SPEC_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     _check(s, JQ)                                  # cold: lists
     (old,) = _structures(s)
     assert not old.matched.is_stacked and old.matched.n_base == 5

@@ -34,7 +34,8 @@ import pytest
 from tidb_tpu.chunk import compress
 from tidb_tpu.chunk.compress import ColLayout
 from tidb_tpu.errors import LayoutError
-from tidb_tpu.executor import build, device_cache as dc, run_to_completion
+from tidb_tpu.executor import device_cache as dc, run_to_completion
+from tidb_tpu.executor.builder import build
 from tidb_tpu.executor.fragment import TpuFragmentExec
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine
@@ -272,7 +273,7 @@ def run_device(s, sql, *, max_slab=None, dist=None, staged=None):
 
 def _cache_entry(eng, table_name):
     tid = eng.catalog.info_schema.table(table_name).id
-    for (_dev, sid, t, _parts), ent in dc._CACHE.items():
+    for (_dev, sid, t, _parts), ent in dc.CACHE.items():
         if sid == id(eng.store) and t == tid:
             return ent
     raise AssertionError(f"no cache entry for {table_name}")

@@ -139,11 +139,11 @@ def test_eviction_never_deletes_protected_sibling(serving):
     s.query(_dev_sql(0))                       # d0 hot in the HBM cache
     tid0 = eng.catalog.info_schema.table("d0").id
     key0 = None
-    for (dev, sid, t, _parts) in list(dc._CACHE):
+    for (dev, sid, t, _parts) in list(dc.CACHE):
         if sid == id(eng.store) and t == tid0:
             key0 = (dev, sid, t, _parts)
     assert key0 is not None, "d0 not cached after its query"
-    ent0 = dc._CACHE[key0]
+    ent0 = dc.CACHE[key0]
     dev_ids = {i: [id(a) for _s, a in col.arrays()]
                for i, col in ent0.dev.items()}
     assert dev_ids
@@ -153,8 +153,8 @@ def test_eviction_never_deletes_protected_sibling(serving):
         # would be trimmed first — protection must skip it
         for i in range(1, N_DEV_TABLES):
             s.query(_dev_sql(i))
-        assert key0 in dc._CACHE, "protected entry evicted"
-        ent_after = dc._CACHE[key0]
+        assert key0 in dc.CACHE, "protected entry evicted"
+        ent_after = dc.CACHE[key0]
         assert ent_after is ent0, "protected entry replaced mid-flight"
         for i, ids in dev_ids.items():
             assert [id(a) for _s, a in ent_after.dev[i].arrays()] == ids, \
@@ -164,7 +164,7 @@ def test_eviction_never_deletes_protected_sibling(serving):
     # the LRU budget is PER DEVICE now: entries for distinct devices
     # never pressure each other
     per_dev: dict = {}
-    for k in dc._CACHE:
+    for k in dc.CACHE:
         per_dev[k[0]] = per_dev.get(k[0], 0) + 1
     assert all(n <= dc.MAX_CACHED_TABLES + 1 for n in per_dev.values())
 

@@ -188,7 +188,7 @@ def test_device_lost_dispatch_retries_once_on_survivor(pod):
     # the dead device's cache shard was evicted with the quarantine
     tid = eng.catalog.info_schema.table("dim").id
     assert not any(k[0] == 0 and k[1] == id(eng.store) and k[2] == tid
-                   for k in dc._CACHE), \
+                   for k in dc.CACHE), \
         "quarantine must evict the dead device's cache shard"
 
 
@@ -465,9 +465,9 @@ def test_evict_device_rehomes_lost_slabs_onto_survivors(pod):
     assert s.query(full).rows == oracle
 
     tid = eng.catalog.info_schema.table("facts").id
-    key = next(k for k in dc._CACHE
+    key = next(k for k in dc.CACHE
                if k[0] == -1 and k[1] == id(eng.store) and k[2] == tid)
-    ent = dc._CACHE[key]
+    ent = dc.CACHE[key]
     owners0 = list(ent.owners)
     assert len(set(owners0)) > 1
     victim = owners0[0]
@@ -496,7 +496,7 @@ def test_evict_device_rehomes_lost_slabs_onto_survivors(pod):
     # next touch: partial refill of EXACTLY the lost slabs, onto the
     # re-homed owners — untouched arrays stay by identity
     assert s.query(full).rows == oracle
-    ent2 = dc._CACHE[key]
+    ent2 = dc.CACHE[key]
     assert ent2 is ent, "partial refill must reuse the entry in place"
     assert not ent.lost
     devs = jax.devices()

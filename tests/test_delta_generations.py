@@ -118,7 +118,7 @@ def _got(s, asof=None) -> list:
 
 def _entry(eng, name="t"):
     tid = eng.catalog.info_schema.table(name).id
-    for (_dev, sid, t, parts), ent in dc._CACHE.items():
+    for (_dev, sid, t, parts), ent in dc.CACHE.items():
         beside = parts is not None and parts[0] in ("plain", "behind")
         if sid == id(eng.store) and t == tid and not beside:
             return ent
@@ -230,7 +230,7 @@ def test_a_reader_far_behind_costs_the_plain_consumers_nothing():
         return _count("tidb_tpu_delta_declines_total", gate=name)
 
     def slot(tag):
-        return {k: e for k, e in dc._CACHE.items() if k[1] == id(eng.store)
+        return {k: e for k, e in dc.CACHE.items() if k[1] == id(eng.store)
                 and k[3] is not None and k[3][0] == tag}
 
     try:
@@ -345,9 +345,9 @@ def test_the_bound_frees_the_oldest_and_its_read_is_rebuilt_beside(
         # no generation kept any more: the accounting is back where one
         # generation's was
         tid = eng.catalog.info_schema.table("t").id
-        for key in [k for k in dc._CACHE if k[2] == tid
+        for key in [k for k in dc.CACHE if k[2] == tid
                     and k[3] is not None and k[3][0] == "behind"]:
-            dc._drop_entry(key, dc._CACHE[key])
+            dc._drop_entry(key, dc.CACHE[key])
         monkeypatch.setattr(dc, "KEPT_GENERATIONS", 0)
         _write(s, rows, 6)
         assert _got(s) == _want(rows)

@@ -67,7 +67,7 @@ def _oracle(s, q=Q):
 
 def _entry(eng, name="t"):
     tid = eng.catalog.info_schema.table(name).id
-    for (_dev, sid, t, parts), ent in dc._CACHE.items():
+    for (_dev, sid, t, parts), ent in dc.CACHE.items():
         # (a plain consumer's copy sits beside it, tagged in `parts`)
         plain = parts is not None and parts[0] == "plain"
         if sid == id(eng.store) and t == tid and not plain:
@@ -137,7 +137,7 @@ def test_delta_version_in_plan_keys():
     of one generation share it), and NOT in what the specialization
     cache keys: generations of one base build share their lineage, so a
     write costs the next statement no specialization and no trace."""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs, compile_cache
     eng, s = _engine()
     s.query(Q)
     e0 = _entry(eng)
@@ -148,12 +148,12 @@ def test_delta_version_in_plan_keys():
     assert e1.delta_version > e0.delta_version
     assert e1.lineage == e0.lineage
     s.query("INSERT INTO t VALUES (4, 1235, 'k2')")
-    t0 = fragment.PROGRAM_TRACES
+    t0 = compile_cache.PROGRAM_TRACES
     s.query(Q)
     e2 = _entry(eng)
     assert e2.delta_version > e1.delta_version
-    assert fragment._ent_geometry(e2) == fragment._ent_geometry(e1)
-    assert fragment.PROGRAM_TRACES == t0, "a write must not trace"
+    assert agg_slabs._ent_geometry(e2) == agg_slabs._ent_geometry(e1)
+    assert compile_cache.PROGRAM_TRACES == t0, "a write must not trace"
 
 
 def test_delta_merge_stale_fault_warned_cpu_fallback():
@@ -289,8 +289,8 @@ def test_a_rebuild_never_frees_what_another_statement_computes_on(
         # the other reader's install, for a snapshot that is not ours
         foreign = copy.copy(extend_from)
         foreign.td = object()
-        key = next(k_ for k_, e in dc._CACHE.items() if e is extend_from)
-        dc._CACHE[key] = foreign
+        key = next(k_ for k_, e in dc.CACHE.items() if e is extend_from)
+        dc.CACHE[key] = foreign
         return None
 
     monkeypatch.setattr(delta, "extend_entry", declined)
@@ -529,7 +529,7 @@ def test_the_compaction_trigger_is_measured_on_the_entry():
     assert delta.compaction_due(ent) == "delta-fill"
     ent.delta_rows, ent.dead_rows = 1, ent.base_total // 8
     assert delta.compaction_due(ent) == "dead-rows"
-    from tidb_tpu.session import DEFAULT_VARS
+    from tidb_tpu.sysvars import DEFAULT_VARS
     assert "tidb_tpu_delta_compact_rows" not in DEFAULT_VARS
 
 
@@ -549,17 +549,17 @@ def test_a_compaction_commits_though_the_table_moved_on(eager_compaction,
     assert delta.pending_compactions() == 1
     if not warmed:
         dc._READERS.clear()
-    real = dc._stream_slabs
+    real = dc.stream_slabs
 
     def moved_on(*a, **k):
         yield from real(*a, **k)
         s.query("INSERT INTO t VALUES (9, 99, 'k0')")   # mid-rebuild
 
-    dc._stream_slabs = moved_on
+    dc.stream_slabs = moved_on
     try:
         assert delta.run_pending_compactions() == 1
     finally:
-        dc._stream_slabs = real
+        dc.stream_slabs = real
     ent = _entry(eng)
     now = eng.store.snapshot().table_data(
         eng.catalog.info_schema.table("t").id)
@@ -579,7 +579,7 @@ def test_a_compaction_that_changes_a_layout_compiles_nothing_in_a_statement(
     specialized digest — and the statements after it none: neither over
     the swapped generation nor over its next extension, neither at their
     first execution there nor at their second."""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import compile_cache
     eng, s = _sorted_engine()
     s.vars["tidb_tpu_compaction"] = "off"
     for q in (QF, QF_PRUNED, QF_GROUPED):
@@ -592,20 +592,20 @@ def test_a_compaction_that_changes_a_layout_compiles_nothing_in_a_statement(
     ent0 = _entry(eng, "f")
     kinds0 = {i: l.kind for i, l in ent0.layouts.items() if l is not None}
     assert "delta" in kinds0.values() and delta.pending_compactions() == 1
-    t0 = fragment.PROGRAM_TRACES
+    t0 = compile_cache.PROGRAM_TRACES
     assert delta.run_pending_compactions() == 1
-    assert fragment.PROGRAM_TRACES > t0, "the layouts did not change"
+    assert compile_cache.PROGRAM_TRACES > t0, "the layouts did not change"
     ent1 = _entry(eng, "f")
     assert ent1.lineage != ent0.lineage and "delta" not in {
         l.kind for l in ent1.layouts.values() if l is not None}
-    t1, dec0 = fragment.PROGRAM_TRACES, _declines()
+    t1, dec0 = compile_cache.PROGRAM_TRACES, _declines()
     for q in (QF, QF_PRUNED, QF_GROUPED) * 2:
         assert s.query(q).rows == _oracle(s, q), q
     s.query("INSERT INTO f VALUES (6000, '1996-02-02', 5)")
     s.query("DELETE FROM f WHERE k = 17")
     for q in (QF, QF_PRUNED, QF_GROUPED) * 2:
         assert s.query(q).rows == _oracle(s, q), q
-    assert fragment.PROGRAM_TRACES == t1 and _declines() == dec0
+    assert compile_cache.PROGRAM_TRACES == t1 and _declines() == dec0
 
 
 @pytest.mark.parametrize("beside", [True, False])

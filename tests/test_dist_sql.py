@@ -6,7 +6,9 @@ executor/tiflash_test.go pattern — a real cluster faked in-process)."""
 import numpy as np
 import pytest
 
-from tidb_tpu.executor import build, run_to_completion
+from tidb_tpu.executor import run_to_completion
+
+from tidb_tpu.executor.builder import build
 from tidb_tpu.executor.fragment import TpuFragmentExec
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine
@@ -193,7 +195,7 @@ def test_skewed_exchange_retries_exactly_once(session):
     session.vars["tidb_tpu_exchange_bucket_cap"] = 64
     session.vars["tidb_tpu_dist_staged_exchange"] = "off"
     try:
-        from tidb_tpu.executor.fragment import _COMPILE_CACHE
+        from tidb_tpu.executor.compile_cache import _COMPILE_CACHE
         _COMPILE_CACHE.clear()
         got = run_dist(session, sql)
     finally:

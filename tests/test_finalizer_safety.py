@@ -106,19 +106,19 @@ def test_store_collected_under_cache_lock_defers_eviction():
     eng, s = _pod_engine()
     assert s.query(DIM_SQL).rows
     sid = id(eng.store)
-    assert any(k[1] == sid for k in dc._CACHE)
+    assert any(k[1] == sid for k in dc.CACHE)
     eng.close()
     holder = [eng, s]
     del eng, s
-    with dc._LOCK:
-        for _k in dc._CACHE:           # a holder mid-iteration
+    with dc.LOCK:
+        for _k in dc.CACHE:           # a holder mid-iteration
             holder.clear()
             gc.collect()               # the store dies HERE
         # the finalizer only queued the id: nothing changed under us
-        assert any(k[1] == sid for k in dc._CACHE)
+        assert any(k[1] == sid for k in dc.CACHE)
     # the next cache entry point reaps it
     assert dc.storage_stats(sid) == []
-    assert not any(k[1] == sid for k in dc._CACHE)
+    assert not any(k[1] == sid for k in dc.CACHE)
     assert sid not in dc._STORE_FINALIZERS
 
 

@@ -5,7 +5,9 @@ vec(X) == scalar(X); here device fragment == CPU volcano pipeline)."""
 import numpy as np
 import pytest
 
-from tidb_tpu.executor import build, run_to_completion
+from tidb_tpu.executor import run_to_completion
+
+from tidb_tpu.executor.builder import build
 from tidb_tpu.executor.fragment import TpuFragmentExec
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine
@@ -192,16 +194,16 @@ def test_device_table_cache_reuse_and_invalidation():
     # serial single-session workload → deterministically device 0
     key = (0, id(eng.store), eng.catalog.info_schema.table("ct").id, None)
     r1 = run_device(s, sql)
-    ent1 = device_cache._CACHE.get(key)
+    ent1 = device_cache.CACHE.get(key)
     assert ent1 is not None and 0 in ent1.dev
     r2 = run_device(s, sql)
-    ent2 = device_cache._CACHE.get(key)
+    ent2 = device_cache.CACHE.get(key)
     assert ent2 is ent1          # cache hit: same device payload object
     assert_same(r1, r2)
     # a write replaces TableData → identity check must rebuild
     s.execute("INSERT INTO ct VALUES (99, 'new')")
     r3 = run_device(s, sql)
-    ent3 = device_cache._CACHE.get(key)
+    ent3 = device_cache.CACHE.get(key)
     assert ent3 is not ent1
     assert sum(r[1] for r in r3) == 4001
     assert_same(r3, s.query(sql).rows)
@@ -249,7 +251,7 @@ def test_source_reason_codes_stay_in_taxonomy():
     import os
     import re
 
-    from tidb_tpu.executor.fragment import FALLBACK_REASONS
+    from tidb_tpu.executor.eligibility import FALLBACK_REASONS
     base = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "tidb_tpu", "executor")
     found = 0
@@ -264,7 +266,7 @@ def test_source_reason_codes_stay_in_taxonomy():
 
 
 def test_unknown_reason_normalizes_to_shape():
-    from tidb_tpu.executor.fragment import FragmentFallback
+    from tidb_tpu.executor.eligibility import FragmentFallback
     assert FragmentFallback("x", reason="no-such-code").reason == "shape"
     assert FragmentFallback("x").reason == "shape"
 

@@ -30,8 +30,9 @@ import re
 
 import pytest
 
-from tidb_tpu.executor import (build, device_cache, fragment as frag_mod,
-                               run_to_completion)
+from tidb_tpu.executor import (agg_slabs, compile_cache, device_cache,
+                               fragment as frag_mod, run_to_completion)
+from tidb_tpu.executor.builder import build
 from tidb_tpu.executor.fragment import TpuFragmentExec
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine
@@ -335,20 +336,20 @@ def test_whole_query_is_slabs_plus_one_then_one_launch(sql):
     s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
                    "tidb_tpu_max_slab_rows": 1024})   # 3 slabs
     try:
-        frag_mod._SPEC_CACHE.clear()
+        agg_slabs._SPEC_CACHE.clear()
         cold = s.query(sql).rows
         ph = s.last_guard.phases
         assert ph.fused_pipelines == 3, ph.summary()
         assert ph.programs_launched == ph.fused_pipelines + 1, ph.summary()
         assert s.query(sql).rows == cold    # traces the statement program
-        traces = frag_mod.PROGRAM_TRACES
+        traces = compile_cache.PROGRAM_TRACES
         for _ in range(2):
             assert s.query(sql).rows == cold
             ph = s.last_guard.phases
             assert ph.programs_launched == ph.fused_pipelines == 1, \
                 ph.summary()
             assert ph.specialization_hits >= 1, ph.summary()
-        assert frag_mod.PROGRAM_TRACES == traces, \
+        assert compile_cache.PROGRAM_TRACES == traces, \
             "repeated digest must not retrace"
     finally:
         for k in ("tidb_tpu_engine", "tidb_tpu_row_threshold",

@@ -21,7 +21,9 @@ Pinned invariants:
 
 import pytest
 
-from tidb_tpu.executor import build, fragment as frag_mod, run_to_completion
+from tidb_tpu.executor import (agg_slabs, compile_cache,
+                               fragment as frag_mod, run_to_completion)
+from tidb_tpu.executor.builder import build
 from tidb_tpu.executor.fragment import TpuFragmentExec
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine
@@ -136,7 +138,7 @@ def test_fused_join_launch_accounting():
     _, s = join_fixture()
     s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
                    "tidb_tpu_max_slab_rows": 1024})
-    frag_mod._SPEC_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     cpu_rows = s.query(Q3_SHAPE).rows
     ph = s.last_guard.phases
     # a digest's first execution: 3 probe slabs × 1 fused program + 1 root
@@ -188,18 +190,18 @@ def test_fused_warm_repeat_zero_retrace_two_launches_per_slab():
     _, s = join_fixture()
     s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
                    "tidb_tpu_max_slab_rows": 1024})
-    frag_mod._SPEC_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     cold = s.query(STR_KEY).rows
     ph = s.last_guard.phases
     assert ph.fused_pipelines == 3, ph.summary()
     assert ph.programs_launched <= 2 * ph.fused_pipelines, ph.summary()
     assert s.query(STR_KEY).rows == cold    # traces the statement program
-    traces = frag_mod.PROGRAM_TRACES
+    traces = compile_cache.PROGRAM_TRACES
     for _ in range(3):
         assert s.query(STR_KEY).rows == cold
         ph = s.last_guard.phases
         assert ph.fused_pipelines == ph.programs_launched == 1, ph.summary()
-    assert frag_mod.PROGRAM_TRACES == traces, \
+    assert compile_cache.PROGRAM_TRACES == traces, \
         "warm fused repeat must not retrace"
 
 

@@ -1,5 +1,5 @@
 """An aggregate without GROUP BY has one group: every driver gives its
-partial ONE slot (`fragment._initial_group_cap`), whose states are plain
+partial ONE slot (`agg_slabs.initial_group_cap`), whose states are plain
 masked reductions (`ops/segment.py`), and says so — `grouping="global"`,
 `gcap=1` on the `device.fragment` span, `tidb_tpu_agg_partials_total
 {grouping="global"}` per partial launched. Device against the CPU oracle,
@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from tidb_tpu.executor import device_cache as dc
-from tidb_tpu.executor import fragment
+from tidb_tpu.executor import agg_slabs, compile_cache
 from tidb_tpu.session import Engine
 from tidb_tpu.util import timeline
 from tidb_tpu.util.observability import REGISTRY
@@ -64,14 +64,14 @@ def run_checked(s, sql, tmp_path, monkeypatch, **vars_):
     s.vars["tidb_tpu_engine"] = "off"
     oracle = s.query(sql).rows
     asked = []
-    real = fragment._get_or_build
+    real = compile_cache.get_or_build
 
     def recording(sig, kind, build):
         prog = real(sig, kind, build)
         asked.append((kind, getattr(prog, "group_cap", None)))
         return prog
 
-    monkeypatch.setattr(fragment, "_get_or_build", recording)
+    monkeypatch.setattr(compile_cache, "get_or_build", recording)
     before = {g: _partials(g) for g in ("global", "bounds", "factorize")}
     s.vars.update(tidb_tpu_engine="on", tidb_tpu_row_threshold=1,
                   tidb_tpu_strict="on", **vars_)
@@ -249,7 +249,7 @@ def test_partials_counter_counts_slabs_for_global_and_none_for_grouped(
     statement's first execution, ONE statement program from its second on
     — and by 0 for a grouped one (which counts under its own grouping)."""
     vars_ = {"tidb_tpu_max_slab_rows": 1024}
-    fragment._SPEC_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     _, first = run_checked(session, SHAPES["q6_shaped"], tmp_path,
                            monkeypatch, **vars_)
     _, warm = run_checked(session, SHAPES["q6_shaped"], tmp_path,

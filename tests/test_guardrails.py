@@ -350,20 +350,20 @@ def test_device_cache_eviction_keeps_partitioned_entries():
         def hbm_bytes(self):
             return 100
 
-    saved = dict(dc._CACHE)
-    dc._CACHE.clear()
+    saved = dict(dc.CACHE)
+    dc.CACHE.clear()
     try:
-        dc._CACHE[(0, 1, 10, None)] = _Ent()     # evictable
-        dc._CACHE[(0, 1, 20, (0,))] = _Ent()     # partitioned, protected
-        dc._CACHE[(0, 1, 20, (1,))] = _Ent()     # partitioned, protected
+        dc.CACHE[(0, 1, 10, None)] = _Ent()     # evictable
+        dc.CACHE[(0, 1, 20, (0,))] = _Ent()     # partitioned, protected
+        dc.CACHE[(0, 1, 20, (1,))] = _Ent()     # partitioned, protected
         dc._evict_to_budget(150, keep=None,
                             keep_tables=frozenset({(1, 20)}))
-        assert (0, 1, 20, (0,)) in dc._CACHE
-        assert (0, 1, 20, (1,)) in dc._CACHE
-        assert (0, 1, 10, None) not in dc._CACHE
+        assert (0, 1, 20, (0,)) in dc.CACHE
+        assert (0, 1, 20, (1,)) in dc.CACHE
+        assert (0, 1, 10, None) not in dc.CACHE
     finally:
-        dc._CACHE.clear()
-        dc._CACHE.update(saved)
+        dc.CACHE.clear()
+        dc.CACHE.update(saved)
 
 
 def test_hash_partition_routes_negative_keys_like_mysql(eng):

@@ -30,7 +30,7 @@ import numpy as np
 import pytest
 
 from tidb_tpu.errors import ExecutionError
-from tidb_tpu.executor import fragment
+from tidb_tpu.executor import agg_slabs, compile_cache, tree_fragment
 from tidb_tpu.session import Engine
 from tidb_tpu.util import timeline
 from tidb_tpu.util.observability import REGISTRY, normalize_sql
@@ -131,12 +131,12 @@ def sorted_runs(monkeypatch):
     """Key domains of this scale address slots directly; a domain cap this
     low sends every grouped aggregate through sorted runs, as the full
     scale's millions of keys do."""
-    monkeypatch.setattr(fragment, "SLOT_ADDRESS_CAP", 64)
-    fragment._COMPILE_CACHE.clear()
-    fragment._SPEC_CACHE.clear()
+    monkeypatch.setattr(tree_fragment, "SLOT_ADDRESS_CAP", 64)
+    compile_cache._COMPILE_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     yield
-    fragment._COMPILE_CACHE.clear()
-    fragment._SPEC_CACHE.clear()
+    compile_cache._COMPILE_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
 
 
 @pytest.mark.parametrize("name", ["Q3", "Q10", "Q18"])
@@ -269,19 +269,19 @@ def test_an_append_past_a_power_of_two_mints_one_finalize(sorted_runs):
         # seven state arrays, one word
         assert [t for _p, t in first] == [None, None, "1/7"]
         assert run() == [(slab, None), (sort, None), (fin, None)]
-        traces = fragment.PROGRAM_TRACES
+        traces = compile_cache.PROGRAM_TRACES
         dev.execute(f"INSERT INTO w VALUES (3, {2 ** 20})")
         wider = run()
         assert [p for p, _t in wider[:2]] == [slab, sort]
         assert wider[2][0].startswith("finalize_") and wider[2][0] != fin
         assert wider[2][1] == "1/7"         # 21 + 12 and 1 + 12 bits
-        assert fragment.PROGRAM_TRACES == traces + 1
+        assert compile_cache.PROGRAM_TRACES == traces + 1
         assert text_rows(dev.execute(WIDENED)[0])[0][:2] == \
             ("3", str(2 ** 20 + sum((i * 7919) % 5000 for i in range(3000)
                                     if (i * 37) % 1000 == 3)))
         dev.execute(f"INSERT INTO w VALUES (4, {2 ** 20 - 7})")
         assert run() == [(slab, None), (sort, None), (wider[2][0], None)]
-        assert fragment.PROGRAM_TRACES == traces + 1
+        assert compile_cache.PROGRAM_TRACES == traces + 1
     finally:
         eng.close()
 
@@ -399,7 +399,7 @@ def test_strict_raises_on_a_host_join_over_a_device_sized_scan(loaded):
 def test_a_wrong_group_estimate_climbs_the_ladder_to_the_same_rows(
         loaded, sorted_runs, monkeypatch):
     eng, ref = loaded
-    monkeypatch.setattr(fragment, "_initial_group_cap",
+    monkeypatch.setattr(agg_slabs, "initial_group_cap",
                         lambda root, default, max_cap, key_bounds: 16)
     s = device_session(eng, tidb_tpu_max_slab_rows=16384)
     retries = counter("tidb_tpu_ladder_retries_total", rung="group")

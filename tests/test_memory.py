@@ -123,7 +123,8 @@ def test_unspillable_query_cancels(session):
 def test_multi_slab_device_sort(session):
     # a full ORDER BY (no LIMIT → Sort root, not TopN) over small slabs:
     # device per-slab sort + host run merge must equal the CPU sort
-    from tidb_tpu.executor import build, run_to_completion
+    from tidb_tpu.executor import run_to_completion
+    from tidb_tpu.executor.builder import build
     from tidb_tpu.executor.fragment import TpuFragmentExec
     from tidb_tpu.parser import parse
     s = session

@@ -22,7 +22,10 @@ from decimal import Decimal
 import numpy as np
 import pytest
 
-from tidb_tpu.executor import build, device_cache as dc, run_to_completion
+from tidb_tpu.chunk import compress
+from tidb_tpu.executor import device_cache as dc, run_to_completion
+
+from tidb_tpu.executor.builder import build
 from tidb_tpu.executor.fragment import TpuFragmentExec
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine
@@ -58,7 +61,7 @@ def run_device(s, sql, *, max_slab=None):
 
 def _cache_entry(eng, table_name):
     tid = eng.catalog.info_schema.table(table_name).id
-    for (_dev, sid, t, _parts), ent in dc._CACHE.items():
+    for (_dev, sid, t, _parts), ent in dc.CACHE.items():
         if sid == id(eng.store) and t == tid:
             return ent
     raise AssertionError(f"no cache entry for {table_name}")
@@ -107,11 +110,11 @@ def test_streamed_slabs_byte_exact_vs_upload_all():
     for i, ft in enumerate(fts):
         if i not in ent.dev:
             continue
-        vals, valid = dc._materialize_col(ent, i)
+        vals, valid = dc.materialize_col(ent, i)
         if ft.is_wide_decimal:
-            enc = dc.wide_decimal_limbs(vals, ft.wide_limb_count)
+            enc = compress.wide_decimal_limbs(vals, ft.wide_limb_count)
         else:
-            enc, dictionary = dc._encode_col(ft, vals, valid)
+            enc, dictionary = dc.encode_col(ft, vals, valid)
             if dictionary is None:
                 assert ent.dicts[i] is None
             else:
@@ -121,7 +124,7 @@ def test_streamed_slabs_byte_exact_vs_upload_all():
         # compressed columns: the resident slab is packed words — decode
         # reproduces the logical column under validity (invalid slots
         # decode to the layout's reference value, not the raw bytes)
-        slabs = dc._decoded_slabs(ent, i) if lay is not None \
+        slabs = dc.decoded_slabs(ent, i) if lay is not None \
             else ent.dev[i]
         for si, (dv, dm) in enumerate(slabs):
             start = si * ent.slab_cap

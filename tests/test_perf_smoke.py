@@ -17,6 +17,7 @@ import pytest
 
 from tidb_tpu.executor import device_cache as dc
 from tidb_tpu.executor import fragment
+from tidb_tpu.executor import agg_slabs, compile_cache
 from tidb_tpu.session import Engine
 
 pytestmark = pytest.mark.perf_smoke
@@ -52,7 +53,7 @@ def _storage_ids(ent, base_only=False):
 
 def _entry(eng):
     tid = eng.catalog.info_schema.table("p").id
-    for (_dev, sid, t, _parts), ent in dc._CACHE.items():
+    for (_dev, sid, t, _parts), ent in dc.CACHE.items():
         if sid == id(eng.store) and t == tid:
             return ent
     raise AssertionError("table p not cached")
@@ -86,7 +87,7 @@ def test_warm_concurrency_zero_retraces_zero_reuploads(session):
     assert s.query(SQL).rows == rows_cold  # specialized: the statement
     ent = _entry(eng)                      # program's trace
     dev_ids = _storage_ids(ent)
-    traces = fragment.PROGRAM_TRACES
+    traces = compile_cache.PROGRAM_TRACES
 
     sessions = []
     for _ in range(8):
@@ -111,7 +112,7 @@ def test_warm_concurrency_zero_retraces_zero_reuploads(session):
         t.join(timeout=120)
     assert not any(t.is_alive() for t in threads), "warm replay hung"
     assert not failures, failures
-    assert fragment.PROGRAM_TRACES == traces, \
+    assert compile_cache.PROGRAM_TRACES == traces, \
         "concurrent warm replays re-traced a program"
     ent2 = _entry(eng)
     assert ent2 is ent, "concurrent warm replays rebuilt the cache entry"
@@ -124,10 +125,10 @@ def test_repeat_query_zero_retraces_and_no_reupload(session):
     rows_cold = s.query(SQL).rows          # cold: trace + first touch
     ent = _entry(eng)
     assert ent.dev, "cold run left no device arrays cached"
-    traces = fragment.PROGRAM_TRACES
+    traces = compile_cache.PROGRAM_TRACES
 
     rows_warm = s.query(SQL).rows          # warm: must reuse everything
-    assert fragment.PROGRAM_TRACES == traces, \
+    assert compile_cache.PROGRAM_TRACES == traces, \
         "repeated identical query re-traced a program"
     # (the first statement program over the table makes each column's
     # slabs ONE array, on the device: from here on the storage stands)
@@ -163,19 +164,19 @@ def test_warm_selective_scan_launches_only_surviving_slabs():
     s.vars["tidb_tpu_max_slab_rows"] = 1024   # 3 slabs, sorted → partitioned
     sel = "SELECT COUNT(*), SUM(a) FROM q WHERE a >= 1024"
     full = "SELECT COUNT(*), SUM(a) FROM q"
-    fragment._SPEC_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     rows_cold = s.query(sel).rows              # cold: encode + upload
     surviving = 2
     assert s.last_guard.phases.programs_launched == surviving + 1
     tid = eng.catalog.info_schema.table("q").id
-    ent = next(e for (_d, sid, t, _p), e in dc._CACHE.items()
+    ent = next(e for (_d, sid, t, _p), e in dc.CACHE.items()
                if sid == id(eng.store) and t == tid)
     # cold-pruned slab 0 committed as a hole (None placeholder): its
     # encode+upload never happened at all
     assert any(t is None for slabs in ent.dev.values() for t in slabs), \
         "cold prune must leave holes, not upload pruned slabs"
     assert s.query(sel).rows == rows_cold      # traces the two-slab program
-    traces = fragment.PROGRAM_TRACES
+    traces = compile_cache.PROGRAM_TRACES
     # (its build stacked the RESIDENT slabs of each column: a hole stays one)
     assert all(col.is_stacked and col.holes() == {0}
                for col in ent.dev.values())
@@ -188,7 +189,7 @@ def test_warm_selective_scan_launches_only_surviving_slabs():
     assert ph.programs_launched == 1, \
         f"warm selective launches: {ph.programs_launched}"
     assert ph.h2d_bytes == 0 and ph.as_dict()["upload_s"] == 0.0
-    assert fragment.PROGRAM_TRACES == traces, "warm repeat re-traced"
+    assert compile_cache.PROGRAM_TRACES == traces, "warm repeat re-traced"
     assert _storage_ids(ent) == dev_ids, \
         "a column re-uploaded (or re-stacked) on a pruned warm repeat"
 
@@ -213,7 +214,7 @@ def test_warm_read_after_appends_no_base_reupload_one_extra_launch(session):
     uploads nothing at all and is ONE launch again."""
     eng, s = session
     s.vars["tidb_tpu_compaction"] = "off"     # no async rebuild mid-test
-    fragment._SPEC_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     s.query(SQL)                               # cold: trace + first touch
     base_launches = s.last_guard.phases.programs_launched   # slabs + 1
     s.query(SQL)                               # warm baseline

@@ -67,7 +67,7 @@ def _counter(name: str, dev: int):
 
 def _table_keys(eng, name: str):
     tid = eng.catalog.info_schema.table(name).id
-    return [k for k in dc._CACHE
+    return [k for k in dc.CACHE
             if k[1] == id(eng.store) and k[2] == tid]
 
 
@@ -190,7 +190,7 @@ def test_partitioned_fact_slabs_spread_single_resident(pod):
 
     keys = _table_keys(eng, "facts")
     assert len(keys) == 1 and keys[0][0] == -1, keys
-    ent = dc._CACHE[keys[0]]
+    ent = dc.CACHE[keys[0]]
     owners = ent.owners
     assert owners is not None and len(owners) == 8
     # contiguous non-decreasing ranges over the mesh

@@ -26,7 +26,9 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from tidb_tpu.executor import delta, fragment
+from tidb_tpu.executor import delta
+
+from tidb_tpu.executor import compile_cache
 from tidb_tpu.session import Engine
 from tidb_tpu.util import failpoint
 from tidb_tpu.util.observability import REGISTRY
@@ -98,9 +100,9 @@ def _drive(ds, client, data, n_ops, on_op=None):
     ref = _reference(ds, data)
     traces = []
     for n in range(n_ops):
-        t0 = fragment.PROGRAM_TRACES
+        t0 = compile_cache.PROGRAM_TRACES
         answer = kind.run(client, op)
-        traces.append(fragment.PROGRAM_TRACES - t0)
+        traces.append(compile_cache.PROGRAM_TRACES - t0)
         assert answer["n"] == n
         assert kind.check(op, answer, ref), (n, answer)
         if on_op is not None:
@@ -196,7 +198,7 @@ def test_a_superseded_generation_frees_by_reference_count(ds):
     count, not whenever the cycle collector happens to run (a server
     raises its young threshold: `server._tune_gc`). With the collector
     off, ten operations leave the device's live bytes where they were
-    (`fragment._plan_aligned_joins`' recursive closure used to hold every
+    (`agg_slabs.plan_aligned_joins`' recursive closure used to hold every
     generation it had seen: 14 GB of a 16 GB chip in a 40 s window). The
     cache keeps `KEPT_GENERATIONS` older generations behind each newest
     one for the readers a commit overtakes, so the bytes are steady once

@@ -6,7 +6,9 @@ against the host."""
 import numpy as np
 import pytest
 
-from tidb_tpu.executor import build, run_to_completion
+from tidb_tpu.executor import run_to_completion
+
+from tidb_tpu.executor.builder import build
 from tidb_tpu.executor.fragment import TpuFragmentExec
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine
@@ -176,13 +178,13 @@ def test_warm_rollup_launch_count(session):
     """A single-fragment rollup is <= slabs + 1 programs at its first
     execution — the level tiling rides inside the per-slab partial
     program, not extra launches — and ONE statement program warm."""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
     s = session
     sql = ROLLUP_QUERIES[0]
     s.vars["tidb_tpu_engine"] = "on"
     s.vars["tidb_tpu_row_threshold"] = 1
     try:
-        fragment._SPEC_CACHE.clear()
+        agg_slabs._SPEC_CACHE.clear()
         s.query(sql)               # compile + first-touch
         ph = s.last_guard.phases
         # 4000 rows pad into one slab: partial + fused finalize

@@ -399,3 +399,18 @@ def test_the_cost_hint_forgets_a_first_touch_and_leaves_out_the_wait():
     assert 0.020 <= cost < scheduler.CHEAP_BATCH_S
     lifetime = reg.stmt_summary[next(iter(reg.stmt_summary))]
     assert lifetime["device_s"] / lifetime["count"] > scheduler.CHEAP_BATCH_S
+
+
+def test_a_client_cannot_set_itself_out_of_admission(tier):
+    """`tidb_tpu_scheduler` was an internal flag any client could SET (the
+    compactor's warm runs read it). It is no variable now: a session that
+    sets it is admitted like any other — "unscheduled" is a field of the
+    context the compactor builds (`ExecContext.unscheduled`)."""
+    from tidb_tpu.executor.scheduler import POOL
+    _eng, new_session = tier
+    s = new_session()
+    s.execute("SET tidb_tpu_scheduler = 'off'")
+    before = POOL.stats()["admissions"]
+    assert s.query("SELECT g, COUNT(*) FROM big GROUP BY g ORDER BY g") \
+        .rows[0] == (0, 429)
+    assert POOL.stats()["admissions"] > before

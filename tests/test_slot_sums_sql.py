@@ -12,7 +12,7 @@ import pytest
 from test_refresh_stream import (SCALE, SEED, SETTINGS, _SessionClient,
                                  _drive, _engine, _load)
 from tidb_tpu.executor import device_cache as dc
-from tidb_tpu.executor import fragment
+from tidb_tpu.executor import agg_slabs, compile_cache
 from tidb_tpu.ops import segment as seg
 from tidb_tpu.util import timeline
 from tidb_tpu.util.observability import REGISTRY
@@ -35,11 +35,11 @@ def on_the_matrix_unit(monkeypatch):
     ROLLUP's tiling leave a tail."""
     monkeypatch.setattr(seg, "SLOT_SUM_MIN_WORK", 2)
     monkeypatch.setattr(seg, "SLOT_SUM_BLOCK", 4096)
-    fragment._COMPILE_CACHE.clear()
-    fragment._SPEC_CACHE.clear()
+    compile_cache._COMPILE_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     yield
-    fragment._COMPILE_CACHE.clear()
-    fragment._SPEC_CACHE.clear()
+    compile_cache._COMPILE_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     dc.clear()
 
 
@@ -78,7 +78,7 @@ def test_q1_q3_q5_over_several_slabs(ds, shaped, on_the_matrix_unit,
         expect = {"Q1": "mxu:24/88", "Q3": "mxu:8/24", "Q5": "mxu:8/24",
                   "Q6": "flat:6"}
         for q, sql in shaped.STATEMENTS.items():
-            before, traces = _lowerings(), fragment.PROGRAM_TRACES
+            before, traces = _lowerings(), compile_cache.PROGRAM_TRACES
             timeline.start_global(str(tmp_path))
             try:
                 rows = _text(s.query(sql).rows)
@@ -96,7 +96,7 @@ def test_q1_q3_q5_over_several_slabs(ds, shaped, on_the_matrix_unit,
             grew = _grew(before)
             assert grew.pop(lowering) == 1, (q, grew)
             assert set(grew) <= {"masked"} and sum(grew.values()) \
-                == len(tags) - 1 <= fragment.PROGRAM_TRACES - traces
+                == len(tags) - 1 <= compile_cache.PROGRAM_TRACES - traces
             # the digest's second execution traces ONE statement program,
             # a body a surviving slab: the lowering is said once a body
             before = _lowerings()

@@ -376,8 +376,8 @@ def test_a_named_lock_behaves_as_the_lock_it_wraps(recorder, reentrant):
     lock.release()
 
 
-LOCKS = [("tidb_tpu.executor.device_cache", "_LOCK", "device_cache"),
-         ("tidb_tpu.executor.fragment", "_CC_LOCK", "compile_cache"),
+LOCKS = [("tidb_tpu.executor.device_cache", "LOCK", "device_cache"),
+         ("tidb_tpu.executor.compile_cache", "LOCK", "compile_cache"),
          ("tidb_tpu.executor.index_scan", "_LOCK", "index_views"),
          ("tidb_tpu.executor.delta", "_EXT_LOCK", "delta_extend"),
          ("tidb_tpu.native", "_lock", "rowcodec")]

@@ -473,14 +473,14 @@ PINNED = {
 @pytest.mark.parametrize("case", sorted(PINNED))
 def test_a_warm_aggregate_launches_the_same_programs_in_the_same_order(
         case, monkeypatch):
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs, tree_fragment
     from tidb_tpu.ops.jax_env import jax
     sql, slab_prog, slab_sig, tail, whole, frag_tags, merge_tags, \
         device_gets = PINNED[case]
     by_slab = [(slab_prog, i, slab_sig) for i in range(3)] + \
         [(name, None, sig) for name, sig in tail]
     if frag_tags["grouping"] == "runs":
-        monkeypatch.setattr(fragment, "SLOT_ADDRESS_CAP", 64)
+        monkeypatch.setattr(tree_fragment, "SLOT_ADDRESS_CAP", 64)
     eng, s = _slab_session()
 
     def launches(cap):
@@ -491,7 +491,7 @@ def test_a_warm_aggregate_launches_the_same_programs_in_the_same_order(
 
     try:
         want = eng.new_session().query(sql).rows
-        fragment._SPEC_CACHE.clear()        # the first execution of a digest
+        agg_slabs._SPEC_CACHE.clear()        # the first execution of a digest
         with timeline.capture() as cap:
             s.query(sql)
         assert launches(cap)[1] == by_slab
@@ -537,7 +537,7 @@ def test_lowered_programs_carry_their_name_and_their_stages(monkeypatch):
     onto this small table: the threshold is a constant of `ops/segment.py`)
     — and the fused finalize holds `merge` and `sort` under `finalize`."""
     from tidb_tpu.executor import device_cache as dc
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs, compile_cache
     from tidb_tpu.ops import jax_env
     from tidb_tpu.ops import segment as seg
     monkeypatch.setattr(seg, "SLOT_SUM_MIN_WORK", 2)
@@ -553,8 +553,8 @@ def test_lowered_programs_carry_their_name_and_their_stages(monkeypatch):
         return call
 
     monkeypatch.setattr(jax_env, "named_jit", recording)
-    fragment._COMPILE_CACHE.clear()
-    fragment._SPEC_CACHE.clear()
+    compile_cache._COMPILE_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     try:
         eng = Engine()
         s = eng.new_session()
@@ -568,8 +568,8 @@ def test_lowered_programs_carry_their_name_and_their_stages(monkeypatch):
         assert s.last_engine == "tpu"
         eng.close()
     finally:
-        fragment._COMPILE_CACHE.clear()
-        fragment._SPEC_CACHE.clear()
+        compile_cache._COMPILE_CACHE.clear()
+        agg_slabs._SPEC_CACHE.clear()
         dc.clear()
     partial = next(n for n in seen if n.startswith("partial_chain_"))
     final = next(n for n in seen if n.startswith("finalize_"))

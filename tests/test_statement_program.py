@@ -1,4 +1,4 @@
-"""One launch a warm aggregate statement (`fragment._StatementProgram`).
+"""One launch a warm aggregate statement (`agg_slabs._StatementProgram`).
 
 Once an earlier execution of a statement's digest has settled its
 capacities and every slab is resident, `_run_agg_slabs` issues the slab
@@ -29,7 +29,7 @@ import functools
 import pytest
 
 from tidb_tpu.executor import device_cache as dc
-from tidb_tpu.executor import fragment
+from tidb_tpu.executor import agg_slabs, compile_cache, tree_fragment
 from tidb_tpu.executor.tree_fragment import TreeProgram
 from tidb_tpu.session import Engine
 from tidb_tpu.util import failpoint, timeline
@@ -57,7 +57,7 @@ def db():
     s.vars.update({"tidb_tpu_engine": "on", "tidb_tpu_row_threshold": 1,
                    "tidb_tpu_max_slab_rows": SLAB,
                    "tidb_tpu_compaction": "off"})
-    fragment._SPEC_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     yield eng, s
     eng.close()
     dc.clear()
@@ -73,7 +73,7 @@ def oracle(s, sql):
 
 def run(s, sql):
     """One execution → (rows, launch plan, launched program names, traces)."""
-    traces = fragment.PROGRAM_TRACES
+    traces = compile_cache.PROGRAM_TRACES
     with timeline.capture() as cap:
         rows = s.query(sql).rows
     assert s.last_engine == "tpu", sql
@@ -85,7 +85,7 @@ def run(s, sql):
     return (rows, plan,
             [e["name"] for e in sorted(evs, key=lambda e: e["ts"])
              if e["cat"] == "launch"],
-            fragment.PROGRAM_TRACES - traces)
+            compile_cache.PROGRAM_TRACES - traces)
 
 
 def same(rows, want, sql):
@@ -243,14 +243,14 @@ def test_a_statement_program_compiles_where_it_is_built_not_in_the_slot(db):
 def built(monkeypatch):
     """The statement programs asked for: (signature, slabs)."""
     asked = []
-    real = fragment.get_statement_program
+    real = agg_slabs.get_statement_program
 
     def recording(src, prog, n_run, *tail):
         sprog = real(src, prog, n_run, *tail)
         asked.append((sprog.sig, n_run))
         return sprog
 
-    monkeypatch.setattr(fragment, "get_statement_program", recording)
+    monkeypatch.setattr(agg_slabs, "get_statement_program", recording)
     return asked
 
 
@@ -386,7 +386,7 @@ def test_distinct_pairs_and_a_cold_stream_stay_per_slab(db):
 
 def test_sorted_runs_stay_on_their_own_driver(db, monkeypatch):
     _, s = db
-    monkeypatch.setattr(fragment, "SLOT_ADDRESS_CAP", 64)
+    monkeypatch.setattr(tree_fragment, "SLOT_ADDRESS_CAP", 64)
     sql = ("SELECT k, COUNT(*), SUM(v) FROM f GROUP BY k "
            "ORDER BY SUM(v) DESC, k LIMIT 5")
     want = oracle(s, sql)
@@ -405,7 +405,7 @@ def test_live_rows_are_device_values_of_the_entry(db):
     eng, s = db
     s.query(STATEMENTS["chain-bounds"])
     tid = eng.catalog.info_schema.table("f").id
-    (ent,) = [e for (_d, sid, t, _p), e in dc._CACHE.items()
+    (ent,) = [e for (_d, sid, t, _p), e in dc.CACHE.items()
               if sid == id(eng.store) and t == tid]
     lives = [ent.live_arg(i) for i in range(ent.n_slabs)]
     assert [int(n) for n in lives] == [1024, 1024, ROWS - 2048]
@@ -415,7 +415,7 @@ def test_live_rows_are_device_values_of_the_entry(db):
     assert ent.live_counts(frozenset({1})).tolist() == [1024, 0, 952]
     s.execute("DELETE FROM f WHERE a = 3")
     s.query(STATEMENTS["chain-bounds"])
-    (new,) = [e for (_d, sid, t, _p), e in dc._CACHE.items()
+    (new,) = [e for (_d, sid, t, _p), e in dc.CACHE.items()
               if sid == id(eng.store) and t == tid]
     # (three slabs on one device: the masks are ONE array from their
     # birth, and a launch takes the array itself and the slab's row)
@@ -431,17 +431,17 @@ def test_a_cached_statement_program_holds_nothing_of_a_statement(db):
     _, s = db
     for case in ("chain-topn", "tree-sort"):
         thrice(s, STATEMENTS[case])
-    progs = [p for p in fragment._COMPILE_CACHE.values()
-             if isinstance(p, fragment._StatementProgram)]
+    progs = [p for p in compile_cache._COMPILE_CACHE.values()
+             if isinstance(p, agg_slabs._StatementProgram)]
     assert progs
     for p in progs:
-        assert p.control in (fragment._ChainSlabs.control,
-                             fragment._TreeSlabs.control)
+        assert p.control in (agg_slabs.ChainSlabs.control,
+                             agg_slabs.TreeSlabs.control)
         for body in filter(None, (p.body, p.dbody)):
             assert isinstance(body, functools.partial)
-            assert body.func in (fragment._ChainSlabs._slab_body,
-                                 fragment._TreeSlabs._slab_body)
-            assert all(isinstance(a, (fragment._FragmentProgram,
+            assert body.func in (agg_slabs.ChainSlabs._slab_body,
+                                 agg_slabs.TreeSlabs._slab_body)
+            assert all(isinstance(a, (agg_slabs._FragmentProgram,
                                       TreeProgram, int))
                        for a in body.args), body.args
 
@@ -459,11 +459,11 @@ def test_the_control_fetch_packs_into_a_vector_a_kind_and_back():
             "states": [(jnp.array([[1, 2], [3, 4]], dtype=jnp.uint32),
                         jnp.array([0.5, -1.25], dtype=jnp.float32)),
                        (jnp.zeros((0,), dtype=jnp.int64),)]}
-    packed = jax.device_get(jax.jit(fragment._pack)(tree))
+    packed = jax.device_get(jax.jit(agg_slabs._pack)(tree))
     assert sorted(packed) == ["float32", "int64"]
     like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                         tree)
-    back = fragment._unpack(packed, like)
+    back = agg_slabs._unpack(packed, like)
     for got, want in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
         assert got.dtype == want.dtype and got.shape == want.shape
         assert np.array_equal(got, np.asarray(want))
@@ -506,7 +506,7 @@ def _slices() -> float:
 
 def _cached(eng, name):
     tid = eng.catalog.info_schema.table(name).id
-    (ent,) = [e for (_d, sid, t, p), e in dc._CACHE.items()
+    (ent,) = [e for (_d, sid, t, p), e in dc.CACHE.items()
               if sid == id(eng.store) and t == tid and p is None]
     return tid, ent
 
@@ -544,12 +544,12 @@ def test_the_loop_indexes_one_array_a_column(six, built, monkeypatch, case):
     eng, s = six
     sql, survive = SIX_SLABS[case]
     launched = []
-    real = fragment._StatementProgram.__init__
+    real = agg_slabs._StatementProgram.__init__
 
     def init(self, *a):
         real(self, *a)
         launched.append((self, a[-1]))
-    monkeypatch.setattr(fragment._StatementProgram, "__init__", init)
+    monkeypatch.setattr(agg_slabs._StatementProgram, "__init__", init)
     tid = eng.catalog.info_schema.table("g").id
     stacks0, of_g0 = _stacks(), _stacks(tid)
     name = thrice(s, sql, slabs=survive)
@@ -593,10 +593,10 @@ def test_the_first_launch_of_a_statement_program_says_how_it_picks(six):
         s.vars["tidb_tpu_max_slab_rows"] = 8192 if table == "one" else SLAB
         sql = f"SELECT b, MAX(v), COUNT(*) FROM {table} GROUP BY b"
         s.query(sql)
-        traces = fragment.PROGRAM_TRACES
+        traces = compile_cache.PROGRAM_TRACES
         with timeline.capture() as cap:
             s.query(sql)
-        if fragment.PROGRAM_TRACES == traces:
+        if compile_cache.PROGRAM_TRACES == traces:
             pytest.skip("the statement program was built by an earlier test")
         said[table] = [e["args"].get("slab_pick") for e in cap.events
                        if e["ph"] == "X" and e["cat"] == "launch"]
@@ -705,7 +705,7 @@ def test_a_warm_window_of_mixed_plans_slices_no_slab(six, monkeypatch):
     read whole lists its slabs there, `SlabColumn.whole`), nothing is
     stacked again, and every answer is the host's."""
     _eng, s = six
-    monkeypatch.setattr(fragment, "SLOT_ADDRESS_CAP", 64)
+    monkeypatch.setattr(tree_fragment, "SLOT_ADDRESS_CAP", 64)
     want = {k: oracle(s, sql) for k, sql in MIXED.items()}
     plans = {}
     for _ in range(3):

@@ -126,24 +126,24 @@ def test_join_estimate(session):
 
 
 def test_stats_feed_group_cap(session):
-    from tidb_tpu.executor.fragment import _initial_group_cap
+    from tidb_tpu.executor.agg_slabs import initial_group_cap
     p = _plan(session, "SELECT b, COUNT(*) FROM st GROUP BY b")
     agg = _find(p, "PhysHashAgg")
-    cap = _initial_group_cap(agg, 1 << 16, 1 << 23)
+    cap = initial_group_cap(agg, 1 << 16, 1 << 23)
     assert cap >= 32768          # ≥ ndv(b)=20000 with headroom
 
     p = _plan(session, "SELECT a, COUNT(*) FROM st GROUP BY a")
     agg = _find(p, "PhysHashAgg")
-    cap = _initial_group_cap(agg, 1 << 16, 1 << 23)
+    cap = initial_group_cap(agg, 1 << 16, 1 << 23)
     assert cap == 1024           # small reliable estimate → floor
 
     # no GROUP BY: one group, whatever the estimate or the default say
     p = _plan(session, "SELECT COUNT(*), SUM(a) FROM st WHERE b > 5")
     agg = _find(p, "PhysHashAgg")
     assert not agg.group_exprs
-    assert _initial_group_cap(agg, 1 << 16, 1 << 23) == 1
+    assert initial_group_cap(agg, 1 << 16, 1 << 23) == 1
     agg.est_reliable = False
-    assert _initial_group_cap(agg, 1 << 16, 1 << 23) == 1
+    assert initial_group_cap(agg, 1 << 16, 1 << 23) == 1
 
 
 def _wait_stats(eng, tid, pred=lambda st: True, timeout=5.0):

@@ -208,7 +208,7 @@ def test_committed_coverage_baseline_shape():
     fallback reason is in the fragment taxonomy."""
     import json
 
-    from tidb_tpu.executor.fragment import FALLBACK_REASONS
+    from tidb_tpu.executor.eligibility import FALLBACK_REASONS
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(repo, "COVERAGE.json")) as f:
         base = json.load(f)

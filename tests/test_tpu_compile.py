@@ -95,7 +95,7 @@ def recorded():
     """Run the smoke's statements once at toy size with the chip's program
     variants, recording every program launch. → list of calls."""
     from tidb_tpu.executor import device_cache as dc
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs, compile_cache
     from tidb_tpu.ops import jax_env
     from tidb_tpu.session import Engine
     from tidb_tpu.tools import tpch_shaped as T
@@ -104,8 +104,8 @@ def recorded():
     jax = jax_env.jax
     rec = _Recorder(jax)
     mp = pytest.MonkeyPatch()
-    fragment._COMPILE_CACHE.clear()
-    fragment._SPEC_CACHE.clear()
+    compile_cache._COMPILE_CACHE.clear()
+    agg_slabs._SPEC_CACHE.clear()
     try:
         mp.setattr(jax_env, "on_tpu", lambda: True)
         mp.setattr(jax, "jit", rec)
@@ -135,8 +135,8 @@ def recorded():
     finally:
         mp.undo()
         # programs built under the steering must not serve later tests
-        fragment._COMPILE_CACHE.clear()
-        fragment._SPEC_CACHE.clear()
+        compile_cache._COMPILE_CACHE.clear()
+        agg_slabs._SPEC_CACHE.clear()
         dc.clear()
     return rec.calls
 
@@ -177,14 +177,15 @@ def _scaled_cols(jax, cols, factor, sharding):
 
 def _rebuild_tree(owner, factor):
     """A recorded `TreeProgram` again at the real slab geometry."""
-    from tidb_tpu.executor.tree_fragment import TreeProgram, _walk_joins
+    from tidb_tpu.executor.eligibility import walk_joins
+    from tidb_tpu.executor.tree_fragment import TreeProgram
     return TreeProgram(
         owner.plan,
         # (slab capacity, slabs[, capacity of a raw delta slab])
         {k: (c[0] * factor, c[1]) + tuple(d * factor for d in c[2:])
          for k, c in owner.caps.items()},
         owner.group_cap,
-        [owner.join_cfgs[id(n)] for n in _walk_joins(owner.plan)],
+        [owner.join_cfgs[id(n)] for n in walk_joins(owner.plan)],
         owner.agg_key_bounds, owner.scan_layouts, owner.pairs_out,
         owner.pair_cap)
 
@@ -192,22 +193,22 @@ def _rebuild_tree(owner, factor):
 def _rebuild(owner, factor):
     """The same program through its own constructor at the real slab
     geometry → (program, jitted entry point by method name)."""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
     from tidb_tpu.executor.tree_fragment import TreeProgram
-    if isinstance(owner, fragment._FragmentProgram):
-        p = fragment._FragmentProgram(
+    if isinstance(owner, agg_slabs._FragmentProgram):
+        p = agg_slabs._FragmentProgram(
             owner.chain, owner.used_cols, owner.in_types,
             owner.slab_cap * factor, owner.group_cap, owner.key_bounds,
             owner.has_distinct, owner.layouts, owner.pair_cap)
         return {"_partial": p.partial, "_merge": p.merge}
     if isinstance(owner, TreeProgram):
         return {"_run": _rebuild_tree(owner, factor).run}
-    if isinstance(owner, fragment._FusedFinalizeProgram):
-        p = fragment._FusedFinalizeProgram(owner.agg_root, owner.order_root,
+    if isinstance(owner, agg_slabs._FusedFinalizeProgram):
+        p = agg_slabs._FusedFinalizeProgram(owner.agg_root, owner.order_root,
                                            owner.group_cap)
         return {"_run": p.run}
-    if isinstance(owner, fragment._AggMergeProgram):
-        p = fragment._AggMergeProgram(owner.root, owner.group_cap)
+    if isinstance(owner, agg_slabs._AggMergeProgram):
+        p = agg_slabs._AggMergeProgram(owner.root, owner.group_cap)
         return {"_merge": p.merge}
     return None
 
@@ -224,12 +225,13 @@ def _compile_all(calls, kinds, one_chip, monkeypatch):
 
 def _compile_calls(calls, kinds, one_chip, monkeypatch):
     """→ [(owner, label, compiled program)]"""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
+    from tidb_tpu import sysvars
     from tidb_tpu.executor.tree_fragment import TreeProgram
     from tidb_tpu.ops import jax_env
     jax = jax_env.jax
     monkeypatch.setattr(jax_env, "on_tpu", lambda: True)
-    factor = fragment.DEFAULT_MAX_SLAB_ROWS // TOY_ROWS
+    factor = sysvars.DEFAULT_MAX_SLAB_ROWS // TOY_ROWS
     out = []
     for owner, method, shapes in calls:
         if not isinstance(owner, kinds):
@@ -239,7 +241,7 @@ def _compile_calls(calls, kinds, one_chip, monkeypatch):
             out.append((owner, label, _COMPILED[id(owner), method]))
             continue
         entry = _rebuild(owner, factor)[method]
-        if isinstance(owner, fragment._FragmentProgram) \
+        if isinstance(owner, agg_slabs._FragmentProgram) \
                 and method == "_partial":
             cols, n_rows, preps = shapes
             args = (_scaled_cols(jax, cols, factor, one_chip),
@@ -272,7 +274,7 @@ def test_chain_partials_compile_at_a_full_slab(recorded, one_chip,
                                                monkeypatch):
     """Q1 and Q6: scan → in-trace compressed decode → filter → partial
     aggregate over one 8M-row slab."""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
     from tidb_tpu.executor import delta
     calls = [c for c in recorded if c[1] == "_partial"]
     # the one raw program is the delta slab's: Q6 again after the INSERT,
@@ -283,7 +285,7 @@ def test_chain_partials_compile_at_a_full_slab(recorded, one_chip,
     assert any(np.dtype(bool) == getattr(c[2][1], "dtype", None)
                and c[2][1].shape == (TOY_ROWS,) for c in calls
                if c[0].layouts), "no base slab ran under a liveness mask"
-    stats = _compile_all(calls, fragment._FragmentProgram, one_chip,
+    stats = _compile_all(calls, agg_slabs._FragmentProgram, one_chip,
                          monkeypatch)
     assert len(stats) >= 4     # Q1, Q6, Q6 masked, Q6 over the delta slab
     _fits(stats)
@@ -300,9 +302,9 @@ def test_global_aggregate_partial_has_no_slot_axis(recorded, one_chip,
     contraction over blocks of rows (`ops/segment.slot_sums`), with the
     one convolution of the program in its body."""
     import re
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
     calls = [c for c in recorded if c[1] == "_partial"]
-    compiled = _compile_calls(calls, fragment._FragmentProgram, one_chip,
+    compiled = _compile_calls(calls, agg_slabs._FragmentProgram, one_chip,
                               monkeypatch)
 
     def agg_slot_axes(text, slots):
@@ -344,11 +346,12 @@ def test_delta_decode_has_no_slab_wide_scan(recorded, one_chip, monkeypatch):
     the blocks' totals."""
     import re
     from tidb_tpu.chunk import compress
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
+    from tidb_tpu import sysvars
     calls = [c for c in recorded if c[1] == "_partial"]
-    compiled = _compile_calls(calls, fragment._FragmentProgram, one_chip,
+    compiled = _compile_calls(calls, agg_slabs._FragmentProgram, one_chip,
                               monkeypatch)
-    slab = fragment.DEFAULT_MAX_SLAB_ROWS
+    slab = sysvars.DEFAULT_MAX_SLAB_ROWS
     checked = 0
     for owner, _label, c in compiled:
         deltas = [lay for lay in (owner.layouts or {}).values()
@@ -387,11 +390,11 @@ def test_finalize_and_merge_compile_over_sf10_partials(recorded, one_chip,
                                                        monkeypatch):
     """The whole-query tails: fused finalize (merge → finalize → ORDER BY)
     and the delta merge, over 8 stacked slab partials, inputs donated."""
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
     calls = [c for c in recorded if c[1] != "_partial"]
     stats = _compile_all(
-        calls, (fragment._FusedFinalizeProgram, fragment._AggMergeProgram,
-                fragment._FragmentProgram), one_chip, monkeypatch)
+        calls, (agg_slabs._FusedFinalizeProgram, agg_slabs._AggMergeProgram,
+                agg_slabs._FragmentProgram), one_chip, monkeypatch)
     labels = {label for label, _ in stats}
     assert "_FusedFinalizeProgram._run" in labels, labels
     assert labels & {"_FragmentProgram._merge", "_AggMergeProgram._merge"}, \
@@ -410,7 +413,8 @@ def test_a_statement_program_is_one_body_in_a_loop(recorded, one_chip,
     under the statement program's."""
     import functools
     import re
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
+    from tidb_tpu import sysvars
     from tidb_tpu.ops import jax_env
     jax = jax_env.jax
     calls = [c for c in recorded if c[1] == "_partial" and c[0].layouts
@@ -418,9 +422,9 @@ def test_a_statement_program_is_one_body_in_a_loop(recorded, one_chip,
              and getattr(c[2][1], "dtype", None) != np.dtype(bool)]
     assert calls, "the toy run launched no Q6 partial over a live prefix"
     (owner, _label, one), = _compile_calls(
-        calls[:1], fragment._FragmentProgram, one_chip, monkeypatch)
-    factor = fragment.DEFAULT_MAX_SLAB_ROWS // TOY_ROWS
-    p = fragment._FragmentProgram(
+        calls[:1], agg_slabs._FragmentProgram, one_chip, monkeypatch)
+    factor = sysvars.DEFAULT_MAX_SLAB_ROWS // TOY_ROWS
+    p = agg_slabs._FragmentProgram(
         owner.chain, owner.used_cols, owner.in_types,
         owner.slab_cap * factor, owner.group_cap, owner.key_bounds,
         owner.has_distinct, owner.layouts, owner.pair_cap)
@@ -429,9 +433,9 @@ def test_a_statement_program_is_one_body_in_a_loop(recorded, one_chip,
             _scaled_live(jax, n_rows, factor, one_chip))
     args = (_scaled(jax, preps, 1, one_chip), _stacked(jax, slab, 6),
             None, (jax.ShapeDtypeStruct((3,), np.int32, sharding=one_chip),))
-    sp = fragment._StatementProgram(
-        "stmt_chain", functools.partial(fragment._ChainSlabs._slab_body, p),
-        None, p._merge, fragment._ChainSlabs.control, True, "toy", args)
+    sp = agg_slabs._StatementProgram(
+        "stmt_chain", functools.partial(agg_slabs.ChainSlabs._slab_body, p),
+        None, p._merge, agg_slabs.ChainSlabs.control, True, "toy", args)
     assert set(sp.like) == {"ngs", "ng", "keys", "states"}
     compiled = sp.run.lower(*args).compile()    # the build's own executable
     m, m1 = compiled.memory_analysis(), one.memory_analysis()
@@ -452,7 +456,7 @@ def _stacked(jax, slab, n_slabs: int):
     slab, and a live-row count — as `device_cache.Stacked` over the
     column's ONE array (`SlabColumn.stack`'s shape), a dictionary or a
     delta base as it is."""
-    from tidb_tpu.executor import device_cache as dc
+    from tidb_tpu.executor import device_cache as dc, device_emit
     node = dc._node(dc.Stacked)
 
     def stack(x):
@@ -461,8 +465,8 @@ def _stacked(jax, slab, n_slabs: int):
         if x.shape and x.shape[-1] < TOY_ROWS // 64:
             return x            # (a dictionary, a delta base: shared)
         return node(jax.ShapeDtypeStruct(
-            (n_slabs,) + dc._folded(x.shape), x.dtype, sharding=x.sharding),
-            None, 0, tuple(x.shape))
+            (n_slabs,) + device_emit.folded(x.shape), x.dtype,
+            sharding=x.sharding), None, 0, tuple(x.shape))
     return jax.tree.map(stack, slab)
 
 
@@ -519,13 +523,14 @@ def test_a_six_slab_statement_program_indexes_its_slabs_in_place(
     inside: padded to eight and read with a stride."""
     import functools
     import re
-    from tidb_tpu.executor import fragment
+    from tidb_tpu.executor import agg_slabs
+    from tidb_tpu import sysvars
     from tidb_tpu.executor.tree_fragment import TreeProgram
     from tidb_tpu.ops import jax_env
     jax = jax_env.jax
     monkeypatch.setattr(jax_env, "on_tpu", lambda: True)
-    factor = fragment.DEFAULT_MAX_SLAB_ROWS // TOY_ROWS
-    slab_rows, n_slabs = fragment.DEFAULT_MAX_SLAB_ROWS, 6
+    factor = sysvars.DEFAULT_MAX_SLAB_ROWS // TOY_ROWS
+    slab_rows, n_slabs = sysvars.DEFAULT_MAX_SLAB_ROWS, 6
     picks = (jax.ShapeDtypeStruct((n_slabs,), np.int32, sharding=one_chip),)
 
     # -- Q1: a chain over six slabs under live-row counts ------------------
@@ -533,7 +538,7 @@ def test_a_six_slab_statement_program_indexes_its_slabs_in_place(
         c for c in recorded if c[1] == "_partial" and c[0].layouts
         and c[0].chain[0].group_exprs
         and getattr(c[2][1], "dtype", None) != np.dtype(bool))
-    p = fragment._FragmentProgram(
+    p = agg_slabs._FragmentProgram(
         owner.chain, owner.used_cols, owner.in_types,
         owner.slab_cap * factor, owner.group_cap, owner.key_bounds,
         owner.has_distinct, owner.layouts, owner.pair_cap)
@@ -541,17 +546,17 @@ def test_a_six_slab_statement_program_indexes_its_slabs_in_place(
             _scaled_live(jax, n_rows, factor, one_chip))
     chain_args = (_scaled(jax, preps, 1, one_chip),
                   _stacked(jax, slab, n_slabs), None, picks)
-    chain = fragment._StatementProgram(
-        "stmt_chain", functools.partial(fragment._ChainSlabs._slab_body, p),
-        None, p._merge, fragment._ChainSlabs.control, True, "toy-q1",
+    chain = agg_slabs._StatementProgram(
+        "stmt_chain", functools.partial(agg_slabs.ChainSlabs._slab_body, p),
+        None, p._merge, agg_slabs.ChainSlabs.control, True, "toy-q1",
         chain_args)
 
     # -- Q3: the join tree's statement program over a delta generation -----
     sp, _m, (shared, base, delta, _picks) = next(
-        c for c in recorded if isinstance(c[0], fragment._StatementProgram))
+        c for c in recorded if isinstance(c[0], agg_slabs._StatementProgram))
     assert isinstance(sp.body.args[0], TreeProgram) and delta is not None
     bodies = [functools.partial(
-        fragment._TreeSlabs._slab_body, _rebuild_tree(b.args[0], factor),
+        agg_slabs.TreeSlabs._slab_body, _rebuild_tree(b.args[0], factor),
         b.args[1]) for b in (sp.body, sp.dbody)]
     si, sr, pv, ai, nested = shared
     grow = functools.partial(_scaled, jax, factor=factor, sharding=one_chip)
@@ -567,7 +572,7 @@ def test_a_six_slab_statement_program_indexes_its_slabs_in_place(
                 grow(live), grow(sliced))
     fused_args = (shared, _stacked(jax, own(base), n_slabs), own(delta),
                   picks)
-    fused = fragment._StatementProgram(
+    fused = agg_slabs._StatementProgram(
         "stmt_fused", bodies[0], bodies[1], sp.tail, sp.control, sp.small,
         "toy-q3", fused_args)
 
@@ -626,10 +631,10 @@ def test_a_short_leaf_takes_whole_lanes_in_its_stack(one_chip):
     and a statement program's loop read a slab of it inside the consuming
     fusion."""
     import re
-    from tidb_tpu.executor import device_cache as dc
+    from tidb_tpu.executor import device_cache as dc, device_emit
     from tidb_tpu.ops.jax_env import jax, jnp, lax
     n, n_slabs = 1000, 6
-    assert dc._folded((n,)) == (8, 128)
+    assert device_emit.folded((n,)) == (8, 128)
     stack = jax.ShapeDtypeStruct((n_slabs, 8, 128), np.int32,
                                  sharding=one_chip)
     mask = jax.ShapeDtypeStruct((n_slabs, 8, 128), np.bool_,
@@ -667,9 +672,10 @@ def test_a_slab_goes_into_its_stack_in_place(one_chip):
     fill never holds two stacks, and the slab's 1-D → folded form costs no
     pass of its own."""
     import re
-    from tidb_tpu.executor import device_cache as dc, fragment
+    from tidb_tpu.executor import device_cache as dc
+    from tidb_tpu import sysvars
     from tidb_tpu.ops.jax_env import jax
-    rows, n = fragment.DEFAULT_MAX_SLAB_ROWS, 6
+    rows, n = sysvars.DEFAULT_MAX_SLAB_ROWS, 6
     first, put = dc._stack_programs(n, (rows,), "uint32")
     stack = jax.ShapeDtypeStruct((n, rows // 128, 128), np.uint32,
                                  sharding=one_chip)
@@ -693,9 +699,10 @@ def test_masks_are_born_in_a_filled_stacks_layout(one_chip):
     of masks has — the slab axis outside the tile, unpadded: a byte a row
     — and the program holds no temporaries to speak of."""
     import re
-    from tidb_tpu.executor import device_emit, fragment
+    from tidb_tpu.executor import device_emit
+    from tidb_tpu import sysvars
     from tidb_tpu.ops.jax_env import jax
-    rows, n = fragment.DEFAULT_MAX_SLAB_ROWS, 6
+    rows, n = sysvars.DEFAULT_MAX_SLAB_ROWS, 6
     born = device_emit.emit_alive_stack([rows] * 5 + [17], rows)
     assert born.shape == (n, rows // 128, 128) and born.dtype == bool
     assert int(born[5].sum()) == 17 and bool(born[4].all())

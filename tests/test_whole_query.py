@@ -6,7 +6,9 @@ CPU volcano oracle, plus warm launch-count pins."""
 import numpy as np
 import pytest
 
-from tidb_tpu.executor import build, run_to_completion
+from tidb_tpu.executor import run_to_completion
+
+from tidb_tpu.executor.builder import build
 from tidb_tpu.executor.fragment import TpuFragmentExec
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine

@@ -138,8 +138,8 @@ def test_codec_roundtrip_wide(s):
 
 
 def test_limb_split_recombine():
-    from tidb_tpu.executor.device_cache import (wide_decimal_limbs,
-                                                wide_decimal_unlimb)
+    from tidb_tpu.chunk.compress import (wide_decimal_limbs,
+                                         wide_decimal_unlimb)
     vals = np.array([10**37 - 1, -(10**37 - 1), 0, 123456789,
                      -987654321012345678901234567], dtype=object)
     limbs = wide_decimal_limbs(vals, 5)

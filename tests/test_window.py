@@ -179,7 +179,8 @@ DEVICE_WINDOW_QUERIES = [
 
 @pytest.mark.parametrize("sql", DEVICE_WINDOW_QUERIES)
 def test_device_window_matches_cpu(session, sql):
-    from tidb_tpu.executor import build, run_to_completion
+    from tidb_tpu.executor import run_to_completion
+    from tidb_tpu.executor.builder import build
     from tidb_tpu.executor.fragment import TpuFragmentExec
     from tidb_tpu.parser import parse
     s = session
@@ -293,7 +294,8 @@ def test_first_last_value():
 def test_frames_on_device():
     import numpy as np
     from tidb_tpu.session import Engine
-    from tidb_tpu.executor import build, run_to_completion
+    from tidb_tpu.executor import run_to_completion
+    from tidb_tpu.executor.builder import build
     from tidb_tpu.executor.fragment import TpuFragmentExec
     from tidb_tpu.parser import parse
     s = Engine().new_session()

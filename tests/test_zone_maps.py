@@ -34,9 +34,10 @@ import pytest
 
 from tidb_tpu.chunk import compress
 from tidb_tpu.errors import LayoutError
-from tidb_tpu.executor import build, run_to_completion, zonemap
+from tidb_tpu.executor import run_to_completion, zonemap
+from tidb_tpu.executor.builder import build
 from tidb_tpu.executor.fragment import TpuFragmentExec
-from tidb_tpu.executor import fragment
+from tidb_tpu.executor import agg_slabs
 from tidb_tpu.parser import parse
 from tidb_tpu.session import Engine
 from tidb_tpu.util import failpoint
@@ -343,7 +344,7 @@ def test_stale_zone_map_error_is_typed():
     q_dev(s, "SELECT COUNT(*) FROM zm WHERE v >= 3072")   # build zone maps
     ent = next(iter(
         __import__("tidb_tpu.executor.device_cache",
-                   fromlist=["_CACHE"])._CACHE.values()))
+                   fromlist=["CACHE"]).CACHE.values()))
     scan = type("S", (), {"filters": [object()]})()
     failpoint.enable("zone-map-stale", value="boom")
     try:
@@ -367,7 +368,7 @@ def test_spec_cache_evicted_on_compression_flip():
     assert ph.specialization_hits >= 1
 
     def entries():
-        return {k: v.get("lay_sig") for k, v in fragment._SPEC_CACHE.items()
+        return {k: v.get("lay_sig") for k, v in agg_slabs._SPEC_CACHE.items()
                 if len(k) > 2 and k[2] == q}
     on_sigs = entries()
     assert on_sigs and all(sig != "-" for sig in on_sigs.values()), on_sigs

@@ -337,3 +337,39 @@ def packed_slab_bytes(layout: ColLayout, cap: int) -> int:
     word_bytes = 4 * (-(-cap // per))
     base_bytes = 8 if layout.kind == "delta" else 0
     return word_bytes + mask_bytes + base_bytes
+
+
+# ---------------------------------------------------------------------------
+# Wide DECIMAL: limb planes
+# ---------------------------------------------------------------------------
+
+WIDE_LIMB_BITS = 30
+WIDE_LIMB_BASE = 1 << WIDE_LIMB_BITS
+
+
+def wide_decimal_limbs(vals, n_limbs: int) -> np.ndarray:
+    """Arbitrary-precision scaled ints (object array) → (n_limbs, N) int64
+    base-2³⁰ limb planes via shift/mask, so only the TOP limb is signed —
+    value == Σ limbs[k]·2^(30k) exactly. The device-side layout of
+    MyDecimal's word vector (types/mydecimal.go:236-246) as
+    struct-of-arrays; ONE base everywhere (storage planes, on-device
+    splits of narrow inputs, host recombination) so every producer/
+    consumer pair agrees by construction."""
+    out = np.empty((n_limbs, len(vals)), dtype=np.int64)
+    cur = np.asarray(vals, dtype=object)
+    mask = WIDE_LIMB_BASE - 1
+    for k in range(n_limbs - 1):
+        out[k] = (cur & mask).astype(np.int64)
+        cur = cur >> WIDE_LIMB_BITS           # python ints: floor shift
+    out[n_limbs - 1] = cur.astype(np.int64)   # top: small, carries sign
+    return out
+
+
+def wide_decimal_unlimb(limbs: np.ndarray) -> np.ndarray:
+    """(n_limbs, G) int64 limb sums → object array of exact Python ints.
+    Works on UNNORMALIZED limb sums (planes may exceed the base)."""
+    n_limbs, g = limbs.shape
+    out = np.zeros(g, dtype=object)
+    for k in range(n_limbs - 1, -1, -1):
+        out = out * WIDE_LIMB_BASE + limbs[k].astype(object)
+    return out

@@ -20,6 +20,7 @@ from typing import List, Optional
 import numpy as np
 
 from tidb_tpu.errors import DuplicateKeyError
+from tidb_tpu.executor.scan import align_chunk_to_schema
 
 
 DEFAULT_REORG_BATCH = 1 << 16     # ddl/backfilling.go batch-size analog
@@ -37,7 +38,6 @@ def unique_backfill(session, info, cols: List[str], name: str,
     at the end catches duplicates that span batches. Raises
     DuplicateKeyError exactly like the reference's write-reorg dup check
     (ddl/backfilling.go)."""
-    from tidb_tpu.executor.scan import align_chunk_to_schema
     from tidb_tpu.session import _key_tuples
     from tidb_tpu.util import failpoint
 

@@ -1,11 +1,15 @@
 """Volcano executors over Chunks (ref: /root/reference/executor/).
 
 `Executor` mirrors the reference's three-method iterator interface
-(executor/executor.go:259-265: Open / Next(*chunk.Chunk) / Close); `build`
-mirrors executorBuilder.build (executor/builder.go:144), the single seam
-where engines plug in: a PhysTpuFragment node builds a fragment executor
-that runs the whole subtree as one jitted device program instead of a
-CPU operator pipeline.
+(executor/executor.go:259-265: Open / Next(*chunk.Chunk) / Close);
+`builder.build` mirrors executorBuilder.build (executor/builder.go:144),
+the single seam where engines plug in: a PhysTpuFragment node builds a
+fragment executor that runs the whole subtree as one jitted device program
+instead of a CPU operator pipeline.
+
+This module is the base every operator module imports (the context, the
+iterator interface, the operators that need nothing else): it imports no
+other module of the package.
 
 All CPU operators are vectorized numpy over Chunk columns — they are both
 the correctness oracle for the device kernels (the reference's vec-vs-scalar
@@ -19,29 +23,31 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from tidb_tpu import types as T
 from tidb_tpu.chunk import Chunk, Column, DEFAULT_CHUNK_SIZE
-from tidb_tpu.errors import ExecutionError, QueryKilledError
+from tidb_tpu.errors import QueryKilledError
 from tidb_tpu.expression import Expression
 from tidb_tpu.expression.runner import eval_on_chunk, filter_mask
-from tidb_tpu.planner.physical import (PhysDual, PhysHashAgg, PhysHashJoin,
-                                       PhysIndexScan, PhysLimit,
-                                       PhysProjection, PhysSelection,
-                                       PhysSort, PhysTableScan, PhysTopN,
-                                       PhysTpuFragment, PhysUnionAll,
-                                       PhysWindow, PhysicalPlan)
 from tidb_tpu.types import FieldType
+from tidb_tpu.util.escalation import EscalationStats
+from tidb_tpu.util.memory import Tracker
+from tidb_tpu.util.phases import PhaseTimer
 
 
 class ExecContext:
     """Per-statement execution context (ref: sessionctx.Context subset)."""
 
     def __init__(self, txn=None, snapshot=None, vars: Optional[Dict] = None,
-                 guard=None):
-        from tidb_tpu.util.memory import Tracker
+                 guard=None, unscheduled: bool = False):
         self.txn = txn              # storage.Transaction (reads merge staged)
         self.snapshot = snapshot    # storage.Snapshot (autocommit reads)
         self.vars = vars or {}
         self.killed = False
+        # run on the caller's thread as it stands: no admission, no
+        # placement, no batch slot (executor/scheduler.py). The compactor's
+        # warm runs are (delta._warm): a compile inside a slot would hold
+        # every statement up while it lasts
+        self.unscheduled = unscheduled
         # per-statement ExecutionGuard (util/guard.py): kill flag +
         # deadline + root tracker, polled at every checkpoint below
         self.guard = guard
@@ -61,7 +67,6 @@ class ExecContext:
         if guard is not None:
             self.escalation = guard.escalation
         else:
-            from tidb_tpu.util.escalation import EscalationStats
             self.escalation = EscalationStats()
         # per-statement device phase timings + byte/compile ledger
         # (util/phases.py), surfaced in EXPLAIN ANALYZE runtime info,
@@ -71,7 +76,6 @@ class ExecContext:
         if guard is not None and getattr(guard, "phases", None) is not None:
             self.phases = guard.phases
         else:
-            from tidb_tpu.util.phases import PhaseTimer
             self.phases = PhaseTimer()
         self.tracer = None         # Tracer while TRACE runs (trace.go)
 
@@ -84,15 +88,6 @@ class ExecContext:
             raise QueryKilledError("Query execution was interrupted")
         if self.guard is not None:
             self.guard.check(site)
-
-    def device_slot(self):
-        """Admission slot for device dispatch (executor/scheduler.py):
-        one statement enqueues XLA work at a time; host phases and the
-        blocking fetches stay outside so sessions overlap. Queue waits
-        are charged to this statement's guard; KILL/deadline are honored
-        while queued."""
-        from tidb_tpu.executor.scheduler import device_slot
-        return device_slot(self)
 
     def scan_table(self, table_id: int, parts=None):
         """Yield (region_or_None, chunk, alive_mask) honoring txn staging.
@@ -161,7 +156,7 @@ class Executor:
             if ch.num_rows:
                 chunks.append(ch)
         if not chunks:
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         return Chunk.concat(chunks) if len(chunks) > 1 else chunks[0]
 
 
@@ -203,7 +198,7 @@ class MemTableExec(MaterializingExec):
     def _materialize(self) -> Chunk:
         rows = self.plan.rows_fn()
         if not rows:
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         cols = []
         for ci, ft in enumerate(self.schema):
             raw = [ft.encode_value(r[ci]) for r in rows]
@@ -218,7 +213,7 @@ class MemTableExec(MaterializingExec):
         return Chunk(cols)
 
 
-def _empty_chunk(schema: List[FieldType]) -> Chunk:
+def empty_chunk(schema: List[FieldType]) -> Chunk:
     cols = []
     for ft in schema:
         vals = (np.empty(0, dtype=object) if ft.is_varlen
@@ -271,7 +266,6 @@ class DualExec(Executor):
 
 def _dual_chunk(n: int) -> Chunk:
     # a zero-column chunk can't carry a row count; use a hidden const column
-    from tidb_tpu import types as T
     return Chunk([Column(T.bigint(False), np.zeros(n, dtype=np.int64), None)])
 
 
@@ -367,65 +361,3 @@ class UnionAllExec(Executor):
             else:
                 cols.append(Column(ft, col.values, col.validity))
         return Chunk(cols)
-
-
-# ---------------------------------------------------------------------------
-# Builder (ref: executor/builder.go:144 — the engine seam)
-# ---------------------------------------------------------------------------
-
-
-def build(plan: PhysicalPlan) -> Executor:
-    from tidb_tpu.executor.hash_agg import HashAggExec
-    from tidb_tpu.executor.join import HashJoinExec
-    from tidb_tpu.executor.scan import TableScanExec
-    from tidb_tpu.executor.sort import SortExec, TopNExec
-
-    if isinstance(plan, PhysTpuFragment):
-        from tidb_tpu.executor.fragment import TpuFragmentExec
-        return TpuFragmentExec(plan)
-    if isinstance(plan, PhysTableScan):
-        return TableScanExec(plan)
-    if isinstance(plan, PhysIndexScan):
-        from tidb_tpu.executor.index_scan import IndexScanExec
-        return IndexScanExec(plan)
-    from tidb_tpu.planner.physical import (PhysIndexLookupJoin,
-                                           PhysMemTable, PhysMergeJoin)
-    if isinstance(plan, PhysMemTable):
-        return MemTableExec(plan)
-    if isinstance(plan, PhysMergeJoin):
-        from tidb_tpu.executor.merge_join import MergeJoinExec
-        return MergeJoinExec(plan)
-    from tidb_tpu.planner.physical import (PhysIndexOrderedScan,
-                                           PhysStreamAgg)
-    if isinstance(plan, PhysStreamAgg):
-        from tidb_tpu.executor.stream_agg import StreamAggExec
-        return StreamAggExec(plan)
-    if isinstance(plan, PhysIndexOrderedScan):
-        from tidb_tpu.executor.index_scan import IndexOrderedScanExec
-        return IndexOrderedScanExec(plan)
-    if isinstance(plan, PhysIndexLookupJoin):
-        from tidb_tpu.executor.index_join import IndexLookupJoinExec
-        return IndexLookupJoinExec(plan, build(plan.children[0]))
-    if isinstance(plan, PhysDual):
-        return DualExec(plan.schema.field_types, plan.n_rows)
-    kids = [build(c) for c in plan.children]
-    if isinstance(plan, PhysSelection):
-        return SelectionExec(plan.conditions, kids[0])
-    if isinstance(plan, PhysProjection):
-        return ProjectionExec(plan.exprs, plan.schema.field_types, kids[0])
-    if isinstance(plan, PhysHashAgg):
-        return HashAggExec(plan, kids[0])
-    if isinstance(plan, PhysHashJoin):
-        return HashJoinExec(plan, kids[0], kids[1])
-    if isinstance(plan, PhysWindow):
-        from tidb_tpu.executor.window import WindowExec
-        return WindowExec(plan, kids[0])
-    if isinstance(plan, PhysSort):
-        return SortExec(plan.by, plan.descs, kids[0])
-    if isinstance(plan, PhysTopN):
-        return TopNExec(plan.by, plan.descs, plan.offset, plan.count, kids[0])
-    if isinstance(plan, PhysLimit):
-        return LimitExec(plan.offset, plan.count, kids[0])
-    if isinstance(plan, PhysUnionAll):
-        return UnionAllExec(plan.schema.field_types, kids)
-    raise ExecutionError(f"no executor for {type(plan).__name__}")

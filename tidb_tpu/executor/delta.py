@@ -78,8 +78,19 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from tidb_tpu.chunk import compress
 from tidb_tpu.errors import LayoutError
+from tidb_tpu.executor import (ExecContext, device_cache as dc, device_emit,
+                               scheduler)
+from tidb_tpu.executor.scan import align_chunk_to_schema
+from tidb_tpu.ops.jax_env import jax
+from tidb_tpu.sysvars import var_on
+from tidb_tpu.types import fold_ci_array
 from tidb_tpu.util import failpoint, timeline
+from tidb_tpu.util.escalation import pow2
+from tidb_tpu.util.guard import ExecutionGuard
+from tidb_tpu.util.observability import REGISTRY
+from tidb_tpu.util.phases import PhaseTimer
 
 #: the delta slab holds slab_cap // DELTA_CAP_SHARE rows, never fewer
 #: than MIN_DELTA_CAP (both powers of two, as every slab capacity is)
@@ -100,24 +111,19 @@ MAX_STEPS = 64
 _EXT_LOCK = timeline.named_lock("delta_extend")
 
 
-def _var_on(vars_, name: str, default: str = "on") -> bool:
-    return str(vars_.get(name, default)).lower() not in ("off", "0", "false")
-
-
 def delta_capacity(slab_cap: int) -> int:
     return max(MIN_DELTA_CAP, int(slab_cap) // DELTA_CAP_SHARE)
 
 
 def decline(gate: str, table_id) -> None:
     """Count one full rebuild a stale (or unusable) entry fell to."""
-    from tidb_tpu.util.observability import REGISTRY
     REGISTRY.inc("tidb_tpu_delta_declines_total", {"gate": gate})
     if timeline.ENABLED:
         timeline.instant("delta.decline", "delta",
                          args={"gate": gate, "table": table_id})
 
 
-class _Declined(Exception):
+class Declined(Exception):
     def __init__(self, gate: str):
         super().__init__(gate)
         self.gate = gate
@@ -165,7 +171,7 @@ def _positions(segs, rows: np.ndarray):
 def _diff(ent, td, scope):
     """What changed between the entry's ledger and `td` → (appended
     [(region, row_start, row_stop, delta offset)], dead base positions,
-    dead delta positions, seen, rowmap, delta_rows). Raises _Declined."""
+    dead delta positions, seen, rowmap, delta_rows). Raises Declined."""
     seen, rowmap = dict(ent.seen), dict(ent.rowmap)
     cursor = ent.delta_rows
     appended, dead_base, dead_delta = [], [], []
@@ -178,7 +184,7 @@ def _diff(ent, td, scope):
             if r.id <= ent.max_rid:
                 # an OLD region this build never saw — deletes reset its
                 # partition tag to None, pulling it into scope
-                raise _Declined("region-rescoped")
+                raise Declined("region-rescoped")
             n = r.num_rows
             appended.append((r, 0, n, cursor))
             rowmap[r.id] = ((0, n, True, cursor, None),)
@@ -193,12 +199,12 @@ def _diff(ent, td, scope):
             continue
         n_prev = prev.num_rows
         if r.num_rows < n_prev:
-            raise _Declined("region-shrank")
+            raise Declined("region-shrank")
         grew = r.num_rows - n_prev
         if r.live_rows - grew != prev.live_rows:
             now = np.asarray(r.deleted[:n_prev])
             if prev.live_rows != n_prev and (prev.deleted & ~now).any():
-                raise _Declined("row-resurrected")
+                raise Declined("row-resurrected")
             fresh = np.flatnonzero(now & ~prev.deleted) \
                 if prev.live_rows != n_prev else np.flatnonzero(now)
             if fresh.size:
@@ -215,7 +221,7 @@ def _diff(ent, td, scope):
             cursor += grew
         seen[r.id] = r
     if len(present) != len(seen):
-        raise _Declined("regions-rewritten")
+        raise Declined("regions-rewritten")
     cat = lambda parts: (np.concatenate(parts).astype(np.int64)  # noqa: E731
                          if parts else np.empty(0, dtype=np.int64))
     return appended, cat(dead_base), cat(dead_delta), seen, rowmap, cursor
@@ -224,7 +230,6 @@ def _diff(ent, td, scope):
 def _rows_of(appended, scan, col_idx: int):
     """ONE column of the appended rows → (vals, valid), aligned to the
     scan's schema (DDL-padded)."""
-    from tidb_tpu.executor.scan import align_chunk_to_schema
     vals, valid = [], []
     for r, start, stop, _off in appended:
         col = align_chunk_to_schema(r.chunk, scan.table).columns[col_idx]
@@ -242,16 +247,14 @@ def _raw_chunk(ent, scan, i: int, ftype, appended):
     from tidb_tpu.ops.jax_env import device_float_dtype
     vals, valid = _rows_of(appended, scan, i)
     if ftype.is_wide_decimal:
-        from tidb_tpu.executor.device_cache import wide_decimal_limbs
-        return wide_decimal_limbs(vals, ftype.wide_limb_count), valid
+        return compress.wide_decimal_limbs(vals, ftype.wide_limb_count), valid
     if ftype.is_varlen:
         dictionary = ent.dicts.get(i)
         if dictionary is None:
-            raise _Declined("dictionary")
+            raise Declined("dictionary")
         folded = np.array([str(v) for v in vals], dtype=object)
         keys = dictionary
         if ftype.is_ci:
-            from tidb_tpu.types import fold_ci_array
             folded, keys = fold_ci_array(folded), fold_ci_array(dictionary)
         codes = np.searchsorted(keys, folded).astype(np.int32) \
             if len(keys) else np.zeros(len(folded), dtype=np.int32)
@@ -259,7 +262,7 @@ def _raw_chunk(ent, scan, i: int, ftype, appended):
             hit = np.clip(codes, 0, max(len(keys) - 1, 0))
             if not len(keys) or (keys[hit[valid]] != folded[valid]).any():
                 # its code would mean another string to every program
-                raise _Declined("dictionary")
+                raise Declined("dictionary")
         return np.where(valid, codes, 0).astype(np.int32), valid
     if vals.dtype == np.dtype(np.float64):
         return vals.astype(np.dtype(device_float_dtype())), valid
@@ -300,9 +303,8 @@ def _widen_for(ent, i: int, v: np.ndarray, m: np.ndarray) -> None:
 def _pad_chunk(v: np.ndarray, m: np.ndarray):
     """Appended rows as the append program takes them: values and
     validity padded with zeros to a power-of-two bucket of rows."""
-    from tidb_tpu.executor.device_cache import _pow2
     n = int(m.shape[0])
-    bucket = _pow2(n, MIN_BUCKET)
+    bucket = pow2(n, MIN_BUCKET)
     pv = np.zeros(v.shape[:-1] + (bucket,), dtype=v.dtype)
     pv[..., :n] = v
     pm = np.zeros(bucket, dtype=bool)
@@ -310,13 +312,12 @@ def _pad_chunk(v: np.ndarray, m: np.ndarray):
     return pv, pm
 
 
-def _pad_idx(pos: np.ndarray, cap: int) -> np.ndarray:
+def pad_idx(pos: np.ndarray, cap: int) -> np.ndarray:
     """Positions as the mask programs take them: int32, padded with `cap`
     (dropped by the scatter) to a power-of-two bucket, or empty."""
-    from tidb_tpu.executor.device_cache import _pow2
     if not pos.size:
         return np.empty(0, dtype=np.int32)
-    out = np.full(_pow2(pos.size, MIN_BUCKET), cap, dtype=np.int32)
+    out = np.full(pow2(pos.size, MIN_BUCKET), cap, dtype=np.int32)
     out[:pos.size] = pos
     return out
 
@@ -343,7 +344,6 @@ def extend_entry(ctx, scan, ent, max_slab: int, phases=None,
     extends, the others take what it made), else None; `then(generation)`
     is run before the next statement gets its turn (the install: a waiting
     statement must find what this one made)."""
-    from tidb_tpu.util.phases import PhaseTimer
     corrupted = failpoint.inject("delta-merge-stale")
     if corrupted is not None:
         raise LayoutError(
@@ -361,7 +361,7 @@ def extend_entry(ctx, scan, ent, max_slab: int, phases=None,
             return got
         except LayoutError:
             raise
-        except _Declined as d:
+        except Declined as d:
             if not quiet:
                 decline(d.gate, scan.table.id)
         except Exception:  # noqa: BLE001 — extension is best-effort:
@@ -373,19 +373,16 @@ def extend_entry(ctx, scan, ent, max_slab: int, phases=None,
 
 
 def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
-    from tidb_tpu.executor import device_cache as dc
-    from tidb_tpu.executor import device_emit
-    from tidb_tpu.util.observability import REGISTRY
     table_id = scan.table.id
     td = ctx.snapshot.table_data(table_id)
     if td is None or ent.seen is None or not ent.dev:
-        raise _Declined("no-coverage")
+        raise Declined("no-coverage")
     pruned = getattr(scan, "partitions", None)
     scope = None if pruned is None else set(pruned)
     resident = sorted(ent.dev)
     ftypes = scan.schema.field_types
     if any(i >= len(ftypes) for i in resident):
-        raise _Declined("schema")
+        raise Declined("schema")
     with timeline.span("delta.diff", "delta", table=table_id,
                        regions=len(td.regions)):
         appended, dead_base, dead_delta, seen, rowmap, cursor = \
@@ -394,7 +391,7 @@ def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
     n_new = cursor - ent.delta_rows
     dcap = ent.delta_cap or delta_capacity(cap)
     if cursor > dcap:
-        raise _Declined("delta-full")
+        raise Declined("delta-full")
     changed = bool(n_new or dead_base.size or dead_delta.size)
 
     new = dc.CachedTable(td, ent.max_slab, ent.total + n_new
@@ -496,7 +493,7 @@ def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
                            slab=int(dead_base[0] // cap),
                            tombs=int(dead_base.size), touched=touched,
                            rows=cap * touched, table=table_id):
-            idx = _pad_idx(dead_base, cap * base_slabs)
+            idx = pad_idx(dead_base, cap * base_slabs)
             alive.set_stack(device_emit.emit_alive_update(
                 alive.stack_leaf(), none, idx, cap, stacked=True))
         h2d += idx.nbytes
@@ -508,13 +505,13 @@ def _extend_locked(ctx, scan, ent, max_slab, ph, masked=False):
         with timeline.span("delta.tombstone", "delta", slab=int(s),
                            tombs=int(pos.size), rows=cap,
                            table=table_id):
-            idx = _pad_idx(pos, cap)
+            idx = pad_idx(pos, cap)
             alive[s] = device_emit.emit_alive_update(alive[s], none, idx,
                                                      cap)
         h2d += idx.nbytes
     if n_new or dead_delta.size:
-        born = _pad_idx(np.arange(ent.delta_rows, cursor), dcap)
-        idx = _pad_idx(dead_delta, dcap)
+        born = pad_idx(np.arange(ent.delta_rows, cursor), dcap)
+        idx = pad_idx(dead_delta, dcap)
         with timeline.span("delta.tombstone", "delta", slab=base_slabs,
                            tombs=int(dead_delta.size), rows=dcap,
                            table=table_id):
@@ -569,7 +566,6 @@ def base_masks(ent, table_id) -> "dc.SlabColumn":
     so has the program that rewrites them (a warm-up that ran it over a
     mask a slab would leave the stacked one to compile inside some later
     window). Else a mask a slab."""
-    from tidb_tpu.executor import device_cache as dc, device_emit
     cap, n = ent.slab_cap, ent.base_slabs
     if n > 1 and ent.owners is None and not ent.lost:
         return dc.SlabColumn.born_stacked(
@@ -583,8 +579,6 @@ def base_masks(ent, table_id) -> "dc.SlabColumn":
 
 def _on_slab(ent, s: int, fn, *args):
     """Run `fn` on the device that owns slab `s` of a pod entry."""
-    from tidb_tpu.executor import device_cache as dc
-    from tidb_tpu.ops.jax_env import jax
     d = ent.owners[s] if ent.owners is not None and s < len(ent.owners) \
         else ent.device
     h = dc.device_handle(d) if (ent.owners is not None or d) else None
@@ -607,7 +601,6 @@ def _dead_rows_by_region(ent, seen):
 def delta_column(ent, scan, i: int, ftype):
     """The delta slab of a column the generation did not hold yet, from
     the rows its ledger says were appended → (vals, mask) on the device."""
-    from tidb_tpu.executor import device_emit
     appended = sorted(
         ((ent.seen[rid], start, stop, off)
          for rid, segs in ent.rowmap.items()
@@ -615,7 +608,7 @@ def delta_column(ent, scan, i: int, ftype):
         key=lambda t: t[3])
     rows = appended or _one_row(ent.seen)
     if not rows:
-        raise _Declined("no-coverage")
+        raise Declined("no-coverage")
     v, m = _raw_chunk(ent, scan, i, ftype, rows)
     if not appended:
         v, m = v[..., :0], m[:0]    # (only rows died so far: it is empty)
@@ -676,7 +669,7 @@ def schedule_compaction(store, key, scan, cols, max_slab: int,
            "cause": cause}
     with _PENDING_LOCK:
         _PENDING[key] = job
-    if _var_on(vars_, "tidb_tpu_compaction"):
+    if var_on(vars_, "tidb_tpu_compaction"):
         _ensure_worker()
 
 
@@ -757,10 +750,6 @@ def _compact_one(job) -> bool:
     `compaction-commit` failpoint sits between the finished rebuild and
     the swap: a fault there deletes the rebuilt buffers and leaves the
     old generation serving byte-exactly."""
-    from tidb_tpu.executor import ExecContext
-    from tidb_tpu.executor import device_cache as dc
-    from tidb_tpu.executor.scheduler import SCHEDULER
-    from tidb_tpu.util.phases import PhaseTimer
     store = job["store"]()
     if store is None:
         return False
@@ -770,8 +759,8 @@ def _compact_one(job) -> bool:
     td = snapshot.table_data(table_id)
     if td is None:
         return False
-    with dc._LOCK:
-        cur = dc._CACHE.get(key)
+    with dc.LOCK:
+        cur = dc.CACHE.get(key)
     if cur is None or not getattr(cur, "is_delta", False):
         return False    # evicted, or already rebuilt fresh — nothing to do
     guard = _IdleGuard()
@@ -783,16 +772,16 @@ def _compact_one(job) -> bool:
         # slot is for dispatching programs, the rebuild dispatches none,
         # and held through the encode it kept every statement waiting for
         # as long as the rebuild took (0.5 s a table of 0.6M rows, chip)
-        with SCHEDULER.slot(guard=guard, conn_id=guard.conn_id):
+        with scheduler.SCHEDULER.slot(guard=guard, conn_id=guard.conn_id):
             pass
         with timeline.span("compact.run", "delta", table=table_id,
                            cause=job.get("cause", ""),
                            rows=int(td.live_rows)):
             ctx = ExecContext(snapshot=snapshot, vars=dict(job["vars"]))
             ph = PhaseTimer()
-            parts, total, cov, max_rid = dc._collect_parts(ctx, scan,
+            parts, total, cov, max_rid = dc.collect_parts(ctx, scan,
                                                            coverage=True)
-            slab_cap = dc._pow2(min(total, job["max_slab"])) if total \
+            slab_cap = pow2(min(total, job["max_slab"]), lo=1024) if total \
                 else 1024
             n_slabs = (total + slab_cap - 1) // slab_cap
             new = dc.CachedTable(td, job["max_slab"], total, slab_cap,
@@ -800,8 +789,7 @@ def _compact_one(job) -> bool:
                                  compressed=cur.compressed)
             new.device = getattr(cur, "device", 0)
             if new.device < 0:
-                from tidb_tpu.executor import scheduler as _sched
-                nd = max(_sched.pool_devices(ctx), 1)
+                nd = max(scheduler.pool_devices(ctx), 1)
                 new.owners = [min(s * nd // max(n_slabs, 1), nd - 1)
                               for s in range(n_slabs)]
             new.set_coverage(cov, max_rid)
@@ -815,24 +803,24 @@ def _compact_one(job) -> bool:
                         # _col_prep re-runs choose_layout under the CURRENT
                         # workload hints — the compaction-time layout
                         # re-search of arXiv 2112.13099
-                        preps[i] = dc._col_prep(new, i, ftypes[i])
+                        preps[i] = dc.col_prep(new, i, ftypes[i])
                         _keep_what_still_fits(preps[i], cur, i)
                         new.dicts[i] = preps[i]["dict"]
                         new.bounds[i] = preps[i]["bounds"]
                         new.layouts[i] = preps[i]["layout"]
                         if new.compressed:
-                            zm = dc._col_zone_stats(new, preps[i])
+                            zm = dc.col_zone_stats(new, preps[i])
                             if zm is not None:
                                 new.zmaps[i] = zm
-                    for _ in dc._stream_slabs(ctx, new, None, cols, preps, ph):
+                    for _ in dc.stream_slabs(ctx, new, None, cols, preps, ph):
                         pass
             timeline.tag(slabs=n_slabs)
             pv = _warm(store, key, scan, new, job["max_slab"])
             new = pv.ent
             failpoint.inject("compaction-commit")
             with timeline.span("compact.swap", "delta", table=table_id):
-                with dc._LOCK:
-                    installed = dc._CACHE.get(key)
+                with dc.LOCK:
+                    installed = dc.CACHE.get(key)
                     if installed is None or \
                             installed.lineage != cur.lineage:
                         # evicted, or rebuilt by a statement meanwhile:
@@ -841,14 +829,13 @@ def _compact_one(job) -> bool:
                     dc.install_preview(pv)
                 # the replaced generation's buffers free NOW unless a live
                 # statement still computes on them (protect discipline)
-                dc._safe_delete(installed, key[1:3])
+                dc.safe_delete(installed, key[1:3])
     except BaseException:
         if new is not None:
             new.delete()    # exclusively owned — frees HBM immediately
         for built in (pv.aligned.values() if pv is not None else ()):
             built.delete()
         raise
-    from tidb_tpu.util.observability import REGISTRY
     REGISTRY.inc("tidb_tpu_compactions_total",
                  {"table": str(table_id), "cause": job.get("cause", "")})
     return True
@@ -865,13 +852,12 @@ def _warm(store, key, scan, new, max_slab: int):
     reader whose statement's text is known runs TWICE, under a guard that
     carries the text: the first run settles the digest's specialization
     over the rebuilt shapes, the second is the ONE statement program the
-    statements after the swap will launch (`fragment._StatementProgram`).
+    statements after the swap will launch (`agg_slabs._StatementProgram`).
+    How a reader is run again came with it (`device_cache.note_reader`:
+    the executor above the cache hands the cache a callable, the cache's
+    write path imports nothing above itself).
     A reader that cannot be warmed is skipped: the statement then pays
     what it would have paid. → the preview to install."""
-    from tidb_tpu.executor import ExecContext
-    from tidb_tpu.executor import device_cache as dc
-    from tidb_tpu.executor.fragment import TpuFragmentExec
-    from tidb_tpu.util.guard import ExecutionGuard
     pv = dc.Preview(key, new)
     table_id = scan.table.id
 
@@ -885,7 +871,7 @@ def _warm(store, key, scan, new, max_slab: int):
 
     # (a pod entry's slabs live on several devices under placement the
     # statements' admission makes: not warmed)
-    for plan, vars_, sql in \
+    for plan, vars_, sql, run in \
             dc.readers(key[1], table_id) if key[0] >= 0 else ():
         # a plain generation (nothing written meanwhile) is read a second
         # time under masks and with an empty delta slab: the variants the
@@ -900,10 +886,8 @@ def _warm(store, key, scan, new, max_slab: int):
             # connections have made of the other tables by now)
             for _ in range(2):
                 guard = ExecutionGuard(sql=sql) if sql else None
-                ctx = ExecContext(
-                    snapshot=store.snapshot(),
-                    vars={**vars_, "tidb_tpu_scheduler": "off"},
-                    guard=guard)
+                ctx = ExecContext(snapshot=store.snapshot(), vars=vars_,
+                                  guard=guard, unscheduled=True)
                 ctx.phases.device_index = key[0]    # the entry's device
                 if guard is not None:
                     guard.device_index = key[0]
@@ -920,10 +904,8 @@ def _warm(store, key, scan, new, max_slab: int):
                                               masked=True,
                                               private=True) or swap
                     for _rep in range(2 if sql else 1):
-                        ex = TpuFragmentExec(plan)
-                        ex.open(ctx)
-                        with pv, ex._protect_tables():
-                            ex._run_device()
+                        with pv:
+                            run(plan, ctx)
                 except Exception as e:  # noqa: BLE001 — best effort
                     timeline.tag(skipped=type(e).__name__)
                 finally:

@@ -62,20 +62,21 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from tidb_tpu.util import timeline
+from tidb_tpu.chunk import Column, compress
+from tidb_tpu.chunk.device import encode_strings
+from tidb_tpu.errors import DeviceLost, LayoutError
+from tidb_tpu.executor import device_emit, scheduler, zonemap
+from tidb_tpu.executor.scan import align_chunk_to_schema
+from tidb_tpu.ops.jax_env import jax, jnp, lax
+from tidb_tpu.storage import Snapshot
+from tidb_tpu.sysvars import var_int, var_on
+from tidb_tpu.types import fold_ci_array
+from tidb_tpu.util import failpoint, phases as _ph, timeline
+from tidb_tpu.util.escalation import pow2
+from tidb_tpu.util.observability import REGISTRY, first_touch
+from tidb_tpu.util.phases import PhaseTimer
 
 MAX_CACHED_TABLES = 4       # PER DEVICE — each pool member's own cap
-# HBM budget for the table cache (v5e has 16 GiB; leave headroom for the
-# programs' working set). Exceeding it evicts LRU tables — the memory
-# Tracker analog for device residency (util/memory/tracker.go). Like the
-# entry cap, the budget is per device.
-DEFAULT_HBM_BUDGET_BYTES = 8 << 30
-# pod partitioning threshold: tables at or above this many rows (by the
-# region ledger's approximate count, available before any host collect)
-# partition their slab ranges across the pool instead of replicating —
-# a per-device replica of a fact table would blow every device's budget
-# for no locality win
-DEFAULT_PARTITION_MIN_ROWS = 1 << 22
 # older generations kept behind a key's newest one, for the statement whose
 # snapshot another connection's commit-and-read has overtaken. ONE: under
 # three query streams beside a refresh stream a statement arrives behind the
@@ -101,9 +102,9 @@ class Stacked:
     table whole asks for rows it knows (`WholeColumn`). Each is `slab(row)`:
     an index of the stack inside the fusion that reads the slab. `shape`:
     the leaf's own in ONE slab — a stack holds a 1-D leaf folded
-    (`_folded`), and a slab of it is reshaped back, which is a bitcast. A
-    pytree node: the array and `row` (or None) are its children, `which`
-    and `shape` static."""
+    (`device_emit.folded`), and a slab of it is reshaped back, which is a
+    bitcast. A pytree node: the array and `row` (or None) are its
+    children, `which` and `shape` static."""
 
     __slots__ = ("a", "row", "which", "shape")
 
@@ -116,10 +117,10 @@ class Stacked:
     def slab(self, row):
         """Row `row` of the stack — a traced scalar, or a number — as the
         leaf in its own shape (traced)."""
-        from tidb_tpu.ops.jax_env import lax
-        return _unfold(self.a[row] if isinstance(row, int) else
-                       lax.dynamic_index_in_dim(self.a, row, 0,
-                                                keepdims=False), self.shape)
+        return device_emit.unfold(
+            self.a[row] if isinstance(row, int) else
+            lax.dynamic_index_in_dim(self.a, row, 0, keepdims=False),
+            self.shape)
 
 
 _NODES: set = set()
@@ -127,10 +128,9 @@ _NODES: set = set()
 
 def _node(cls):
     """`cls` — `Stacked`, `WholeColumn` — registered as a pytree node on
-    first use (jax is not imported with this module): `children` → (its
+    first use: `children` → (its
     arrays, what is static), `cls(*children, *static)` back."""
     if cls not in _NODES:
-        from tidb_tpu.ops.jax_env import jax
         jax.tree_util.register_pytree_node(
             cls, cls.children, lambda aux, kids: cls(*kids, *aux))
         _NODES.add(cls)
@@ -143,11 +143,10 @@ def in_place(tree, picks=(), k=None):
     reads them: a `Stacked` leaf as its slab (the row it came with, else
     turn `k`'s of a statement program's `picks`), a `WholeColumn` as its
     list of slabs. The statement programs' loop
-    (`fragment._StatementProgram`), the slab programs
-    (`fragment._Program._partial`) and the tree programs
+    (`agg_slabs._StatementProgram`), the slab programs
+    (`agg_slabs._FragmentProgram._partial`) and the tree programs
     (`TreeProgram._run`) call it first; anything else is handed `col[s]`,
     a copy (`tidb_tpu_slab_slices_total`)."""
-    from tidb_tpu.ops.jax_env import jax
 
     def read(x):
         if isinstance(x, WholeColumn):
@@ -161,42 +160,6 @@ def in_place(tree, picks=(), k=None):
         read, tree, is_leaf=lambda x: isinstance(x, (WholeColumn, Stacked)))
 
 
-LANES = 128     # the minor dimension of a device tile
-
-
-def _folded(shape: tuple) -> tuple:
-    """The shape a slab's leaf has inside its column's stack. The device
-    tiles an array's two minor dimensions: stacked as (slabs, rows), the
-    SLAB axis of a 1-D leaf would lie inside a tile — padded to 8 (six
-    slabs take the HBM of eight) and every slab read with a stride. Folded
-    to (rows / 128, 128) the slab axis stays outside, a slab is as
-    contiguous as it was alone, and slab ↔ its 1-D form is a bitcast
-    (compiled for the v5e: `tests/test_tpu_compile.py`). A length that is
-    no multiple of 128 (a small table's) is padded up to one: its slab is
-    the fold's first rows."""
-    if len(shape) != 1:
-        return shape
-    return (-(-shape[0] // LANES), LANES)
-
-
-def _fold(a):
-    """One slab's leaf as its column's stack holds it (`_folded`)."""
-    if a.ndim != 1:
-        return a
-    from tidb_tpu.ops.jax_env import jnp
-    pad = -a.shape[0] % LANES
-    return (jnp.pad(a, (0, pad)) if pad else a).reshape(_folded(a.shape))
-
-
-def _unfold(a, shape: tuple):
-    """A slab of a stack → the leaf in its own shape (a bitcast, and a
-    cut where the fold was padded)."""
-    if len(shape) != 1:
-        return a
-    a = a.reshape(-1)
-    return a if a.shape[0] == shape[0] else a[:shape[0]]
-
-
 def _stack_programs(n: int, shape: tuple, dtype: str):
     """→ (`first`, `put`): a new stack of `n` slabs of one leaf with its
     first slab in row 0 (allocated where that slab lies: the entry's
@@ -204,19 +167,18 @@ def _stack_programs(n: int, shape: tuple, dtype: str):
     the write is in place and a fill never holds two stacks (names of
     their own kind, `slab_stack_<sig8>`; which row is an argument: one
     `put` a leaf)."""
-    from tidb_tpu.executor import device_emit
-    from tidb_tpu.ops.jax_env import jnp, lax
     key = (n, tuple(shape), dtype)
 
     def _put(stack, slab, row):
-        return lax.dynamic_update_index_in_dim(stack, _fold(slab), row, 0)
+        return lax.dynamic_update_index_in_dim(
+            stack, device_emit.fold(slab), row, 0)
 
     def _first(slab):
-        return _put(jnp.zeros((n,) + _folded(tuple(shape)), dtype=dtype),
-                    slab, 0)
-    return (device_emit._delta_program("slab_stack", ("first",) + key,
+        return _put(jnp.zeros((n,) + device_emit.folded(tuple(shape)),
+                              dtype=dtype), slab, 0)
+    return (device_emit.delta_program("slab_stack", ("first",) + key,
                                        lambda: _first),
-            device_emit._delta_program("slab_stack", ("put",) + key,
+            device_emit.delta_program("slab_stack", ("put",) + key,
                                        lambda: _put, donate_argnums=(0,)))
 
 
@@ -225,7 +187,6 @@ def _write_slab(write, *args):
     the stack, WRITTEN: dispatch runs ahead, and every slab a fill
     uploaded before its first write ended would lie beside the stack at
     once."""
-    from tidb_tpu.ops.jax_env import jax
     return jax.block_until_ready(write(*args))
 
 
@@ -247,7 +208,6 @@ def _fill(col: list, device):
     device at any moment: the leaf's bytes and at most one slab's more.
     The arrays are dropped, never `.delete()`d: a launch in flight that
     took one still reads it."""
-    from tidb_tpu.ops.jax_env import jax
     first, put = _stack_programs(len(col), tuple(col[0].shape),
                                  str(col[0].dtype))
     # (the stack is committed to its device iff its slabs were — an
@@ -287,7 +247,6 @@ def _count_slice(b: "_BaseSlabs") -> None:
     arrays, for a per-slab consumer. Always-on counter
     `tidb_tpu_slab_slices_total{table=}`: 0 in a warm window of statements
     that run `launch_plan=whole` (nothing hides a copy a statement)."""
-    from tidb_tpu.util.observability import REGISTRY
     REGISTRY.inc("tidb_tpu_slab_slices_total", {"table": b.table})
 
 
@@ -305,8 +264,9 @@ class _BaseSlabs:
     def __init__(self, slabs):
         self.slabs = list(slabs)    # (None once stacked)
         # once stacked: the slab pytree's leaves, each with a leading axis
-        # of the RESIDENT slabs and its 1-D rows folded (`_folded`; a leaf
-        # every slab's tuple shares — a dictionary — as it is: `shared`),
+        # of the RESIDENT slabs and its 1-D rows folded
+        # (`device_emit.folded`; a leaf every slab's tuple shares — a
+        # dictionary — as it is: `shared`),
         # each leaf's own shape in one slab, the slab's tree structure,
         # and slab → row of the stack (None: a hole)
         self.leaves: Optional[list] = None
@@ -388,7 +348,6 @@ class SlabColumn:
         """A one-leaf column (a generation's liveness masks) whose base
         slabs a program MADE as one array (`stack`: slabs × a slab's leaf
         of `shape`, folded): nothing to fill, nothing crosses the host."""
-        from tidb_tpu.ops.jax_env import jax
         b = _BaseSlabs(())
         b.slabs, b.leaves, b.shared = None, [stack], (False,)
         b.shapes, b.treedef = (tuple(shape),), jax.tree.structure(0)
@@ -436,7 +395,7 @@ class SlabColumn:
         if row is None:
             return None
         _count_slice(b)
-        return b.tree(lambda a, shape: _unfold(a[row], shape))
+        return b.tree(lambda a, shape: device_emit.unfold(a[row], shape))
 
     def __setitem__(self, s: int, t) -> None:
         slabs = self.base.lists()
@@ -507,8 +466,6 @@ class SlabColumn:
         if slabs is None or len(slabs) < 2:
             return
         del slabs
-        from tidb_tpu.ops.jax_env import jax
-        from tidb_tpu.util.observability import REGISTRY
         with _STACK_LOCK:
             slabs = b.slabs         # (under the lock: never in transit)
             if slabs is None:
@@ -587,7 +544,6 @@ class SlabColumn:
         row = b.pos[s]
         idx = b.row_dev.get(row)
         if idx is None:
-            from tidb_tpu.ops.jax_env import jax
             idx = b.row_dev[row] = jax.device_put(
                 np.int32(row), next(iter(b.leaves[0].devices())))
         node = _node(Stacked)
@@ -622,7 +578,6 @@ class WholeColumn:
         return (self.base, self.delta), (self.rows,)
 
     def slabs(self) -> list:
-        from tidb_tpu.ops.jax_env import jax
         is_stack = lambda x: isinstance(x, Stacked)     # noqa: E731
         out = [None if row is None else jax.tree.map(
             lambda x, row=row: x.slab(row) if is_stack(x) else x,
@@ -654,7 +609,7 @@ class SlabPicks:
         if ent.owners is None and not getattr(ent, "lost", None):
             # (slabs on several devices are no one array, and a lost
             # slab's refill writes a slab: such an entry keeps its lists —
-            # and takes the per-slab plan, `fragment._launch_plan`)
+            # and takes the per-slab plan, `agg_slabs._launch_plan`)
             try:
                 col.stack(self.table)
             except BaseException:
@@ -793,7 +748,7 @@ class CachedTable:
         # col → ColLayout for packed columns; None/absent = raw layout
         self.layouts: Dict[int, Optional[object]] = {}
         # col → (lo, hi) over valid values; None for floats/empty — feeds
-        # the perfect-hash group-by domain gate (fragment._agg_key_bounds)
+        # the perfect-hash group-by domain gate (agg_slabs.chain_key_bounds)
         self.bounds: Dict[int, Optional[Tuple[int, int]]] = {}
         # col → zonemap.ColumnZoneMap (compressed tables only): the
         # per-slab min/max/null-count ledger the host-side slab pruner
@@ -806,7 +761,7 @@ class CachedTable:
         self.holes: Dict[int, frozenset] = {}
 
     def set_coverage(self, cov, max_rid: int) -> None:
-        """Adopt a base build's coverage ledger (`_collect_parts`)."""
+        """Adopt a base build's coverage ledger (`collect_parts`)."""
         from tidb_tpu.executor import delta
         self.cov, self.max_rid = cov, max_rid
         self.seen, self.rowmap = delta.ledger_from_coverage(cov) \
@@ -825,7 +780,6 @@ class CachedTable:
         generation and `key` (two statements racing upload twice)."""
         got = self.live_dev.get(key)
         if got is None:
-            from tidb_tpu.ops.jax_env import jax
             got = self.live_dev[key] = jax.device_put(
                 make(), device_handle(self.device))
         return got
@@ -835,7 +789,6 @@ class CachedTable:
         upload a base build and distinct set, none a statement or commit."""
         got = self.pick_dev.get(rows)
         if got is None:
-            from tidb_tpu.ops.jax_env import jax
             got = self.pick_dev[rows] = jax.device_put(
                 np.asarray(rows, dtype=np.int32), device_handle(self.device))
         return got
@@ -862,7 +815,6 @@ class CachedTable:
         prefix where the generation holds none."""
         if self.alive is not None:
             return self.alive[s]
-        from tidb_tpu.executor import device_emit
         return device_emit.emit_alive_init(self.slab_rows(s),
                                            self.slab_shape(s)[0])
 
@@ -901,7 +853,6 @@ class CachedTable:
     def logical_bytes(self, cols=None) -> int:
         """Bytes the selected columns WOULD occupy uncompressed (raw
         columns and the raw delta slab: physical == logical)."""
-        from tidb_tpu.chunk import compress
         total = 0
         for i, slabs in self.dev.items():
             if cols is not None and i not in cols:
@@ -939,7 +890,6 @@ def _entry_delete(ent) -> None:
     """Free an evicted entry's device buffers (tolerates test doubles
     that stub hbm_bytes() without delete())."""
     if timeline.ENABLED:
-        from tidb_tpu.util import phases as _ph
         cur = _ph.current()
         try:
             freed = int(ent.hbm_bytes())
@@ -992,7 +942,7 @@ def _keep_behind(newest, older, arrays, live=None) -> None:
 def _install_generation(tbl, key, cur, new, same, version, arrays,
                         live=None):
     """`new` takes its place under `key` of `tbl`, whose entry `cur` is
-    None or of `new`'s base build (called under `_LOCK`) → (the generation
+    None or of `new`'s base build (called under `LOCK`) → (the generation
     to serve, "newest" | "kept"). Newer than the entry: it becomes the
     entry, and the entry what is kept behind it. Older (its statement
     waited while others stepped the cache past its snapshot): it serves
@@ -1058,7 +1008,6 @@ def _commits_to(ctx, store, table_id: int, base, td, version: int) -> list:
          if t is not base.td]
     if len(path) < 2 or path[-1][1] is not td:
         return [ctx]
-    from tidb_tpu.storage import Snapshot
     return [_OneCommit(ctx, Snapshot({table_id: t}, v, store))
             for v, t in path[:-1]] + [ctx]
 
@@ -1072,9 +1021,8 @@ def _store_holds(store, table_id: int):
 
 def _note_kept() -> None:
     """The gauges: generations kept behind the newest ones, and the bytes
-    they own (called under `_LOCK`)."""
-    from tidb_tpu.util.observability import REGISTRY
-    ents = [e for e in list(_CACHE.values()) + list(_ALIGNED.values())
+    they own (called under `LOCK`)."""
+    ents = [e for e in list(CACHE.values()) + list(_ALIGNED.values())
             if getattr(e, "kept", None)]    # (tests' doubles have none)
     REGISTRY.set_gauge("tidb_tpu_delta_generations_kept",
                        sum(len(e.kept) for e in ents))
@@ -1085,21 +1033,20 @@ def _note_kept() -> None:
 def _count_read(age: str) -> None:
     """What a cached read was served from: the key's `newest` generation,
     one `kept` behind it, or a plain table `rebuilt` beside the cache."""
-    from tidb_tpu.util.observability import REGISTRY
     REGISTRY.inc("tidb_tpu_delta_generation_reads_total", {"age": age})
 
 
-_CACHE: "OrderedDict[int, CachedTable]" = OrderedDict()
+CACHE: "OrderedDict[int, CachedTable]" = OrderedDict()
 # FK-aligned join structures (see AlignedJoin below); keyed by join path
 _ALIGNED: "OrderedDict[tuple, AlignedJoin]" = OrderedDict()
 
-# ONE lock for all shared device-cache state (_CACHE, _ALIGNED, the
+# ONE lock for all shared device-cache state (CACHE, _ALIGNED, the
 # protection registry, eviction). RLock because eviction helpers are
 # reachable from paths that already hold it. Expensive work — host scans,
 # encoding, uploads, LUT builds — happens OUTSIDE the lock; only dict
 # lookups/insertions/evictions are serialized, so concurrent first
 # touches of DIFFERENT tables still overlap.
-_LOCK = timeline.named_lock("device_cache", reentrant=True)
+LOCK = timeline.named_lock("device_cache", reentrant=True)
 
 # thread ident → frozenset of (store_id, table_id) pairs that thread's
 # in-flight statement is actively computing on. The per-THREAD successor
@@ -1143,51 +1090,53 @@ def _previewing() -> Optional[Preview]:
     return getattr(_PREVIEW, "cur", None)
 
 
-def note_reader(store_id: int, table_ids, plan, vars_, what) -> None:
+def note_reader(store_id: int, table_ids, plan, vars_, what, run) -> None:
     """Remember `plan` (a device fragment that just ran) as a reader of
-    its tables: what a compaction warms before its swap. `what` tells one
-    reader from another (the statement's text and the fragment's root: a
-    table that moves is re-planned at every statement, and the newest
-    plan of a statement stands for the older ones)."""
+    its tables: what a compaction warms before its swap, by `run(plan,
+    ctx)` — the executor's own way of running it once more. `what` tells
+    one reader from another (the statement's text and the fragment's
+    root: a table that moves is re-planned at every statement, and the
+    newest plan of a statement stands for the older ones)."""
     if _previewing() is not None:
         return
-    with _LOCK:
+    with LOCK:
         for tid in table_ids:
             seen = _READERS.setdefault((store_id, tid), OrderedDict())
             known = seen.get(what)
             if known is not None and known[0] is plan:
                 seen.move_to_end(what)
                 continue
-            seen[what] = (plan, dict(vars_))
+            seen[what] = (plan, dict(vars_), run)
             seen.move_to_end(what)
             while len(seen) > MAX_READERS:
                 seen.popitem(last=False)
 
 
 def readers(store_id: int, table_id: int) -> list:
-    """→ [(plan, vars, the statement's text or None)]."""
-    with _LOCK:
-        return [(plan, vars_, what[0] if isinstance(what[0], str) else None)
-                for what, (plan, vars_) in
+    """→ [(plan, vars, the statement's text or None, run)]."""
+    with LOCK:
+        return [(plan, vars_, what[0] if isinstance(what[0], str) else None,
+                 run)
+                for what, (plan, vars_, run) in
                 _READERS.get((store_id, table_id), {}).items()]
 
 
 def install_preview(pv: Preview) -> None:
     """The swap: the rebuilt generation takes its key and the structures
-    built over it take theirs (called under `_LOCK`)."""
-    _CACHE[pv.key] = pv.ent
-    _CACHE.move_to_end(pv.key)
+    built over it take theirs (called under `LOCK`)."""
+    CACHE[pv.key] = pv.ent
+    CACHE.move_to_end(pv.key)
     for akey, new in pv.aligned.items():
         old = _ALIGNED.get(akey)
         _ALIGNED[akey] = new
         _ALIGNED.move_to_end(akey)
         if old is not None and old is not new:
-            _safe_delete(old)
+            safe_delete(old)
     pv.aligned.clear()
 
 
 def _all_protected() -> frozenset:
-    with _LOCK:
+    with LOCK:
         if not _PROTECT:
             return frozenset()
         out = set()
@@ -1199,7 +1148,7 @@ def _all_protected() -> frozenset:
 def _protected_elsewhere(pair) -> bool:
     """Whether a statement of ANOTHER thread computes on the table."""
     me = threading.get_ident()
-    with _LOCK:
+    with LOCK:
         return any(pair in pairs for tid, pairs in _PROTECT.items()
                    if tid != me)
 
@@ -1212,20 +1161,20 @@ def protect_tables(pairs):
     entry pop defers the buffer free to refcounting (below)."""
     tid = threading.get_ident()
     pairs = frozenset(pairs)
-    with _LOCK:
+    with LOCK:
         prev = _PROTECT.get(tid)
         _PROTECT[tid] = pairs if prev is None else (prev | pairs)
     try:
         yield
     finally:
-        with _LOCK:
+        with LOCK:
             if prev is None:
                 _PROTECT.pop(tid, None)
             else:
                 _PROTECT[tid] = prev
 
 
-def _safe_delete(ent, pair=None) -> None:
+def safe_delete(ent, pair=None) -> None:
     """Free an evicted entry's device buffers — unless a concurrent
     statement may still be computing on them, in which case the explicit
     free is skipped and refcounting reclaims the arrays the moment the
@@ -1243,34 +1192,34 @@ def _safe_delete(ent, pair=None) -> None:
 def _drop_entry(key, ent) -> None:
     """Take `ent` out of the cache (if it is still what `key` holds) and
     free what no statement in flight computes on."""
-    with _LOCK:
-        held = _CACHE.get(key) is ent
+    with LOCK:
+        held = CACHE.get(key) is ent
         if held:
-            _CACHE.pop(key, None)
+            CACHE.pop(key, None)
     if held:
         # (a generation kept behind the entry shares its arrays)
-        _safe_delete(ent, key[1:3])
+        safe_delete(ent, key[1:3])
 
 
 def clear():
-    with _LOCK:
-        cache = list(_CACHE.items())
+    with LOCK:
+        cache = list(CACHE.items())
         aligned = list(_ALIGNED.values())
-        _CACHE.clear()
+        CACHE.clear()
         _ALIGNED.clear()
         _READERS.clear()
         _note_kept()
     for k, e in cache:
-        _safe_delete(e, k[1:3])
+        safe_delete(e, k[1:3])
     for e in aligned:
-        _safe_delete(e)
+        safe_delete(e)
 
 
 def invalidate(table_id: int):
     dead_c, dead_a = [], []
-    with _LOCK:
-        for key in [k for k in _CACHE if k[2] == table_id]:
-            ent = _CACHE.pop(key, None)
+    with LOCK:
+        for key in [k for k in CACHE if k[2] == table_id]:
+            ent = CACHE.pop(key, None)
             if ent is not None:
                 dead_c.append((key, ent))
         for key in [k for k, e in _ALIGNED.items()
@@ -1280,16 +1229,16 @@ def invalidate(table_id: int):
                 dead_a.append(ent)
         _note_kept()
     for key, ent in dead_c:
-        _safe_delete(ent, key[1:3])
+        safe_delete(ent, key[1:3])
     for ent in dead_a:
-        _safe_delete(ent)
+        safe_delete(ent)
 
 
 _STORE_FINALIZERS: Dict[int, object] = {}
 # ids of collected stores, queued by their weakref finalizers. The
 # collector runs a finalizer on whatever thread allocates next — possibly
-# inside a `with _LOCK` block that is iterating _CACHE, or on a thread
-# holding some other lock while a sibling holds _LOCK — so the finalizer
+# inside a `with LOCK` block that is iterating CACHE, or on a thread
+# holding some other lock while a sibling holds LOCK — so the finalizer
 # itself only appends here (atomic, lock-free) and the eviction happens
 # at the next cache call that cares (_reap_dead_stores).
 _DEAD_STORES: deque = deque()
@@ -1297,7 +1246,7 @@ _DEAD_STORES: deque = deque()
 
 def _reap_dead_stores() -> None:
     """Evict the entries of every store collected since the last call —
-    run by the cache's entry points before they read _CACHE, so a dead
+    run by the cache's entry points before they read CACHE, so a dead
     engine's tables are never looked up, counted against the budget, or
     left holding HBM past the next statement."""
     while _DEAD_STORES:
@@ -1309,8 +1258,8 @@ def _reap_dead_stores() -> None:
 
 
 def _evict_store(store_id: int):
-    with _LOCK:
-        dead_c = [(k, _CACHE.pop(k)) for k in list(_CACHE)
+    with LOCK:
+        dead_c = [(k, CACHE.pop(k)) for k in list(CACHE)
                   if k[1] == store_id]
         dead_a = [_ALIGNED.pop(k) for k in list(_ALIGNED)
                   if k[0] == store_id]
@@ -1319,9 +1268,9 @@ def _evict_store(store_id: int):
             del _READERS[k]
         _note_kept()
     for key, ent in dead_c:
-        _safe_delete(ent, key[1:3])
+        safe_delete(ent, key[1:3])
     for ent in dead_a:
-        _safe_delete(ent)
+        safe_delete(ent)
 
 
 # ---------------------------------------------------------------------------
@@ -1337,7 +1286,6 @@ def device_handle(idx):
     if idx is None or idx < 0:
         return None
     try:
-        from tidb_tpu.ops.jax_env import jax
         devs = jax.devices()
     except Exception:  # noqa: BLE001 — no backend: pinning is moot
         return None
@@ -1346,7 +1294,7 @@ def device_handle(idx):
     return devs[idx] if idx < len(devs) else devs[0]
 
 
-def _ctx_device(ctx) -> int:
+def ctx_device(ctx) -> int:
     """The pool device index this statement is pinned to (stamped by
     scheduler placement on the guard, mirrored on the PhaseTimer for
     guard-less contexts); 0 when no placement ran — the single-device
@@ -1368,11 +1316,9 @@ def _approx_rows(td) -> int:
 
 
 def _pod_partition(ctx, td) -> bool:
-    from tidb_tpu.executor import scheduler
     if scheduler.pool_devices(ctx) <= 1:
         return False
-    min_rows = int(ctx.vars.get("tidb_tpu_partition_min_rows",
-                                DEFAULT_PARTITION_MIN_ROWS))
+    min_rows = var_int(ctx.vars, "tidb_tpu_partition_min_rows")
     return _approx_rows(td) >= max(min_rows, 1)
 
 
@@ -1388,8 +1334,8 @@ def locate_tables(table_ids, store_id: Optional[int] = None) \
     is pod-partitioned, un-steal — engine B's statements."""
     want = set(table_ids)
     out: Dict[int, set] = {}
-    with _LOCK:
-        keys = list(_CACHE)
+    with LOCK:
+        keys = list(CACHE)
     for k in keys:
         if k[2] in want and (store_id is None or k[1] == store_id):
             out.setdefault(k[2], set()).add(k[0])
@@ -1401,8 +1347,8 @@ def replica_overhead_bytes() -> int:
     copy of each (store, table, parts) — the bench's replication-cost
     meter. Pod-partitioned entries hold one copy by construction."""
     _reap_dead_stores()
-    with _LOCK:
-        entries = list(_CACHE.items())
+    with LOCK:
+        entries = list(CACHE.items())
     groups: Dict[tuple, List[int]] = {}
     for k, e in entries:
         if k[0] < 0:
@@ -1449,22 +1395,22 @@ def evict_device(dead: int, survivors=None) -> int:
     dead = int(dead)
     surv = [int(s) for s in (survivors or []) if int(s) != dead]
     dead_c, dead_a, rehomed = [], [], []
-    with _LOCK:
-        for k in [k for k in _CACHE if k[0] == dead]:
-            ent = _CACHE.pop(k, None)
+    with LOCK:
+        for k in [k for k in CACHE if k[0] == dead]:
+            ent = CACHE.pop(k, None)
             if ent is not None:
                 dead_c.append((k, ent))
         if dead == 0 and _ALIGNED:
             dead_a.extend(_ALIGNED.values())
             _ALIGNED.clear()
         prot = _all_protected()
-        for k in [k for k in _CACHE if k[0] < 0]:
-            ent = _CACHE[k]
+        for k in [k for k in CACHE if k[0] < 0]:
+            ent = CACHE[k]
             owners = getattr(ent, "owners", None)
             if not owners or dead not in owners:
                 continue
             if getattr(ent, "is_delta", False) or not surv:
-                _CACHE.pop(k, None)
+                CACHE.pop(k, None)
                 dead_c.append((k, ent))
                 continue
             lost = [s for s, o in enumerate(owners) if o == dead]
@@ -1510,9 +1456,9 @@ def evict_device(dead: int, survivors=None) -> int:
                         _delete_array(a)
             rehomed.append(k)
     for k, ent in dead_c:
-        _safe_delete(ent, k[1:3])
+        safe_delete(ent, k[1:3])
     for ent in dead_a:
-        _safe_delete(ent)
+        safe_delete(ent)
     if timeline.ENABLED and (dead_c or rehomed):
         timeline.instant(f"device-evict dev{dead}", "cache",
                          args={"dropped": len(dead_c),
@@ -1520,14 +1466,7 @@ def evict_device(dead: int, survivors=None) -> int:
     return len(dead_c) + len(rehomed)
 
 
-def _pow2(n: int, lo: int = 1024) -> int:
-    cap = lo
-    while cap < n:
-        cap <<= 1
-    return cap
-
-
-def _collect_parts(ctx, scan, coverage: bool = False):
+def collect_parts(ctx, scan, coverage: bool = False):
     """Materialize the scan's region stream host-side (no column copies:
     alignment reuses region arrays; only partially-deleted regions filter).
 
@@ -1539,7 +1478,6 @@ def _collect_parts(ctx, scan, coverage: bool = False):
     (a region that later re-enters partition scope via the part-reset on
     delete must force a rebuild, and only an id ceiling can tell it
     apart from a genuinely appended region)."""
-    from tidb_tpu.executor.scan import align_chunk_to_schema
     parts = []
     cov = []
     total = 0
@@ -1565,7 +1503,7 @@ def _collect_parts(ctx, scan, coverage: bool = False):
     return parts, total, cov, max_rid
 
 
-def _materialize_col(ent: CachedTable, col_idx: int):
+def materialize_col(ent: CachedTable, col_idx: int):
     vals_list, valid_list = [], []
     for chunk, mask in ent.parts:
         col = chunk.columns[col_idx]
@@ -1581,12 +1519,10 @@ def _materialize_col(ent: CachedTable, col_idx: int):
     return np.concatenate(vals_list), np.concatenate(valid_list)
 
 
-def _encode_col(ftype, vals: np.ndarray, valid: np.ndarray):
+def encode_col(ftype, vals: np.ndarray, valid: np.ndarray):
     """→ (device-ready values, dictionary or None). Strings become sorted-
     dictionary rank codes (order-preserving, so comparisons work on codes);
     DOUBLE narrows to the device float dtype."""
-    from tidb_tpu.chunk import Column
-    from tidb_tpu.chunk.device import encode_strings
     from tidb_tpu.ops.jax_env import device_float_dtype
     if ftype.is_varlen:
         return encode_strings(Column(ftype, vals, None))
@@ -1595,7 +1531,7 @@ def _encode_col(ftype, vals: np.ndarray, valid: np.ndarray):
     return vals, None
 
 
-def _col_bounds(vals: np.ndarray, valid: np.ndarray,
+def col_bounds(vals: np.ndarray, valid: np.ndarray,
                 dictionary) -> Optional[Tuple[int, int]]:
     if dictionary is not None:
         return (0, len(dictionary) - 1) if len(dictionary) else None
@@ -1605,38 +1541,6 @@ def _col_bounds(vals: np.ndarray, valid: np.ndarray,
     if not len(vv):
         return None
     return int(vv.min()), int(vv.max())
-
-
-WIDE_LIMB_BITS = 30
-WIDE_LIMB_BASE = 1 << WIDE_LIMB_BITS
-
-
-def wide_decimal_limbs(vals, n_limbs: int) -> np.ndarray:
-    """Arbitrary-precision scaled ints (object array) → (n_limbs, N) int64
-    base-2³⁰ limb planes via shift/mask, so only the TOP limb is signed —
-    value == Σ limbs[k]·2^(30k) exactly. The device-side layout of
-    MyDecimal's word vector (types/mydecimal.go:236-246) as
-    struct-of-arrays; ONE base everywhere (storage planes, on-device
-    splits of narrow inputs, host recombination) so every producer/
-    consumer pair agrees by construction."""
-    out = np.empty((n_limbs, len(vals)), dtype=np.int64)
-    cur = np.asarray(vals, dtype=object)
-    mask = WIDE_LIMB_BASE - 1
-    for k in range(n_limbs - 1):
-        out[k] = (cur & mask).astype(np.int64)
-        cur = cur >> WIDE_LIMB_BITS           # python ints: floor shift
-    out[n_limbs - 1] = cur.astype(np.int64)   # top: small, carries sign
-    return out
-
-
-def wide_decimal_unlimb(limbs: np.ndarray) -> np.ndarray:
-    """(n_limbs, G) int64 limb sums → object array of exact Python ints.
-    Works on UNNORMALIZED limb sums (planes may exceed the base)."""
-    n_limbs, g = limbs.shape
-    out = np.zeros(g, dtype=object)
-    for k in range(n_limbs - 1, -1, -1):
-        out = out * WIDE_LIMB_BASE + limbs[k].astype(object)
-    return out
 
 
 #: rows of strings one numpy call sorts or searches. Comparing objects,
@@ -1694,7 +1598,7 @@ def _str_codes(keys: np.ndarray, vals: np.ndarray) -> np.ndarray:
     return out
 
 
-def _col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
+def col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
     """Once-per-column host prep for the streamed first-touch: materialize
     the column and build the GLOBAL dictionary/bounds. Per-slab encoding
     then reduces to a searchsorted against the sorted keys (strings), an
@@ -1702,10 +1606,8 @@ def _col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
     byte-identical to encoding the whole column at once, because the
     dictionary is global and searchsorted on the sorted unique keys IS
     np.unique's return_inverse."""
-    from tidb_tpu.chunk import compress
-    from tidb_tpu.util.observability import first_touch
     with first_touch("materialize", col=col_idx):
-        vals, valid = _materialize_col(ent, col_idx)
+        vals, valid = materialize_col(ent, col_idx)
     if ftype.is_wide_decimal:
         return {"kind": "wide", "vals": vals, "valid": valid,
                 "n_limbs": ftype.wide_limb_count,
@@ -1716,7 +1618,6 @@ def _col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
             for a, b in _str_pieces(vals.shape[0]):
                 str_vals[a:b] = [str(v) for v in vals[a:b]]
             if ftype.is_ci:
-                from tidb_tpu.types import fold_ci_array
                 folded = fold_ci_array(str_vals)
                 keys, first = np.unique(folded, return_index=True)
                 dictionary = str_vals[first]    # representative per class
@@ -1744,7 +1645,7 @@ def _col_prep(ent: CachedTable, col_idx: int, ftype) -> dict:
                 "dtype": np.dtype(device_float_dtype()),
                 "dict": None, "bounds": None, "layout": None}
     with first_touch("layout", col=col_idx):
-        bounds = _col_bounds(vals, valid, None)
+        bounds = col_bounds(vals, valid, None)
     prep = {"kind": "num", "vals": vals, "valid": valid,
             "dict": None, "bounds": bounds, "layout": None}
     if ent.compressed:
@@ -1764,7 +1665,6 @@ def workload_hints() -> Optional[dict]:
     dictionary layouts earn their keep (dict codes feed group
     factorization directly) and the cardinality cap loosens."""
     try:
-        from tidb_tpu.util.observability import REGISTRY
         profs = REGISTRY.summary_profiles()
     except Exception:  # noqa: BLE001 — hints are advisory, never fatal
         return None
@@ -1777,11 +1677,10 @@ def workload_hints() -> Optional[dict]:
     return {"group_heavy": rows <= 1024 * calls}
 
 
-def _col_zone_stats(ent: CachedTable, prep: dict):
+def col_zone_stats(ent: CachedTable, prep: dict):
     """Per-slab zone map for one prepped column, in the space the
     pruner compares in (see executor/zonemap.py). Wide decimals carry
     none — their limb planes have no totally-ordered slab stats."""
-    from tidb_tpu.executor import zonemap
     k = prep["kind"]
     if k == "wide":
         return None
@@ -1798,7 +1697,6 @@ def _est_slab_phys(prep: dict, slab_cap: int) -> int:
     """Physical bytes ONE slab of a prepped column would upload —
     computable without encoding it (the h2d_skipped ledger for slabs
     that never encode)."""
-    from tidb_tpu.chunk import compress
     lay = prep.get("layout")
     if lay is not None:
         return compress.packed_slab_bytes(lay, slab_cap)
@@ -1815,7 +1713,6 @@ def _est_slab_phys(prep: dict, slab_cap: int) -> int:
 def _slab_logical_est(ent: CachedTable, i: int, preps=None) -> int:
     """Logical (raw-equivalent) bytes ONE slab of column `i` answers
     for — resolvable even when the device tuple is a pruned hole."""
-    from tidb_tpu.chunk import compress
     lay = ent.layouts.get(i)
     if lay is not None:
         return compress.raw_slab_bytes(lay, ent.slab_cap)
@@ -1834,7 +1731,8 @@ def _slab_host(prep: dict, start: int, stop: int, slab_cap: int):
     valid = prep["valid"][start:stop]
     kind = prep["kind"]
     if kind == "wide":
-        v = wide_decimal_limbs(prep["vals"][start:stop], prep["n_limbs"])
+        v = compress.wide_decimal_limbs(prep["vals"][start:stop],
+                                        prep["n_limbs"])
         if n < slab_cap:
             pv = np.zeros((v.shape[0], slab_cap), dtype=np.int64)
             pv[:, :n] = v
@@ -1857,7 +1755,6 @@ def _slab_host(prep: dict, start: int, stop: int, slab_cap: int):
         m = pm
     layout = prep.get("layout")
     if layout is not None:
-        from tidb_tpu.chunk import compress
         return compress.pack_slab(layout, v, m, prep.get("dictvals"))
     return v, m
 
@@ -1874,21 +1771,19 @@ def _logical_tuple_bytes(ent: CachedTable, i: int, t) -> int:
     lay = ent.layouts.get(i)
     if lay is None:
         return _tuple_nbytes(t)
-    from tidb_tpu.chunk import compress
     return compress.raw_slab_bytes(lay, ent.slab_cap)
 
 
 def _note_storage_metrics(ent: CachedTable, key) -> None:
     if key is None:
         return
-    from tidb_tpu.util.observability import REGISTRY
     REGISTRY.observe("tidb_tpu_table_physical_bytes",
                      float(ent.hbm_bytes()), {"table": str(key[2])})
     REGISTRY.observe("tidb_tpu_table_logical_bytes",
                      float(ent.logical_bytes()), {"table": str(key[2])})
 
 
-def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
+def stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
                   skip=frozenset(), fill=None, tail=None):
     """Generator behind open_table: per slab, encode the missing columns
     (host), issue their uploads (async device_put), and yield
@@ -1919,11 +1814,6 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
     moved, so the build's parts still say what they hold); `tail` (col →
     slab tuple) is the delta slab of each streamed column, appended at
     the commit."""
-    from tidb_tpu.errors import DeviceLost
-    from tidb_tpu.executor import zonemap
-    from tidb_tpu.ops.jax_env import jax, jnp
-    from tidb_tpu.util import failpoint
-    from tidb_tpu.util.observability import first_touch
     new_slabs = {i: [] for i in preps}
     dev_idx = getattr(ent, "device", 0)
     owners = getattr(ent, "owners", None)
@@ -2030,7 +1920,7 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
                         logical=sum(_logical_tuple_bytes(ent, i, t)
                                     for i, t in cols.items()))
         yield s, cols
-    with _LOCK:
+    with LOCK:
         for i, slabs in new_slabs.items():
             if fill is not None and i in fill:
                 # partial refill: splice ONLY the re-uploaded lost slabs
@@ -2069,8 +1959,7 @@ def _stream_slabs(ctx, ent: CachedTable, key, used_cols, preps, phases,
     phases.clear_in_flight()
     _note_storage_metrics(ent, key)
     if key is not None:
-        budget = int(ctx.vars.get("tidb_tpu_hbm_budget",
-                                  DEFAULT_HBM_BUDGET_BYTES))
+        budget = var_int(ctx.vars, "tidb_tpu_hbm_budget")
         _evict_to_budget(budget, keep=key, keep_tables=_protected(ctx))
 
 
@@ -2081,9 +1970,6 @@ def _validate_layouts(ent: CachedTable, used_cols) -> None:
     fallback in the executor) and never as silently wrong rows. The
     failpoint models the corruption: any armed value stands in for a
     descriptor that no longer matches the packed data."""
-    from tidb_tpu.chunk import compress
-    from tidb_tpu.errors import LayoutError
-    from tidb_tpu.util import failpoint
     corrupted = failpoint.inject("compressed-decode-mismatch")
     if corrupted is not None:
         raise LayoutError(
@@ -2095,7 +1981,7 @@ def _validate_layouts(ent: CachedTable, used_cols) -> None:
             compress.validate(lay)
 
 
-def _decoded_slabs(ent: CachedTable, col: int):
+def decoded_slabs(ent: CachedTable, col: int):
     """Column slabs DECODED to raw (vals, valid) tuples — the one-off
     eager decode for aligned-join builds, whose outputs (midx/matched
     and gathered build columns) are cached raw in the fact slab layout,
@@ -2105,8 +1991,6 @@ def _decoded_slabs(ent: CachedTable, col: int):
     lay = ent.layouts.get(col)
     if lay is None:
         return slabs
-    from tidb_tpu.chunk import compress
-    from tidb_tpu.ops.jax_env import jnp
     return [t if ent.slab_shape(s)[1]
             else compress.decode_slab(lay, t, ent.slab_cap, jnp)
             for s, t in enumerate(slabs)]
@@ -2121,8 +2005,8 @@ def storage_stats(store_id: Optional[int] = None) -> List[dict]:
     table ids restart per engine, so an unscoped dump can attribute a
     stale entry to an unrelated live table."""
     _reap_dead_stores()
-    with _LOCK:
-        entries = [(k, e) for k, e in _CACHE.items()
+    with LOCK:
+        entries = [(k, e) for k, e in CACHE.items()
                    if store_id is None or k[1] == store_id]
     rows = []
     for key, ent in entries:
@@ -2207,8 +2091,6 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
     tagged "behind". The tagged slot of `consumer` never moves backwards
     either; the slot of `behind` holds the last such reader's snapshot.
     """
-    from tidb_tpu.util import failpoint
-    from tidb_tpu.util.phases import PhaseTimer
     table_id = scan.table.id
     tabs = getattr(phases, "tables", None)
     if tabs is not None:
@@ -2216,8 +2098,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         # the digest profile, closing the loop locality placement
         # (scheduler.place_statement) routes by
         tabs.add(table_id)
-    comp_on = str(ctx.vars.get("tidb_tpu_compression", "on")).lower() \
-        not in ("off", "0", "false")
+    comp_on = var_on(ctx.vars, "tidb_tpu_compression")
     cacheable = getattr(ctx, "txn", None) is None
     td = ctx.snapshot.table_data(table_id) if cacheable else None
     # key by owning store too: distinct engines may reuse table ids; a
@@ -2230,7 +2111,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
     # need complete local columns and keep per-device entries.
     store = getattr(ctx.snapshot, "store", None) if cacheable else None
     parts = getattr(scan, "partitions", None)
-    dev = _ctx_device(ctx) if cacheable else 0
+    dev = ctx_device(ctx) if cacheable else 0
     if cacheable and prune and td is not None and _pod_partition(ctx, td):
         dev = -1
     key = (dev, id(store), table_id,
@@ -2246,7 +2127,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                           prune=prune, _plain=gate)
 
     _reap_dead_stores()
-    with _LOCK:
+    with LOCK:
         if store is not None and id(store) not in _STORE_FINALIZERS:
             import weakref
             _STORE_FINALIZERS[id(store)] = weakref.finalize(
@@ -2266,8 +2147,8 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
     pv = _previewing()
     version = int(getattr(ctx.snapshot, "version", 0) or 0) \
         if cacheable else 0
-    with _LOCK:
-        ent = _CACHE.get(key) if cacheable else None
+    with LOCK:
+        ent = CACHE.get(key) if cacheable else None
         if pv is not None and key == pv.key:
             ent = pv.ent
             if not _usable(ent) or (ent.is_delta and not delta_ok):
@@ -2289,13 +2170,13 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                 # below: this statement's serves it alone)
                 ent = None
             else:
-                _CACHE.pop(key, None)
+                CACHE.pop(key, None)
                 stale = ent
                 ent = None
         elif ent is not None:
-            _CACHE.move_to_end(key)
+            CACHE.move_to_end(key)
     if stale is not None:
-        _safe_delete(stale, key[1:3])
+        safe_delete(stale, key[1:3])
     if newest is not None:
         from tidb_tpu.executor import delta as _delta
         with timeline.span("delta.generation", "delta", table=table_id):
@@ -2316,8 +2197,8 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                 def _swap(new_ent):
                     # (under the extensions' lock: the next statement to
                     # get its turn finds this generation installed)
-                    with _LOCK:
-                        cur = _CACHE.get(key)
+                    with LOCK:
+                        cur = CACHE.get(key)
                         if cur is None or cur.lineage == new_ent.lineage:
                             # the generation swap: in-flight readers keep
                             # the object they hold (their snapshot), a
@@ -2327,7 +2208,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                             # arrays; refcounting frees what a dropped one
                             # alone held.
                             moved[:] = _install_generation(
-                                _CACHE, key, cur, new_ent,
+                                CACHE, key, cur, new_ent,
                                 lambda g: g.td is new_ent.td,
                                 lambda g: g.delta_version, _table_arrays,
                                 still_held)
@@ -2345,7 +2226,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                         step, scan, base, max_slab, phases,
                         quiet=pv is not None, then=_swap,
                         made=lambda: _generation_of(
-                            _CACHE.get(key),
+                            CACHE.get(key),
                             step.snapshot.table_data(table_id),
                             base.lineage)) is None:
                     break
@@ -2364,15 +2245,15 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                 # device arrays with `newest`, and an explicit delete
                 # here would free buffers it is serving.
                 dead = None
-                with _LOCK:
-                    cur = _CACHE.get(key)
+                with LOCK:
+                    cur = CACHE.get(key)
                     if cur is newest:
-                        _CACHE.pop(key, None)
+                        CACHE.pop(key, None)
                         dead = newest
                     elif cur is not None and _usable(cur):
                         ent = cur
                 if dead is not None:
-                    _safe_delete(dead, key[1:3])
+                    safe_delete(dead, key[1:3])
             timeline.tag(age="rebuilt" if ent is None else age)
         if ent is None and not forward:
             # a snapshot older than what is kept: the counted plain rebuild
@@ -2388,19 +2269,18 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
             _delta.decline(_plain, table_id)
             age = "rebuilt"
         if cacheable:
-            parts, total, cov, max_rid = _collect_parts(ctx, scan,
+            parts, total, cov, max_rid = collect_parts(ctx, scan,
                                                         coverage=True)
         else:
-            parts, total = _collect_parts(ctx, scan)
+            parts, total = collect_parts(ctx, scan)
             cov, max_rid = None, -1
-        slab_cap = _pow2(min(total, max_slab)) if total else 1024
+        slab_cap = pow2(min(total, max_slab), lo=1024) if total else 1024
         n_slabs = (total + slab_cap - 1) // slab_cap
         built = CachedTable(td, max_slab, total, slab_cap, n_slabs, parts,
                             len(scan.schema), compressed=comp_on)
         built.device = dev
         if dev < 0:
-            from tidb_tpu.executor import scheduler as _sched
-            nd = max(_sched.pool_devices(ctx), 1)
+            nd = max(scheduler.pool_devices(ctx), 1)
             # contiguous slab spans per owner: slab s → owner device
             # s*nd//n_slabs (monotone, covers every device when
             # n_slabs >= nd)
@@ -2412,12 +2292,12 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         if cacheable:
             victims = []
             replica = False
-            with _LOCK:
-                cur = _CACHE.get(key)
+            with LOCK:
+                cur = CACHE.get(key)
                 if cur is not None and _usable(cur):
                     # lost a cold-build race: adopt the winner, drop ours
                     ent = cur
-                    _CACHE.move_to_end(key)
+                    CACHE.move_to_end(key)
                 elif cur is not None and _plain != "behind" \
                         and cur.delta_version > built.delta_version:
                     # a statement at a later snapshot got there meanwhile:
@@ -2431,18 +2311,18 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                         # that statement computes on, so while any other
                         # thread protects the table its last reference
                         # frees it, not we
-                        _CACHE.pop(key)
+                        CACHE.pop(key)
                         if not _protected_elsewhere(key[1:3]):
                             victims.append(cur)
                         cur = None
-                    ent = _CACHE[key] = built
+                    ent = CACHE[key] = built
                     # lazy replication: another device already holds this
                     # (store, table, parts) — this install is a replica
                     replica = dev >= 0 and any(
                         k != key and k[0] >= 0 and k[1:] == key[1:]
-                        for k in _CACHE)
+                        for k in CACHE)
                     prot = _all_protected()
-                    same = [k for k in _CACHE if k[0] == dev]
+                    same = [k for k in CACHE if k[0] == dev]
                     over = len(same) - MAX_CACHED_TABLES
                     for k in same:
                         if over <= 0:
@@ -2451,12 +2331,11 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                         # table a live statement protects (a device may
                         # transiently exceed its cap under concurrency)
                         if k != key and k[1:3] not in prot:
-                            victims.append(_CACHE.pop(k))
+                            victims.append(CACHE.pop(k))
                             over -= 1
             for v in victims:
                 _entry_delete(v)
             if replica:
-                from tidb_tpu.util.observability import REGISTRY
                 REGISTRY.inc("tidb_tpu_table_replicas_total",
                              {"device": str(dev)})
         else:
@@ -2471,7 +2350,6 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
     ph = phases if phases is not None else PhaseTimer()
     if ent.is_delta and ent.delta_rows:
         ph.note_delta_rows(ent.delta_rows, token=id(ent))
-    from tidb_tpu.executor import zonemap
     skip = zonemap.prune_slabs(ent, scan) if prune else frozenset()
     missing = []
     refill = []
@@ -2495,7 +2373,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
             else:
                 full.append(i)
         if full:
-            with _LOCK:
+            with LOCK:
                 for i in full:
                     # this statement's predicates reach slabs an earlier,
                     # more selective statement pruned away on cold touch:
@@ -2521,12 +2399,11 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
         ph.add_scan(phys, logical=logi)
         return ent, None
     failpoint.inject("device-transfer")
-    from tidb_tpu.util.observability import first_touch
     ftypes = scan.schema.field_types
     preps = {}
     with ph.phase("encode"):
         for i in missing:
-            preps[i] = _col_prep(ent, i, ftypes[i])
+            preps[i] = col_prep(ent, i, ftypes[i])
             if i in fill:
                 # the re-prep must reproduce the committed layout for
                 # spliced slabs to decode alongside the warm ones — the
@@ -2540,7 +2417,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                     and old.sig() == new.sig())
                 if not same:
                     del fill[i]
-                    with _LOCK:
+                    with LOCK:
                         ent.dev.pop(i, None)
                         ent.holes.pop(i, None)
             ent.dicts[i] = preps[i]["dict"]
@@ -2552,7 +2429,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
             ent.layouts[i] = preps[i]["layout"]
             if ent.compressed:
                 with first_touch("layout", col=i):
-                    zm = _col_zone_stats(ent, preps[i])
+                    zm = col_zone_stats(ent, preps[i])
                 if zm is not None:
                     ent.zmaps[i] = zm
     _validate_layouts(ent, used_cols)
@@ -2571,7 +2448,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
                     tail[i], nbytes = _delta.delta_column(ent, scan, i,
                                                           ftypes[i])
                     ph.add_h2d(nbytes, logical=nbytes)
-        except _delta._Declined as d:
+        except _delta.Declined as d:
             # (an appended string the column's dictionary, built from the
             # base's rows, does not hold): rebuild fresh
             _delta.decline(d.gate, table_id)
@@ -2580,7 +2457,7 @@ def open_table(ctx, scan, used_cols, max_slab: int, phases=None,
             _drop_entry(key, ent)
             return open_table(ctx, scan, used_cols, max_slab, phases=phases,
                               prune=prune, delta_ok=delta_ok, _plain=_plain)
-    return ent, _stream_slabs(ctx, ent, key, list(used_cols), preps, ph,
+    return ent, stream_slabs(ctx, ent, key, list(used_cols), preps, ph,
                               skip=skip, fill=fill or None, tail=tail)
 
 
@@ -2609,10 +2486,10 @@ def _evict_to_budget(budget: int, keep, keep_aligned=frozenset(),
     actually holds."""
     dead_c, dead_a = [], []
     _reap_dead_stores()
-    with _LOCK:
+    with LOCK:
         keep_tables = frozenset(keep_tables) | _all_protected()
         usage: Dict[int, int] = {}
-        for k, e in _CACHE.items():
+        for k, e in CACHE.items():
             for d, b in _entry_dev_bytes(k, e).items():
                 usage[d] = usage.get(d, 0) + b
         for e in _ALIGNED.values():
@@ -2625,7 +2502,7 @@ def _evict_to_budget(budget: int, keep, keep_aligned=frozenset(),
             ent = _ALIGNED.pop(victim)
             usage[0] -= ent.hbm_bytes()
             dead_a.append(ent)
-        while len(_CACHE) > 1:
+        while len(CACHE) > 1:
             over = {d for d, b in usage.items() if b > budget}
             if not over:
                 break
@@ -2635,27 +2512,26 @@ def _evict_to_budget(budget: int, keep, keep_aligned=frozenset(),
             # table get evicted mid-query. LRU order: first matching
             # entry that relieves an over-budget device.
             victim = next(
-                (k for k in _CACHE
+                (k for k in CACHE
                  if k != keep and k[1:3] not in keep_tables
-                 and set(_entry_dev_bytes(k, _CACHE[k])) & over), None)
+                 and set(_entry_dev_bytes(k, CACHE[k])) & over), None)
             if victim is None:
                 break
-            ent = _CACHE.pop(victim)
+            ent = CACHE.pop(victim)
             for d, b in _entry_dev_bytes(victim, ent).items():
                 usage[d] = usage.get(d, 0) - b
             dead_c.append(ent)
     for ent in dead_c:
         _entry_delete(ent)
     for ent in dead_a:
-        _safe_delete(ent)
+        safe_delete(ent)
 
 
 def aligned_budget_check(ctx, keep_keys=frozenset(),
                          keep_tables=frozenset()) -> None:
     """Enforce the HBM budget after aligned planning, never evicting the
     entries the in-flight query is about to execute with."""
-    budget = int(ctx.vars.get("tidb_tpu_hbm_budget",
-                              DEFAULT_HBM_BUDGET_BYTES))
+    budget = var_int(ctx.vars, "tidb_tpu_hbm_budget")
     _evict_to_budget(budget, keep=None,
                      keep_aligned=frozenset(keep_keys),
                      keep_tables=frozenset(keep_tables))
@@ -2778,8 +2654,7 @@ def _build_cat(ent: CachedTable, col: int, base_only: bool = False):
     Compressed slabs decode here — the LUT/gather builds below run once
     per cached structure, so the eager decode is off the per-query path.
     A delta generation's raw delta slab follows its base slabs."""
-    from tidb_tpu.ops.jax_env import jnp
-    slabs = _decoded_slabs(ent, col)
+    slabs = decoded_slabs(ent, col)
     if base_only:
         slabs = slabs[:ent.base_slabs]
     if len(slabs) == 1:
@@ -2790,7 +2665,6 @@ def _build_cat(ent: CachedTable, col: int, base_only: bool = False):
 
 def _alive_cat(ent: CachedTable):
     """Row liveness over `_build_cat`'s rows."""
-    from tidb_tpu.ops.jax_env import jnp
     masks = [ent.slab_mask(s) for s in range(ent.n_slabs)]
     return masks[0] if len(masks) == 1 else jnp.concatenate(list(masks))
 
@@ -2818,7 +2692,7 @@ def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
     the build's). fact: (fact CachedTable, its key column) when the probe
     key is a column of the fact scan itself — such a structure follows
     both tables' delta generations."""
-    from tidb_tpu.ops.jax_env import jax, jnp, named_jit, program_name
+    from tidb_tpu.ops.jax_env import named_jit, program_name
     stale = None
     space = tuple(space) + (build_ent.lineage,)
     pv = _previewing()
@@ -2838,7 +2712,7 @@ def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
     def _install(new):
         """Under the key, before or behind what it holds → what serves."""
         new.version = version
-        with _LOCK:
+        with LOCK:
             cur = tbl.get(key)
             if cur is None or (_family(cur) and cur.unique):
                 new, _age = _install_generation(
@@ -2852,7 +2726,7 @@ def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
             # (else: another base build's, and newer: served uninstalled)
         return new
 
-    with _LOCK:
+    with LOCK:
         ent = tbl.get(key)
         if ent is not None:
             # the structure that pairs the generations of this snapshot:
@@ -2888,10 +2762,10 @@ def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
         # it serves any snapshot's generations again. (One of a LATER
         # snapshot stays: this statement is behind the cache, on tables
         # rebuilt beside it, and what it builds serves it alone.)
-        with _LOCK:
+        with LOCK:
             if tbl.get(key) is stale:
                 tbl.pop(key, None)
-        _safe_delete(stale)
+        safe_delete(stale)
 
     lo, hi = bounds
     domain = hi - lo + 1
@@ -2925,7 +2799,7 @@ def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
         bk_v, bk_m, b_alive)
     if int(jax.device_get(maxcnt)) > 1:
         ent.unique = False          # negative result cached
-        with _LOCK:
+        with LOCK:
             if key not in tbl:
                 tbl[key] = ent
         return None
@@ -2962,8 +2836,6 @@ def get_aligned(ctx, key, tds: Dict[int, object], fact_slabs,
 
 
 def _gather_program(col: int, bv, cap: int):
-    from tidb_tpu.executor import device_emit
-    from tidb_tpu.ops.jax_env import jnp
 
     def _gather(bv_, bm_, midx, matched):
         v = jnp.take(jnp.asarray(bv_), midx, axis=-1)
@@ -2972,7 +2844,7 @@ def _gather_program(col: int, bv, cap: int):
 
     # the build column is an ARGUMENT: no table data in the program, so
     # every data set of one shape shares one executable
-    return device_emit._delta_program(
+    return device_emit.delta_program(
         "gather", (col, bv.shape, str(bv.dtype), cap), lambda: _gather)
 
 
@@ -2988,7 +2860,7 @@ def aligned_col(ent: AlignedJoin, build_ent: CachedTable, col: int):
         for midx, matched in zip(ent.midx, ent.matched)]
     n_base = ent.matched.n_base
     slabs = SlabColumn(slabs[:n_base], *slabs[n_base:])
-    with _LOCK:
+    with LOCK:
         # first-commit-wins against a concurrent identical gather
         return ent.cols.setdefault(col, slabs)
 
@@ -3025,8 +2897,7 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
     arriving onto a key a live one holds), `dangling` (a build row
     arriving while live fact rows match none: one of them may be its —
     an UPDATE of a build row is a key that dies and arrives)."""
-    from tidb_tpu.executor import delta, device_emit
-    from tidb_tpu.ops.jax_env import jax, jnp, lax
+    from tidb_tpu.executor import delta
     fact_tid = next((t for t, td in tds.items() if td is fact_ent.td), None)
     build_tid = next((t for t, td in tds.items()
                       if td is build_ent.td and t != fact_tid), None)
@@ -3050,7 +2921,7 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
     new.bcat = dict(old.bcat)
     base_n = build_ent.base_slabs * build_ent.slab_cap
     new.build_nb = base_n + build_ent.delta_cap
-    pad = delta._pad_idx
+    pad = delta.pad_idx
 
     # ---- the build side, step by step: dead keys leave the lookup table
     # and unmatch the fact rows that carry them, new keys enter it
@@ -3097,7 +2968,7 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
                                          fill_value=-1) >= 0)
                 return lut.at[put_].set(val_, mode="drop"), taken
 
-        step = device_emit._delta_program(
+        step = device_emit.delta_program(
             "delta_merge", ("lut", domain, clr.shape, put.shape),
             lambda: _lut_step)
         new.lut, taken = step(new.lut, clr, put, val)
@@ -3170,19 +3041,19 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
                             *in_place(base, rows, k), rg, lut)
                         # (as the stack holds a slab's mask: the loop
                         # hands out the new stack itself)
-                        return c, (_fold(matched), n_dang)
+                        return c, (device_emit.fold(matched), n_dang)
                     _c, (matched, n_dang) = lax.scan(
                         turn, None, jnp.arange(n_base, dtype=jnp.int32))
                     return matched, jnp.sum(n_dang, dtype=jnp.int32)
 
-                prog = device_emit._delta_program(
+                prog = device_emit.delta_program(
                     "delta_merge", key + (n_base,),
                     lambda _f=_unmatch_base: _f)
                 matched, n_dang = prog(base, picks.vectors(), ranges,
                                        new.lut)
                 new.matched.set_stack(matched)
             else:
-                prog = device_emit._delta_program(
+                prog = device_emit.delta_program(
                     "delta_merge", key, lambda _unmatch=_unmatch: _unmatch)
                 new.matched[s], n_dang = prog(
                     fcol[s], new.matched[s], new.midx[s],
@@ -3218,7 +3089,7 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
         kv = np.concatenate([p[0] for p in parts]).astype(np.int64)
         km = np.concatenate([p[1] for p in parts])
         off, n = appended[0][3], int(kv.shape[0])
-        bucket = _pow2(n, delta.MIN_BUCKET)
+        bucket = pow2(n, delta.MIN_BUCKET)
         pk = np.zeros(bucket, dtype=np.int64)
         pk[:n] = kv
         pm = np.zeros(bucket, dtype=bool)
@@ -3260,7 +3131,7 @@ def _advance_aligned(old: AlignedJoin, tds, fact_ent: CachedTable,
                         midx.at[at].set(mi, mode="drop"), out,
                         jnp.sum(kmask & (i < n_) & ~hit, dtype=jnp.int32))
 
-        prog = device_emit._delta_program("delta_merge", (
+        prog = device_emit.delta_program("delta_merge", (
             "aligned", lo, domain, bucket, dcap, base_n, nb, tuple(cols),
             bool(bdelta)), lambda _extend=_extend: _extend)
         with timeline.span("delta.upload", "delta",

@@ -2,7 +2,7 @@
 
 One implementation of the root reductions — grouped aggregation (sort-
 factorize or stats-informed perfect-hash) and window evaluation — used by
-both the linear-chain fragment programs (executor/fragment.py) and the
+both the linear-chain fragment programs (executor/agg_slabs.py) and the
 join-tree / distributed programs (executor/tree_fragment.py,
 dist_fragment.py). The reference splits the same logic between
 executor/aggregate.go and unistore's cophandler/mpp_exec.go; here it is
@@ -27,8 +27,17 @@ import functools
 import itertools
 from typing import List, Sequence
 
+from tidb_tpu.chunk import compress
 from tidb_tpu.expression import ColumnRef, EvalContext
 from tidb_tpu.expression.aggfuncs import AggFunc
+from tidb_tpu.ops import factorize as F, segment as seg, window as W
+from tidb_tpu.ops.jax_env import jax, jnp, lax
+from tidb_tpu.ops.segment import SortedRuns
+from tidb_tpu.planner.physical import (PhysHashAgg, PhysLimit, PhysSort,
+                                       PhysTopN, PhysWindow)
+from tidb_tpu.types import TypeKind
+from tidb_tpu.util import timeline
+from tidb_tpu.util.observability import REGISTRY
 
 
 STAGES = ("decode", "filter", "project", "join_probe", "agg", "merge",
@@ -37,7 +46,6 @@ STAGES = ("decode", "filter", "project", "join_probe", "agg", "merge",
 
 def stage(name: str):
     """`jax.named_scope(name)` for one of `STAGES`."""
-    from tidb_tpu.ops.jax_env import jax
     return jax.named_scope(name)
 
 
@@ -72,12 +80,8 @@ def emit_decode(layout, slab, cap: int):
     traced program since this runs while tracing: the always-on counter
     `tidb_tpu_delta_decode_programs_total{scan=}` and the tag `delta_scan`
     on the span that covers the trace (the program's first `launch`)."""
-    from tidb_tpu.chunk import compress
-    from tidb_tpu.ops.jax_env import jnp, lax
     decoded = compress.decode_slab(layout, slab, cap, jnp)  # validates
     if layout.kind == "delta":
-        from tidb_tpu.util import timeline
-        from tidb_tpu.util.observability import REGISTRY
         scan = compress.delta_scan(layout, cap)
         REGISTRY.inc("tidb_tpu_delta_decode_programs_total", {"scan": scan})
         timeline.tag(delta_scan=scan)
@@ -91,7 +95,6 @@ def emit_sort(keys, descs, live):
     rank-encoded per column exactly like executor/sort.py's host
     rank_keys, so direction + MySQL NULL ordering (NULLs first ASC,
     last DESC) behave identically on device and host."""
-    from tidb_tpu.ops import factorize as F
     return F.sort_perm(keys, descs, live)
 
 
@@ -99,7 +102,6 @@ def emit_sort(keys, descs, live):
 def emit_topk(keys, descs, live, k: int):
     """Traced top-k row selection → (idx (k,), n_out). Same rank
     encoding as emit_sort; k is static (min(count+offset, cap))."""
-    from tidb_tpu.ops import factorize as F
     return F.topn(keys, descs, live, k)
 
 
@@ -119,8 +121,6 @@ def emit_distinct(gids, v, m, live, n: int, keys, pairs_out: bool,
     OUTPUT arrays shrink — n_pairs reports the TRUE count, so the driver
     can detect a truncated pair set and resize through the capacity
     ladder."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import factorize as F
     first, _pg, n_pairs, rep = F.distinct_pair_factorize(
         gids, v, m, live, n)
     if not pairs_out:
@@ -146,9 +146,6 @@ def emit_root(ctx: EvalContext, live, root, aggs=None, group_cap: int = 0,
       TopN/Sort: {cols, n_out} (gathered in sorted order, truncated to
       k for TopN); Window: emit_window's {cols, live}; any row root
       (Selection/Projection/Join): padded {cols, live}."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.planner.physical import (PhysHashAgg, PhysLimit,
-                                           PhysSort, PhysTopN, PhysWindow)
     if isinstance(root, PhysHashAgg):
         return emit_agg(ctx, live, root, aggs, group_cap, key_bounds,
                         pairs_out=pairs_out, pair_cap=pair_cap)
@@ -205,7 +202,6 @@ def partials_of(partials):
 def stack_partials(key_cols, states, slot_live):
     """`partials_of`'s lists → one stacked partial (traced). Already
     stacked arrays pass through."""
-    from tidb_tpu.ops.jax_env import jnp
     if not isinstance(slot_live, (list, tuple)):
         return key_cols, states, slot_live
     cat = jnp.concatenate
@@ -222,8 +218,6 @@ def emit_merge(root, aggs: List[AggFunc], group_cap: int, key_cols,
     identities, scatter-merge states (AggFunc.merge is the same segment
     op as update — SURVEY A.4). One implementation shared by the chain
     program's merge and the fused pipeline's root-merge program."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import factorize as F
     # where each partial's slots end in the stack (None: handed in stacked)
     ends = list(itertools.accumulate(int(a.shape[0]) for a in slot_live)) \
         if isinstance(slot_live, (list, tuple)) else None
@@ -267,7 +261,6 @@ def _order_keys(order_root, aggs, nk: int, keys, states, live):
     (keys [(values, valid)], their directions): a group key is its slot
     column, an aggregate its `AggFunc.order_keys` (one or more columns,
     all in the aggregate's direction)."""
-    from tidb_tpu.ops.jax_env import jnp
     okeys, descs = [], []
     for e, desc in zip(order_root.by, order_root.descs):
         cols = [keys[e.index]] if e.index < nk else \
@@ -294,8 +287,6 @@ def emit_finalize(root, order_root, aggs: List[AggFunc], group_cap: int,
     → {keys, states, n_groups, n_out}: keys/states gathered in output
     order (truncated to k for TopN); n_groups is the TRUE merged group
     count for the caller's capacity-ladder validation."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.planner.physical import PhysTopN
     merged = emit_merge(root, aggs, group_cap, key_cols, states, slot_live)
     cap = group_cap
     live = jnp.arange(cap, dtype=jnp.int32) < merged["n_groups"]
@@ -327,13 +318,11 @@ def emit_agg(ctx: EvalContext, live, root, aggs: List[AggFunc],
 
     With `pairs_out`, the result gains "pairs": {agg_idx: (cols,
     n_pairs)} — the deduped (group-keys, value) tuples of every DISTINCT
-    agg, for the cross-slab host merge (fragment._merge_distinct_states).
+    agg, for the cross-slab host merge (host_decode.merge_distinct_states).
     The pair factorize is computed ONCE per distinct agg and shared with
     the state first-occurrence mask: lax.sort compiles are the dominant
     device-program compile cost (ops/factorize.py docstring), so no sort
     runs twice."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import factorize as F
     n = live.shape[0]
     cap = group_cap
     if root.group_exprs and getattr(root, "rollup", False):
@@ -409,7 +398,6 @@ def sorted_runs_ok(root) -> bool:
     difference of two prefix sums over all rows, exact in wrapping int64
     and nowhere else — a float group near 1 beside one near 1e16 would
     come out 0 or 8."""
-    from tidb_tpu.expression import ColumnRef
     if not root.group_exprs or getattr(root, "rollup", False):
         return False
     if any(e.ftype.kind.is_string or e.ftype.is_wide_decimal
@@ -440,8 +428,6 @@ def group_rows(ctx: EvalContext, live, root, key_bounds):
     re-sort of the partial slots.
     → {"words", "payloads", "live", "n_groups": 0} (nothing here can
     overflow a group capacity)."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import factorize as F
     keys = [e.eval(ctx) for e in root.group_exprs]
     payloads = []
     for desc in root.aggs:
@@ -469,10 +455,6 @@ def _runs_states(root, aggs, runs, payloads, live_s, arg_bits=()):
     the trace, and counter `tidb_tpu_run_sum_scans_total{range=
     bounded|whole}`, the scans by whether the word's fields had known
     widths."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import segment as seg
-    from tidb_tpu.util import timeline
-    from tidb_tpu.util.observability import REGISTRY
     n = live_s.shape[0]
     inputs, named, i = [], {}, 0
     for desc in root.aggs:
@@ -504,7 +486,6 @@ def _fill_states(aggs, plans, inputs, gids, cap: int, sums):
     """The state tuples from the sums of the aggregates' planned columns
     (`AggFunc.row_sums`, in the columns' order); an aggregate without a
     plan runs its own `update`."""
-    from tidb_tpu.ops.jax_env import jnp
     sums = iter(sums)
     states = []
     for agg, plan, (v, m) in zip(aggs, plans, inputs):
@@ -528,10 +509,6 @@ def emit_runs_finalize(root, order_root, aggs: List[AggFunc], cap: int,
     (`topn_select`; an ORDER BY without a limit sorts).
     → {keys, states, n_groups[, n_out]} as emit_merge / emit_finalize
     give them."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import factorize as F
-    from tidb_tpu.ops.segment import SortedRuns
-    from tidb_tpu.planner.physical import PhysTopN
     runs = SortedRuns(rows["ends"], rows["n_runs"], cap)
     live_s = rows["words"][0] != jnp.int64(F.DEAD_WORD)
     states = _runs_states(root, aggs, runs, rows["payloads"], live_s,
@@ -565,8 +542,6 @@ def _perfect_groups(ctx: EvalContext, live, root, cap: int,
     (executor/aggregate.go getGroupKey), minus the sort factorize's
     O(n log n) multi-operand bitonic sort. cap == the packed key domain.
     """
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import segment as seg
     n = live.shape[0]
     keys = [e.eval(ctx) for e in root.group_exprs]
     # packed code: per-key code 0 = NULL (its own group), else 1+v-lo
@@ -609,7 +584,6 @@ def _rollup_tile(ctx: EvalContext, live, root):
     level replication.  Wide-decimal limb planes are 2-D (limbs, rows),
     so values concatenate along the LAST axis; 1-D masks along axis 0 is
     the same thing."""
-    from tidb_tpu.ops.jax_env import jnp
     reps = len(root.group_exprs) + 1
 
     def t(a):
@@ -632,8 +606,6 @@ def _distinct_arg(ctx: EvalContext, live, desc):
     where ANY DISTINCT argument is NULL). `vcols` keeps the raw per-arg
     (value, mask) columns for the cross-slab pair output — the combined
     code is batch-local and cannot be compared across slabs."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import factorize as F
     vcols = []
     m = live
     for a in desc.args:
@@ -675,11 +647,6 @@ def _agg_states(ctx, live, root, aggs, gids, cap: int, n: int,
     covers the trace; and of a contraction counter
     `tidb_tpu_slot_sum_columns_total{range=bounded|whole}`, its distinct
     summed values by whether a width was known."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import factorize as F
-    from tidb_tpu.ops import segment as seg
-    from tidb_tpu.util import timeline
-    from tidb_tpu.util.observability import REGISTRY
     evaluated = {}      # an argument → its (values, validity)
     inputs = []
     for ai, (agg, desc) in enumerate(zip(aggs, root.aggs)):
@@ -745,7 +712,6 @@ def emit_window(ctx: EvalContext, live, root):
     spec, then the cumulative/segment primitives of ops/window.py traced
     with jnp (the whole-column reformulation of executor/window.go).
     → {cols, live} with the window outputs appended to the child columns."""
-    from tidb_tpu.ops.jax_env import jnp
     n_child = len(root.children[0].schema)
     in_cols = [ctx.column(i) for i in range(n_child)]
     out_cols = emit_window_cols(ctx, live, root, in_cols)
@@ -760,8 +726,6 @@ def emit_window_cols(ctx: EvalContext, live, root, in_cols):
     per window spec. Shared by the window-ROOT emit above and the
     interior-window case of TreeProgram._emit, where the appended
     columns feed the operator above in the same trace."""
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import factorize as F
     n = live.shape[0]
     out_cols = list(in_cols)
     layouts = {}
@@ -805,9 +769,6 @@ def emit_window_cols(ctx: EvalContext, live, root, in_cols):
 
 
 def _window_value(ctx, live, d, n, perm, pstart, peerstart):
-    from tidb_tpu.ops.jax_env import jnp
-    from tidb_tpu.ops import window as W
-    from tidb_tpu.types import TypeKind
     vals = valid = fill = None
     if d.args:
         v, m = d.args[0].eval(ctx)
@@ -859,7 +820,6 @@ def emit_partition(arrays: Sequence, dest, live, n_shards: int,
     → (bufs [(n_shards*bucket_cap,)...], sent_live, counts (n_shards,),
        need ()). Within bucket d the prefix [0:counts[d]] is contiguous
     live rows (rows are ranked densely per destination)."""
-    from tidb_tpu.ops.jax_env import jax, jnp, lax
     n = dest.shape[0]
     iota = jnp.arange(n, dtype=jnp.int32)
     d = jnp.where(live, dest, jnp.int32(n_shards))  # dead rows → no bucket
@@ -893,7 +853,7 @@ def emit_batched(partial_fn, name: str):
     micro-batcher (executor/microbatch.py) slices that axis back out,
     one lane per waiting session. → the jitted batched callable
     `(cols, n_rows, stacked_preps) -> outputs`, compiled under `name`."""
-    from tidb_tpu.ops.jax_env import jax, named_jit
+    from tidb_tpu.ops.jax_env import named_jit
 
     def batched(cols, n_rows, stacked_preps):
         return jax.vmap(partial_fn,
@@ -921,7 +881,7 @@ DELTA_SCOPES = ("delta_merge", "tombstone")
 _DELTA_PROGRAMS: dict = {}
 
 
-def _delta_program(kind: str, key: tuple, build, **jit_kwargs):
+def delta_program(kind: str, key: tuple, build, **jit_kwargs):
     """The jitted program `<kind>_<sig8 of key>`, built once a process:
     `key` holds every constant `build()`'s function closes over (and
     stands for `jit_kwargs`, e.g. a donated argument)."""
@@ -936,7 +896,6 @@ def _delta_program(kind: str, key: tuple, build, **jit_kwargs):
 def emit_delta_alloc(specs, cap: int):
     """Empty raw delta slabs, made on the device (nothing crosses PCIe):
     `specs` = [(leading shape, dtype name)] per column → [(vals, mask)]."""
-    from tidb_tpu.ops.jax_env import jax, jnp
     specs = tuple((tuple(lead), str(dt)) for lead, dt in specs)
 
     def build():
@@ -945,7 +904,7 @@ def emit_delta_alloc(specs, cap: int):
                 return [(jnp.zeros(lead + (cap,), dtype=dt),
                          jnp.zeros(cap, dtype=bool)) for lead, dt in specs]
         return _alloc
-    return _delta_program("delta_merge", ("alloc", specs, cap), build)()
+    return delta_program("delta_merge", ("alloc", specs, cap), build)()
 
 
 def emit_delta_append(slab, chunk_vals, chunk_mask, offset: int, n: int,
@@ -954,7 +913,6 @@ def emit_delta_append(slab, chunk_vals, chunk_mask, offset: int, n: int,
     bucket) into a raw delta slab at row `offset` → the new (vals, mask).
     A scatter with the padding's indices out of range, so the rows beyond
     `n` are dropped and a write near the capacity cannot shift."""
-    from tidb_tpu.ops.jax_env import jax, jnp
     bucket = int(chunk_mask.shape[0])
     lead = tuple(chunk_vals.shape[:-1])
     key = ("append", lead, str(chunk_vals.dtype), bucket, cap)
@@ -967,21 +925,59 @@ def emit_delta_append(slab, chunk_vals, chunk_mask, offset: int, n: int,
                 return (v.at[..., idx].set(cv, mode="drop"),
                         m.at[idx].set(cm, mode="drop"))
         return _append
-    return _delta_program("delta_merge", key, build)(
+    return delta_program("delta_merge", key, build)(
         slab[0], slab[1], chunk_vals, chunk_mask, jnp.int32(offset),
         jnp.int32(n))
 
 
+# ---------------------------------------------------------------------------
+# The folded slab layout (how a column's stack holds a slab's 1-D leaf)
+# ---------------------------------------------------------------------------
+
+LANES = 128     # the minor dimension of a device tile
+
+
+def folded(shape: tuple) -> tuple:
+    """The shape a slab's leaf has inside its column's stack. The device
+    tiles an array's two minor dimensions: stacked as (slabs, rows), the
+    SLAB axis of a 1-D leaf would lie inside a tile — padded to 8 (six
+    slabs take the HBM of eight) and every slab read with a stride. Folded
+    to (rows / 128, 128) the slab axis stays outside, a slab is as
+    contiguous as it was alone, and slab ↔ its 1-D form is a bitcast
+    (compiled for the v5e: `tests/test_tpu_compile.py`). A length that is
+    no multiple of 128 (a small table's) is padded up to one: its slab is
+    the fold's first rows."""
+    if len(shape) != 1:
+        return shape
+    return (-(-shape[0] // LANES), LANES)
+
+
+def fold(a):
+    """One slab's leaf as its column's stack holds it (`folded`)."""
+    if a.ndim != 1:
+        return a
+    pad = -a.shape[0] % LANES
+    return (jnp.pad(a, (0, pad)) if pad else a).reshape(folded(a.shape))
+
+
+def unfold(a, shape: tuple):
+    """A slab of a stack → the leaf in its own shape (a bitcast, and a
+    cut where the fold was padded)."""
+    if len(shape) != 1:
+        return a
+    a = a.reshape(-1)
+    return a if a.shape[0] == shape[0] else a[:shape[0]]
+
+
 def emit_alive_init(n_live: int, cap: int):
     """The liveness mask of a slab that has only a live prefix."""
-    from tidb_tpu.ops.jax_env import jax, jnp
 
     def build():
         def _init(n):
             with jax.named_scope("tombstone"):
                 return jnp.arange(cap, dtype=jnp.int32) < n
         return _init
-    return _delta_program("tombstone", ("init", cap), build)(
+    return delta_program("tombstone", ("init", cap), build)(
         jnp.int32(n_live))
 
 
@@ -990,18 +986,16 @@ def emit_alive_stack(counts, cap: int):
     (`counts` rows each, a host int32 vector), made as ONE array the way
     `device_cache.SlabColumn` stacks a column: slabs × a slab's rows
     folded in two."""
-    from tidb_tpu.executor.device_cache import _folded
-    from tidb_tpu.ops.jax_env import jax, jnp
-    fold = _folded((cap,))
+    rows, lanes = folded((cap,))
 
     def build():
         def _init(n):
             with jax.named_scope("tombstone"):
-                pos = jnp.arange(fold[0] * fold[1],
-                                 dtype=jnp.int32).reshape(fold)
+                pos = jnp.arange(rows * lanes,
+                                 dtype=jnp.int32).reshape(rows, lanes)
                 return pos[None] < n[:, None, None]
         return _init
-    return _delta_program("tombstone", ("init", cap, len(counts)), build)(
+    return delta_program("tombstone", ("init", cap, len(counts)), build)(
         jnp.asarray(list(counts), dtype=jnp.int32))
 
 
@@ -1013,7 +1007,6 @@ def emit_alive_update(alive, born, dead, cap: int, stacked: bool = False):
     slabs × a slab's rows folded in two) and the rows are positions in the
     whole base (padded with its size): one rewrite whatever slabs they
     fall in."""
-    from tidb_tpu.ops.jax_env import jax
     key = ("update", int(born.shape[0]), int(dead.shape[0]), cap) + \
         (tuple(alive.shape) if stacked else ())
 
@@ -1022,11 +1015,11 @@ def emit_alive_update(alive, born, dead, cap: int, stacked: bool = False):
             with jax.named_scope("tombstone"):
                 if stacked:
                     # (a slab's rows lie folded in two, `cap` of them
-                    # from the fold's start: `device_cache._fold`)
+                    # from the fold's start: `fold`)
                     c = a.shape[2]
                     b, d = ((p // cap, p % cap // c, p % cap % c)
                             for p in (b, d))
                 return a.at[b].set(True, mode="drop") \
                         .at[d].set(False, mode="drop")
         return _update
-    return _delta_program("tombstone", key, build)(alive, born, dead)
+    return delta_program("tombstone", key, build)(alive, born, dead)

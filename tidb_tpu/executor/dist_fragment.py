@@ -49,26 +49,48 @@ Fault recovery comes in two grades:
     program below — where fault retry stays full-step because the
     collectives entangle every rank's state — is kept as the
     byte-exactness oracle (`set tidb_tpu_dist_staged_exchange = off`).
+
+The drivers at the end (`run_device_dist`, what `TpuFragmentExec`
+dispatches a distributed plan to) pick among the three and decode what
+they gather. No benchmark cell runs any of this (PERF.md §7).
 """
 
 from __future__ import annotations
 
 import copy
+import dataclasses
 import time
+import types
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from tidb_tpu.executor.tree_fragment import (JOIN_OUT_CAP, JoinCfg,
-                                             TreeProgram, _scans,
-                                             _walk_nodes, dictionary_flows,
+from tidb_tpu.chunk import Chunk, Column, compress
+from tidb_tpu.errors import ShardFailure
+from tidb_tpu.executor import (agg_slabs as A, compile_cache, device_cache,
+                               device_emit, empty_chunk, host_decode,
+                               scheduler, zonemap)
+from tidb_tpu.executor.eligibility import (FragmentFallback, fragment_ok,
+                                           linearize, scans_of,
+                                           strip_order_root, walk_nodes)
+from tidb_tpu.executor.tree_fragment import (JoinCfg, TreeProgram,
+                                             dictionary_flows,
                                              escalate_join,
                                              plan_join_configs,
-                                             tree_signature)
+                                             trace_scan_col, tree_signature)
+from tidb_tpu.expression import ColumnRef
+from tidb_tpu.expression.aggfuncs import build_agg
+from tidb_tpu.ops import factorize
+from tidb_tpu.ops.jax_env import jax, jnp, lax
 from tidb_tpu.planner.physical import (PhysExchange, PhysHashAgg,
-                                       PhysProjection, PhysSelection,
-                                       PhysSort, PhysTableScan, PhysTopN,
-                                       PhysWindow, PhysicalPlan)
+                                       PhysHashJoin, PhysProjection,
+                                       PhysSelection, PhysSort,
+                                       PhysTableScan, PhysTopN, PhysWindow,
+                                       PhysicalPlan)
+from tidb_tpu.sysvars import var_int, var_on
+from tidb_tpu.util import failpoint, timeline
+from tidb_tpu.util.escalation import CapacityLadder, pow2
+from tidb_tpu.util.phases import tree_nbytes
 
 AXIS = "shard"
 
@@ -85,7 +107,7 @@ class DistTreeProgram(TreeProgram):
                  group_cap: int, mesh, bucket_caps: Dict[int, int],
                  join_cfgs: Optional[Sequence[JoinCfg]] = None,
                  scan_layouts=None, kind: str = "dist", sig: str = ""):
-        from tidb_tpu.ops.jax_env import jax, named_jit, shard_map
+        from tidb_tpu.ops.jax_env import named_jit, shard_map
         self.mesh = mesh
         self.n_shards = mesh.devices.size
         self.bucket_caps = bucket_caps    # id(exchange-node) → bucket cap
@@ -114,7 +136,6 @@ class DistTreeProgram(TreeProgram):
                  aligned_inputs=()):
         # the dist path keeps the 3-arg shard_map signature (FK-aligned
         # join structures are a single-chip cache)
-        from tidb_tpu.util import failpoint
         # host-side per-shard dispatch seam: shard_map traces ONE body
         # for all shards, so a per-shard fault cannot raise inside the
         # trace — instead the "shard-step" site fires once per rank here
@@ -127,7 +148,6 @@ class DistTreeProgram(TreeProgram):
 
     # -- traced per-shard body ----------------------------------------------
     def _run(self, scan_inputs, scan_rows, prep_vals):
-        from tidb_tpu.ops.jax_env import jnp, lax
         self._prepared = {id(n): v
                           for n, v in zip(self.prep_nodes, prep_vals)
                           if v is not None}
@@ -162,7 +182,6 @@ class DistTreeProgram(TreeProgram):
         return out
 
     def _emit(self, node: PhysicalPlan, scan_inputs, scan_rows):
-        from tidb_tpu.ops.jax_env import jnp
         from tidb_tpu.parallel import collective as C
         if isinstance(node, PhysTableScan):
             slot = next(i for i, s in enumerate(self.scan_order)
@@ -181,7 +200,6 @@ class DistTreeProgram(TreeProgram):
                     # compressed shard slab: decode inside the
                     # shard_map body, so PCIe/ICI only ever carried
                     # the packed words
-                    from tidb_tpu.executor import device_emit
                     c = device_emit.emit_decode(lays[i], c, cap)
                 col_list.append(c)
             ctx = self._ctx(col_list)
@@ -211,8 +229,6 @@ class DistTreeProgram(TreeProgram):
 
     # -- distributed root reductions -----------------------------------------
     def _finish_dist(self, cols, live):
-        from tidb_tpu.ops.jax_env import jnp, lax
-        from tidb_tpu.ops import factorize as F
         from tidb_tpu.parallel import collective as C
         root = self.plan
         if isinstance(root, PhysHashAgg):
@@ -222,7 +238,7 @@ class DistTreeProgram(TreeProgram):
             # ---- per-shard partial (the MPP task's partial agg) ----
             if root.group_exprs:
                 keys = [e.eval(ctx) for e in root.group_exprs]
-                gids, n_groups, rep = F.factorize(keys, live, cap)
+                gids, n_groups, rep = factorize.factorize(keys, live, cap)
                 gids = jnp.where(live, gids, jnp.int32(cap))
                 slot_live = jnp.arange(cap, dtype=jnp.int32) < n_groups
                 key_out = [(jnp.asarray(v)[rep], jnp.asarray(m)[rep] &
@@ -233,10 +249,10 @@ class DistTreeProgram(TreeProgram):
                 slot_live = jnp.arange(cap, dtype=jnp.int32) < 1
                 key_out = []
                 gneed = jnp.int32(0)
-            from tidb_tpu.executor.device_emit import agg_states
             # DISTINCT dedup is exact per shard: the planner re-keyed the
             # exchange on the group keys, so a group's rows never split
-            states = agg_states(ctx, live, root, self.aggs, gids, cap, n)
+            states = device_emit.agg_states(ctx, live, root, self.aggs,
+                                            gids, cap, n)
             # ---- gather partials, merge owned groups ----
             gkeys, gstates, gslot = C.gather_partials(
                 key_out, [tuple(st) for st in states], slot_live, AXIS)
@@ -248,7 +264,7 @@ class DistTreeProgram(TreeProgram):
                 owner = jnp.zeros(gslot.shape[0], dtype=jnp.int32)
             own = gslot & (owner == rank)
             if root.group_exprs:
-                fgids, n_own, frep = F.factorize(gkeys, own, cap)
+                fgids, n_own, frep = factorize.factorize(gkeys, own, cap)
                 fgids = jnp.where(own, fgids, jnp.int32(cap))
                 out_live = jnp.arange(cap, dtype=jnp.int32) < n_own
                 f_keys = [(jnp.asarray(v)[frep],
@@ -279,9 +295,9 @@ class DistTreeProgram(TreeProgram):
             n_out_cols = len(root.schema)
             if isinstance(root, PhysTopN):
                 k = min(root.count + root.offset, n)
-                idx, n_out = F.topn(keys, root.descs, live, k)
+                idx, n_out = factorize.topn(keys, root.descs, live, k)
             else:
-                idx, n_out = F.sort_perm(keys, root.descs, live)
+                idx, n_out = factorize.sort_perm(keys, root.descs, live)
             gathered = [(jnp.take(jnp.asarray(v), idx),
                          jnp.take(jnp.asarray(m), idx))
                         for v, m in cols[:n_out_cols]]
@@ -291,7 +307,6 @@ class DistTreeProgram(TreeProgram):
         if isinstance(root, PhysWindow):
             # ---- window root: the exchange co-located every partition on
             # one shard, so per-shard emit_window is globally exact ----
-            from tidb_tpu.executor import device_emit
             ctx = self._ctx(cols)
             out = device_emit.emit_window(ctx, live, root)
             out["_gneed_local"] = jnp.int32(0)
@@ -358,9 +373,6 @@ class StagedDistAgg:
         {"ng", "keys", "states"} ready for _merge_tree_agg_passes.
         Pruned ranks carry the ng=0 identity checkpoint (the merge
         skips ng==0 passes)."""
-        from tidb_tpu.executor.fragment import (FragmentFallback,
-                                                _GroupCapOverflow,
-                                                _note_grouping, get_program)
         ckpts: List[Optional[dict]] = [None] * self.nd
         ng_true = [0] * self.nd
         caps_ran = [0] * self.nd
@@ -371,8 +383,8 @@ class StagedDistAgg:
             # between dispatch rounds is a guard checkpoint: a killed
             # query must not queue another per-rank compile
             self.ctx.check_killed("device-dispatch")
-            self.grouping = _note_grouping(self.root, None, self.group_cap)
-            prog = get_program(self.chain, self.used_cols, self.in_types,
+            self.grouping = A.note_grouping(self.root, None, self.group_cap)
+            prog = A.get_program(self.chain, self.used_cols, self.in_types,
                                self.slab_cap, self.group_cap,
                                layouts=self.layouts or None)
             prep_vals = prog.collect_preps(self.dicts)
@@ -391,21 +403,18 @@ class StagedDistAgg:
             need = max(ng_true[r] for r in over)
             self.group_cap = self.ladder.resize(
                 "group", self.group_cap, need=need, max_cap=self.cap_limit)
-            self.ladder.attempt("group", _GroupCapOverflow(need))
+            self.ladder.attempt("group", A.GroupCapOverflow(need))
             self.ladder.partial_resume("group", rerun=len(over),
                                        reused=self.nd - len(over))
             to_run = over
 
     @staticmethod
     def _is_shard_fault(e: BaseException) -> bool:
-        from tidb_tpu.errors import ShardFailure
         return isinstance(e, ShardFailure) or \
             type(e).__name__ == "XlaRuntimeError"
 
     def _run_rank(self, r: int, prog, prep_vals):
         """One rank's local work through the per-shard recovery ladder."""
-        from tidb_tpu.errors import ShardFailure
-        from tidb_tpu.util import failpoint
         try:
             return self._attempt(r, self.devices[r], prog, prep_vals,
                                  site="shard-step")
@@ -447,10 +456,6 @@ class StagedDistAgg:
     def _attempt(self, r: int, dev, prog, prep_vals, site: str):
         """Upload rank r's host slice onto `dev`, run the partial there,
         fetch its checkpoint → ({"ng", "keys", "states"}, true_count)."""
-        from tidb_tpu.executor.fragment import (_count_agg_partial,
-                                                _tree_delete)
-        from tidb_tpu.ops.jax_env import jax, jnp
-        from tidb_tpu.util import failpoint
         ph = self.ctx.phases
         dcols = None
         out = None
@@ -463,25 +468,24 @@ class StagedDistAgg:
                 dcols = {i: tuple(jax.device_put(a, dev)
                                   for a in self.rank_cols[r][i])
                          for i in prog.used_cols}
-            from tidb_tpu.chunk import compress as _compress
             _rank_b = sum(a.nbytes for i in prog.used_cols
                           for a in self.rank_cols[r][i])
             _rank_lb = sum(
-                (_compress.raw_slab_bytes(self.layouts[i], self.slab_cap)
+                (compress.raw_slab_bytes(self.layouts[i], self.slab_cap)
                  if self.layouts.get(i) is not None
                  else sum(a.nbytes for a in self.rank_cols[r][i]))
                 for i in prog.used_cols)
             ph.add_h2d(_rank_b, logical=_rank_lb)
             # the rank's partial streams these slabs
             ph.add_scan(_rank_b, logical=_rank_lb)
-            with self.ctx.device_slot():
+            with scheduler.device_slot(self.ctx):
                 with ph.launch(prog.partial_name, slab=r):
                     out = prog.partial(dcols,
                                        jnp.int32(int(self.rank_rows[r])),
                                        prep_vals)
             ph.note_launch()
             ph.note_fused()   # per-rank chain partial = fused local stage
-            _count_agg_partial(self.grouping)
+            A.count_agg_partial(self.grouping)
             with ph.drain():
                 # drain outside the scheduler slot (GIL-released wait):
                 # sibling statements dispatch while this rank executes
@@ -497,7 +501,6 @@ class StagedDistAgg:
                     {"keys": [(v[:k], m[:k]) for v, m in out["keys"]],
                      "states": [tuple(a[:k] for a in st)
                                 for st in out["states"]]})
-            from tidb_tpu.util.phases import tree_nbytes
             ph.add_d2h(tree_nbytes(got) + 4)
             return ({"ng": k, "keys": got["keys"],
                      "states": got["states"]}, ngt)
@@ -506,14 +509,13 @@ class StagedDistAgg:
             # on success the host checkpoint is now authoritative, on a
             # fault the abandoned buffers must be gone BEFORE the retry /
             # re-dispatch uploads its generation (never 2× HBM residency)
-            _tree_delete(dcols)
-            _tree_delete(out)
+            compile_cache.tree_delete(dcols)
+            compile_cache.tree_delete(out)
 
     def _warn_degraded(self, r: int, err: BaseException) -> None:
         """Degraded-mesh completion is a typed, retryable warning on the
         statement guard (surfaced by SHOW WARNINGS), NOT an error — the
         result is complete and exact; only the mesh shrank."""
-        from tidb_tpu.errors import ShardFailure
         guard = getattr(self.ctx, "guard", None)
         if guard is not None and hasattr(guard, "warnings"):
             guard.warnings.append(
@@ -541,7 +543,7 @@ def _exchange_scan_chain(node: PhysicalPlan) -> Optional[PhysTableScan]:
 
 
 def _has_exchange(node: PhysicalPlan) -> bool:
-    return any(isinstance(n, PhysExchange) for n in _walk_nodes(node))
+    return any(isinstance(n, PhysExchange) for n in walk_nodes(node))
 
 
 class _ExchangeLeaf(PhysTableScan):
@@ -554,9 +556,8 @@ class _ExchangeLeaf(PhysTableScan):
     the pushed-down conjuncts before partitioning."""
 
     def __init__(self, exch: PhysExchange, tag: int):
-        import types as pytypes
         PhysicalPlan.__init__(self, exch.schema)
-        self.table = pytypes.SimpleNamespace(id=f"staged-exch:{tag}")
+        self.table = types.SimpleNamespace(id=f"staged-exch:{tag}")
         self.alias = None
         self.filters = []
         self.used_columns = None
@@ -571,13 +572,13 @@ def staged_exchange_plan(root: PhysicalPlan):
     Sort root, whose per-shard candidate emission + host k-way merge IS
     the monolithic root reduction; or an exchange whose child is not a
     plain scan chain), else (new_root, grafts) where grafts pairs each
-    PhysExchange with its stage-3 _ExchangeLeaf in _walk_nodes order.
+    PhysExchange with its stage-3 _ExchangeLeaf in walk_nodes order.
     new_root is a CLONE of the upper plan — ancestors of an exchange are
     copy.copy'd with fresh children lists, never mutated, because cached
     TreePrograms hold references into the original plan. Exchange-free
     subtrees (e.g. a broadcast join's probe side) are reused as-is so
     their scan/prep identities survive into the rewritten plan."""
-    exchanges = [n for n in _walk_nodes(root) if isinstance(n, PhysExchange)]
+    exchanges = [n for n in walk_nodes(root) if isinstance(n, PhysExchange)]
     if not exchanges:
         return None
     if isinstance(root, (PhysTopN, PhysSort)):
@@ -626,8 +627,6 @@ class _PartitionProgram(TreeProgram):
         return super()._emit(node, scan_inputs, scan_rows)
 
     def _finish(self, cols, live):
-        from tidb_tpu.executor import device_emit
-        from tidb_tpu.ops.jax_env import jnp
         from tidb_tpu.parallel import collective as C
         exch = self.plan
         present = [i for i, c in enumerate(cols) if c is not None]
@@ -685,10 +684,6 @@ class StagedDistExchange:
     def __init__(self, root, new_root, grafts, mesh, host_cols, scan_meta,
                  ctx, ladder):
         from dataclasses import replace as d_replace
-
-        from tidb_tpu.chunk import compress as _compress
-        from tidb_tpu.executor.device_cache import _col_bounds, _pow2
-        from tidb_tpu.executor.fragment import _var_bool
         self.root = root
         self.new_root = new_root
         self.mesh = mesh
@@ -698,7 +693,7 @@ class StagedDistExchange:
         self.ladder = ladder
         nd = self.nd
         vars_ = ctx.vars
-        comp_on = _var_bool(vars_.get("tidb_tpu_compression", "on"))
+        comp_on = var_on(vars_, "tidb_tpu_compression")
         meta = {id(s): (s, u, t) for s, u, t in scan_meta}
         scan_dicts_all = {id(s): {i: host_cols[(id(s), i)][2] for i in u}
                           for s, u, t in scan_meta}
@@ -710,22 +705,20 @@ class StagedDistExchange:
             rank's slice), compressed per rank like StagedDistAgg's —
             each rank packs its own slab, so no word-alignment
             constraint applies and layouts are chosen globally."""
-            cap = _pow2((total + nd - 1) // nd, lo=8)
+            cap = pow2((total + nd - 1) // nd, lo=8)
             layouts = {}
             if comp_on:
                 for i in used:
                     vals, valid, _d = host_cols[(id(scan), i)]
                     if vals.ndim != 1:
                         continue
-                    lay, _dv = _compress.choose_layout(vals, valid,
+                    lay, _dv = compress.choose_layout(vals, valid,
                                                        allow_dict=False)
                     if lay is not None and lay.width > 0:
                         layouts[i] = lay
             dicts = {i: host_cols[(id(scan), i)][2] for i in used}
             skip: frozenset = frozenset()
             if zone_prune and comp_on and getattr(scan, "filters", None):
-                from tidb_tpu.executor import zonemap
-                from tidb_tpu.executor.fragment import _RankZoneEnt
                 zmaps = {}
                 for i in used:
                     vals, valid, _d = host_cols[(id(scan), i)]
@@ -757,7 +750,7 @@ class StagedDistExchange:
                     segm = valid[lo:lo + cap]
                     pm[:segm.shape[0]] = segm
                     lay = layouts.get(i)
-                    cols[i] = _compress.pack_slab(lay, pv, pm) \
+                    cols[i] = compress.pack_slab(lay, pv, pm) \
                         if lay is not None else (pv, pm)
                 rank_cols.append(cols)
             rank_rows = np.clip(total - np.arange(nd) * cap, 0,
@@ -771,8 +764,7 @@ class StagedDistExchange:
         # stage-1 sources: one per exchange, zone-map rank pruning on (a
         # pruned rank partitions nothing — its checkpoint is the empty-
         # buckets identity, filled after a real checkpoint fixes dtypes)
-        cap_override = int(vars_.get("tidb_tpu_exchange_bucket_cap", 0)
-                           or 0)
+        cap_override = var_int(vars_, "tidb_tpu_exchange_bucket_cap")
         self.exchanges: List[dict] = []
         for tag, (exch, leaf) in enumerate(grafts):
             scan = _exchange_scan_chain(exch.children[0])
@@ -782,11 +774,11 @@ class StagedDistExchange:
             info.update({
                 "exch": exch, "leaf": leaf, "tag": tag,
                 "bcaps": [cap_override
-                          or _pow2(4 * ((est + nd - 1) // nd), lo=64)] * nd,
+                          or pow2(4 * ((est + nd - 1) // nd), lo=64)] * nd,
             })
             fl, _ = dictionary_flows(exch, {id(scan): info["dicts"]})
             info["flow_list"] = [fl.get(id(n), [])
-                                 for n in _walk_nodes(exch)]
+                                 for n in walk_nodes(exch)]
             # the exchange's dictionary_flows entry IS its output dict
             # list — the leaf's scan dictionaries for the stage-3 flows
             info["leaf_dicts"] = {i: d for i, d in
@@ -795,7 +787,7 @@ class StagedDistExchange:
 
         # direct (non-exchanged) scans surviving into the stage-3 plan
         self.direct: Dict[int, dict] = {}
-        for scan in _scans(new_root):
+        for scan in scans_of(new_root):
             if isinstance(scan, _ExchangeLeaf):
                 continue
             _s, used, total = meta[id(scan)]
@@ -809,14 +801,14 @@ class StagedDistExchange:
         self.flows2, self.root_dicts2 = dictionary_flows(new_root,
                                                          scan_dicts3)
         self.flow_list2 = [self.flows2.get(id(n), [])
-                           for n in _walk_nodes(new_root)]
+                           for n in walk_nodes(new_root)]
 
         scan_bounds = {}
         for sid, d in self.direct.items():
             b = {}
             for i in d["used"]:
                 vals, valid, dictionary = host_cols[(sid, i)]
-                bb = _col_bounds(vals, valid, dictionary)
+                bb = device_cache.col_bounds(vals, valid, dictionary)
                 if bb is not None:
                     b[i] = bb
             scan_bounds[sid] = b
@@ -824,18 +816,13 @@ class StagedDistExchange:
         self.join_cfgs = [d_replace(c, out_cap=self._shard_out_cap(c))
                           if c.mode == "expand" else c
                           for c in self.join_cfgs]
-        self.out_cap_max = int(vars_.get("tidb_tpu_join_out_cap",
-                                         JOIN_OUT_CAP))
-
-        from tidb_tpu.executor.fragment import (DEFAULT_GROUP_CAP,
-                                                _initial_group_cap)
+        self.out_cap_max = var_int(vars_, "tidb_tpu_join_out_cap")
         caps_all = [d["cap"] for d in self.direct.values()] + \
             [i["cap"] for i in self.exchanges]
         self.cap_limit = max(caps_all) * nd
         if isinstance(new_root, PhysHashAgg):
-            self.gcap = _initial_group_cap(
-                new_root, int(vars_.get("tidb_tpu_group_cap",
-                                        DEFAULT_GROUP_CAP)),
+            self.gcap = A.initial_group_cap(
+                new_root, var_int(vars_, "tidb_tpu_group_cap"),
                 self.cap_limit)
         else:
             self.gcap = 1
@@ -844,8 +831,7 @@ class StagedDistExchange:
     def _shard_out_cap(self, cfg) -> int:
         # expand caps are PER SHARD: the balanced share of the global
         # estimate; skew comes back as join_need → 1 retry
-        from tidb_tpu.executor.device_cache import _pow2
-        return _pow2(int(cfg.est * 1.3 / self.nd) + 16, lo=1024)
+        return pow2(int(cfg.est * 1.3 / self.nd) + 16, lo=1024)
 
     # -- per-rank fault ladder (shared by every stage) ----------------------
 
@@ -854,8 +840,6 @@ class StagedDistExchange:
         StagedDistAgg._run_rank's rungs with the staged-exchange
         degraded/re-dispatch failpoints. `attempt(device, site)` runs
         the stage once; only the failed rank climbs the ladder."""
-        from tidb_tpu.errors import ShardFailure
-        from tidb_tpu.util import failpoint
         try:
             return attempt(self.devices[r], "shard-step")
         except Exception as e1:
@@ -888,7 +872,6 @@ class StagedDistExchange:
         """One retryable warning per RECOVERED RANK (not per surviving
         rank): degraded-mesh completion is complete and exact — only the
         mesh shrank (surfaced by SHOW WARNINGS / EXPLAIN ANALYZE)."""
-        from tidb_tpu.errors import ShardFailure
         guard = getattr(self.ctx, "guard", None)
         if guard is not None and hasattr(guard, "warnings"):
             guard.warnings.append(
@@ -900,9 +883,6 @@ class StagedDistExchange:
     # -- stage 1: partition programs + bucket checkpoints -------------------
 
     def _stage1_program(self, info: dict, bcap: int) -> _PartitionProgram:
-        from tidb_tpu.executor.fragment import (_build_lock, _cache_get,
-                                                _cache_put,
-                                                _charge_compile)
         exch, scan = info["exch"], info["scan"]
         caps = {id(scan): (info["cap"], 1)}
         # the PER-RANK bucket cap is part of the signature: a skewed
@@ -911,26 +891,21 @@ class StagedDistExchange:
         sig = (f"stagedx1|nd={self.nd}|bcap={bcap}|" +
                tree_signature(exch, caps, 0,
                               scan_layouts=(info["lay_pairs"],)))
-        prog = _cache_get(sig)
+        prog = compile_cache.cache_get(sig)
         if prog is None:
-            with _build_lock(sig):
-                prog = _cache_get(sig)
+            with compile_cache.build_lock(sig):
+                prog = compile_cache.cache_get(sig)
                 if prog is None:
                     t0 = time.perf_counter()
                     prog = _PartitionProgram(
                         exch, caps, self.nd, bcap,
                         scan_layouts=(info["lay_pairs"],))
-                    _cache_put(sig, prog)
-                    _charge_compile("dist", t0)
+                    compile_cache.cache_put(sig, prog)
+                    compile_cache.charge_compile("dist", t0)
         return prog
 
     def _attempt_stage1(self, r: int, dev, prog, prep_vals, info: dict,
                         bcap: int, site: str):
-        from tidb_tpu.chunk import compress as _compress
-        from tidb_tpu.executor.fragment import _tree_delete
-        from tidb_tpu.ops.jax_env import jax, jnp
-        from tidb_tpu.util import failpoint, timeline
-        from tidb_tpu.util.phases import tree_nbytes
         ph = self.ctx.phases
         dcols = None
         out = None
@@ -947,11 +922,11 @@ class StagedDistExchange:
                     b = sum(a.nbytes for a in t)
                     phys_b += b
                     lay = info["layouts"].get(i)
-                    logi_b += _compress.raw_slab_bytes(lay, info["cap"]) \
+                    logi_b += compress.raw_slab_bytes(lay, info["cap"]) \
                         if lay is not None else b
                 ph.add_h2d(phys_b, logical=logi_b)
                 ph.add_scan(phys_b, logical=logi_b)
-                with self.ctx.device_slot():
+                with scheduler.device_slot(self.ctx):
                     with ph.launch(prog.name, slab=r):
                         out = prog((dcols,),
                                    (jnp.int32(int(info["rank_rows"][r])),),
@@ -989,15 +964,13 @@ class StagedDistExchange:
             # eager-delete discipline (StagedDistAgg._attempt): abandoned
             # buffers must be gone BEFORE a retry / re-dispatch uploads
             # its generation — never 2× HBM residency
-            _tree_delete(dcols)
-            _tree_delete(out)
+            compile_cache.tree_delete(dcols)
+            compile_cache.tree_delete(out)
 
     def _run_stage1(self, info: dict) -> List[dict]:
         """All ranks' bucket checkpoints for one exchange. Faults climb
         the per-rank ladder; a bucket-cap overflow resizes ONLY the
         overflowed rank at its exact reported need and re-runs it."""
-        from tidb_tpu.executor.fragment import FragmentFallback
-        from tidb_tpu.util import failpoint
         nd = self.nd
         ckpts: List[Optional[dict]] = [None] * nd
         to_run = [r for r in range(nd) if r not in info["skip"]]
@@ -1058,9 +1031,7 @@ class StagedDistExchange:
         shared power-of-two capacity — the stage-3 leaf's slab. The
         shared cap keeps stage 3 ONE program for all ranks (skew shows
         up as padding, not as per-rank recompiles)."""
-        from tidb_tpu.executor.device_cache import _pow2
         from tidb_tpu.parallel import collective as C
-        from tidb_tpu.util import timeline
         nd = self.nd
         ph = self.ctx.phases
         with timeline.span("checkpoint", "checkpoint", pid=ph.conn_id,
@@ -1075,7 +1046,7 @@ class StagedDistExchange:
                 n = full[cols[0]][0].shape[0] if cols else 0
                 routed = [full] * nd
                 recv_rows = [n] * nd
-            recv_cap = _pow2(max(max(recv_rows), 1), lo=64)
+            recv_cap = pow2(max(max(recv_rows), 1), lo=64)
 
             def pad(bufs):
                 cols = {}
@@ -1100,12 +1071,6 @@ class StagedDistExchange:
     # -- stage 3: per-rank receive/probe/dedup programs ----------------------
 
     def _attempt_stage3(self, r: int, dev, prog, prep_vals, site: str):
-        from tidb_tpu.chunk import compress as _compress
-        from tidb_tpu.executor.fragment import (_count_agg_partial,
-                                                _note_grouping, _tree_delete)
-        from tidb_tpu.ops.jax_env import jax, jnp
-        from tidb_tpu.util import failpoint, timeline
-        from tidb_tpu.util.phases import tree_nbytes
         ph = self.ctx.phases
         root = self.new_root
         dcols = None
@@ -1125,20 +1090,20 @@ class StagedDistExchange:
                         b = sum(a.nbytes for a in t)
                         phys_b += b
                         lay = src["layouts"].get(i)
-                        logi_b += _compress.raw_slab_bytes(lay, src["cap"]) \
+                        logi_b += compress.raw_slab_bytes(lay, src["cap"]) \
                             if lay is not None else b
                 ph.add_h2d(phys_b, logical=logi_b)
                 ph.add_scan(phys_b, logical=logi_b)
                 rows = tuple(jnp.int32(int(src["rank_rows"][r]))
                              for src in self.stage3_order)
-                with self.ctx.device_slot():
+                with scheduler.device_slot(self.ctx):
                     with ph.launch(prog.name, slab=r):
                         out = prog(dcols, rows, prep_vals)
                 ph.note_launch()
                 ph.note_fused()
                 if isinstance(root, PhysHashAgg):
                     # the tag lands on this rank's `probe` span
-                    _count_agg_partial(_note_grouping(root, None, self.gcap))
+                    A.count_agg_partial(A.note_grouping(root, None, self.gcap))
                 with ph.drain():
                     jax.block_until_ready(out)
                 failpoint.inject("shard-checkpoint-write")
@@ -1164,12 +1129,10 @@ class StagedDistExchange:
                 ph.add_d2h(tree_nbytes(got) + 4)
                 return ck, ngt, ju, jt
         finally:
-            _tree_delete(dcols)
-            _tree_delete(out)
+            compile_cache.tree_delete(dcols)
+            compile_cache.tree_delete(out)
 
     def _run_stage3(self) -> List[dict]:
-        from tidb_tpu.executor.fragment import (FragmentFallback,
-                                                get_tree_program)
         nd = self.nd
         outs: List[Optional[dict]] = [None] * nd
         ng_true = [0] * nd
@@ -1184,7 +1147,7 @@ class StagedDistExchange:
         rounds = 0
         while True:
             self.ctx.check_killed("device-dispatch")
-            prog = get_tree_program(self.new_root, caps3, self.gcap,
+            prog = A.get_tree_program(self.new_root, caps3, self.gcap,
                                     join_cfgs=list(self.join_cfgs),
                                     scan_layouts=lays3)
             prep_vals = prog.collect_preps(self.flow_list2)
@@ -1253,7 +1216,7 @@ class StagedDistExchange:
             stage3_srcs[id(info["leaf"])] = \
                 dict(self._route(info, ckpts), scan=info["leaf"])
         self.stage3_order = []
-        for scan in _scans(self.new_root):
+        for scan in scans_of(self.new_root):
             if isinstance(scan, _ExchangeLeaf):
                 self.stage3_order.append(stage3_srcs[id(scan)])
             else:
@@ -1271,10 +1234,6 @@ def unify_string_join_dicts(root: PhysicalPlan, host_cols) -> None:
     cophandler/mpp_exec.go:158-173) and the probe-side KeyRemap LUT
     degenerates to identity. host_cols: (id(scan), col_idx) →
     [codes, valid, dictionary], mutated in place."""
-    from tidb_tpu.executor.fragment import FragmentFallback
-    from tidb_tpu.executor.tree_fragment import _trace_scan_col
-    from tidb_tpu.expression import ColumnRef
-    from tidb_tpu.planner.physical import PhysHashJoin
     parent: Dict = {}
 
     def find(x):
@@ -1292,7 +1251,7 @@ def unify_string_join_dicts(root: PhysicalPlan, host_cols) -> None:
         if ra != rb:
             parent[rb] = ra
 
-    for node in _walk_nodes(root):
+    for node in walk_nodes(root):
         if not isinstance(node, PhysHashJoin):
             continue
         for l, r in node.equi or []:
@@ -1303,9 +1262,9 @@ def unify_string_join_dicts(root: PhysicalPlan, host_cols) -> None:
                     "ci-collated join keys need fold-aware dictionary "
                     "unification (single-chip / CPU only)",
                     reason="string-dict")
-            lh = _trace_scan_col(node.children[0], l.index) \
+            lh = trace_scan_col(node.children[0], l.index) \
                 if isinstance(l, ColumnRef) else None
-            rh = _trace_scan_col(node.children[1], r.index) \
+            rh = trace_scan_col(node.children[1], r.index) \
                 if isinstance(r, ColumnRef) else None
             if lh is None or rh is None:
                 raise FragmentFallback(
@@ -1351,3 +1310,515 @@ def _unflatten_cols(flat, meta):
     for m in meta:
         out.append(None if m is None else (flat[m], flat[m + 1]))
     return out
+
+
+# ---------------------------------------------------------------------------
+# Drivers (the MPPGather role of executor/mpp_gather.go:42)
+# ---------------------------------------------------------------------------
+
+
+def _get_dist_program(root, caps, group_cap, mesh, bucket_caps,
+                      join_cfgs=None, scan_layouts=None):
+    bux = ",".join(str(bucket_caps[id(n)]) for n in walk_nodes(root)
+                   if isinstance(n, PhysExchange) and n.kind == "hash")
+    sig = (f"dist={mesh.devices.size}|bux={bux}|" +
+           tree_signature(root, caps, group_cap, join_cfgs,
+                          scan_layouts=scan_layouts))
+    return compile_cache.get_or_build(sig, "dist", lambda: DistTreeProgram(
+        root, caps, group_cap, mesh, dict(bucket_caps), join_cfgs,
+        scan_layouts, kind="dist", sig=sig))
+
+
+class _RankZoneEnt:
+    """Duck-typed zone-map carrier for staged-dist rank pruning: the
+    per-rank slice plays the slab role, so zonemap.prune_slabs runs
+    unchanged over rank-granular stats."""
+
+    __slots__ = ("compressed", "n_slabs", "zmaps", "dicts")
+
+    def __init__(self, nd: int, zmaps: dict, dicts: dict):
+        self.compressed = True
+        self.n_slabs = nd
+        self.zmaps = zmaps
+        self.dicts = dicts
+
+
+def _staged_dist_chain(root) -> Optional[List[PhysicalPlan]]:
+    """Root→scan chain when this dist fragment is eligible for the
+    staged checkpointable path: an agg root over an exchange-free
+    Scan/Selection/Projection chain (a PhysExchange anywhere breaks
+    linearize), no DISTINCT aggs (per-rank dedup cannot merge
+    without key co-location), and every stage device-capable for the
+    single-device chain program."""
+    if not isinstance(root, PhysHashAgg):
+        return None
+    if any(d.distinct and d.args for d in root.aggs):
+        return None
+    chain = linearize(root)
+    if chain is None or not fragment_ok(root, 0):
+        return None
+    return chain
+
+def _run_dist_agg_staged(ctx, schema, root, mesh, host_cols,
+                          scan_meta) -> Optional[Chunk]:
+    """Staged checkpointable dist agg (dist_fragment.StagedDistAgg):
+    per-rank partials → host checkpoints → host merge. Returns None
+    when the fragment is not eligible — the caller falls through to
+    the monolithic shard_map program."""
+    chain = _staged_dist_chain(root)
+    if chain is None or len(scan_meta) != 1:
+        return None
+    scan, used_enc, total = scan_meta[0]
+    used_cols = A.used_column_indices(chain)
+    if not set(used_cols) <= set(used_enc):
+        return None
+    nd = mesh.devices.size
+    cap = pow2((total + nd - 1) // nd, lo=8)
+    # per-column compressed layouts, chosen GLOBALLY (one layout must
+    # serve every rank's slab — the per-rank chain partials share one
+    # traced program). Each rank packs its own slab independently, so
+    # no cap/word-alignment constraint applies here; dictionaries
+    # would need per-device replication, so allow_dict=False.
+    comp_on = var_on(ctx.vars, "tidb_tpu_compression")
+    layouts = {}
+    if comp_on:
+        for i in used_cols:
+            vals, valid, _d = host_cols[(id(scan), i)]
+            if vals.ndim != 1:
+                continue
+            lay, _dv = compress.choose_layout(vals, valid,
+                                               allow_dict=False)
+            if lay is not None and lay.width > 0:
+                layouts[i] = lay
+    dicts = {i: host_cols[(id(scan), i)][2] for i in used_cols}
+    # rank-level zone maps: the per-rank slice is this path's
+    # dispatch unit, so stats are built per rank (slab_cap=cap) and
+    # the scan's conjuncts evaluate exactly as on the slab path. A
+    # pruned rank packs nothing, uploads nothing and runs nothing —
+    # its checkpoint is the ng=0 merge identity.
+    skip_ranks: frozenset = frozenset()
+    if comp_on and getattr(scan, "filters", None):
+        zmaps = {}
+        for i in used_cols:
+            vals, valid, _d = host_cols[(id(scan), i)]
+            if vals.ndim != 1:
+                continue
+            kind = "code" if _d is not None else \
+                ("float" if vals.dtype.kind == "f" else "num")
+            zmaps[i] = zonemap.column_stats(vals, valid, cap, total,
+                                            kind=kind)
+        shim = _RankZoneEnt(nd, zmaps, dicts)
+        skip_ranks = zonemap.prune_slabs(shim, scan)
+        if skip_ranks:
+            zonemap.note_skipped(ctx.phases, len(skip_ranks))
+            phys_b = logi_b = 0
+            for i in used_cols:
+                vals, valid, _d = host_cols[(id(scan), i)]
+                lay = layouts.get(i)
+                if lay is not None:
+                    phys_b += compress.packed_slab_bytes(lay, cap)
+                    logi_b += compress.raw_slab_bytes(lay, cap)
+                else:
+                    b = cap * vals.dtype.itemsize + cap
+                    phys_b += b
+                    logi_b += b
+            zonemap.note_h2d_skipped(ctx.phases,
+                                     phys_b * len(skip_ranks))
+            ctx.phases.add_scan(
+                0, logical=logi_b * len(skip_ranks))
+    # per-rank host slices — the checkpoint story's source of truth:
+    # a retry or re-dispatch re-uploads ONLY its rank's slice
+    # (pruned ranks hold None: never packed, never touched)
+    rank_cols = []
+    for r in range(nd):
+        if r in skip_ranks:
+            rank_cols.append(None)
+            continue
+        lo = r * cap
+        cols = {}
+        for i in used_cols:
+            vals, valid, _d = host_cols[(id(scan), i)]
+            pv = np.zeros(cap, dtype=vals.dtype)
+            pm = np.zeros(cap, dtype=bool)
+            seg = vals[lo:lo + cap]
+            pv[:seg.shape[0]] = seg
+            segm = valid[lo:lo + cap]
+            pm[:segm.shape[0]] = segm
+            lay = layouts.get(i)
+            cols[i] = compress.pack_slab(lay, pv, pm) \
+                if lay is not None else (pv, pm)
+        rank_cols.append(cols)
+    rank_rows = np.clip(total - np.arange(nd) * cap, 0,
+                        cap).astype(np.int32)
+    in_types = [scan.schema.field_types[i] for i in used_cols]
+    vars_ = ctx.vars
+    group_cap = var_int(vars_, "tidb_tpu_group_cap")
+    cap_limit = cap * nd
+    gcap = A.initial_group_cap(root, group_cap, cap_limit)
+    ladder = CapacityLadder(guard=getattr(ctx, "guard", None),
+                            stats=ctx.escalation)
+    runner = StagedDistAgg(root, chain, mesh, rank_cols, rank_rows,
+                           dicts, used_cols, in_types, cap, gcap,
+                           cap_limit, ctx, ladder,
+                           layouts=layouts or None,
+                           skip_ranks=skip_ranks)
+    pass_outs = runner.execute()
+    flows, _root_dicts = dictionary_flows(root, {id(scan): dicts})
+    inp_dicts = {i: d for i, d in
+                 enumerate(flows.get(id(root), []))}
+    with ctx.phases.phase("decode"):
+        return host_decode.merge_tree_agg_passes(
+            ctx, schema, root, pass_outs, inp_dicts)
+
+def _run_dist_exchange_staged(ctx, schema, root, mesh, host_cols,
+                               scan_meta) -> Optional[Chunk]:
+    """Staged checkpointable dist exchange (dist_fragment.
+    StagedDistExchange): per-rank partition programs → device→host
+    bucket checkpoints + host routing → per-rank fused probe/dedup
+    programs over the rewritten (exchange→leaf) plan. Returns None
+    when the plan is ineligible — the caller falls through to the
+    monolithic shard_map program, the byte-exactness oracle."""
+    grafted = staged_exchange_plan(root)
+    if grafted is None:
+        return None
+    new_root, grafts = grafted
+    ladder = CapacityLadder(guard=getattr(ctx, "guard", None),
+                            stats=ctx.escalation)
+    runner = StagedDistExchange(root, new_root, grafts, mesh,
+                                host_cols, scan_meta, ctx,
+                                ladder)
+    outs = runner.execute()
+    if isinstance(new_root, PhysHashAgg):
+        # the exchange re-keyed on the group keys, so each group's
+        # rows landed wholly on ONE rank: the host merge never
+        # combines two partials of one group (DISTINCT states stay
+        # exact — same invariant as the monolithic owner merge)
+        inp_dicts = {i: d for i, d in
+                     enumerate(runner.flows2.get(id(new_root), []))}
+        with ctx.phases.phase("decode"):
+            return host_decode.merge_tree_agg_passes(
+                ctx, schema, new_root, outs, inp_dicts)
+    dicts_root = {i: d for i, d in enumerate(runner.root_dicts2)}
+    cols_vm = [(np.concatenate([np.asarray(o["cols"][ci][0])
+                                for o in outs]),
+                np.concatenate([np.asarray(o["cols"][ci][1])
+                                for o in outs]))
+               for ci in range(len(new_root.schema))]
+    live = np.concatenate([np.asarray(o["live"]) for o in outs])
+    with ctx.phases.phase("decode"):
+        return host_decode.compact_decode(cols_vm, live,
+                               new_root.schema.field_types,
+                               dicts_root)
+
+def run_device_dist(ctx, plan, schema) -> Chunk:
+    # ORDER BY / TopN over the agg: shard programs compute the agg
+    # only — the ordering stays a host concern after the shard merge
+    # (the fused finalize is a single-device shape; a shard program
+    # would pass the agg through and emit un-aggregated rows)
+    order_root, root = strip_order_root(plan.root)
+    chunk = _dist_exec(ctx, plan, schema, root)
+    if order_root is not None:
+        chunk = host_decode.host_order(chunk, order_root, root.schema)
+        chunk = host_decode.topn_slice(chunk, order_root)
+    return chunk
+
+def _dist_exec(ctx, plan, schema, root) -> Chunk:
+    """Planner-fragmented tree as one shard_map program over the mesh
+    (executor/dist_fragment.py; the MPPGather role of
+    executor/mpp_gather.go:42 lives in this method)."""
+    from tidb_tpu.parallel import make_mesh
+
+    nd = plan.dist
+    if len(jax.devices()) < nd:
+        raise FragmentFallback(f"mesh wants {nd} devices, "
+                               f"{len(jax.devices())} available",
+                               reason="mesh-size")
+    mesh = make_mesh(nd)
+    P = jax.sharding.PartitionSpec
+    sharding = jax.sharding.NamedSharding(mesh, P("shard"))
+
+    scans = scans_of(root)
+    caps: Dict[int, int] = {}
+    scan_inputs = []
+    scan_rows = []
+    scan_dicts = {}
+    scan_bounds: Dict[int, Dict[int, Tuple[int, int]]] = {}
+    host_cols: Dict[Tuple[int, int], list] = {}
+    scan_meta = []
+    ph = ctx.phases
+    for scan in scans:
+        used = scan.used_columns if scan.used_columns else \
+            list(range(len(scan.schema)))
+        parts, total = device_cache.collect_parts(ctx, scan)
+        if total == 0:
+            raise FragmentFallback("empty input", reason="empty-input")
+        shim = types.SimpleNamespace(parts=parts)
+        ftypes = scan.schema.field_types
+        with ph.phase("encode"):
+            for i in used:
+                vals, valid = device_cache.materialize_col(shim, i)
+                vals, dictionary = device_cache.encode_col(ftypes[i], vals,
+                                                           valid)
+                host_cols[(id(scan), i)] = [vals, valid, dictionary]
+        scan_meta.append((scan, used, total))
+    # string equi-join keys: unify dictionaries BEFORE sharding so
+    # equal strings hash equal on every shard (dist_fragment doc)
+    unify_string_join_dicts(root, host_cols)
+    # staged checkpointable paths: an exchange-free agg chain runs as
+    # per-rank single-device partials with device→host checkpoints
+    # (StagedDistAgg); exchange-carrying plans (distributed joins,
+    # DISTINCT re-keys, windows) cut at the exchange instead —
+    # per-rank partition programs, host-routed bucket checkpoints,
+    # per-rank probe programs (StagedDistExchange). Either way a
+    # shard fault re-executes ONLY the failed rank through the
+    # retry → re-dispatch → degraded-mesh ladder. Plans neither path
+    # accepts (TopN/Sort roots, non-scan-chain exchange children)
+    # keep the monolithic shard_map program below, where fault retry
+    # stays full-step — it also remains the staged paths'
+    # byte-exactness oracle.
+    if var_on(ctx.vars, "tidb_tpu_dist_staged"):
+        staged = _run_dist_agg_staged(ctx, schema, root, mesh,
+                                          host_cols, scan_meta)
+        if staged is not None:
+            return staged
+    if var_on(ctx.vars, "tidb_tpu_dist_staged_exchange"):
+        staged = _run_dist_exchange_staged(ctx, schema, root, mesh,
+                                               host_cols, scan_meta)
+        if staged is not None:
+            return staged
+    comp_on = var_on(ctx.vars, "tidb_tpu_compression")
+    dist_layouts = []
+    for scan, used, total in scan_meta:
+        cap = pow2((total + nd - 1) // nd, lo=8)
+        caps[id(scan)] = cap
+        cols = {}
+        dicts = {}
+        bounds: Dict[int, Tuple[int, int]] = {}
+        lay_pairs = []
+        for i in used:
+            vals, valid, dictionary = host_cols[(id(scan), i)]
+            dicts[i] = dictionary
+            b = device_cache.col_bounds(vals, valid, dictionary)
+            if b is not None:
+                bounds[i] = b
+            # each rank's rows pack on their own (the packed order is
+            # planar WITHIN a slab) and the per-rank word arrays
+            # concatenate into the one array that shards across the
+            # mesh, so word boundaries must coincide with shard
+            # boundaries: cap a multiple of WORD_BITS makes every
+            # per ∈ {1,2,4,8,32} divide the shard evenly.
+            # Dictionaries would need
+            # replication, a width-0 (1,) stub can't shard, and a
+            # delta slab can't either — its (1,) base is global while
+            # each shard's cumsum would need its OWN running base.
+            lay = None
+            if comp_on and vals.ndim == 1 and \
+                    cap % compress.WORD_BITS == 0:
+                lay, _dv = compress.choose_layout(vals, valid,
+                                                   allow_dict=False)
+                if lay is not None and (lay.width == 0
+                                        or lay.kind == "delta"):
+                    lay = None
+            with ph.phase("encode"):
+                pv = np.zeros(nd * cap, dtype=vals.dtype)
+                pv[:total] = vals
+                pm = np.zeros(nd * cap, dtype=bool)
+                pm[:total] = valid
+                packed = tuple(
+                    np.concatenate(parts) for parts in zip(*(
+                        compress.pack_slab(
+                            lay, pv[r * cap:(r + 1) * cap],
+                            pm[r * cap:(r + 1) * cap])
+                        for r in range(nd)))) \
+                    if lay is not None else None
+            logical_b = pv.nbytes + pm.nbytes
+            with ph.phase("upload"):
+                if packed is not None:
+                    cols[i] = tuple(jax.device_put(a, sharding)
+                                    for a in packed)
+                else:
+                    cols[i] = (jax.device_put(pv, sharding),
+                               jax.device_put(pm, sharding))
+            phys_b = sum(a.nbytes for a in packed) \
+                if packed is not None else logical_b
+            ph.add_h2d(phys_b, logical=logical_b)
+            # the dist program streams these shards from HBM too
+            ph.add_scan(phys_b, logical=logical_b)
+            ph.mark_in_flight()
+            if lay is not None:
+                lay_pairs.append((i, lay))
+        dist_layouts.append(tuple(lay_pairs))
+        rows = np.clip(total - np.arange(nd) * cap, 0,
+                       cap).astype(np.int32)
+        scan_inputs.append(cols)
+        scan_rows.append(jax.device_put(rows, sharding))
+        scan_dicts[id(scan)] = dicts
+        scan_bounds[id(scan)] = bounds
+    scan_inputs = tuple(scan_inputs)
+    scan_rows = tuple(scan_rows)
+    dist_layouts = tuple(dist_layouts) if any(dist_layouts) else None
+
+    flows, root_dicts = dictionary_flows(root, scan_dicts)
+    flow_list = [flows.get(id(n), []) for n in walk_nodes(root)]
+
+    # initial bucket cap per hash exchange: 4× the balanced share
+    # (tidb_tpu_exchange_bucket_cap overrides — skew/retry testing)
+    cap_override = var_int(
+        ctx.vars, "tidb_tpu_exchange_bucket_cap")
+    bucket_caps: Dict[int, int] = {}
+    for node in walk_nodes(root):
+        if isinstance(node, PhysExchange) and node.kind == "hash":
+            est = max(int(node.est_rows), 1)
+            bucket_caps[id(node)] = cap_override or pow2(
+                4 * ((est + nd - 1) // nd), lo=64)
+
+    vars_ = ctx.vars
+    group_cap = var_int(vars_, "tidb_tpu_group_cap")
+    is_agg = isinstance(root, PhysHashAgg)
+    max_cap = max(caps.values())
+    gcap = A.initial_group_cap(root, group_cap, max_cap * nd) \
+        if is_agg else 1
+
+    hash_exchanges = [n for n in walk_nodes(root)
+                      if isinstance(n, PhysExchange)
+                      and n.kind == "hash"]
+    def _shard_out_cap(cfg):
+        # expand caps are PER SHARD: start from the balanced share of
+        # the global estimate; skew comes back as join_need → 1 retry
+        return pow2(int(cfg.est * 1.3 / nd) + 16, lo=1024)
+
+    join_cfgs = plan_join_configs(root, scan_bounds)
+    join_cfgs = [dataclasses.replace(c, out_cap=_shard_out_cap(c))
+                 if c.mode == "expand" else c for c in join_cfgs]
+    out_cap_max = var_int(vars_, "tidb_tpu_join_out_cap")
+    ladder = CapacityLadder(guard=getattr(ctx, "guard", None),
+                            stats=ctx.escalation)
+    shard_faults = 0
+    while True:
+        # each retrace round is a checkpoint: a killed query must not
+        # queue another multi-shard compile
+        ctx.check_killed("device-dispatch")
+        prog = _get_dist_program(root, caps, gcap, mesh, bucket_caps,
+                                 join_cfgs, dist_layouts)
+        prep_vals = prog.collect_preps(flow_list)
+        try:
+            # a shard fault (failpoint or real device error) can
+            # surface at the drain OR the fetch — both stay in the
+            # try. The scheduler slot covers only the async dispatch;
+            # the GIL-releasing drain runs outside it so sibling
+            # statements' host phases overlap the mesh execution.
+            with scheduler.device_slot(ctx):
+                with ph.launch(prog.name):
+                    raw = prog(scan_inputs, scan_rows, prep_vals)
+            ph.note_launch()
+            if is_agg:
+                A.count_agg_partial(A.note_grouping(root, None, gcap))
+            with ph.drain():
+                jax.block_until_ready(raw)
+            with ph.phase("fetch"):
+                out = jax.device_get(raw)
+            ph.add_d2h(tree_nbytes(out))
+        except Exception as e:
+            # one shard's step failing (the "shard-step" failpoint, or
+            # a real per-device runtime fault) heals by re-dispatching
+            # the WHOLE step — shard_map is deterministic over
+            # host-resident inputs, so a retry recomputes every shard
+            if not (isinstance(e, ShardFailure) or
+                    type(e).__name__ == "XlaRuntimeError"):
+                raise
+            shard_faults += 1
+            if shard_faults > 1:
+                # the fault persisted through the retry: surface ONE
+                # typed error (the store and session stay usable)
+                raise ShardFailure(
+                    "distributed fragment shard step failed twice: "
+                    f"{e}") from e
+            ladder.shard_retry(e)
+            continue
+        retry = False
+        ju = np.asarray(out["join_unique"])
+        jneed = np.asarray(out["join_need"])
+        for ji, cfg in enumerate(join_cfgs):
+            new_cfg, action = escalate_join(
+                cfg, bool(ju[ji]), int(jneed[ji]), out_cap_max,
+                flip_out_cap=_shard_out_cap(cfg), ladder=ladder)
+            if action == "over-max":
+                ladder.fallback("join")
+                raise FragmentFallback(
+                    f"join fan-out {int(jneed[ji])} exceeds "
+                    f"device cap", reason="join-cap")
+            if new_cfg is not None:
+                # a lost PK-FK bet re-traces in expand mode; an expand
+                # overflow resizes to the largest shard's true need —
+                # one recompile either way, never a CPU fallback
+                join_cfgs[ji] = new_cfg
+                retry = True
+        needs = np.asarray(out["exchange_need"])
+        for need, node in zip(needs, hash_exchanges):
+            if int(need) > bucket_caps[id(node)]:
+                failpoint.inject("exchange-overflow")
+                # resize only the overflowed exchange, to its exact
+                # reported need — one recompile, no doubling ladder
+                bucket_caps[id(node)] = ladder.resize(
+                    "exchange", bucket_caps[id(node)],
+                    need=int(need), lo=64)
+                retry = True
+        gneed = int(out["group_need"])
+        if gneed > gcap:
+            if gcap >= max_cap * nd:
+                ladder.fallback("group")
+                raise FragmentFallback("group cap overflow", reason="group-cap")
+            # the pmax'd true per-shard group count came back: exact
+            # need, one recompile
+            gcap = ladder.resize("group", gcap, need=gneed,
+                                 max_cap=max_cap * nd)
+            retry = True
+        if not retry:
+            break
+        ladder.attempt("dist")
+
+    dicts_root = {i: d for i, d in enumerate(root_dicts)}
+    if is_agg:
+        out_live = np.asarray(out["out_live"])
+        idx = np.nonzero(out_live)[0]
+        inp = flows.get(id(root), [])
+        cols: List[Column] = []
+        for kc, e in enumerate(root.group_exprs):
+            ft = schema[kc]
+            v, m = out["keys"][kc]
+            d = inp[e.index] if isinstance(e, ColumnRef) and \
+                e.index < len(inp) else None
+            cols.append(host_decode.decode_col(ft, np.asarray(v)[idx],
+                                    np.asarray(m)[idx], d))
+        for agg, st in zip([build_agg(d) for d in root.aggs],
+                           out["states"]):
+            v, m = agg.final(np, tuple(np.asarray(a) for a in st))
+            cols.append(host_decode.decode_col(agg.ftype, np.asarray(v)[idx],
+                                    np.asarray(m)[idx], None))
+        if root.group_exprs and not len(idx):
+            return empty_chunk(schema)
+        return Chunk(cols)
+    if isinstance(root, (PhysTopN, PhysSort)):
+        # per-shard candidates arrive concatenated; the host does the
+        # final k-way merge (the MPPGather role)
+        n_outs = np.asarray(out["n_out"])
+        per_shard = out["cols"][0][0].shape[0] // nd \
+            if out["cols"] else 0
+        pieces = []
+        for s in range(nd):
+            lo = s * per_shard
+            n = int(n_outs[s])
+            piece = []
+            for ci, ((v, m), ft) in enumerate(
+                    zip(out["cols"], root.schema.field_types)):
+                piece.append(host_decode.decode_col(
+                    ft, np.asarray(v)[lo:lo + n],
+                    np.asarray(m)[lo:lo + n], dicts_root.get(ci)))
+            pieces.append(Chunk(piece))
+        merged = Chunk.concat(pieces) if len(pieces) > 1 else pieces[0]
+        merged = host_decode.host_order(merged, root, root.schema)
+        return host_decode.topn_slice(merged, root)
+    # window / selection / projection / join row root: compact the
+    # shard-concatenated padded output by its live mask
+    return host_decode.compact_decode(out["cols"], out["live"],
+                           root.schema.field_types, dicts_root)

@@ -22,11 +22,15 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from tidb_tpu.chunk import Chunk, Column
-from tidb_tpu.executor import Executor, _empty_chunk
+from tidb_tpu.executor import Executor, empty_chunk
 from tidb_tpu.expression import EvalContext, Expression
 from tidb_tpu.expression.aggfuncs import AggFunc, build_agg
 from tidb_tpu.expression.runner import host_context
 from tidb_tpu.planner.physical import PhysHashAgg
+from tidb_tpu.sysvars import var_int
+from tidb_tpu.types import fold_ci_array
+from tidb_tpu.util import memory as M
+from tidb_tpu.util.memory import hash_partition
 
 _OVERFLOW_GUARD = 1 << 61
 
@@ -80,7 +84,6 @@ def factorize_columns(cols: Sequence[Tuple[np.ndarray, np.ndarray]]
 def _fold_group_key_cols(key_cols, group_exprs):
     """Fold ci group-key columns so equal-under-collation values form ONE
     group; binary columns pass through (util/collate semantics)."""
-    from tidb_tpu.types import fold_ci_array
     out = []
     for (v, m), e in zip(key_cols, group_exprs):
         v = np.asarray(v)
@@ -95,7 +98,6 @@ def batch_partial(group_exprs, descs, aggs, scalar: bool, ch: Chunk):
     computation over picklable inputs — runs on worker threads AND in
     spawned worker processes (the UpdatePartialResult body of the
     reference's partial workers, executor/aggregate.go:127)."""
-    from tidb_tpu.util import memory as M
     ctx = host_context(ch)
     key_cols = [e.eval(ctx) for e in group_exprs]
     # ci collations group in FOLD space; outputs keep a raw
@@ -256,7 +258,6 @@ class HashAggExec(Executor):
     N_SPILL_PARTITIONS = 16
 
     def _aggregate(self) -> Chunk:
-        from tidb_tpu.util import memory as M
         partial_keys: List[List[Tuple[np.ndarray, np.ndarray]]] = []
         partial_states: List[List[Tuple]] = []
         distinct_rows: List[List[Tuple[np.ndarray, np.ndarray, np.ndarray]]] = \
@@ -316,8 +317,7 @@ class HashAggExec(Executor):
         # appear when per-row compute is heavy relative to row width
         # (many exprs, wide decimals); the graph is the reference's
         # architecture, the single-thread path is the fast default here.
-        conc = max(int(self.ctx.vars.get("tidb_tpu_cpu_concurrency", 1)),
-                   1)
+        conc = max(var_int(self.ctx.vars, "tidb_tpu_cpu_concurrency"), 1)
         try:
             if conc == 1:
                 while True:
@@ -405,7 +405,7 @@ class HashAggExec(Executor):
             if ch.num_rows:
                 chunks.append(ch)
         if not chunks:
-            return _empty_chunk(self.schema)   # no rows at ANY level
+            return empty_chunk(self.schema)   # no rows at ANY level
         full_ge, full_scalar = self.group_exprs, self.scalar
         k = len(full_ge)
         pieces: List[Chunk] = []
@@ -431,7 +431,7 @@ class HashAggExec(Executor):
             self.group_exprs, self.scalar = full_ge, full_scalar
             self._replay = None
         if not pieces:
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         return Chunk.concat(pieces) if len(pieces) > 1 else pieces[0]
 
     def _fold_group_keys(self, key_cols):
@@ -446,7 +446,6 @@ class HashAggExec(Executor):
 
     def _spill_batch(self, spill, pk, states, batch_distinct) -> None:
         """Split one batch's partial groups by key hash into partitions."""
-        from tidb_tpu.util.memory import hash_partition
         pk_h = self._fold_group_keys(pk) if pk else pk
         n_groups = len(pk[0][0]) if pk else 0
         buckets = hash_partition(pk_h, spill.n)
@@ -498,7 +497,7 @@ class HashAggExec(Executor):
             if piece.num_rows:
                 pieces.append(piece)
         if not pieces:
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         return Chunk.concat(pieces) if len(pieces) > 1 else pieces[0]
 
     def _merge_partials(self, partial_keys, partial_states, distinct_rows,
@@ -509,7 +508,7 @@ class HashAggExec(Executor):
                     [(np.empty(0), np.empty(0, dtype=bool))
                      for _ in self.group_exprs],
                     [a.init(np, 1) for a in self.aggs], 1, empty_input=True)
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
 
         if self.scalar:
             # all batches share group 0: straight merge
@@ -570,7 +569,6 @@ class HashAggExec(Executor):
         for k, v in enumerate(vcols):
             aft = self.descs[i].args[k].ftype
             if aft.is_ci and getattr(v, "dtype", None) == np.dtype(object):
-                from tidb_tpu.types import fold_ci_array
                 v = fold_ci_array(v)
             dcols.append(v)
         _, _, reps = factorize_columns(

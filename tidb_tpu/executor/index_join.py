@@ -20,8 +20,9 @@ from typing import List, Optional
 import numpy as np
 
 from tidb_tpu.chunk import Chunk, Column
-from tidb_tpu.executor import MaterializingExec, _empty_chunk
+from tidb_tpu.executor import MaterializingExec, empty_chunk
 from tidb_tpu.expression.runner import eval_on_chunk, filter_mask
+from tidb_tpu.executor.index_scan import get_index
 
 
 class IndexLookupJoinExec(MaterializingExec):
@@ -37,7 +38,6 @@ class IndexLookupJoinExec(MaterializingExec):
                 f"{self.plan.index_name}")
 
     def _materialize(self) -> Chunk:
-        from tidb_tpu.executor.index_scan import get_index
         plan = self.plan
         outer_chunks: List[Chunk] = []
         while True:
@@ -47,7 +47,7 @@ class IndexLookupJoinExec(MaterializingExec):
             if ch.num_rows:
                 outer_chunks.append(ch)
         if not outer_chunks:
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         outer = Chunk.concat(outer_chunks) if len(outer_chunks) > 1 \
             else outer_chunks[0]
 

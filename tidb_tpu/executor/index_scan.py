@@ -25,11 +25,12 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from tidb_tpu.chunk import Chunk
-from tidb_tpu.executor import MaterializingExec, _empty_chunk
+from tidb_tpu.executor import MaterializingExec, empty_chunk
 from tidb_tpu.expression.runner import filter_mask
 from tidb_tpu.planner.ranger import Range
 from tidb_tpu.util import timeline
 from tidb_tpu.util.observability import REGISTRY
+from tidb_tpu.executor.scan import align_chunk_to_schema
 
 MAX_CACHED_INDEXES = 16
 
@@ -209,7 +210,6 @@ def _live_view(ctx, table_id: int, table_info, cacheable, td,
                     len(hit[1].columns) == len(table_info.columns):
                 _VIEW_CACHE.move_to_end(vkey)
                 return hit[1]
-    from tidb_tpu.executor.scan import align_chunk_to_schema
     live_chunks: List[Chunk] = []
     for _region, chunk, alive in ctx.scan_table(table_id):
         ctx.check_killed()
@@ -222,7 +222,7 @@ def _live_view(ctx, table_id: int, table_info, cacheable, td,
         view = Chunk.concat(live_chunks) if len(live_chunks) > 1 \
             else live_chunks[0]
     else:
-        view = _empty_chunk([c.ftype for c in table_info.columns])
+        view = empty_chunk([c.ftype for c in table_info.columns])
     if cacheable:
         with _LOCK:
             _VIEW_CACHE[vkey] = (td, view)
@@ -298,7 +298,7 @@ class IndexScanExec(MaterializingExec):
                             plan.table)
             rows = ent.probe(plan.ranges)
         if not len(rows):
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         out = ent.view.take(rows)
         for pred in plan.residual:
             keep = filter_mask(pred, out)
@@ -329,7 +329,7 @@ class IndexOrderedScanExec(MaterializingExec):
         else:
             pos = np.concatenate([si.null_pos, si.sorted_pos])
         if not len(pos):
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         out = si.view.take(pos)
         for pred in plan.filters:
             keep = filter_mask(pred, out)

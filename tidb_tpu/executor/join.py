@@ -21,20 +21,21 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from tidb_tpu import types as T
 from tidb_tpu.chunk import Chunk, Column
-from tidb_tpu.executor import Executor
-from tidb_tpu.expression import Expression, cast
+from tidb_tpu.executor import Executor, empty_chunk
+from tidb_tpu import expression
+from tidb_tpu.expression import Expression
 from tidb_tpu.expression.runner import filter_mask, host_context
 from tidb_tpu.planner.physical import PhysHashJoin
-from tidb_tpu.types import TypeKind
+from tidb_tpu.types import fold_ci_array
+from tidb_tpu.util import memory as M
+from tidb_tpu.util.memory import hash_partition
 
 _CODE_GUARD = 1 << 61
 
 
 def _empty_like(ftypes) -> Chunk:
-    from tidb_tpu.executor import _empty_chunk
-    return _empty_chunk(list(ftypes))
+    return empty_chunk(list(ftypes))
 
 
 def _key_arrays(exprs: List[Expression], chunk: Chunk,
@@ -45,7 +46,6 @@ def _key_arrays(exprs: List[Expression], chunk: Chunk,
         v, m = e.eval(ctx)
         v = np.asarray(v)
         if ci_flags is not None and ci_flags[i] and v.dtype == object:
-            from tidb_tpu.types import fold_ci_array
             v = fold_ci_array(v)
         out.append((v, np.asarray(m, dtype=bool)))
     return out
@@ -61,29 +61,6 @@ def _normalize(vals: np.ndarray) -> np.ndarray:
     if vals.dtype == object:
         return np.asarray([str(v) for v in vals], dtype=object)
     return vals
-
-
-def coerce_key_pair(l: Expression, r: Expression):
-    """Cast both sides of an equi pair into one comparable domain
-    (decimal scales equalized; int vs float → double)."""
-    lt, rt = l.ftype, r.ftype
-    if lt.kind.is_string or rt.kind.is_string:
-        return l, r
-    if lt.kind == rt.kind and lt.scale == rt.scale:
-        return l, r
-    common = T.merge_numeric(lt, rt)
-    if common.kind is TypeKind.DECIMAL:
-        if lt.scale != common.scale or lt.kind is not TypeKind.DECIMAL:
-            l = cast(l, common)
-        if rt.scale != common.scale or rt.kind is not TypeKind.DECIMAL:
-            r = cast(r, common)
-        return l, r
-    if common.kind.is_float:
-        if not lt.kind.is_float:
-            l = cast(l, common)
-        if not rt.kind.is_float:
-            r = cast(r, common)
-    return l, r
 
 
 class _BuildTable:
@@ -172,7 +149,8 @@ class HashJoinExec(Executor):
         self.plan = plan
         self.kind = plan.kind
         self.build_right = plan.build_right
-        self.equi = [coerce_key_pair(l, r) for l, r in plan.equi]
+        self.equi = [expression.coerce_key_pair(l, r)
+                     for l, r in plan.equi]
         self._table: Optional[_BuildTable] = None
         self._build_chunk: Optional[Chunk] = None
         self._grace = None            # (build_spill, probe_spill) if spilled
@@ -218,7 +196,6 @@ class HashJoinExec(Executor):
     def _ensure_built(self):
         if self._table is not None or self._grace is not None:
             return
-        from tidb_tpu.util import memory as M
         build_exec = self.children[self._build_idx]
         build_fts = build_exec.schema
         self._tracker = self.ctx.mem_tracker.child("HashJoin")
@@ -273,7 +250,6 @@ class HashJoinExec(Executor):
         self._table = _BuildTable(bkeys)
 
     def _spill_side(self, spill, chunk: Chunk, build: bool) -> None:
-        from tidb_tpu.util.memory import hash_partition
         build_key_exprs, probe_key_exprs = self._keys()
         exprs = build_key_exprs if build else probe_key_exprs
         keys = _key_arrays(exprs, chunk, equi_ci_flags(self.equi))
@@ -285,7 +261,6 @@ class HashJoinExec(Executor):
         ~1/P of the build side, probing that partition's probe chunks.
         A skewed partition that alone exceeds the quota cancels honestly
         (tracked consume raises) instead of silently re-inflating."""
-        from tidb_tpu.util import memory as M
         build_spill, probe_spill = self._grace
         build_key_exprs, _ = self._keys()
         for p in range(build_spill.n):

@@ -17,8 +17,9 @@ from typing import List
 import numpy as np
 
 from tidb_tpu.chunk import Chunk
-from tidb_tpu.executor import MaterializingExec, _empty_chunk
+from tidb_tpu.executor import MaterializingExec, empty_chunk
 from tidb_tpu.expression.runner import filter_mask
+from tidb_tpu.executor.index_scan import get_index
 
 
 class MergeJoinExec(MaterializingExec):
@@ -35,7 +36,6 @@ class MergeJoinExec(MaterializingExec):
                 f"{self.plan.right_index}")
 
     def _materialize(self) -> Chunk:
-        from tidb_tpu.executor.index_scan import get_index
         plan = self.plan
         li = get_index(self.ctx, plan.left_table.id, plan.left_key,
                        plan.left_table)
@@ -44,13 +44,13 @@ class MergeJoinExec(MaterializingExec):
         lv, lp = li.sorted_vals, li.sorted_pos
         rv, rp = ri.sorted_vals, ri.sorted_pos
         if not len(lv) or not len(rv):
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         lo = np.searchsorted(rv, lv, side="left")
         hi = np.searchsorted(rv, lv, side="right")
         counts = hi - lo
         total = int(counts.sum())
         if total == 0:
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         l_slot = np.repeat(np.arange(len(lv)), counts)
         offs = np.arange(total) - np.repeat(np.cumsum(counts) - counts,
                                             counts)

@@ -48,8 +48,15 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from tidb_tpu.chunk import Chunk
+from tidb_tpu.errors import QueryInterrupted, QueryTimeout
+from tidb_tpu.executor import compile_cache, device_emit, scheduler
+from tidb_tpu.executor.agg_slabs import slab_iter
+from tidb_tpu.executor.host_decode import decode_col, positional_dict
+from tidb_tpu.ops.jax_env import jnp
 from tidb_tpu.util import failpoint, timeline
 from tidb_tpu.util.observability import REGISTRY, normalize_sql
+from tidb_tpu.util.phases import tree_nbytes
 
 # follower guard-poll cadence while parked on the batch event
 POLL_S = 0.02
@@ -201,16 +208,40 @@ def _structure_matches(jax, ref_pv, pv) -> bool:
     return True
 
 
+class _BatchedProgram:
+    """A base fragment program vmapped over a leading member axis: one
+    launch serves `b_pad` statements whose prepared parameters are
+    stacked along axis 0 (`_lead`). Shares the compile
+    cache/LRU with scalar programs under sig `batched[B]|<base sig>`."""
+
+    __slots__ = ("base", "b_pad", "partial", "partial_name")
+
+    def __init__(self, base, b_pad: int, sig: str = ""):
+        from tidb_tpu.ops.jax_env import program_name
+        self.base = base
+        self.b_pad = b_pad
+        self.partial_name = program_name("batched", sig)
+        self.partial = device_emit.emit_batched(base._partial,
+                                                self.partial_name)
+
+
+def get_batched_program(base, b_pad: int,
+                        base_sig: str) -> _BatchedProgram:
+    """`base`: the chain's `agg_slabs` fragment program."""
+    sig = f"batched[{b_pad}]|{base_sig}"
+    return compile_cache.get_or_build(sig, "batched",
+                         lambda: _BatchedProgram(base, b_pad, sig))
+
+
 def _lead(exec_, batch: _Batch, prog, root, ent, dicts, prep_vals,
           slab_ids, sig) -> Optional[object]:
-    from tidb_tpu.executor import fragment
-    from tidb_tpu.ops.jax_env import jax, jnp
+    from tidb_tpu.ops.jax_env import jax
 
     ctx = exec_.ctx
     ph = ctx.phases
     guard = getattr(ctx, "guard", None)
 
-    with ctx.device_slot():
+    with scheduler.device_slot(ctx):
         # grant time: close the batch and claim compatible members
         with _LOCK:
             batch.closed = True
@@ -240,9 +271,9 @@ def _lead(exec_, batch: _Batch, prog, root, ent, dicts, prep_vals,
             stacked = jax.tree_util.tree_map(
                 lambda *xs: np.stack([np.asarray(x) for x in xs]),
                 *all_pvs)
-            bprog = fragment.get_batched_program(prog, b_pad, sig)
+            bprog = get_batched_program(prog, b_pad, sig)
             outs = []
-            for cols, n in exec_._slab_iter(ent, None, prog.used_cols,
+            for cols, n in slab_iter(ent, None, prog.used_cols,
                                             slab_ids):
                 with ph.launch(bprog.partial_name, sig=f"batched:{sig}"):
                     outs.append(bprog.partial(cols, jnp.int32(n),
@@ -263,7 +294,6 @@ def _lead(exec_, batch: _Batch, prog, root, ent, dicts, prep_vals,
             jax.block_until_ready(outs)
         with ph.phase("fetch"):
             host_outs = jax.device_get(outs)
-        from tidb_tpu.util.phases import tree_nbytes
         ph.add_d2h(tree_nbytes(host_outs))
         failpoint.inject("microbatch-demux")
         with ph.phase("decode"):
@@ -289,8 +319,6 @@ def _demux(host_outs, b_real: int, root, dicts) -> List[object]:
     """Slice each slab output's leading member axis into per-member
     (live-compacted, dictionary-decoded) Chunks — the batched twin of
     _execute_filter's decode loop."""
-    from tidb_tpu.chunk import Chunk
-    from tidb_tpu.executor.fragment import _decode_col, _positional_dict
     chunks: List[object] = []
     for k in range(b_real):
         pieces = []
@@ -302,8 +330,8 @@ def _demux(host_outs, b_real: int, root, dicts) -> List[object]:
                     zip(out["cols"], root.schema.field_types)):
                 vals = np.asarray(v)[k][idx]
                 mask = np.asarray(m)[k][idx]
-                piece.append(_decode_col(
-                    ft, vals, mask, _positional_dict(root, ci, dicts)))
+                piece.append(decode_col(
+                    ft, vals, mask, positional_dict(root, ci, dicts)))
             pieces.append(Chunk(piece))
         chunks.append(Chunk.concat(pieces) if len(pieces) > 1
                       else pieces[0])
@@ -311,7 +339,6 @@ def _demux(host_outs, b_real: int, root, dicts) -> List[object]:
 
 
 def _is_guard_error(e: BaseException) -> bool:
-    from tidb_tpu.errors import QueryInterrupted, QueryTimeout
     return isinstance(e, (QueryInterrupted, QueryTimeout)) \
         or not isinstance(e, Exception)
 

@@ -115,7 +115,10 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
-from tidb_tpu.util import timeline
+from tidb_tpu.sysvars import var_str
+from tidb_tpu.util import failpoint, timeline
+from tidb_tpu.util.backoff import BackoffExhausted, Backoffer
+from tidb_tpu.util.observability import REGISTRY
 
 # consecutive grants one connection may take while another conn waits
 DEFAULT_FAIRNESS_CAP = 4
@@ -452,8 +455,6 @@ class DeviceHealthMonitor:
         """Quarantine `idx` after a device-level fault. → True when the
         device was quarantined (survivors exist); False when it is the
         last healthy device or outside the pool."""
-        from tidb_tpu.util.backoff import BackoffExhausted, Backoffer
-        from tidb_tpu.util.observability import REGISTRY
         idx = int(idx)
         with self._pool._lock:
             n = len(self._pool.schedulers)
@@ -521,9 +522,6 @@ class DeviceHealthMonitor:
         """One health probe of a quarantined device: the device-readmit
         failpoint gate, then a tiny best-effort transfer onto the real
         device handle. Pass → readmitted; fail → next flap-guard step."""
-        from tidb_tpu.util import failpoint
-        from tidb_tpu.util.backoff import BackoffExhausted
-        from tidb_tpu.util.observability import REGISTRY
         ok, probe_err = True, None
         try:
             failpoint.inject("device-readmit")
@@ -825,7 +823,7 @@ def _queues_on(ctx) -> bool:
     """tidb_tpu_device_queues resolution: on/off are explicit; the
     default `auto` activates the pool exactly when >1 device is visible
     (a single-device host keeps PR 5/15 semantics byte-identically)."""
-    queues = str(ctx.vars.get("tidb_tpu_device_queues", "auto")).lower()
+    queues = var_str(ctx.vars, "tidb_tpu_device_queues").lower()
     if queues in ("on", "1", "true"):
         return True
     if queues in ("off", "0", "false"):
@@ -837,21 +835,20 @@ def pool_devices(ctx) -> int:
     """Serving peers the statement can be placed across: the visible
     device count when the pool is active, else 1. device_cache consults
     this for its replicate-vs-partition placement decisions."""
-    mode = str(ctx.vars.get("tidb_tpu_scheduler", "on")).lower()
-    if mode in ("off", "0", "false") or not _queues_on(ctx):
+    if getattr(ctx, "unscheduled", False) or not _queues_on(ctx):
         return 1
     return _visible_devices()
 
 
 def device_slot(ctx):
     """The executor-facing entry: the routed scheduler's slot bound to
-    the statement's guard/conn, or a no-op when `tidb_tpu_scheduler=off`.
+    the statement's guard/conn, or a no-op for a context made
+    `unscheduled` (`ExecContext.unscheduled`: the compactor's warm runs).
     With the pool active (device_queues on, or auto with >1 device) the
     statement's guard carries its placement — stamped here on first
     acquire if admit_statement didn't already — and every acquire of
     the statement lands on that one queue."""
-    mode = str(ctx.vars.get("tidb_tpu_scheduler", "on")).lower()
-    if mode in ("off", "0", "false"):
+    if getattr(ctx, "unscheduled", False):
         return _null_slot()
     guard = getattr(ctx, "guard", None)
     conn_id = getattr(guard, "conn_id", 0) if guard is not None else 0
@@ -882,8 +879,7 @@ def admit_statement(ctx) -> None:
     and unclassified statements only get the placement stamp: their
     point reads go straight to the dispatch slot, exactly the PR 15
     flow (and the microbatch rendezvous depends on that)."""
-    mode = str(ctx.vars.get("tidb_tpu_scheduler", "on")).lower()
-    if mode in ("off", "0", "false") or not _queues_on(ctx):
+    if getattr(ctx, "unscheduled", False) or not _queues_on(ctx):
         return
     guard = getattr(ctx, "guard", None)
     if guard is None:
@@ -897,7 +893,6 @@ def admit_statement(ctx) -> None:
     guard.sched_admitted = True
     steal_ok = bool(getattr(guard, "sched_steal_ok", True)) \
         and POOL.size() > 1
-    from tidb_tpu.util import failpoint
     idx = home
     waited_total = 0.0
     while True:
@@ -916,14 +911,12 @@ def admit_statement(ctx) -> None:
                 # waiter thread itself performs the migration, so the
                 # statement is never lost (this thread still owns it)
                 # and never runs twice (no other thread ever could).
-                from tidb_tpu.util.backoff import Backoffer
                 Backoffer("steal-migrate", base_ms=1.0, max_ms=20.0,
                           budget_ms=1000.0,
                           guard=guard).backoff(err)
                 idx, steal_ok = home, False
                 continue
             idx, steal_ok = int(m.target), False
-            from tidb_tpu.util.observability import REGISTRY
             if m.drained:
                 # quarantine drain, not a steal: the waiter left a
                 # quarantined home queue for a healthy survivor
@@ -966,8 +959,7 @@ def device_fault(ctx, err) -> Optional[int]:
     survivor's index, or None when the pool cannot degrade (scheduler
     off, single slot, or no healthy survivor) — the caller lets the
     typed error surface instead."""
-    mode = str(ctx.vars.get("tidb_tpu_scheduler", "on")).lower()
-    if mode in ("off", "0", "false") or not _queues_on(ctx):
+    if getattr(ctx, "unscheduled", False) or not _queues_on(ctx):
         return None
     guard = getattr(ctx, "guard", None)
     dev = getattr(err, "device", None)
@@ -994,7 +986,6 @@ def device_fault(ctx, err) -> Optional[int]:
             ("Warning", 1105,
              f"device {dev} lost ({err}); statement retried on device "
              f"{idx}"))
-    from tidb_tpu.util.observability import REGISTRY
     REGISTRY.inc("tidb_tpu_statements_migrated_total",
                  {"device": str(idx)})
     return idx

@@ -18,9 +18,10 @@ from typing import List, Optional
 import numpy as np
 
 from tidb_tpu.chunk import Chunk
-from tidb_tpu.executor import Executor, MaterializingExec, _empty_chunk
+from tidb_tpu.executor import Executor, MaterializingExec, empty_chunk
 from tidb_tpu.expression import Expression
 from tidb_tpu.expression.runner import host_context
+from tidb_tpu.types import fold_ci_array
 
 
 def rank_keys(by: List[Expression], descs: List[bool],
@@ -36,7 +37,6 @@ def rank_keys(by: List[Expression], descs: List[bool],
         if v.dtype == object and e.ftype.is_varlen:
             v = np.asarray([str(x) for x in v], dtype=object)
             if e.ftype.is_ci:
-                from tidb_tpu.types import fold_ci_array
                 v = fold_ci_array(v)
         elif v.dtype == object:
             # a wide DECIMAL: scaled Python ints, ranked by VALUE (their text
@@ -105,7 +105,7 @@ class TopNExec(MaterializingExec):
             else:
                 candidate = merged
         if candidate is None or candidate.num_rows == 0:
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         idx = sort_indices(self.by, self.descs, candidate)
         idx = idx[self.offset:bound]
         return candidate.take(idx)

@@ -15,9 +15,10 @@ from __future__ import annotations
 import numpy as np
 
 from tidb_tpu.chunk import Chunk, Column
-from tidb_tpu.executor import MaterializingExec, _empty_chunk
+from tidb_tpu.executor import MaterializingExec, empty_chunk
 from tidb_tpu.expression.aggfuncs import build_agg
 from tidb_tpu.expression.runner import filter_mask, host_context
+from tidb_tpu.executor.index_scan import get_index
 
 
 class StreamAggExec(MaterializingExec):
@@ -33,13 +34,12 @@ class StreamAggExec(MaterializingExec):
                 f"{self.plan.index_name}")
 
     def _materialize(self) -> Chunk:
-        from tidb_tpu.executor.index_scan import get_index
         plan = self.plan
         si = get_index(self.ctx, plan.table.id, plan.key_col, plan.table)
         # key order with the NULL group first (its rows are contiguous)
         pos = np.concatenate([si.null_pos, si.sorted_pos])
         if len(pos) == 0:
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         ch = si.view.take(pos)
         if plan.filters:
             mask = np.ones(ch.num_rows, dtype=bool)
@@ -48,7 +48,7 @@ class StreamAggExec(MaterializingExec):
             if not mask.all():
                 pos = pos[mask]
                 if len(pos) == 0:
-                    return _empty_chunk(self.schema)
+                    return empty_chunk(self.schema)
                 ch = si.view.take(pos)
         kc = ch.columns[plan.key_col]
         kv, km = kc.values, kc.valid_mask()

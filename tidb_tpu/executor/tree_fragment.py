@@ -41,414 +41,32 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from tidb_tpu.expression import ColumnRef, EvalContext, Expression
+from tidb_tpu.executor import (compile_cache, device_cache, device_emit,
+                               eligibility)
+from tidb_tpu.executor.eligibility import (scans_of, stage_exprs, walk_joins,
+                                           walk_nodes)
+from tidb_tpu.expression import ColumnRef, EvalContext, Expression, ranges
 from tidb_tpu.expression.aggfuncs import build_agg
-from tidb_tpu.ops.factorize import KeyBounds, bounds_sig
-from tidb_tpu.planner.physical import (PhysHashAgg, PhysHashJoin,
-                                       PhysLimit, PhysProjection,
-                                       PhysSelection, PhysSort,
-                                       PhysTableScan, PhysTopN,
+from tidb_tpu.ops import join as J
+from tidb_tpu.ops import segment as seg
+from tidb_tpu.ops.factorize import KeyBounds, bounds_sig, choose_key_bounds
+from tidb_tpu.ops.jax_env import jnp
+from tidb_tpu.planner.physical import (PhysExchange, PhysHashAgg,
+                                       PhysHashJoin, PhysLimit,
+                                       PhysProjection, PhysSelection,
+                                       PhysSort, PhysTableScan, PhysTopN,
                                        PhysTpuFragment, PhysWindow,
                                        PhysicalPlan)
+from tidb_tpu.util.escalation import pow2
 
-JOIN_KINDS = ("inner", "left", "right", "semi", "anti")
 JOIN_DOMAIN_CAP = 1 << 25      # max packed build-key domain for LUT joins
-JOIN_OUT_CAP = 1 << 26         # max expand-mode output rows (HBM guard)
-
-
-def has_join(plan: PhysicalPlan) -> bool:
-    if isinstance(plan, PhysHashJoin):
-        return True
-    return any(has_join(c) for c in plan.children)
-
-
-def has_window(plan: PhysicalPlan) -> bool:
-    if isinstance(plan, PhysWindow):
-        return True
-    return any(has_window(c) for c in plan.children)
-
-
-def _string_key_ok(l: Expression, r: Expression) -> bool:
-    """String equi keys must be bare ColumnRefs (so the probe side's codes
-    can be dictionary-remapped into the build side's space) with MATCHING
-    collation classes — a mixed ci/binary pair would fold one side's
-    dictionary out of sorted order (and can merge two binary codes into
-    one fold class), so it runs on the CPU engine instead."""
-    if not (l.ftype.kind.is_string or r.ftype.kind.is_string):
-        return True
-    if l.ftype.is_ci != r.ftype.is_ci:
-        return False
-    return isinstance(l, ColumnRef) and isinstance(r, ColumnRef)
-
-
-def nested_fragments(plan: PhysicalPlan) -> List[PhysicalPlan]:
-    """The device-rows fragments nested in this tree as join build sides,
-    in _walk_nodes order."""
-    return [n for n in _walk_nodes(plan)
-            if isinstance(n, PhysTpuFragment) and n.device_rows]
-
-
-def device_rows_ok(agg: PhysicalPlan, threshold: int) -> bool:
-    """Can this aggregate run as a fragment of its own whose merged groups
-    stay on the device, as a join's build side? It must be a device
-    fragment in its own right (chain or tree, over a scan that clears the
-    row threshold), grouped, and every output column must finalize
-    in-trace: plain keys (no dictionary to carry across), and
-    count/sum/avg/min/max over narrow non-string results."""
-    from tidb_tpu.executor.fragment import _fragment_ok
-    if not isinstance(agg, PhysHashAgg) or not agg.group_exprs or \
-            getattr(agg, "rollup", False):
-        return False
-    if any(e.ftype.kind.is_string or e.ftype.is_wide_decimal
-           for e in agg.group_exprs):
-        return False
-    for d in agg.aggs:
-        if d.distinct or d.name not in ("count", "sum", "avg", "min", "max"):
-            return False
-        if d.ftype.kind.is_string:
-            return False
-        # of the wide results only a SUM over a 1-D argument has a 1-D
-        # final (AggFunc.final_narrow, checked at run time to fit)
-        if d.ftype.is_wide_decimal and not (
-                d.name == "sum" and build_agg(d).orders_in_trace):
-            return False
-    return _fragment_ok(agg, threshold) or tree_ok(agg, threshold)
-
-
-def nest_build_aggregates(plan: PhysicalPlan, threshold: int) -> None:
-    """Inside a tree that tree_ok admitted: wrap each aggregate that is a
-    semijoin's build side (under its HAVING selection and projection) in a
-    nested device-rows fragment."""
-    for node in _walk_nodes(plan):
-        if not (isinstance(node, PhysHashJoin) and node.kind == "semi"
-                and node.build_right):
-            continue
-        above = node
-        below = node.children[1]
-        while isinstance(below, (PhysSelection, PhysProjection)):
-            above, below = below, below.children[0]
-        if isinstance(below, PhysHashAgg) and \
-                device_rows_ok(below, threshold):
-            frag = PhysTpuFragment(below)
-            frag.est_rows = below.est_rows
-            frag.device_rows = True
-            above.children[1 if above is node else 0] = frag
-
-
-def tree_ok(plan: PhysicalPlan, threshold: int) -> bool:
-    """Static eligibility of a join tree (runtime checks catch the rest)."""
-    from tidb_tpu.executor.fragment import _string_exprs_are_refs
-
-    max_scan = [0.0]
-
-    def walk(node: PhysicalPlan, is_root: bool, build: bool = False) -> bool:
-        # `build`: inside a semijoin's build side, where an aggregate may
-        # run as a nested fragment whose groups stay on the device
-        if isinstance(node, PhysTpuFragment):
-            return node.device_rows
-        if build and isinstance(node, PhysHashAgg):
-            return device_rows_ok(node, threshold)
-        from tidb_tpu.executor.fragment import (_exprs_device_ok,
-                                                _strip_order_root)
-        # an order root over the agg sorts by refs into the agg's row,
-        # which _order_over_agg_ok judges below
-        if not (is_root and _strip_order_root(node)[0] is not None) and \
-                not _exprs_device_ok(_stage_exprs(node),
-                                     wide_refs_ok=build):
-            return False
-        if isinstance(node, PhysTableScan):
-            max_scan[0] = max(max_scan[0], getattr(node, "est_rows", 0.0))
-            return True
-        if isinstance(node, PhysSelection):
-            return walk(node.children[0], False, build)
-        if isinstance(node, PhysProjection):
-            if not _string_exprs_are_refs(node.exprs):
-                return False
-            return walk(node.children[0], False, build)
-        if isinstance(node, PhysHashJoin):
-            if node.kind not in JOIN_KINDS or not node.equi:
-                return False
-            # probe-anchored output ⇒ the preserved side must be the probe
-            if node.kind in ("left", "semi", "anti") and not node.build_right:
-                return False
-            if node.kind == "right" and node.build_right:
-                return False
-            for le, re in node.equi:
-                if not _string_key_ok(le, re):
-                    return False
-            return walk(node.children[0], False) and \
-                walk(node.children[1], False,
-                     node.kind == "semi" and node.build_right)
-        if is_root and isinstance(node, PhysHashAgg):
-            if getattr(node, "rollup", False) and \
-                    any(d.distinct for d in node.aggs):
-                return False    # DISTINCT+ROLLUP stays on the host oracle
-            for desc in node.aggs:
-                if desc.distinct and len(desc.args) > 1 and \
-                        desc.name != "count":
-                    return False    # multi-arg DISTINCT is COUNT-only
-                try:
-                    if not build_agg(desc).device_capable:
-                        return False
-                except Exception:
-                    return False
-                if any(a.ftype.kind.is_string for a in desc.args) \
-                        and desc.name != "count":
-                    return False
-                if not _string_exprs_are_refs(desc.args):
-                    return False    # string agg args read dict codes
-            if not _string_exprs_are_refs(node.group_exprs):
-                return False
-            return walk(node.children[0], False)
-        if is_root and isinstance(node, (PhysTopN, PhysSort)):
-            if not _string_exprs_are_refs(node.by):
-                return False
-            from tidb_tpu.executor.fragment import (_identity_projection,
-                                                    _order_over_agg_ok)
-            child = node.children[0]
-            while _identity_projection(child) and child.children:
-                child = child.children[0]
-            if isinstance(child, PhysHashAgg):
-                # ORDER BY / TopN over the agg (identity projections are
-                # transparent): the driver strips the order root and runs
-                # it as the agg's fused device finalize
-                # (device_emit.emit_finalize), so the agg keeps its root
-                # role here
-                if not _order_over_agg_ok(node, child):
-                    return False
-                return walk(child, True)
-            return walk(node.children[0], False)
-        if isinstance(node, PhysWindow):
-            # root OR interior: interior windows compute their columns
-            # in-trace (TreeProgram._emit) and feed the operator above —
-            # the TopN-over-ROW_NUMBER / agg-over-window shapes
-            from tidb_tpu.executor.fragment import _window_device_ok
-            return _window_device_ok(node) and walk(node.children[0], False)
-        if is_root and isinstance(node, PhysLimit):
-            # LIMIT over a join: the program emits the first offset+count
-            # live rows in probe row order (device_emit.emit_root)
-            return node.count is not None and walk(node.children[0], False)
-        return False
-
-    # joinless trees are admitted when a window makes the tree program
-    # worthwhile (mid-chain windows have no linear-chain lowering)
-    return walk(plan, True) and (has_join(plan) or has_window(plan)) \
-        and max_scan[0] >= threshold
-
-
-def dist_ok(plan: PhysicalPlan, threshold: int) -> bool:
-    """Eligibility for the multi-shard (shard_map) compilation: the same
-    operator allowlist as tree_ok, but joins are optional (a linear Q1
-    chain distributes as shard-partials + owned final merge). Reducible
-    roots (agg/TopN/Sort) merge across shards; window roots repartition on
-    their partition keys; selection/projection/join roots emit per-shard
-    rows the host concatenates. String join keys work because the dist
-    executor unifies the key dictionaries host-side before sharding, so
-    equal strings hash equal on every shard (the mpp repartition invariant
-    of cophandler/mpp_exec.go:158-173)."""
-    from tidb_tpu.planner.physical import PhysExchange
-    if isinstance(plan, PhysExchange):
-        return False               # already fragmented
-    if isinstance(plan, (PhysTopN, PhysSort)) and plan.children:
-        from tidb_tpu.executor.fragment import _identity_projection
-        below = plan.children[0]
-        while _identity_projection(below) and below.children:
-            below = below.children[0]
-        if isinstance(below, PhysHashAgg):
-            # ORDER-over-agg: _run_device_dist strips the order root
-            # before compiling (the shard program computes the agg; the
-            # host orders after the merge) — eligibility is the agg's
-            return dist_ok(below, threshold)
-    if isinstance(plan, PhysHashAgg):
-        if getattr(plan, "rollup", False):
-            return False    # super-aggregate levels don't shard-merge yet
-        if any(d.distinct for d in plan.aggs):
-            # DISTINCT distributes by re-keying the exchange so every
-            # group (or every distinct value, for global aggs) is wholly
-            # on one shard (the repartition trick of cophandler/
-            # mpp_exec.go); a global agg needs all distinct args equal to
-            # pick ONE key
-            if not plan.group_exprs:
-                if any(d.distinct and len(d.args) != 1
-                       for d in plan.aggs):
-                    return False    # tuple re-key has no single column
-                dargs = {repr(d.args[0]) for d in plan.aggs
-                         if d.distinct and d.args}
-                if len(dargs) != 1:
-                    return False
-    elif isinstance(plan, PhysWindow):
-        pass        # the per-window spec check below covers the root too
-    elif not isinstance(plan, (PhysTopN, PhysSort, PhysSelection,
-                               PhysProjection, PhysHashJoin)):
-        return False
-    # per-shard windows need every partition wholly on one shard: all
-    # specs must share ONE non-empty bare-ColumnRef partition list so a
-    # single hash exchange directly below the window co-locates them
-    # (insert_exchanges). Above the window only row-wise projections are
-    # distributable (window root, or the select list over it) — a
-    # reducing ancestor (agg/TopN/join) would need its own repartition
-    # point mid-tree
-    def _windows_ok(n, proj_chain):
-        if isinstance(n, PhysWindow):
-            if not proj_chain:
-                return False
-            parts = {repr(d.partition) for d in n.wdescs}
-            if len(parts) != 1 or not n.wdescs[0].partition:
-                return False
-            if not all(isinstance(e, ColumnRef)
-                       for e in n.wdescs[0].partition):
-                return False
-            proj_chain = False       # no second window below the first
-        elif not isinstance(n, PhysProjection):
-            proj_chain = False
-        return all(_windows_ok(c, proj_chain) for c in n.children)
-
-    if not _windows_ok(plan, True):
-        return False
-    # wide-decimal COLUMNS can't shard (the dist scan encoder is 1-D);
-    # wide RESULTS over narrow/computed args are fine — limb states
-    # all_gather as ordinary 1-D planes
-    if isinstance(plan, PhysHashAgg) and any(
-            isinstance(sub, ColumnRef) and sub.ftype.is_wide_decimal
-            for d in plan.aggs for a in d.args for sub in a.walk()):
-        return False
-    if has_join(plan) or has_window(plan):
-        # windowed shapes compile as tree programs (mirrors the
-        # single-device dispatch in fragment.py)
-        return tree_ok(plan, threshold)
-    return _chain_shape_ok(plan, threshold)
-
-
-def _chain_shape_ok(plan: PhysicalPlan, threshold: int) -> bool:
-    from tidb_tpu.executor.fragment import _fragment_ok
-    return _fragment_ok(plan, threshold)
-
-
-def _scans(plan: PhysicalPlan) -> List[PhysTableScan]:
-    if isinstance(plan, PhysTableScan):
-        return [plan]
-    out: List[PhysTableScan] = []
-    for c in plan.children:
-        out.extend(_scans(c))
-    return out
-
-
-# ---------------------------------------------------------------------------
-# Join key preparation (string dictionary remap)
-# ---------------------------------------------------------------------------
-
-
-@dataclass(eq=False)
-class KeyRemap(Expression):
-    """Remaps the probe side's dictionary codes into the build side's
-    dictionary space so string equi keys compare as integers.
-
-    prepare() receives the JOIN's input dictionary list (left ++ right
-    children) and computes a probe-code → build-code LUT host-side
-    (one searchsorted of two sorted dictionaries); codes absent from the
-    build dictionary map to -1, which matches nothing. The LUT ships as a
-    traced input, so dictionary changes never recompile."""
-
-    child: Expression            # side-local probe key (ColumnRef)
-    my_flow_idx: int             # my column's index in the join flow (l++r)
-    build_flow_idx: int          # build key column's index in the join flow
-    ci: bool = False             # compare under a ci collation
-
-    def __post_init__(self):
-        self.ftype = self.child.ftype
-
-    def children(self):
-        return [self.child]
-
-    def prepare(self, dictionaries):
-        pdict = dictionaries[self.my_flow_idx] \
-            if self.my_flow_idx < len(dictionaries) else None
-        bdict = dictionaries[self.build_flow_idx] \
-            if self.build_flow_idx < len(dictionaries) else None
-        if pdict is None or bdict is None or len(bdict) == 0:
-            return np.full(max(len(pdict) if pdict is not None else 0, 1),
-                           -1, np.int32)
-        if self.ci:
-            # ci dictionaries are representatives sorted by fold
-            # (chunk/device.encode_strings): match in fold space
-            from tidb_tpu.types import fold_ci_array
-            pdict = fold_ci_array(np.asarray(pdict, dtype=object))
-            bdict = fold_ci_array(np.asarray(bdict, dtype=object))
-        pos = np.searchsorted(bdict, pdict)
-        pos_c = np.clip(pos, 0, len(bdict) - 1)
-        hit = bdict[pos_c] == pdict
-        return np.where(hit, pos_c, -1).astype(np.int32)
-
-    def eval(self, ctx: EvalContext):
-        lut = ctx.prepared.get(id(self))
-        if lut is None:
-            raise AssertionError("KeyRemap without prepared LUT")
-        xp = ctx.xp
-        v, m = self.child.eval(ctx)
-        n_lut = lut.shape[0]
-        vc = xp.clip(v, 0, n_lut - 1).astype(xp.int32)
-        out = xp.take(xp.asarray(lut), vc).astype(xp.int64)
-        out = xp.where((v >= 0) & (v < n_lut), out, xp.int64(-1))
-        return out, m
-
-    def __repr__(self):
-        return f"remap({self.child!r})"
-
-
-def join_key_exprs(node: PhysHashJoin):
-    """→ (build_keys, probe_keys) in equi order, coerced to a shared
-    comparable domain, with probe-side string keys wrapped in KeyRemap.
-    Memoized on the node (wrappers must be identical objects across the
-    planner gate, prep collection, and trace)."""
-    cached = getattr(node, "_dev_join_keys", None)
-    if cached is not None:
-        return cached
-    from tidb_tpu.executor.join import coerce_key_pair
-    nl = len(node.children[0].schema)
-    bkeys: List[Expression] = []
-    pkeys: List[Expression] = []
-    for l, r in node.equi:
-        lc, rc = coerce_key_pair(l, r)
-        b, p = (rc, lc) if node.build_right else (lc, rc)
-        if b.ftype.kind.is_string and isinstance(b, ColumnRef) \
-                and isinstance(p, ColumnRef):
-            b_flow = (nl if node.build_right else 0) + b.index
-            p_flow = (0 if node.build_right else nl) + p.index
-            p = KeyRemap(p, p_flow, b_flow,
-                         ci=b.ftype.is_ci or p.ftype.is_ci)
-        bkeys.append(b)
-        pkeys.append(p)
-    node._dev_join_keys = (bkeys, pkeys)
-    return bkeys, pkeys
-
-
-def _stage_exprs(node: PhysicalPlan) -> List[Expression]:
-    from tidb_tpu.executor.fragment import _stage_exprs as chain_stage
-    from tidb_tpu.planner.physical import PhysExchange
-    if isinstance(node, PhysHashJoin):
-        bkeys, pkeys = join_key_exprs(node)
-        return list(bkeys) + list(pkeys) + list(node.other_conditions or [])
-    if isinstance(node, PhysExchange):
-        return list(node.keys)
-    return chain_stage(node)
-
-
-def _walk_nodes(plan: PhysicalPlan) -> List[PhysicalPlan]:
-    """Deterministic DFS (children first, left-to-right) — the structural
-    order used for prep-value alignment across compile cache hits."""
-    out: List[PhysicalPlan] = []
-
-    def rec(n):
-        for c in n.children:
-            rec(c)
-        out.append(n)
-
-    rec(plan)
-    return out
-
-
-def _walk_joins(plan: PhysicalPlan) -> List[PhysHashJoin]:
-    return [n for n in _walk_nodes(plan) if isinstance(n, PhysHashJoin)]
+# Beyond the masked reduce's slot count a directly addressed partial is an
+# int64 scatter-add per state — 1.1 s a state and 8M-row slab on a v5e
+# (a GROUP BY over 150K customer keys at SF=1 took 2.9 s so, against
+# 0.9 s at SF=8 where its 1.2M keys already grouped by sorted runs:
+# PERF.md §6, PR 28). So a
+# wider key domain groups by sorted runs wherever the aggregates allow.
+SLOT_ADDRESS_CAP = 1024
 
 
 def aligned_chain(build: PhysicalPlan
@@ -457,7 +75,7 @@ def aligned_chain(build: PhysicalPlan
     join substitutes with FK-aligned fact-rowspace columns — plus every
     join crossed on the way (outermost first). Follows Sel/Proj and each
     nested join's PROBE child (the rowspace-preserving side). The ONE
-    traversal both the planner (fragment._plan_aligned_joins) and the
+    traversal both the planner (agg_slabs.plan_aligned_joins) and the
     trace (_emit_join_aligned) use, so they cannot disagree on the
     anchor."""
     node = build
@@ -495,8 +113,9 @@ class JoinCfg:
     # (executor/device_cache.AlignedJoin) — static, part of the trace
     aligned_cols: Optional[Tuple[int, ...]] = None
     # blocked expand: this join's probe anchor scan is row-range masked and
-    # the tree runs in K passes whose root agg states merge host-side —
-    # a many-to-many fan-out beyond JOIN_OUT_CAP never leaves the device
+    # the tree runs in K passes whose root agg states merge host-side — a
+    # many-to-many fan-out beyond `tidb_tpu_join_out_cap` never leaves the
+    # device
     blocked: bool = False
 
 
@@ -518,8 +137,6 @@ def escalate_join(cfg: JoinCfg, unique_ok: bool, total: int,
     join must re-trace. A util/escalation.CapacityLadder passed as
     `ladder` gets the rung recorded on its per-query stats."""
     from dataclasses import replace as d_replace
-
-    from tidb_tpu.executor.device_cache import _pow2
     if cfg.mode == "unique" and not unique_ok:
         if ladder is not None:
             ladder.flip("join")
@@ -532,11 +149,11 @@ def escalate_join(cfg: JoinCfg, unique_ok: bool, total: int,
         if ladder is not None:
             ladder.stats.exact_resizes += 1
             ladder.stats.note("join", "exact")
-        return d_replace(cfg, out_cap=_pow2(total)), "resize"
+        return d_replace(cfg, out_cap=pow2(total, lo=1024)), "resize"
     return None, None
 
 
-def _bounds_list(node: PhysicalPlan, scan_bounds, quantities: bool = False
+def bounds_list(node: PhysicalPlan, scan_bounds, quantities: bool = False
                  ) -> List[Optional[Tuple[int, int]]]:
     """Per output column (lo, hi) value bounds, traced from the device
     cache's per-scan-column stats; schema-length list, None = unbounded.
@@ -545,8 +162,6 @@ def _bounds_list(node: PhysicalPlan, scan_bounds, quantities: bool = False
     bounds are those of scaled integers alone and a computed projection
     is bounded by interval arithmetic (expression/ranges): what an
     aggregate's summed argument can hold."""
-    from tidb_tpu.expression import ranges
-    from tidb_tpu.planner.physical import PhysExchange
     if isinstance(node, (PhysTableScan, PhysTpuFragment)):
         # (a nested fragment's rows bring the bounds of their group keys)
         b = scan_bounds.get(id(node), {})
@@ -554,16 +169,16 @@ def _bounds_list(node: PhysicalPlan, scan_bounds, quantities: bool = False
             return ranges.column_ranges(node.schema.field_types, b)
         return [b.get(i) for i in range(len(node.schema))]
     if isinstance(node, (PhysSelection, PhysExchange)):
-        return _bounds_list(node.children[0], scan_bounds, quantities)
+        return bounds_list(node.children[0], scan_bounds, quantities)
     if isinstance(node, PhysProjection):
-        inp = _bounds_list(node.children[0], scan_bounds, quantities)
+        inp = bounds_list(node.children[0], scan_bounds, quantities)
         if quantities:
             return [ranges.value_range(e, inp) for e in node.exprs]
         return [inp[e.index] if isinstance(e, ColumnRef)
                 and e.index < len(inp) else None for e in node.exprs]
     if isinstance(node, PhysHashJoin):
-        l = _bounds_list(node.children[0], scan_bounds, quantities)
-        r = _bounds_list(node.children[1], scan_bounds, quantities)
+        l = bounds_list(node.children[0], scan_bounds, quantities)
+        r = bounds_list(node.children[1], scan_bounds, quantities)
         nl = len(node.children[0].schema)
         nr = len(node.children[1].schema)
         l = (l + [None] * nl)[:nl]
@@ -574,10 +189,9 @@ def _bounds_list(node: PhysicalPlan, scan_bounds, quantities: bool = False
     return [None] * len(node.schema)
 
 
-def _trace_scan_col(node: PhysicalPlan, idx: int):
+def trace_scan_col(node: PhysicalPlan, idx: int):
     """Trace a column through Sel/Proj down to (scan, col) WITHOUT crossing
     joins (a join can duplicate rows, breaking uniqueness)."""
-    from tidb_tpu.planner.physical import PhysExchange
     while True:
         if isinstance(node, PhysTableScan):
             return node, idx
@@ -603,7 +217,7 @@ def _build_unique_hint(node: PhysHashJoin) -> bool:
     build = node.children[bi]
     raw_keys = [(r if node.build_right else l) for l, r in node.equi]
     if len(raw_keys) == 1 and isinstance(raw_keys[0], ColumnRef):
-        hit = _trace_scan_col(build, raw_keys[0].index)
+        hit = trace_scan_col(build, raw_keys[0].index)
         if hit is not None:
             scan, idx = hit
             table = scan.table
@@ -628,13 +242,12 @@ def _build_unique_hint(node: PhysHashJoin) -> bool:
 def plan_join_configs(root: PhysicalPlan, scan_bounds) -> List[JoinCfg]:
     """Initial per-join configs in _walk_nodes order (the runtime adapts
     mode/out_cap from the flags the program reports)."""
-    from tidb_tpu.executor.device_cache import _pow2
     cfgs: List[JoinCfg] = []
-    for node in _walk_joins(root):
+    for node in walk_joins(root):
         bi = 1 if node.build_right else 0
         build = node.children[bi]
-        bkeys, _ = join_key_exprs(node)
-        bb = _bounds_list(build, scan_bounds)
+        bkeys, _ = eligibility.join_key_exprs(node)
+        bb = bounds_list(build, scan_bounds)
         bounds: Optional[List[Tuple[int, int]]] = []
         domain = 1
         for e in bkeys:
@@ -651,7 +264,7 @@ def plan_join_configs(root: PhysicalPlan, scan_bounds) -> List[JoinCfg]:
                 break
         est = max(int(node.est_rows), 1)
         mode = "unique" if _build_unique_hint(node) else "expand"
-        out_cap = _pow2(int(est * 1.3), lo=1024) if mode == "expand" else 0
+        out_cap = pow2(int(est * 1.3), lo=1024) if mode == "expand" else 0
         cfgs.append(JoinCfg(mode, out_cap,
                             tuple(bounds) if bounds else None,
                             domain if bounds else 0, est))
@@ -669,7 +282,7 @@ def tree_agg_key_bounds(root: PhysicalPlan, scan_bounds,
         return None
     if getattr(root, "rollup", False):
         return None     # level tiling needs the sort factorize
-    inp = _bounds_list(root.children[0], scan_bounds)
+    inp = bounds_list(root.children[0], scan_bounds)
     out: Optional[List[Tuple[int, int]]] = []
     domain = 1
     for e in root.group_exprs:
@@ -680,16 +293,12 @@ def tree_agg_key_bounds(root: PhysicalPlan, scan_bounds,
         lo, hi = inp[e.index]
         domain *= (hi - lo + 2)
         out.append((lo, hi))
-    from tidb_tpu.executor import device_emit
-    from tidb_tpu.executor.fragment import SLOT_ADDRESS_CAP
-    from tidb_tpu.expression import ranges
-    from tidb_tpu.ops.factorize import choose_key_bounds
     return choose_key_bounds(
         out, domain, SLOT_ADDRESS_CAP, domain_cap,
         device_emit.sorted_runs_ok(root), ranges.agg_arg_bits(
             root, tuple((n, tuple(sorted(b.items())))
                         for n, b in scan_bounds.items()),
-            lambda: _bounds_list(root.children[0], scan_bounds, True)))
+            lambda: bounds_list(root.children[0], scan_bounds, True)))
 
 
 # ---------------------------------------------------------------------------
@@ -704,7 +313,7 @@ def tree_signature(plan: PhysicalPlan, caps: Dict[int, Tuple[int, int]],
              f"akb={bounds_sig(agg_key_bounds)}"]
     ji = 0
     si = 0
-    for node in _walk_nodes(plan):
+    for node in walk_nodes(plan):
         if isinstance(node, PhysTableScan):
             cap = caps[id(node)]
             cap = cap if isinstance(cap, tuple) else (cap, 1)
@@ -795,14 +404,15 @@ class TreeProgram:
                      for k, v in caps.items()}
         self.group_cap = group_cap
         self.agg_key_bounds = agg_key_bounds
-        joins = _walk_joins(plan)
+        joins = walk_joins(plan)
         if join_cfgs is None:
             join_cfgs = [JoinCfg("unique") for _ in joins]
         self.join_cfgs = {id(n): c for n, c in zip(joins, join_cfgs)}
         self.join_order = {id(n): i for i, n in enumerate(joins)}
-        self.scan_order = _scans(plan)
+        self.scan_order = scans_of(plan)
         self.nested_order = {id(n): i
-                             for i, n in enumerate(nested_fragments(plan))}
+                             for i, n in enumerate(
+                                 eligibility.nested_fragments(plan))}
         # per-scan-slot ((col, ColLayout), ...) pairs, parallel to
         # scan_order: compressed columns decode INSIDE the trace at the
         # scan emit — raw bytes never crossed PCIe
@@ -820,8 +430,8 @@ class TreeProgram:
         if isinstance(plan, PhysHashAgg):
             self.aggs = [build_agg(d) for d in plan.aggs]
         self.prep_nodes: List[Expression] = []
-        for node in _walk_nodes(plan):
-            for e in _stage_exprs(node):
+        for node in walk_nodes(plan):
+            for e in stage_exprs(node):
                 for sub in e.walk():
                     if type(sub).prepare is not Expression.prepare:
                         self.prep_nodes.append(sub)
@@ -835,8 +445,8 @@ class TreeProgram:
         not node identity — because compile-cache hits reuse this program
         for fresh plan objects whose node ids differ."""
         vals = []
-        for node, dicts in zip(_walk_nodes(self.plan), flow_list):
-            for e in _stage_exprs(node):
+        for node, dicts in zip(walk_nodes(self.plan), flow_list):
+            for e in stage_exprs(node):
                 for sub in e.walk():
                     if type(sub).prepare is not Expression.prepare:
                         vals.append(sub.prepare(dicts))
@@ -845,12 +455,10 @@ class TreeProgram:
     # -- trace ---------------------------------------------------------------
     def _run(self, scan_inputs, scan_rows, prep_vals, aligned_inputs=(),
              ranges=None, nested=()):
-        from tidb_tpu.executor.device_cache import in_place
-        from tidb_tpu.executor.fragment import _count_trace
-        _count_trace()        # once per TRACE — perf_smoke retrace meter
+        compile_cache.count_trace()     # once per TRACE (the retrace meter)
         # (of a stacked column: a table read whole lists its slabs here,
         # the anchor's one slab is indexed here — read where they lie)
-        scan_inputs, scan_rows, aligned_inputs = in_place(
+        scan_inputs, scan_rows, aligned_inputs = device_cache.in_place(
             (scan_inputs, scan_rows, aligned_inputs))
         self._prepared = {id(n): v
                           for n, v in zip(self.prep_nodes, prep_vals)
@@ -866,7 +474,6 @@ class TreeProgram:
         return self._finish(cols, live)
 
     def _ctx(self, cols):
-        from tidb_tpu.ops.jax_env import jnp
         return EvalContext(jnp, cols, prepared=self._prepared,
                            on_device=True)
 
@@ -875,8 +482,6 @@ class TreeProgram:
         nodes; root reductions are handled in _finish. The column list is
         ALWAYS schema-length so join concatenation stays positionally
         aligned (unused columns ride as None)."""
-        from tidb_tpu.executor.device_emit import stage
-        from tidb_tpu.ops.jax_env import jnp
         if isinstance(node, PhysTableScan):
             sub = self._scan_sub.get(id(node))
             if sub is not None:
@@ -884,7 +489,7 @@ class TreeProgram:
                 # row space; live starts from the match mask
                 col_list, live = sub
                 ctx = self._ctx(col_list)
-                with stage("filter"):
+                with device_emit.stage("filter"):
                     for f in node.filters:
                         v, m = f.eval(ctx)
                         live = live & (v != 0) & m
@@ -905,7 +510,6 @@ class TreeProgram:
                 if c is not None and lay is not None:
                     # compressed slab(s): traced decode (gather-free
                     # shift/mask, fused by XLA into the scan it feeds)
-                    from tidb_tpu.executor import device_emit
                     if isinstance(c, (list, tuple)) and c and \
                             isinstance(c[0], tuple):
                         c = [device_emit.emit_decode(lay, s, slab_cap)
@@ -943,7 +547,7 @@ class TreeProgram:
                 start, stop = self._ranges
                 live = live & (iota >= start) & (iota < stop)
             ctx = self._ctx(col_list)
-            with stage("filter"):
+            with device_emit.stage("filter"):
                 for f in node.filters:
                     v, m = f.eval(ctx)
                     live = live & (v != 0) & m
@@ -956,7 +560,7 @@ class TreeProgram:
         if isinstance(node, PhysSelection):
             cols, live = self._emit(node.children[0], scan_inputs, scan_rows)
             ctx = self._ctx(cols)
-            with stage("filter"):
+            with device_emit.stage("filter"):
                 for c in node.conditions:
                     v, m = c.eval(ctx)
                     live = live & (v != 0) & m
@@ -964,7 +568,7 @@ class TreeProgram:
         if isinstance(node, PhysProjection):
             cols, live = self._emit(node.children[0], scan_inputs, scan_rows)
             ctx = self._ctx(cols)
-            with stage("project"):
+            with device_emit.stage("project"):
                 return [e.eval(ctx) for e in node.exprs], live
         if isinstance(node, PhysHashJoin):
             return self._emit_join(node, scan_inputs, scan_rows)
@@ -972,7 +576,6 @@ class TreeProgram:
             # interior window: compute the window columns in-trace and
             # hand them to the operator above (a window ROOT is emitted
             # by _finish via emit_root instead)
-            from tidb_tpu.executor import device_emit
             cols, live = self._emit(node.children[0], scan_inputs,
                                     scan_rows)
             out = device_emit.emit_window_cols(self._ctx(cols), live,
@@ -985,9 +588,6 @@ class TreeProgram:
 
     # -- join ---------------------------------------------------------------
     def _emit_join(self, node: PhysHashJoin, scan_inputs, scan_rows):
-        from tidb_tpu.executor.device_emit import stage
-        from tidb_tpu.ops.jax_env import jnp
-        from tidb_tpu.ops import join as J
         cfg = self.join_cfgs[id(node)]
         if cfg.mode == "aligned":
             return self._emit_join_aligned(node, cfg, scan_inputs,
@@ -998,8 +598,8 @@ class TreeProgram:
             bcols, blive, pcols, plive = rcols, rlive, lcols, llive
         else:
             bcols, blive, pcols, plive = lcols, llive, rcols, rlive
-        with stage("join_probe"):
-            bkeys, pkeys = join_key_exprs(node)
+        with device_emit.stage("join_probe"):
+            bkeys, pkeys = eligibility.join_key_exprs(node)
             bctx = self._ctx(bcols)
             # the probe ctx must see the JOIN flow for KeyRemap preps, but
             # KeyRemap evals its child against probe-side columns
@@ -1051,7 +651,6 @@ class TreeProgram:
         ZERO device work beyond evaluating the build side's filters on the
         aligned columns. Probe rowspace is preserved exactly — unique-mode
         semantics with an identity gather."""
-        from tidb_tpu.ops.jax_env import jnp
         bi = 1 if node.build_right else 0
         build, probe = node.children[bi], node.children[1 - bi]
         ji = self.join_order[id(node)]
@@ -1082,9 +681,8 @@ class TreeProgram:
         joined = (list(pcols) + list(bcols) if node.build_right
                   else list(bcols) + list(pcols))
         if node.other_conditions:
-            from tidb_tpu.executor.device_emit import stage
             jctx = self._ctx(joined)
-            with stage("join_probe"):
+            with device_emit.stage("join_probe"):
                 for cond in node.other_conditions:
                     v, m = cond.eval(jctx)
                     bmatched = bmatched & (v != 0) & m
@@ -1104,7 +702,6 @@ class TreeProgram:
 
     def _finish_join_unique(self, node, bcols, pcols, plive, match_idx,
                             matched):
-        from tidb_tpu.ops.jax_env import jnp
 
         def gather_build(keep):
             out = []
@@ -1146,9 +743,6 @@ class TreeProgram:
 
     def _finish_join_expand(self, node, cfg: JoinCfg, bcols, pcols, plive,
                             start, count, order):
-        from tidb_tpu.ops.jax_env import jnp
-        from tidb_tpu.ops import join as J
-        from tidb_tpu.ops import segment as seg
         P = plive.shape[0]
         if node.kind in ("semi", "anti") and not node.other_conditions:
             self._join_totals.append(jnp.int64(0))
@@ -1205,8 +799,6 @@ class TreeProgram:
 
     # -- root reductions ------------------------------------------------------
     def _finish(self, cols, live):
-        from tidb_tpu.ops.jax_env import jnp
-        from tidb_tpu.executor import device_emit
         root = self.plan
         flags = self._join_unique_flags
         out_flags = {

@@ -11,7 +11,7 @@ path the device engine traces.
 Two execution paths share the ops/window.py primitives:
 
 * **Device** — when the engine is on, the input clears the row threshold
-  and every spec passes the fragment gate (fragment._window_device_ok),
+  and every spec passes the fragment gate (eligibility.window_device_ok),
   the per-spec sort runs as a device lexsort over the HOST-rank-encoded
   keys (executor/sort.rank_keys bakes in direction + MySQL NULL
   ordering, so the device comparison is a plain int compare) and the
@@ -31,11 +31,14 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from tidb_tpu.chunk import Chunk, Column
-from tidb_tpu.executor import Executor, MaterializingExec, _empty_chunk
+from tidb_tpu.executor import Executor, MaterializingExec, empty_chunk
+from tidb_tpu.executor.eligibility import window_device_ok
+from tidb_tpu.executor.sort import rank_keys
 from tidb_tpu.expression import EvalContext
 from tidb_tpu.expression.runner import host_context
 from tidb_tpu.ops import window as W
 from tidb_tpu.planner.physical import PhysWindow
+from tidb_tpu.sysvars import var_int, var_on
 from tidb_tpu.types import TypeKind
 
 
@@ -54,7 +57,7 @@ class WindowExec(MaterializingExec):
             if ch.num_rows:
                 chunks.append(ch)
         if not chunks:
-            return _empty_chunk(self.schema)
+            return empty_chunk(self.schema)
         inp = Chunk.concat(chunks) if len(chunks) > 1 else chunks[0]
         ctx = host_context(inp)
         n = inp.num_rows
@@ -88,16 +91,11 @@ class WindowExec(MaterializingExec):
         return Chunk(out_cols)
 
     def _device_eligible(self, n: int) -> bool:
-        from tidb_tpu.executor.fragment import (_var_bool,
-                                                _window_device_ok)
-        from tidb_tpu.planner.physical import DEFAULT_TPU_ROW_THRESHOLD
         ctx = getattr(self, "ctx", None)
-        vars_ = getattr(ctx, "vars", None) or {}
-        if not _var_bool(vars_.get("tidb_tpu_engine", "off")):
+        if ctx is None or not var_on(ctx.vars, "tidb_tpu_engine"):
             return False
-        threshold = int(vars_.get("tidb_tpu_row_threshold",
-                                  DEFAULT_TPU_ROW_THRESHOLD))
-        return n >= max(threshold, 1) and _window_device_ok(self.plan)
+        threshold = var_int(ctx.vars, "tidb_tpu_row_threshold")
+        return n >= max(threshold, 1) and window_device_ok(self.plan)
 
     def _one_device(self, d, ctx, inp, n: int, key: str,
                     sort_cache) -> Optional[Column]:
@@ -109,7 +107,6 @@ class WindowExec(MaterializingExec):
             from tidb_tpu.ops.jax_env import jnp
             layout = sort_cache.get("dev|" + key)
             if layout is None:
-                from tidb_tpu.executor.sort import rank_keys
                 pkeys = rank_keys(list(d.partition),
                                   [False] * len(d.partition), inp)
                 okeys = rank_keys(list(d.order), list(d.descs), inp)
@@ -220,7 +217,6 @@ def _sorted_layout(chunk: Chunk, n: int, d):
     """→ (sidx, pstart, peerstart) for one window spec. Rank-encoded keys
     (executor/sort.rank_keys) bake in direction and MySQL NULL ordering,
     so boundary detection is a plain code comparison."""
-    from tidb_tpu.executor.sort import rank_keys
     pkeys = rank_keys(list(d.partition), [False] * len(d.partition), chunk)
     okeys = rank_keys(list(d.order), list(d.descs), chunk)
     all_keys = pkeys + okeys

@@ -1,13 +1,13 @@
 """Per-slab zone maps + host-side slab pruning.
 
-At encode time (device_cache._col_prep) every cached column gets
+At encode time (device_cache.col_prep) every cached column gets
 per-slab statistics — min/max over valid values, null count, row
 count, and a distinct-count estimate. Before a fragment dispatches,
 `prune_slabs` evaluates the scan's conjunctive predicates
 (comparisons, desugared BETWEEN, IN, IS [NOT] NULL) against those
 statistics host-side and returns the set of slabs that CANNOT contain
 a passing row. A pruned slab costs nothing: no H2D transfer on cold
-first touch (device_cache._stream_slabs skips encode+upload), no
+first touch (device_cache.stream_slabs skips encode+upload), no
 program launch warm, no escalation bookkeeping.
 
 Statistics live in the space the device program compares in, so
@@ -44,6 +44,8 @@ import numpy as np
 from tidb_tpu.errors import LayoutError
 from tidb_tpu.types import TypeKind
 from tidb_tpu.util import failpoint
+from tidb_tpu.expression import ColumnRef, Constant, ScalarFunc
+from tidb_tpu.util.observability import REGISTRY
 
 failpoint.register(
     "zone-map-stale", "zone-map consult at the host-side slab-prune "
@@ -167,7 +169,6 @@ def surviving(ent, scan, skipped) -> List[int]:
 def _prune_mask(expr, ent, scan, n_slabs) -> Optional[np.ndarray]:
     """Per-slab prune verdict for ONE conjunct, or None when the shape
     is not understood (contributes no pruning)."""
-    from tidb_tpu.expression import ScalarFunc
     if not isinstance(expr, ScalarFunc):
         return None
     op = expr.op
@@ -210,7 +211,6 @@ def _prune_mask(expr, ent, scan, n_slabs) -> Optional[np.ndarray]:
 
 def _column_side(args):
     """(col_ref, const, flipped) for a 2-arg comparison, or None."""
-    from tidb_tpu.expression import ColumnRef, Constant
     if len(args) != 2:
         return None
     a, b = args
@@ -222,7 +222,6 @@ def _column_side(args):
 
 
 def _isnull_mask(expr, ent, n_slabs, negate=False):
-    from tidb_tpu.expression import ColumnRef
     arg = expr.args[0]
     if not isinstance(arg, ColumnRef):
         return None
@@ -383,7 +382,6 @@ def _in_mask(expr, ent, scan, n_slabs):
     """col IN (c1, c2, ...): a slab survives iff SOME item can fall in
     its [lo, hi] window (string items: iff present in the dictionary
     inside the window)."""
-    from tidb_tpu.expression import ColumnRef, Constant
     if not expr.args or not isinstance(expr.args[0], ColumnRef):
         return None
     col = expr.args[0]
@@ -449,7 +447,6 @@ def note_skipped(phases, n: int) -> None:
     if phases is not None:
         phases.note_slabs_skipped(n)
     dev = getattr(phases, "device_index", 0) if phases is not None else 0
-    from tidb_tpu.util.observability import REGISTRY
     REGISTRY.inc("tidb_tpu_slabs_skipped_total",
                  {"engine": "device", "device": str(dev or 0)},
                  by=n)
@@ -462,7 +459,6 @@ def note_h2d_skipped(phases, nbytes: int, table: str = "") -> None:
         return
     if phases is not None:
         phases.note_h2d_skipped(nbytes)
-    from tidb_tpu.util.observability import REGISTRY
     REGISTRY.observe("tidb_tpu_h2d_skipped_bytes", nbytes,
                      {"table": table})
 

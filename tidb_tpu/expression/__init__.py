@@ -2071,7 +2071,7 @@ def _floor_mod(xp, a, n):
 
 # ops whose kernels can only run host-side (string results with no
 # dictionary precompute, or object-array machinery) — the device gate
-# (_fragment_ok/tree_ok) rejects fragments containing them up front
+# (eligibility.fragment_ok/tree_ok) rejects fragments containing them up front
 # ---------------------------------------------------------------------------
 # Temporal epoch conversions, digests, radix conversions
 # (ref: expression/builtin_time.go, builtin_encryption.go, builtin_math.go)
@@ -2668,6 +2668,29 @@ def func(op: str, *args: Expression, ftype: Optional[FieldType] = None
 
 def cast(arg: Expression, target: FieldType) -> ScalarFunc:
     return ScalarFunc("cast", [arg], target)
+
+
+def coerce_key_pair(l: Expression, r: Expression):
+    """Cast both sides of an equi pair into one comparable domain
+    (decimal scales equalized; int vs float → double)."""
+    lt, rt = l.ftype, r.ftype
+    if lt.kind.is_string or rt.kind.is_string:
+        return l, r
+    if lt.kind == rt.kind and lt.scale == rt.scale:
+        return l, r
+    common = T.merge_numeric(lt, rt)
+    if common.kind is TypeKind.DECIMAL:
+        if lt.scale != common.scale or lt.kind is not TypeKind.DECIMAL:
+            l = cast(l, common)
+        if rt.scale != common.scale or rt.kind is not TypeKind.DECIMAL:
+            r = cast(r, common)
+        return l, r
+    if common.kind.is_float:
+        if not lt.kind.is_float:
+            l = cast(l, common)
+        if not rt.kind.is_float:
+            r = cast(r, common)
+    return l, r
 
 
 def lit(value, ftype: Optional[FieldType] = None) -> Constant:

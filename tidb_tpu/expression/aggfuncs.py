@@ -22,6 +22,8 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from tidb_tpu import types as T
+from tidb_tpu.chunk.compress import (WIDE_LIMB_BASE, WIDE_LIMB_BITS,
+                                     wide_decimal_unlimb)
 from tidb_tpu.errors import PlanError
 from tidb_tpu.expression import ColumnRef, Expression
 from tidb_tpu.ops import segment as seg
@@ -235,8 +237,6 @@ class SumAgg(AggFunc):
                      for _ in range(planes + 1))   # limbs… + counts
 
     def _input_limbs(self, xp, values):
-        from tidb_tpu.executor.device_cache import (WIDE_LIMB_BASE,
-                                                    WIDE_LIMB_BITS)
         if getattr(values, "ndim", 1) == 2:
             return [values[k] for k in range(values.shape[0])]
         mask = xp.int64(WIDE_LIMB_BASE - 1)
@@ -268,7 +268,7 @@ class SumAgg(AggFunc):
                 return [seg.SumColumn(v, validity), count]
             return [seg.SumColumn(v, validity, None, 0, bits, False), count]
         # the limb planes of _update_wide, each a bit field of the value
-        from tidb_tpu.executor.device_cache import WIDE_LIMB_BITS as B
+        B = WIDE_LIMB_BITS
         if getattr(values, "ndim", 1) == 2:
             limbs = [seg.SumColumn(values, validity, k)
                      for k in range(values.shape[0])]
@@ -318,7 +318,6 @@ class SumAgg(AggFunc):
             # args. Recombining the limbs is exact (no carries, see
             # _init_wide), and the scale correction mirrors _sum_of: the
             # limb update accumulated RAW input limbs without _cast_in
-            from tidb_tpu.executor.device_cache import wide_decimal_unlimb
             limbs = np.stack([np.asarray(a) for a in partial[:-1]])
             psums = wide_decimal_unlimb(limbs)
             if self._out_scale > self._in_scale:
@@ -349,7 +348,6 @@ class SumAgg(AggFunc):
             hi, lo, counts = state
             return hi.astype(np.float64) + lo.astype(np.float64), counts
         if self._wide and len(state) > 2:
-            from tidb_tpu.executor.device_cache import wide_decimal_unlimb
             limbs = np.stack([np.asarray(a) for a in state[:-1]])
             sums = wide_decimal_unlimb(limbs)    # one base, all producers
             if self._out_scale > self._in_scale:
@@ -394,8 +392,6 @@ class SumAgg(AggFunc):
         # (arithmetic shifts floor, so negatives work), then (top, middle ·
         # 2³⁰ + low) orders like the value — the scale correction of
         # _sum_of is a positive constant and cannot reorder
-        from tidb_tpu.executor.device_cache import (WIDE_LIMB_BASE,
-                                                    WIDE_LIMB_BITS)
         assert self.orders_in_trace
         l0, l1, l2 = state[:3]
         mask = xp.int64(WIDE_LIMB_BASE - 1)

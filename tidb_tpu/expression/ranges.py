@@ -6,7 +6,7 @@ back by a floor division, a sum's operands meet at the larger scale.
 
 Who asks: the aggregate's contraction (`ops/segment.slot_sums`) cuts only
 the bits a summed value can hold, and learns them here from the device
-cache's per-column (min, max) (`executor/device_cache._col_bounds`).
+cache's per-column (min, max) (`executor/device_cache.col_bounds`).
 Sound means: for rows whose every leaf lies inside its column's range,
 the evaluator's value lies inside the range returned. Every step is held
 to int64, the dtype the evaluator computes in: where an intermediate

@@ -12,6 +12,7 @@ from typing import Callable, Dict, List, Tuple
 
 from tidb_tpu import types as T
 from tidb_tpu.errors import UnknownTableError
+from tidb_tpu.executor import device_cache
 
 # name → (column name, type) list + row builder(session) → rows
 _TABLES: Dict[str, Tuple[List[Tuple[str, object]],
@@ -279,7 +280,6 @@ def _table_storage(session):
     the columns it uploaded. The ZONE_MAP_* columns expose the
     encode-time per-slab statistics slab pruning consults (slab count,
     global min/max over known slabs, total null count)."""
-    from tidb_tpu.executor import device_cache
     names = {t.id: t.name for t in _user_tables(session)}
     cols = {t.id: [c.name for c in t.columns] for t in _user_tables(session)}
     out = []

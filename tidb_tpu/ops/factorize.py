@@ -142,7 +142,7 @@ WORD_BITS = 62      # a packed key word stays a non-negative int64
 
 
 # How a grouped aggregate's partials find their groups — the three
-# lowerings, named where they are chosen (fragment._agg_key_bounds,
+# lowerings, named where they are chosen (agg_slabs.chain_key_bounds,
 # tree_fragment.tree_agg_key_bounds) and read everywhere else:
 SLOTS = "slots"          # key bounds address the group slots directly
 RUNS = "runs"            # key bounds pack the keys into sort words: sorted runs
@@ -189,7 +189,7 @@ def bounds_sig(key_bounds: Optional[KeyBounds]) -> str:
     but not for RUNS: there this names the SLAB programs, which only hand
     out rows, and with them the statement's `aggrows`; the widths are
     trace constants of the finalize alone, which appends `widths_sig`
-    itself (fragment._runs_finalize), so a width that moves renames no
+    itself (agg_slabs._runs_finalize), so a width that moves renames no
     slab program and no shared sort."""
     if key_bounds is None:
         return "None"
@@ -285,7 +285,7 @@ def sort_rows(words: Sequence, live, payloads: Sequence):
     (DEAD_WORD) instead of a key operand of its own, the run ends come
     from a ONE-operand uint32 sort (flag in the top bit, position below),
     and this function is its own program, shared by every statement
-    (fragment._SortRowsProgram): no statement's programs hold a sort."""
+    (agg_slabs._SortRowsProgram): no statement's programs hold a sort."""
     n = live.shape[0]
     words = [jnp.where(live, w, jnp.int64(DEAD_WORD)) for w in words]
     out = lax.sort(tuple(words) + tuple(payloads), num_keys=len(words))

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
-from tidb_tpu.expression import ColumnRef, Expression
+from tidb_tpu.expression import ColumnRef, Expression, coerce_key_pair
 from tidb_tpu.expression.aggfuncs import AggDesc, build_agg
 from tidb_tpu.planner.logical import (LogicalAggregation, LogicalDataSource,
                                       LogicalDual, LogicalJoin, LogicalLimit,
@@ -27,8 +27,9 @@ from tidb_tpu.planner.logical import (LogicalAggregation, LogicalDataSource,
                                       LogicalSort, LogicalTopN,
                                       LogicalUnionAll, LogicalWindow,
                                       Schema)
+from tidb_tpu.sysvars import DEFAULT_VARS
 
-DEFAULT_TPU_ROW_THRESHOLD = 32768
+DEFAULT_TPU_ROW_THRESHOLD = DEFAULT_VARS["tidb_tpu_row_threshold"]
 
 
 class PhysicalPlan:
@@ -378,7 +379,6 @@ def insert_exchanges(node: PhysicalPlan, n_shards: int) -> PhysicalPlan:
         return node
     if not isinstance(node, PhysHashJoin) or not node.equi:
         return node
-    from tidb_tpu.executor.join import coerce_key_pair
     coerced = [coerce_key_pair(l, r) for l, r in node.equi]
     lkeys = [c[0] for c in coerced]
     rkeys = [c[1] for c in coerced]
@@ -655,7 +655,9 @@ def physical_optimize(plan: LogicalPlan, ctx) -> PhysicalPlan:
     phys = order_below_projection(phys)
     use_tpu = bool(getattr(ctx, "use_tpu", False))
     if use_tpu:
-        from tidb_tpu.executor.fragment import extract_fragments
+        # (the plan nodes above are what the executor is built on: its
+        # question — which subtrees run on the device — is asked UP here)
+        from tidb_tpu.executor.eligibility import extract_fragments
         threshold = int(getattr(ctx, "tpu_row_threshold",
                                 DEFAULT_TPU_ROW_THRESHOLD))
         phys = extract_fragments(phys, threshold)
@@ -671,8 +673,7 @@ def _distribute_fragments(plan: PhysicalPlan, n_shards: int,
     insert exchange boundaries (the fragmentation pass) and mark them for
     shard_map compilation."""
     if isinstance(plan, PhysTpuFragment):
-        from tidb_tpu.executor.tree_fragment import (dist_ok,
-                                                     nested_fragments)
+        from tidb_tpu.executor.eligibility import dist_ok, nested_fragments
         # a nested device-rows fragment lives on ONE device: its
         # enclosing tree stays single-device too
         if dist_ok(plan.root, threshold) and \
@@ -721,7 +722,6 @@ def _try_merge_join(join: LogicalJoin, left: PhysicalPlan,
     if not isinstance(left, PhysTableScan) or \
             not isinstance(right, PhysTableScan):
         return None
-    from tidb_tpu.executor.join import coerce_key_pair
     le, re = join.equi[0]
     if le.ftype.kind.is_string != re.ftype.kind.is_string:
         return None
@@ -760,7 +760,6 @@ def _try_index_join(join: LogicalJoin, left: PhysicalPlan,
         return None
     if not isinstance(right, PhysTableScan):
         return None
-    from tidb_tpu.executor.join import coerce_key_pair
     le, re = join.equi[0]
     # string vs numeric keys compare NUMERICALLY in MySQL; the raw index
     # probe can't serve that (coerce_key_pair passes strings through)

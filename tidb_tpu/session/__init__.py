@@ -20,13 +20,17 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from tidb_tpu import types as T
+from tidb_tpu import sysvars, types as T
 from tidb_tpu.catalog import Catalog, ColumnInfo, IndexInfo, TableInfo
 from tidb_tpu.chunk import Chunk, Column
 from tidb_tpu.errors import (DDLError, ExecutionError, PlanError,
                              SchemaChangedError, TiDBTPUError, TxnError,
                              UnknownColumnError, UnknownTableError)
-from tidb_tpu.executor import ExecContext, build, run_to_completion
+from tidb_tpu.executor import ExecContext, delta, run_to_completion
+from tidb_tpu.executor.builder import build
+from tidb_tpu.executor.eligibility import check_strict_plan
+from tidb_tpu.executor.fragment import TpuFragmentExec
+from tidb_tpu.executor.scan import align_chunk_to_schema
 from tidb_tpu.expression import Expression
 from tidb_tpu.expression.runner import eval_on_chunk, filter_mask
 from tidb_tpu.parser import ast, parse
@@ -34,68 +38,9 @@ from tidb_tpu.planner import optimize
 from tidb_tpu.planner.builder import ExpressionRewriter, SubqueryEvaluator
 from tidb_tpu.planner.logical import Schema
 from tidb_tpu.storage import Store, Transaction
+from tidb_tpu.sysvars import is_on, var_int, var_on, var_str
 from tidb_tpu.types import FieldType
 from tidb_tpu.util import timeline
-
-DEFAULT_VARS: Dict[str, object] = {
-    # ref: sessionctx/variable/tidb_vars.go — the knobs our engine honors
-    "max_chunk_size": 65536,
-    "tidb_tpu_engine": "auto",        # on | off | auto (auto: on when TPU)
-    "tidb_tpu_row_threshold": 32768,  # min est. rows to route to device
-    # staged (checkpointable, per-shard recoverable) distributed agg;
-    # off = always the monolithic shard_map program
-    "tidb_tpu_dist_staged": "on",
-    # staged exchange-carrying fragments (distributed joins, DISTINCT
-    # re-keys, windows): partition → device→host bucket checkpoint →
-    # per-rank probe, each stage re-dispatchable per rank; off = the
-    # monolithic in-trace all_to_all program (the byte-exactness oracle)
-    "tidb_tpu_dist_staged_exchange": "on",
-    # compressed device-resident columns (bit-pack / frame-of-reference /
-    # dictionary) with decode fused into the scan; off = raw layouts
-    "tidb_tpu_compression": "on",
-    "tidb_mem_quota_query": 8 << 30,
-    "sql_mode": "STRICT_TRANS_TABLES",
-    "autocommit": 1,
-    # statement deadline in ms, 0 = none. Deviation from MySQL (which
-    # scopes it to read-only SELECT): applies to EVERY statement — the
-    # never-hang guarantee matters more here than MySQL fidelity
-    "max_execution_time": 0,
-    # when non-empty, every session records its spans, packet-in to last
-    # byte out, into ONE Chrome-trace JSON under this directory
-    # (util/timeline.py; written every 5 s and on stop) — load it in
-    # chrome://tracing or Perfetto
-    "tidb_tpu_trace_dir": "",
-    # priority-aware serving tier (executor/scheduler.py): classify each
-    # admission as interactive/batch and grant the device slot by class;
-    # off = the plain FIFO admission order, byte-identical to classless
-    "tidb_tpu_priority_scheduling": "on",
-    # same-plan micro-batching (executor/microbatch.py): coalesce up to
-    # this many queued same-digest statements into ONE batched device
-    # program. 1 = parametrize only (shared programs, no coalescing),
-    # 0 = literal-baked programs (the pre-serving-tier behavior)
-    "tidb_tpu_microbatch_max": 16,
-    # one admission queue per visible device with locality-aware
-    # placement and work stealing (SchedulerPool): auto = on when more
-    # than one device is visible (single-device hosts size the pool to
-    # 1, byte-identical to the shared device-0 queue); off = every
-    # statement shares the device-0 queue (the PR 15 serving tier)
-    "tidb_tpu_device_queues": "auto",
-    # tables with at least this many rows partition their slab ranges
-    # across the pool (one contiguous span per owner device) instead of
-    # replicating a full copy per device (executor/device_cache.py)
-    "tidb_tpu_partition_min_rows": 1 << 22,
-    # coalesced single-row ingest (session/writebatch.py): N queued
-    # same-digest autocommit writes share ONE commit — readers pay one
-    # delta extension instead of N; off = every write commits alone
-    "tidb_tpu_write_coalesce": "on",
-    # async compaction of delta-extended cache entries (executor/
-    # delta.py): rebuild base slabs with re-chosen layouts in idle
-    # batch-class slots, when the entry's own sizes say it is due
-    # (delta.compaction_due); off = deltas accumulate until a test or a
-    # tool drains them via delta.run_pending_compactions()
-    "tidb_tpu_compaction": "on",
-}
-
 
 class ResultSet:
     """Query result. `rows` (python tuples) materialize lazily from the
@@ -337,7 +282,6 @@ def _note_host_rows(exec_root) -> None:
     operator's own `wall_ms`) under `executor.run`. A statement whose
     joins, grouping, ordering and limit ran on the device leaves the host
     its result rows."""
-    from tidb_tpu.executor.fragment import TpuFragmentExec
     from tidb_tpu.util.observability import REGISTRY
 
     def walk(ex):
@@ -471,7 +415,6 @@ class Engine:
         if t is not None and t is not threading.current_thread():
             t.join(timeout=10.0)
         # (likewise the device cache's compactor, for this engine's store)
-        from tidb_tpu.executor import delta
         delta.forget_store(self.store)
 
     def _auto_analyze_pass(self) -> None:
@@ -480,10 +423,9 @@ class Engine:
         (or that accumulated tidb_auto_analyze_min_rows with no stats)
         re-analyzes on THIS thread. Config reads GLOBAL scope — the
         analyzer serves every session."""
-        from tidb_tpu.executor.fragment import _var_bool
         from tidb_tpu.parser import ast as _ast
         gv = self.global_vars
-        if not _var_bool(gv.get("tidb_enable_auto_analyze", True)):
+        if not is_on(gv.get("tidb_enable_auto_analyze", True)):
             return
         ratio = float(gv.get("tidb_auto_analyze_ratio", 0.5))
         min_rows = int(gv.get("tidb_auto_analyze_min_rows", 1000))
@@ -556,7 +498,7 @@ class _PlanContext:
 
     @property
     def use_tpu(self) -> bool:
-        mode = str(self.session.vars.get("tidb_tpu_engine", "auto"))
+        mode = var_str(self.session.vars, "tidb_tpu_engine")
         if mode == "off":
             return False
         if mode == "on":
@@ -566,15 +508,15 @@ class _PlanContext:
 
     @property
     def tpu_row_threshold(self) -> int:
-        return int(self.session.vars.get("tidb_tpu_row_threshold", 32768))
+        return var_int(self.session.vars, "tidb_tpu_row_threshold")
 
     @property
     def dist_devices(self) -> int:
         """Shards for distributed fragments: tidb_tpu_dist_devices=N pins
         an N-way mesh; 'auto' uses every visible device (>1 ⇒ MPP-style
         distribution; the tidb_allow_mpp analog)."""
-        v = self.session.vars.get("tidb_tpu_dist_devices", 0)
-        if str(v) == "auto":
+        v = var_str(self.session.vars, "tidb_tpu_dist_devices")
+        if v == "auto":
             import jax
             return len(jax.devices())
         try:
@@ -588,7 +530,7 @@ class Session:
 
     def __init__(self, engine: Optional[Engine] = None):
         self.engine = engine or Engine()
-        self.vars: Dict[str, object] = dict(DEFAULT_VARS)
+        self.vars: Dict[str, object] = dict(sysvars.DEFAULT_VARS)
         self.vars.update(self.engine.global_vars)
         self.txn: Optional[Transaction] = None
         self.last_plan = None
@@ -681,9 +623,7 @@ class Session:
             # admission classification for the priority-aware scheduler:
             # the class + cost hint ride the guard into every
             # device_slot() acquire of this statement
-            prio = str(self.vars.get("tidb_tpu_priority_scheduling",
-                                     "on")).lower()
-            if prio not in ("off", "0", "false"):
+            if var_on(self.vars, "tidb_tpu_priority_scheduling"):
                 guard.sched_class, guard.sched_cost = \
                     _classify_admission(s, one, from_prepared)
                 # tables the digest historically touched: the pool's
@@ -698,7 +638,7 @@ class Session:
             PROCESS_REGISTRY.stmt_begin(self.conn_id, guard)
             # opt-in cross-session Chrome trace: the sysvar names the
             # directory; start is idempotent, clearing the var stops it
-            trace_dir = str(self.vars.get("tidb_tpu_trace_dir", "") or "")
+            trace_dir = var_str(self.vars, "tidb_tpu_trace_dir")
             if trace_dir:
                 timeline.start_global(trace_dir)
             # bind the statement's attribution ledger to this thread so
@@ -1231,9 +1171,9 @@ class Session:
                 info_schema.version,
                 self.engine.stats_version,
                 tuple(sizes),
-                str(v.get("tidb_tpu_engine")),
-                int(v.get("tidb_tpu_row_threshold", 32768)),
-                str(v.get("tidb_tpu_dist_devices", 0)),
+                var_str(v, "tidb_tpu_engine"),
+                var_int(v, "tidb_tpu_row_threshold"),
+                var_str(v, "tidb_tpu_dist_devices"),
                 str(v.get("time_zone", "SYSTEM")),  # tz folds into plans
                 self.user)
 
@@ -1360,9 +1300,8 @@ class Session:
         with maybe_span(tr, "planner.optimize"):
             plan = self._plan(stmt)
         self.last_plan = plan
-        from tidb_tpu.executor.fragment import _var_bool, check_strict_plan
         pctx = _PlanContext(self)
-        if _var_bool(self.vars.get("tidb_tpu_strict", False)) and \
+        if var_on(self.vars, "tidb_tpu_strict") and \
                 pctx.use_tpu:
             check_strict_plan(plan, pctx.tpu_row_threshold)
         with maybe_span(tr, "executor.build"):
@@ -1534,7 +1473,6 @@ class Session:
     def _auto_id_seed(self, info: TableInfo, c) -> int:
         """MAX(col) over live + staged rows: restored/imported tables
         keep counting past their data."""
-        from tidb_tpu.executor.scan import align_chunk_to_schema
         mx = 0
         snap = self._read_view_snapshot()
         if snap.has_table(info.id):
@@ -1850,7 +1788,6 @@ class Session:
         keep-masks for staged inserts, and the matched rows themselves
         (`want_rows`: a DELETE has no use for them, and gathering eight
         columns of every region that holds a match is half its time)."""
-        from tidb_tpu.executor.scan import align_chunk_to_schema
         schema = Schema.from_table(info)
         cond: Optional[Expression] = None
         if where is not None:
@@ -2248,7 +2185,6 @@ class Session:
                     f"'{stmt.table}'")
             cat.drop_column(stmt.table, stmt.column_name)
             # eager storage rewrite minus the dropped column
-            from tidb_tpu.executor.scan import align_chunk_to_schema
             snap = self.engine.store.snapshot()
             if snap.has_table(info.id):
                 keep_cols = [i for i in range(len(info.columns))
@@ -2452,7 +2388,6 @@ class Session:
     def _analyze(self, stmt: ast.AnalyzeTable) -> ResultSet:
         """Build per-column histogram/NDV/TopN stats (ref:
         executor/analyze.go → statistics/histogram.go:49)."""
-        from tidb_tpu.executor.scan import align_chunk_to_schema
         from tidb_tpu.statistics import analyze_columns
         # counts pending BEFORE the snapshot are certainly covered by it;
         # later-arriving counts must survive the subtraction (the
@@ -2671,7 +2606,6 @@ def _key_tuples(chunk: Chunk, idxs: List[int]):
 
 
 def _used_device(exec_root) -> bool:
-    from tidb_tpu.executor.fragment import TpuFragmentExec
 
     def walk(e):
         if isinstance(e, TpuFragmentExec) and e.used_device:

@@ -55,6 +55,7 @@ import time
 from typing import Dict, List, Optional
 
 from tidb_tpu.errors import TiDBTPUError
+from tidb_tpu.sysvars import var_on
 from tidb_tpu.util import timeline
 from tidb_tpu.util.observability import REGISTRY, normalize_sql
 
@@ -111,8 +112,7 @@ def commit_gate(store, table_id: int) -> threading.Lock:
 
 
 def enabled(sess) -> bool:
-    return str(sess.vars.get("tidb_tpu_write_coalesce", "on")).lower() \
-        not in ("off", "0", "false")
+    return var_on(sess.vars, "tidb_tpu_write_coalesce")
 
 
 def coalesce(sess, table_id: int, stage) -> Optional[int]:

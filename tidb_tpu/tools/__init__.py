@@ -28,6 +28,7 @@ import numpy as np
 from tidb_tpu.chunk import Chunk
 from tidb_tpu.chunk.codec import decode_chunk, encode_chunk
 from tidb_tpu.errors import TiDBTPUError
+from tidb_tpu.executor.scan import align_chunk_to_schema
 
 BACKUP_FORMAT_VERSION = 1
 
@@ -212,7 +213,6 @@ def backup(engine, out_dir: str,
         payloads = []
         if snap.has_table(info.id):
             for region, alive in snap.scan(info.id):
-                from tidb_tpu.executor.scan import align_chunk_to_schema
                 chunk = align_chunk_to_schema(region.chunk, info)
                 if not alive.all():
                     chunk = chunk.take(np.nonzero(alive)[0])

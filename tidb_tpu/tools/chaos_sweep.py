@@ -33,6 +33,10 @@ from typing import Callable, Dict, List, Optional
 from tidb_tpu.errors import (ExecutionError, MemoryQuotaExceeded,
                              ShardFailure, TiDBTPUError, TxnError)
 from tidb_tpu.util import failpoint
+from tidb_tpu.executor import (delta as _delta, device_cache as _dc,
+                               microbatch as _mb,
+                               zonemap)  # noqa: F401 — zonemap registers
+from tidb_tpu.executor.scheduler import POOL, SCHEDULER
 
 # every statement must finish (result or typed error) inside this
 DEADLINE_S = 30.0
@@ -434,7 +438,6 @@ def list_sites() -> Dict[str, str]:
     enumeration matches what the coverage gate sweeps.
     → {site: description} (tools/check_failpoints.py cross-checks the
     count, keeping the advertised site number honest)."""
-    from tidb_tpu.executor import zonemap  # noqa: F401 — registers at import
     return failpoint.catalog()
 
 
@@ -689,8 +692,6 @@ def run_sweep(verbose: bool = False, mesh: Optional[int] = None,
                     failures.append(
                         f"{sc.name}: sibling session made no progress")
             elif sc.run == "microbatch":
-                from tidb_tpu.executor import microbatch as _mb
-                from tidb_tpu.executor.scheduler import SCHEDULER
                 from tidb_tpu.util.observability import REGISTRY
                 # oracle per member, run SOLO (a solo leader takes the
                 # individual path, so the armed demux site never fires)
@@ -755,7 +756,6 @@ def run_sweep(verbose: bool = False, mesh: Optional[int] = None,
                             f"{sc.name}: demux faulted but no fallback "
                             f"was recorded")
             elif sc.run == "steal":
-                from tidb_tpu.executor.scheduler import POOL
                 q = QUERIES[1]
                 # a second serving peer even on a 1-device host: the
                 # steal protocol is pure host-side queue mechanics, so
@@ -811,8 +811,6 @@ def run_sweep(verbose: bool = False, mesh: Optional[int] = None,
                     failures.append(f"{sc.name}: {q!r} SILENT WRONG "
                                     f"RESULT after faulted steal")
             elif sc.run == "podfault":
-                from tidb_tpu.executor import device_cache as _dc
-                from tidb_tpu.executor.scheduler import POOL
                 from tidb_tpu.util.observability import REGISTRY
 
                 def _ctr(name):
@@ -982,7 +980,6 @@ def run_sweep(verbose: bool = False, mesh: Optional[int] = None,
                     wrong += 1
                     failures.append(f"{sc.name}: {q!r} SILENT WRONG RESULT")
             elif sc.run == "compact":
-                from tidb_tpu.executor import delta as _delta
                 q = QUERIES[0]
                 # compaction due after four appended rows: the trigger is
                 # a share of the delta slab's capacity, squeezed for the

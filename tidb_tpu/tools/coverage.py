@@ -14,7 +14,7 @@ Per query the sweep reports:
   fused              every extracted fragment ran on device (and at
                      least one fragment was extracted)
   n_fragments        device fragments extracted from the plan
-  fallback           normalized reason code (fragment.FALLBACK_REASONS)
+  fallback           normalized reason code (eligibility.FALLBACK_REASONS)
                      of the first fragment that fell back, else None
   programs_per_slab  warm-run device launches / data slabs — the
                      slabs+1 fused-pipeline model shows up as ~1.x
@@ -32,6 +32,9 @@ import time
 from typing import Dict, List, Optional
 
 import numpy as np
+from tidb_tpu.executor import run_to_completion
+from tidb_tpu.executor.builder import build
+from tidb_tpu.executor.fragment import TpuFragmentExec
 
 # ---------------------------------------------------------------------------
 # Queries: TPC-H 1-22, adapted to the engine's SQL surface.
@@ -221,7 +224,7 @@ QUERIES: Dict[str, str] = {
 EXPECTED_FALLBACK: Dict[str, str] = {
     # (q18 left this list in PR 28: its IN over a grouped-HAVING subquery
     # plans as a semijoin whose build side is a nested device-rows
-    # fragment — tree_fragment.nest_build_aggregates)
+    # fragment — eligibility.nest_build_aggregates)
     # the SUBSTRING(c_phone, ...) group key / IN-list is a COMPUTED
     # string: no dictionary to prepare codes against, host executes
     "q22": "shape",
@@ -373,7 +376,6 @@ def build_schema(s, n_lineitem: int = 6000, seed: int = 42) -> None:
 # ---------------------------------------------------------------------------
 
 def _fragments(root) -> list:
-    from tidb_tpu.executor.fragment import TpuFragmentExec
     out = []
 
     def walk(e):
@@ -389,7 +391,6 @@ def _fragments(root) -> list:
 def run_one(s, name: str, time_cpu: bool = True) -> dict:
     """Run one coverage query (device on, forced threshold) and report
     fused status, fallback code, warm launches-per-slab, and speedup."""
-    from tidb_tpu.executor import build, run_to_completion
     from tidb_tpu.parser import parse
 
     sql = QUERIES[name]

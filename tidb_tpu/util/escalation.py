@@ -34,7 +34,11 @@ from tidb_tpu.util import failpoint
 from tidb_tpu.util.backoff import Backoffer
 
 
-def _pow2(n: int, lo: int = 1) -> int:
+def pow2(n: int, lo: int = 1) -> int:
+    """The capacity a need of `n` rounds up to: the next power of two, at
+    least `lo` (a power of two). Every static shape the executor sizes —
+    slabs, group and join capacities, exchange and delta buckets — is
+    rounded HERE, so the ladder's rungs and the first guess agree."""
     c = max(int(n), lo, 1)
     return 1 << (c - 1).bit_length()
 
@@ -147,11 +151,11 @@ class CapacityLadder:
         result is clamped to `max_cap` when given (callers detect the
         exhausted ladder as current >= max_cap BEFORE calling)."""
         if need is not None:
-            new = _pow2(max(int(need), current + 1), lo=lo)
+            new = pow2(max(int(need), current + 1), lo=lo)
             self.stats.exact_resizes += 1
             self.stats.note(kind, "exact")
         else:
-            new = _pow2(current * factor, lo=lo)
+            new = pow2(current * factor, lo=lo)
             self.stats.doublings += 1
             self.stats.note(kind, "double")
         if max_cap is not None:

@@ -93,7 +93,7 @@ for _site, _desc in (
 # exchange or dispatches per-shard steps, so the sweep's coverage gate
 # only demands them when it runs with a mesh (--mesh N)
 register("exchange-overflow", "distributed exchange bucket resize/retrace "
-         "(executor/fragment.py _run_device_dist)", mesh_only=True)
+         "(executor/dist_fragment.py _dist_exec)", mesh_only=True)
 register("shard-step", "host-side per-shard dispatch of a distributed "
          "fragment step (executor/dist_fragment.py) — a raise here models "
          "ONE shard failing; the staged agg path retries only that rank, "
@@ -131,7 +131,7 @@ register("fused-pipeline-overflow", "capacity boundary of the slab-loop "
          "join/group overflows are classified into rerun sets; a VALUE "
          "reads as an overflow of a whole-statement program, which the "
          "per-slab driver then answers "
-         "(executor/fragment.py _run_agg_slabs)")
+         "(executor/agg_slabs.py run_agg_slabs)")
 register("compressed-decode-mismatch", "layout-descriptor validation of "
          "the compressed device-resident columns a statement is about to "
          "decode — a value here models a corrupted descriptor, which must "
@@ -142,7 +142,7 @@ register("fused-finalize-overflow", "TopN / distinct-pair-cap validation "
          "distinct-pair count check (before clipped pair sets could be "
          "consumed) and after the finalize's flag fetch; overflow resizes "
          "through the resumable 'pairs' ladder rung, re-running only the "
-         "slabs that clipped (executor/fragment.py _run_agg_slabs)")
+         "slabs that clipped (executor/agg_slabs.py run_agg_slabs)")
 register("delta-append", "atomic apply point of a staged write — hit "
          "inside Store.commit after validation, before the locked "
          "apply+version bump; a retryable raise here heals through the "

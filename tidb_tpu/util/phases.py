@@ -1,7 +1,7 @@
 """Per-query device phase timing — the overlap runtime's observability.
 
 The streamed first-touch pipeline (executor/device_cache.open_table +
-fragment._execute_*) interleaves host encode of slab k+1 with the async
+fragment._execute_*, agg_slabs.run_agg_slabs) interleaves host encode of slab k+1 with the async
 upload/compute of slab k. This module measures where the wall time went
 and how much host work was actually hidden behind device activity:
 

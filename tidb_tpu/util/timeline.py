@@ -216,7 +216,7 @@ class _Collector:
 # JAX's own duration events → `compile`-lane spans: a program is traced,
 # lowered and compiled inside its first launch, not where it is built (but
 # for a statement program, which compiles where it is built, outside the
-# batch slot: `fragment._StatementProgram`)
+# batch slot: `agg_slabs._StatementProgram`)
 _JAX_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "jax.trace",
                "/jax/core/compile/jaxpr_to_mlir_module_duration":
                    "jax.lower",

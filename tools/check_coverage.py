@@ -45,7 +45,7 @@ def run(root: str = None):
     with open(base_path) as f:
         baseline = json.load(f)["queries"]
     fresh = _sweep(root)
-    from tidb_tpu.executor.fragment import FALLBACK_REASONS
+    from tidb_tpu.executor.eligibility import FALLBACK_REASONS
     problems = []
     for q in sorted(baseline, key=lambda n: int(n[1:])):
         pin = baseline[q]

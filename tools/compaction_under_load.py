@@ -30,7 +30,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmarks")]
 
 from tidb_tpu.client import Client                            # noqa: E402
-from tidb_tpu.executor import delta, device_cache, fragment   # noqa: E402
+from tidb_tpu.executor import (compile_cache, delta,   # noqa: E402
+                               device_cache)
 from tidb_tpu.server import Server                            # noqa: E402
 from tidb_tpu.session import Engine                           # noqa: E402
 from tidb_tpu.util.observability import REGISTRY              # noqa: E402
@@ -87,13 +88,13 @@ def main():
     say(phase="loaded", device=str(jax.devices()[0].device_kind))
 
     traces, events, rebuilt_at = {}, [], []
-    count = fragment._count_trace
+    count = compile_cache.count_trace
 
     def count_by_thread():
         name = threading.current_thread().name
         traces[name] = traces.get(name, 0) + 1
         count()
-    fragment._count_trace = count_by_thread
+    compile_cache.count_trace = count_by_thread
 
     def timed(mod, name):
         inner = getattr(mod, name)
